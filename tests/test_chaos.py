@@ -149,7 +149,7 @@ async def test_deadline_raises_typed_error(server):
         with pytest.raises(ZKDeadlineError) as ei:
             await asyncio.wait_for(c.get('/d', deadline=200), 5)
         assert ei.value.code == 'DEADLINE_EXCEEDED'
-        assert isinstance(ei.value, ZKProtocolError)  # typed taxonomy
+        assert isinstance(ei.value, ZKProtocolError)  # typed error
         assert ei.value.opcode == 'GET_DATA'
         assert ei.value.path == '/d'
     finally:

@@ -348,8 +348,8 @@ async def test_ingest_corrupt_ustring_parity():
 
 async def test_ingest_host_placement():
     """Explicit placement='host' pins ticks to the CPU backend and
-    serves traffic normally (the latency-aware fallback for tunneled
-    accelerators whose dispatch RTT exceeds the tick budget)."""
+    serves traffic normally (where 'auto' ends up when the
+    accelerator's dispatch RTT exceeds the tick budget)."""
     ingest = FleetIngest(body_mode='host', max_frames=8, bypass_bytes=0,
                          placement='host', warm='block')
     srv = await ZKServer().start()

@@ -259,6 +259,61 @@ async def test_unmatched_xid_exits_3(event_loop):
     assert s['errors']['proto'] >= 1
 
 
+async def test_uneven_connect_phase_does_not_hang(event_loop):
+    """Two epoll threads whose connect phases end far apart (one
+    member answers its handshake 300 ms late — a follower taking the
+    ensure-path CREATE does the same): the thread that finished first
+    keeps its phase stamp while it idles, so the run proceeds.  It
+    used to clear the stamp after one 10 ms pump, and main then waited
+    forever for both stamps at once."""
+    import time
+
+    lsock = socket.socket()
+    lsock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+    lsock.bind(('127.0.0.1', 0))
+    lsock.listen(8)
+    port = lsock.getsockname()[1]
+
+    def serve(conn, late):
+        try:
+            with conn:
+                if _recv_frame(conn) is None:   # ConnectRequest
+                    return
+                if late:
+                    time.sleep(0.3)
+                conn.sendall(_frame(_CONNECT_RESP))
+                n = 0
+                while True:
+                    body = _recv_frame(conn)
+                    if body is None:
+                        return
+                    xid = struct.unpack('>i', body[:4])[0]
+                    conn.sendall(_frame(
+                        struct.pack('>iqi', xid, 100 + n, 0)))
+                    n += 1
+        except OSError:
+            pass
+
+    def accept():
+        try:
+            for i in range(2):
+                conn, _ = lsock.accept()
+                threading.Thread(target=serve, args=(conn, i == 0),
+                                 daemon=True).start()
+        except OSError:
+            pass
+        finally:
+            lsock.close()
+
+    threading.Thread(target=accept, daemon=True).start()
+    cmd = loadgen.argv([('127.0.0.1', port)], 2, threads=2, count=2,
+                       mix='get=100', ensure_path=False, pipeline=1)
+    rc, s = await _run_loadgen(cmd, timeout=20)
+    assert rc == 0, s
+    assert s['connected'] == 2
+    assert s['ops']['GET_DATA']['count'] == 4
+
+
 # -- pillar 4: 1k-session tier-1 smoke ----------------------------------
 
 

@@ -163,7 +163,6 @@ def test_corrupt_artifact_load_failure_latches(pristine):
     src.write_text('// source\n')
     bad = pristine / 'libzkwire.test.so'
     bad.write_bytes(b'\x7fELF garbage')
-    os.utime(str(bad), (time.time() + 60, time.time() + 60))
     with native._lock:
         native._try_load()
     assert native._lib is None and native._load_failed
@@ -172,7 +171,6 @@ def test_corrupt_artifact_load_failure_latches(pristine):
     esrc.write_text('// source\n')
     ebad = pristine / '_zkwire_ext.test.so'
     ebad.write_bytes(b'\x7fELF garbage')
-    os.utime(str(ebad), (time.time() + 60, time.time() + 60))
     with native._lock:
         native._try_load_ext()
     assert native._ext is None and native._ext_load_failed
@@ -212,3 +210,93 @@ def test_ext_path_is_abi_tagged():
     tag = sysconfig.get_config_var('SOABI') or 'abi3'
     assert tag in native.ext_path()
     assert 'v%d' % native._EXT_ABI_VERSION in native.ext_path()
+
+
+# -- artifacts are named by what they were built from -------------------
+
+_GOOD_LIB = ('extern "C" int zkwire_abi_version() { return %d; }\n'
+             'extern "C" int zkwire_frame_scan(const void*, int, int, '
+             'int, int*, int*, int*) { return 0; }\n'
+             % native._ABI_VERSION)
+
+
+@pytest.fixture
+def own_tree(monkeypatch, tmp_path):
+    """Like ``pristine``, but with the REAL path helpers over a private
+    checkout root, so the hash naming itself is under test."""
+    saved = (native._lib, native._load_failed, native._builder)
+    native._lib, native._load_failed, native._builder = None, False, None
+    (tmp_path / 'native').mkdir()
+    (tmp_path / 'tools').mkdir()
+    monkeypatch.setattr(native, '_root', lambda: str(tmp_path))
+    yield tmp_path
+    native._lib, native._load_failed, native._builder = saved
+
+
+def test_artifact_name_follows_source_and_flags(own_tree, monkeypatch):
+    """The name carries a digest of the source bytes and the compile
+    command: edit either and the artifact is a different file."""
+    src = own_tree / 'native' / 'zkwire.cpp'
+    src.write_text(_GOOD_LIB)
+    first = native.lib_path()
+    assert first == native.lib_path()            # stable
+    assert os.path.dirname(first) == str(own_tree / 'native')
+    src.write_text(_GOOD_LIB + '// edited\n')
+    edited = native.lib_path()
+    assert edited != first
+    monkeypatch.setattr(native, '_LIB_CC', native._LIB_CC + ['-O3'])
+    assert native.lib_path() not in (first, edited)
+
+    lg = own_tree / 'tools' / 'loadgen.c'
+    lg.write_text('int main(void) { return 0; }\n')
+    a = native.loadgen_path()
+    lg.write_text('int main(void) { return 1; }\n')
+    assert native.loadgen_path() != a
+
+
+def test_planted_stale_binary_is_never_loaded(own_tree):
+    """A checkout copied with its build outputs: binaries of an OLDER
+    source sit in native/ with copy-time (i.e. newer) mtimes — under
+    the old version-only name and under the older source's own hash
+    name.  Neither is what the current files produce, so neither is
+    bound: the loader asks for a build instead."""
+    if not have_cc():
+        pytest.skip('no compiler')
+    src = own_tree / 'native' / 'zkwire.cpp'
+    src.write_text(_GOOD_LIB.replace(
+        'return %d' % native._ABI_VERSION, 'return 987654'))
+    older = native.build()                # a real, loadable, STALE .so
+    assert older is not None
+    legacy = own_tree / 'native' / ('libzkwire.v%d.so'
+                                    % native._ABI_VERSION)
+    legacy.write_bytes(open(older, 'rb').read())
+    future = time.time() + 3600
+    os.utime(older, (future, future))     # "newer than source"
+    os.utime(str(legacy), (future, future))
+
+    src.write_text(_GOOD_LIB)             # the files git would commit
+    want = native.lib_path()
+    assert want not in (older, str(legacy))
+    with native._lock:
+        native._try_load()
+    assert native._lib is None and not native._load_failed
+    # the blocking path builds the current source and binds THAT
+    lib = native.ensure_lib()
+    assert lib is not None
+    assert lib.zkwire_abi_version() == native._ABI_VERSION
+    assert os.path.exists(want)
+
+
+def test_loadgen_is_rebuilt_from_current_source(own_tree):
+    if not have_cc():
+        pytest.skip('no compiler')
+    lg = own_tree / 'tools' / 'loadgen.c'
+    lg.write_text('int main(void) { return 7; }\n')
+    stale = native.build_loadgen()
+    assert subprocess.run([stale]).returncode == 7
+    lg.write_text('int main(void) { return 9; }\n')
+    future = time.time() + 3600
+    os.utime(stale, (future, future))
+    fresh = native.build_loadgen()
+    assert fresh != stale
+    assert subprocess.run([fresh]).returncode == 9

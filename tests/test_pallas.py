@@ -110,55 +110,103 @@ def test_pallas_empty_and_full_rows():
     assert int(got.n_frames[1]) == 1 and bool(got.bad[2])
 
 
-def test_vmem_limit_env_override(monkeypatch):
-    """ZKSTREAM_PALLAS_VMEM_BYTES overrides the guard ceiling at import
-    time; malformed or non-positive values warn and keep the default."""
-    from zkstream_tpu.ops import pallas_scan
-
-    monkeypatch.setenv('ZKSTREAM_PALLAS_VMEM_BYTES', '33554432')
-    assert pallas_scan._read_vmem_limit() == 33554432
-    for bad in ('32M', '0', '-1'):
-        monkeypatch.setenv('ZKSTREAM_PALLAS_VMEM_BYTES', bad)
-        with pytest.warns(UserWarning, match='ZKSTREAM_PALLAS_VMEM'):
-            assert pallas_scan._read_vmem_limit() == 16 * 1024 * 1024
+V5E = 'TPU v5 lite'
 
 
-def test_vmem_guard_and_fallback(monkeypatch):
-    """Shapes whose kernel would blow the scoped-VMEM limit must raise
-    a clear error from pallas_wire_scan, and wire_pipeline_step_pallas
-    must transparently fall back to the jnp pipeline for them."""
-    from zkstream_tpu.ops import pallas_scan
-    from zkstream_tpu.ops.pallas_scan import fits_vmem, pallas_wire_scan
+def test_vmem_ceiling_is_keyed_by_device_kind():
+    """The guard's ceiling comes from a table keyed by the device_kind
+    JAX reports; a device that is not in it is an error, not a
+    default — and this suite's CPU devices are not in it."""
+    from zkstream_tpu.ops.pallas_scan import fits_vmem, scoped_vmem_limit
 
-    # the assertions below encode the default 16 MiB ceiling
-    monkeypatch.setattr(pallas_scan, '_VMEM_LIMIT', 16 * 1024 * 1024)
+    assert scoped_vmem_limit(V5E) == 16 * 1024 * 1024
+    with pytest.raises(ValueError, match='no scoped-VMEM ceiling'):
+        scoped_vmem_limit('TPU v9000')
+    with pytest.raises(ValueError, match='no scoped-VMEM ceiling'):
+        scoped_vmem_limit()              # the target device: a CPU
+    with pytest.raises(ValueError, match='no scoped-VMEM ceiling'):
+        fits_vmem(64, 512)
 
-    assert fits_vmem(256, 5000, max_frames=48, block_rows=128)
-    # observed Mosaic stack OOMs: R=256 x Lp~5120 and R=128 x Lp~13568
-    assert not fits_vmem(256, 5000, max_frames=48, block_rows=256)
-    assert not fits_vmem(1024, 13440, max_frames=128, block_rows=128)
+
+def test_vmem_guard_refuses_what_mosaic_refuses():
+    """The shapes the old estimate admitted and the installed Mosaic
+    refuses (RESOURCE_EXHAUSTED, scoped vmem) are refused by the guard
+    now; the pocket shapes that compile are still admitted.
+    (tests/test_compile_v5e.py compiles the boundary for real.)"""
+    from zkstream_tpu.ops.pallas_scan import fits_vmem, fits_vmem_full
+
+    # multi-block R=128: Lp=7296 needs 16.57 MiB, Lp=8320 18.82
+    assert not fits_vmem(8192, 7296 - 20, 64, 64, V5E)
+    assert not fits_vmem(8192, 8320 - 20, 64, 64, V5E)
+    # single block: R=64 x Lp=16512 needs 16.2, R=40 x Lp=30080 18.39
+    assert not fits_vmem(64, 16512 - 20, 32, 64, V5E)
+    assert not fits_vmem(40, 30080 - 20, 32, 64, V5E)
+    # the pocket and its ingest buckets
+    assert fits_vmem(8192, 6144, 64, 64, V5E)
+    assert fits_vmem(4096, 4096, 32, 64, V5E)
+    assert not fits_vmem(8192, 8192, 32, 64, V5E)
+    # bench.py's corpus row does not fit one program
+    assert not fits_vmem(16384, 15792, 64, 64, V5E)
+    # rows/program double with block_rows 256: half the length fits
+    assert fits_vmem(256, 2048, 48, 128, V5E)
+    assert not fits_vmem(512, 5000, 48, 256, V5E)
+    # the fused kernel pays for every unrolled data word
+    assert fits_vmem_full(8192, 2048, 32, 64, 16, V5E)
+    assert fits_vmem_full(1024, 512, 8, 64, 256, V5E)
+    assert not fits_vmem_full(8192, 4096, 8, 64, 256, V5E)
+
+
+def test_pallas_names_never_substitute_jnp(monkeypatch):
+    """A function named ``*_pallas`` runs the kernel or raises: a
+    shape past the VMEM ceiling is a ValueError from both entry
+    points, never a quiet jnp result (only ``auto_impl`` may choose
+    jnp, and it says so)."""
+    from zkstream_tpu.ops import pallas_scan, pipeline
+    from zkstream_tpu.ops.pipeline import wire_full_decode_pallas
+
+    monkeypatch.setattr(pallas_scan, 'scoped_vmem_limit',
+                        lambda device_kind=None: 16 * 1024 * 1024)
+
+    def no_jnp(*a, **kw):
+        raise AssertionError('jnp pipeline substituted for the kernel')
+    monkeypatch.setattr(pipeline, 'wire_pipeline_step', no_jnp)
+    monkeypatch.setattr(pipeline, 'getdata_bodies_jnp', no_jnp)
 
     buf = jnp.zeros((1024, 13440), jnp.uint8)
     lens = jnp.zeros((1024,), jnp.int32)
     with pytest.raises(ValueError, match='scoped VMEM'):
-        pallas_wire_scan(buf, lens, max_frames=128, block_rows=128)
-    # The pipeline wrapper silently takes the jnp path instead.
-    out = wire_pipeline_step_pallas(buf, lens, max_frames=128,
-                                    block_rows=128)
-    assert int(out.n_frames.sum()) == 0
+        wire_pipeline_step_pallas(buf, lens, max_frames=128,
+                                  block_rows=128)
+    buf = jnp.zeros((8, 200_000), jnp.uint8)
+    lens = jnp.zeros((8,), jnp.int32)
+    with pytest.raises(ValueError, match='scoped VMEM'):
+        wire_full_decode_pallas(buf, lens, max_frames=6, max_data=16,
+                                block_rows=8)
 
 
-def test_auto_dispatch_routes_by_platform_and_shape():
-    """wire_pipeline_step_auto picks the measured winner: jnp on
-    non-TPU platforms (this suite runs on the CPU backend) and inside
-    the recorded pocket only on TPU; the pocket predicate matches the
-    sweep table in PROFILE.md."""
+def test_pallas_on_a_device_without_a_ceiling_raises():
+    """Non-interpret on this suite's CPU backend: the guard has no
+    ceiling for the device and says so (Mosaic could not lower there
+    either) — it does not fall back."""
+    buf = jnp.zeros((8, 256), jnp.uint8)
+    lens = jnp.zeros((8,), jnp.int32)
+    with pytest.raises(ValueError, match='no scoped-VMEM ceiling'):
+        wire_pipeline_step_pallas(buf, lens, max_frames=4)
+
+
+def test_auto_dispatch_routes_by_platform_and_shape(monkeypatch):
+    """auto_impl names the measured winner: jnp on non-TPU platforms
+    (this suite runs on the CPU backend), the kernel inside the
+    recorded pocket on TPU — but only where it also fits the device's
+    scoped VMEM, and the name says which."""
+    import types
+
     from zkstream_tpu.ops.pipeline import (
         _pallas_pocket,
-        _target_platform,
-        wire_pipeline_step,
+        auto_impl,
         wire_pipeline_step_auto,
     )
+    from zkstream_tpu.utils import platform
 
     # the recorded win pocket (tools/sweep_pallas.py)
     assert _pallas_pocket(8192, 64)
@@ -166,13 +214,24 @@ def test_auto_dispatch_routes_by_platform_and_shape():
     assert not _pallas_pocket(2048, 64)      # small fleet: jnp
     assert not _pallas_pocket(32768, 64)     # tie band: jnp default
 
-    assert _target_platform() == 'cpu'       # forced by conftest
+    assert auto_impl(8192, 2048, 64) == 'jnp'    # CPU: forced by conftest
     buf = np.zeros((8192, 256), np.uint8)
     lens = np.zeros((8192,), np.int32)
     auto = wire_pipeline_step_auto(buf, lens, max_frames=64)
     ref = wire_pipeline_step(buf, lens, max_frames=64)
     # on CPU the auto path IS the jnp path (pallas cannot lower here)
     assert int(jnp.sum(auto.n_frames)) == int(jnp.sum(ref.n_frames))
+
+    chip = types.SimpleNamespace(platform='tpu', device_kind=V5E)
+    monkeypatch.setattr(platform, 'target_device', lambda: chip)
+    assert auto_impl(8192, 2048, 64) == 'pallas'
+    assert auto_impl(4096, 4096, 32) == 'pallas'
+    assert auto_impl(8192, 8192, 32) == 'jnp'    # pocket, but no fit
+    assert auto_impl(2048, 2048, 64) == 'jnp'    # outside the pocket
+    # a TPU the kernels were never sized for is an error, not a guess
+    chip.device_kind = 'TPU v9000'
+    with pytest.raises(ValueError, match='no scoped-VMEM ceiling'):
+        auto_impl(8192, 2048, 64)
 
 
 def test_auto_dispatch_honors_default_device_override():
@@ -181,21 +240,10 @@ def test_auto_dispatch_honors_default_device_override():
     jnp even when the pocket matches."""
     import jax
 
-    from zkstream_tpu.ops.pipeline import _target_platform
+    from zkstream_tpu.ops.pipeline import auto_impl
 
     with jax.default_device(jax.devices('cpu')[0]):
-        assert _target_platform() == 'cpu'
-
-
-def test_target_platform_accepts_string_override():
-    """jax.default_device also accepts a platform string; the dispatch
-    probe must not assume a Device object."""
-    import jax
-
-    from zkstream_tpu.ops.pipeline import _target_platform
-
-    with jax.default_device('cpu'):
-        assert _target_platform() == 'cpu'
+        assert auto_impl(8192, 2048, 64) == 'jnp'
 
 
 def _getdata_fleet(rng, B, L, max_data):
@@ -248,33 +296,6 @@ def test_pallas_full_decode_matches_jnp(seed):
     st_p, bd_p = wire_full_decode_pallas(
         buf, lens, max_frames=6, max_data=MD, block_rows=8,
         interpret=True)
-    st_j = wire_pipeline_step(buf, lens, max_frames=6)
-    _assert_same(st_p, st_j)
-    bd_j = parse_reply_bodies(buf, st_j.starts, st_j.sizes,
-                              max_data=MD, max_path=8)
-    for f in ('data_len', 'data', 'data_mask', 'data_ok'):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(bd_p, f)), np.asarray(getattr(bd_j, f)),
-            err_msg=f'field {f}')
-    _assert_same(bd_p.stat_after_data, bd_j.stat_after_data)
-
-
-def test_full_decode_vmem_fallback_is_the_jnp_path():
-    """A shape whose fused kernel would exceed scoped VMEM must fall
-    back to wire_pipeline_step + the jnp GET_DATA unpack — same
-    planes, no compile attempt — exactly as the header kernel's
-    fallback contract (the r4 rewiring this guards)."""
-    from zkstream_tpu.ops.pallas_scan import fits_vmem_full
-    from zkstream_tpu.ops.pipeline import wire_full_decode_pallas
-    from zkstream_tpu.ops.replies import parse_reply_bodies
-
-    rng = random.Random(3)
-    MD = 16
-    B, L = 8, 200_000               # L large: blows the VMEM budget
-    assert not fits_vmem_full(B, L, 6, 8, MD)
-    buf, lens = _getdata_fleet(rng, B, L, MD)
-    st_p, bd_p = wire_full_decode_pallas(
-        buf, lens, max_frames=6, max_data=MD, block_rows=8)
     st_j = wire_pipeline_step(buf, lens, max_frames=6)
     _assert_same(st_p, st_j)
     bd_j = parse_reply_bodies(buf, st_j.starts, st_j.sizes,

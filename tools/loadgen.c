@@ -1193,6 +1193,13 @@ static void *thread_main(void *arg) {
         pump(th, 10);
         int done = 0;
         switch (phase) {
+        case PH_CONNECT:
+            /* finished in the entry branch above, and it stays
+             * finished: a thread that idles here while a slower one
+             * still handshakes must not clear its stamp, or main's
+             * wait_phase never sees every thread done at once */
+            done = 1;
+            break;
         case PH_HOLD:
             ping_sweep(th, (double)C.session_timeout_ms / 3000.0);
             done = 1;              /* hold ends when main says so */

@@ -482,7 +482,7 @@ class ZKConnection(FSM):
         self.log.warning('error communicating with ZK: %s',
                          self.last_error)
         reqs, self.reqs = self.reqs, {}
-        # Pending ops surface the ZK error taxonomy, never a raw OS
+        # Pending ops surface the typed ZK errors, never a raw OS
         # exception: a socket-level error becomes CONNECTION_LOSS with
         # the original chained as __cause__ (the clean-close straggler
         # path already spoke ZKProtocolError only).

@@ -197,46 +197,65 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _read_vmem_limit() -> int:
-    """Per-program scoped-VMEM ceiling used by the compile guard.
-
-    Defaults to the 16 MiB budget calibrated on v5e; other TPU
-    generations (or future Mosaic versions) may allow more, so the
-    guard is overridable via ``ZKSTREAM_PALLAS_VMEM_BYTES``.  Read
-    once at import: ``pallas_wire_scan`` is jitted, so a per-call read
-    would only take effect at first trace per shape and could diverge
-    from ``fits_vmem``."""
-    import os
-    import warnings
-    env = os.environ.get('ZKSTREAM_PALLAS_VMEM_BYTES')
-    if env:
-        try:
-            val = int(env)
-        except ValueError:
-            val = -1
-        if val > 0:
-            return val
-        warnings.warn(
-            'ignoring ZKSTREAM_PALLAS_VMEM_BYTES=%r (must be a '
-            'positive integer byte count); using 16 MiB' % (env,))
-    return 16 * 1024 * 1024
+#: Mosaic's scoped-VMEM ceiling per kernel program, by the
+#: ``device_kind`` JAX reports.  One entry per device the kernels have
+#: been compiled for; the compiler states the figure itself when a
+#: program exceeds it ("limit 16.00M", libtpu 0.0.34).  A device that
+#: is not in the table is an error, not a default.
+_SCOPED_VMEM_BYTES = {
+    'TPU v5 lite': 16 * 1024 * 1024,
+}
 
 
-_VMEM_LIMIT = _read_vmem_limit()
+def scoped_vmem_limit(device_kind: str | None = None) -> int:
+    """The scoped-VMEM ceiling the guard holds a kernel program to on
+    ``device_kind`` (default: the device the computation is being
+    traced for, utils/platform.target_device)."""
+    if device_kind is None:
+        from ..utils.platform import target_device
+        device_kind = target_device().device_kind
+    try:
+        return _SCOPED_VMEM_BYTES[device_kind]
+    except KeyError:
+        raise ValueError(
+            'no scoped-VMEM ceiling on record for device kind %r: the '
+            'Pallas kernels have only been sized for %s'
+            % (device_kind, sorted(_SCOPED_VMEM_BYTES))) from None
 
 
-def _vmem_estimate(R: int, Lp: int, max_frames: int,
-                   words_per_frame: int = 6) -> int:
-    """Projected scoped-VMEM bytes for one program: ~3 int32 planes of
-    [R, Lp] live at once (byte plane, rolled word plane, lane iota /
-    temporaries) plus the double-buffered u8 input and the per-frame
-    output blocks (6 int32 words/frame for the tick kernel; the fused
-    full-decode kernel adds the dlen/data/Stat words).  Calibrated
-    against observed Mosaic stack OOMs (20.8M at R=256, Lp=5120;
-    20.5M at R=128, Lp=13568)."""
-    plane = R * Lp * 4
-    return (int(3.2 * plane) + words_per_frame * max_frames * R * 4
-            + (1 << 20))
+#: (output words/frame, live [R, 1] columns) of the header kernel, for
+#: the VMEM guard: the 6 tick planes out; under 3 columns measured
+_SCAN_SIZES = (6, 4)
+
+
+def _vmem_estimate(R: int, Bp: int, Lp: int, max_frames: int,
+                   words_per_frame: int, columns: int) -> int:
+    """Upper bound on the scoped-VMEM bytes of one kernel program,
+    fitted to what the installed Mosaic (libtpu 0.0.34) allocates for
+    ``TPU v5 lite`` — found by bisecting ``vmem_limit_bytes`` per shape
+    on the compile-only topology (tests/test_compile_v5e.py holds the
+    guard to it):
+
+    - 4 int32 planes of [R, Lp] live at once (byte plane, rolled word
+      plane, lane iota, select temporaries): 4.0x the plane in every
+      shape measured;
+    - ``columns`` [R, 1] int32 values live across the frame loop, each
+      padded to a full 128-lane tile (R x 512 B);
+    - on a multi-block grid, the double-buffered u8 input block and
+      the double-buffered per-frame output blocks.  (Mosaic only
+      charges the input block from Lp >= 7296 at R=128 — 16.38 MiB
+      there against 14.36 at Lp=7168 — so counting it always refuses
+      Lp 7040-7168, which would compile; a single block is charged
+      neither);
+    - 256 KiB of slack on top.
+
+    The previous fit (3.2x the plane + outputs + 1 MiB, calibrated on
+    an older Mosaic) admitted shapes this compiler refuses: R=128,
+    Lp=7296, F=64 estimated 12.59 MiB, needs 16.57."""
+    est = 4 * R * Lp * 4 + columns * R * 512 + (256 << 10)
+    if Bp > R:
+        est += 2 * R * Lp + 2 * words_per_frame * max_frames * R * 4
+    return est
 
 
 def _block_shape(B: int, L: int, block_rows: int,
@@ -257,12 +276,31 @@ def _block_shape(B: int, L: int, block_rows: int,
     return R, Bp, _round_up(L + _PAD, 128)
 
 
+def _check_vmem(kernel: str, R: int, Bp: int, Lp: int, max_frames: int,
+                words: int, columns: int) -> None:
+    """The compile guard of both kernels: a readable error where
+    Mosaic would answer RESOURCE_EXHAUSTED."""
+    need = _vmem_estimate(R, Bp, Lp, max_frames, words, columns)
+    limit = scoped_vmem_limit()
+    if need > limit:
+        raise ValueError(
+            '%s: one program of R=%d rows x Lp=%d bytes x %d frames '
+            '(%d words/frame) needs ~%.1f MiB of scoped VMEM (> %d MiB '
+            'on this device); shrink block_rows, L or max_data, or use '
+            'the jnp pipeline, which has no such bound'
+            % (kernel, R, Lp, max_frames, words, need / 2**20,
+               limit >> 20))
+
+
 def fits_vmem(B: int, L: int, max_frames: int = 32,
-              block_rows: int = 64) -> bool:
-    """Whether :func:`pallas_wire_scan` can compile for this shape
-    without exceeding the per-program scoped-VMEM limit."""
-    R, _Bp, Lp = _block_shape(B, L, block_rows)
-    return _vmem_estimate(R, Lp, max_frames) <= _VMEM_LIMIT
+              block_rows: int = 64,
+              device_kind: str | None = None) -> bool:
+    """Whether :func:`pallas_wire_scan` compiles for this shape inside
+    ``device_kind``'s scoped-VMEM ceiling (default: the device being
+    traced for)."""
+    R, Bp, Lp = _block_shape(B, L, block_rows)
+    return (_vmem_estimate(R, Bp, Lp, max_frames, *_SCAN_SIZES)
+            <= scoped_vmem_limit(device_kind))
 
 
 @functools.partial(
@@ -286,17 +324,9 @@ def pallas_wire_scan(buf, lens, max_frames: int = 32,
     """
     B, L = buf.shape
     R, Bp, Lp = _block_shape(B, L, block_rows, interpret)
-    if not interpret and \
-            _vmem_estimate(R, Lp, max_frames) > _VMEM_LIMIT:
-        raise ValueError(
-            'pallas_wire_scan shape (rows/program R=%d from '
-            'block_rows=%d, L=%d, max_frames=%d) needs ~%d MiB of '
-            'scoped VMEM (> %d MiB limit); shrink block_rows or L, or '
-            'use the jnp pipeline (wire_pipeline_step), which has no '
-            'such bound'
-            % (R, block_rows, L, max_frames,
-               _vmem_estimate(R, Lp, max_frames) >> 20,
-               _VMEM_LIMIT >> 20))
+    if not interpret:
+        _check_vmem('pallas_wire_scan', R, Bp, Lp, max_frames,
+                    *_SCAN_SIZES)
 
     buf = jnp.zeros((Bp, Lp), jnp.uint8).at[:B, :L].set(buf)
     lens = jnp.zeros((Bp, 1), jnp.int32).at[:B, 0].set(
@@ -342,18 +372,24 @@ def pallas_wire_scan(buf, lens, max_frames: int = 32,
     }
 
 
-def full_scan_words(max_data: int) -> int:
-    """Output words/frame of the fused full-decode kernel (for the
-    VMEM guard): 6 tick planes + dlen + data words + Stat words."""
-    return 7 + max_data // 4 + _STAT_WORDS
+def _full_scan_sizes(max_data: int) -> tuple[int, int]:
+    """(output words/frame, live [R, 1] columns) of the fused
+    full-decode kernel, for the VMEM guard: 6 tick planes + dlen +
+    data words + Stat words out; every unrolled data-word gather keeps
+    two columns live on top of a dozen for the scan and the Stat
+    (measured: 18 / 44 / 139 columns at max_data 16 / 64 / 256)."""
+    dw = max_data // 4
+    return 7 + dw + _STAT_WORDS, 12 + 2 * dw
 
 
 def fits_vmem_full(B: int, L: int, max_frames: int = 32,
-                   block_rows: int = 64, max_data: int = 16) -> bool:
+                   block_rows: int = 64, max_data: int = 16,
+                   device_kind: str | None = None) -> bool:
     """VMEM guard for :func:`pallas_wire_full_scan`."""
-    R, _Bp, Lp = _block_shape(B, L, block_rows)
-    return _vmem_estimate(R, Lp, max_frames,
-                          full_scan_words(max_data)) <= _VMEM_LIMIT
+    R, Bp, Lp = _block_shape(B, L, block_rows)
+    return (_vmem_estimate(R, Bp, Lp, max_frames,
+                           *_full_scan_sizes(max_data))
+            <= scoped_vmem_limit(device_kind))
 
 
 @functools.partial(
@@ -382,16 +418,9 @@ def pallas_wire_full_scan(buf, lens, max_frames: int = 32,
     B, L = buf.shape
     R, Bp, Lp = _block_shape(B, L, block_rows, interpret)
     DW = max_data // 4
-    words = full_scan_words(max_data)
-    if not interpret and \
-            _vmem_estimate(R, Lp, max_frames, words) > _VMEM_LIMIT:
-        raise ValueError(
-            'pallas_wire_full_scan shape (R=%d, L=%d, max_frames=%d, '
-            'max_data=%d) needs ~%d MiB scoped VMEM (> %d MiB); '
-            'shrink block_rows/L/max_data or use the jnp full decode'
-            % (R, L, max_frames, max_data,
-               _vmem_estimate(R, Lp, max_frames, words) >> 20,
-               _VMEM_LIMIT >> 20))
+    if not interpret:
+        _check_vmem('pallas_wire_full_scan', R, Bp, Lp, max_frames,
+                    *_full_scan_sizes(max_data))
 
     buf = jnp.zeros((Bp, Lp), jnp.uint8).at[:B, :L].set(buf)
     lens = jnp.zeros((Bp, 1), jnp.int32).at[:B, 0].set(

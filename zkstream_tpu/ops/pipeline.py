@@ -15,11 +15,13 @@ TPU v5e) and ``wire_pipeline_step_pallas`` (the scan + header parse
 fused into one Mosaic kernel, ops/pallas_scan.py — a single
 custom-call, worth it when per-op dispatch overhead dominates); both
 share :func:`_assemble` so the routing/stats semantics cannot diverge.
-bench.py times both and reports the best.
+:func:`auto_impl` is the one place that chooses between them; a
+function named ``*_pallas`` runs the kernel or raises.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax.numpy as jnp
@@ -99,14 +101,11 @@ def wire_pipeline_step_pallas(buf, lens, max_frames: int = 32,
     parse fused into one Pallas kernel (ops/pallas_scan.py); only the
     cheap [B, F] -> [B] routing reductions remain as XLA ops.
 
-    Shapes whose kernel would exceed the per-program scoped-VMEM limit
-    fall back to the (unbounded, usually faster) jnp pipeline instead
-    of failing to compile."""
-    from .pallas_scan import fits_vmem, pallas_wire_scan
+    A shape whose kernel would exceed the device's scoped-VMEM ceiling
+    raises (``pallas_wire_scan``'s guard): callers that want the jnp
+    pipeline there ask :func:`auto_impl`."""
+    from .pallas_scan import pallas_wire_scan
 
-    if not interpret and not fits_vmem(buf.shape[0], buf.shape[1],
-                                       max_frames, block_rows):
-        return wire_pipeline_step(buf, lens, max_frames=max_frames)
     r = pallas_wire_scan(buf, lens, max_frames=max_frames,
                          block_rows=block_rows, interpret=interpret)
     return _stats_from_scan(r)
@@ -127,10 +126,9 @@ class GetDataBodies(NamedTuple):
 def getdata_bodies_jnp(buf, st: WireStats,
                        max_data: int) -> GetDataBodies:
     """The GET_DATA planes via the jnp body parser — the reference
-    semantics the fused kernel must match, packaged as GetDataBodies.
-    Used as the VMEM-overflow fallback of
-    :func:`wire_full_decode_pallas` and as the equal-work jnp
-    candidate in tools/sweep_pallas.py."""
+    semantics the fused kernel must match, packaged as GetDataBodies:
+    the equal-work jnp candidate of tools/sweep_pallas.py and the
+    reference chip_smoke.py holds the kernel to."""
     from . import replies as R
 
     frame_ok = (st.starts >= 0) & (st.sizes >= 16)
@@ -153,18 +151,12 @@ def wire_full_decode_pallas(buf, lens, max_frames: int = 32,
     cheap elementwise unpack XLA fuses for free.  Returns
     ``(WireStats, GetDataBodies)`` — the Pallas counterpart of
     ``wire_pipeline_step`` + ``parse_reply_bodies``'s GET_DATA planes
-    (property-tested equivalent in tests/test_pallas.py).  Shapes
-    whose kernel would exceed the scoped-VMEM limit fall back to the
-    jnp path, like :func:`wire_pipeline_step_pallas`."""
+    (property-tested equivalent in tests/test_pallas.py).  A shape
+    whose kernel would exceed the scoped-VMEM ceiling raises, like
+    :func:`wire_pipeline_step_pallas`."""
     from ..protocol.consts import MAX_PACKET
-    from .pallas_scan import fits_vmem_full, pallas_wire_full_scan
+    from .pallas_scan import pallas_wire_full_scan
     from .replies import _STAT_FIELDS, StatPlanes
-
-    if not interpret and not fits_vmem_full(
-            buf.shape[0], buf.shape[1], max_frames, block_rows,
-            max_data):
-        st = wire_pipeline_step(buf, lens, max_frames=max_frames)
-        return st, getdata_bodies_jnp(buf, st, max_data)
 
     r = pallas_wire_full_scan(buf, lens, max_frames=max_frames,
                               block_rows=block_rows, max_data=max_data,
@@ -222,13 +214,13 @@ def wire_pipeline_step(buf, lens, max_frames: int = 32) -> WireStats:
 
 
 def _pallas_pocket(B: int, max_frames: int) -> bool:
-    """The shape region where the fused kernel measurably beats the
-    jnp pipeline on TPU v5e (PROFILE.md 'Pallas crossover study',
-    tools/sweep_pallas.py): frame-dense midsize fleets — at
-    (8192, 64) the kernel holds 1.20-1.24x across repeated interleaved
-    runs with block_rows=64.  Everywhere else the two are within the
-    ±10 % run-noise band or jnp wins (worst pallas cell: 0.78x at
-    (32768, 8)), so jnp is the default.
+    """The shape region where the fused kernel was last measured ahead
+    of the jnp pipeline on a v5e (tools/sweep_pallas.py, block_rows=64,
+    on round-3 code and another installation: 1.20-1.24x at
+    (8192, 64), within a ±10 % noise band or behind everywhere else,
+    worst 0.78x at (32768, 8)) — so jnp is the default.  The table has
+    not been re-measured on the installed compiler; ROADMAP S9 does
+    that and keeps or deletes it.
 
     Caveat: under ``shard_map`` (parallel/fleet.py) ``B`` here is the
     per-shard LOCAL batch (global B / dp), while the pocket was
@@ -240,29 +232,40 @@ def _pallas_pocket(B: int, max_frames: int) -> bool:
     return max_frames >= 32 and 4096 <= B <= 16384
 
 
-def _target_platform() -> str:
-    """The platform the caller's computation will actually lower to:
-    honors an active ``jax.default_device`` override (the fleet
-    ingest pins ticks to the host CPU backend this way) before falling
-    back to the default backend."""
-    import jax
+#: rows per kernel program the auto-dispatch runs the kernel at (the
+#: pocket's measured configuration)
+_AUTO_BLOCK_ROWS = 64
 
-    dev = jax.config.jax_default_device
-    if dev is not None:
-        # jax.default_device accepts a Device or a platform string
-        return dev if isinstance(dev, str) else dev.platform
-    return jax.default_backend()
+#: header-scan implementations by the name :func:`auto_impl` returns
+WIRE_STEP_IMPLS = {
+    'jnp': wire_pipeline_step,
+    'pallas': functools.partial(wire_pipeline_step_pallas,
+                                block_rows=_AUTO_BLOCK_ROWS),
+}
+
+
+def auto_impl(B: int, L: int, max_frames: int) -> str:
+    """Name the *measured* winner for this shape on the device the
+    computation is being traced for (utils/platform.target_device):
+    ``'pallas'`` inside the kernel's recorded win pocket on TPU where
+    the kernel also fits the device's scoped VMEM, ``'jnp'`` everywhere
+    else — and on every non-TPU platform, where Mosaic cannot lower.
+    Trace-time (shapes are static under jit); both are property-tested
+    equivalent.  Returning the name, not the result, is what lets a
+    caller record which implementation its program was built from."""
+    from ..utils.platform import target_device
+
+    dev = target_device()
+    if dev.platform == 'tpu' and _pallas_pocket(B, max_frames):
+        from .pallas_scan import fits_vmem
+
+        if fits_vmem(B, L, max_frames, _AUTO_BLOCK_ROWS,
+                     device_kind=dev.device_kind):
+            return 'pallas'
+    return 'jnp'
 
 
 def wire_pipeline_step_auto(buf, lens, max_frames: int = 32) -> WireStats:
-    """Dispatch to the *measured* winner for this shape: the Pallas
-    kernel (block_rows=64) inside its recorded win pocket on TPU, the
-    jnp pipeline everywhere else — and on every non-TPU platform,
-    where Mosaic cannot lower.  The decision is trace-time (shapes are
-    static under jit); both paths are property-tested equivalent."""
-    if (_target_platform() == 'tpu'
-            and _pallas_pocket(buf.shape[0], max_frames)):
-        return wire_pipeline_step_pallas(buf, lens,
-                                         max_frames=max_frames,
-                                         block_rows=64)
-    return wire_pipeline_step(buf, lens, max_frames=max_frames)
+    """:func:`auto_impl`'s choice for this shape, run."""
+    impl = auto_impl(buf.shape[0], buf.shape[1], max_frames)
+    return WIRE_STEP_IMPLS[impl](buf, lens, max_frames=max_frames)

@@ -65,7 +65,9 @@ class MeshFleetIngest(FleetIngest):
 
     # the mesh decides placement; the latency probe is meaningless here
     def _resolve_placement(self) -> None:
-        self._placed = True
+        dev = self.mesh.devices.flat[0]
+        self.placed = {'platform': dev.platform,
+                       'device_kind': dev.device_kind, 'rtt_ms': None}
 
     def bind_metrics(self, collector, prefix: str = '') -> None:
         super().bind_metrics(collector, prefix)

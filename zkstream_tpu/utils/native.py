@@ -6,11 +6,15 @@ binds it via ctypes.  Design constraints, in order:
 - **Never block the event loop.**  ``get_lib()`` only dlopens an
   already-built artifact; when a build is needed it is kicked off on a
   daemon thread and ``get_lib()`` returns None until it lands, so the
-  connection path silently runs pure-Python in the meantime.
+  connection path runs pure-Python in the meantime.  Anything that
+  measures (bench.py, chip_smoke.py) calls the blocking ``ensure_*`` /
+  ``build_loadgen`` instead and fails on None.
 - **Stale artifacts can't poison the process.**  The artifact name
-  embeds the ABI version (``libzkwire.v1.so``); an old build is simply
-  a different filename that is never dlopened, sidestepping glibc's
-  same-path handle caching.
+  embeds the ABI version and a hash of the source and compile flags
+  (``libzkwire.v1.<hash>.so``); a build of any other source is simply
+  a different filename that is never dlopened — file times say nothing
+  in a checkout that was copied — sidestepping glibc's same-path
+  handle caching as well.
 - **Graceful degradation.**  No compiler, failed build, failed load →
   None, and callers keep the pure-Python implementations — mirroring
   how the reference runs on nothing but the OS TCP stack (SURVEY.md §2:
@@ -23,6 +27,7 @@ implementations with it).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -47,34 +52,54 @@ def source_path() -> str:
     return os.path.join(_root(), 'native', 'zkwire.cpp')
 
 
-def lib_path() -> str:
-    return os.path.join(_root(), 'native',
-                        'libzkwire.v%d.so' % _ABI_VERSION)
+_LIB_CC = ['g++', '-O2', '-shared', '-fPIC', '-std=c++17']
 
 
-def build() -> str | None:
-    """Compile the library if missing or stale; return its path or
-    None.  Synchronous — call from tests/tools, not the event loop
-    (:func:`get_lib` wraps it in a background thread)."""
-    src, out = source_path(), lib_path()
-    if not os.path.exists(src):
-        return None
-    if (os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
+def _digest(src: str, cc: list[str]) -> str:
+    """What an artifact was built from: the compile command and the
+    source bytes.  It goes into the artifact's name, so the binary that
+    loads is by construction the one these files produce."""
+    h = hashlib.sha256(' '.join(cc).encode())
+    with open(src, 'rb') as f:
+        h.update(f.read())
+    return h.hexdigest()[:12]
+
+
+def _compile(what: str, cc: list[str], src: str, out: str) -> str | None:
+    """Build ``out`` from ``src`` unless it is already there (its name
+    says what it was built from); returns the path, or None when the
+    compiler is missing or the compile fails."""
+    if os.path.exists(out):
         return out
     tmp = out + '.tmp.%d' % os.getpid()
-    cmd = ['g++', '-O2', '-shared', '-fPIC', '-std=c++17', src, '-o', tmp]
     try:
-        r = subprocess.run(cmd, capture_output=True, text=True,
-                           timeout=120)
+        r = subprocess.run(cc + [src, '-o', tmp], capture_output=True,
+                           text=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired) as e:
-        log.info('native build unavailable: %s', e)
+        log.info('%s build unavailable: %s', what, e)
         return None
     if r.returncode != 0:
-        log.warning('native build failed: %s', r.stderr.strip())
+        log.warning('%s build failed: %s', what, r.stderr.strip())
         return None
     os.replace(tmp, out)  # atomic: concurrent builders can't mix halves
     return out
+
+
+def lib_path() -> str:
+    return os.path.join(
+        _root(), 'native', 'libzkwire.v%d.%s.so'
+        % (_ABI_VERSION, _digest(source_path(), _LIB_CC)))
+
+
+def build() -> str | None:
+    """Compile the library unless the artifact for this source is
+    already there; return its path or None.  Synchronous — call from
+    tests/tools, not the event loop (:func:`get_lib` wraps it in a
+    background thread)."""
+    src = source_path()
+    if not os.path.exists(src):
+        return None
+    return _compile('native', _LIB_CC, src, lib_path())
 
 
 def _bind(path: str) -> ctypes.CDLL | None:
@@ -94,12 +119,14 @@ def _bind(path: str) -> ctypes.CDLL | None:
 
 
 def _try_load() -> None:
-    """Bind the on-disk artifact if present and current (fast: one
-    stat + dlopen).  Sets _lib/_load_failed; caller holds _lock."""
+    """Bind the on-disk artifact built from the current source, if
+    there is one (one source hash + stat + dlopen).  Sets
+    _lib/_load_failed; caller holds _lock."""
     global _lib, _load_failed
-    out, src = lib_path(), source_path()
-    if not (os.path.exists(out) and os.path.exists(src)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
+    if not os.path.exists(source_path()):
+        return
+    out = lib_path()
+    if not os.path.exists(out):
         return
     try:
         _lib = _bind(out)
@@ -170,36 +197,28 @@ def ext_source_path() -> str:
     return os.path.join(_root(), 'native', 'zkwire_ext.c')
 
 
+def _ext_cc() -> list[str]:
+    import sysconfig
+    return ['gcc', '-O2', '-shared', '-fPIC',
+            '-I', sysconfig.get_paths()['include']]
+
+
 def ext_path() -> str:
     import sysconfig
     tag = sysconfig.get_config_var('SOABI') or 'abi3'
-    return os.path.join(_root(), 'native', '_zkwire_ext.v%d.%s.so'
-                        % (_EXT_ABI_VERSION, tag))
+    return os.path.join(
+        _root(), 'native', '_zkwire_ext.v%d.%s.%s.so'
+        % (_EXT_ABI_VERSION, tag,
+           _digest(ext_source_path(), _ext_cc())))
 
 
 def build_ext() -> str | None:
-    """Compile the extension if missing or stale; return path or None."""
-    import sysconfig
-    src, out = ext_source_path(), ext_path()
+    """Compile the extension unless the artifact for this source is
+    already there; return path or None."""
+    src = ext_source_path()
     if not os.path.exists(src):
         return None
-    if (os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
-        return out
-    tmp = out + '.tmp.%d' % os.getpid()
-    cmd = ['gcc', '-O2', '-shared', '-fPIC',
-           '-I', sysconfig.get_paths()['include'], src, '-o', tmp]
-    try:
-        r = subprocess.run(cmd, capture_output=True, text=True,
-                           timeout=120)
-    except (OSError, subprocess.TimeoutExpired) as e:
-        log.info('native ext build unavailable: %s', e)
-        return None
-    if r.returncode != 0:
-        log.warning('native ext build failed: %s', r.stderr.strip())
-        return None
-    os.replace(tmp, out)
-    return out
+    return _compile('native ext', _ext_cc(), src, ext_path())
 
 
 #: opcode -> reply-body-layout enum shared with zkwire_ext.c (keep in
@@ -282,9 +301,10 @@ def _bind_ext(path: str):
 
 def _try_load_ext() -> None:
     global _ext, _ext_load_failed
-    out, src = ext_path(), ext_source_path()
-    if not (os.path.exists(out) and os.path.exists(src)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
+    if not os.path.exists(ext_source_path()):
+        return
+    out = ext_path()
+    if not os.path.exists(out):
         return
     try:
         _ext = _bind_ext(out)
@@ -339,9 +359,9 @@ def ensure_ext():
 # protocol over raw sockets (the measuring instrument the bench
 # families spawn instead of the Python read workers — README "Load
 # generation").  Same discipline as the other two artifacts:
-# version-named output, atomic tmp+rename publish, graceful None when
-# the host has no compiler so `make check`/tier-1 never hard-fail on
-# a codec-less image.
+# version- and source-hash-named output, atomic tmp+rename publish,
+# graceful None when the host has no compiler so `make check`/tier-1
+# never hard-fail on a codec-less image.
 
 _LOADGEN_VERSION = 1
 
@@ -350,34 +370,24 @@ def loadgen_source_path() -> str:
     return os.path.join(_root(), 'tools', 'loadgen.c')
 
 
+_LOADGEN_CC = ['gcc', '-O2', '-pthread']
+
+
 def loadgen_path() -> str:
-    return os.path.join(_root(), 'native',
-                        'zkloadgen.v%d' % _LOADGEN_VERSION)
+    return os.path.join(
+        _root(), 'native', 'zkloadgen.v%d.%s'
+        % (_LOADGEN_VERSION,
+           _digest(loadgen_source_path(), _LOADGEN_CC)))
 
 
 def build_loadgen() -> str | None:
-    """Compile the load generator if missing or stale; return its
-    path or None.  Synchronous (tools/bench only, never the event
-    loop)."""
-    src, out = loadgen_source_path(), loadgen_path()
+    """Compile the load generator unless the binary for this source is
+    already there; return its path or None.  Synchronous (tools/bench
+    only, never the event loop)."""
+    src = loadgen_source_path()
     if not os.path.exists(src):
         return None
-    if (os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
-        return out
-    tmp = out + '.tmp.%d' % os.getpid()
-    cmd = ['gcc', '-O2', '-pthread', src, '-o', tmp]
-    try:
-        r = subprocess.run(cmd, capture_output=True, text=True,
-                           timeout=120)
-    except (OSError, subprocess.TimeoutExpired) as e:
-        log.info('loadgen build unavailable: %s', e)
-        return None
-    if r.returncode != 0:
-        log.warning('loadgen build failed: %s', r.stderr.strip())
-        return None
-    os.replace(tmp, out)
-    return out
+    return _compile('loadgen', _LOADGEN_CC, src, loadgen_path())
 
 
 class NativeFrameScanner:
