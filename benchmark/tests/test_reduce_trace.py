@@ -1,0 +1,109 @@
+"""The trace reduction: interval arithmetic on a synthetic trace, the
+bytes function against a hand count, the loader on a trace the CPU
+backend writes, and the whole reduction on a small trace recorded on
+the chip with the numbers it must give."""
+
+import json
+import os
+
+import pytest
+import reduce_trace as rt
+from conftest import BENCH
+
+DATA = os.path.join(BENCH, 'tests', 'data')
+
+
+def test_union_and_gap_attribution():
+    assert rt.union([(5, 7), (0, 2), (1, 3), (7, 8)]) == [(0, 3), (5, 8)]
+    trace = {'planes': [
+        {'name': '/device:TPU:0', 'lines': [
+            {'name': 'XLA Modules', 'events': [
+                ['jit_step(1)', 100.0, 50.0], ['jit_step(2)', 400.0, 100.0],
+                ['jit_other(3)', 900.0, 100.0]]},
+            {'name': 'XLA Ops', 'events': [
+                ['fusion.1', 100.0, 20.0], ['copy.2', 110.0, 40.0],
+                ['fusion.1', 400.0, 100.0], ['fusion.9', 900.0, 100.0]]}]},
+        {'name': '/host:CPU', 'lines': [{'name': 'main', 'events': [
+            ['ingest_tick', 90.0, 100.0],        # covers 150..190 of gap 1
+            ['await_replies', 150.0, 200.0],     # 150..350, tick wins 150..190
+            ['await_replies', 600.0, 250.0]]}]},  # 600..850 of gap 2
+    ]}
+    red = rt.reduce(trace, window_ns=2000.0,
+                    host_spans=('ingest_tick', 'await_replies'))
+    assert red['chips'] == 1
+    assert red['busy_s'] == pytest.approx((50 + 100 + 100) / 1e9)
+    assert red['window_s'] == pytest.approx(2000 / 1e9)
+    assert red['programs']['jit_step'] == {
+        'seconds': pytest.approx(150 / 1e9), 'count': 2}
+    assert red['ops'][0] == ['fusion.1', pytest.approx(120 / 1e9)]
+    gaps = dict(red['idle_gaps'])
+    # gap 1 = 150..400, gap 2 = 500..900
+    assert gaps['ingest_tick'] == pytest.approx(40 / 1e9)
+    assert gaps['await_replies'] == pytest.approx((160 + 250) / 1e9)
+    assert gaps['unattributed'] == pytest.approx((50 + 150) / 1e9)
+
+
+def test_no_device_event_is_zero_busy():
+    red = rt.reduce({'planes': [{'name': '/host:CPU', 'lines': []}]},
+                    window_ns=1e9)
+    assert red['busy_s'] == 0.0 and red['chips'] == 0
+
+
+def test_tick_bytes_against_a_hand_count():
+    # bucket [1024, 4096], 8 frames a stream: the u8 batch 4,194,304 B
+    # and 1,024 int32 lengths 4,096 B are read; 1,024 rows of
+    # 3 + 6*8 = 51 int32 = 208,896 B are written
+    assert rt.tick_bytes(1024, 4096, 8) == 4_194_304 + 4_096 + 208_896
+    assert rt.tick_bytes(8, 4096, 8) == 32_768 + 32 + 1_632
+
+
+def test_loader_reads_what_the_profiler_writes(tmp_path):
+    os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+    import jax
+    import numpy as np
+
+    f = jax.jit(lambda x: x.astype('int32').sum(axis=1))
+    x = np.zeros((8, 128), np.uint8)
+    np.asarray(f(x))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    with jax.profiler.TraceAnnotation('ingest_tick'):
+        np.asarray(f(x))
+    jax.profiler.stop_trace()
+    path = rt.find_xplane(str(tmp_path))
+    assert path and path.endswith('.xplane.pb')
+    trace = rt.load_xplane(path, keep_host=('ingest_tick',))
+    host = [p for p in trace['planes'] if p['name'] == rt.HOST_PLANE]
+    names = {e[0] for p in host for ln in p['lines'] for e in ln['events']}
+    assert names == {'ingest_tick'}
+    # a CPU trace has no TPU plane: nothing ran on a device
+    assert rt.reduce(trace, host_spans=('ingest_tick',))['busy_s'] == 0.0
+    assert 'PLANE /host:CPU' in rt.summarize(trace)
+
+
+@pytest.mark.skipif(not os.path.isfile(os.path.join(DATA, 'v5e_read.json')),
+                    reason='no recorded trace')
+def test_recorded_v5e_trace_reduces_to_the_recorded_numbers():
+    trace = rt.load_json(os.path.join(DATA, 'v5e_read.json'))
+    with open(os.path.join(DATA, 'v5e_read.expected.json')) as f:
+        want = json.load(f)
+    red = rt.reduce(trace, window_ns=want['window_ns'],
+                    host_spans=('ingest_tick', 'await_replies', 'validate'))
+    assert red['chips'] == want['chips']
+    assert red['busy_s'] == pytest.approx(want['busy_s'], rel=1e-9)
+    assert red['programs']['jit_step']['count'] == want['jit_step_count']
+    assert red['programs']['jit_step']['seconds'] == pytest.approx(
+        want['jit_step_seconds'], rel=1e-9)
+    assert red['ops'][0][0] == want['top_op']
+    assert dict(red['idle_gaps']) == pytest.approx(want['idle_gaps'],
+                                                   rel=1e-9)
+    assert 0 < red['busy_s'] < red['window_s']
+    # by hand: the five tick programs of this window ran 458,390 +
+    # 1,981,906 + 459,359 + 1,982,578 + 459,777 ns; the ops inside them
+    # leave a few hundred ns between them uncovered
+    by_hand = 458_390 + 1_981_906 + 459_359 + 1_982_578 + 459_777
+    assert sum(want['module_ns_by_hand']) == by_hand
+    assert red['programs']['jit_step']['seconds'] == pytest.approx(
+        by_hand / 1e9)
+    assert 0.999 * by_hand <= red['busy_s'] * 1e9 <= by_hand
