@@ -658,9 +658,9 @@ def test_chaos_ensemble_cli_rerun_and_trace(tmp_path):
     assert all(d['tier'] == 'ensemble' for d in dumps)
     assert all('member_events' in d and 'history' in d
                for d in dumps)
-    # schema-2 payload: stamped, member rings per member, merged
+    # schema-stamped payload: stamped, member rings per member, merged
     # zxid-ordered timeline
-    assert all(d['trace_schema'] == 2 for d in dumps)
+    assert all(d['trace_schema'] == 3 for d in dumps)
     # 3 voters, plus any plan-drawn observers (the read plane): every
     # member's ring is carried, observers included
     assert all(len(d['member_rings']) >= 3 for d in dumps)

@@ -347,7 +347,7 @@ async def test_cli_timeline_demo_text_and_json(capsys):
     out, _err = capsys.readouterr()
     assert rc == 0
     dump = json.loads(out)
-    assert dump['trace_schema'] == 2
+    assert dump['trace_schema'] == 3
     assert set(dump['rings']) >= {'client', 'member:0', 'member:1'}
     assert any(e['op'] == 'GROUP_FSYNC' for e in dump['timeline'])
 

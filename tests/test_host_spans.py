@@ -1,0 +1,419 @@
+"""Time by phase from inside the program (ISSUE 24): host spans armed
+by the profiler session (utils/trace.host_span), the fleet ingest's
+tick phases as spans and as an always-on histogram, the members'
+tick-ledger phases and latency histograms as cumulative ``mntr`` rows
+whose before/after difference is a window's exact histogram."""
+
+from __future__ import annotations
+
+import asyncio
+import sys
+
+import pytest
+
+from helpers import mntr_rows, wait_until
+from zkstream_tpu import Client
+from zkstream_tpu.io.ingest import FleetIngest
+from zkstream_tpu.utils import trace
+from zkstream_tpu.utils.metrics import TICK_BUCKETS, Histogram, TickLedger
+
+PHASES = ['ingest.batch', 'ingest.dispatch', 'ingest.readback',
+          'ingest.route']
+
+
+@pytest.fixture(autouse=True)
+def clean_host_ring():
+    trace.host_ring.reset()
+    trace._recording = False
+    yield
+    trace.host_ring.reset()
+    trace._recording = False
+
+
+@pytest.fixture
+def armed(monkeypatch):
+    """The profiler's switch patched on: spans are recorded as inside
+    a session (the annotation itself is then an un-armed no-op)."""
+    monkeypatch.setattr(trace, '_is_enabled', lambda: True)
+
+
+def _ingest() -> FleetIngest:
+    return FleetIngest(body_mode='host', max_frames=8, min_len=256,
+                       bypass_bytes=0, warm='block')
+
+
+async def _fleet_client(server, ingest) -> Client:
+    await ingest.prewarm(1)
+    c = Client(address='127.0.0.1', port=server.port, ingest=ingest,
+               session_timeout=5000)
+    c.start()
+    await c.wait_connected(timeout=5)
+    return c
+
+
+def _check_device_ticks(spans) -> list:
+    """Every device tick in ``spans``: ``ingest.tick`` with its four
+    phases in order, all numbered alike, inside it, summing to no more
+    than it.  Returns the tick spans."""
+    ticks = [s for s in spans
+             if s.op == 'ingest.tick' and s.tick is not None]
+    assert len({t.tick for t in ticks}) == len(ticks)
+    for t in ticks:
+        assert t.kind == 'host' and t.parent is None
+        assert t.detail.startswith('device ') and t.nbytes > 0
+        kids = [s for s in spans
+                if s.parent == 'ingest.tick' and s.tick == t.tick]
+        assert [k.op for k in kids] == PHASES
+        for k in kids:
+            assert t.t0_ns <= k.t0_ns <= k.t1_ns <= t.t1_ns
+            assert k.duration_ms == pytest.approx(
+                (k.t1_ns - k.t0_ns) / 1e6)
+        assert [k.t0_ns for k in kids] == sorted(k.t0_ns for k in kids)
+        assert sum(k.t1_ns - k.t0_ns for k in kids) <= t.t1_ns - t.t0_ns
+    return ticks
+
+
+def test_unarmed_host_span_is_the_shared_noop():
+    """No profiler session: one shared object, nothing recorded,
+    nothing allocated."""
+    assert not trace.host_ring and not trace.host_ring.totals
+    for accumulate in (False, True):
+        assert trace.host_span('x', accumulate) is trace.NO_SPAN
+    with trace.host_span('ingest.tick', tick=3) as sp:
+        assert sp is trace.NO_SPAN
+        sp.set(detail='d', batch=1)
+        sp.cancel()
+
+    def many():
+        for _ in range(10_000):
+            with trace.host_span('client.rx', accumulate=True):
+                pass
+            with trace.host_span('ingest.batch', tick=7):
+                pass
+    many()                                  # warm every cache
+    before = sys.getallocatedblocks()
+    many()
+    assert sys.getallocatedblocks() - before < 1000      # not 20,000
+    assert len(trace.host_ring) == 0 and not trace.host_ring.totals
+    assert trace.host_ring.dropped == 0
+
+
+def test_armed_spans_nest_settle_and_accumulate(armed):
+    with trace.host_span('ingest.tick', tick=5) as sp:
+        with trace.host_span('ingest.batch', tick=5):
+            pass
+        with trace.host_span('client.rx', accumulate=True):
+            pass
+        sp.set(detail='device 8x256 streams=1', batch=2, nbytes=40)
+    with trace.host_span('client.rx', accumulate=True):
+        pass
+    with trace.host_span('ingest.tick', tick=6) as sp:
+        sp.cancel()                         # routed nothing
+    batch, tick = trace.host_ring.spans()
+    assert (batch.op, batch.parent, batch.tick) == (
+        'ingest.batch', 'ingest.tick', 5)
+    assert (tick.op, tick.parent, tick.tick, tick.batch, tick.nbytes) == (
+        'ingest.tick', None, 5, 2, 40)
+    assert tick.status == 'ok' and tick.kind == 'host'
+    assert tick.t0_ns <= batch.t0_ns <= batch.t1_ns <= tick.t1_ns
+    count, total_ns = trace.host_ring.totals['client.rx']
+    assert count == 2 and total_ns > 0
+    # schema 3: the host fields follow the schema-2 keys, in one order
+    keys = list(tick.to_dict())
+    assert keys[-4:] == ['tick', 't0_ns', 't1_ns', 'duration_ms']
+    assert keys.index('detail') < keys.index('tick')
+    assert list(batch.to_dict())[-5:] == [
+        'parent', 'tick', 't0_ns', 't1_ns', 'duration_ms']
+
+
+def test_a_new_session_resets_the_ring(monkeypatch):
+    """The ring holds exactly one profiler session."""
+    on = [True]
+    monkeypatch.setattr(trace, '_is_enabled', lambda: on[0])
+    for _ in range(3):
+        with trace.host_span('a'):
+            pass
+        with trace.host_span('b', accumulate=True):
+            pass
+    assert len(trace.host_ring) == 3
+    on[0] = False
+    assert trace.host_span('a') is trace.NO_SPAN
+    assert len(trace.host_ring) == 3        # kept for the reader
+    on[0] = True
+    with trace.host_span('a'):
+        pass
+    assert len(trace.host_ring) == 1
+    assert trace.host_ring.totals == {}
+
+
+def test_host_ring_counts_what_it_drops(armed, monkeypatch):
+    small = trace.TraceRing(4)
+    monkeypatch.setattr(trace, 'host_ring', small)
+    for _ in range(6):
+        with trace.host_span('a'):
+            pass
+    assert len(small) == 4 and small.dropped == 2
+    small.reset()
+    assert len(small) == 0 and small.dropped == 0
+
+
+async def test_device_tick_leaves_tick_and_four_phases(server, armed):
+    ingest = _ingest()
+    c = await _fleet_client(server, ingest)
+    try:
+        await c.create('/h', b'v' * 100)
+        trace.host_ring.reset()
+        t0, ops0 = ingest.ticks, 6
+        for _ in range(ops0):
+            data, _stat = await c.get('/h')
+            assert data == b'v' * 100
+        spans = trace.host_ring.spans()
+        ticks = _check_device_ticks(spans)
+        assert [t.tick for t in ticks] == list(
+            range(t0 + 1, ingest.ticks + 1))
+        assert sum(t.batch for t in ticks) >= ops0      # frames routed
+        # the per-op boundaries: counted, no object each
+        assert trace.host_ring.totals['client.submit'][0] == ops0
+        assert trace.host_ring.totals['client.rx'][0] >= len(ticks)
+        assert {s.op for s in spans} == {'ingest.tick', *PHASES}
+        assert trace.host_ring.dropped == 0
+    finally:
+        await c.close()
+    # always on, armed or not: one observation per phase per device tick
+    for phase in ('batch', 'dispatch', 'readback', 'route'):
+        assert ingest.phase_hist.count({'phase': phase}) == ingest.ticks
+    assert ingest.phase_hist.buckets == TICK_BUCKETS
+
+
+async def test_phase_histogram_needs_no_session_and_binds(server):
+    from zkstream_tpu import Collector
+
+    col = Collector()
+    ingest = _ingest()
+    ingest.bind_metrics(col)
+    c = await _fleet_client(server, ingest)
+    try:
+        await c.create('/p', b'x')
+        await c.get('/p')
+    finally:
+        await c.close()
+    assert ingest.ticks > 0 and len(trace.host_ring) == 0
+    hist = col.get_collector('zkstream_ingest_phase_ms')
+    assert hist is ingest.phase_hist
+    text = col.expose()
+    for phase in ('batch', 'dispatch', 'readback', 'route'):
+        assert hist.count({'phase': phase}) == ingest.ticks
+        assert ('zkstream_ingest_phase_ms_count{phase="%s"} %d'
+                % (phase, ingest.ticks)) in text
+    assert ingest.tick_hist.count() >= ingest.ticks
+
+
+async def test_a_tick_off_the_device_says_which(server, armed):
+    """A pass-through (direct) tick is one ``ingest.tick`` span with no
+    tick number and no phases."""
+    ingest = FleetIngest(body_mode='host', max_frames=8, min_len=256,
+                         warm='block')       # bypass_bytes at default
+    c = Client(address='127.0.0.1', port=server.port, ingest=ingest,
+               session_timeout=5000)
+    c.start()
+    try:
+        await c.wait_connected(timeout=5)
+        await c.create('/d', b'x')
+        await c.get('/d')
+        await asyncio.sleep(0)               # the bookkeeping tick
+    finally:
+        await c.close()
+    spans = trace.host_ring.spans()
+    assert ingest.ticks == 0 and ingest.ticks_scalar > 0
+    assert spans and {s.op for s in spans} == {'ingest.tick'}
+    assert all(s.tick is None and s.detail == 'direct' for s in spans)
+    assert not ingest.phase_hist.count({'phase': 'batch'})
+
+
+async def test_spans_lie_in_the_profilers_trace(server, tmp_path):
+    """Under a real profiler session (CPU backend): the same device
+    tick is in the host ring AND, as ``TraceAnnotation`` events with
+    the tick's number, on one thread's line of ``/host:CPU`` in the
+    ``.xplane.pb`` — nested, phases inside the tick."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    ingest = _ingest()
+    c = await _fleet_client(server, ingest)
+    try:
+        await c.create('/t', b'v' * 64)
+        assert trace.host_span('x') is trace.NO_SPAN
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for _ in range(4):
+                await c.get('/t')
+        finally:
+            jax.profiler.stop_trace()
+        assert trace.host_span('x') is trace.NO_SPAN
+    finally:
+        await c.close()
+    ticks = _check_device_ticks(trace.host_ring.spans())
+    assert ticks and trace.host_ring.totals['client.submit'][0] == 4
+    (path,) = glob.glob(str(tmp_path / '**' / '*.xplane.pb'),
+                        recursive=True)
+    names = ['ingest.tick', *PHASES, 'client.rx', 'client.submit']
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != '/host:CPU':
+            continue
+        for line in plane.lines:
+            found = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                      dict(e.stats).get('tick'))
+                     for e in line.events if e.name in names]
+            if found:
+                lines.append(found)
+    assert len(lines) == 1                   # the loop's thread
+    events = lines[0]
+    assert {n for n, *_ in events} == set(names)
+    for t in ticks:
+        (outer,) = [e for e in events
+                    if e[0] == 'ingest.tick' and e[3] == t.tick]
+        inner = [e for e in events if e[0] in PHASES and e[3] == t.tick]
+        assert [e[0] for e in sorted(inner, key=lambda e: e[1])] == PHASES
+        assert all(outer[1] <= e[1] <= e[2] <= outer[2] for e in inner)
+
+
+# -- the members: ledger phases, always-on histograms, mntr rows ---------
+
+def test_tick_ledger_subtracts_a_nested_forward_rpc(monkeypatch):
+    """A follower parked in the forwarded RPC is ``forward_rpc`` time,
+    not ``decode_apply`` time."""
+    import types
+
+    from zkstream_tpu.utils import metrics
+
+    assert 'forward_rpc' in TickLedger.PHASES
+    assert TICK_BUCKETS[-3:] == (100.0, 250.0, 1000.0)
+    # the ledger's clock, scripted: decode_apply opens at 0 ms, the
+    # RPC parks the loop from 1 to 5 ms, decode_apply closes at 5.25
+    clock = iter([0.0, 0.001, 0.005, 0.00525])
+    monkeypatch.setattr(metrics, 'time', types.SimpleNamespace(
+        perf_counter=lambda: next(clock)))
+    led = TickLedger()
+    led.enter('decode_apply')
+    led.enter('forward_rpc')
+    led.exit()
+    led.exit()
+    led.close_tick()
+    phases = led.last_tick['phases']
+    assert phases['forward_rpc'] == pytest.approx(4.0)
+    assert phases['decode_apply'] == pytest.approx(1.25)
+    assert led.last_tick['total_ms'] == pytest.approx(5.25)
+    rows = dict(led.phase_hist.rows())
+    assert rows['zk_tick_phase_ms_count{phase="forward_rpc"}'] == 1
+    assert rows['zk_tick_phase_ms_sum{phase="forward_rpc"}'] == \
+        phases['forward_rpc']
+    assert rows['zk_tick_phase_ms_bucket{phase="forward_rpc",le="2.5"}'] == 0
+    assert rows['zk_tick_phase_ms_bucket{phase="forward_rpc",le="+Inf"}'] == 1
+
+
+def test_histogram_rows_are_what_expose_prints():
+    h = Histogram('zk_x_ms', 'help', buckets=(1.0, 10.0))
+    for v, labels in ((0.5, None), (5.0, None), (50.0, None),
+                      (2.0, {'phase': 'a'})):
+        h.observe(v, labels)
+    rows = h.rows()
+    assert rows[:5] == [('zk_x_ms_bucket{le="1"}', 1),
+                        ('zk_x_ms_bucket{le="10"}', 2),
+                        ('zk_x_ms_bucket{le="+Inf"}', 3),
+                        ('zk_x_ms_sum', 55.5), ('zk_x_ms_count', 3)]
+    assert ('zk_x_ms_bucket{phase="a",le="10"}', 1) in rows
+    assert h.expose().splitlines()[2:] == ['%s %s' % kv for kv in rows]
+
+
+def _window(before: dict, after: dict, name: str, labels: str = '') -> list:
+    """``name``'s rows over a window: after minus before (a series the
+    member had not yet opened reads 0 before)."""
+    lead = labels[:-1] + ',' if labels else '{'
+    keys = [k for k in after
+            if k in (name + '_sum' + labels, name + '_count' + labels)
+            or k.startswith(name + '_bucket' + lead)]
+    return [(k, float(after[k]) - float(before.get(k, 0))) for k in keys]
+
+
+async def test_mntr_histograms_with_no_collector_and_window_delta():
+    """A quorum-enabled member built with NO collector exports, for
+    every ledger phase, the busy tick, the quorum ack and the fan-out
+    flush, cumulative ``_bucket`` / ``_sum`` / ``_count`` rows; after
+    minus before is exactly the histogram of the window's own
+    observations (so a percentile over the window is
+    ``Histogram.percentile`` on them)."""
+    from zkstream_tpu.server import ZKEnsemble
+    from zkstream_tpu.server.replication import QUORUM_ACK_BUCKETS
+
+    ens = await ZKEnsemble(3).start()
+    gate = ens.quorum
+    assert gate.enabled and ens.servers[0].collector is None
+    port = ens.addresses()[0][1]
+    c = Client(address='127.0.0.1', port=port, session_timeout=5000)
+    w = Client(address='127.0.0.1', port=port, session_timeout=5000)
+    c.start()
+    w.start()
+    try:
+        await c.wait_connected(timeout=5)
+        await w.wait_connected(timeout=5)
+        await c.create('/q', b'0')
+        seen = []
+        # another session's watch: its notifications leave through the
+        # fan-out shards (the mutator's own ride its reply)
+        w.watcher('/q').on('dataChanged', lambda d, s: seen.append(d))
+        await wait_until(lambda: seen)
+        for i in range(5):
+            await c.set('/q', b'a%d' % i)   # before the window
+        await wait_until(lambda: len(seen) >= 2)
+        before = await mntr_rows(port)
+
+        window = Histogram('zk_quorum_ack_ms', buckets=QUORUM_ACK_BUCKETS)
+        observe = gate.ack_hist.observe
+
+        def both(value, labels=None):
+            observe(value, labels)
+            window.observe(value, labels)
+        gate.ack_hist.observe = both
+        try:
+            for i in range(20):
+                await c.set('/q', b'b%d' % i)
+        finally:
+            del gate.ack_hist.observe
+        after = await mntr_rows(port)
+    finally:
+        await c.close()
+        await w.close()
+        await ens.stop()
+
+    assert window.count() == 20
+    got = _window(before, after, 'zk_quorum_ack_ms')
+    want = window.rows()
+    assert [k for k, _ in got] == [k for k, _ in want]
+    assert [v for _, v in got] == pytest.approx([v for _, v in want])
+    assert len(got) == len(QUORUM_ACK_BUCKETS) + 3
+    assert float(before['zk_quorum_ack_ms_count']) >= 5
+    # the same shape for the ledger's phases, the tick and the fan-out
+    for name, labels in (
+            ('zk_tick_phase_ms', '{phase="decode_apply"}'),
+            ('zk_tick_phase_ms', '{phase="cork_flush"}'),
+            ('zk_tick_ms', ''),
+            ('zk_fanout_tick_ms', '{plane="fanout"}')):
+        rows = dict(_window(before, after, name, labels))
+        count = rows[name + '_count' + labels]
+        assert count > 0 and rows[name + '_sum' + labels] > 0, name
+        inf = name + '_bucket' + (labels[:-1] + ',le="+Inf"}'
+                                  if labels else '{le="+Inf"}')
+        assert rows[inf] == count
+        cum = [v for k, v in rows.items() if '_bucket' in k]
+        assert cum == sorted(cum)
+    # the since-start row the older readers name is still there
+    assert 'zk_tick_phase_ms_p99{phase="decode_apply"}' in after
+    # and the flight recorder's frames keep the counters only
+    assert not [k for k, _ in ens.servers[0].monitor_stats(histograms=False)
+                if '_bucket' in k]

@@ -16,6 +16,8 @@ import sys
 
 import pytest
 
+from helpers import mntr_rows
+
 WORKER = os.path.join(os.path.dirname(__file__),
                       'process_member_worker.py')
 
@@ -384,6 +386,45 @@ async def test_rolling_sigkill_chaos_soak(process_ensemble):
             m.proc.stdout.close()
 
 
+async def test_follower_counts_the_time_parked_in_forwarded_rpcs(
+        process_ensemble):
+    """A follower forwards each write through a blocking control-
+    channel RPC on its loop's thread: its tick ledger books that as
+    ``forward_rpc`` (nested under ``decode_apply``, so no longer part
+    of it), and its ``mntr`` exports the phase cumulatively — the
+    window's parked time is after minus before.  The leader, which
+    forwards nothing, has no such series."""
+    leader, (f1, _f2) = process_ensemble
+    c = _client([('127.0.0.1', f1.ports[0])])
+    try:
+        await c.wait_connected(timeout=10)
+        await c.create('/fw', b'0')
+        before = await mntr_rows(f1.ports[0])
+        for i in range(25):
+            await c.set('/fw', b'v%d' % i)
+        after = await mntr_rows(f1.ports[0])
+        lead = await mntr_rows(leader.ports[0])
+    finally:
+        await c.close()
+    count = 'zk_tick_phase_ms_count{phase="forward_rpc"}'
+    total = 'zk_tick_phase_ms_sum{phase="forward_rpc"}'
+    inf = 'zk_tick_phase_ms_bucket{phase="forward_rpc",le="+Inf"}'
+    # the ledger books per busy tick, and a tick may hold several
+    # writes: at least one tick, at most one per write (plus pings)
+    ticks = int(after[count]) - int(before[count])
+    assert 1 <= ticks <= 30
+    assert int(after[inf]) - int(before[inf]) == ticks
+    parked_ms = float(after[total]) - float(before[total])
+    assert parked_ms > 0
+    # the parked time is no longer inside decode_apply: the follower's
+    # own decode + dispatch of 25 small writes is far under the round
+    # trips it waited for
+    own = 'zk_tick_phase_ms_sum{phase="decode_apply"}'
+    assert float(after[own]) - float(before[own]) < parked_ms * 5
+    assert float(after[own]) > 0
+    assert count not in lead and 'zk_tick_count' in lead
+
+
 async def _scrape_trce(port: int) -> dict:
     import json
 
@@ -431,7 +472,7 @@ async def test_trce_scrape_merges_cross_process_timeline(
         rings = {'client': c.trace.dump()}
         for port in (leader.ports[0], f1.ports[0], f2.ports[0]):
             dump = await _scrape_trce(port)
-            assert dump['trace_schema'] == 2
+            assert dump['trace_schema'] == 3
             rings['member:%s' % (dump['member'],)] = dump['spans']
         merged = merge_timelines(rings)
         sel = [(e['source'], e['op']) for e in merged

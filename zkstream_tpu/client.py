@@ -50,7 +50,7 @@ from .utils.aio import ambient_loop
 from .utils.fsm import FSM, bind_transition_metrics
 from .utils.logging import Logger
 from .utils.metrics import Collector
-from .utils.trace import TraceRing
+from .utils.trace import TraceRing, host_span
 
 METRIC_ZK_EVENT_COUNTER = 'zookeeper_events'
 METRIC_ZK_DEGRADED_GAUGE = 'zookeeper_degraded'
@@ -546,15 +546,20 @@ class Client(FSM):
         connection died between the liveness check and the send) must
         not leave its span open — the ring would report a phantom
         in-flight op forever; it settles as ``abandoned`` and the
-        error propagates."""
-        span = self.trace.start(pkt['opcode'], pkt.get('path'))
-        try:
-            req = conn.request(pkt)
-        except BaseException as e:
-            span.finish(status='abandoned',
-                        error=getattr(e, 'code', None)
-                        or type(e).__name__)
-            raise
+        error propagates.
+
+        Host span ``client.submit`` (profiler sessions only; count
+        and total, no object per op): from here until the encoded
+        request is with the connection's send plane."""
+        with host_span('client.submit', accumulate=True):
+            span = self.trace.start(pkt['opcode'], pkt.get('path'))
+            try:
+                req = conn.request(pkt)
+            except BaseException as e:
+                span.finish(status='abandoned',
+                            error=getattr(e, 'code', None)
+                            or type(e).__name__)
+                raise
         span.xid = pkt['xid']
         span.backend = conn.backend.key
         if conn.session is not None:

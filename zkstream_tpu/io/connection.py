@@ -29,6 +29,7 @@ from ..utils.aio import set_nodelay
 from ..utils.events import EventEmitter
 from ..utils.fsm import FSM
 from ..utils.logging import Logger
+from ..utils.trace import host_span
 from .sendplane import SendPlane
 
 METRIC_ZK_CONNECT_LATENCY = 'zookeeper_connect_latency_ms'
@@ -544,11 +545,19 @@ class ZKConnection(FSM):
 
     def _sock_data(self, data: bytes) -> None:
         """Socket bytes -> 'sockData', via the fault schedule when an
-        injector is installed (splits/delays/dups/mid-frame resets)."""
-        if self.faults is None:
-            self.emit('sockData', data)
-        else:
-            self.faults.rx(self, data)
+        injector is installed (splits/delays/dups/mid-frame resets).
+
+        Host span ``client.rx`` (profiler sessions only; count and
+        total, no object per call): the bytes' way from here through
+        the state's ``sockData`` handler — on a fleet connection in
+        the ingest's batch regime that ends with them in its slot
+        (``FleetIngest.feed``); in the pass-through regime, and with
+        no ingest, the decode and delivery are inside it too."""
+        with host_span('client.rx', accumulate=True):
+            if self.faults is None:
+                self.emit('sockData', data)
+            else:
+                self.faults.rx(self, data)
 
     def _tx_write(self, data: bytes) -> None:
         """The send plane's sink: one coalesced buffer per flush."""

@@ -83,7 +83,8 @@ from ..protocol.records import (
     _RESP_READERS,
 )
 from ..utils.logging import Logger
-from ..utils.metrics import Histogram
+from ..utils.metrics import TICK_BUCKETS, Histogram
+from ..utils.trace import NO_SPAN, host_span
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from .connection import ZKConnection  # noqa: quoted annotations
@@ -98,6 +99,15 @@ def _next_pow2(n: int) -> int:
 #: sentinel distinguishing "never compiled" from "compile failed" in
 #: the executable cache
 _MISSING = object()
+
+METRIC_INGEST_PHASE = 'zkstream_ingest_phase_ms'
+_PHASE_HELP = ('Device tick time by phase, milliseconds (batch: find the '
+               'slots that hold bytes, build [Bp, L] | dispatch: the '
+               'executable call, H2D + enqueue | readback: device wait + '
+               'D2H | route: unpack, assemble, deliver)')
+#: the label sets of ``zkstream_ingest_phase_ms``, built once
+_PHASE_LABELS = tuple({'phase': p} for p in
+                      ('batch', 'dispatch', 'readback', 'route'))
 
 
 def _executable_platform(ex) -> str | None:
@@ -257,6 +267,13 @@ class FleetIngest:
         self.tick_hist = Histogram(
             'zkstream_ingest_tick_ms',
             'Ingest tick (batched drain) duration, milliseconds')
+        #: Where a DEVICE tick's wall time went, one observation per
+        #: phase per device tick (so each series counts ``ticks``):
+        #: the operator's always-on view of the boundaries the host
+        #: spans ``ingest.batch|dispatch|readback|route`` mark in a
+        #: profiler session.  Swapped by bind_metrics like tick_hist.
+        self.phase_hist = Histogram(METRIC_INGEST_PHASE, _PHASE_HELP,
+                                    buckets=TICK_BUCKETS)
         #: ticks routed to the scalar drain by the fragmentation guard
         self.ticks_frag = 0
         self.frames_routed = 0
@@ -463,6 +480,7 @@ class FleetIngest:
         pack the results into (ints, byts-or-None).  Pure array code —
         jitted directly here, re-wrapped in ``shard_map`` by the
         mesh-aware subclass (parallel/fleet.py)."""
+        import jax
         import jax.numpy as jnp
 
         from ..ops.pipeline import WIRE_STEP_IMPLS, auto_impl
@@ -482,6 +500,10 @@ class FleetIngest:
         st = WIRE_STEP_IMPLS[impl](buf, lens,
                                    max_frames=self.max_frames)
 
+        # the stages carry named scopes (frame_scan / header_gather
+        # inside the step, body_parse and pack here): metadata only,
+        # so a kept profiler trace names the program's ops by stage
+        @jax.named_scope('pack')
         def pack_ints(extra=()):
             head = jnp.stack(
                 [st.n_frames, st.resid,
@@ -494,35 +516,37 @@ class FleetIngest:
 
         if not device_bodies:
             return st, pack_ints(), None
-        bd = parse_reply_bodies(
-            buf, st.starts, st.sizes,
-            max_data=self.max_data, max_path=self.max_path)
-        lb = parse_list_bodies(
-            buf, st.starts, st.sizes,
-            max_children=self.max_children, max_name=self.max_name,
-            max_acls=self.max_acls, max_scheme=self.max_scheme,
-            max_id=self.max_id)
+        with jax.named_scope('body_parse'):
+            bd = parse_reply_bodies(
+                buf, st.starts, st.sizes,
+                max_data=self.max_data, max_path=self.max_path)
+            lb = parse_list_bodies(
+                buf, st.starts, st.sizes,
+                max_children=self.max_children, max_name=self.max_name,
+                max_acls=self.max_acls, max_scheme=self.max_scheme,
+                max_id=self.max_id)
 
         def src(name):
             v = getattr(bd, name, None)
             return v if v is not None else getattr(lb, name)
 
-        extra = []
-        for ent in self._body_schema():
-            if ent[0] == 'plane':
-                extra.append(src(ent[1]).astype(jnp.int32))
-            elif ent[0] == 'multi':
-                t = src(ent[1]).astype(jnp.int32)
-                extra += [t[:, :, k] for k in range(ent[2])]
-            else:
-                sp = src(ent[1])
-                extra += [getattr(sp, f).astype(jnp.int32)
-                          for f in StatPlanes._fields]
-        B = buf.shape[0]
-        byts = jnp.concatenate(
-            [src(name).reshape(B, self.max_frames, -1)
-             for name, _w in self._bytes_schema()], axis=2)
-        return st, pack_ints(extra), byts
+        with jax.named_scope('pack'):
+            extra = []
+            for ent in self._body_schema():
+                if ent[0] == 'plane':
+                    extra.append(src(ent[1]).astype(jnp.int32))
+                elif ent[0] == 'multi':
+                    t = src(ent[1]).astype(jnp.int32)
+                    extra += [t[:, :, k] for k in range(ent[2])]
+                else:
+                    sp = src(ent[1])
+                    extra += [getattr(sp, f).astype(jnp.int32)
+                              for f in StatPlanes._fields]
+            B = buf.shape[0]
+            byts = jnp.concatenate(
+                [src(name).reshape(B, self.max_frames, -1)
+                 for name, _w in self._bytes_schema()], axis=2)
+            return st, pack_ints(extra), byts
 
     def _step_fn(self, device_bodies: bool):
         """Build (and cache) the jittable one-dispatch decode for this
@@ -732,6 +756,9 @@ class FleetIngest:
         self.tick_hist = collector.histogram(
             prefix + 'zkstream_ingest_tick_ms',
             'Ingest tick (batched drain) duration, milliseconds')
+        self.phase_hist = collector.histogram(
+            prefix + METRIC_INGEST_PHASE, _PHASE_HELP,
+            buckets=TICK_BUCKETS)
 
     def _placement_series(self) -> dict:
         if self.placed is None:
@@ -938,10 +965,19 @@ class FleetIngest:
 
     def _tick(self) -> None:
         t0 = time.perf_counter()
-        if self._tick_impl():
+        # host span ``ingest.tick`` (utils/trace.host_span: recorded
+        # only inside a profiler session): the whole tick, as the
+        # duration histogram times it.  ``tick`` is the number this
+        # tick takes if it runs the device program — its phases carry
+        # the same one; a tick that did not says so in ``detail``.
+        with host_span('ingest.tick', tick=self.ticks + 1) as sp:
+            routed = self._tick_impl(sp)
+            if not routed:
+                sp.cancel()
+        if routed:
             self.tick_hist.observe((time.perf_counter() - t0) * 1000.0)
 
-    def _tick_impl(self) -> bool:
+    def _tick_impl(self, sp=NO_SPAN) -> bool:
         """One drain tick; returns True when it routed work (those
         ticks feed the duration histogram — empty bookkeeping wakeups
         would only blur the distribution's low end)."""
@@ -966,24 +1002,37 @@ class FleetIngest:
                 self.ticks_frag += 1
             if not still_direct:
                 self._flip_batch()
+            sp.set(tick=None, detail='direct', nbytes=win)
             return True
         if self.faults is not None:
             self._inject_tick_faults()
-        active = [(conn, buf) for conn, buf in self._slots.values()
-                  if buf and conn.is_in_state('connected')]
-        if not active:
-            if self._release_held():
-                self._schedule()     # finish the withheld suffixes
-            return False
+        # Phase ``batch`` (host span ``ingest.batch``) opens with the
+        # scan for the slots that hold bytes — the first step of
+        # building the batch, and at fleet width not a small one —
+        # and closes when ``[Bp, L]`` is built.  A tick that is
+        # drained another way (nothing buffered, pass-through flip,
+        # bucket still compiling) leaves no batch span behind.
+        active: list = []
         before = self.frames_routed
         try:
-            self._tick_inner(active)
+            t0 = time.perf_counter()
+            with host_span('ingest.batch', tick=self.ticks + 1) as bsp:
+                active = [(conn, buf)
+                          for conn, buf in self._slots.values()
+                          if buf and conn.is_in_state('connected')]
+                plan = self._prepare_batch(active, sp) if active else None
+                if plan is None:
+                    bsp.cancel()
+            if plan is not None:
+                self._tick_inner(active, plan, sp, t0)
         finally:
-            self._note_frames(self.frames_routed - before)
-            self._frames_mark = self.frames_routed
+            if active:
+                self._note_frames(self.frames_routed - before)
+                self._frames_mark = self.frames_routed
+                sp.set(batch=self.frames_routed - before)
             if self._release_held():
-                self._schedule()
-        return True
+                self._schedule()     # finish the withheld suffixes
+        return bool(active)
 
     def _inject_tick_faults(self) -> None:
         """Apply the injector's tick-time decisions to the batch-regime
@@ -1024,13 +1073,20 @@ class FleetIngest:
             released = True
         return released
 
-    def _tick_inner(self, active) -> None:
+    def _prepare_batch(self, active, sp=NO_SPAN):
+        """Decide how this tick drains and, for a device tick, build
+        its batch: returns ``(ex, device_bodies, batch, lens)``, or
+        None when the tick was drained here another way (the
+        pass-through flip, a bucket still compiling or one that
+        failed to compile)."""
         if self._want_direct():
             self.ticks_scalar += 1
             if self._frag_scalar:
                 self.ticks_frag += 1
+            sp.set(tick=None,
+                   detail='frag' if self._frag_scalar else 'scalar')
             self._flip_direct(active)
-            return
+            return None
 
         B = len(active)
         maxlen = max(len(buf) for _c, buf in active)
@@ -1044,19 +1100,21 @@ class FleetIngest:
                 # through the scalar codec while the bucket warms
                 self._start_warm(key)
                 self.ticks_warming += 1
+                sp.set(tick=None, detail='warming')
                 for conn, buf in active:
                     if id(conn) not in self._slots:
                         continue
                     self._deliver_scalar(conn, buf)
-                return
+                return None
         if ex is None:  # compile failed: this bucket stays scalar
             self._require_compiled(key)
             self.ticks_scalar += 1
+            sp.set(tick=None, detail='scalar')
             for conn, buf in active:
                 if id(conn) not in self._slots:
                     continue
                 self._deliver_scalar(conn, buf)
-            return
+            return None
         self.ticks += 1
 
         device, Bp, L = key
@@ -1067,22 +1125,44 @@ class FleetIngest:
             # into the batch row before anything can mutate it
             batch[i, :len(buf)] = np.frombuffer(buf, np.uint8)
             lens[i] = len(buf)
+        if sp is not NO_SPAN:
+            sp.set(detail='device %dx%d streams=%d' % (Bp, L, B),
+                   nbytes=int(lens.sum()))
+        return ex, device, batch, lens
 
-        if device:
-            ints, byts = ex(batch, lens)
-            ints = np.asarray(ints)  # the only 2 readbacks per tick
-            byts = np.asarray(byts)
-        else:
-            ints = np.asarray(ex(batch, lens))
-            byts = None
-        st, bd = self._unpack(ints, byts)
-
-        retick = False
-        for i, (conn, buf) in enumerate(active):
-            if self._route_stream(conn, buf, st, bd, i):
-                retick = True
-        if retick:
-            self._schedule()
+    def _tick_inner(self, active, plan, sp, t0: float) -> None:
+        """The device tick proper, once its batch stands (``t0``: when
+        phase ``batch`` opened): dispatch, readback, route — each a
+        host span under ``ingest.tick`` carrying the tick's number
+        (profiler sessions only) and, with ``batch``, one observation
+        of ``zkstream_ingest_phase_ms{phase=}`` (always)."""
+        ex, device, batch, lens = plan
+        n = self.ticks
+        t1 = time.perf_counter()
+        with host_span('ingest.dispatch', tick=n):
+            out = ex(batch, lens)
+        t2 = time.perf_counter()
+        with host_span('ingest.readback', tick=n):
+            if device:
+                ints = np.asarray(out[0])  # the only 2 readbacks per tick
+                byts = np.asarray(out[1])
+            else:
+                ints = np.asarray(out)
+                byts = None
+        t3 = time.perf_counter()
+        with host_span('ingest.route', tick=n):
+            st, bd = self._unpack(ints, byts)
+            retick = False
+            for i, (conn, buf) in enumerate(active):
+                if self._route_stream(conn, buf, st, bd, i):
+                    retick = True
+            if retick:
+                self._schedule()
+        t4 = time.perf_counter()
+        observe = self.phase_hist.observe
+        for labels, a, b in zip(_PHASE_LABELS, (t0, t1, t2, t3),
+                                (t1, t2, t3, t4)):
+            observe((b - a) * 1000.0, labels)
 
     def _route_stream(self, conn, buf, st, bd, i: int) -> bool:
         """Deliver stream ``i``'s decoded tick results to its

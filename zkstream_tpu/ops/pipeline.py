@@ -24,6 +24,7 @@ from __future__ import annotations
 import functools
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from .frame_scan import frame_cursor_scan
@@ -207,10 +208,14 @@ def wire_pipeline_step(buf, lens, max_frames: int = 32) -> WireStats:
       lens: int32 [B] valid byte counts.
       max_frames: static per-stream frame bound for this tick.
     """
-    starts, sizes, counts, bad, resid = frame_cursor_scan(
-        buf, lens, max_frames)
-    headers = parse_reply_headers(buf, starts, sizes)
-    return _assemble(headers, starts, sizes, counts, bad, resid)
+    # named scopes are metadata only: a kept profiler trace names the
+    # program's ops by stage, the compiled code is the same
+    with jax.named_scope('frame_scan'):
+        starts, sizes, counts, bad, resid = frame_cursor_scan(
+            buf, lens, max_frames)
+    with jax.named_scope('header_gather'):
+        headers = parse_reply_headers(buf, starts, sizes)
+        return _assemble(headers, starts, sizes, counts, bad, resid)
 
 
 def _pallas_pocket(B: int, max_frames: int) -> bool:

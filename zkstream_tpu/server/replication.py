@@ -56,6 +56,7 @@ import time
 
 from ..protocol.consts import CreateFlag
 from ..utils.events import EventEmitter
+from ..utils.metrics import Collector
 from .persist import entry_zxid
 from .store import (
     ReplicaStore,
@@ -223,12 +224,11 @@ class QuorumGate:
         self._futs: list = []         # (target_zxid, Future) rpc waits
         self._timer = None
         self._commit_t: dict[int, float] = {}
-        self._hist = None
-        if collector is not None:
-            self.bind_metrics(collector)
-
-    def bind_metrics(self, collector) -> None:
-        self._hist = collector.histogram(
+        #: commit -> majority-ack latency.  Standalone without a
+        #: collector (an OS-process member has none and exports it
+        #: through ``mntr``, server/server.py), registered with one.
+        source = collector if collector is not None else Collector()
+        self.ack_hist = source.histogram(
             METRIC_QUORUM_ACK,
             'Commit to majority-ack latency, ms',
             buckets=QUORUM_ACK_BUCKETS)
@@ -360,9 +360,8 @@ class QuorumGate:
         now = time.monotonic()
         covered = [z for z in self._commit_t if z <= floor]
         for z in covered:
-            dur_ms = (now - self._commit_t.pop(z)) * 1000.0
-            if self._hist is not None:
-                self._hist.observe(dur_ms)
+            self.ack_hist.observe(
+                (now - self._commit_t.pop(z)) * 1000.0)
         if self.trace is not None:
             self.trace.note('QUORUM_ACK', zxid=floor, kind='server',
                             batch=max(1, len(covered)))
@@ -969,6 +968,10 @@ class RemoteLeader(EventEmitter):
         #: shift every later batch's slice indices
         self._mirror_lock = threading.Lock()
         self._loop: asyncio.AbstractEventLoop | None = None
+        self._loop_thread: int | None = None
+        #: Optional utils/metrics.TickLedger (the member's server
+        #: wires its own): loop time parked in :meth:`_rpc`
+        self.ledger = None
         self._seq = 0
         self._events_task: asyncio.Task | None = None
         #: kept referenced: a dropped StreamWriter closes its transport
@@ -996,6 +999,7 @@ class RemoteLeader(EventEmitter):
 
     async def connect(self) -> 'RemoteLeader':
         self._loop = asyncio.get_running_loop()
+        self._loop_thread = threading.get_ident()
         # the control-channel dial can hang on a partitioned peer —
         # it must park an executor thread, not the loop every other
         # session of this member is served from (the loop-blocking
@@ -1205,6 +1209,15 @@ class RemoteLeader(EventEmitter):
     # -- control-channel RPC --
 
     def _rpc(self, method: str, *args):
+        # tick phase ``forward_rpc``: the send and the blocking wait
+        # for the leader's answer park this member's whole loop (only
+        # a call ON the loop's thread is the loop's time — harnesses
+        # drive this channel from other threads too)
+        led = self.ledger
+        if led is not None and threading.get_ident() == self._loop_thread:
+            led.enter('forward_rpc')
+        else:
+            led = None
         try:
             with self._lock:
                 if self._sock is None:
@@ -1223,6 +1236,9 @@ class RemoteLeader(EventEmitter):
             # raw EOF that tears the serving connection down.
             self._note_leader_lost()
             raise ZKLeaderLostError(str(e)) from e
+        finally:
+            if led is not None:
+                led.exit()
         tag, rseq, status, payload, base, entries = res[:6]
         assert tag == 'res' and rseq == seq, res
         self._adopt_epoch(res[6] if len(res) > 6 else None)

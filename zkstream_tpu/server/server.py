@@ -1172,6 +1172,7 @@ class ZKServer:
                 # RemoteReplicaStore of an OS-process follower
                 # included — same attribute)
                 self.store.trace = self.trace
+                self._wire_forward_ledger()
         #: Outbound write coalescing for accepted connections
         #: (io/sendplane.py): None = process default, True/False force.
         self.cork = cork
@@ -1628,6 +1629,14 @@ class ZKServer:
         ref = self.elections_ref
         return ref.elections if ref is not None else self.elections
 
+    def _wire_forward_ledger(self) -> None:
+        """An OS-process follower's database is the control channel
+        to the leader (server/replication.py RemoteLeader): the loop
+        time it parks in a forwarded RPC is this member's
+        ``forward_rpc`` tick phase."""
+        if hasattr(self.db, 'ledger'):
+            self.db.ledger = self.ledger
+
     def repoint(self, db, store=None, role: str | None = None) -> None:
         """Leadership failover (server/election.py): swap this
         member's backing database/store while the listener keeps its
@@ -1659,6 +1668,7 @@ class ZKServer:
                     wal.ledger = self.ledger
             else:
                 self.store.trace = self.trace
+                self._wire_forward_ledger()
         if role is not None:
             self.role = role
         else:
@@ -1757,9 +1767,13 @@ class ZKServer:
         return 'applied version=%d voters=%s\n' % (
             final[1], _csv(final[4]))
 
-    def monitor_stats(self) -> list[tuple[str, object]]:
+    def monitor_stats(self, histograms: bool = True
+                      ) -> list[tuple[str, object]]:
         """The ``mntr`` key/value inventory (ordered), real-ZK key
-        names where an equivalent exists."""
+        names where an equivalent exists.  ``histograms`` False leaves
+        the cumulative histogram rows out (:meth:`_histogram_rows`:
+        some 150 of them; the flight recorder's 250 ms frames keep
+        the counters only)."""
         ephemerals = sum(len(s.ephemerals)
                          for s in self.db.sessions.values())
         data_size = sum(len(n.data)
@@ -1879,7 +1893,28 @@ class ZKServer:
                if self.overload is not None else []) \
             + multi_rows + gate_rows \
             + quorum_rows + config_rows + tick_rows + blackbox_rows \
-            + wal_rows
+            + wal_rows + (self._histogram_rows() if histograms else [])
+
+    def _histogram_rows(self) -> list[tuple[str, object]]:
+        """This member's duration histograms, cumulative since it
+        started, in Prometheus form (``_bucket{..,le=}`` / ``_sum`` /
+        ``_count``, utils/metrics ``Histogram.rows``): each tick
+        phase (``zk_tick_phase_ms{phase=}``), the busy tick
+        (``zk_tick_ms``), commit -> majority ack on a leader
+        (``zk_quorum_ack_ms``) and the fan-out shard flush
+        (``zk_fanout_tick_ms{plane="fanout"}``).  A scraper that keeps
+        the rows before and after a window has that window's exact
+        bucket counts, busy time and count by subtraction — what the
+        ``_p99`` rows above, percentiles since start, cannot give."""
+        hists = []
+        if self.ledger is not None:
+            hists += [self.ledger.phase_hist, self.ledger.tick_hist]
+        q = self.quorum
+        if q is not None and q.enabled:
+            hists.append(q.ack_hist)
+        if self.watch_table is not None:
+            hists.append(self.watch_table.tick_hist)
+        return [row for h in hists for row in h.rows()]
 
     def _ingress_census_rows(self) -> list[tuple[str, object]]:
         """Per-shard connection census (sharded ingress only): how

@@ -69,8 +69,11 @@ from ..io.sendplane import (
 )
 from ..protocol.consts import XID_NOTIFICATION
 from ..utils.aio import ambient_loop
+from ..utils.metrics import Collector
 
 METRIC_FANOUT_TICK = 'zk_fanout_tick_ms'
+#: the label set of the fan-out plane's flush histograms
+_FANOUT = {'plane': 'fanout'}
 
 #: Shard-flush duration buckets (ms): the interesting band is whether
 #: a 100k-subscriber event amortizes to sub-millisecond per shard.
@@ -157,22 +160,22 @@ class WatchTable:
         #: child subscribers) share one encode without thrashing.
         self._memo: dict[tuple, bytes] = {}
         self._memo_scheduled = False
-        self._frames_hist = None
-        self._bytes_hist = None
-        self._tick_hist = None
-        if collector is not None:
-            self._frames_hist = collector.histogram(
-                METRIC_FLUSH_FRAMES,
-                'Frames per coalesced transport write, by plane',
-                buckets=FRAME_BUCKETS)
-            self._bytes_hist = collector.histogram(
-                METRIC_FLUSH_BYTES,
-                'Bytes per coalesced transport write, by plane',
-                buckets=BYTE_BUCKETS)
-            self._tick_hist = collector.histogram(
-                METRIC_FANOUT_TICK,
-                'Per-shard fan-out flush duration (ms)',
-                buckets=TICK_BUCKETS)
+        # standalone without a collector (an OS-process member has
+        # none and exports the flush duration through ``mntr``,
+        # server/server.py), registered with one
+        source = collector if collector is not None else Collector()
+        self._frames_hist = source.histogram(
+            METRIC_FLUSH_FRAMES,
+            'Frames per coalesced transport write, by plane',
+            buckets=FRAME_BUCKETS)
+        self._bytes_hist = source.histogram(
+            METRIC_FLUSH_BYTES,
+            'Bytes per coalesced transport write, by plane',
+            buckets=BYTE_BUCKETS)
+        self.tick_hist = source.histogram(
+            METRIC_FANOUT_TICK,
+            'Per-shard fan-out flush duration (ms)',
+            buckets=TICK_BUCKETS)
         self._store = server.store
         self._bind_store(self._store)
 
@@ -620,9 +623,8 @@ class WatchTable:
         finally:
             if ledger is not None:
                 ledger.exit()
-        if frames and self._frames_hist is not None:
-            labels = {'plane': 'fanout'}
-            self._frames_hist.observe(frames, labels)
-            self._bytes_hist.observe(nbytes, labels)
-            self._tick_hist.observe(
-                (time.perf_counter() - t0) * 1000.0, labels)
+        if frames:
+            self._frames_hist.observe(frames, _FANOUT)
+            self._bytes_hist.observe(nbytes, _FANOUT)
+            self.tick_hist.observe(
+                (time.perf_counter() - t0) * 1000.0, _FANOUT)

@@ -383,7 +383,7 @@ class BlackBoxRecorder:
         if srv is None:
             return body
         try:
-            body['mntr'] = {k: v for k, v in srv.monitor_stats()}
+            body['mntr'] = dict(srv.monitor_stats(histograms=False))
         except Exception as e:        # a half-torn-down server must
             body['mntr_error'] = repr(e)   # not lose the frame
         ledger = getattr(srv, 'ledger', None)

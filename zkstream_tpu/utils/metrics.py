@@ -150,6 +150,8 @@ class Histogram:
         self.buckets = bounds
         #: label key -> [per-bucket counts..., +Inf count, sum]
         self._series: dict[tuple[tuple[str, str], ...], list] = {}
+        #: label key -> the series' rendered row keys (:meth:`rows`)
+        self._row_names: dict[tuple[tuple[str, str], ...], list] = {}
 
     def _row(self, labels: dict[str, str] | None) -> list:
         key = _label_key(labels)
@@ -224,26 +226,38 @@ class Histogram:
     def _fmt_bound(bound: float) -> str:
         return '%g' % (bound,)
 
+    def rows(self) -> list[tuple[str, object]]:
+        """Every series as cumulative ``(key, value)`` rows in
+        Prometheus form — ``<name>_bucket{...,le="..."}`` per bound
+        and ``+Inf``, then ``<name>_sum{...}`` and ``<name>_count{...}``
+        — what :meth:`expose` prints and what a member's ``mntr``
+        carries (server/server.py), so that after-minus-before over
+        any window gives that window's exact bucket counts, its sum
+        and its count.  The keys of a series are rendered once."""
+        out: list[tuple[str, object]] = []
+        for key, row in sorted(self._series.items()):
+            names = self._row_names.get(key)
+            if names is None:
+                les = [self._fmt_bound(b) for b in self.buckets] + ['+Inf']
+                names = self._row_names[key] = [
+                    self.name + '_bucket' + _render_labels(
+                        key, (('le', le),)) for le in les] + [
+                    self.name + '_sum' + _render_labels(key),
+                    self.name + '_count' + _render_labels(key)]
+            cum = 0
+            for i in range(len(self.buckets) + 1):
+                cum += row[i]
+                out.append((names[i], cum))
+            out.append((names[-2], row[-1]))
+            out.append((names[-1], cum))
+        return out
+
     def expose(self) -> str:
         lines = []
         if self.help:
             lines.append('# HELP %s %s' % (self.name, self.help))
         lines.append('# TYPE %s histogram' % (self.name,))
-        for key, row in sorted(self._series.items()):
-            cum = 0
-            for i, bound in enumerate(self.buckets):
-                cum += row[i]
-                lines.append('%s_bucket%s %d' % (
-                    self.name,
-                    _render_labels(key, (('le', self._fmt_bound(bound)),)),
-                    cum))
-            cum += row[len(self.buckets)]
-            lines.append('%s_bucket%s %d' % (
-                self.name, _render_labels(key, (('le', '+Inf'),)), cum))
-            lines.append('%s_sum%s %s' % (self.name,
-                                          _render_labels(key), row[-1]))
-            lines.append('%s_count%s %d' % (self.name,
-                                            _render_labels(key), cum))
+        lines += ['%s %s' % kv for kv in self.rows()]
         return '\n'.join(lines)
 
 
@@ -252,9 +266,11 @@ METRIC_TICK_PHASE = 'zk_tick_phase_ms'
 
 #: Tick/phase duration buckets, ms: a busy tick on this stack spans
 #: tens of microseconds (one pipelined reply) up to tens of
-#: milliseconds (a wide fan-out flush or a slow-device fsync).
+#: milliseconds (a wide fan-out flush or a slow-device fsync); under a
+#: write herd ``decode_apply`` and a parked ``forward_rpc`` run to
+#: hundreds (PERF.md section 6), so the edges go on to 1 s.
 TICK_BUCKETS = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
-                10.0, 25.0, 50.0)
+                10.0, 25.0, 50.0, 100.0, 250.0, 1000.0)
 
 
 class TickLedger:
@@ -281,7 +297,13 @@ class TickLedger:
       synchronous barrier on close paths);
     - ``cork_flush`` — send-plane buffer join + transport write;
     - ``fanout_flush`` — the watch table's per-shard flush loop
-      (minus the nested cork writes it triggers).
+      (minus the nested cork writes it triggers);
+    - ``forward_rpc`` — a follower parked in the blocking control-
+      channel RPC that forwards a write (or a session open/close) to
+      the leader (server/replication.py ``RemoteLeader._rpc``): the
+      whole loop stands still for it.  Nested under ``decode_apply``
+      like the rest, so a follower's ``decode_apply`` is its own
+      decode and dispatch, not the leader's round trip.
 
     A "tick" here is the whole burst: asyncio runs ``call_soon``
     callbacks scheduled during a callback in the *next* loop
@@ -297,7 +319,7 @@ class TickLedger:
     """
 
     PHASES = ('rx_drain', 'decode_apply', 'fsync_gate', 'cork_flush',
-              'fanout_flush')
+              'fanout_flush', 'forward_rpc')
 
     #: Close a still-active burst after this many loop iterations
     #: anyway: under saturating back-to-back load every iteration has
@@ -325,7 +347,7 @@ class TickLedger:
         self.phase_hist = source.histogram(
             METRIC_TICK_PHASE,
             'Busy-tick time by phase, ms (rx_drain | decode_apply | '
-            'fsync_gate | cork_flush | fanout_flush)',
+            'fsync_gate | cork_flush | fanout_flush | forward_rpc)',
             buckets=TICK_BUCKETS)
         self.tick_hist = source.histogram(
             METRIC_TICK, 'Busy-tick wall span, ms',

@@ -35,6 +35,17 @@ lost or duplicated write instead of a log-grepping session.
 (``chaos --trace-out``, the ``trce`` admin word, ``timeline --json``);
 :meth:`Span.to_dict` emits its keys in one fixed order so dumps are
 byte-stable for a given span.
+
+**Host spans** (:func:`host_span`) are the third kind: where a process
+spends its own loop time (the fleet ingest's tick and its phases, the
+client's receive and submit paths).  They are armed by the JAX
+profiler session and by nothing else — no option, no environment
+variable: while ``jax.profiler.start_trace`` is active a host span is
+a ``jax.profiler.TraceAnnotation`` (an event on the calling thread's
+line of ``/host:CPU`` in the ``.xplane.pb``, on the clock of the
+device's ``XLA Ops`` line) AND a settled :class:`Span` in the
+process-wide :data:`host_ring`; with no session it is one
+``is_enabled()`` call and a shared no-op.
 """
 
 from __future__ import annotations
@@ -42,19 +53,25 @@ from __future__ import annotations
 import collections
 import itertools
 import json
+import sys
+import threading
 import time
 
 #: Version stamp for every JSON emission of span dumps.  Bump when
 #: span fields or their meaning change; consumers key on it.
 #: Schema 2: member rings (``member``/``batch``/``nbytes``/``detail``
 #: fields, server-side ops), stable-ordered ``Span.to_dict``.
-TRACE_SCHEMA = 2
+#: Schema 3: host spans (``parent``/``tick``/``t0_ns``/``t1_ns``
+#: fields, emitted after the schema-2 keys; spans that do not carry
+#: them serialize exactly as under schema 2).
+TRACE_SCHEMA = 3
 
 #: ``to_dict`` emission order (after the four always-present keys):
 #: fixed so a span serializes byte-identically regardless of which
 #: setattr path populated it.
 _OPTIONAL_FIELDS = ('path', 'xid', 'zxid', 'backend', 'session_id',
-                    'member', 'batch', 'nbytes', 'detail', 'error')
+                    'member', 'batch', 'nbytes', 'detail', 'error',
+                    'parent', 'tick', 't0_ns', 't1_ns')
 
 
 def server_trace_default() -> bool:
@@ -73,7 +90,8 @@ class Span:
     __slots__ = ('span_id', 'kind', 'op', 'path', 'xid', 'zxid',
                  'backend', 'session_id', 'status', 'error',
                  't_wall', '_t0', 'duration_ms',
-                 'member', 'batch', 'nbytes', 'detail', '_on_slow')
+                 'member', 'batch', 'nbytes', 'detail', '_on_slow',
+                 'parent', 'tick', 't0_ns', 't1_ns')
 
     def __init__(self, span_id: int, op: str, path: str | None = None,
                  kind: str = 'op'):
@@ -94,6 +112,13 @@ class Span:
         self.nbytes: int | None = None
         #: Free-form qualifier (log-entry op, follower token).
         self.detail: str | None = None
+        #: Host spans only (:func:`host_span`): the enclosing host
+        #: span's name, the identifier everything under one ingest
+        #: tick shares, and start/end on ``time.perf_counter_ns``.
+        self.parent: str | None = None
+        self.tick: int | None = None
+        self.t0_ns: int | None = None
+        self.t1_ns: int | None = None
         self.status: str = 'open'
         self.error: str | None = None
         self.t_wall = time.time()
@@ -159,6 +184,10 @@ class TraceRing:
         #: (utils/blackbox.py persists the span's causal chain).
         self.slow_ms: float | None = None
         self.on_slow = None
+        #: name -> [count, total_ns]: boundaries crossed once per op,
+        #: where a Span object each would be the cost being measured
+        #: (:func:`host_span` with ``accumulate=True``)
+        self.totals: dict[str, list] = {}
         self._ring: collections.deque[Span] = collections.deque(
             maxlen=capacity)
         self._ids = itertools.count(1)
@@ -212,6 +241,10 @@ class TraceRing:
         span.batch = None
         span.nbytes = None
         span.detail = None
+        span.parent = None
+        span.tick = None
+        span.t0_ns = None
+        span.t1_ns = None
         span.status = 'ok'
         span.error = None
         span.t_wall = time.time()
@@ -246,6 +279,13 @@ class TraceRing:
 
     def clear(self) -> None:
         self._ring.clear()
+
+    def reset(self) -> None:
+        """Empty the ring AND its books (``dropped``, ``totals``): the
+        start of a new recording window."""
+        self._ring.clear()
+        self.totals.clear()
+        self.dropped = 0
 
 
 def format_spans(spans: list[dict], limit: int | None = None) -> str:
@@ -357,3 +397,144 @@ def format_timeline(entries: list[dict],
                          e.get('status', ''), e.get('path') or '-',
                          ' '.join(extra))).rstrip())
     return '\n'.join(lines)
+
+
+# ---------------------------------------------------------------------
+# Host spans: where this process's own loop time goes, on the
+# profiler's clock.
+# ---------------------------------------------------------------------
+
+#: Sized for one 4 s profiler window of the busiest recorder: the
+#: ingest leaves 5 spans a device tick (``ingest.tick`` and its four
+#: phases) and ticks at most ~200 times a second (a 2 ms tick floor on
+#: the chip: ~800 ticks, 4,000 spans in 4 s); 16,384 is four times
+#: that.  A longer session wraps, and ``host_ring.dropped`` says so.
+HOST_RING_CAPACITY = 16384
+
+#: The process-wide ring of host spans.  It holds exactly one profiler
+#: session: the first host span of a new session resets it.
+host_ring = TraceRing(HOST_RING_CAPACITY)
+
+
+class _NoSpan:
+    """What :func:`host_span` returns with no profiler session: one
+    shared object, nothing recorded, nothing allocated."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set(self, **fields) -> None:
+        pass
+
+    def cancel(self) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+#: ``jax.profiler.TraceAnnotation`` and its ``is_enabled``, bound the
+#: first time a host span is asked for AFTER something else imported
+#: jax: this module never imports it (an ensemble member never does,
+#: server/member_worker.py), and without jax no session can be active.
+_annotation = None
+_is_enabled = None
+#: a session was active at the last look (its first span reset the ring)
+_recording = False
+_open = threading.local()
+
+
+def _bind() -> bool:
+    """Bind the profiler's annotation type and its switch; False while
+    jax is not (fully) imported in this process."""
+    global _annotation, _is_enabled
+    prof = getattr(sys.modules.get('jax'), 'profiler', None)
+    ann = getattr(prof, 'TraceAnnotation', None)
+    if ann is None:
+        return False
+    _annotation = ann
+    if _is_enabled is None:
+        _is_enabled = ann.is_enabled
+    return True
+
+
+def host_span(name: str, accumulate: bool = False, **ids):
+    """Mark a span of this thread's time: ``with host_span('ingest.
+    batch', tick=n): ...``.
+
+    With no profiler session active this is one ``is_enabled()`` call
+    and returns :data:`NO_SPAN`.  Inside one, the span is (1) a
+    ``jax.profiler.TraceAnnotation(name, **ids)`` and (2) on exit a
+    settled :class:`Span` in :data:`host_ring` — ``kind='host'``,
+    ``op`` the name, ``parent`` the enclosing host span's name,
+    ``tick`` from ``ids``, ``t0_ns``/``t1_ns`` from
+    ``time.perf_counter_ns`` — plus whatever :meth:`set` added
+    (``batch``, ``nbytes``, ``detail``).  ``accumulate=True`` is for a
+    boundary crossed once per op: the annotation is opened, but the
+    ring gets ``(count, total_ns)`` under the name
+    (``host_ring.totals``) and no object."""
+    global _recording
+    if (_annotation is None and not _bind()) or not _is_enabled():
+        _recording = False
+        return NO_SPAN
+    if not _recording:
+        host_ring.reset()
+        _recording = True
+    return _HostSpan(name, accumulate, ids)
+
+
+class _HostSpan:
+    __slots__ = ('name', 'ids', 'fields', '_accumulate', '_ann',
+                 '_parent', '_t0', '_cancelled')
+
+    def __init__(self, name: str, accumulate: bool, ids: dict):
+        self.name = name
+        self.ids = ids
+        self.fields: dict | None = None
+        self._accumulate = accumulate
+        self._cancelled = False
+
+    def set(self, **fields) -> None:
+        """Span fields known only once the work is under way."""
+        if self.fields is None:
+            self.fields = fields
+        else:
+            self.fields.update(fields)
+
+    def cancel(self) -> None:
+        """Leave nothing in the ring (the tick routed no work)."""
+        self._cancelled = True
+
+    def __enter__(self):
+        self._parent = getattr(_open, 'span', None)
+        _open.span = self
+        self._ann = _annotation(self.name, **self.ids)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        _open.span = self._parent
+        if self._cancelled:
+            return False
+        if self._accumulate:
+            tot = host_ring.totals.get(self.name)
+            if tot is None:
+                tot = host_ring.totals[self.name] = [0, 0]
+            tot[0] += 1
+            tot[1] += t1 - self._t0
+            return False
+        fields = self.fields or {}
+        fields.setdefault('tick', self.ids.get('tick'))
+        host_ring.note(
+            self.name, kind='host',
+            parent=None if self._parent is None else self._parent.name,
+            t0_ns=self._t0, t1_ns=t1,
+            duration_ms=(t1 - self._t0) / 1e6, **fields)
+        return False
