@@ -185,6 +185,36 @@ async def test_device_tick_leaves_tick_and_four_phases(server, armed):
     assert ingest.phase_hist.buckets == TICK_BUCKETS
 
 
+async def test_a_fleets_burst_is_one_client_flush(server, armed):
+    """The send side's engagement counter: ``client.flush`` is the
+    shared tier's tick, so ``client.submit``'s count over its count is
+    requests per flush — N for a burst from N sessions of one loop
+    (1.0 by construction while every client had a tier of its own)."""
+    from zkstream_tpu.io.transport import probe
+    if probe().chosen == 'asyncio':
+        pytest.skip('no batched transport backend: no tier, no flush')
+    clients = []
+    try:
+        for _ in range(6):
+            c = Client(address='127.0.0.1', port=server.port,
+                       session_timeout=30000, max_spares=0)
+            c.start()
+            await c.wait_connected(timeout=5)
+            clients.append(c)
+        await asyncio.sleep(0.05)
+        trace.host_ring.reset()
+        got = await asyncio.gather(*[c.list('/') for c in clients])
+        assert len(got) == 6
+        totals = trace.host_ring.totals
+        assert totals['client.submit'][0] == 6
+        assert totals['client.flush'][0] == 1
+        assert 0 < totals['client.flush'][1]
+        assert len(trace.host_ring) == 0        # count and total only
+    finally:
+        for c in clients:
+            await c.close()
+
+
 async def test_phase_histogram_needs_no_session_and_binds(server):
     from zkstream_tpu import Collector
 

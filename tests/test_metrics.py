@@ -408,3 +408,45 @@ async def test_tick_ledger_sums_to_busy_tick_on_live_server(server):
     # generous slop for a loaded CI core)
     assert phase_total >= 0.25 * tick_total, \
         (phase_total, tick_total)
+
+
+def test_adopted_series_read_as_own_rows_plus_theirs():
+    """``Collector.adopt``: a series another object owns and feeds (a
+    loop's shared transport tier) shows under its name in every
+    collector that adopted it, beside that collector's own rows, live
+    and without a copy."""
+    from zkstream_tpu.utils.metrics import Counter, Histogram
+
+    theirs_c = Counter('n_total', 'things')
+    theirs_h = Histogram('d', 'depth', buckets=(1, 4))
+    cols = [Collector(), Collector()]
+    own = cols[0].counter('n_total', 'things')
+    own.increment({'plane': 'server'}, by=2)
+    own.increment({'plane': 'client'})
+    for col in cols:
+        for _ in range(2):                      # again: no change
+            col.adopt(theirs_c)
+            col.adopt(theirs_h)
+    theirs_c.increment({'plane': 'client'}, by=5)
+    theirs_h.observe(3, {'plane': 'client'})
+    theirs_h.observe(9, {'plane': 'client'})
+    cols[1].get_collector('d').observe(1, {'plane': 'client'})
+    a, b = (col.get_collector('n_total') for col in cols)
+    assert a is own and a.value({'plane': 'server'}) == 2
+    assert a.value({'plane': 'client'}) == 6
+    assert b.value({'plane': 'client'}) == 5
+    assert sorted(a.label_keys()) == [(('plane', 'client'),),
+                                      (('plane', 'server'),)]
+    assert 'n_total{plane="client"} 6.0' in cols[0].expose()
+    ha, hb = (col.get_collector('d') for col in cols)
+    assert ha.count({'plane': 'client'}) == 2
+    assert (hb.count({'plane': 'client'}), hb.sum({'plane': 'client'})) \
+        == (3, 13.0)
+    assert hb.bucket_value(1, {'plane': 'client'}) == 1
+    assert hb.bucket_value(float('inf'), {'plane': 'client'}) == 3
+    assert ha.percentile(50, {'plane': 'client'}) == theirs_h.percentile(
+        50, {'plane': 'client'})
+    assert ('d_count{plane="client"}', 3) in hb.rows()
+    assert theirs_h.count({'plane': 'client'}) == 2     # fed by its owner
+    with pytest.raises(ValueError):
+        cols[0].adopt(Histogram('d', buckets=(1, 2)))

@@ -31,6 +31,7 @@ from zkstream_tpu.io.transport import (
     probe,
     resolve_backend,
 )
+from zkstream_tpu import Client
 from zkstream_tpu.protocol.framing import PacketCodec
 from zkstream_tpu.server import ZKServer
 from zkstream_tpu.utils.metrics import Collector
@@ -432,6 +433,401 @@ def test_mntr_reports_transport_backend():
         srv2 = ZKServer(transport=BATCHED[0])
         rows2 = dict(srv2.monitor_stats())
         assert rows2['zk_transport_backend'] == BATCHED[0]
+
+
+# -- the client plane: one tier for the clients of one loop ------------
+
+class _Tap:
+    """A TCP proxy in front of a server that keeps, per accepted
+    connection, every byte the client side sent — the wire as the
+    member saw it."""
+
+    def __init__(self, upstream_port: int):
+        self.upstream = upstream_port
+        self.streams: list[bytearray] = []
+        self._server = None
+        self._writers: list = []
+
+    async def start(self) -> '_Tap':
+        self._server = await asyncio.start_server(
+            self._accept, '127.0.0.1', 0)
+        self.port = self._server.sockets[0].getsockname()[1]
+        return self
+
+    async def _accept(self, reader, writer):
+        rec = bytearray()
+        self.streams.append(rec)
+        up_r, up_w = await asyncio.open_connection('127.0.0.1',
+                                                   self.upstream)
+        self._writers += [writer, up_w]
+
+        async def pump(src, dst, keep):
+            try:
+                while True:
+                    data = await src.read(1 << 16)
+                    if not data:
+                        break
+                    if keep is not None:
+                        keep.extend(data)
+                    dst.write(data)
+            except (ConnectionError, OSError):
+                pass
+            finally:
+                dst.close()
+        await asyncio.gather(pump(reader, up_w, rec),
+                             pump(up_r, writer, None))
+
+    async def stop(self) -> None:
+        self._server.close()
+        for w in self._writers:
+            w.close()
+
+
+def _requests_in(stream: bytes) -> tuple[list[dict], bytes, bytes]:
+    """A recorded client stream after its ConnectRequest, decoded as a
+    member decodes it: the request of every whole frame, the bytes of
+    a trailing partial frame, and the recorded bytes decoded from."""
+    head = 4 + int.from_bytes(stream[:4], 'big')
+    codec = PacketCodec(server=True, use_native=False)
+    assert len(codec.decode(bytes(stream[:head]))) == 1
+    codec.handshaking = False
+    body = bytes(stream[head:])
+    return codec.decode(body), codec.take_pending(), body
+
+
+def _assert_intact(stream: bytes, last_paths: list[str]) -> list[dict]:
+    """The stream is whole frames only, byte-exact (a client's encoder
+    gives the recorded bytes back from what the member decoded), xids
+    ascending, and its last requests are for ``last_paths``, in that
+    order."""
+    reqs, partial, body = _requests_in(stream)
+    assert partial == b''
+    enc = PacketCodec(use_native=False)
+    enc.handshaking = False
+    assert b''.join(enc.encode(dict(p)) for p in reqs) == body
+    xids = [p['xid'] for p in reqs]
+    assert xids == sorted(xids)
+    assert [p.get('path') for p in reqs[-len(last_paths):]] == last_paths
+    return reqs
+
+
+async def _fleet(port: int, n: int, backend: str, **kw) -> list[Client]:
+    """``n`` connected clients, dialed one after another so that the
+    i-th accepted connection is the i-th client's."""
+    clients = []
+    for _ in range(n):
+        c = Client(address='127.0.0.1', port=port, transport=backend,
+                   session_timeout=30000, max_spares=0, **kw)
+        c.start()
+        await c.wait_connected(timeout=10)
+        clients.append(c)
+    await asyncio.sleep(0.05)       # the handshakes' own flushes
+    return clients
+
+
+def _send(c: Client, path: str):
+    """One ``getData`` handed to the client's send plane NOW (no loop
+    hop): the future of its reply."""
+    fut, _span = c._start_op(c._conn_or_raise(), {
+        'opcode': 'GET_DATA', 'path': path, 'watch': False})
+    return fut
+
+
+def _depth(c: Client, backend: str) -> tuple[int, float]:
+    h = c.collector.get_collector(METRIC_SUBMIT_DEPTH)
+    labels = {'plane': 'client', 'backend': backend}
+    return h.count(labels), h.sum(labels)
+
+
+@pytest.mark.parametrize('backend', BATCHED)
+async def test_clients_of_one_loop_hold_one_tier(backend):
+    from zkstream_tpu.io import transport as tmod
+    srv = await ZKServer().start()
+    clients = await _fleet(srv.port, 5, backend)
+    legacy = Client(address='127.0.0.1', port=srv.port,
+                    transport='asyncio', session_timeout=30000)
+    try:
+        tier = clients[0].transport_tier
+        assert isinstance(tier, TransportTier)
+        assert tier.plane == 'client' and tier.backend == backend
+        assert all(c.transport_tier is tier for c in clients)
+        assert tier.refs == 5
+        loop = asyncio.get_running_loop()
+        assert tmod._loop_tiers[loop][backend] is tier
+        # every connection's plane sends through it
+        assert all(c._conn_or_raise()._tx._tier is tier
+                   for c in clients)
+        assert legacy.transport_tier is None
+        other = [b for b in BATCHED if b != backend]
+        if other:
+            odd = (await _fleet(srv.port, 1, other[0]))[0]
+            clients.append(odd)
+            assert odd.transport_tier is not tier
+            assert odd.transport_tier.backend == other[0]
+        # the members' side is untouched: a tier of the server's own
+        assert srv.transport_tier is not tier
+    finally:
+        for c in clients:
+            await c.close()
+        await srv.stop()
+
+
+@pytest.mark.parametrize('backend', BATCHED)
+async def test_fleet_burst_is_one_submission(backend):
+    """One request from each of N clients in one loop iteration: ONE
+    tick, ONE submission of depth N (in every client's collector: the
+    series is the tier's), every connection's bytes intact and in
+    order — on the parent N ticks and N submissions of depth 1."""
+    n = 8
+    srv = await ZKServer().start()
+    tap = await _Tap(srv.port).start()
+    clients = await _fleet(tap.port, n, backend)
+    try:
+        tier = clients[0].transport_tier
+        subs0, sys0 = tier.submissions, tier.syscalls
+        depth0 = _depth(clients[-1], backend)
+        sent0 = [len(s) for s in tap.streams]
+        futs = []
+        for i, c in enumerate(clients):
+            futs.append(_send(c, '/a%d' % i))
+        futs.append(_send(clients[2], '/second'))   # 2 frames, 1 entry
+        replies = await asyncio.gather(*futs, return_exceptions=True)
+        assert all(getattr(r, 'code', None) == 'NO_NODE'
+                   for r in replies), replies
+        assert tier.submissions == subs0 + 1
+        assert tier.syscalls == sys0 + (1 if backend == 'uring' else n)
+        for c in clients:
+            count, total = _depth(c, backend)
+            assert (count, total) == (depth0[0] + 1, depth0[1] + n)
+        ctr = clients[0].collector.get_collector(METRIC_FLUSH_SYSCALLS)
+        assert ctr.value({'plane': 'client',
+                          'backend': backend}) == tier.syscalls
+        for i, stream in enumerate(tap.streams):
+            assert len(stream) > sent0[i]
+            _assert_intact(stream, ['/a%d' % i] + ['/second'] * (i == 2))
+    finally:
+        for c in clients:
+            await c.close()
+        await tap.stop()
+        await srv.stop()
+
+
+@pytest.mark.parametrize('backend', BATCHED)
+async def test_lone_client_still_reads_depth_one(backend):
+    srv = await ZKServer().start()
+    (c,) = await _fleet(srv.port, 1, backend)
+    try:
+        await c.create('/lone', b'v')
+        for _ in range(5):
+            assert (await c.get('/lone'))[0] == b'v'
+        count, total = _depth(c, backend)
+        assert count >= 6 and total == count
+        assert c.transport_tier.refs == 1
+    finally:
+        await c.close()
+        await srv.stop()
+
+
+@pytest.mark.parametrize('backend', BATCHED)
+async def test_last_close_releases_the_tier(backend):
+    """Closing one client leaves the others sending on an open tier;
+    the ring fd and the registry entry go with the last one."""
+    from zkstream_tpu.io import transport as tmod
+    srv = await ZKServer().start()
+    a, b, c = await _fleet(srv.port, 3, backend)
+    loop = asyncio.get_running_loop()
+    try:
+        tier = a.transport_tier
+        await b.create('/k', b'v')
+        await a.close()
+        assert tier.refs == 2 and tmod._loop_tiers[loop][backend] is tier
+        if backend == 'uring':
+            assert tier._uring is not None
+        subs = tier.submissions
+        got = await asyncio.gather(b.get('/k'), c.get('/k'))
+        assert [d for d, _st in got] == [b'v', b'v']
+        assert tier.submissions > subs and b.transport_tier is tier
+        await b.close()
+        await c.close()
+        assert tier.refs == 0 and tier._uring is None
+        assert loop not in tmod._loop_tiers
+        # a later client builds the loop's tier anew
+        (d,) = await _fleet(srv.port, 1, backend)
+        try:
+            assert d.transport_tier is not tier
+            assert (await d.get('/k'))[0] == b'v'
+        finally:
+            await d.close()
+    finally:
+        for x in (a, b, c):
+            await x.close()
+        await srv.stop()
+
+
+@pytest.mark.parametrize('backend', BATCHED)
+def test_client_reused_across_two_runs_sends_on_both(backend):
+    """One ``asyncio.run`` after another with the same client: its
+    connection's plane keeps sending through the first loop's tier on
+    the second loop (the dead-loop guard), and the client's lease
+    moves to the second loop's tier, the first released."""
+    import threading
+
+    from zkstream_tpu.io import transport as tmod
+    srv_loop = asyncio.new_event_loop()
+    srv = srv_loop.run_until_complete(ZKServer().start())
+    thread = threading.Thread(target=srv_loop.run_forever, daemon=True)
+    thread.start()
+    box: dict = {}
+
+    async def first():
+        c = box['c'] = Client(address='127.0.0.1', port=srv.port,
+                              transport=backend, session_timeout=30000)
+        c.start()
+        await c.wait_connected(timeout=10)
+        await c.create('/run1', b'1')
+        box['tier'] = c.transport_tier
+        box['loop'] = asyncio.get_running_loop()
+
+    async def second():
+        c = box['c']
+        # the reply is never read (the socket's reader died with the
+        # first loop): only the send is this test's business
+        op = asyncio.ensure_future(c.create('/run2', b'2'))
+        for _ in range(200):
+            if '/run2' in box['seen']():
+                break
+            await asyncio.sleep(0.01)
+        op.cancel()
+        tier = c.transport_tier
+        assert tier is not box['tier'] and tier.refs == 1
+        assert tmod._loop_tiers[asyncio.get_running_loop()][backend] \
+            is tier
+        assert box['tier'].refs == 0
+        assert box['loop'] not in tmod._loop_tiers
+        c._tier_lease.release()
+
+    def seen():
+        fut = asyncio.run_coroutine_threadsafe(_paths(srv), srv_loop)
+        return fut.result(5)
+    box['seen'] = seen
+    try:
+        asyncio.run(first())
+        assert '/run1' in seen()
+        asyncio.run(second())
+        assert '/run2' in seen()
+    finally:
+        asyncio.run_coroutine_threadsafe(srv.stop(), srv_loop).result(10)
+        srv_loop.call_soon_threadsafe(srv_loop.stop)
+        thread.join(10)
+        assert not thread.is_alive()
+        srv_loop.close()
+
+
+async def _paths(srv) -> set:
+    return {p for p in ('/run1', '/run2') if p in srv.db.nodes}
+
+
+@pytest.mark.parametrize('backend', BATCHED)
+async def test_flush_hard_drains_only_its_own_entry(backend):
+    """Two clients with bytes parked in the shared tier: a hard flush
+    on one is a submission of depth 1 that puts ITS bytes on the wire
+    before returning, ahead of its later frames; the neighbour's stay
+    parked until the tick and arrive whole."""
+    srv = await ZKServer().start()
+    tap = await _Tap(srv.port).start()
+    a, b = await _fleet(tap.port, 2, backend)
+    try:
+        tier = a.transport_tier
+        pa, pb = a._conn_or_raise()._tx, b._conn_or_raise()._tx
+        futs = [_send(b, '/b1'), _send(b, '/b2')]
+        pb.flush_now()                  # parked in the tier's entry
+        futs.append(_send(a, '/a1'))
+        subs, depth = tier.submissions, _depth(a, backend)
+        sent_a = len(tap.streams[0])
+        pa.flush_hard()
+        assert tier.submissions == subs + 1
+        assert _depth(a, backend) == (depth[0] + 1, depth[1] + 1)
+        assert pa._entry.nbytes == 0 and pb._entry.nbytes > 0
+        futs += [_send(a, '/a2'), _send(b, '/b3')]
+        await asyncio.gather(*futs, return_exceptions=True)
+        assert len(tap.streams[0]) > sent_a
+        _assert_intact(tap.streams[0], ['/a1', '/a2'])
+        _assert_intact(tap.streams[1], ['/b1', '/b2', '/b3'])
+    finally:
+        await a.close()
+        await b.close()
+        await tap.stop()
+        await srv.stop()
+
+
+@pytest.mark.parametrize('backend', BATCHED)
+async def test_tx_fault_on_one_client_spares_its_neighbour(backend):
+    """An injected truncated frame + reset on one client
+    (``faults.tx``: before the cork, delivered by a hard flush of that
+    client's entry) while a neighbour has frames corked and parked in
+    the same tier: the neighbour's stream is byte-exact."""
+    from zkstream_tpu.io.faults import FaultConfig, FaultInjector
+    inj = FaultInjector(7, FaultConfig(p_tx_reset=1.0, max_faults=1))
+    inj.active = False
+    srv = await ZKServer().start()
+    tap = await _Tap(srv.port).start()
+    (a,) = await _fleet(tap.port, 1, backend, faults=inj)
+    (b,) = await _fleet(tap.port, 1, backend)
+    try:
+        assert a.transport_tier is b.transport_tier
+        futs = [_send(b, '/b1'), _send(b, '/b2')]
+        b._conn_or_raise()._tx.flush_now()
+        futs += [_send(b, '/b3'), _send(a, '/a1')]
+        inj.active = True
+        futs.append(_send(a, '/cut-%s' % ('x' * 64)))
+        inj.active = False
+        assert inj.fired == [('tx', 'tx mid-frame reset')]
+        futs.append(_send(b, '/b4'))
+        got = await asyncio.gather(*futs, return_exceptions=True)
+        # the neighbour: four replies, four whole frames in order
+        assert [getattr(r, 'code', None) for r in got[:3] + got[5:]] \
+            == ['NO_NODE'] * 4
+        reqs = _assert_intact(tap.streams[1], ['/b1', '/b2', '/b3', '/b4'])
+        assert len(reqs) == 4
+        # the faulted client: its earlier frame whole, then a cut one
+        reqs, partial, _body = _requests_in(tap.streams[0])
+        assert [p.get('path') for p in reqs] == ['/a1']
+        assert 0 < len(partial) < 64
+        assert isinstance(got[4], Exception)
+    finally:
+        inj.close()
+        await a.close()
+        await b.close()
+        await tap.stop()
+        await srv.stop()
+
+
+@pytest.mark.parametrize('backend', BATCHED)
+async def test_collector_shared_with_a_server_keeps_both_planes(backend):
+    """The shared tier's series are its own and a joined client's
+    collector ADOPTS them (reads its own rows plus theirs): a
+    collector a server registered the same names in first keeps the
+    server's rows."""
+    col = Collector()
+    srv = await ZKServer(transport=backend, collector=col).start()
+    clients = await _fleet(srv.port, 2, backend, collector=col)
+    try:
+        await asyncio.gather(*[c.list('/') for c in clients])
+        ctr = col.get_collector(METRIC_FLUSH_SYSCALLS)
+        assert ctr.value({'plane': 'server', 'backend': backend}) > 0
+        assert ctr.value({'plane': 'client', 'backend': backend}) \
+            == clients[0].transport_tier.syscalls
+        dep = col.get_collector(METRIC_SUBMIT_DEPTH)
+        for plane in ('server', 'client'):
+            assert dep.count({'plane': plane, 'backend': backend}) > 0
+        text = col.expose()
+        assert text.count('# TYPE %s counter' % METRIC_FLUSH_SYSCALLS) == 1
+        assert ('%s_count{backend="%s",plane="client"}'
+                % (METRIC_SUBMIT_DEPTH, backend)) in text
+    finally:
+        for c in clients:
+            await c.close()
+        await srv.stop()
 
 
 # -- chaos slices: the batched tier under seeded faults ----------------

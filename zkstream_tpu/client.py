@@ -157,15 +157,13 @@ class Client(FSM):
         self.op_timeout = op_timeout
 
         self.collector = collector if collector is not None else Collector()
-        #: Batched-syscall transport tier for this client's
-        #: connections (io/transport.py): None when the resolved
-        #: backend is 'asyncio' (the legacy per-plane writes).
-        #: ``transport=`` forces a tier ('uring'|'mmsg'|'asyncio');
-        #: None = the ZKSTREAM_TRANSPORT / capability-probe default.
-        from .io.transport import make_tier
-        self.transport_tier = make_tier(transport,
-                                        collector=self.collector,
-                                        plane='client')
+        #: This client's lease on the batched-syscall transport tier
+        #: its event loop's clients share (io/transport.py; see
+        #: :attr:`transport_tier`).  ``transport=`` forces a backend
+        #: ('uring'|'mmsg'|'asyncio'); None = the ZKSTREAM_TRANSPORT /
+        #: capability-probe default.
+        from .io.transport import TierLease
+        self._tier_lease = TierLease(transport, self.collector)
         self.collector.counter(METRIC_ZK_EVENT_COUNTER,
             'Total number of zookeeper events')
         #: Per-op latency distribution, labelled by opcode; recorded by
@@ -309,6 +307,15 @@ class Client(FSM):
     def state_closed(self, S) -> None:
         self.emit('close')
 
+    @property
+    def transport_tier(self):
+        """The transport tier this client's connections send through:
+        the ONE tier shared by every client on the running event loop
+        with the same backend, so one loop iteration's requests from a
+        whole fleet leave in one batched submission.  None when the
+        backend is 'asyncio' (the per-plane writes)."""
+        return self._tier_lease.tier()
+
     def start(self) -> None:
         """Begin connecting.  Separate from __init__ so the caller
         controls which running event loop the client binds to (the
@@ -334,11 +341,10 @@ class Client(FSM):
             self.cache.close()
         if self._read_plane is not None:
             await self._read_plane.close()
-        if self.transport_tier is not None:
-            # release the tier's ring fd with the client instead of
-            # waiting on cyclic GC (the plane/entry closures keep the
-            # tier in a cycle); a reused client lazily re-creates it
-            self.transport_tier.close()
+        # the shared tier's ring fd closes with its last client
+        # instead of waiting on cyclic GC (the plane/entry closures
+        # keep the tier in a cycle); a reused client joins again
+        self._tier_lease.release()
 
     def update_backends(self, backends) -> bool:
         """Adopt a new live member list (README "Dynamic

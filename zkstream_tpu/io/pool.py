@@ -223,6 +223,7 @@ class ReadPlane:
                      log=c.log, seed=seed,
                      connect_policy=c.pool._connect_policy,
                      default_policy=c._retry_policy,
+                     transport=c._tier_lease.backend,
                      read_distribution=False)
         sub.start()
         self.subs.append(sub)

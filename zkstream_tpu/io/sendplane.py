@@ -43,7 +43,9 @@ Beneath the plane sits the batched-syscall transport tier
 tier is attached, a flush hands its chunk list to the tier's
 per-tick submission queue instead of joining and writing — one
 io_uring submission (or one C writev batch) then covers EVERY dirty
-connection of the tick.  The plane's contracts are tier-independent:
+connection of the tick (a server's connections; on the client plane,
+the connections of every client on the event loop: they share one
+tier).  The plane's contracts are tier-independent:
 ``flush_hard`` still puts bytes on the wire before returning (the
 tier drains that entry synchronously), the durability barrier still
 gates BEFORE bytes reach any queue, and a disabled cork bypasses the

@@ -55,11 +55,26 @@ holds all tiers to byte-identical per-connection streams):
   (SendPlane.flush_now), so no ack byte reaches a submission queue
   before its txn is on disk — backend-independent.
 
+Who shares a tier.  A server builds one for all its connections
+(:func:`make_tier`).  A client has ONE connection, so a tier of its own
+would cover one connection per submission; instead the clients running
+on one event loop share that loop's tier per resolved backend
+(:class:`TierLease`: a registry keyed by loop and backend, counted by
+the clients that joined) — a fleet of sessions flushes one loop
+iteration's requests in one ``_tick`` and one submission.  A lone
+client gets the same thing at depth 1.
+
 Observability: ``zookeeper_flush_syscalls_total{plane,backend}``
 counts actual write submissions (the A/B number: O(dirty conns) per
 tick on mmsg/asyncio, O(1) on uring) and ``zookeeper_submit_depth``
-histograms connections covered per batched submission.  Scraped by
-``bench.py --transport`` (`make bench-transport`).
+histograms connections covered per batched submission.  Both are the
+tier's own series (registered with the ``collector`` it was built
+with; a shared client tier has none and every joined client's
+collector adopts them).  Scraped by ``bench.py --transport`` (`make
+bench-transport`).  Under a profiler session each tick is a host span
+``<plane>.flush`` (utils/trace.host_span; count and total only):
+``client.submit``'s count over ``client.flush``'s is requests per
+flush.
 """
 
 from __future__ import annotations
@@ -69,8 +84,12 @@ import errno
 import logging
 import os
 import sys
+import threading
+import weakref
 
 from ..utils.aio import ambient_loop
+from ..utils.metrics import Collector
+from ..utils.trace import host_span
 
 log = logging.getLogger('zkstream_tpu.transport')
 
@@ -284,18 +303,27 @@ class TransportTier:
         self._uring_dead = False
         self.syscalls = 0        # lifetime submissions (tests/mntr)
         self.submissions = 0     # batched submit rounds
-        self._syscall_ctr = None
-        self._depth_hist = None
-        if collector is not None:
-            self._syscall_ctr = collector.counter(
-                METRIC_FLUSH_SYSCALLS,
-                'Write submissions issued by the outbound plane, by '
-                'plane and backend')
-            self._depth_hist = collector.histogram(
-                METRIC_SUBMIT_DEPTH,
-                'Connections covered per batched transport '
-                'submission, by plane and backend',
-                buckets=DEPTH_BUCKETS)
+        #: Clients holding a :class:`TierLease` on this tier (a
+        #: server's tier is its own and stays at 0).
+        self.refs = 0
+        #: The tick's host span (utils/trace.host_span): the client
+        #: plane's ``client.flush`` is the engagement counter beside
+        #: ``client.submit``.
+        self._span = plane + '.flush'
+        #: The tier's own series: registered with the collector it
+        #: was given, standalone without one (a loop's shared client
+        #: tier belongs to no client's collector; each joined client
+        #: adopts them).
+        source = collector if collector is not None else Collector()
+        self.syscall_ctr = source.counter(
+            METRIC_FLUSH_SYSCALLS,
+            'Write submissions issued by the outbound plane, by '
+            'plane and backend')
+        self.depth_hist = source.histogram(
+            METRIC_SUBMIT_DEPTH,
+            'Connections covered per batched transport '
+            'submission, by plane and backend',
+            buckets=DEPTH_BUCKETS)
 
     # -- SendPlane-facing API --
 
@@ -383,22 +411,23 @@ class TransportTier:
         shared callback must be no weaker — errors are logged per
         flush, and the submission + schedule-slot release always
         run."""
-        work, self._tick_work = self._tick_work, []
-        try:
-            for fn in work:
-                try:
-                    fn()
-                except Exception:
-                    log.exception('transport tick flush failed')
-        finally:
-            self._scheduled_on = None
-            dirty, self._dirty = self._dirty, []
-            self._submit(dirty)
+        with host_span(self._span, accumulate=True):
+            work, self._tick_work = self._tick_work, []
+            try:
+                for fn in work:
+                    try:
+                        fn()
+                    except Exception:
+                        log.exception('transport tick flush failed')
+            finally:
+                self._scheduled_on = None
+                dirty, self._dirty = self._dirty, []
+                self._submit(dirty)
 
     def _count(self, n: int, backend: str) -> None:
         self.syscalls += n
-        if self._syscall_ctr is not None and n:
-            self._syscall_ctr.increment(
+        if n:
+            self.syscall_ctr.increment(
                 {'plane': self.plane, 'backend': backend}, by=n)
 
     def _submit(self, entries: list[_Entry]) -> None:
@@ -456,10 +485,9 @@ class TransportTier:
                 led.exit()
         self.submissions += 1
         self._count(nsys, self.backend)
-        if self._depth_hist is not None:
-            self._depth_hist.observe(
-                len(batch_fds), {'plane': self.plane,
-                                 'backend': self.backend})
+        self.depth_hist.observe(
+            len(batch_fds), {'plane': self.plane,
+                             'backend': self.backend})
         for (e, chunks, nbytes), res in zip(raw_entries, results):
             if res != nbytes:       # the hot path writes everything
                 self._settle(e, chunks, nbytes, res)
@@ -566,3 +594,73 @@ def make_tier(arg: str | None, collector=None, plane: str = 'server',
         return None
     return TransportTier(backend, collector=collector, plane=plane,
                          ledger=ledger)
+
+
+#: The client plane's shared tiers: loop -> {backend: tier}.  Weak on
+#: the loop; a tier leaves when its last lease is released, and a
+#: closed loop's tiers are swept by the next join (a tick stranded on
+#: a dead loop holds that loop, so the weak key alone would not).
+_loop_tiers: weakref.WeakKeyDictionary = weakref.WeakKeyDictionary()
+#: Loops in different threads join and leave through one registry.
+_loop_tiers_lock = threading.Lock()
+
+
+class TierLease:
+    """One client's reference on its event loop's shared client-plane
+    tier.  ``Client.transport_tier`` reads :meth:`tier`: the first
+    read on a loop joins that loop's tier for the client's backend
+    (building it if this client is the first there), and a client
+    reused on a later loop (one ``asyncio.run`` after another) moves
+    with it.  :meth:`release` is the client's close: the ring fd goes
+    with the tier's last lease, and nothing another client has
+    pending is touched (entries are per connection)."""
+
+    __slots__ = ('backend', '_collector', '_tier', '_loop')
+
+    def __init__(self, arg: str | None, collector):
+        #: resolved once, here, as a tier of one's own was
+        self.backend = resolve_backend(arg)
+        #: the client's: adopts each joined tier's series
+        self._collector = collector
+        self._tier: TransportTier | None = None
+        self._loop = None
+
+    def tier(self) -> TransportTier | None:
+        """The running loop's shared tier (None on ``asyncio``: the
+        planes keep their own writes)."""
+        if self.backend == 'asyncio':
+            return None
+        loop = ambient_loop()
+        if loop is self._loop:
+            return self._tier
+        self.release()
+        with _loop_tiers_lock:
+            for dead in [lp for lp in _loop_tiers if lp.is_closed()]:
+                for t in _loop_tiers.pop(dead).values():
+                    t.close()
+            tiers = _loop_tiers.setdefault(loop, {})
+            tier = tiers.get(self.backend)
+            if tier is None:
+                tier = tiers[self.backend] = TransportTier(
+                    self.backend, plane='client')
+            tier.refs += 1
+        self._tier, self._loop = tier, loop
+        self._collector.adopt(tier.syscall_ctr)
+        self._collector.adopt(tier.depth_hist)
+        return tier
+
+    def release(self) -> None:
+        tier, loop = self._tier, self._loop
+        self._tier = self._loop = None
+        if tier is None:
+            return
+        with _loop_tiers_lock:
+            tier.refs -= 1
+            if tier.refs:
+                return
+            tiers = _loop_tiers.get(loop)
+            if tiers is not None and tiers.get(tier.backend) is tier:
+                del tiers[tier.backend]
+                if not tiers:
+                    del _loop_tiers[loop]
+        tier.close()

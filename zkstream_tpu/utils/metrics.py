@@ -51,26 +51,43 @@ class Counter:
         self.name = name
         self.help = help_text
         self._values: dict[tuple[tuple[str, str], ...], float] = {}
+        #: Counters other objects own and feed, read as part of this
+        #: one (:meth:`Collector.adopt`).
+        self._linked: list[Counter] = []
 
     def increment(self, labels: dict[str, str] | None = None,
                   by: float = 1.0) -> None:
         key = _label_key(labels)
         self._values[key] = self._values.get(key, 0.0) + by
 
+    def link(self, other: Counter) -> None:
+        if other is not self and other not in self._linked:
+            self._linked.append(other)
+
+    def _merged(self) -> dict:
+        """Own values plus the linked counters', per label set."""
+        if not self._linked:
+            return self._values
+        out = dict(self._values)
+        for other in self._linked:
+            for key, val in other._values.items():
+                out[key] = out.get(key, 0.0) + val
+        return out
+
     def value(self, labels: dict[str, str] | None = None) -> float:
-        return self._values.get(_label_key(labels), 0.0)
+        return self._merged().get(_label_key(labels), 0.0)
 
     def label_keys(self) -> list[tuple[tuple[str, str], ...]]:
         """Every label set with a recorded value (scrape helpers walk
         this to enumerate series, like Histogram.label_keys)."""
-        return list(self._values.keys())
+        return list(self._merged())
 
     def expose(self) -> str:
         lines = []
         if self.help:
             lines.append('# HELP %s %s' % (self.name, self.help))
         lines.append('# TYPE %s counter' % (self.name,))
-        for key, val in sorted(self._values.items()):
+        for key, val in sorted(self._merged().items()):
             lines.append('%s%s %s' % (self.name, _render_labels(key),
                                       val))
         return '\n'.join(lines)
@@ -152,6 +169,27 @@ class Histogram:
         self._series: dict[tuple[tuple[str, str], ...], list] = {}
         #: label key -> the series' rendered row keys (:meth:`rows`)
         self._row_names: dict[tuple[tuple[str, str], ...], list] = {}
+        #: Histograms other objects own and feed, read as part of
+        #: this one (:meth:`Collector.adopt`).
+        self._linked: list[Histogram] = []
+
+    def link(self, other: Histogram) -> None:
+        """``other`` has this histogram's buckets (the collector's
+        registration checks)."""
+        if other is not self and other not in self._linked:
+            self._linked.append(other)
+
+    def _merged(self) -> dict:
+        """Own series plus the linked histograms', per label set."""
+        if not self._linked:
+            return self._series
+        out = dict(self._series)
+        for other in self._linked:
+            for key, row in other._series.items():
+                mine = out.get(key)
+                out[key] = (list(row) if mine is None
+                            else [a + b for a, b in zip(mine, row)])
+        return out
 
     def _row(self, labels: dict[str, str] | None) -> list:
         key = _label_key(labels)
@@ -173,17 +211,17 @@ class Histogram:
         row[-1] += value
 
     def count(self, labels: dict[str, str] | None = None) -> int:
-        row = self._series.get(_label_key(labels))
+        row = self._merged().get(_label_key(labels))
         return sum(row[:-1]) if row is not None else 0
 
     def sum(self, labels: dict[str, str] | None = None) -> float:
-        row = self._series.get(_label_key(labels))
+        row = self._merged().get(_label_key(labels))
         return row[-1] if row is not None else 0.0
 
     def label_keys(self) -> list[tuple[tuple[str, str], ...]]:
         """Every label set this histogram holds series for (sorted
         key tuples, as ``_label_key`` produces)."""
-        return list(self._series)
+        return list(self._merged())
 
     def percentile(self, q: float,
                    labels: dict[str, str] | None = None) -> float:
@@ -192,7 +230,7 @@ class Histogram:
         in, interpolate linearly inside it.  The +Inf bucket clamps
         to the largest finite bound (no upper edge to interpolate
         toward); an empty series returns NaN."""
-        row = self._series.get(_label_key(labels))
+        row = self._merged().get(_label_key(labels))
         if row is None:
             return float('nan')
         total = sum(row[:-1])
@@ -214,7 +252,7 @@ class Histogram:
                      labels: dict[str, str] | None = None) -> int:
         """Cumulative count for the bucket with upper bound ``le``
         (``float('inf')`` for the +Inf bucket)."""
-        row = self._series.get(_label_key(labels))
+        row = self._merged().get(_label_key(labels))
         if row is None:
             return 0
         if le == float('inf'):
@@ -235,7 +273,7 @@ class Histogram:
         any window gives that window's exact bucket counts, its sum
         and its count.  The keys of a series are rendered once."""
         out: list[tuple[str, object]] = []
-        for key, row in sorted(self._series.items()):
+        for key, row in sorted(self._merged().items()):
             names = self._row_names.get(key)
             if names is None:
                 les = [self._fmt_bound(b) for b in self.buckets] + ['+Inf']
@@ -526,6 +564,19 @@ class Collector:
             return existing
         self._histograms[name] = Histogram(name, help_text, buckets)
         return self._histograms[name]
+
+    def adopt(self, series: Counter | Histogram) -> None:
+        """Expose, under its own name, a series that another object
+        owns and feeds (an event loop's shared transport tier,
+        io/transport.py).  This collector's series of that name —
+        made if need be — then reads as its own rows plus the adopted
+        ones; adopting the same series again changes nothing."""
+        if isinstance(series, Counter):
+            own = self.counter(series.name, series.help)
+        else:
+            own = self.histogram(series.name, series.help,
+                                 series.buckets)
+        own.link(series)
 
     def _check_gauge_free(self, name: str) -> None:
         """Gauges are never idempotent — a same-name registration (of
