@@ -271,15 +271,63 @@ async def test_cached_read_advances_read_floor(cached_pair):
 
 
 async def test_fill_gate_rejects_stale_reply(cached_pair):
-    """A reply older than the cache position (a lagging member's
-    read racing an invalidation) must not be deposited — else the
-    invalidated value would be resurrected and served forever."""
+    """A reply older than the newest invalidation of its path (a
+    lagging member's read, or one in flight, racing the notification)
+    must not be deposited — else the invalidated value would be
+    resurrected and served forever."""
     c1, _ = cached_pair
     cache = c1.cache
-    cache._pos = max(cache._pos, 1000)
+    cache._invalidate('dataChanged', '/app/stale', 1000)
     cache.fill('GET_DATA', '/app/stale',
                {'data': b'old', 'stat': None, 'zxid': 999})
     assert cache.lookup('GET_DATA', '/app/stale') is None
+    cache.fill('GET_DATA', '/app/stale',
+               {'data': b'new', 'stat': None, 'zxid': 1000})
+    assert cache.lookup('GET_DATA', '/app/stale')['data'] == b'new'
+
+
+async def test_fill_gate_is_per_path(cached_pair):
+    """An invalidation of one path does not turn away a reply of
+    another: nothing the stream has said up to its position named it,
+    so it has not changed since the reply.  A created/deleted child
+    stands for its parent too (the children list, the stat)."""
+    c1, _ = cached_pair
+    cache = c1.cache
+    cache._invalidate('dataChanged', '/app/a', 1000)
+    cache._invalidate('created', '/app/dir/kid', 1001)
+    assert cache._pos == 1001
+    cache.fill('GET_DATA', '/app/b',
+               {'data': b'b', 'stat': None, 'zxid': 999})
+    assert cache.lookup('GET_DATA', '/app/b')['data'] == b'b'
+    cache.fill('GET_CHILDREN2', '/app/dir',
+               {'children': [], 'stat': None, 'zxid': 1000})
+    assert cache.lookup('GET_CHILDREN2', '/app/dir') is None
+    cache.fill('GET_CHILDREN2', '/app/dir',
+               {'children': ['kid'], 'stat': None, 'zxid': 1001})
+    assert cache.lookup('GET_CHILDREN2', '/app/dir')['children'] == ['kid']
+
+
+async def test_fill_gate_forgets_into_one_floor(cached_pair, monkeypatch):
+    """The gate's per-path memory is bounded: outgrown, it collapses
+    into one floor at the position, which every path is then held to
+    (the rule it had for all paths before); a resync does the same."""
+    from zkstream_tpu.io import cache as cache_mod
+    monkeypatch.setattr(cache_mod, '_DROPPED_MAX', 4)
+    c1, _ = cached_pair
+    cache = c1.cache
+    cache._dropped.clear()      # the fixture's own create of /app
+    for i in range(5):
+        cache._invalidate('dataChanged', '/app/n%d' % i, 2000 + i)
+    assert cache._dropped == {} and cache._floor == 2004
+    cache.fill('GET_DATA', '/app/other',
+               {'data': b'old', 'stat': None, 'zxid': 2003})
+    assert cache.lookup('GET_DATA', '/app/other') is None
+    cache.fill('GET_DATA', '/app/n0',
+               {'data': b'v', 'stat': None, 'zxid': 2004})
+    assert cache.lookup('GET_DATA', '/app/n0')['data'] == b'v'
+    cache._invalidate('dataChanged', '/app/n0', 2010)
+    cache._resync(cache.roots['/app'])
+    assert cache._dropped == {} and cache._floor == cache._pos >= 2010
 
 
 def test_cache_knob_resolution(monkeypatch):

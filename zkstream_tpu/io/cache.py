@@ -35,9 +35,19 @@ apply to it verbatim.  Three mechanisms make that hold:
    fill zxid into the client floor, exactly like a server read.
 
 3. **The fill gate.**  A reply deposits into the cache only if its
-   zxid is at or above the last notification position: a distributed
-   read off a lagging member must not resurrect a value the
-   notification stream already invalidated.
+   zxid is at or above the newest invalidation OF ITS OWN PATH: a
+   reply from before that invalidation — a read in flight when the
+   notification arrived, a distributed read off a lagging member —
+   must not resurrect the value the stream already killed.  A
+   notification of ANOTHER path does not turn it away: every
+   invalidation up to the position has been applied in order, so a
+   path none of them named has not changed since the reply.  (A
+   subscriber that re-reads on the change event, as Curator's cache
+   does, would otherwise lose most of its refreshes whenever several
+   keys change together: the fleet drains a whole tick's frames before
+   any awaiting read resumes.)  The plane remembers the newest
+   invalidation of at most ``_DROPPED_MAX`` paths; beyond that it
+   forgets them all and holds every path to the position it had then.
 
 Gaps are never silent.  A disconnect marks every subtree stale (reads
 fall through); reconnect replays the registrations via SET_WATCHES2
@@ -76,6 +86,10 @@ METRIC_CACHE_STALENESS = 'zookeeper_cache_staleness_ms'
 #: entries live long enough to amortize their one fill round trip.
 STALENESS_BUCKETS = (0.1, 1.0, 10.0, 100.0, 1000.0, 10000.0,
                      60000.0, 600000.0)
+
+#: Paths whose newest invalidation the fill gate remembers one by
+#: one; one more and they collapse into one floor for every path.
+_DROPPED_MAX = 4096
 
 #: Opcodes the plane serves and fills.  GET_ACL stays uncached (ACL
 #: changes carry no notification type to invalidate on).
@@ -139,6 +153,12 @@ class CachePlane:
         #: half of the coherence position (the reply half is the live
         #: session's ``last_zxid``).
         self._pos = 0
+        #: The fill gate's memory: path -> zxid of its newest
+        #: invalidation, and the floor that stands for every path not
+        #: in it (the position at the last resync, or when the map
+        #: last outgrew ``_DROPPED_MAX``).
+        self._dropped: dict[str, int] = {}
+        self._floor = 0
         #: Plain counters for bench/campaign summaries (the metric
         #: series below carry the labelled breakdown).
         self.hits = 0
@@ -251,6 +271,8 @@ class CachePlane:
         # state at ``zxid`` — raise the client floor so no later
         # server read (distributed or primary) can show older state
         self.client._note_read_floor(zxid)
+        dropped = self._dropped
+        dropped[path] = zxid
         n = 0
         if self._data.pop(path, None) is not None:
             n += 1
@@ -262,10 +284,14 @@ class CachePlane:
             # membership changed: the parent's child list AND its
             # stat (pzxid/cversion/numChildren) are both stale
             parent = _parent(path)
+            dropped[parent] = zxid
             if self._children.pop(parent, None) is not None:
                 n += 1
             if self._stats.pop(parent, None) is not None:
                 n += 1
+        if len(dropped) > _DROPPED_MAX:
+            dropped.clear()
+            self._floor = self._pos
         if n:
             self.invalidations += n
             if self._inval_c is not None:
@@ -282,6 +308,9 @@ class CachePlane:
             # entries filled from here on are newer than anything the
             # dark window could have invalidated
             self._pos = sess.last_zxid
+        # ... and than anything the gate remembers of single paths
+        self._dropped.clear()
+        self._floor = self._pos
         root.armed = True
         root.stale = False
 
@@ -379,16 +408,18 @@ class CachePlane:
             self._miss_c.increment({'op': opcode})
 
     def fill(self, opcode: str, path: str, pkt: dict) -> None:
-        """Deposit one server reply.  Gated on the notification
-        position: a reply off a member behind an invalidation this
-        plane already applied must not resurrect the dead value."""
+        """Deposit one server reply.  Gated on the newest
+        invalidation of ``path`` (of any path, for one the plane no
+        longer remembers singly): a reply from before an invalidation
+        this plane already applied must not resurrect the dead
+        value."""
         if opcode not in _CACHED_OPS:
             return
         root = self._covering_root(path)
         if root is None or not root.armed or root.stale:
             return
         zxid = pkt.get('zxid', 0)
-        if zxid < self._pos:
+        if zxid < self._floor or zxid < self._dropped.get(path, 0):
             return
         now = time.monotonic()
         if opcode == 'GET_DATA':

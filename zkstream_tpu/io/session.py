@@ -25,6 +25,7 @@ from ..utils.events import EventEmitter
 from ..utils.fsm import FSM
 from ..utils.logging import Logger
 from ..utils.metrics import Collector
+from ..utils.trace import host_span
 from .backoff import BackoffPolicy
 from .watcher import ZKPersistentWatcher, ZKWatcher
 
@@ -430,27 +431,34 @@ class ZKSession(FSM):
 
     def process_notification(self, pkt: dict) -> None:
         """Dispatch a NOTIFICATION to the right path's watcher
-        (reference: lib/zk-session.js:389-419)."""
+        (reference: lib/zk-session.js:389-419).
+
+        Host span ``client.notify`` (profiler sessions only; count and
+        total, no object per frame): an xid -1 frame at the session
+        until every watcher it matches has emitted — the one-shot
+        engine's notify, the persistent registrations' listeners and
+        with them the cache plane's invalidation (io/cache.py)."""
         if pkt['state'] != 'SYNC_CONNECTED':
             self.log.warning('received notification with bad state %s',
                              pkt['state'])
             return
-        evt = _NOTIFICATION_EVENTS[pkt['type']]
-        self.log.trace('notification %s for %s', evt, pkt['path'])
-        self.collector.get_collector(
-            METRIC_ZK_NOTIFICATION_COUNTER).increment({'event': evt})
-        if self.trace is not None:
-            self.trace.note('NOTIFICATION', pkt['path'],
-                            zxid=self.last_zxid, kind='notification',
-                            session_id=self.get_session_id())
-        watcher = self.watchers.get(pkt['path'])
-        if watcher is not None:
-            watcher.notify(evt)
-        if self.persistent_watchers:
-            zxid = pkt.get('zxid', 0)
-            if zxid > self.notif_zxid:
-                self.notif_zxid = zxid
-            self._dispatch_persistent(evt, pkt['path'], zxid)
+        with host_span('client.notify', accumulate=True):
+            evt = _NOTIFICATION_EVENTS[pkt['type']]
+            self.log.trace('notification %s for %s', evt, pkt['path'])
+            self.collector.get_collector(
+                METRIC_ZK_NOTIFICATION_COUNTER).increment({'event': evt})
+            if self.trace is not None:
+                self.trace.note('NOTIFICATION', pkt['path'],
+                                zxid=self.last_zxid, kind='notification',
+                                session_id=self.get_session_id())
+            watcher = self.watchers.get(pkt['path'])
+            if watcher is not None:
+                watcher.notify(evt)
+            if self.persistent_watchers:
+                zxid = pkt.get('zxid', 0)
+                if zxid > self.notif_zxid:
+                    self.notif_zxid = zxid
+                self._dispatch_persistent(evt, pkt['path'], zxid)
 
     def _dispatch_persistent(self, evt: str, path: str,
                              zxid: int) -> None:

@@ -1846,6 +1846,12 @@ class ZKServer:
                 ('zk_blackbox_frames', bb.frames),
                 ('zk_blackbox_bytes', bb.bytes_written),
             ]
+        # cumulative: frames the table's persistent fan-out handed to
+        # the send plane (the emitter fallback keeps no count)
+        fanout_rows: list[tuple[str, object]] = (
+            [] if self.watch_table is None else
+            [('zk_persistent_notifications',
+              self.watch_table.persistent_sent)])
         if self.ledger is not None:
             tick_rows.append(('zk_tick_count', self.ledger.ticks))
             for phase in TickLedger.PHASES:
@@ -1892,7 +1898,8 @@ class ZKServer:
             + (self.overload.mntr_rows()
                if self.overload is not None else []) \
             + multi_rows + gate_rows \
-            + quorum_rows + config_rows + tick_rows + blackbox_rows \
+            + quorum_rows + config_rows + fanout_rows + tick_rows \
+            + blackbox_rows \
             + wal_rows + (self._histogram_rows() if histograms else [])
 
     def _histogram_rows(self) -> list[tuple[str, object]]:

@@ -154,6 +154,12 @@ class WatchTable:
         #: ``zk_persistent_watches`` / ``zk_recursive_watches``).
         self.persistent_count = 0
         self.recursive_count = 0
+        #: Notification frames :meth:`_fan_persistent` handed to the
+        #: send plane since this member started (mntr
+        #: ``zk_persistent_notifications``): a subscriber that was
+        #: closed, or that the overload gate evicted instead, is not
+        #: in it.
+        self.persistent_sent = 0
         #: Per-tick encode memo: (type, path, zxid) -> wire bytes.
         #: Cleared at the next tick boundary, so interleaved event
         #: kinds within one tick (a DELETED fanning to both data and
@@ -393,19 +399,19 @@ class WatchTable:
                 if not conn.closed:
                     self._enqueue_persistent(conn, data)
             return
-        srv.packets_sent += len(subs)
+        sent = len(subs)
         shards = self._shards
         sched: list = []
         ov = getattr(srv, 'overload', None)
         for conn in subs:
             if conn.closed:
-                srv.packets_sent -= 1
+                sent -= 1
                 continue
             if ov is not None \
                     and not ov.allow_persistent_notification(conn):
                 # the gate EVICTED the stalled subscriber (typed
                 # close) rather than dropping the frame
-                srv.packets_sent -= 1
+                sent -= 1
                 continue
             buf = conn._fanout_buf
             if not buf:
@@ -415,6 +421,8 @@ class WatchTable:
                     shard.scheduled = True
                     sched.append(shard)
             buf.append(data)
+        srv.packets_sent += sent
+        self.persistent_sent += sent
         if sched:
             self._schedule_shards(sched)
 
@@ -426,6 +434,7 @@ class WatchTable:
                 and not ov.allow_persistent_notification(conn):
             return
         self.server.packets_sent += 1
+        self.persistent_sent += 1
         fi = self.server.faults
         if fi is not None and fi.server_tx(conn, data,
                                            pre=conn._preflush_fanout):
