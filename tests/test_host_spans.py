@@ -215,6 +215,50 @@ async def test_a_fleets_burst_is_one_client_flush(server, armed):
             await c.close()
 
 
+async def test_a_loops_requests_share_its_deadline_timer(server, armed):
+    """The deadline queue's engagement counter: ``client.deadline`` is
+    an arming or a firing of the loop's ONE timer, so ``client.submit``'s
+    count over its count is requests per loop timer (1.0 by
+    construction while every op armed an ``asyncio.wait_for``)."""
+    from zkstream_tpu.utils.aio import deadline_queue
+    clients = []
+    try:
+        for _ in range(4):
+            c = Client(address='127.0.0.1', port=server.port,
+                       session_timeout=30000, max_spares=0)
+            c.start()
+            await c.wait_connected(timeout=5)
+            clients.append(c)
+        queue = deadline_queue(asyncio.get_running_loop())
+        trace.host_ring.reset()
+        for _ in range(10):
+            await asyncio.gather(*[c.list('/', deadline=30000)
+                                   for c in clients for _ in range(25)])
+        totals = trace.host_ring.totals
+        assert totals['client.submit'][0] == 1000
+        # the first arming, then one for each compaction of the heap
+        assert 1 <= totals['client.deadline'][0] \
+            <= 1000 // queue.COMPACT_MIN + 1
+        server.drop_replies = True
+        before = totals['client.deadline'][0]
+        with pytest.raises(Exception) as ei:
+            await clients[0].list('/', deadline=20)
+        assert getattr(ei.value, 'code', None) == 'DEADLINE_EXCEEDED'
+        # the earlier deadline moved the timer, and it fired
+        assert totals['client.deadline'][0] >= before + 2
+        assert len(trace.host_ring) == 0        # count and total only
+        # the reply that never came, or close() would wait for it
+        conn = clients[0].current_connection()
+        (xid,) = [x for x in conn.reqs if x > 0]
+        conn.process_reply({'xid': xid, 'zxid': 1, 'err': 'OK',
+                            'opcode': 'GET_CHILDREN2', 'children': [],
+                            'stat': None})
+    finally:
+        server.drop_replies = False
+        for c in clients:
+            await c.close()
+
+
 async def test_phase_histogram_needs_no_session_and_binds(server):
     from zkstream_tpu import Collector
 

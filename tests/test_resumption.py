@@ -156,6 +156,41 @@ async def test_clean_close_cancels_inflight_request(server):
     assert ev1[:2] == ['session', 'connect']
 
 
+@pytest.mark.parametrize('lose', [False, True])
+async def test_reserved_xid_request_serves_future_and_listeners(
+        server, lose):
+    """A reserved-xid request (PING here) keeps its emitter for the
+    piggy-backing ``once`` listeners, and ``as_future()`` on the same
+    request is settled beside them: by the reply, or by the teardown's
+    typed error."""
+    from zkstream_tpu.protocol import consts
+    c1, _ = tracked_client(server)
+    await c1.wait_connected(timeout=5)
+    conn = c1.current_connection()
+    server.drop_pings = lose
+    seen = []
+    conn.ping(lambda err, latency: seen.append(('first', err)))
+    req = conn.reqs[consts.XID_PING]
+    fut = req.as_future()
+    assert req.as_future() is fut           # one future a request
+    conn.ping(lambda err, latency: seen.append(('second', err)))
+    assert conn.reqs[consts.XID_PING] is req    # piggy-backed
+    if lose:
+        conn.transport.abort()
+        with pytest.raises(ZKProtocolError) as ei:
+            await asyncio.wait_for(fut, 5)
+        assert ei.value.code == 'CONNECTION_LOSS'
+        assert [(who, err.code) for who, err in seen] == \
+            [('first', 'CONNECTION_LOSS'), ('second', 'CONNECTION_LOSS')]
+    else:
+        pkt = await asyncio.wait_for(fut, 5)
+        assert pkt['xid'] == consts.XID_PING and pkt['err'] == 'OK'
+        assert seen == [('first', None), ('second', None)]
+    assert consts.XID_PING not in conn.reqs
+    server.drop_pings = False
+    await c1.close()
+
+
 async def test_resumption_preserves_session_id(server):
     c1, _ = tracked_client(server)
     await c1.wait_connected(timeout=5)

@@ -104,6 +104,37 @@ async def test_error_and_deadline_spans(server):
         await c.close()
 
 
+async def test_deadline_span_covers_the_whole_deadline(server):
+    """The deadline comes from the loop's one queue, not a timer of
+    the op's own: the span still settles ``deadline`` no earlier than
+    the op asked for, a reply that arrives afterwards does not
+    re-settle it, and an op with no deadline settles ``ok``."""
+    c = Client(address='127.0.0.1', port=server.port,
+               session_timeout=5000)
+    c.start()
+    try:
+        await c.wait_connected(timeout=5)
+        await c.create('/q', b'x')
+        server.drop_replies = True
+        conn = c.current_connection()
+        with pytest.raises(ZKDeadlineError):
+            await c.get('/q', deadline=60)
+        span = c.trace.dump()[-1]
+        assert span['status'] == 'deadline'
+        assert span['duration_ms'] >= 60
+        conn.process_reply({'xid': span['xid'], 'zxid': 9, 'err': 'OK',
+                            'opcode': 'GET_DATA', 'data': b'late',
+                            'stat': None})
+        again = c.trace.dump()[-1]
+        assert (again['status'], again.get('zxid')) == ('deadline', None)
+        server.drop_replies = False
+        await c.get('/q', deadline=None)
+        assert c.trace.dump()[-1]['status'] == 'ok'
+    finally:
+        server.drop_replies = False
+        await c.close()
+
+
 async def test_notifications_recorded_in_ring(server):
     c = Client(address='127.0.0.1', port=server.port,
                session_timeout=5000)

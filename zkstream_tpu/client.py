@@ -46,7 +46,7 @@ from .protocol.consts import MAX_PACKET, CreateFlag
 from .protocol.errors import ZKDeadlineError, ZKNotConnectedError, \
     ZKThrottledError
 from .protocol.records import OPEN_ACL_UNSAFE, Stat
-from .utils.aio import ambient_loop
+from .utils.aio import DeadlineExpired, ambient_loop, deadline_queue
 from .utils.fsm import FSM, bind_transition_metrics
 from .utils.logging import Logger
 from .utils.metrics import Collector
@@ -582,27 +582,34 @@ class Client(FSM):
         """Bound one request future by the per-request deadline.
 
         ``deadline`` is the per-op override in ms (``_USE_DEFAULT`` =
-        the client's ``op_timeout``; ``None`` = unbounded).  On expiry
-        the op fails fast with a typed :class:`ZKDeadlineError` instead
-        of hanging on a dead or wedged connection; the underlying
-        request is cancelled for the caller, and the connection's
-        teardown paths still settle it exactly once internally.
+        the client's ``op_timeout``; ``None`` = unbounded, nothing
+        armed).  The future is awaited bare; its deadline stands in
+        the running loop's one :class:`DeadlineQueue` (utils/aio.py)
+        — never early, late by at most the loop iteration its timer
+        fires in.  On expiry the op fails fast with a typed
+        :class:`ZKDeadlineError` instead of hanging on a dead or
+        wedged connection; the request stays pending on the
+        connection, whose reply or teardown paths still settle it
+        exactly once internally (a late reply is dropped).
 
         Every completion path (reply, error, deadline) records the
         elapsed time into the per-op latency histogram."""
         ms = self.op_timeout if deadline is _USE_DEFAULT else deadline
         t0 = time.monotonic()
+        entry = None
         try:
-            if ms is None:
-                return await fut
-            try:
-                return await asyncio.wait_for(fut, ms / 1000.0)
-            except asyncio.TimeoutError:
-                if span is not None:
-                    span.finish(status='deadline',
-                                error='DEADLINE_EXCEEDED')
-                raise ZKDeadlineError(opcode, path, ms) from None
+            if ms is not None:
+                queue = deadline_queue(asyncio.get_running_loop())
+                entry = queue.add(fut, ms / 1000.0)
+            return await fut
+        except DeadlineExpired:
+            if span is not None:
+                span.finish(status='deadline',
+                            error='DEADLINE_EXCEEDED')
+            raise ZKDeadlineError(opcode, path, ms) from None
         finally:
+            if entry is not None:
+                queue.discard(entry)
             self._op_latency.observe(
                 (time.monotonic() - t0) * 1000.0, {'op': opcode})
             if self.on_op is not None and span is not None:

@@ -9,9 +9,9 @@ piggybacking, SET_WATCHES queueing, and failing every outstanding
 request exactly once on each teardown path.
 
 Where the reference wires Node streams and sockets together, this uses
-an asyncio ``Protocol`` feeding the symmetric ``PacketCodec``; requests
-are represented by ``ZKRequest`` emitters ('reply'/'error'), which the
-client facade adapts to awaitables.
+an asyncio ``Protocol`` feeding the symmetric ``PacketCodec``; a request
+is a ``ZKRequest``, which carries the client facade's future and is an
+emitter ('reply'/'error') for what must run inside the reply routing.
 """
 
 from __future__ import annotations
@@ -59,29 +59,46 @@ def _finish_span(req, zxid: int | None = None, status: str = 'ok',
 
 
 class ZKRequest(EventEmitter):
-    """One in-flight request: emits 'reply' (packet) or 'error' (exc)
-    exactly once (reference: lib/connection-fsm.js:378-382).  The
-    client facade may attach a trace ``span``; the connection's
-    reply/error routing closes it."""
+    """One in-flight request, settled exactly once by the connection:
+    :meth:`resolve` (the reply packet) or :meth:`fail` (a typed
+    error) (reference: lib/connection-fsm.js:378-382).  Whoever
+    awaits it takes :meth:`as_future` — the connection settles that
+    future itself, with no listener in between; the 'reply' /
+    'error' events serve what must run inside the routing call (the
+    reserved xids' piggy-backing, a watcher's arm).  The client
+    facade may attach a trace ``span``; the connection's reply/error
+    routing closes it."""
 
     def __init__(self, packet: dict):
         super().__init__()
         self.packet = packet
         #: Optional utils/trace.Span, attached by Client._start_op.
         self.span = None
+        #: The awaiter's future, once as_future() was asked for it.
+        self.fut: asyncio.Future | None = None
 
     def as_future(self) -> asyncio.Future:
-        """Adapt to an awaitable resolving to the reply packet.
+        """The awaitable that resolves to the reply packet (one per
+        request: a second call returns the same future)."""
+        if self.fut is None:
+            self.fut = asyncio.get_running_loop().create_future()
+        return self.fut
 
-        Plain ``on`` (not ``once``): reply/error fire at most once per
-        request by contract, the ``done()`` guards make a double-settle
-        harmless, and skipping the once-wrapper + removal scan matters
-        on the per-op hot path."""
-        fut: asyncio.Future = asyncio.get_running_loop().create_future()
-        self.on('reply', lambda pkt: fut.done() or fut.set_result(pkt))
-        self.on('error', lambda err, *a: fut.done() or
-                fut.set_exception(err))
-        return fut
+    def resolve(self, pkt: dict) -> None:
+        fut = self.fut
+        # done() already: the awaiter gave up (deadline, cancelled)
+        # and the late reply is dropped
+        if fut is not None and not fut.done():
+            fut.set_result(pkt)
+        if self._listeners:
+            self.emit('reply', pkt)
+
+    def fail(self, err: Exception, *args) -> None:
+        fut = self.fut
+        if fut is not None and not fut.done():
+            fut.set_exception(err)
+        if self._listeners:
+            self.emit('error', err, *args)
 
 
 class _SocketProtocol(asyncio.Protocol):
@@ -497,7 +514,7 @@ class ZKConnection(FSM):
             _finish_span(req, status='error',
                          error=getattr(req_err, 'code', None)
                          or type(req_err).__name__)
-            req.emit('error', req_err)
+            req.fail(req_err)
 
         # Deliberately not scope-bound: the 'error' event must fire even
         # though we leave this state immediately
@@ -538,7 +555,7 @@ class ZKConnection(FSM):
             reqs, self.reqs = self.reqs, {}
             for req in reqs.values():
                 _finish_span(req, status='abandoned', error=err.code)
-                req.emit('error', err)
+                req.fail(err)
         S.immediate(fail_stragglers)
 
     # -- request plumbing --
@@ -604,7 +621,7 @@ class ZKConnection(FSM):
             return
         if pkt['err'] == 'OK':
             _finish_span(req, zxid=pkt.get('zxid'))
-            req.emit('reply', pkt)
+            req.resolve(pkt)
         else:
             _finish_span(req, zxid=pkt.get('zxid'), status='error',
                          error=pkt['err'])
@@ -614,7 +631,7 @@ class ZKConnection(FSM):
             err = (ZKThrottledError()
                    if pkt['err'] == 'THROTTLED'
                    else ZKError(pkt['err']))
-            req.emit('error', err, pkt)
+            req.fail(err, pkt)
 
     def request(self, pkt: dict) -> ZKRequest:
         """Send a normal (positive-xid) request
