@@ -4,16 +4,12 @@
 # baked-in tooling only.
 
 PYTHON ?= python3
-LINT_TARGETS = zkstream_tpu tests tools bench.py chip_smoke.py \
+LINT_TARGETS = zkstream_tpu tests tools chip_smoke.py \
     __graft_entry__.py
 
-.PHONY: all test check analyze native loadgen bench asan ubsan \
+.PHONY: all test check analyze native loadgen asan ubsan \
     sanitize chaos chaos-ensemble obs durability election linearize \
-    reconfig overload cache \
-    bench-wal bench-fanout bench-trace bench-election \
-    bench-transport bench-ingress bench-quorum bench-linearize \
-    bench-read bench-reconfig bench-blackbox bench-overload \
-    bench-million timeline coverage clean
+    reconfig overload cache timeline coverage clean
 
 all: check test
 
@@ -112,115 +108,6 @@ cache:
 	$(PYTHON) -m pytest tests/test_process_ensemble.py -q \
 	    -k 'cached'
 
-# Failover-time envelope: paired leader-kill cells at 3- vs 5-member
-# in-process ensembles — kill the leader, time detection -> elected
-# successor (zk_election_ms) and the client-observed failover (kill
-# -> first acked write through the new leader), exact sign test
-# between the sizes.  Rounds via ZKSTREAM_BENCH_ELECTION_ROUNDS.
-bench-election:
-	$(PYTHON) bench.py --election
-
-# Quorum-commit cost envelope: paired quorum-on/off write-heavy
-# cells at 3/5 in-process members (the leader's ack gated on the
-# majority floor vs the fsync-only barrier) plus MULTI batching
-# cells (one multi of K creates vs K pipelined singletons), exact
-# sign tests (table in PROFILE.md "Quorum commit").  Rounds via
-# ZKSTREAM_BENCH_QUORUM_ROUNDS.
-bench-quorum:
-	$(PYTHON) bench.py --quorum
-
-# Dynamic-membership cost envelope: per-round adjacent write cells
-# on one 3-voter ensemble — steady state vs during an observer join
-# vs during a voter replace — with exact sign tests against the
-# steady arm and join/replace duration percentiles (table in
-# PROFILE.md "Reconfiguration").  The bar: the observer-join arm
-# must NOT be significantly slower (an observer never widens the
-# write quorum).  Rounds via ZKSTREAM_BENCH_RECONFIG_ROUNDS.
-bench-reconfig:
-	$(PYTHON) bench.py --reconfig
-
-# Paired durability-cost envelope: wal-off vs sync=tick (group
-# commit) vs sync=always write-heavy cells at fleet 16/64 with
-# fsync-latency histograms per cell and exact sign tests (table in
-# PROFILE.md "Durability plane").  Rounds via ZKSTREAM_BENCH_WAL_ROUNDS;
-# WAL device via ZKSTREAM_BENCH_WAL_DIR (default tmpfs — measure the
-# plane, not this image's 9p filesystem).
-bench-wal:
-	$(PYTHON) bench.py --wal
-
-# Batched-syscall transport envelope: the best available batched
-# backend (io_uring where the kernel has it, the C writev batch
-# otherwise) vs the asyncio validator, paired cells over real kernel
-# sockets at 128/1k/10k connections x write-heavy/fanout with exact
-# sign tests, per-cell syscall counts
-# (zookeeper_flush_syscalls_total) and tick-ledger phase shares
-# (table in PROFILE.md "Transport tier").  Rounds via
-# ZKSTREAM_BENCH_TRANSPORT_ROUNDS; narrow with --conns/--workloads.
-bench-transport: native
-	$(PYTHON) bench.py --transport
-
-# Shared-nothing ingress envelope: per-core accept shards + batched
-# receive drain (io/ingress.py) vs the single-loop validator, paired
-# cells over real kernel sockets at 1k/10k/100k connections x
-# write-heavy/fanout with exact sign tests, syscalls-per-tick
-# accounted BOTH directions per cell
-# (zookeeper_flush_syscalls_total + zookeeper_recv_syscalls_total /
-# zookeeper_recv_drain_depth) and tick-ledger phase shares incl. the
-# new rx_drain phase (table in PROFILE.md "Ingress").  Rounds via
-# ZKSTREAM_BENCH_INGRESS_ROUNDS; narrow with --conns/--workloads.
-bench-ingress: native
-	$(PYTHON) bench.py --ingress
-
-# Serving-plane fan-out envelope: the sharded watch table vs the
-# per-connection emitter dispatch (server/watchtable.py), paired
-# table/emitter cells over the 1k/10k/100k-session x watchers sweep
-# with exact sign tests, per-shard flush-batch + tick histograms, and
-# the tick-ledger phase table per table-arm cell (table in PROFILE.md
-# "Fan-out plane").  Rounds via ZKSTREAM_BENCH_FANOUT_ROUNDS; narrow
-# with --sessions/--watchers.
-bench-fanout: loadgen
-	$(PYTHON) bench.py --fanout
-
-# Read scale-out envelope (README "Read plane"): paired cells at
-# 1 vs 3 vs 5 read-serving members — the leader plus non-voting
-# OBSERVERS spawned as real OS processes (member_worker --observer)
-# so read capacity genuinely parallelizes — x 1k/10k raw-socket
-# sessions x read-heavy/mixed workloads.  Exact sign tests: read
-# throughput must be significantly HIGHER at 3 and 5 members than 1,
-# and write p50 NOT significantly worse with observers attached (the
-# write quorum never widens: observers don't vote).  Gate counters
-# (zk_read_zxid_gate_*) and tick-ledger phases scraped per cell.
-# Rounds via ZKSTREAM_BENCH_READ_ROUNDS, window via
-# ZKSTREAM_BENCH_READ_SECS; narrow with --sessions/--workloads.
-# Table in PROFILE.md "Read plane".
-bench-read: loadgen
-	$(PYTHON) bench.py --read
-
-# The million-session campaign (README "Load generation"; PROFILE.md
-# round 19): ONE C-loadgen run per member count against a real
-# leader + observers fleet — handshake wave, keepalive-only hold
-# with live pings, a watch armed per session, fan-out rounds through
-# every armed watcher, and a post-failover-shaped SET_WATCHES storm.
-# Member RSS/fd counts scraped at the all-connected peak; when the
-# host fd/memory cap bounds the session count the cell names it in
-# caps.binding_constraint.  The default is a tier-1-safe 2000 x 2s
-# smoke; the real campaign scales with
-# ZKSTREAM_BENCH_MILLION_SESSIONS=1000000 (plus _MEMBERS, _SECS,
-# _RAMP — see README "Load generation").
-bench-million: loadgen
-	$(PYTHON) bench.py --million
-
-# Overload-plane envelope (README "Overload plane"): paired
-# stalled-consumer defense cells (defense on vs overload=False — the
-# on-arm's peak tx backlog must stay bounded by the hard watermark
-# while the off-arm's grows with the pipelined reads) plus paired
-# plane-overhead cells (plane on vs ZKSTREAM_NO_OVERLOAD=1, fleet
-# 16/64, write-heavy) with exact two-sided sign tests.  Rounds via
-# ZKSTREAM_BENCH_OVERLOAD_ROUNDS.  Table in PROFILE.md "Overload
-# plane".
-bench-overload:
-	$(PYTHON) bench.py --overload
-
 # Observability suite: metrics (counters/gauges/histograms +
 # exposition), causal tracing (client spans + member rings + the
 # zxid-merged timeline), the tick ledger, the four-letter admin
@@ -240,22 +127,6 @@ obs:
 timeline:
 	$(PYTHON) -m zkstream_tpu timeline
 
-# Paired trace-plane overhead envelope: member span rings + tick
-# ledger (the default) vs ZKSTREAM_NO_SERVER_TRACE=1, write-heavy
-# cells at fleet 16/64 with exact sign tests — the acceptance bar is
-# "not significantly slower at any cell".  Rounds via
-# ZKSTREAM_BENCH_TRACE_ROUNDS.
-bench-trace:
-	$(PYTHON) bench.py --traceov
-
-# Paired black-box-plane overhead envelope: the crash-durable flight
-# recorder + slow-op digest (the default) vs ZKSTREAM_NO_BLACKBOX=1,
-# WAL-backed write-heavy cells at fleet 16/64 with exact sign tests —
-# acceptance bar "not significantly slower at any cell" (table in
-# PROFILE.md).  Rounds via ZKSTREAM_BENCH_BLACKBOX_ROUNDS.
-bench-blackbox:
-	$(PYTHON) bench.py --blackbox
-
 # Linearizability plane (analysis/linearize.py; README
 # "Linearizability"): the checker's own violation corpus
 # (tests/linearize_corpus — every known-bad history flagged with a
@@ -270,15 +141,6 @@ linearize:
 	$(PYTHON) -m pytest tests/test_linearize.py -q -m 'not slow'
 	$(PYTHON) -m pytest tests/test_chaos_ensemble.py -q \
 	    -k 'concurrent' -m 'not slow'
-
-# WGL cost guard: check time vs history length/width cells over
-# synthetic-but-valid concurrent histories (every finding there
-# would be a checker false positive).  Asserts the per-key
-# partition + zxid pruning + greedy no-effect commits keep the
-# campaign-shaped cell under its budget (table in PROFILE.md
-# "Linearizability checker").
-bench-linearize:
-	$(PYTHON) tools/bench_linearize.py
 
 check: analyze cache
 	$(PYTHON) tools/lint.py $(LINT_TARGETS)
@@ -305,8 +167,7 @@ native:
 
 # Build the raw-socket C load generator (tools/loadgen.c ->
 # native/zkloadgen.vN).  Same capability-probed discipline as the
-# codecs: graceful skip without a compiler (benches then fall back
-# to the Python worker arm and say so).
+# codecs: graceful skip without a compiler.
 loadgen:
 	$(PYTHON) -c "from zkstream_tpu.utils import native; \
 	    p = native.build_loadgen(); \
@@ -325,20 +186,6 @@ ubsan:
 
 # Both sanitizer drives, back to back.
 sanitize: asan ubsan
-
-bench:
-	$(PYTHON) bench.py
-
-# Write-heavy (SET_DATA/CREATE-dominated) client-ops cells only: the
-# outbound-plane family (single-pass encode + tick-corked coalescing,
-# PROFILE.md "Encode side").  Host-path; prints per-cell flush-batch
-# distributions from zookeeper_flush_batch_frames/_bytes plus the
-# tick-ledger phase table (zk_tick_phase_ms: decode_apply /
-# fsync_gate / cork_flush / fanout_flush share per cell).  The paired
-# coalescing sign-test lives in tools/sweep_crossover.py
-# (--workload write --paired native,native-nocork).
-bench-write:
-	$(PYTHON) bench.py --write
 
 # Line coverage (reference Makefile:61-66 istanbul analogue).  No
 # coverage package in this image; tools/cover.py implements it on

@@ -564,15 +564,131 @@ def _same(tag: str, want, got) -> None:
               % (tag, f))
 
 
-def kernel_corpus(cfg: dict):
-    """Mixed-opcode rows as the benchmark builds them (GET_DATA-dominant
-    with children/ACL lists, notifications, error and ping replies);
-    each check cuts them to the row length its kernel program fits."""
-    import bench
+# -- the kernels' corpus: mixed-opcode reply streams.  Every stream
+# carries the same (opcode, width) sequence at the same byte offsets
+# and random contents, so the builder stays vectorized --
+_FRAMES = 64                 # frames per stream
+_DATA_LEN = 256              # GET_DATA payload bytes
+_CH2_N, _CH2_NAME = 8, 12    # GET_CHILDREN2: children x name bytes
+_CH_N, _CH_NAME = 6, 10      # GET_CHILDREN (no Stat)
+_ACL_N, _ACL_SCHEME, _ACL_ID = 2, 6, 24
+_NOTIF_PATH = 20
 
-    rows = max(cfg['scan_pocket'][0], cfg['full_small'][0],
-               cfg['tick_pocket'][0])
-    return bench._fleet(rows)[0]
+#: Per-16-frame opcode pattern: GET_DATA-dominant, with children and
+#: ACL lists, watch notifications, error and ping replies interleaved
+#: so every plane of the decode carries live traffic.
+_SLOT_PATTERN = (
+    'data', 'data', 'children2', 'data', 'notif', 'data', 'acl',
+    'data', 'data', 'children', 'data_err', 'data', 'data',
+    'children2', 'ping', 'data')
+
+_BODY_LEN = {
+    'data': 16 + 4 + _DATA_LEN + 68,
+    'data_err': 16,                       # error reply: header only
+    'children2': 16 + 4 + _CH2_N * (4 + _CH2_NAME) + 68,
+    'children': 16 + 4 + _CH_N * (4 + _CH_NAME),
+    'acl': 16 + 4 + _ACL_N * (4 + 4 + _ACL_SCHEME + 4 + _ACL_ID) + 68,
+    'notif': 16 + 4 + 4 + 4 + _NOTIF_PATH,
+    'ping': 16,
+}
+
+
+def kernel_corpus(cfg: dict):
+    """uint8 [rows, L]: framed streams of valid mixed-opcode replies —
+    reply headers then per-opcode bodies (reference layouts:
+    lib/zk-buffer.js:275-370,428-442) — from a fixed seed; each check
+    cuts them to the row length its kernel program fits."""
+    import numpy as np
+
+    B = max(cfg['scan_pocket'][0], cfg['full_small'][0],
+            cfg['tick_pocket'][0])
+    kinds = _SLOT_PATTERN * (_FRAMES // len(_SLOT_PATTERN))
+    rng = np.random.RandomState(42)
+    v = np.zeros((B, sum(4 + _BODY_LEN[k] for k in kinds)), np.uint8)
+
+    def be(field, width, out):
+        shifts = np.arange(8 * (width - 1), -1, -8, dtype=np.int64)
+        out[...] = ((field[..., None] >> shifts) & 0xFF).astype(np.uint8)
+
+    def ri(lo, hi):
+        return rng.randint(lo, hi, (B,)).astype(np.int64)
+
+    def full(x):
+        return np.full((B,), x, np.int64)
+
+    def ascii_bytes(n):
+        return rng.randint(97, 123, (B, n), dtype=np.uint8)  # a-z
+
+    def write_stat(off, mzxid, data_len=0, num_children=0):
+        be(ri(1, 1 << 40), 8, v[:, off:off + 8])          # czxid
+        be(mzxid, 8, v[:, off + 8:off + 16])              # mzxid
+        be(ri(1, 1 << 41), 8, v[:, off + 16:off + 24])    # ctime
+        be(ri(1, 1 << 41), 8, v[:, off + 24:off + 32])    # mtime
+        be(ri(0, 1 << 10), 4, v[:, off + 32:off + 36])    # version
+        be(ri(0, 1 << 10), 4, v[:, off + 36:off + 40])    # cversion
+        be(ri(0, 1 << 10), 4, v[:, off + 40:off + 44])    # aversion
+        # ephemeralOwner stays 0
+        be(full(data_len), 4, v[:, off + 52:off + 56])    # dataLength
+        be(full(num_children), 4, v[:, off + 56:off + 60])
+        be(ri(1, 1 << 40), 8, v[:, off + 60:off + 68])    # pzxid
+
+    # xids: sequential per stream from a random base, like the
+    # connection FSM's allocator — a reply xid is unique in flight
+    xbase = rng.randint(1, 1 << 19, (B,)).astype(np.int64)
+    o = xi = 0
+    for kind in kinds:
+        be(full(_BODY_LEN[kind]), 4, v[:, o:o + 4])
+        if kind == 'notif':
+            xid, zxid, err = full(-1), full(-1), 0
+        elif kind == 'ping':
+            xid, zxid, err = full(-2), ri(1, 1 << 40), 0
+        else:
+            xid, zxid = xbase + xi, ri(1, 1 << 40)
+            err = -101 if kind == 'data_err' else 0  # NO_NODE
+            xi += 1
+        be(xid, 4, v[:, o + 4:o + 8])
+        be(zxid, 8, v[:, o + 8:o + 16])
+        be(full(err), 4, v[:, o + 16:o + 20])
+        p = o + 20                                  # payload start
+        if kind == 'data':
+            be(full(_DATA_LEN), 4, v[:, p:p + 4])
+            v[:, p + 4:p + 4 + _DATA_LEN] = rng.randint(
+                0, 256, (B, _DATA_LEN), dtype=np.uint8)
+            write_stat(p + 4 + _DATA_LEN, zxid, data_len=_DATA_LEN)
+        elif kind in ('children2', 'children'):
+            n, w = ((_CH2_N, _CH2_NAME) if kind == 'children2'
+                    else (_CH_N, _CH_NAME))
+            be(full(n), 4, v[:, p:p + 4])
+            c = p + 4
+            for _k in range(n):
+                be(full(w), 4, v[:, c:c + 4])
+                v[:, c + 4:c + 4 + w] = ascii_bytes(w)
+                c += 4 + w
+            if kind == 'children2':
+                write_stat(c, zxid, num_children=n)
+        elif kind == 'acl':
+            be(full(_ACL_N), 4, v[:, p:p + 4])
+            c = p + 4
+            for _k in range(_ACL_N):
+                be(full(0x1F), 4, v[:, c:c + 4])    # perms: ALL
+                be(full(_ACL_SCHEME), 4, v[:, c + 4:c + 8])
+                v[:, c + 8:c + 8 + _ACL_SCHEME] = ascii_bytes(
+                    _ACL_SCHEME)
+                c += 8 + _ACL_SCHEME
+                be(full(_ACL_ID), 4, v[:, c:c + 4])
+                v[:, c + 4:c + 4 + _ACL_ID] = ascii_bytes(_ACL_ID)
+                c += 4 + _ACL_ID
+            write_stat(c, zxid)
+        elif kind == 'notif':
+            be(ri(1, 5), 4, v[:, p:p + 4])          # type: valid enum
+            be(full(3), 4, v[:, p + 4:p + 8])       # SYNC_CONNECTED
+            be(full(_NOTIF_PATH), 4, v[:, p + 8:p + 12])
+            v[:, p + 12] = ord('/')
+            v[:, p + 13:p + 12 + _NOTIF_PATH] = ascii_bytes(
+                _NOTIF_PATH - 1)
+        # 'ping' / 'data_err': header-only bodies, nothing more
+        o += 4 + _BODY_LEN[kind]
+    return v
 
 
 def kernel_checks(cfg: dict, on_chip: bool, corpus) -> dict:

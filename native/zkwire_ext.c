@@ -1,11 +1,11 @@
 /* zkwire_ext: CPython-extension decoder for the per-connection receive
  * hot path.
  *
- * Why this exists (see tools/profile_hotpath.py for the numbers): the
- * pure-Python scalar decode of a GET_DATA reply stream runs at ~15-25
- * MiB/s, and >90% of that time is jute primitive reads — per-field
- * struct.unpack_from calls, bounds checks, and dict/dataclass plumbing
- * in zkstream_tpu/protocol/{jute,records}.py.  Framing alone is cheap
+ * Why this exists: the pure-Python scalar decode of a GET_DATA reply
+ * stream spends nearly all of its time in jute primitive reads —
+ * per-field struct.unpack_from calls, bounds checks, and
+ * dict/dataclass plumbing in
+ * zkstream_tpu/protocol/{jute,records}.py.  Framing alone is cheap
  * (the plain-C-ABI scanner in zkwire.cpp covers it), so the profitable
  * native boundary is the *whole* receive transform: accumulated bytes
  * -> list of packet dicts, in one C pass.  That is the same span the

@@ -199,25 +199,6 @@ async def test_trce_word_dumps_member_ring(server):
         await c.close()
 
 
-async def test_trce_word_with_trace_disabled():
-    """A server with the trace plane off still answers trce (empty
-    ring) — scrapes must not error on an untraced member."""
-    import json
-
-    from zkstream_tpu.server import ZKServer
-
-    srv = await ZKServer(trace=False).start()
-    try:
-        assert srv.trace is None and srv.ledger is None
-        dump = json.loads(await _four_letter(srv, b'trce'))
-        assert dump['spans'] == [] and dump['dropped'] == 0
-        # and mntr omits the ledger rows rather than lying
-        text = (await _four_letter(srv, b'mntr')).decode()
-        assert 'zk_tick_count' not in text
-    finally:
-        await srv.stop()
-
-
 async def test_mntr_follower_mode_in_ensemble():
     from zkstream_tpu.server import ZKEnsemble
 

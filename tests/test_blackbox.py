@@ -172,6 +172,9 @@ async def test_server_slow_op_digest_persists_causal_chain(
     the counter moves, mntr reports it, and the box holds slow_op
     frames carrying the offending span plus its zxid chain."""
     monkeypatch.setenv('ZKSTREAM_SLOW_OP_MS', '0.0001')
+    # slow_op frames and the final one only: no cadence frame can be
+    # appended between the drain this test waits for and the stop
+    monkeypatch.setenv('ZKSTREAM_BLACKBOX_MS', '60000')
     from zkstream_tpu.server import ZKServer
     from zkstream_tpu.utils.metrics import Collector
 
@@ -195,9 +198,15 @@ async def test_server_slow_op_digest_persists_causal_chain(
         # every counted slow op observed the threshold histogram
         assert srv.blackbox._hist is not None
         assert srv.blackbox._hist.count() == srv.blackbox.slow_ops
+        # frames are written behind the loop, one write in flight at a
+        # time; stop() does not wait for that write, so a frame still
+        # in flight would land after the final one: wait until every
+        # frame the recorder counted is on disk
+        member = list_boxes(d)[0]
+        await wait_until(lambda: len(read_box(d, member)['frames'])
+                         == srv.blackbox.frames)
     finally:
         await srv.stop()
-    member = list_boxes(d)[0]
     box = read_box(d, member)
     assert box['status'] == 'ok'     # clean stop: no torn tail
     slow = [f for f in box['frames'] if f['kind'] == 'slow_op']

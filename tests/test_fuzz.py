@@ -13,40 +13,74 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from zkstream_tpu.protocol.errors import ZKProtocolError
+from zkstream_tpu.protocol.errors import (
+    ZKFrameTooLargeError,
+    ZKProtocolError,
+)
 from zkstream_tpu.protocol.records import Stat
 from zkstream_tpu.protocol.framing import FrameDecoder, PacketCodec
 from zkstream_tpu.protocol.jute import JuteReader, JuteWriter
 from zkstream_tpu.utils import native
 
 
+def _first_framing_fault(junk: bytes, cap: int):
+    """Walk ``junk`` as the framer does: the code the first invalid
+    length prefix must raise (None when every prefix is valid or
+    incomplete) and how many whole frames stand before it — a body
+    among those may fail to decode first."""
+    off = frames = 0
+    while len(junk) - off >= 4:
+        (ln,) = struct.unpack_from('>i', junk, off)
+        if ln < 0:
+            return 'BAD_LENGTH', frames
+        if ln > cap:
+            return 'FRAME_TOO_LARGE', frames
+        if len(junk) - off < 4 + ln:
+            break
+        off += 4 + ln
+        frames += 1
+    return None, frames
+
+
+def _assert_junk_contract(codec, junk):
+    """Arbitrary bytes into a codec: packets out or a typed
+    ZKProtocolError — ``FRAME_TOO_LARGE`` exactly when the first
+    invalid prefix declares more than the codec's cap (and carries the
+    length and the cap), ``BAD_LENGTH`` when it is negative,
+    ``BAD_DECODE`` only for a whole frame's body — nothing else."""
+    fault, frames = _first_framing_fault(junk, codec._max_frame)
+    try:
+        pkts = codec.decode(junk)
+    except ZKProtocolError as e:
+        assert isinstance(getattr(e, 'packets', []), list)
+        if e.code == 'BAD_DECODE':
+            assert frames > 0
+        else:
+            assert e.code == fault
+            if fault == 'FRAME_TOO_LARGE':
+                assert isinstance(e, ZKFrameTooLargeError)
+                assert e.length > e.cap == codec._max_frame
+    else:
+        assert isinstance(pkts, list)
+        # an invalid prefix with no frame before it cannot pass
+        assert fault is None or frames > 0
+
+
 @settings(max_examples=120, deadline=None)
 @given(st.binary(max_size=400),
        st.lists(st.integers(1, 64), max_size=8))
 def test_codec_decode_junk_contract(junk, xids):
-    """Arbitrary bytes into the steady-state codec: packets out or
-    ZKProtocolError (BAD_LENGTH / BAD_DECODE), nothing else."""
     codec = PacketCodec()
     codec.handshaking = False
     for x in xids:
         codec.xid_map[x] = 'GET_DATA'
-    try:
-        pkts = codec.decode(junk)
-    except ZKProtocolError as e:
-        assert e.code in ('BAD_LENGTH', 'BAD_DECODE')
-        assert isinstance(getattr(e, 'packets', []), list)
-    else:
-        assert isinstance(pkts, list)
+    _assert_junk_contract(codec, junk)
 
 
 @settings(max_examples=120, deadline=None)
 @given(st.binary(max_size=400))
 def test_handshake_decode_junk_contract(junk):
-    codec = PacketCodec()
-    try:
-        codec.decode(junk)
-    except ZKProtocolError as e:
-        assert e.code in ('BAD_LENGTH', 'BAD_DECODE')
+    _assert_junk_contract(PacketCodec(), junk)
 
 
 @settings(max_examples=100, deadline=None)

@@ -473,10 +473,9 @@ async def test_oversized_device_body_falls_back_to_scalar_reader():
 
 
 async def test_fragmentation_guard_enters_and_exits():
-    """The upper dispatch guard (CROSSOVER.md's 1,024-conn losing
-    regime): a large fleet whose ticks are sparse routes to the scalar
-    drain; when ticks become batches again the device path resumes —
-    with hysteresis in between."""
+    """The upper dispatch guard: a large fleet whose ticks are sparse
+    routes to the scalar drain; when ticks become batches again the
+    device path resumes — with hysteresis in between."""
     # the guard must be requested explicitly here: bypass_bytes=0
     # auto-disables it (force-device means force-device)
     ing = mk_ingest(frag_guard=True)   # bypass_bytes=0, warm='block'
@@ -574,8 +573,8 @@ async def test_direct_and_batch_regimes_deliver_identically():
 async def test_force_device_auto_disables_frag_guard():
     """bypass_bytes=0 promises every tick on the device pipeline
     (tests, benchmarks); under frag_guard auto (the default) that
-    promise now extends to the fragmentation guard (r4 advisor
-    finding: sweep_crossover had to pass frag_guard=False by hand)."""
+    promise extends to the fragmentation guard: no caller has to pass
+    frag_guard=False by hand."""
     assert mk_ingest().frag_guard is False          # bypass_bytes=0
     assert mk_ingest(frag_guard=True).frag_guard is True   # pinned
     assert FleetIngest().frag_guard is True         # production default

@@ -16,8 +16,7 @@ Four pillars:
 4. **Scale smoke** — 1k sessions against one in-process server,
    inside the tier-1 budget.
 
-Every test skips cleanly when the host has no C compiler (the same
-graceful degradation the bench families use).
+Every test skips cleanly when the host has no C compiler.
 """
 
 import asyncio

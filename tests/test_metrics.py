@@ -269,7 +269,7 @@ def test_histogram_percentile_interpolation():
     """Histogram percentiles interpolate inside the bucket that holds
     the rank (the histogram_quantile rule), clamp at the largest
     finite bound for +Inf samples, and NaN on empty series — the
-    estimator bench.py publishes per-op p50/p99 through."""
+    estimator behind every ``_p99`` row of ``mntr``."""
     import math
 
     from zkstream_tpu.utils.metrics import Histogram
@@ -330,9 +330,10 @@ def test_tick_ledger_nested_phases_subtract():
 
 def test_tick_ledger_phase_p99_and_scrape():
     from zkstream_tpu.utils.metrics import (
+        METRIC_TICK,
+        METRIC_TICK_PHASE,
         Collector,
         TickLedger,
-        scrape_tick_cells,
     )
 
     col = Collector()
@@ -344,12 +345,9 @@ def test_tick_ledger_phase_p99_and_scrape():
     assert led.ticks == 4
     assert led.phase_p99('cork_flush') is not None
     assert led.phase_p99('fanout_flush') is None
-    cells = scrape_tick_cells(col)
-    assert cells['ticks'] == 4
-    assert 'cork_flush' in cells['phases']
-    ph = cells['phases']['cork_flush']
-    assert ph['count'] == 4
-    assert 0.0 <= ph['share'] <= 1.0
+    assert col.get_collector(METRIC_TICK).count() == 4
+    assert col.get_collector(METRIC_TICK_PHASE).count(
+        {'phase': 'cork_flush'}) == 4
 
 
 async def test_tick_ledger_coalesces_spilled_callbacks():

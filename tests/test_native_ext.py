@@ -2,8 +2,7 @@
 pure-Python codec, which is the semantic spec.
 
 The extension covers the steady-state client receive path — framing +
-reply-body decode in one native pass (the boundary the profile in
-tools/profile_hotpath.py justifies).  Every test drives both
+reply-body decode in one native pass.  Every test drives both
 implementations over identical bytes and asserts identical packets,
 identical buffer state, and identical error behavior, including the
 lossy corners (frames sharing a chunk with a bad frame).

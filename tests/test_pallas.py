@@ -145,7 +145,7 @@ def test_vmem_guard_refuses_what_mosaic_refuses():
     assert fits_vmem(8192, 6144, 64, 64, V5E)
     assert fits_vmem(4096, 4096, 32, 64, V5E)
     assert not fits_vmem(8192, 8192, 32, 64, V5E)
-    # bench.py's corpus row does not fit one program
+    # a 16,384-connection corpus row does not fit one program
     assert not fits_vmem(16384, 15792, 64, 64, V5E)
     # rows/program double with block_rows 256: half the length fits
     assert fits_vmem(256, 2048, 48, 128, V5E)
@@ -208,7 +208,7 @@ def test_auto_dispatch_routes_by_platform_and_shape(monkeypatch):
     )
     from zkstream_tpu.utils import platform
 
-    # the recorded win pocket (tools/sweep_pallas.py)
+    # the recorded win pocket
     assert _pallas_pocket(8192, 64)
     assert not _pallas_pocket(8192, 8)       # frame-sparse: jnp
     assert not _pallas_pocket(2048, 64)      # small fleet: jnp

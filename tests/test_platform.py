@@ -1,7 +1,7 @@
 """The device contract of utils/platform.py and its callers: where JAX
 work lands (``force_cpu`` / ``target_device``), that a measurement
-path without a chip fails before it prints (``require_accelerator``,
-``bench.py``), and that the compile cache can be placed from outside
+path without a chip fails before it prints (``require_accelerator``),
+and that the compile cache can be placed from outside
 (``enable_compile_cache``)."""
 
 from __future__ import annotations
@@ -130,29 +130,6 @@ def test_require_accelerator_returns_the_stamp(monkeypatch):
     assert platform.require_accelerator() == stamp
 
 
-def test_bench_default_mode_exits_nonzero_on_cpu_before_any_metric():
-    """``python bench.py`` on a machine whose JAX finds no accelerator:
-    non-zero exit, nothing on stdout (every result line goes there),
-    and the reason on stderr — never a CPU number under a device
-    metric's name."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, 'bench.py')], cwd=REPO,
-        env=_clean_env(JAX_PLATFORMS='cpu'), capture_output=True,
-        text=True, timeout=120)
-    assert out.returncode != 0
-    assert out.stdout == ''
-    assert 'no accelerator' in out.stderr
-    assert 'metric' not in out.stderr
-
-
-def test_bench_has_no_child_device_probe():
-    """Nothing in bench.py may enumerate devices in a child and then
-    need the chip in the parent: a chip belongs to one process."""
-    src = open(os.path.join(REPO, 'bench.py')).read()
-    assert 'jax.devices()' not in src
-    assert 'bounded_probe' not in src
-
-
 # -- the compile cache --
 
 _CACHE_CODE = (
@@ -229,21 +206,3 @@ def test_entry_keeps_example_args_on_host():
     for a in args:
         assert type(a).__module__ == 'numpy', \
             ('example arg eagerly placed on a device', type(a))
-
-
-def test_tools_pin_cpu_before_first_jax_use():
-    """The host-path diagnostic tools must call force_cpu at import
-    top level, before anything can touch the default backend: a chip
-    belongs to one process, and a host-path sweep has no business
-    holding it.  (tools/sweep_pallas.py is the opposite by design — it
-    refuses to run without an accelerator.)"""
-    for tool in ('diag_ingest.py', 'sweep_crossover.py'):
-        src = open(os.path.join(REPO, 'tools', tool)).read()
-        assert 'force_cpu(' in src, f'{tool} does not pin a platform'
-        pin = src.index('force_cpu(')
-        for needle in ('import jax', 'jnp.', 'jax.devices'):
-            used = src.find(needle)
-            assert used == -1 or used > pin, \
-                f'{tool} touches jax before pinning the platform'
-    src = open(os.path.join(REPO, 'tools', 'sweep_pallas.py')).read()
-    assert 'require_accelerator()' in src

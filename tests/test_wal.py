@@ -17,6 +17,8 @@ from zkstream_tpu.protocol.consts import CreateFlag, Perm
 from zkstream_tpu.protocol.records import ACL, OPEN_ACL_UNSAFE, Id
 from zkstream_tpu.server.persist import (
     MAGIC_SEGMENT,
+    METRIC_APPEND_BYTES,
+    METRIC_FSYNC,
     WriteAheadLog,
     crc32c,
     decode_entry,
@@ -539,9 +541,8 @@ async def test_wal_metrics_exposition(tmp_path):
     assert 'zkstream_wal_append_bytes_count' in text
     assert 'zkstream_wal_segments 1' in text
     assert 'zkstream_wal_last_index 1' in text
-    from zkstream_tpu.server.persist import scrape_wal_cells
-    cells = scrape_wal_cells(collector)
-    assert cells['fsyncs'] >= 1 and cells['appends'] == 1
+    assert collector.get_collector(METRIC_FSYNC).count() >= 1
+    assert collector.get_collector(METRIC_APPEND_BYTES).count() == 1
     db.wal.close()
 
 

@@ -368,22 +368,3 @@ async def test_ensemble_chaos_slice_watchtable_disabled(monkeypatch):
 
 # (The default-on guards live beside the campaigns they protect:
 # tests/test_chaos.py and tests/test_chaos_ensemble.py.)
-
-
-# -- the 100k campaign (slow: scale proof, kept out of tier-1) ----------
-
-@pytest.mark.slow
-@pytest.mark.timeout(600)
-async def test_100k_watcher_fanout_campaign():
-    """100k sessions on one box, every one watching the hot path: the
-    fan-out completes, delivers exactly once per subscriber, and the
-    maintained count stays exact — the serving-plane scale target."""
-    import bench
-
-    col = Collector()
-    r = await bench.fanout_cell(100000, 100000, table=True,
-                                events=3, collector=col)
-    assert r['events'] == 3
-    fr = col.get_collector(METRIC_FLUSH_FRAMES)
-    # every subscriber of every event got exactly one frame
-    assert fr.sum({'plane': 'fanout'}) == 300000.0

@@ -1,14 +1,12 @@
 /* zkloadgen — raw-socket C load generator for the zkstream wire
  * protocol (tools/loadgen.c; README "Load generation").
  *
- * Every server-side ceiling the bench families used to report was the
- * CLIENT's: 8 Python worker processes decode ~9k replies/s each, so
- * `bench-read` topped out at ~75-89k reads/s however many observers
- * served (PROFILE.md round 15 carry).  This program is the measuring
- * instrument that removes the instrument from the measurement: it
- * drives the real wire protocol (handshake, ping, get/exists/list,
- * create/set, watch arm, SET_WATCHES) at hardware speed while doing
- * ONLY what correctness requires per reply in C:
+ * A Python client decodes a few thousand replies a second per
+ * process, so a server driven by one is measured at the client's
+ * ceiling.  This program removes the instrument from the
+ * measurement: it drives the real wire protocol (handshake, ping,
+ * get/exists/list, create/set, watch arm, SET_WATCHES) at hardware
+ * speed while doing ONLY what correctness requires per reply in C:
  *
  *   - frame split + 16-byte header decode (xid / zxid / err);
  *   - per-session **zxid floor checking** — a reply carrying a zxid
@@ -39,18 +37,17 @@
  *
  * Phases (any subset, driven by flags):
  *   connect ramp (--ramp hs/s: handshake storms are a WORKLOAD, not
- *   an accident) -> optional stdio sync (READY/GO, the read_worker
- *   protocol) -> optional watch arm -> steady window (--mix op
+ *   an accident) -> optional stdio sync (print READY, wait for GO
+ *   on stdin) -> optional watch arm -> steady window (--mix op
  *   weights | --count parity mode | --idle-ping keepalive-only) ->
  *   optional fan-out rounds (one writer, every session a watcher) ->
  *   optional SET_WATCHES re-arm storm (the post-failover shape) ->
- *   drain -> one JSON summary line on stdout (bench.py cell schema).
+ *   drain -> one JSON summary line on stdout.
  *
  * Built by zkstream_tpu/utils/native.py (build_loadgen) with the
- * same graceful skip-when-no-compiler discipline as zkwire_ext; the
- * Python read workers stay as the env-gated validator arm
- * (ZKSTREAM_LOADGEN=py), cross-checked for op-count / zxid parity in
- * tests/test_loadgen.py.
+ * same graceful skip-when-no-compiler discipline as zkwire_ext;
+ * cross-checked against the Python client for op-count / zxid
+ * parity in tests/test_loadgen.py.
  */
 
 #define _GNU_SOURCE
@@ -176,7 +173,7 @@ typedef struct {
     int ensure_path;         /* CREATE the hot path first */
     int session_timeout_ms;
     double connect_timeout_s;
-    int stdio_sync;          /* READY/GO protocol with the bench */
+    int stdio_sync;          /* READY/GO protocol with the driver */
     int src_addrs;           /* 127.0.0.x spread (0 = auto) */
     int close_sessions;      /* CLOSE_SESSION before closing sockets */
     double drain_s;

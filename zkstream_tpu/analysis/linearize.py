@@ -74,8 +74,8 @@ _WRITES = frozenset(('create', 'set', 'set_data', 'delete', 'multi'))
 
 #: Default node budget for one component's search.  The per-key
 #: partition + zxid pruning keep real campaign histories orders of
-#: magnitude under this (tools/bench_linearize.py guards the cost);
-#: hitting it is reported as its own violation, never silent.
+#: magnitude under this; hitting it is reported as its own violation,
+#: never silent.
 MAX_NODES = 250_000
 
 
@@ -314,8 +314,7 @@ def _search(ops: list[IntervalOp], finals: dict | None,
     exists, else a dict describing the deepest stuck point (or the
     exhausted budget).
 
-    Two prunings keep this flat on real histories (``make
-    bench-linearize`` guards the cost):
+    Two prunings keep this flat on real histories:
 
     - **zxid order**: completed-ok writes are leader-sequenced, so
       only the one with the minimal remaining zxid may linearize

@@ -87,8 +87,8 @@ async def _run(args) -> int:
                   'python': False, 'ingest': None}[args.codec]
     ingest = None
     if args.codec == 'ingest':
-        # the batched device plane with its production defaults
-        # (measured bypass crossover, background warm) — CROSSOVER.md
+        # the batched device plane with its shipped defaults
+        # (byte-threshold bypass, background warm)
         from .io.ingest import FleetIngest
         ingest = FleetIngest(body_mode='host')
     client = Client(servers=args.server,
@@ -923,8 +923,7 @@ async def _timeline(args) -> int:
         await asyncio.sleep(0.05)
         rings = {'client': client.trace.dump()}
         for s in ens.servers:
-            if s.trace is not None:
-                rings['member:%s' % (s.member,)] = s.trace.dump()
+            rings['member:%s' % (s.member,)] = s.trace.dump()
         merged = merge_timelines(rings)
         if args.as_json:
             print(_json.dumps({'trace_schema': TRACE_SCHEMA,

@@ -1012,9 +1012,8 @@ async def run_schedule(seed: int, ops: int = 6,
         _note_open_spans(res, client.trace)
         # dump after teardown so close-phase errors are captured too
         res.trace = client.trace.dump()
-        if srv.trace is not None:
-            res.member_rings = {
-                'member:%s' % (srv.member,): srv.trace.dump()}
+        res.member_rings = {
+            'member:%s' % (srv.member,): srv.trace.dump()}
         for key, spans in salvaged.items():
             res.member_rings.setdefault(key, spans)
 
@@ -2176,7 +2175,7 @@ async def run_ensemble_schedule(seed: int, ops: int = 12,
         res.trace = client.trace.dump()
         res.member_rings = {
             'member:%s' % (s.member,): s.trace.dump()
-            for s in ens.servers if s.trace is not None}
+            for s in ens.servers}
         # harvested black boxes fill only the gaps: a live member's
         # ring dump is fresher than its on-disk frames
         for key, spans in salvaged.items():
@@ -2755,7 +2754,7 @@ async def run_concurrent_schedule(seed: int, ops: int = 12,
         res.trace = cls[0].trace.dump()
         res.member_rings = {
             'member:%s' % (s.member,): s.trace.dump()
-            for s in ens.servers if s.trace is not None}
+            for s in ens.servers}
         for key, spans in salvaged.items():
             res.member_rings.setdefault(key, spans)
         res.history = list(h.records)

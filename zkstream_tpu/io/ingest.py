@@ -52,8 +52,7 @@ subsequent ticks run the device program.  ``warm='block'`` compiles
 inline on first use — deterministic, for tests and one-shot tools —
 and :meth:`prewarm` lets benchmarks/servers pay the compile up front.
 This is what bounds the ingest latency tail: the worst tick costs
-max(scalar drain, steady device tick), never a compile
-(measured: tools/diag_ingest.py).
+max(scalar drain, steady device tick), never a compile.
 
 **Every diversion is visible.**  A tick that did not run the device
 program is counted by the path it took (``ticks_scalar`` /
@@ -176,8 +175,9 @@ class FleetIngest:
       log: parent logger.
     """
 
-    #: Fragmentation-guard calibration (CROSSOVER.md, 1,024-conn
-    #: cells): engage only for fleets at least this large...
+    #: Fragmentation-guard calibration (fitted on a host-CPU backend
+    #: at 1,024 connections, never on the chip: ROADMAP D2): engage
+    #: only for fleets at least this large...
     FRAG_MIN_FLEET = 600
     #: ...entering scalar routing when the frames-per-tick EMA drops
     #: below ENTER x fleet size (ticks stopped being batches), leaving
@@ -225,12 +225,9 @@ class FleetIngest:
         #: every tick onto the device pipeline (tests, benchmarks) —
         #: including disabling the fragmentation guard, which would
         #: otherwise still divert >=600-connection fragmented fleets
-        #: to the scalar drain.  Default 16 KiB = the measured parity
-        #: point (~128
-        #: connections x ~135 B frames, CROSSOVER.md): below it the
-        #: scalar drain wins outright; above it the device path is
-        #: free e2e and adds the stats plane + device bodies +
-        #: offload.
+        #: to the scalar drain.  Default 16 KiB (~128 connections x
+        #: ~135 B frames) is where the two paths met on a host-CPU
+        #: backend; the chip has not re-fitted it (ROADMAP D2).
         self.bypass_bytes = bypass_bytes
         #: Where the tick's XLA program runs.  A tick is latency-bound
         #: (one dispatch + one readback inside the event loop), so
@@ -277,11 +274,11 @@ class FleetIngest:
         #: ticks routed to the scalar drain by the fragmentation guard
         self.ticks_frag = 0
         self.frames_routed = 0
-        #: Upper dispatch guard (CROSSOVER.md: at 1,024 desynchronized
-        #: connections the tick batches fragment to ~16% fill and the
-        #: batched path loses ~37% to the per-socket C drain — the
-        #: measured losing regime the byte threshold cannot see,
-        #: because fragmented mega-fleets still clear 16 KiB/tick).
+        #: Upper dispatch guard: when a large fleet's connections
+        #: desynchronize, the tick batches fragment (a small share of
+        #: the slots hold a frame) and the per-socket drain is the
+        #: cheaper path — a regime the byte threshold cannot see,
+        #: because fragmented mega-fleets still clear 16 KiB/tick.
         #: An EMA of frames routed per tick, compared against the
         #: registered fleet size with hysteresis, routes those ticks
         #: back to the scalar drain.  Auto (None): enabled only with a
@@ -903,9 +900,8 @@ class FleetIngest:
     def _frag_guarded(self) -> bool:
         """The upper dispatch guard: True routes this tick to the
         scalar drain because the fleet is large but its ticks are
-        fragmented (frames/tick ≪ fleet size — the measured losing
-        regime, CROSSOVER.md).  Hysteresis keeps the router from
-        flapping on tick noise."""
+        fragmented (frames/tick ≪ fleet size).  Hysteresis keeps the
+        router from flapping on tick noise."""
         if not self.frag_guard:
             return False
         n = len(self._slots)

@@ -3,10 +3,8 @@ drain beneath the unchanged request-dispatch path.
 
 The send path leaves the kernel as one submission chain per corked
 tick (io/transport.py), but ingress was still ONE ``asyncio.start_server``
-loop doing one ``reader.read()`` task wakeup per connection per tick —
-and the tick ledger (PR 7, PROFILE.md "Where a busy tick goes") says
-decode+dispatch eats the majority of every busy tick at every
-write-heavy fleet size.  At 10k+ live sessions the per-connection
+loop doing one ``reader.read()`` task wakeup per connection per
+tick.  At 10k+ live sessions the per-connection
 stream machinery (protocol ``data_received`` → ``StreamReader`` feed →
 task wakeup → ``read()`` copy) is the real ceiling: O(connections)
 Python-level wakeups and buffer hops per tick before a single request
@@ -86,8 +84,7 @@ O(1) enters on uring, one per ``read()`` on the validator) and
 ``zookeeper_recv_drain_depth`` histograms connections covered per
 batched drain (the O(dirty-shards)-submissions-per-tick number).
 ``mntr`` reports ``zk_ingress_shards`` / ``zk_ingress_backend`` and a
-per-shard connection census.  Scraped by ``bench.py --ingress``
-(`make bench-ingress`).
+per-shard connection census.
 """
 
 from __future__ import annotations
@@ -734,16 +731,14 @@ class IngressPlane:
             fds.append(conn._rx_fd)
         if not fds:
             return
+        # the tick's rx_drain phase: kernel-to-user time only
+        # (decode + dispatch lands in decode_apply inside feed)
         ledger = self.server.ledger
-        if ledger is not None:
-            # the tick's rx_drain phase: kernel-to-user time only
-            # (decode + dispatch lands in decode_apply inside feed)
-            ledger.enter('rx_drain')
+        ledger.enter('rx_drain')
         try:
             results, nsys, backend = self._drain_fds(fds)
         finally:
-            if ledger is not None:
-                ledger.exit()
+            ledger.exit()
         for conn in conns:
             conn._rx_skip = True
         self._skip_clear.extend(conns)
@@ -865,46 +860,3 @@ def make_plane(server, shards: int | None, backend: str | None,
         return None
     return IngressPlane(server, nshards, resolved,
                         collector=collector)
-
-
-def scrape_recv_cells(collector) -> dict:
-    """Summarize the receive-direction counters for bench cells
-    (bench.py --ingress): submissions by backend plus drain-depth
-    distribution — the rx sibling of the transport tier's syscall
-    scrape."""
-    out: dict = {}
-    try:
-        ctr = collector.get_collector(METRIC_RECV_SYSCALLS)
-    except ValueError:
-        ctr = None
-    if ctr is not None:
-        by_backend = {}
-        for key in ctr.label_keys():
-            labels = dict(key)
-            if labels.get('plane') == 'server':
-                by_backend[labels.get('backend', '?')] = \
-                    ctr.value(labels)
-        if by_backend:
-            out['recv_syscalls'] = by_backend
-    try:
-        dep = collector.get_collector(METRIC_RECV_DRAIN_DEPTH)
-    except ValueError:
-        dep = None
-    if dep is not None:
-        # every server-plane backend series (a uring plane latched
-        # down mid-cell reports under both tiers — the scrape must
-        # cover all of a cell's drains, like the syscalls scrape)
-        by_backend = {}
-        for key in dep.label_keys():
-            labels = dict(key)
-            if labels.get('plane') != 'server':
-                continue
-            n = dep.count(labels)
-            if n:
-                by_backend[labels.get('backend', '?')] = {
-                    'drains': n,
-                    'mean': round(dep.sum(labels) / n, 1),
-                    'p99': round(dep.percentile(99, labels), 1)}
-        if by_backend:
-            out['drain_depth'] = by_backend
-    return out

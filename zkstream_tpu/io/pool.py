@@ -175,11 +175,10 @@ class ReadPlane:
         self._resolver.on('changed', self._on_config_change)
 
     def summary(self) -> dict:
-        """Read-path accounting for bench/campaign reports: where
-        this client's reads actually went.  Reads the cache plane
+        """Read-path accounting for campaign reports: where this
+        client's reads actually went.  Reads the cache plane
         absorbed (README "Client cache plane") never reach this
-        plane at all, so they are reported alongside — the cached
-        arm of ``bench.py --read`` keys on exactly this split."""
+        plane at all, so they are reported alongside."""
         out = {'distributed': self.distributed,
                'bounced': self.bounced,
                'fallbacks': self.fallbacks,

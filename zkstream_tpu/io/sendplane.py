@@ -31,8 +31,7 @@ Observability: per-flush batch size lands in the
 ``zookeeper_flush_batch_frames`` / ``zookeeper_flush_batch_bytes``
 histograms (labelled ``plane="client"|"server"``; the watch table's
 per-shard fan-out flushes record under ``plane="fanout"``,
-server/watchtable.py), scraped by bench.py write-heavy cells,
-``bench.py --fanout`` and tools/sweep_crossover.py.
+server/watchtable.py).
 
 ``ZKSTREAM_NO_CORK=1`` (or ``cork=False`` on Client / ZKServer)
 degrades to write-through — every frame still flows through the plane
@@ -341,29 +340,3 @@ class SendPlane:
         if self._syscall_ctr is not None:
             self._syscall_ctr.increment(
                 {'plane': self._labels['plane'], 'backend': 'asyncio'})
-
-
-def scrape_flush_cells(collector) -> dict:
-    """Summarize the flush-batch histograms per plane for bench cells
-    (bench.py client_ops, tools/sweep_crossover.py): flush count,
-    mean/p50/p99 frames per flush, p50/p99 bytes per flush."""
-    out: dict = {}
-    try:
-        fr = collector.get_collector(METRIC_FLUSH_FRAMES)
-        by = collector.get_collector(METRIC_FLUSH_BYTES)
-    except ValueError:
-        return out
-    for key in fr.label_keys():
-        labels = dict(key)
-        n = fr.count(labels)
-        if not n:
-            continue
-        out[labels.get('plane', '')] = {
-            'flushes': n,
-            'frames_mean': round(fr.sum(labels) / n, 2),
-            'frames_p50': round(fr.percentile(50, labels), 2),
-            'frames_p99': round(fr.percentile(99, labels), 2),
-            'bytes_p50': round(by.percentile(50, labels), 1),
-            'bytes_p99': round(by.percentile(99, labels), 1),
-        }
-    return out

@@ -70,8 +70,7 @@ tick on mmsg/asyncio, O(1) on uring) and ``zookeeper_submit_depth``
 histograms connections covered per batched submission.  Both are the
 tier's own series (registered with the ``collector`` it was built
 with; a shared client tier has none and every joined client's
-collector adopts them).  Scraped by ``bench.py --transport`` (`make
-bench-transport`).  Under a profiler session each tick is a host span
+collector adopts them).  Under a profiler session each tick is a host span
 ``<plane>.flush`` (utils/trace.host_span; count and total only):
 ``client.submit``'s count over ``client.flush``'s is requests per
 flush.

@@ -8,7 +8,7 @@ connected-state drain (lib/connection-fsm.js:213-229) once per
 connection, but as a single fused XLA computation with static shapes.
 
 This is the unit the driver compile-checks (see __graft_entry__.py) and
-the benchmark measures (bench.py).  Two equivalent implementations:
+the benchmark times as ``jit_step``.  Two equivalent implementations:
 ``wire_pipeline_step`` (pure jnp/lax — runs anywhere; the XLA scan
 gathers only the ~20 header bytes per frame, so it is the fast path on
 TPU v5e) and ``wire_pipeline_step_pallas`` (the scan + header parse
@@ -128,8 +128,7 @@ def getdata_bodies_jnp(buf, st: WireStats,
                        max_data: int) -> GetDataBodies:
     """The GET_DATA planes via the jnp body parser — the reference
     semantics the fused kernel must match, packaged as GetDataBodies:
-    the equal-work jnp candidate of tools/sweep_pallas.py and the
-    reference chip_smoke.py holds the kernel to."""
+    the reference chip_smoke.py holds the kernel to."""
     from . import replies as R
 
     frame_ok = (st.starts >= 0) & (st.sizes >= 16)
@@ -219,13 +218,12 @@ def wire_pipeline_step(buf, lens, max_frames: int = 32) -> WireStats:
 
 
 def _pallas_pocket(B: int, max_frames: int) -> bool:
-    """The shape region where the fused kernel was last measured ahead
-    of the jnp pipeline on a v5e (tools/sweep_pallas.py, block_rows=64,
-    on round-3 code and another installation: 1.20-1.24x at
-    (8192, 64), within a ±10 % noise band or behind everywhere else,
-    worst 0.78x at (32768, 8)) — so jnp is the default.  The table has
-    not been re-measured on the installed compiler; ROADMAP S9 does
-    that and keeps or deletes it.
+    """The shape region where the fused kernel (block_rows=64) was
+    once recorded ahead of the jnp pipeline on a v5e, on older code
+    and another installation; level or behind everywhere else, so jnp
+    is the default.  The table has not been re-measured on the
+    installed compiler and no benchmark cell reaches it; ROADMAP S10(b)
+    does that and keeps or deletes it.
 
     Caveat: under ``shard_map`` (parallel/fleet.py) ``B`` here is the
     per-shard LOCAL batch (global B / dp), while the pocket was

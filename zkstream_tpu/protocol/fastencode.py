@@ -1,5 +1,5 @@
 """Single-pass steady-state encoders — the send-side twin of the
-struct-batched decode (PROFILE.md).
+struct-batched decode.
 
 ``records.write_request`` / ``write_response`` walk a ``JuteWriter``
 one primitive at a time: ~10-15 Python-level calls and one

@@ -169,7 +169,7 @@ class JuteReader:
         precompiled big-endian ``struct.Struct`` whose layout is a
         concatenation of jute ints/longs — semantically identical to
         the per-field reads but one bounds check and one C call for
-        the whole run (the scalar decode hot path: see PROFILE.md)."""
+        the whole run (the scalar decode hot path)."""
         self._need(st.size)
         v = st.unpack_from(self._view, self._off)
         self._off += st.size

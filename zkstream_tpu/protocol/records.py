@@ -62,7 +62,7 @@ class Stat(typing.NamedTuple):
     A NamedTuple, not a dataclass: immutable and field-named either way,
     but tuple construction happens in C — the decode hot path builds one
     per stat-bearing reply, and a frozen dataclass pays ~11 Python-level
-    ``object.__setattr__`` calls each (see tools/profile_hotpath.py)."""
+    ``object.__setattr__`` calls each."""
 
     czxid: int = 0
     mzxid: int = 0

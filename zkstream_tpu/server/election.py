@@ -358,13 +358,12 @@ class ElectionCoordinator(EventEmitter):
             dur_ms = (time.perf_counter() - t0) * 1000.0
             if self._hist is not None:
                 self._hist.observe(dur_ms)
-            if srv.trace is not None:
-                srv.trace.note('ELECTION', kind='server',
-                               batch=len(votes), detail=reason,
-                               duration_ms=round(dur_ms, 3))
-                srv.trace.note('EPOCH_BUMP', zxid=self.db.zxid,
-                               kind='server',
-                               detail='epoch=%d' % (new_epoch,))
+            srv.trace.note('ELECTION', kind='server',
+                           batch=len(votes), detail=reason,
+                           duration_ms=round(dur_ms, 3))
+            srv.trace.note('EPOCH_BUMP', zxid=self.db.zxid,
+                           kind='server',
+                           detail='epoch=%d' % (new_epoch,))
             log.info('member %d elected leader at epoch %d (%s, '
                      '%d votes, %.1f ms)', win.member, new_epoch,
                      reason, len(votes), dur_ms)

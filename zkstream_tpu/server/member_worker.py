@@ -46,9 +46,9 @@ def main() -> int:
         sys.path.insert(0, root)
     from zkstream_tpu.server.election import run_member
 
-    # a read-plane member may serve up to a million sessions (`make
-    # bench-million`): lift the soft fd limit as far as the host
-    # allows, and name the binding constraint when it can't
+    # a read-plane member may serve up to a million sessions: lift
+    # the soft fd limit as far as the host allows, and name the
+    # binding constraint when it can't
     # (utils/fdlimit.py — ZKServer.start does the same against its
     # admission ceiling)
     from zkstream_tpu.utils import fdlimit

@@ -11,7 +11,7 @@ loads the newest valid snapshot and replays the log tail — tolerating
 a torn final record, the normal signature of dying mid-write.
 
 Group commit (the fsync policy) reuses the shape the outbound plane
-proved out (io/sendplane.py, PROFILE.md "Encode side"): one fsync per
+proved out (io/sendplane.py): one fsync per
 busy event-loop tick instead of one per append, with an ordering
 barrier so durability still *precedes* every ack:
 
@@ -1565,26 +1565,3 @@ def open_wal_database(path: str, *, sync: str = 'tick',
     restore_sessions(db, rec.sessions)
     reap_orphan_ephemerals(db)
     return db
-
-
-def scrape_wal_cells(collector) -> dict:
-    """Summarize the WAL histograms for bench cells (`bench.py --wal`):
-    fsync count + latency p50/p99, append count + bytes p50/p99."""
-    out: dict = {}
-    try:
-        fs = collector.get_collector(METRIC_FSYNC)
-        ap = collector.get_collector(METRIC_APPEND_BYTES)
-    except ValueError:
-        return out
-    n = fs.count()
-    if n:
-        out['fsyncs'] = n
-        out['fsync_p50_ms'] = round(fs.percentile(50), 3)
-        out['fsync_p99_ms'] = round(fs.percentile(99), 3)
-        out['fsync_mean_ms'] = round(fs.sum() / n, 3)
-    m = ap.count()
-    if m:
-        out['appends'] = m
-        out['append_p50_b'] = round(ap.percentile(50), 1)
-        out['append_p99_b'] = round(ap.percentile(99), 1)
-    return out

@@ -142,7 +142,8 @@ DEFAULT_QUORUM_WAIT_MS = 250.0
 def quorum_enabled() -> bool:
     """Global kill switch (mirrors ``ZKSTREAM_NO_WAL`` /
     ``ZKSTREAM_NO_ELECTION``): the fsync-only ack barrier stays
-    available as the A/B validator arm (``bench.py --quorum``)."""
+    available as the validator arm (and the benchmark's control that
+    must read ``correct: false``)."""
     return os.environ.get('ZKSTREAM_NO_QUORUM') != '1'
 
 

@@ -35,7 +35,7 @@ from ..io.sendplane import SendPlane
 from ..protocol.framing import PacketCodec, resolve_frame_cap
 from ..utils.aio import set_nodelay
 from ..utils.metrics import TickLedger
-from ..utils.trace import TRACE_SCHEMA, TraceRing, server_trace_default
+from ..utils.trace import TRACE_SCHEMA, TraceRing
 from .store import ReplicaStore, ZKDatabase, ZKOpError, ZKServerSession
 from .watchtable import WatchTable, watchtable_default
 
@@ -204,12 +204,10 @@ class ReadGate:
         dur_ms = (time.perf_counter() - t0) * 1000.0
         if self._hist is not None:
             self._hist.observe(dur_ms)
-        trace = self.server.trace
-        if trace is not None:
-            trace.note('READ_GATE', pkt.get('path'), zxid=floor,
-                       kind='server',
-                       detail='bounce' if bounced else 'block',
-                       duration_ms=round(dur_ms, 3))
+        self.server.trace.note(
+            'READ_GATE', pkt.get('path'), zxid=floor, kind='server',
+            detail='bounce' if bounced else 'block',
+            duration_ms=round(dur_ms, 3))
 
     def _drain(self) -> None:
         """Re-dispatch every parked read the member has caught up
@@ -656,8 +654,7 @@ class ServerConnection:
         # whole decode + dispatch burst (store apply and WAL
         # append included; nested sync/flush phases subtract)
         ledger = self.server.ledger
-        if ledger is not None:
-            ledger.enter('decode_apply')
+        ledger.enter('decode_apply')
         try:
             try:
                 pkts = self.codec.decode(data)
@@ -680,16 +677,16 @@ class ServerConnection:
                 # an inflight storm — one drain decoding a whole
                 # pipelined burst — pauses this connection's rx
                 ov.after_drain(self, len(pkts))
-            trace = self.server.trace
-            if trace is not None and pkts and not (
+            if pkts and not (
                     len(pkts) == 1
                     and pkts[0].get('opcode') == 'PING'):
                 # bare keepalive pings skip the ring: at fleet
                 # scale they are most batches, and recording
                 # them would wash the txn chains out of the
                 # bounded window (and cost a span per ping)
-                trace.note('SRV_DECODE', kind='server',
-                           batch=len(pkts), nbytes=len(data))
+                self.server.trace.note(
+                    'SRV_DECODE', kind='server',
+                    batch=len(pkts), nbytes=len(data))
             # Outstanding accounting is batch-scoped: a
             # pipelined read delivers N requests at once, and
             # every one is outstanding until its handler
@@ -716,8 +713,7 @@ class ServerConnection:
                 # the unhandled remainder from the gauge
                 self.server.outstanding -= remaining
         finally:
-            if ledger is not None:
-                ledger.exit()
+            ledger.exit()
         ov = self.server.overload
         if ov is not None and not self.closed:
             # the validator twin of the ingress drain's hard-watermark
@@ -1103,7 +1099,6 @@ class ZKServer:
                  watchtable: bool | None = None,
                  fanout_shards: int | None = None,
                  member: str | None = None,
-                 trace: bool | None = None,
                  transport: str | None = None,
                  flush_cap: int | None = None,
                  ingress_shards: int | None = None,
@@ -1148,31 +1143,11 @@ class ZKServer:
         self.member = member if member is not None else '0'
         #: The server-side trace plane (utils/trace.py): this member's
         #: bounded span ring plus the per-tick phase ledger
-        #: (utils/metrics.TickLedger).  None = process default
-        #: (``ZKSTREAM_NO_SERVER_TRACE=1`` disables), True/False
-        #: force — the A/B knob `bench.py --traceov` pairs on.
-        enabled_trace = (server_trace_default() if trace is None
-                         else trace)
-        self.trace = (TraceRing(MEMBER_RING_CAPACITY,
-                                member=self.member)
-                      if enabled_trace else None)
-        self.ledger = TickLedger(collector) if enabled_trace else None
-        if enabled_trace:
-            if self.store is self.db:
-                # leader/standalone member: the shared database's
-                # COMMIT spans, the WAL's append/fsync spans and its
-                # loop-blocking sync time all belong to this ring
-                self.db.trace = self.trace
-                wal = getattr(self.db, 'wal', None)
-                if wal is not None:
-                    wal.trace = self.trace
-                    wal.ledger = self.ledger
-            else:
-                # follower: the replica's APPLY spans land here (the
-                # RemoteReplicaStore of an OS-process follower
-                # included — same attribute)
-                self.store.trace = self.trace
-                self._wire_forward_ledger()
+        #: (utils/metrics.TickLedger).  Every member owns both: the
+        #: ``mntr`` tick rows and the ``trce`` word read them.
+        self.trace = TraceRing(MEMBER_RING_CAPACITY, member=self.member)
+        self.ledger = TickLedger(collector)
+        self._wire_trace()
         #: Outbound write coalescing for accepted connections
         #: (io/sendplane.py): None = process default, True/False force.
         self.cork = cork
@@ -1344,7 +1319,7 @@ class ZKServer:
                                           server=self,
                                           collector=collector)
                          if enabled_bb and bb_dir else None)
-        if self.blackbox is not None and self.trace is not None:
+        if self.blackbox is not None:
             # the slow-op digest: spans settled on this member's ring
             # at/over the threshold get their causal chain persisted
             self.trace.slow_ms = slow_op_ms()
@@ -1399,14 +1374,12 @@ class ZKServer:
                     c.close()
 
     #: Listen backlog: the asyncio default (100) drops handshakes
-    #: under a thundering herd of reconnects at fleet scale.  The old
-    #: default here (1024) was set against Python-client waves; the C
-    #: loadgen's measured handshake storms arrive faster than one
-    #: accept sweep drains, so the default now matches the kernel's
-    #: own clamp (``net.core.somaxconn``, 4096 on the profiled host —
-    #: anything above it is silently truncated anyway).  Override
-    #: with ``ZKSTREAM_LISTEN_BACKLOG``; PROFILE.md round 19 has the
-    #: wave numbers this was re-derived from.
+    #: under a thundering herd of reconnects at fleet scale, and the
+    #: C loadgen's handshake storms arrive faster than one accept
+    #: sweep drains, so the default is the kernel's own clamp
+    #: (``net.core.somaxconn`` — anything above it is silently
+    #: truncated anyway) and 1024 where that cannot be read.
+    #: Override with ``ZKSTREAM_LISTEN_BACKLOG``.
     BACKLOG = 1024
 
     @staticmethod
@@ -1461,9 +1434,8 @@ class ZKServer:
         bookkeeping half every shed path shares (the validator's
         :meth:`shed_client` below and the ingress plane's RST shed,
         io/ingress.py)."""
-        if self.trace is not None:
-            self.trace.note('OVERLOAD', kind='server',
-                            detail='shed:%s' % (reason,))
+        self.trace.note('OVERLOAD', kind='server',
+                        detail='shed:%s' % (reason,))
         if self.overload is not None:
             self.overload.count_shed(reason)
 
@@ -1629,13 +1601,30 @@ class ZKServer:
         ref = self.elections_ref
         return ref.elections if ref is not None else self.elections
 
-    def _wire_forward_ledger(self) -> None:
-        """An OS-process follower's database is the control channel
-        to the leader (server/replication.py RemoteLeader): the loop
-        time it parks in a forwarded RPC is this member's
-        ``forward_rpc`` tick phase."""
-        if hasattr(self.db, 'ledger'):
-            self.db.ledger = self.ledger
+    def _wire_trace(self) -> None:
+        """Point the storage this member serves from at its ring and
+        ledger (at construction, and again when :meth:`repoint` swaps
+        the storage)."""
+        if self.store is self.db:
+            # leader/standalone member: the shared database's
+            # COMMIT spans, the WAL's append/fsync spans and its
+            # loop-blocking sync time all belong to this ring
+            self.db.trace = self.trace
+            wal = getattr(self.db, 'wal', None)
+            if wal is not None:
+                wal.trace = self.trace
+                wal.ledger = self.ledger
+        else:
+            # follower: the replica's APPLY spans land here (the
+            # RemoteReplicaStore of an OS-process follower
+            # included — same attribute)
+            self.store.trace = self.trace
+            # an OS-process follower's database is the control
+            # channel to the leader (server/replication.py
+            # RemoteLeader): the loop time it parks in a forwarded
+            # RPC is this member's ``forward_rpc`` tick phase
+            if hasattr(self.db, 'ledger'):
+                self.db.ledger = self.ledger
 
     def repoint(self, db, store=None, role: str | None = None) -> None:
         """Leadership failover (server/election.py): swap this
@@ -1659,16 +1648,7 @@ class ZKServer:
         self.db.on('sessionExpired', self._on_session_expired)
         if self.watch_table is not None:
             self.watch_table.rebind_store(self.store)
-        if self.trace is not None:
-            if self.store is self.db:
-                self.db.trace = self.trace
-                wal = getattr(self.db, 'wal', None)
-                if wal is not None:
-                    wal.trace = self.trace
-                    wal.ledger = self.ledger
-            else:
-                self.store.trace = self.trace
-                self._wire_forward_ledger()
+        self._wire_trace()
         if role is not None:
             self.role = role
         else:
@@ -1829,10 +1809,16 @@ class ZKServer:
         # decomposition, README "Causal tracing"): tick count, each
         # phase's per-tick p99, and how often the bounded span ring
         # wrapped
-        tick_rows: list[tuple[str, object]] = []
-        if self.trace is not None:
-            tick_rows.append(('zk_trace_ring_dropped',
-                              self.trace.dropped))
+        tick_rows: list[tuple[str, object]] = [
+            ('zk_trace_ring_dropped', self.trace.dropped),
+            ('zk_tick_count', self.ledger.ticks),
+        ]
+        for phase in TickLedger.PHASES:
+            p99 = self.ledger.phase_p99(phase)
+            if p99 is not None:
+                tick_rows.append(
+                    ('zk_tick_phase_ms_p99{phase="%s"}' % (phase,),
+                     round(p99, 4)))
         # black-box plane rows (utils/blackbox.py): the slow-op count
         # is ALWAYS present (0 with the recorder off — the clean-
         # schedule invariant asserts on it either way); frame/byte
@@ -1852,14 +1838,6 @@ class ZKServer:
             [] if self.watch_table is None else
             [('zk_persistent_notifications',
               self.watch_table.persistent_sent)])
-        if self.ledger is not None:
-            tick_rows.append(('zk_tick_count', self.ledger.ticks))
-            for phase in TickLedger.PHASES:
-                p99 = self.ledger.phase_p99(phase)
-                if p99 is not None:
-                    tick_rows.append(
-                        ('zk_tick_phase_ms_p99{phase="%s"}' % (phase,),
-                         round(p99, 4)))
         return [
             ('zk_version', 'zkstream_tpu'),
             ('zk_uptime_ms',
@@ -1913,9 +1891,7 @@ class ZKServer:
         the rows before and after a window has that window's exact
         bucket counts, busy time and count by subtraction — what the
         ``_p99`` rows above, percentiles since start, cannot give."""
-        hists = []
-        if self.ledger is not None:
-            hists += [self.ledger.phase_hist, self.ledger.tick_hist]
+        hists = [self.ledger.phase_hist, self.ledger.tick_hist]
         q = self.quorum
         if q is not None and q.enabled:
             hists.append(q.ack_hist)
@@ -1947,10 +1923,8 @@ class ZKServer:
             return json.dumps({
                 'trace_schema': TRACE_SCHEMA,
                 'member': self.member,
-                'dropped': (0 if self.trace is None
-                            else self.trace.dropped),
-                'spans': ([] if self.trace is None
-                          else self.trace.dump()),
+                'dropped': self.trace.dropped,
+                'spans': self.trace.dump(),
             }) + '\n'
         if word in ('stat', 'srvr'):
             lines = ['Zookeeper version: zkstream_tpu (in-process)']

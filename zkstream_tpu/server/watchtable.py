@@ -44,7 +44,6 @@ identical notification streams.
 Observability: per-shard flush batches land in the shared
 ``zookeeper_flush_batch_frames`` / ``_bytes`` histograms labelled
 ``plane="fanout"``; shard-flush duration in ``zk_fanout_tick_ms``.
-Both are scraped by ``bench.py --fanout`` (`make bench-fanout`).
 
 Beneath the shard cork sits the batched-syscall transport tier
 (io/transport.py): each dirty connection's ``send_flush`` defers its

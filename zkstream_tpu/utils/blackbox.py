@@ -78,9 +78,8 @@ METRIC_SLOW_OP_MS = 'zookeeper_slow_op_ms'
 
 def blackbox_enabled() -> bool:
     """Process-wide default for the flight recorder.
-    ``ZKSTREAM_NO_BLACKBOX=1`` disables it — the off arm of the
-    paired overhead family (`bench.py --blackbox`), mirroring the
-    WAL/trace/watchtable kill switches."""
+    ``ZKSTREAM_NO_BLACKBOX=1`` disables it, mirroring the
+    WAL/watchtable kill switches."""
     return os.environ.get('ZKSTREAM_NO_BLACKBOX') != '1'
 
 

@@ -1,8 +1,7 @@
 """RLIMIT_NOFILE handling for million-session serving (README "Load
 generation").
 
-The first honest million-session campaign (PROFILE.md round 19) made
-fd limits a first-class failure mode instead of a mystery EMFILE
+fd limits are a first-class failure mode here, not a mystery EMFILE
 deep in accept(2): every server entry point lifts the soft limit as
 far as the host allows **at startup**, and when the host cap is the
 binding constraint the error says so by name — which limit, what it

@@ -1,46 +1,18 @@
-"""Driver glue for the C load generator (tools/loadgen.c).
-
-The bench families spawn ``zkloadgen`` instead of the Python read
-workers by default — the Python arm decodes ~9k replies/s per worker
-process, so every "server" ceiling it measured was actually the
-client's (PROFILE.md round 15 carry; round 19 re-baselines).  This
-module owns the build (via utils/native.py's graceful
-skip-when-no-compiler discipline) and the knob surface:
-
-- ``ZKSTREAM_LOADGEN``: ``c`` (default) drives benches with the C
-  loadgen; ``py`` keeps the Python worker validator arm.
-- ``ZKSTREAM_LOADGEN_THREADS``: epoll threads per loadgen process
-  (default: auto = min(cores, 8)).
-- ``ZKSTREAM_LOADGEN_PIPELINE``: outstanding ops per connection
-  (default 16; the million-session campaign uses 1).
-- ``ZKSTREAM_LOADGEN_RAMP``: handshakes/s for the connect wave
-  (default 0 = unpaced).
-- ``ZKSTREAM_LOADGEN_SRC_ADDRS``: loopback source addresses to spread
-  connections over (default 0 = auto: one per ~20k sessions, with
-  ``IP_BIND_ADDRESS_NO_PORT`` where the kernel has it) so a single
-  host can open ~1M sockets without exhausting one address's ~28k
-  ephemeral ports.
-
-All knobs are documented in README "Load generation"; the zkanalyze
-knob-drift baseline stays at zero.
+"""Driver glue for the C load generator (tools/loadgen.c): the build
+(utils/native.py's skip-when-no-compiler discipline) and the command
+line.  The generator speaks the wire protocol over raw sockets from
+epoll threads, so what it measures is the server and not a Python
+client; its flags and exit codes are in README "Load generation".
 """
 
 from __future__ import annotations
 
-import os
-
 from . import native
-
-
-def mode() -> str:
-    """``'c'`` (default) or ``'py'`` (the validator arm)."""
-    m = os.environ.get('ZKSTREAM_LOADGEN', 'c').strip().lower()
-    return 'py' if m == 'py' else 'c'
 
 
 def available() -> str | None:
     """Build (if needed) and return the binary path, or None when the
-    host has no compiler — callers fall back to the Python arm."""
+    host has no compiler."""
     return native.build_loadgen()
 
 
@@ -51,30 +23,27 @@ def argv(servers, sessions, *, duration=None, count=None, mix=None,
          session_timeout_ms=None, close_sessions=False,
          ensure_path=True, quiet=True, cached=False,
          cached_write_ms=None) -> list[str] | None:
-    """The zkloadgen command line for one run, env knobs applied.
-    Returns None when the binary can't be built."""
+    """The zkloadgen command line for one run; a keyword left at its
+    default leaves the binary's own default (threads: min(cores, 8);
+    pipeline: 16; ramp: unpaced; src_addrs: one loopback source
+    address per ~20k sessions).  Returns None when the binary can't
+    be built."""
     binary = available()
     if binary is None:
         return None
     cmd = [binary,
            '--servers', ','.join('%s:%d' % (h, p) for h, p in servers),
            '--sessions', str(int(sessions))]
-    env = os.environ.get
     if duration is not None:
         cmd += ['--duration', str(float(duration))]
     if count is not None:
         cmd += ['--count', str(int(count))]
     if mix:
         cmd += ['--mix', mix]
-    pipeline = pipeline if pipeline is not None else env(
-        'ZKSTREAM_LOADGEN_PIPELINE')
     if pipeline is not None:
         cmd += ['--pipeline', str(int(pipeline))]
-    threads = threads if threads is not None else env(
-        'ZKSTREAM_LOADGEN_THREADS')
     if threads is not None:
         cmd += ['--threads', str(int(threads))]
-    ramp = ramp if ramp is not None else env('ZKSTREAM_LOADGEN_RAMP')
     if ramp is not None:
         cmd += ['--ramp', str(float(ramp))]
     if idle_ping is not None:
@@ -91,8 +60,6 @@ def argv(servers, sessions, *, duration=None, count=None, mix=None,
         cmd += ['--data', str(int(data))]
     if stdio_sync:
         cmd += ['--stdio-sync']
-    src_addrs = src_addrs if src_addrs is not None else env(
-        'ZKSTREAM_LOADGEN_SRC_ADDRS')
     if src_addrs is not None:
         cmd += ['--src-addrs', str(int(src_addrs))]
     if session_timeout_ms is not None:

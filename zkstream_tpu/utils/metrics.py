@@ -353,7 +353,7 @@ class TickLedger:
 
     Works without a collector (mntr-only servers keep their own
     histograms); with one, the same histograms are registered for
-    scraping (``scrape_tick_cells`` summarizes them per bench cell).
+    scraping.
     """
 
     PHASES = ('rx_drain', 'decode_apply', 'fsync_gate', 'cork_flush',
@@ -466,59 +466,6 @@ class TickLedger:
         if not self.phase_hist.count(labels):
             return None
         return self.phase_hist.percentile(99, labels)
-
-
-def scrape_tick_cells(collector) -> dict:
-    """Summarize the tick ledger for bench cells (bench.py write-heavy
-    and fan-out families): tick count + wall-span p50/p99, and per
-    phase the per-tick p50/p99 plus ``share`` — the fraction of
-    ledgered tick time the phase ate, the number the accept-shard and
-    io_uring roadmap items are gated on."""
-    out: dict = {}
-    try:
-        th = collector.get_collector(METRIC_TICK)
-        ph = collector.get_collector(METRIC_TICK_PHASE)
-    except ValueError:
-        return out
-    n = th.count()
-    if not n:
-        return out
-    out['ticks'] = n
-    out['tick_ms_p50'] = round(th.percentile(50), 4)
-    out['tick_ms_p99'] = round(th.percentile(99), 4)
-    total = th.sum()
-    phases: dict = {}
-    for key in ph.label_keys():
-        labels = dict(key)
-        name = labels.get('phase', '')
-        c = ph.count(labels)
-        if not c:
-            continue
-        phases[name] = {
-            'count': c,
-            'ms_p50': round(ph.percentile(50, labels), 4),
-            'ms_p99': round(ph.percentile(99, labels), 4),
-            'share': round(ph.sum(labels) / total, 3) if total else 0.0,
-        }
-    if phases:
-        out['phases'] = phases
-    return out
-
-
-def sign_test_p(wins: int, losses: int) -> float:
-    """Two-sided exact sign test (ties dropped): the probability of a
-    split at least this lopsided under H0 = deltas symmetric around 0.
-    Shared by every paired A/B study (tools/sweep_crossover.py's cork
-    pairs, bench.py --wal's durability arms) so the published p-value
-    tables can never drift apart."""
-    import math
-
-    n = wins + losses
-    if n == 0:
-        return 1.0
-    k = min(wins, losses)
-    p = 2.0 * sum(math.comb(n, i) for i in range(k + 1)) / (2.0 ** n)
-    return min(1.0, p)
 
 
 class Collector:

@@ -7,7 +7,7 @@ binds it via ctypes.  Design constraints, in order:
   already-built artifact; when a build is needed it is kicked off on a
   daemon thread and ``get_lib()`` returns None until it lands, so the
   connection path runs pure-Python in the meantime.  Anything that
-  measures (bench.py, chip_smoke.py) calls the blocking ``ensure_*`` /
+  measures (benchmark/, chip_smoke.py) calls the blocking ``ensure_*`` /
   ``build_loadgen`` instead and fails on None.
 - **Stale artifacts can't poison the process.**  The artifact name
   embeds the ABI version and a hash of the source and compile flags
@@ -182,9 +182,8 @@ def ensure_lib(timeout: float = 120.0) -> ctypes.CDLL | None:
 #
 # Separate artifact from the C-ABI scanner: it links against the
 # interpreter ABI (Python.h), decodes whole accumulation buffers into
-# packet dicts (framing + reply bodies in one C pass — the boundary the
-# profile in tools/profile_hotpath.py points at), and is loaded with the
-# same version-named-artifact / background-build discipline.
+# packet dicts (framing + reply bodies in one C pass), and is loaded
+# with the same version-named-artifact / background-build discipline.
 
 _EXT_ABI_VERSION = 10
 
@@ -356,9 +355,8 @@ def ensure_ext():
 # -- C load generator (tools/loadgen.c) -------------------------------
 #
 # A standalone binary, not a shared library: it drives the real wire
-# protocol over raw sockets (the measuring instrument the bench
-# families spawn instead of the Python read workers — README "Load
-# generation").  Same discipline as the other two artifacts:
+# protocol over raw sockets (README "Load generation").  Same
+# discipline as the other two artifacts:
 # version- and source-hash-named output, atomic tmp+rename publish,
 # graceful None when the host has no compiler so `make check`/tier-1
 # never hard-fail on a codec-less image.
@@ -382,8 +380,8 @@ def loadgen_path() -> str:
 
 def build_loadgen() -> str | None:
     """Compile the load generator unless the binary for this source is
-    already there; return its path or None.  Synchronous (tools/bench
-    only, never the event loop)."""
+    already there; return its path or None.  Synchronous (tools and
+    tests only, never the event loop)."""
     src = loadgen_source_path()
     if not os.path.exists(src):
         return None

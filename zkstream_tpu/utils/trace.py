@@ -74,15 +74,6 @@ _OPTIONAL_FIELDS = ('path', 'xid', 'zxid', 'backend', 'session_id',
                     'parent', 'tick', 't0_ns', 't1_ns')
 
 
-def server_trace_default() -> bool:
-    """Process-wide default for the server-side trace plane (member
-    rings + tick ledger).  ``ZKSTREAM_NO_SERVER_TRACE=1`` disables it
-    — the untraced arm of the bench overhead A/B (`bench.py
-    --traceov`), mirroring the cork/WAL/watchtable kill switches."""
-    import os
-    return os.environ.get('ZKSTREAM_NO_SERVER_TRACE') != '1'
-
-
 class Span:
     """One traced operation: request-side fields stamped at creation,
     reply-side fields stamped on completion."""
