@@ -1153,30 +1153,20 @@ static PyObject *py_setup(PyObject *self, PyObject *args) {
   Py_RETURN_NONE;
 }
 
-/* shared frame walk: slice complete frames, decode each body via the
- * reply (xid_map != NULL) or request decoder, with the PacketCodec
- * error contract.  Consumes/releases `view`. */
-static PyObject *decode_stream(Py_buffer view, PyObject *xid_map,
-                               int max_packet) {
+/* shared frame walk: slice complete frames out of buf[0:len], decode
+ * each body via the reply (xid_map != NULL) or request decoder and
+ * append the packets to `pkts`, with the PacketCodec error contract:
+ * *kind stays NULL or names the error (a static string) with its text
+ * in msg[256]; *consumed is what the caller drops from its buffer.
+ * Holds no buffer export: the caller owns the bytes for the duration
+ * of the call.  -1 = a real exception is set (OOM etc.). */
+static int decode_span_into(const uint8_t *buf, Py_ssize_t len,
+                            PyObject *xid_map, int max_packet,
+                            PyObject *pkts, Py_ssize_t *consumed,
+                            const char **kind, char *msg) {
   const char *what = xid_map != NULL ? "Response" : "Request";
-  if (g_stat_cls == NULL) {
-    PyBuffer_Release(&view);
-    PyErr_SetString(PyExc_RuntimeError, "setup() not called");
-    return NULL;
-  }
-
-  const uint8_t *buf = (const uint8_t *)view.buf;
-  Py_ssize_t len = view.len;
-
-  PyObject *pkts = PyList_New(0);
-  if (pkts == NULL) {
-    PyBuffer_Release(&view);
-    return NULL;
-  }
-
-  const char *err_kind = NULL;
-  char err_msg[256] = {0};
-  Py_ssize_t consumed = 0;
+  *kind = NULL;
+  *consumed = 0;
 
   /* pass 1: frame boundaries (so a bad prefix drops earlier frames
    * exactly like FrameDecoder.feed raising mid-scan) */
@@ -1187,17 +1177,16 @@ static PyObject *decode_stream(Py_buffer view, PyObject *xid_map,
                            ((uint32_t)buf[off + 2] << 8) |
                            (uint32_t)buf[off + 3]);
     if (ln < 0 || ln > max_packet) {
-      err_kind = "BAD_LENGTH";
-      snprintf(err_msg, sizeof(err_msg), "Invalid ZK packet length %d",
-               ln);
-      consumed = off;
-      goto done;
+      *kind = "BAD_LENGTH";
+      snprintf(msg, 256, "Invalid ZK packet length %d", ln);
+      *consumed = off;
+      return 0;
     }
     if (len - off < 4 + (Py_ssize_t)ln) break;
     off += 4 + ln;
     end_of_frames = off;
   }
-  consumed = end_of_frames;
+  *consumed = end_of_frames;
 
   /* pass 2: decode each frame body */
   off = 0;
@@ -1210,41 +1199,53 @@ static PyObject *decode_stream(Py_buffer view, PyObject *xid_map,
     PyObject *pkt = xid_map != NULL ? decode_reply(&c, xid_map)
                                     : decode_request(&c);
     if (pkt == NULL) {
-      if (PyErr_Occurred()) { /* real exception (OOM etc.) */
-        Py_DECREF(pkts);
-        PyBuffer_Release(&view);
-        return NULL;
-      }
+      if (PyErr_Occurred()) return -1; /* real exception (OOM etc.) */
       if (c.unsupported) {
         /* valid frame, no layout in this tier: leave it (and
          * everything after it) in the buffer for the Python spec
          * tier — consumed stops at the frame boundary */
-        err_kind = "UNSUPPORTED";
-        snprintf(err_msg, sizeof(err_msg), "%s", c.err);
-        consumed = off;
-        goto done;
+        *kind = "UNSUPPORTED";
+        snprintf(msg, 256, "%s", c.err);
+        *consumed = off;
+        return 0;
       }
-      err_kind = "BAD_DECODE";
-      snprintf(err_msg, sizeof(err_msg), "Failed to decode %s: %s",
-               what, c.err);
-      goto done;
+      *kind = "BAD_DECODE";
+      snprintf(msg, 256, "Failed to decode %s: %s", what, c.err);
+      return 0;
     }
-    if (PyList_Append(pkts, pkt) < 0) {
-      Py_DECREF(pkt);
-      Py_DECREF(pkts);
-      PyBuffer_Release(&view);
-      return NULL;
-    }
+    int rc = PyList_Append(pkts, pkt);
     Py_DECREF(pkt);
+    if (rc < 0) return -1;
     off += 4 + ln;
   }
+  return 0;
+}
 
-done:
+/* one whole buffer -> (pkts, consumed, err_kind, err_msg); consumes/
+ * releases `view` */
+static PyObject *decode_stream(Py_buffer view, PyObject *xid_map,
+                               int max_packet) {
+  if (g_stat_cls == NULL) {
+    PyBuffer_Release(&view);
+    PyErr_SetString(PyExc_RuntimeError, "setup() not called");
+    return NULL;
+  }
+  PyObject *pkts = PyList_New(0);
+  if (pkts == NULL) {
+    PyBuffer_Release(&view);
+    return NULL;
+  }
+  const char *kind;
+  char msg[256] = {0};
+  Py_ssize_t consumed;
+  int rc = decode_span_into((const uint8_t *)view.buf, view.len, xid_map,
+                            max_packet, pkts, &consumed, &kind, msg);
   PyBuffer_Release(&view);
-  PyObject *ret =
-      err_kind == NULL
-          ? Py_BuildValue("(OnOO)", pkts, consumed, Py_None, Py_None)
-          : Py_BuildValue("(Onss)", pkts, consumed, err_kind, err_msg);
+  PyObject *ret = NULL;
+  if (rc == 0)
+    ret = kind == NULL
+              ? Py_BuildValue("(OnOO)", pkts, consumed, Py_None, Py_None)
+              : Py_BuildValue("(Onss)", pkts, consumed, kind, msg);
   Py_DECREF(pkts); /* BuildValue's "O" took its own reference */
   return ret;
 }
@@ -1259,6 +1260,146 @@ static PyObject *py_decode_responses(PyObject *self, PyObject *args) {
   return decode_stream(view, xid_map, max_packet);
 }
 
+/* decode_streams(bufs, lens, xid_maps, max_packet)
+ *   -> (pkts, counts, consumed, errors)
+ *
+ * The fleet ingest's tick in one call: stream i is bufs[i][0:lens[i]]
+ * (the complete-frame prefix the device scan delimited) decoded
+ * against xid_maps[i] by the SAME walk decode_responses runs.  What
+ * decode_responses(bufs[i][:lens[i]], xid_maps[i], max_packet) returns
+ * as (p, c, kind, msg) is here p = the next counts[i] entries of the
+ * ONE flat list pkts (stream order), c = consumed[i], and for the
+ * streams with an error — those alone — errors[i] = (kind, msg).  The
+ * result is flat because the tick holds it whole while it routes: a
+ * tuple and a list a stream would be two more tracked containers a
+ * stream alive at once, and the collector's pace follows those.  A
+ * stream whose decode raised (OOM, a buffer that cannot be exported
+ * or is shorter than its length) contributes no packets and has the
+ * exception INSTANCE as errors[i]: the streams before it have already
+ * consumed their xids, so the call cannot fail as a whole once
+ * decoding has begun.  Arguments are validated before the first
+ * stream is touched.  A stream of length 0 is not touched at all.
+ * Each buffer's export is released before the next stream is read:
+ * every buffer is resizable again when the call returns. */
+static PyObject *py_decode_streams(PyObject *self, PyObject *args) {
+  PyObject *bufs, *lens, *maps;
+  int max_packet;
+  if (!PyArg_ParseTuple(args, "O!O!O!i", &PyList_Type, &bufs,
+                        &PyList_Type, &lens, &PyList_Type, &maps,
+                        &max_packet))
+    return NULL;
+  if (g_stat_cls == NULL) {
+    PyErr_SetString(PyExc_RuntimeError, "setup() not called");
+    return NULL;
+  }
+  Py_ssize_t n = PyList_GET_SIZE(bufs);
+  if (PyList_GET_SIZE(lens) != n || PyList_GET_SIZE(maps) != n) {
+    PyErr_SetString(PyExc_ValueError,
+                    "decode_streams: bufs, lens and xid_maps differ in "
+                    "length");
+    return NULL;
+  }
+  for (Py_ssize_t i = 0; i < n; i++) {
+    if (!PyObject_CheckBuffer(PyList_GET_ITEM(bufs, i)) ||
+        !PyLong_Check(PyList_GET_ITEM(lens, i)) ||
+        !PyDict_Check(PyList_GET_ITEM(maps, i))) {
+      PyErr_Format(PyExc_TypeError,
+                   "decode_streams: stream %zd needs a buffer, an int "
+                   "length and a dict xid_map", i);
+      return NULL;
+    }
+  }
+  PyObject *pkts = PyList_New(0);
+  PyObject *counts = PyList_New(n);
+  PyObject *consumed = PyList_New(n);
+  PyObject *errors = PyDict_New();
+  if (!pkts || !counts || !consumed || !errors) goto fail;
+  for (Py_ssize_t i = 0; i < n; i++) {
+    /* the lists are the caller's and nothing here mutates them, but
+     * decoding runs Python (Stat/ACL constructors): hold our own
+     * references for the duration of the stream */
+    PyObject *bufo = PyList_GET_ITEM(bufs, i);
+    PyObject *map = PyList_GET_ITEM(maps, i);
+    Py_ssize_t ln = PyLong_AsSsize_t(PyList_GET_ITEM(lens, i));
+    Py_ssize_t before = PyList_GET_SIZE(pkts), used = 0;
+    const char *kind = NULL;
+    char msg[256] = {0};
+    int rc = 0;
+    if (ln == -1 && PyErr_Occurred()) {
+      rc = -1;
+    } else if (ln != 0) {
+      Py_buffer view;
+      Py_INCREF(bufo);
+      Py_INCREF(map);
+      if (PyObject_GetBuffer(bufo, &view, PyBUF_SIMPLE) < 0) {
+        rc = -1;
+      } else {
+        if (ln < 0 || ln > view.len) {
+          PyErr_Format(PyExc_ValueError,
+                       "decode_streams: stream %zd length %zd outside "
+                       "its %zd-byte buffer", i, ln, view.len);
+          rc = -1;
+        } else {
+          rc = decode_span_into((const uint8_t *)view.buf, ln, map,
+                                max_packet, pkts, &used, &kind, msg);
+        }
+        PyBuffer_Release(&view);
+      }
+      Py_DECREF(bufo);
+      Py_DECREF(map);
+    }
+    PyObject *err = NULL;
+    if (rc < 0) {
+      /* this stream's packets go, as decode_responses' would */
+      PyObject *et, *ev, *tb;
+      PyErr_Fetch(&et, &ev, &tb);
+      PyErr_NormalizeException(&et, &ev, &tb);
+      if (ev != NULL && tb != NULL) PyException_SetTraceback(ev, tb);
+      Py_XDECREF(et);
+      Py_XDECREF(tb);
+      if (ev == NULL) {
+        PyErr_SetString(PyExc_SystemError,
+                        "decode_streams: failure without an exception");
+        goto fail;
+      }
+      err = ev;
+      used = 0;
+      if (PyList_SetSlice(pkts, before, PyList_GET_SIZE(pkts), NULL) < 0) {
+        Py_DECREF(err);
+        goto fail;
+      }
+    } else if (kind != NULL) {
+      err = Py_BuildValue("(ss)", kind, msg);
+      if (err == NULL) goto fail;
+    }
+    if (err != NULL) {
+      PyObject *key = PyLong_FromSsize_t(i);
+      int bad = key == NULL || PyDict_SetItem(errors, key, err) < 0;
+      Py_XDECREF(key);
+      Py_DECREF(err);
+      if (bad) goto fail;
+    }
+    PyObject *cnt = PyLong_FromSsize_t(PyList_GET_SIZE(pkts) - before);
+    PyObject *use = PyLong_FromSsize_t(used);
+    if (cnt == NULL || use == NULL) {
+      Py_XDECREF(cnt);
+      Py_XDECREF(use);
+      goto fail;
+    }
+    PyList_SET_ITEM(counts, i, cnt);
+    PyList_SET_ITEM(consumed, i, use);
+  }
+  return Py_BuildValue("(NNNN)", pkts, counts, consumed, errors);
+
+fail:
+  /* counts/consumed may hold NULL slots: list dealloc copes */
+  Py_XDECREF(pkts);
+  Py_XDECREF(counts);
+  Py_XDECREF(consumed);
+  Py_XDECREF(errors);
+  return NULL;
+}
+
 static PyObject *py_decode_requests(PyObject *self, PyObject *args) {
   Py_buffer view;
   int max_packet;
@@ -1267,7 +1408,7 @@ static PyObject *py_decode_requests(PyObject *self, PyObject *args) {
 }
 
 static PyObject *py_abi_version(PyObject *self, PyObject *noargs) {
-  return PyLong_FromLong(10);
+  return PyLong_FromLong(11);
 }
 
 /* CRC32C (Castagnoli, reflected 0x82F63B78) for the write-ahead-log
@@ -2115,6 +2256,9 @@ static PyMethodDef methods[] = {
     {"decode_responses", py_decode_responses, METH_VARARGS,
      "decode_responses(buf, xid_map, max_packet) -> "
      "(pkts, consumed, err_kind, err_msg)"},
+    {"decode_streams", py_decode_streams, METH_VARARGS,
+     "decode_streams(bufs, lens, xid_maps, max_packet) -> "
+     "(pkts, counts, consumed, {i: (err_kind, err_msg) | exception})"},
     {"decode_requests", py_decode_requests, METH_VARARGS,
      "decode_requests(buf, max_packet) -> "
      "(pkts, consumed, err_kind, err_msg)"},

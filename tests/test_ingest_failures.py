@@ -379,14 +379,18 @@ async def test_unsupported_reply_opcode_is_bad_decode():
 
 
 async def test_ext_slice_failure_wraps_as_bad_decode():
-    """body_mode='host' C fast path: an exception out of the extension
-    becomes connection-level BAD_DECODE, not a raw crash."""
+    """body_mode='host' C fast path: a stream whose decode raised
+    inside the extension (``decode_streams`` hands the exception back
+    as that stream's error) becomes connection-level BAD_DECODE, not
+    a raw crash."""
     ing = mk_ingest()
     conn = FakeConn()
 
     class BrokenExt:
-        def decode_responses(self, buf, xid_map, max_packet):
-            raise MemoryError('injected')
+        def decode_streams(self, bufs, lens, xid_maps, max_packet):
+            n = len(bufs)
+            return ([], [0] * n, [0] * n,
+                    {i: MemoryError('injected') for i in range(n)})
 
     conn.codec._ext = BrokenExt()
     ing.register(conn)

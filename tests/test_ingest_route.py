@@ -1,0 +1,525 @@
+"""The fleet ingest's batch route against the per-stream reference.
+
+A tick routes its replies as one batch: one pass over the head planes,
+one C decode for every stream (``decode_streams``), and each stream
+handed to its connection's direct settle lane
+(io/connection.py ``state_connected``) — which settles a run of plain
+replies itself and leaves everything else to ``deliver``.  The
+reference is the per-socket scalar drain, the same connection without
+an ingest: every case here drives ONE seeded corpus of wire bytes
+through real ``ZKConnection`` + ``ZKSession`` pairs (no sockets: a
+stub client, a fake transport, a server-side codec that writes the
+bytes) twice, and what can be observed at every connection must be
+identical — the order in which futures settle and with what, the
+notifications, reserved-xid callbacks and state changes between them,
+``session.last_zxid``, the expiry deadline under one clock, ``reqs``,
+``xid_map`` and the bytes left over.  Each case runs with the C
+extension and under ``ZKSTREAM_NO_NATIVE``.
+"""
+
+import asyncio
+import random
+import struct
+import time
+
+import pytest
+
+from zkstream_tpu.io import ingest as ingest_mod
+from zkstream_tpu.io import session as session_mod
+from zkstream_tpu.io.connection import Backend, ZKConnection
+from zkstream_tpu.io.ingest import FleetIngest
+from zkstream_tpu.io.session import ZKSession
+from zkstream_tpu.protocol.framing import PacketCodec, frame
+from zkstream_tpu.protocol.records import Stat
+from zkstream_tpu.utils import native
+
+CLOCK = 1000.0
+
+
+class _Time:
+    """One clock for both runs: ``time.monotonic`` as the session and
+    the ingest read it (the span and the loop keep the real one)."""
+
+    perf_counter = staticmethod(time.perf_counter)
+    time = staticmethod(time.time)
+
+    @staticmethod
+    def monotonic() -> float:
+        return CLOCK
+
+
+class FakeTransport:
+    def __init__(self):
+        self.out: list[bytes] = []
+
+    def write(self, data) -> None:
+        self.out.append(bytes(data))
+
+    def abort(self) -> None:
+        pass
+
+    def can_write_eof(self) -> bool:
+        return False
+
+
+class StubClient:
+    """What a ZKConnection asks of its client."""
+
+    def __init__(self, ingest, use_native: bool):
+        self.ingest = ingest
+        self.use_native_codec = use_native
+        self.session = ZKSession(30000)
+
+    def get_session(self):
+        return self.session
+
+
+class LoggedFuture:
+    """Stands where a request's awaiter future stands and logs the
+    settle the moment it happens (a real future's callbacks run a
+    loop turn later, after whatever else the routing call logged)."""
+
+    def __init__(self, log: list, xid: int):
+        self._log, self._xid, self._done = log, xid, False
+
+    def done(self) -> bool:
+        return self._done
+
+    def set_result(self, pkt) -> None:
+        assert not self._done
+        self._done = True
+        self._log.append(('fut', self._xid, pkt))
+
+    def set_exception(self, err) -> None:
+        assert not self._done
+        self._done = True
+        self._log.append(('fut', self._xid, type(err).__name__,
+                          getattr(err, 'code', None)))
+
+
+class Peer:
+    """One connected session, the server's half of its wire, and the
+    log of everything that was observed at it."""
+
+    def __init__(self, idx: int, ingest, use_native: bool, rng):
+        self.idx = idx
+        self.rng = rng
+        self.log: list = []
+        self.client = StubClient(ingest, use_native)
+        self.session = self.client.session
+        self.conn = conn = ZKConnection(self.client,
+                                        Backend('127.0.0.1', 1 + idx))
+        conn.codec = PacketCodec(use_native=use_native)
+        conn.transport = FakeTransport()
+        self.srv = PacketCodec(server=True, use_native=False)
+        conn.on('stateChanged', lambda st: self.log.append(('state', st)))
+        conn._transition('handshaking')
+        conn.emit('sockData', self.srv.encode({
+            'protocolVersion': 0, 'timeOut': 30000,
+            'sessionId': 0x1000 + idx, 'passwd': b'\x01' * 16}))
+        self.srv.handshaking = False
+        assert conn.is_in_state('connected')
+        assert self.session.is_in_state('attached')
+        self.session.process_notification = \
+            lambda pkt: self.log.append(('notify', pkt['type'],
+                                         pkt['path'], pkt['zxid']))
+        self.zxid = 100 * (idx + 1)
+        self.wire = bytearray()
+
+    # -- the client side: requests whose settling is logged --
+
+    def get(self, path='/k') -> int:
+        req = self.conn.request({'opcode': 'GET_DATA', 'path': path,
+                                 'watch': False})
+        xid = req.packet['xid']
+        req.fut = LoggedFuture(self.log, xid)
+        return xid
+
+    def ping(self) -> None:
+        self.conn.ping(lambda err, _lat: self.log.append(('ping', err)))
+
+    def set_watches(self) -> None:
+        self.conn.set_watches(
+            {'dataChanged': ['/w'], 'createdOrDestroyed': [],
+             'childrenChanged': []}, 5,
+            lambda err: self.log.append(('set_watches', err)))
+
+    # -- the server side: reply bytes onto this peer's wire --
+
+    def _next_zxid(self) -> int:
+        self.zxid += self.rng.randrange(1, 9)
+        return self.zxid
+
+    def reply(self, xid: int, err: str = 'OK') -> None:
+        pkt = {'xid': xid, 'zxid': self._next_zxid(), 'err': err,
+               'opcode': 'GET_DATA'}
+        if err == 'OK':
+            pkt['data'] = self.rng.randbytes(self.rng.randrange(0, 200))
+            pkt['stat'] = Stat(*(self.rng.randrange(1 << 20)
+                                 for _ in range(11)))
+        self.wire += self.srv.encode(pkt)
+
+    def notification(self, path='/w') -> None:
+        self.wire += self.srv.encode({
+            'xid': -1, 'zxid': self._next_zxid(), 'err': 'OK',
+            'opcode': 'NOTIFICATION', 'type': 'DATA_CHANGED',
+            'state': 'SYNC_CONNECTED', 'path': path})
+
+    def reserved(self, xid: int, opcode: str) -> None:
+        self.wire += self.srv.encode({
+            'xid': xid, 'zxid': self._next_zxid(), 'err': 'OK',
+            'opcode': opcode})
+
+    def raw(self, data: bytes) -> None:
+        self.wire += data
+
+    def flush(self) -> None:
+        """Hand what the server wrote to the connection."""
+        data, self.wire = bytes(self.wire), bytearray()
+        if data:
+            self.conn.emit('sockData', data)
+
+    # -- what the comparison reads --
+
+    def snapshot(self, ingest) -> dict:
+        err = self.conn.last_error
+        return {
+            'log': self.log,
+            'state': self.conn.get_state(),
+            'session': self.session.get_state(),
+            'last_zxid': self.session.last_zxid,
+            'expiry_deadline': self.session._expiry_deadline,
+            'last_pkt': self.session.last_pkt,
+            'reqs': sorted(self.conn.reqs),
+            'xid_map': dict(self.conn.codec.xid_map),
+            'last_error': (None if err is None
+                           else (type(err).__name__,
+                                 getattr(err, 'code', None), str(err))),
+            'residue': self.pending(ingest),
+        }
+
+    def pending(self, ingest) -> bytes:
+        """Bytes received and not yet decoded, wherever they wait:
+        the ingest's slot, else the codec's accumulator."""
+        slot = None if ingest is None else ingest._slots.get(id(self.conn))
+        if slot is not None:
+            return bytes(slot[1])
+        pend = self.conn.codec.take_pending()
+        self.conn.codec.restore_pending(pend)
+        return bytes(pend)
+
+
+async def settle() -> None:
+    """Let the call_soon-scheduled tick and the futures' callbacks
+    run."""
+    for _ in range(4):
+        await asyncio.sleep(0)
+
+
+# ---------------------------------------------------------------------
+# the cases: each writes its part of the corpus and says how many
+# frames the lanes must have settled (None: not pinned)
+# ---------------------------------------------------------------------
+
+async def case_plain(peers, flush):
+    for p in peers:
+        p.reply(p.get())
+    await flush()
+    return len(peers)
+
+
+async def case_two_frames(peers, flush):
+    for p in peers:
+        a, b = p.get(), p.get()
+        p.reply(a)
+        p.reply(b)
+    await flush()
+    return 2 * len(peers)
+
+
+async def case_notification_before_reply(peers, flush):
+    for p in peers:
+        x = p.get()
+        p.notification()
+        p.reply(x)
+    await flush()
+    return 0     # the stream leaves the lane at its first packet
+
+
+async def case_notification_after_reply(peers, flush):
+    for p in peers:
+        a, b = p.get(), p.get()
+        p.reply(a)
+        p.notification()
+        p.reply(b)
+    await flush()
+    return len(peers)
+
+
+async def case_ping_reply(peers, flush):
+    for p in peers:
+        a, b = p.get(), p.get()
+        p.ping()
+        p.reply(a)
+        p.reserved(-2, 'PING')
+        p.reply(b)
+    await flush()
+    return len(peers)
+
+
+async def case_set_watches_reply(peers, flush):
+    for p in peers:
+        p.set_watches()
+        x = p.get()
+        p.reserved(-8, 'SET_WATCHES')
+        p.reply(x)
+    await flush()
+    return 0
+
+
+async def case_error_reply(peers, flush):
+    for p in peers:
+        a, b = p.get(), p.get()
+        p.reply(a, 'NO_NODE')
+        p.reply(b)
+    await flush()
+    return 2 * len(peers)
+
+
+async def case_throttled(peers, flush):
+    for p in peers:
+        p.reply(p.get(), 'THROTTLED')
+    await flush()
+    return len(peers)
+
+
+async def case_bad_stream(peers, flush):
+    """A length prefix below zero after a whole frame: the device marks
+    the stream bad and the connection's own codec decides (BAD_LENGTH
+    drops the frames of the chunk before it, like the scalar drain)."""
+    for i, p in enumerate(peers):
+        p.reply(p.get())
+        if i % 2 == 0:
+            p.raw(struct.pack('>i', -5) + b'\x00' * 8)
+    await flush()
+    return None
+
+
+async def case_truncated_tail(peers, flush):
+    for p in peers:
+        a, b = p.get(), p.get()
+        p.reply(a)
+        p.reply(b)
+        whole = bytes(p.wire)
+        cut = len(whole) - p.rng.randrange(1, 12)
+        p.wire = bytearray(whole[:cut])
+        p.rest = whole[cut:]
+    await flush()
+    for p in peers:
+        p.raw(p.rest)
+    await flush()
+    return 2 * len(peers)
+
+
+async def case_decode_error_mid_stream(peers, flush):
+    """A reply whose xid matches no request, after a good one: the
+    packets before it are delivered, then the connection errors."""
+    for i, p in enumerate(peers):
+        a, b = p.get(), p.get()
+        p.reply(a)
+        if i % 2 == 0:
+            p.raw(frame(struct.pack('>iqi', 31337, p.zxid + 1, 0)))
+        p.reply(b)
+    await flush()
+    return None
+
+
+async def case_reply_after_deadline(peers, flush):
+    """The awaiter gave up (its deadline fired): the late reply is
+    dropped exactly once and the xid leaves ``reqs``."""
+    from zkstream_tpu.protocol.errors import ZKDeadlineError
+    for p in peers:
+        a, b = p.get(), p.get()
+        p.conn.reqs[a].fut.set_exception(
+            ZKDeadlineError('GET_DATA', '/k', 50))
+        p.reply(a)
+        p.reply(b)
+    await flush()
+    return 2 * len(peers)
+
+
+async def case_callback_closes_later_connection(peers, flush):
+    """A callback inside the routing of peer 0's stream (a request's
+    'reply' listener, as a watcher's arm is) closes peer 2, whose bytes
+    are in the same tick: peer 2 is skipped, its bytes go back to its
+    codec with the xids the batch decode took for them, and its
+    ``closing`` state decodes them with the next segment."""
+    first, victim = peers[0], peers[2]
+    x = first.get()
+    first.conn.reqs[x].on('reply', lambda _pkt: victim.conn.close())
+    first.reply(x)
+    for p in peers[1:]:
+        a, b = p.get(), p.get()
+        p.reply(a)
+        if p is not victim:
+            p.reply(b)
+    vb = sorted(victim.conn.reqs)[-1]
+    await flush()
+    victim.reply(vb)
+    await flush()
+    return None
+
+
+async def case_second_packet_listener(peers, flush):
+    for p in peers:
+        p.conn.on('packet', lambda pkt, p=p: p.log.append(
+            ('packet', pkt['xid'], pkt['zxid'])))
+        a, b = p.get(), p.get()
+        p.reply(a)
+        p.notification()
+        p.reply(b)
+    await flush()
+    return 0
+
+
+async def case_fault_injector_installed(peers, flush):
+    class Injector:
+        """Passes every frame: its presence alone is what is asked."""
+
+        def tx(self, conn, data):
+            return data
+
+    for p in peers:
+        p.conn.faults = Injector()
+        p.reply(p.get())
+    await flush()
+    return 0
+
+
+async def case_session_moving_away(peers, flush):
+    """A session that is reattaching elsewhere no longer listens to
+    this connection's packets: replies still settle, its zxid and
+    expiry stay."""
+    for p in peers:
+        x = p.get()
+        p.other = ZKConnection(p.client, Backend('127.0.0.1', 99))
+        p.other.codec = PacketCodec(use_native=False)
+        p.other.transport = FakeTransport()
+        p.session.attach_and_send_cr(p.other)
+        assert p.session.is_in_state('reattaching')
+        p.reply(x)
+    await flush()
+    return 0
+
+
+async def case_seeded_mix(peers, flush):
+    """Several ticks of everything that keeps a connection alive, drawn
+    from the seed."""
+    for _round in range(6):
+        for p in peers:
+            rng = p.rng
+            xs = [p.get() for _ in range(rng.randrange(0, 5))]
+            if rng.random() < 0.3:
+                p.ping()
+                xs.append(-2)
+            rng.shuffle(xs)
+            for x in xs:
+                if x == -2:
+                    p.reserved(-2, 'PING')
+                    continue
+                if rng.random() < 0.25:
+                    p.notification('/n%d' % rng.randrange(4))
+                p.reply(x, rng.choice(['OK'] * 6 + ['NO_NODE',
+                                                    'THROTTLED']))
+        await flush()
+    return None
+
+
+CASES = {
+    fn.__name__[len('case_'):]: fn for fn in (
+        case_plain, case_two_frames, case_notification_before_reply,
+        case_notification_after_reply, case_ping_reply,
+        case_set_watches_reply, case_error_reply, case_throttled,
+        case_bad_stream, case_truncated_tail,
+        case_decode_error_mid_stream, case_reply_after_deadline,
+        case_callback_closes_later_connection,
+        case_second_packet_listener, case_fault_injector_installed,
+        case_session_moving_away, case_seeded_mix)}
+
+
+async def run_case(case, through_ingest: bool, use_native: bool,
+                   seed: int):
+    ingest = None
+    if through_ingest:
+        ingest = FleetIngest(bypass_bytes=0, warm='block',
+                             placement='host', max_frames=4, min_len=256)
+    lanes: list = []
+    if ingest is not None:
+        route = ingest._route_batch
+
+        def counted(*args):
+            out = route(*args)
+            lanes.append(out)
+            return out
+        ingest._route_batch = counted
+    peers = [Peer(i, ingest, use_native, random.Random(seed * 131 + i))
+             for i in range(5)]
+
+    async def flush():
+        for p in peers:
+            p.flush()
+        await settle()
+
+    try:
+        expect = await CASES[case](peers, flush)
+        await settle()
+        snaps = [p.snapshot(ingest) for p in peers]
+    finally:
+        for p in peers:
+            p.session.close()
+            p.conn.destroy()
+            if getattr(p, 'other', None) is not None:
+                p.other.destroy()
+        await settle()
+        if ingest is not None:
+            ingest.close()
+    return snaps, expect, sum(a for a, _b in lanes), \
+        sum(b for _a, b in lanes)
+
+
+@pytest.mark.parametrize('use_native', [True, False],
+                         ids=['ext', 'no_native'])
+@pytest.mark.parametrize('case', list(CASES))
+async def test_batch_route_equals_per_stream_reference(
+        case, use_native, monkeypatch):
+    if use_native:
+        if native.ensure_ext() is None:
+            pytest.skip('no C extension here (no compiler)')
+    else:
+        monkeypatch.setenv('ZKSTREAM_NO_NATIVE', '1')
+    monkeypatch.setattr(session_mod, 'time', _Time)
+    monkeypatch.setattr(ingest_mod, 'time', _Time)
+    want, _n, _l, _e = await run_case(case, False, use_native, seed=29)
+    got, lane_frames, laned, emitted = await run_case(
+        case, True, use_native, seed=29)
+    for i, (w, g) in enumerate(zip(want, got)):
+        assert g == w, 'connection %d differs' % i
+    assert any(w['log'] for w in want)       # the case observed something
+    if lane_frames is not None:
+        assert laned == lane_frames
+    if case in ('plain', 'two_frames', 'error_reply', 'throttled'):
+        assert emitted == 0                  # nothing but the lane
+
+
+async def test_lane_is_what_state_connected_registers():
+    """The slot holds the connection's lane until the state's exit."""
+    ingest = FleetIngest(bypass_bytes=0, warm='block', placement='host',
+                         max_frames=4, min_len=256)
+    p = Peer(0, ingest, False, random.Random(1))
+    conn, _buf, lane = ingest._slots[id(p.conn)]
+    assert conn is p.conn and callable(lane)
+    p.conn.destroy()
+    assert id(p.conn) not in ingest._slots
+    p.session.close()
+    await settle()
+    ingest.close()

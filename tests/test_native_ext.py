@@ -546,3 +546,109 @@ def test_differential_fuzz_response_decode():
         assert (k1, c1) == (k2, c2), (trial, wire.hex(), c1, c2)
         assert p1 == p2, (trial, wire.hex())
         assert py.xid_map == ext.xid_map, (trial, wire.hex())
+
+
+# -- decode_streams: the fleet ingest's tick in one call ---------------
+
+def _streams_corpus(seed: int):
+    """Seeded streams: (bytes, length to decode, xid_map) each — whole
+    reply runs, a slice that ends inside a frame, an empty slice, one
+    with an unmatched xid mid-stream, one with a bad length prefix."""
+    rng = random.Random(seed)
+    streams = []
+    for k in range(24):
+        replies = rng.sample(ALL_REPLIES, rng.randrange(1, 6))
+        wire = encode_replies(replies)
+        xmap = xid_map_for(replies)
+        ln = len(wire)
+        kind = k % 6
+        if kind == 1:
+            ln -= rng.randrange(1, 9)           # ends inside a frame
+        elif kind == 2:
+            ln = 0                              # empty slice
+        elif kind == 3 and xmap:
+            xmap.pop(next(iter(xmap)))          # one reply unmatched
+        elif kind == 4:
+            wire += struct.pack('>i', -7) + b'junk'
+            ln = len(wire)                      # bad prefix after frames
+        wire += rng.randbytes(rng.randrange(0, 5))  # bytes past the slice
+        streams.append((wire, ln, xmap))
+    return streams
+
+
+def _per_stream(ext, streams):
+    """What decode_responses says about each slice, on its own maps."""
+    out = []
+    for wire, ln, xmap in streams:
+        xm = dict(xmap)
+        out.append((ext.decode_responses(wire[:ln], xm, 1 << 24), xm))
+    return out
+
+
+@pytest.mark.parametrize('seed', [1, 2, 3])
+def test_decode_streams_equals_decode_responses(seed):
+    ext = native.ensure_ext()
+    streams = _streams_corpus(seed)
+    want = _per_stream(ext, streams)
+    bufs = [bytearray(w) for w, _ln, _xm in streams]
+    maps = [dict(xm) for _w, _ln, xm in streams]
+    pkts, counts, consumed, errors = ext.decode_streams(
+        bufs, [ln for _w, ln, _xm in streams], maps, 1 << 24)
+    pos = 0
+    for i, ((w_pkts, w_used, w_kind, w_msg), w_map) in enumerate(want):
+        assert pkts[pos:pos + counts[i]] == w_pkts, i
+        pos += counts[i]
+        assert consumed[i] == w_used, i
+        assert errors.get(i) == (None if w_kind is None
+                                 else (w_kind, w_msg)), i
+        assert maps[i] == w_map, i               # the same xids popped
+    assert pos == len(pkts)
+    assert sorted(errors) == [i for i, (w, _m) in enumerate(want)
+                              if w[2] is not None]
+    assert {k for k, _m in errors.values()} == {'BAD_DECODE',
+                                                'BAD_LENGTH'}
+    for buf in bufs:
+        buf.extend(b'x')    # no export left held: still resizable
+        del buf[:1]
+
+
+def test_decode_streams_empty_slice_touches_nothing():
+    ext = native.ensure_ext()
+    xmap = {1: 'GET_DATA'}
+    out = ext.decode_streams([bytearray(b'\x00\x00')], [0], [xmap], 1 << 24)
+    assert out == ([], [0], [0], {})
+    assert xmap == {1: 'GET_DATA'}
+    assert ext.decode_streams([], [], [], 1 << 24) == ([], [], [], {})
+
+
+def test_decode_streams_failure_in_one_stream_of_many():
+    """A stream whose decode raises (here: a length past its buffer)
+    has the exception as its error and no packets; its neighbours
+    decode as if alone, and its buffer is resizable afterwards."""
+    ext = native.ensure_ext()
+    wire = encode_replies(ALL_REPLIES[:3])
+    bufs = [bytearray(wire) for _ in range(3)]
+    maps = [xid_map_for(ALL_REPLIES[:3]) for _ in range(3)]
+    pkts, counts, consumed, errors = ext.decode_streams(
+        bufs, [len(wire), len(wire) + 1, len(wire)], maps, 1 << 24)
+    assert counts == [3, 0, 3] and consumed == [len(wire), 0, len(wire)]
+    assert list(errors) == [1] and isinstance(errors[1], ValueError)
+    assert pkts == ALL_REPLIES[:3] * 2
+    assert maps[1] == xid_map_for(ALL_REPLIES[:3])    # untouched
+    for buf in bufs:
+        buf.clear()
+
+
+def test_decode_streams_validates_before_decoding():
+    """A malformed argument fails the call before any stream's xids
+    are consumed."""
+    ext = native.ensure_ext()
+    wire = encode_replies(ALL_REPLIES[:2])
+    xmap = xid_map_for(ALL_REPLIES[:2])
+    with pytest.raises(TypeError):
+        ext.decode_streams([bytearray(wire), bytearray(wire)],
+                           [len(wire), len(wire)], [xmap, []], 1 << 24)
+    with pytest.raises(ValueError):
+        ext.decode_streams([bytearray(wire)], [len(wire), 1], [xmap],
+                           1 << 24)
+    assert xmap == xid_map_for(ALL_REPLIES[:2])

@@ -60,8 +60,9 @@ def _finish_span(req, zxid: int | None = None, status: str = 'ok',
 
 class ZKRequest(EventEmitter):
     """One in-flight request, settled exactly once by the connection:
-    :meth:`resolve` (the reply packet) or :meth:`fail` (a typed
-    error) (reference: lib/connection-fsm.js:378-382).  Whoever
+    :meth:`settle` (its reply packet) or :meth:`fail` (a typed error
+    on a teardown path) (reference: lib/connection-fsm.js:378-382).
+    Whoever
     awaits it takes :meth:`as_future` — the connection settles that
     future itself, with no listener in between; the 'reply' /
     'error' events serve what must run inside the routing call (the
@@ -84,7 +85,29 @@ class ZKRequest(EventEmitter):
             self.fut = asyncio.get_running_loop().create_future()
         return self.fut
 
-    def resolve(self, pkt: dict) -> None:
+    def settle(self, pkt: dict) -> bool:
+        """The reply has arrived: close the span, stamped with the
+        reply zxid, then resolve the request with the packet or fail
+        it with the typed error it carries — the one settle of
+        :meth:`ZKConnection.process_reply` and of the direct lane
+        (``state_connected``).  True when listeners heard it: their
+        callbacks ran inside this call and may have moved any state
+        machine."""
+        code = pkt['err']
+        span = self.span
+        if code != 'OK':
+            if span is not None:
+                span.finish(zxid=pkt.get('zxid'), status='error',
+                            error=code)
+            heard = bool(self._listeners)
+            # the overloaded-member bounce gets its typed class so
+            # the client's write path can key its backoff+retry on
+            # isinstance instead of string-matching the code
+            self.fail(ZKThrottledError() if code == 'THROTTLED'
+                      else ZKError(code), pkt)
+            return heard
+        if span is not None:
+            span.finish(zxid=pkt.get('zxid'))
         fut = self.fut
         # done() already: the awaiter gave up (deadline, cancelled)
         # and the late reply is dropped
@@ -92,6 +115,8 @@ class ZKRequest(EventEmitter):
             fut.set_result(pkt)
         if self._listeners:
             self.emit('reply', pkt)
+            return True
+        return False
 
     def fail(self, err: Exception, *args) -> None:
         fut = self.fut
@@ -389,7 +414,58 @@ class ZKConnection(FSM):
             # itself — the ingest only gets the byte/frame counts its
             # dispatch policy needs — so the regime where batching does
             # not pay costs one flag check over the no-ingest path.
-            self.ingest.register(self)
+            session = self.session
+
+            def lane_open():
+                # what the lane restates is only right while the
+                # session's attached-state handler is the one 'packet'
+                # listener, and no injector stands in the stream
+                held = self._listeners.get('packet')
+                return (held is not None and len(held) == 1
+                        and held[0] is session.packet_listener
+                        and self.faults is None)
+
+            def lane(pkts, err, now):
+                """The direct settle lane: what one routed stream of
+                the ingest's tick costs when it is a run of plain
+                replies.  For the leading packets with ``xid > 0`` it
+                does, in this one function, what ``deliver`` ->
+                ``emit('packet')`` -> the session's ``on_packet`` ->
+                ``process_reply`` do per packet: the session's expiry
+                pushed out from the tick's clock ``now`` (once),
+                ``last_zxid`` raised, the request popped and settled
+                (:meth:`ZKRequest.settle`, as ``process_reply`` does).
+                From the first packet that is anything else — a
+                notification, a reserved xid — and for the stream's
+                decode error, ``deliver`` takes over, in stream order.
+                Returns the packets settled here."""
+                if not pkts or pkts[0]['xid'] <= 0 or not lane_open():
+                    deliver(pkts, err)
+                    return 0
+                session.reset_expiry_timer(now)
+                log_trace = (self.log.trace
+                             if self.log.enabled_for_trace() else None)
+                n = 0
+                for pkt in pkts:
+                    xid = pkt['xid']
+                    if xid <= 0:
+                        break
+                    n += 1
+                    if pkt['zxid'] > session.last_zxid:
+                        session.last_zxid = pkt['zxid']
+                    # self.reqs anew each time: a teardown swaps it
+                    req = self.reqs.pop(xid, None)
+                    if log_trace is not None:
+                        log_trace('server replied to xid %d err %s',
+                                  xid, pkt['err'])
+                    if (req is not None and req.settle(pkt)
+                            and not lane_open()):
+                        break
+                if n < len(pkts) or err is not None:
+                    deliver(pkts[n:], err)
+                return n
+
+            self.ingest.register(self, lane)
             S.defer(lambda: self.ingest.unregister(self))
 
             def on_sock(data):
@@ -617,21 +693,8 @@ class ZKConnection(FSM):
             req = self.reqs.get(xid)
         self.log.trace('server replied to xid %d err %s',
                        xid, pkt['err'])
-        if req is None:
-            return
-        if pkt['err'] == 'OK':
-            _finish_span(req, zxid=pkt.get('zxid'))
-            req.resolve(pkt)
-        else:
-            _finish_span(req, zxid=pkt.get('zxid'), status='error',
-                         error=pkt['err'])
-            # the overloaded-member bounce gets its typed class so
-            # the client's write path can key its backoff+retry on
-            # isinstance instead of string-matching the code
-            err = (ZKThrottledError()
-                   if pkt['err'] == 'THROTTLED'
-                   else ZKError(pkt['err']))
-            req.fail(err, pkt)
+        if req is not None:
+            req.settle(pkt)
 
     def request(self, pkt: dict) -> ZKRequest:
         """Send a normal (positive-xid) request

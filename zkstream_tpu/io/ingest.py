@@ -19,11 +19,13 @@ Division of labor per tick:
 
 - **device**: frame boundary scan, reply-header parse (xid/zxid/err),
   per-stream routing counts, bad-frame flags — the O(bytes) work;
-- **host**: per-frame packet-dict assembly.  In ``body_mode='host'``
-  the packets come from the C-extension decoder when it is loaded (one
-  zero-copy pass over the device-delimited complete-frame slice —
-  byte-identical to the scalar drain because it *is* the scalar
-  decoder), else from the scalar readers positioned at the
+- **host**: per-frame packet-dict assembly, and the route: a tick's
+  streams are delivered as one batch (:meth:`FleetIngest._route_batch`),
+  each to its connection's direct settle lane.  In ``body_mode='host'``
+  the packets come from the C-extension decoder when it is loaded (ONE
+  call a tick over every stream's device-delimited complete-frame
+  slice — byte-identical to the scalar drain because it *is* the
+  scalar decoder), else from the scalar readers positioned at the
   device-located body offsets.  In ``body_mode='device'`` fixed-layout
   bodies (Stat / data / create-path / notification) come from the
   tensor planes, with the scalar readers as fallback for list-shaped
@@ -247,8 +249,10 @@ class FleetIngest:
         self.placed: dict | None = None
         self._place_lock = threading.Lock()
         self.log = (log or Logger()).child(component='FleetIngest')
-        #: id(conn) -> (conn, accumulator)
-        self._slots: dict[int, tuple['ZKConnection', bytearray]] = {}
+        #: id(conn) -> (conn, accumulator, lane): ``lane`` is the
+        #: connection's direct settle lane (:meth:`register`), None
+        #: for one that takes its packets as 'ingestDeliver' events
+        self._slots: dict[int, tuple] = {}
         self._scheduled = False
         #: diagnostics for tests/benchmarks (``ticks`` counts device
         #: ticks; small ticks under ``bypass_bytes`` and ticks deferred
@@ -348,8 +352,19 @@ class FleetIngest:
 
     # -- connection registry --
 
-    def register(self, conn: 'ZKConnection') -> None:
-        slot = self._slots.setdefault(id(conn), (conn, bytearray()))
+    def register(self, conn: 'ZKConnection', lane=None) -> None:
+        """Give ``conn`` a slot until :meth:`unregister`.  ``lane`` is
+        the one callable its state ``connected`` hands over
+        (io/connection.py): ``lane(pkts, err, now) -> int`` takes a
+        routed stream's packets, its decode error if any and the
+        tick's ``time.monotonic()``, delivers them in stream order and
+        returns how many it settled itself.  The slot holds it and
+        ``unregister`` (the state's exit) drops it, so it is never
+        called outside that state.  Without one the stream goes out
+        as the connection's ``'ingestDeliver'`` event, as the scalar,
+        fallback and pass-through deliveries always do."""
+        slot = self._slots.setdefault(id(conn),
+                                      (conn, bytearray(), lane))
         # A partial steady-state frame may have ridden the same TCP
         # segment as the ConnectResponse.  In the BATCH regime it must
         # migrate out of the scalar decoder into the slot (the tick
@@ -939,11 +954,11 @@ class FleetIngest:
         drains slot buffers, so a tail left in ``_held`` across the
         flip would strand, then reorder behind fresh rx bytes."""
         self._release_held()
-        for conn, buf in active:
+        for conn, buf, _lane in active:
             if id(conn) not in self._slots:
                 continue
             self._deliver_scalar(conn, buf)
-        for _cid, (conn, buf) in list(self._slots.items()):
+        for conn, buf, _lane in list(self._slots.values()):
             if buf and conn.codec is not None:
                 conn.codec.restore_pending(bytes(buf))
                 buf.clear()
@@ -953,7 +968,7 @@ class FleetIngest:
         """Pass-through -> batch: reclaim each codec's partial-frame
         residue into its slot so the next tick's scan continues it."""
         self._direct = False
-        for _cid, (conn, buf) in list(self._slots.items()):
+        for conn, buf, _lane in list(self._slots.values()):
             if conn.codec is not None:
                 resid = conn.codec.take_pending()
                 if resid:
@@ -1013,9 +1028,8 @@ class FleetIngest:
         try:
             t0 = time.perf_counter()
             with host_span('ingest.batch', tick=self.ticks + 1) as bsp:
-                active = [(conn, buf)
-                          for conn, buf in self._slots.values()
-                          if buf and conn.is_in_state('connected')]
+                active = [slot for slot in self._slots.values()
+                          if slot[1] and slot[0].is_in_state('connected')]
                 plan = self._prepare_batch(active, sp) if active else None
                 if plan is None:
                     bsp.cancel()
@@ -1037,7 +1051,7 @@ class FleetIngest:
         frame at an arbitrary cut for the device scan to finish on the
         follow-up tick)."""
         fi = self.faults
-        for cid, (conn, buf) in list(self._slots.items()):
+        for cid, (conn, buf, _lane) in list(self._slots.items()):
             if not buf or not conn.is_in_state('connected'):
                 continue
             if fi.ingest_reset(conn):
@@ -1085,7 +1099,7 @@ class FleetIngest:
             return None
 
         B = len(active)
-        maxlen = max(len(buf) for _c, buf in active)
+        maxlen = max(len(slot[1]) for slot in active)
         key = self._bucket(B, maxlen)
         ex = self._exec.get(key, _MISSING)
         if ex is _MISSING:
@@ -1097,7 +1111,7 @@ class FleetIngest:
                 self._start_warm(key)
                 self.ticks_warming += 1
                 sp.set(tick=None, detail='warming')
-                for conn, buf in active:
+                for conn, buf, _lane in active:
                     if id(conn) not in self._slots:
                         continue
                     self._deliver_scalar(conn, buf)
@@ -1106,7 +1120,7 @@ class FleetIngest:
             self._require_compiled(key)
             self.ticks_scalar += 1
             sp.set(tick=None, detail='scalar')
-            for conn, buf in active:
+            for conn, buf, _lane in active:
                 if id(conn) not in self._slots:
                     continue
                 self._deliver_scalar(conn, buf)
@@ -1115,12 +1129,15 @@ class FleetIngest:
 
         device, Bp, L = key
         batch = np.zeros((Bp, L), np.uint8)
+        sizes = [len(slot[1]) for slot in active]
+        # one flat byte view of the batch: a slice assignment copies a
+        # stream into its row before anything can mutate it, without a
+        # numpy call a stream (it was a third of phase ``batch``)
+        rows = memoryview(batch.reshape(-1))
+        for i, (slot, n) in enumerate(zip(active, sizes)):
+            rows[i * L:i * L + n] = slot[1]
         lens = np.zeros((Bp,), np.int32)
-        for i, (_conn, buf) in enumerate(active):
-            # frombuffer views the bytearray; the assignment copies it
-            # into the batch row before anything can mutate it
-            batch[i, :len(buf)] = np.frombuffer(buf, np.uint8)
-            lens[i] = len(buf)
+        lens[:B] = sizes
         if sp is not NO_SPAN:
             sp.set(detail='device %dx%d streams=%d' % (Bp, L, B),
                    nbytes=int(lens.sum()))
@@ -1146,46 +1163,139 @@ class FleetIngest:
                 ints = np.asarray(out)
                 byts = None
         t3 = time.perf_counter()
-        with host_span('ingest.route', tick=n):
+        with host_span('ingest.route', tick=n) as rsp:
             st, bd = self._unpack(ints, byts)
-            retick = False
-            for i, (conn, buf) in enumerate(active):
-                if self._route_stream(conn, buf, st, bd, i):
-                    retick = True
-            if retick:
-                self._schedule()
+            laned, emitted = self._route_batch(active, None, st, bd)
+            rsp.set(lane=laned, emitted=emitted)
         t4 = time.perf_counter()
         observe = self.phase_hist.observe
         for labels, a, b in zip(_PHASE_LABELS, (t0, t1, t2, t3),
                                 (t1, t2, t3, t4)):
             observe((b - a) * 1000.0, labels)
 
-    def _route_stream(self, conn, buf, st, bd, i: int) -> bool:
-        """Deliver stream ``i``'s decoded tick results to its
-        connection (shared by the event-driven tick and the multihost
-        cadence tick).  Returns True when more complete frames may
-        still be buffered (the per-stream frame bound was hit)."""
-        # A user callback from an earlier stream's delivery may have
-        # torn this connection down mid-tick (unregister already
-        # restored its bytes to the codec): skip it.
-        if id(conn) not in self._slots:
-            return False
-        n = int(st.n_frames[i])
-        if bool(st.bad[i]):
-            # Exact scalar-error parity: re-run this stream through
-            # the connection's own codec, which raises BAD_LENGTH/
-            # BAD_DECODE with the pre-error packets attached.
-            self._deliver_fallback(conn, buf)
-            return False
-        pkts, err = self._assemble_stream(conn, buf, st, bd, i, n)
-        resid = int(st.resid[i])
-        if resid:
-            del buf[:resid]
-        self.frames_routed += n
-        if pkts or err is not None:
-            conn.emit('ingestDeliver', pkts, err)
-        return (err is None and n == self.max_frames
-                and len(buf) >= 4)
+    def _route_batch(self, streams, rows, st, bd) -> tuple[int, int]:
+        """Deliver a tick's decoded results as one batch (shared by
+        the event-driven tick and the multihost cadence tick):
+        ``streams`` are the slots that were in it, ``rows`` their rows
+        in the planes (None: the first ``len(streams)``).  One pass
+        over the head planes as Python lists, one clock read, and in
+        ``body_mode='host'`` with the extension loaded ONE C call that
+        decodes every stream's complete-frame slice
+        (:meth:`_decode_batch`); then each stream in turn goes to its
+        connection — through its direct lane when it brought one.
+        Schedules the follow-up tick when a stream hit the per-stream
+        frame bound with more buffered.  Returns the frames the lanes
+        settled and the frames that went the emitter path."""
+        if rows is None:
+            B = len(streams)
+            rows = range(B)
+            n_frames = st.n_frames[:B].tolist()
+            resids = st.resid[:B].tolist()
+            bads = st.bad[:B].tolist()
+        else:
+            n_frames = st.n_frames[rows].tolist()
+            resids = st.resid[rows].tolist()
+            bads = st.bad[rows].tolist()
+        now = time.monotonic()
+        lens = None      # no stream was decoded in a batch (yet)
+        if bd is None:
+            decoded = self._decode_batch(streams, n_frames, resids, bads)
+            if decoded is not None:
+                lens, maps, flat, counts, errors = decoded
+        slots = self._slots
+        max_frames = self.max_frames
+        laned = routed = pos = 0
+        retick = False
+        for i, (conn, buf, lane) in enumerate(streams):
+            pkts = None
+            if lens is not None and lens[i]:
+                # this stream's share of the batch decode
+                pkts = flat[pos:pos + counts[i]]
+                pos += counts[i]
+            # A user callback from an earlier stream's delivery may
+            # have torn this connection down mid-tick (unregister
+            # already restored its bytes to the codec): skip it — and
+            # hand back the xids the batch decode consumed for it, for
+            # the codec will decode those bytes again.
+            if id(conn) not in slots:
+                if pkts:
+                    maps[i].update((pkt['xid'], pkt['opcode'])
+                                   for pkt in pkts
+                                   if pkt['xid'] not in SPECIAL_XIDS)
+                continue
+            if bads[i]:
+                # Exact scalar-error parity: re-run this stream through
+                # the connection's own codec, which raises BAD_LENGTH/
+                # BAD_DECODE with the pre-error packets attached.
+                self._deliver_fallback(conn, buf)
+                continue
+            n = n_frames[i]
+            if pkts is None:
+                pkts, err = self._assemble_stream(conn, buf, st, bd,
+                                                  rows[i], n)
+            else:
+                err = self._decode_error(errors[i]) if i in errors \
+                    else None
+            if resids[i]:
+                del buf[:resids[i]]
+            self.frames_routed += n
+            routed += n
+            if pkts or err is not None:
+                if lane is None:
+                    conn.emit('ingestDeliver', pkts, err)
+                else:
+                    laned += lane(pkts, err, now)
+            if err is None and n == max_frames and len(buf) >= 4:
+                retick = True   # the frame bound was hit: more may wait
+        if retick:
+            self._schedule()
+        return laned, routed - laned
+
+    def _decode_batch(self, streams, n_frames, resids, bads):
+        """C fast path for ``body_mode='host'``: every stream's
+        device-delimited complete-frame slice decoded in ONE call of
+        the C-extension decoder — the same code the scalar drain runs,
+        so parity is by construction, at C speed.  The device scan
+        already proved each slice frame-complete and length-valid
+        (``bad`` streams take :meth:`_deliver_fallback`).  Returns
+        None when no stream's codec has the extension, else
+        ``(lens, maps, pkts, counts, errors)``: ``lens[i]`` the bytes
+        of stream ``i`` that were decoded — 0 for one left to
+        :meth:`_deliver_fallback` or :meth:`_assemble_stream` (bad, no
+        complete frame, a codec without the extension) — against
+        ``maps[i]``; the packets of all of them in ONE flat list,
+        ``counts[i]`` each; ``errors[i]`` where a stream's decode
+        failed.  No buffer is consumed here: the route does that,
+        stream by stream."""
+        ext = None
+        bufs, lens, maps = [], [], []
+        for (conn, buf, _lane), n, resid, bad in zip(
+                streams, n_frames, resids, bads):
+            codec = conn.codec
+            if bad or not n or codec.ext is None:
+                resid = 0
+            else:
+                ext = codec.ext     # the process has one
+            bufs.append(buf)
+            lens.append(resid)
+            maps.append(codec.xid_map)
+        if ext is None:
+            return None
+        pkts, counts, _consumed, errors = ext.decode_streams(
+            bufs, lens, maps, MAX_PACKET)
+        return lens, maps, pkts, counts, errors
+
+    @staticmethod
+    def _decode_error(what) -> ZKProtocolError:
+        """One stream's entry in ``decode_streams``' errors, typed as
+        the scalar drain types it."""
+        if isinstance(what, BaseException):
+            err = ZKProtocolError('BAD_DECODE',
+                'Failed to decode Response: %s: %s'
+                % (type(what).__name__, what))
+            err.__cause__ = what
+            return err
+        return ZKProtocolError(*what)
 
     def _deliver_scalar(self, conn: 'ZKConnection', buf: bytearray,
                         keep_stream: bool = True) -> None:
@@ -1220,15 +1330,13 @@ class FleetIngest:
     # -- host packet assembly --
 
     def _assemble_stream(self, conn, buf, st, bd, i: int, n: int):
-        """Build the packet dicts for stream ``i``'s ``n`` frames.
-        Returns (packets, err); a decode failure mid-stream keeps the
-        packets decoded before it, like PacketCodec.decode."""
+        """Build the packet dicts for stream ``i``'s ``n`` frames from
+        the tick's planes (the streams :meth:`_decode_batch` took never
+        come here).  Returns (packets, err); a decode failure
+        mid-stream keeps the packets decoded before it, like
+        PacketCodec.decode."""
         if not n:
             return [], None
-        if bd is None:
-            ext = conn.codec.ext
-            if ext is not None:
-                return self._assemble_ext(conn, buf, st, ext, i)
         pkts: list[dict] = []
         xid_map = conn.codec.xid_map
         # bulk-convert the header planes for this stream to Python ints
@@ -1268,39 +1376,6 @@ class FleetIngest:
                     err.__cause__ = e
                     return pkts, err
             pkts.append(pkt)
-        return pkts, None
-
-    def _assemble_ext(self, conn, buf, st, ext, i: int):
-        """C fast path for ``body_mode='host'``: decode stream ``i``'s
-        device-delimited complete-frame slice in one zero-copy pass of
-        the C-extension decoder — the same code the scalar drain runs,
-        so parity is by construction, at C speed.  The device scan
-        already proved the slice frame-complete and length-valid
-        (``bad`` streams took :meth:`_deliver_fallback`)."""
-        resid = int(st.resid[i])
-        if not resid:
-            return [], None
-        mv = memoryview(buf)
-        sl = mv[:resid]
-        try:
-            pkts, _consumed, kind, msg = ext.decode_responses(
-                sl, conn.codec.xid_map, MAX_PACKET)
-        except Exception as e:
-            err = ZKProtocolError('BAD_DECODE',
-                'Failed to decode Response: %s: %s'
-                % (type(e).__name__, e))
-            err.__cause__ = e
-            return [], err
-        finally:
-            # Release the views NOW: an exception's traceback (kept
-            # alive via err.__cause__) can pin the call frame and with
-            # it the buffer export, and an exported bytearray cannot
-            # be resized — the caller's `del buf[:resid]` would raise
-            # BufferError and kill the whole tick.
-            sl.release()
-            mv.release()
-        if kind is not None:
-            return pkts, ZKProtocolError(kind, msg)
         return pkts, None
 
     def _read_body(self, pkt, buf, st, bd, i: int, f: int) -> None:

@@ -55,6 +55,9 @@ class ZKSession(FSM):
         #: the session timeout (reference: lib/zk-session.js:77-87).
         self.last_pkt: float | None = None
         self.expiry_timer = EventEmitter()
+        #: What state ``attached`` registered for its connection's
+        #: 'packet' event (as the emitter holds it), else None.
+        self.packet_listener = None
         self._expiry_handle: asyncio.TimerHandle | None = None
         self._expiry_deadline = 0.0
         self._expiry_at = 0.0      # when the pending handle will fire
@@ -147,8 +150,11 @@ class ZKSession(FSM):
                 % (self.get_state(),))
         self.emit('assertAttach', conn)
 
-    def reset_expiry_timer(self) -> None:
-        """Push the expiry deadline out by one session timeout.
+    def reset_expiry_timer(self, now: float | None = None) -> None:
+        """Push the expiry deadline out by one session timeout from
+        ``now`` (``time.monotonic()``; the fleet ingest reads that
+        clock once a tick and hands it down, everyone else leaves it
+        out).
 
         Called on every received packet, so it must be cheap: the
         deadline is just a number, and ONE lazy timer chases it — when
@@ -156,7 +162,8 @@ class ZKSession(FSM):
         reschedules for the remainder instead of expiring.  Avoids a
         cancel + heap insertion per packet (this showed up in the e2e
         runtime profile)."""
-        now = time.monotonic()
+        if now is None:
+            now = time.monotonic()
         self.last_pkt = now * 1000.0
         self._expiry_deadline = now + self.timeout / 1000.0
         if self._expiry_handle is None:
@@ -306,7 +313,11 @@ class ZKSession(FSM):
                     self.last_zxid = pkt['zxid']
                 return
             self.process_notification(pkt)
-        S.on(self.conn, 'packet', on_packet)
+        # The connection's direct settle lane (io/connection.py)
+        # restates on_packet's reply half for a run of plain replies,
+        # and only while this very listener is the connection's one
+        # 'packet' listener: leaving the state removes it.
+        self.packet_listener = S.on(self.conn, 'packet', on_packet)
 
         S.on(self.expiry_timer, 'timeout', lambda: S.goto_state('expired'))
         S.on(self, 'closeAsserted', lambda: S.goto_state('closing'))

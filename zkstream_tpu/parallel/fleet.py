@@ -214,7 +214,7 @@ class MultihostFleetIngest(MeshFleetIngest):
     def _schedule(self) -> None:
         pass
 
-    def register(self, conn) -> None:
+    def register(self, conn, lane=None) -> None:
         # Never raise here: register runs inside the connection FSM's
         # state-entry handler, and an exception there would strand a
         # half-wired connection.  Overflow connections get no row —
@@ -231,7 +231,7 @@ class MultihostFleetIngest(MeshFleetIngest):
                     '(local_rows=%d); overflow connections are served '
                     'by the scalar drain — size the proxy for the '
                     'host\'s connection budget', self.local_rows)
-        super().register(conn)
+        super().register(conn, lane)
 
     def unregister(self, conn) -> None:
         row = self._rows.pop(id(conn), None)
@@ -341,7 +341,8 @@ class MultihostFleetIngest(MeshFleetIngest):
         lens = np.zeros((self.local_rows,), np.int32)
         active = {}
         overflow = []
-        for cid, (conn, buf) in list(self._slots.items()):
+        for cid, slot in list(self._slots.items()):
+            conn, buf, _lane = slot
             if not buf or not conn.is_in_state('connected'):
                 continue
             row = self._rows.get(cid)
@@ -352,7 +353,7 @@ class MultihostFleetIngest(MeshFleetIngest):
             batch[row, :n] = np.frombuffer(memoryview(buf)[:n],
                                            np.uint8)
             lens[row] = n
-            active[row] = (conn, buf)
+            active[row] = slot
         return batch, lens, active, overflow
 
     def _mh_tick(self) -> None:
@@ -404,19 +405,22 @@ class MultihostFleetIngest(MeshFleetIngest):
             return
         self.ticks += 1
 
-        for row, (conn, buf) in active.items():
-            # an earlier row's delivery callback may have torn this
-            # connection down mid-tick (unregister already restored
-            # its bytes to the codec)
-            if id(conn) not in self._slots:
-                continue
+        streams, rows = [], []
+        for row, slot in active.items():
+            conn, buf, _lane = slot
             if (int(st.n_frames[row]) == 0 and not bool(st.bad[row])
                     and int(st.resid[row]) == 0
                     and len(buf) >= self.stream_len):
                 # a single frame larger than stream_len can never fit
                 # a fixed-shape tick: drain this stream through the
                 # scalar codec (which has no length bound) instead of
-                # re-dispatching the same prefix forever
-                self._deliver_scalar(conn, buf)
+                # re-dispatching the same prefix forever — unless an
+                # earlier delivery's callback tore the connection down
+                # mid-tick (unregister already restored its bytes to
+                # the codec)
+                if id(conn) in self._slots:
+                    self._deliver_scalar(conn, buf)
                 continue
-            self._route_stream(conn, buf, st, bd, row)
+            streams.append(slot)
+            rows.append(row)
+        self._route_batch(streams, rows, st, bd)

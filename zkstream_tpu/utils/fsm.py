@@ -94,13 +94,18 @@ class StateScope:
         self._disposers: list[Callable[[], None]] = []
         self._valid = True
 
-    def on(self, emitter: EventEmitter, event: str, cb: Callable) -> None:
+    def on(self, emitter: EventEmitter, event: str,
+           cb: Callable) -> Callable:
+        """Returns the listener as the emitter holds it (``cb`` behind
+        the scope's validity guard): in ``emitter.listeners(event)``
+        exactly as long as the scope lives."""
         def guarded(*args):
             if self._valid:
                 cb(*args)
         emitter.on(event, guarded)
         self._disposers.append(
             lambda: emitter.remove_listener(event, guarded))
+        return guarded
 
     def timeout(self, ms: float,
                 cb: Callable[[], None]) -> asyncio.TimerHandle:

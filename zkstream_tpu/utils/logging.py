@@ -74,6 +74,11 @@ class Logger:
     def trace(self, msg: str, *args) -> None:
         self._log(TRACE, msg, *args)
 
+    def enabled_for_trace(self) -> bool:
+        """Would :meth:`trace` write anything?  For a caller that
+        asks once for a run of lines instead of once a line."""
+        return self.base.isEnabledFor(TRACE)
+
     def debug(self, msg: str, *args) -> None:
         self._log(_logging.DEBUG, msg, *args)
 

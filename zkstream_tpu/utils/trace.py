@@ -71,7 +71,7 @@ TRACE_SCHEMA = 3
 #: setattr path populated it.
 _OPTIONAL_FIELDS = ('path', 'xid', 'zxid', 'backend', 'session_id',
                     'member', 'batch', 'nbytes', 'detail', 'error',
-                    'parent', 'tick', 't0_ns', 't1_ns')
+                    'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted')
 
 
 class Span:
@@ -82,7 +82,7 @@ class Span:
                  'backend', 'session_id', 'status', 'error',
                  't_wall', '_t0', 'duration_ms',
                  'member', 'batch', 'nbytes', 'detail', '_on_slow',
-                 'parent', 'tick', 't0_ns', 't1_ns')
+                 'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted')
 
     def __init__(self, span_id: int, op: str, path: str | None = None,
                  kind: str = 'op'):
@@ -110,6 +110,11 @@ class Span:
         self.tick: int | None = None
         self.t0_ns: int | None = None
         self.t1_ns: int | None = None
+        #: ``ingest.route`` only: the frames of the tick that were
+        #: settled through the connections' direct lanes, and those
+        #: handed to the ``'ingestDeliver'`` emitter path.
+        self.lane: int | None = None
+        self.emitted: int | None = None
         self.status: str = 'open'
         self.error: str | None = None
         self.t_wall = time.time()
@@ -236,6 +241,8 @@ class TraceRing:
         span.tick = None
         span.t0_ns = None
         span.t1_ns = None
+        span.lane = None
+        span.emitted = None
         span.status = 'ok'
         span.error = None
         span.t_wall = time.time()
