@@ -123,6 +123,11 @@ def _ingest_for(dev, **kw):
     # bucket used to fail to compile and drain scalar for the life of
     # the process; the dispatcher now builds it from jnp, and says so
     (8192, 8192, 'jnp'),
+    # the size classes a tick of large replies dispatches: a full
+    # dispatch of the 1 MiB class, and the one row of the widest class
+    # (a frame at the 16 MiB cap)
+    (16, 1 << 20, 'jnp'),
+    (1, 1 << 25, 'jnp'),
 ])
 def test_host_body_tick_bucket_compiles_for_v5e(v5e, Bp, L, impl):
     ing = _ingest_for(v5e, body_mode='host', max_frames=32)

@@ -1,0 +1,467 @@
+"""The fleet ingest's size classes against the per-socket scalar drain.
+
+A tick's rows are dispatched by width (io/ingest.py, "Size classes"):
+one dispatch a power-of-two class present, a slot whose first frame is
+not whole yet waits, one dispatch holds at most ``DISPATCH_BYTES``.
+The reference is, as in tests/test_ingest_route.py, the same real
+``ZKConnection`` + ``ZKSession`` without an ingest: one seeded corpus
+of heavy-tailed reply sizes, cut into seeded random chunks, goes
+through both, and what every connection observed must be identical.
+Beside parity: what the tick copied, padded and deferred (its
+always-on counters), and what ``prewarm`` compiles.
+"""
+
+import random
+
+import numpy as np
+import pytest
+
+from test_ingest_route import Peer, _Time, settle
+from zkstream_tpu.io import ingest as ingest_mod
+from zkstream_tpu.io import session as session_mod
+from zkstream_tpu.io.ingest import FleetIngest
+from zkstream_tpu.protocol.records import Stat
+from zkstream_tpu.utils import native
+
+MIN_LEN = 256
+#: a GET_DATA reply frame over its data: length prefix, reply header,
+#: the data's own length, the Stat
+OVERHEAD = 4 + 16 + 4 + 68
+
+
+def _ingest(**kw) -> FleetIngest:
+    kw.setdefault('max_frames', 4)
+    return FleetIngest(bypass_bytes=0, warm='block', placement='host',
+                       min_len=MIN_LEN, **kw)
+
+
+def reply_sized(p: Peer, xid: int, size: int) -> int:
+    """A GET_DATA reply of ``size`` data bytes onto the peer's wire;
+    returns the frame's bytes (prefix included)."""
+    before = len(p.wire)
+    p.wire += p.srv.encode({
+        'xid': xid, 'zxid': p._next_zxid(), 'err': 'OK',
+        'opcode': 'GET_DATA', 'data': p.rng.randbytes(size),
+        'stat': Stat(*(p.rng.randrange(1 << 20) for _ in range(11)))})
+    return len(p.wire) - before
+
+
+def heavy_tailed(rng, top: int = 1 << 18) -> int:
+    """A size whose class is uniform over 1 KiB .. ``top``'s and below
+    (log-uniform from 16 B): many small, a few near ``top``."""
+    return min(top - OVERHEAD, int(16 * 2 ** (rng.random() * 14.5)))
+
+
+async def feed_chunked(peers, rng) -> None:
+    """Every peer's wire, cut into seeded random chunks, handed to its
+    connection round-robin, the loop turning between rounds."""
+    wires = [bytes(p.wire) for p in peers]
+    for p in peers:
+        p.wire = bytearray()
+    offs = [0] * len(peers)
+    while any(o < len(w) for o, w in zip(offs, wires)):
+        for i, p in enumerate(peers):
+            if offs[i] < len(wires[i]) and rng.random() < 0.8:
+                n = rng.choice((1, 3, 200, 4096, 65536, 1 << 20))
+                n = rng.randrange(1, n + 1)
+                p.conn.emit('sockData', wires[i][offs[i]:offs[i] + n])
+                offs[i] += n
+        await settle()
+    await settle()
+
+
+async def run_corpus(through_ingest: bool, use_native: bool, seed: int,
+                     watch=None):
+    ingest = _ingest() if through_ingest else None
+    if watch is not None and ingest is not None:
+        watch(ingest)
+    peers = [Peer(i, ingest, use_native, random.Random(seed * 977 + i))
+             for i in range(10)]
+    rng = random.Random(seed)
+    try:
+        for p in peers:
+            for _ in range(rng.randrange(3, 9)):
+                reply_sized(p, p.get(), heavy_tailed(p.rng))
+        await feed_chunked(peers, rng)
+        snaps = [p.snapshot(ingest) for p in peers]
+    finally:
+        for p in peers:
+            p.session.close()
+            p.conn.destroy()
+        await settle()
+        if ingest is not None:
+            ingest.close()
+    return snaps, ingest
+
+
+def _patch_clock(monkeypatch) -> None:
+    monkeypatch.setattr(session_mod, 'time', _Time)
+    monkeypatch.setattr(ingest_mod, 'time', _Time)
+
+
+def _codec(use_native: bool, monkeypatch) -> None:
+    if use_native:
+        if native.ensure_ext() is None:
+            pytest.skip('no C extension here (no compiler)')
+    else:
+        monkeypatch.setenv('ZKSTREAM_NO_NATIVE', '1')
+
+
+@pytest.mark.parametrize('use_native', [True, False],
+                         ids=['ext', 'no_native'])
+@pytest.mark.parametrize('seed', [3, 30, 300])
+async def test_heavy_tailed_streams_equal_the_scalar_drain(
+        seed, use_native, monkeypatch):
+    """Classes 1 KiB .. 256 KiB at ``min_len`` 256, any chunking: per
+    stream and in order exactly what the scalar drain delivers; every
+    dispatch wider than ``min_len`` pads to under four times its
+    payload (or is the 8 x ``min_len`` floor); nothing was drained off
+    the device."""
+    _codec(use_native, monkeypatch)
+    _patch_clock(monkeypatch)
+    seen: list = []
+
+    def watch(ingest):
+        # the ticks' batch memory is used again and never zeroed: what
+        # an earlier tick left in the padding must not matter
+        ingest._arena = np.full((ingest.TICK_BYTES,), 0xFF, np.uint8)
+        inner = ingest._tick_inner
+
+        def noted(plans, sp, t0):
+            seen.extend((key, nbytes, len(streams))
+                        for _ex, key, streams, _b, _l, nbytes in plans)
+            return inner(plans, sp, t0)
+        ingest._tick_inner = noted
+
+    want, _none = await run_corpus(False, use_native, seed)
+    got, ingest = await run_corpus(True, use_native, seed, watch)
+    for i, (w, g) in enumerate(zip(want, got)):
+        assert g == w, 'connection %d differs' % i
+    assert sum(len(w['log']) for w in want) > 40
+    assert ingest.ticks and not (ingest.ticks_scalar
+                                 or ingest.ticks_warming)
+    widths = {key[2] for key, _n, _r in seen}
+    assert len(widths) >= 6 and max(widths) >= 1 << 17
+    for (_dev, bp, width), nbytes, rows in seen:
+        assert rows <= bp and nbytes <= bp * width
+        if width > MIN_LEN:
+            assert bp * width < 4 * nbytes or bp * width <= 8 * MIN_LEN
+    assert ingest.dispatches == len(seen) > ingest.ticks
+    assert ingest.bytes_batched == sum(n for _k, n, _r in seen)
+    assert ingest.bytes_dispatched == sum(k[1] * k[2] for k, _n, _r in seen)
+    # a reply that came in pieces waited for its last one
+    assert ingest.slots_deferred > 0
+
+
+async def _one_peer(ingest, seed: int = 5) -> Peer:
+    return Peer(0, ingest, native.ensure_ext() is not None,
+                random.Random(seed))
+
+
+def _futs(p: Peer) -> list:
+    return [(e[1], len(e[2]['data'])) for e in p.log if e[0] == 'fut']
+
+
+async def test_large_frame_in_pieces_is_batched_once():
+    """A 200 KiB reply over 25 reads: the slot sits the ticks out (the
+    host reads the first frame's length prefix, no more) and the bytes
+    are copied into a batch once, when the frame is whole."""
+    ingest = _ingest()
+    p = await _one_peer(ingest)
+    total = reply_sized(p, p.get(), 200 * 1024)
+    wire, p.wire = bytes(p.wire), bytearray()
+    for lo in range(0, total, 8192):
+        p.conn.emit('sockData', wire[lo:lo + 8192])
+        await settle()
+    assert _futs(p) == [(1, 200 * 1024)]
+    assert ingest.bytes_batched == total
+    assert ingest.bytes_recopied == 0
+    assert ingest.dispatches == ingest.ticks == 1
+    assert ingest.bytes_dispatched == 1 << 18       # [1, 256 KiB]
+    assert ingest.slots_deferred == (total - 1) // 8192
+    p.conn.destroy()
+    ingest.close()
+
+
+async def test_small_frame_then_partial_large_one_in_one_slot():
+    """The small reply is delivered at once; of the partial large one
+    behind it the tick copies no more than the frame bound could have
+    consumed at the small one's size, and then it waits like any
+    partial first frame: copied once more, whole."""
+    ingest = _ingest()
+    p = await _one_peer(ingest)
+    small = reply_sized(p, p.get(), 8)
+    large = reply_sized(p, p.get(), 150 * 1024)
+    wire, p.wire = bytes(p.wire), bytearray()
+    p.conn.emit('sockData', wire[:small + 100 * 1024])
+    await settle()
+    assert _futs(p) == [(1, 8)]
+    cut = 512               # the power of two over max_frames x small
+    assert small * ingest.max_frames <= cut < 2 * small * ingest.max_frames
+    assert ingest.bytes_batched == cut
+    assert ingest.bytes_recopied == cut - small
+    p.conn.emit('sockData', wire[small + 100 * 1024:])
+    await settle()
+    assert _futs(p) == [(1, 8), (2, 150 * 1024)]
+    assert ingest.bytes_batched == cut + large
+    assert ingest.bytes_recopied == cut - small
+    assert ingest.slots_deferred >= 1
+    p.conn.destroy()
+    ingest.close()
+
+
+async def test_wide_row_meets_the_frame_bound():
+    """Six 3 KiB replies in one slot at ``max_frames`` 4: the row is
+    the 16 KiB the bound can consume, four frames route, the follow-up
+    tick takes the rest — in order."""
+    ingest = _ingest()
+    p = await _one_peer(ingest)
+    for _ in range(6):
+        reply_sized(p, p.get(), 3 * 1024)
+    p.flush()
+    await settle()
+    assert _futs(p) == [(x, 3 * 1024) for x in range(1, 7)]
+    assert ingest.ticks == 2 and ingest.frames_routed == 6
+    assert sorted(k[2] for k in ingest.buckets) == [1 << 13, 1 << 14]
+    p.conn.destroy()
+    ingest.close()
+
+
+async def test_frame_wider_than_a_dispatch_still_routes_on_the_device():
+    """``DISPATCH_BYTES`` bounds the rows of a class, not a frame: one
+    wider than it is a dispatch of its own one row."""
+    ingest = _ingest()
+    ingest.DISPATCH_BYTES = 1 << 16
+    peers = [Peer(i, ingest, native.ensure_ext() is not None,
+                  random.Random(i)) for i in range(3)]
+    for p, size in zip(peers, (200 * 1024, 30 * 1024, 30 * 1024)):
+        reply_sized(p, p.get(), size)
+        p.flush()
+    await settle()
+    assert [_futs(p) for p in peers] == [
+        [(1, 200 * 1024)], [(1, 30 * 1024)], [(1, 30 * 1024)]]
+    assert ingest.ticks == 1 and not ingest.ticks_scalar
+    # the two 32 KiB rows fill a dispatch; the 256 KiB row is alone
+    assert sorted(ingest.buckets) == [(False, 1, 1 << 18),
+                                      (False, 2, 1 << 15)]
+    for p in peers:
+        p.conn.destroy()
+    ingest.close()
+
+
+async def test_a_class_beyond_the_dispatch_bound_splits():
+    """Five 30 KiB rows at a 64 KiB bound: 2 + 2 + 1, one tick, each
+    stream in one dispatch."""
+    ingest = _ingest()
+    ingest.DISPATCH_BYTES = 1 << 16
+    peers = [Peer(i, ingest, native.ensure_ext() is not None,
+                  random.Random(i)) for i in range(5)]
+    for p in peers:
+        reply_sized(p, p.get(), 30 * 1024)
+        p.flush()
+    await settle()
+    assert all(_futs(p) == [(1, 30 * 1024)] for p in peers)
+    assert ingest.ticks == 1 and ingest.dispatches == 3
+    assert ingest.bytes_dispatched == (2 + 2 + 1) << 15
+    for p in peers:
+        p.conn.destroy()
+    ingest.close()
+
+
+async def test_a_full_tick_leaves_the_rest_to_the_follow_up_tick():
+    """``TICK_BYTES`` bounds what one tick dispatches (its batches lie
+    side by side in memory every tick uses again): five 30 KiB rows,
+    one a dispatch, two dispatches a tick — three ticks, every reply
+    delivered."""
+    ingest = _ingest()
+    ingest.DISPATCH_BYTES = 1 << 15
+    ingest.TICK_BYTES = 1 << 16
+    peers = [Peer(i, ingest, native.ensure_ext() is not None,
+                  random.Random(i)) for i in range(5)]
+    for p in peers:
+        reply_sized(p, p.get(), 30 * 1024)
+        p.flush()
+    await settle()
+    await settle()
+    assert all(_futs(p) == [(1, 30 * 1024)] for p in peers)
+    assert ingest.ticks == 3 and ingest.dispatches == 5
+    assert len(ingest._arena) == 1 << 16
+    for p in peers:
+        p.conn.destroy()
+    ingest.close()
+
+
+async def test_bad_prefix_behind_the_classes_is_the_scalar_error():
+    """A length prefix no frame can have is the device's to flag, wide
+    slot or not: the stream dies with the scalar drain's error."""
+    ingest = _ingest()
+    p = await _one_peer(ingest)
+    p.raw(b'\xff\xff\xff\xf0' + b'x' * 5000)
+    p.flush()
+    await settle()
+    assert p.conn.last_error is not None
+    assert getattr(p.conn.last_error, 'code', None) == 'BAD_LENGTH'
+    assert ingest.bytes_batched == MIN_LEN      # not the 5 KB behind it
+    p.session.close()
+    await settle()
+    ingest.close()
+
+
+@pytest.mark.parametrize('n,nbytes,bound,key', [
+    (1, None, 16 << 20, (False, 8, 256)),          # as before classes
+    (24, None, 16 << 20, (False, 32, 256)),
+    (1024, None, 16 << 20, (False, 1024, 256)),
+    (1, 300, 16 << 20, (False, 4, 512)),           # 8 x min_len floor
+    (3, 1024, 16 << 20, (False, 4, 1024)),
+    (1, 4096, 16 << 20, (False, 1, 4096)),
+    (100, 1 << 16, 1 << 18, (False, 4, 1 << 16)),  # the dispatch bound
+    (100, 1 << 16, 1 << 14, (False, 1, 1 << 16)),
+])
+async def test_prewarm_compiles_the_bucket_asked_for(n, nbytes, bound,
+                                                     key):
+    ingest = _ingest()
+    ingest.DISPATCH_BYTES = bound
+    await (ingest.prewarm(n) if nbytes is None
+           else ingest.prewarm(n, nbytes))
+    assert list(ingest.buckets) == [key]
+    assert ingest.buckets[key]['error'] is None
+    ingest.close()
+
+
+async def test_no_bucket_after_warmup_in_a_run_of_mixed_sizes(
+        monkeypatch):
+    """Warmed as a deployment warms (every class its replies reach x
+    every row count its fleet can give a dispatch), a run of mixed
+    sizes compiles nothing."""
+    _patch_clock(monkeypatch)
+
+    def watch(ingest):
+        async def warm():
+            # a slot may give max_frames replies of the largest size
+            width = MIN_LEN
+            while width <= ingest.max_frames << 18:
+                rows = 1
+                while rows <= 16:
+                    await ingest.prewarm(rows, width)
+                    rows *= 2
+                width *= 2
+        # run_corpus is already inside the loop: warm synchronously
+        # (warm='block' never awaits)
+        coro = warm()
+        with pytest.raises(StopIteration):
+            coro.send(None)
+        ingest.warmed = set(ingest.buckets)
+
+    _snaps, ingest = await run_corpus(True, native.ensure_ext() is not None,
+                                      31, watch)
+    assert ingest.ticks > 5
+    assert set(ingest.buckets) == ingest.warmed
+    assert not any(b['error'] for b in ingest.buckets.values())
+
+
+async def test_mesh_ingest_adds_up_a_ticks_dispatches():
+    """The mesh proxy's fleet stats are a tick's, not its last
+    dispatch's: a tick of two classes is two collective launches."""
+    from zkstream_tpu.parallel.fleet import MeshFleetIngest
+
+    ingest = MeshFleetIngest(min_len=MIN_LEN, max_frames=4, warm='block')
+    peers = [Peer(i, ingest, native.ensure_ext() is not None,
+                  random.Random(i)) for i in range(4)]
+    for p, size in zip(peers, (10, 10, 5000, 40000)):
+        reply_sized(p, p.get(), size)
+        reply_sized(p, p.get(), size)
+        p.flush()
+    await settle()
+    assert [_futs(p) for p in peers] == [
+        [(1, s), (2, s)] for s in (10, 10, 5000, 40000)]
+    assert ingest.ticks == 1 and ingest.dispatches == 3
+    assert ingest.global_stats['total_frames'] == 8
+    assert ingest.global_stats['total_replies'] == 8
+    assert ingest.global_stats['max_zxid'] == max(p.zxid for p in peers)
+    assert ingest.fleet_max_zxid == ingest.global_stats['max_zxid']
+    for p in peers:
+        p.conn.destroy()
+    ingest.close()
+
+
+async def test_spans_of_a_tick_of_two_classes(monkeypatch):
+    """In a profiler session: one ``ingest.tick``, under it one
+    ``ingest.batch``, an ``ingest.dispatch`` (with the dispatch's
+    ``rows``, ``width`` and payload ``nbytes``) and an
+    ``ingest.readback`` a size class, one ``ingest.route`` — all
+    carrying the tick's number."""
+    from zkstream_tpu.utils import trace
+
+    ingest = _ingest()
+    peers = [Peer(i, ingest, native.ensure_ext() is not None,
+                  random.Random(i)) for i in range(3)]
+    frames = [reply_sized(p, p.get(), size)
+              for p, size in zip(peers, (10, 20, 3000))]
+    await ingest.prewarm(2)
+    await ingest.prewarm(1, 3000)
+    trace.host_ring.reset()
+    monkeypatch.setattr(trace, '_is_enabled', lambda: True)
+    monkeypatch.setattr(trace, '_recording', True)
+    for p in peers:
+        p.flush()
+    await settle()
+    monkeypatch.setattr(trace, '_is_enabled', lambda: False)
+    spans = [s for s in trace.host_ring.spans()
+             if s.op.startswith('ingest.')]
+    assert [s.op for s in spans] == [
+        'ingest.batch', 'ingest.dispatch', 'ingest.dispatch',
+        'ingest.readback', 'ingest.readback', 'ingest.route',
+        'ingest.tick']
+    tick = spans[-1]
+    assert {s.tick for s in spans} == {1} and tick.parent is None
+    assert all(s.parent == 'ingest.tick' for s in spans[:-1])
+    assert tick.detail == 'device 2 dispatches streams=3'
+    assert tick.batch == 3 and tick.nbytes == sum(frames)
+    narrow, wide = spans[1], spans[2]
+    assert (narrow.rows, narrow.width, narrow.nbytes) == (
+        2, MIN_LEN, frames[0] + frames[1])
+    assert (wide.rows, wide.width, wide.nbytes) == (1, 4096, frames[2])
+    assert spans[5].lane == 3 and spans[5].emitted == 0
+    assert 'rows' in wide.to_dict() and 'rows' not in tick.to_dict()
+    trace.host_ring.reset()
+    for p in peers:
+        p.conn.destroy()
+    ingest.close()
+
+
+async def test_device_bodies_read_nothing_of_a_stale_batch(monkeypatch):
+    """``body_mode='device'``: the body planes too are cut from the
+    rows' own bytes — replies of two classes over batch memory full of
+    an earlier tick's bytes equal the scalar drain's."""
+    _patch_clock(monkeypatch)
+    use_native = native.ensure_ext() is not None
+
+    async def run(ingest):
+        if ingest is not None:
+            ingest._arena = np.full((ingest.TICK_BYTES,), 0xA5, np.uint8)
+        peers = [Peer(i, ingest, use_native, random.Random(40 + i))
+                 for i in range(4)]
+        for p, size in zip(peers, (0, 100, 200, 700)):
+            reply_sized(p, p.get(), size)
+            reply_sized(p, p.get(), size // 2)
+            p.flush()
+        await settle()
+        snaps = [p.snapshot(ingest) for p in peers]
+        for p in peers:
+            p.session.close()
+            p.conn.destroy()
+        await settle()
+        return snaps
+
+    want = await run(None)
+    ingest = _ingest(body_mode='device', max_data=256)
+    for rows, nbytes in ((1, 256), (2, 512), (1, 2048)):
+        await ingest.prewarm(rows, nbytes)  # seconds each: not in the run
+    got = await run(ingest)
+    assert len(ingest.buckets) == 3
+    for i, (w, g) in enumerate(zip(want, got)):
+        assert g == w, 'connection %d differs in %s' % (
+            i, [k for k in w if w[k] != g[k]])
+    assert ingest.ticks == 1 and ingest.dispatches == 3
+    assert ingest.body_fallbacks == 2     # the 700 B and 350 B bodies
+    ingest.close()

@@ -451,8 +451,13 @@ async def run_case(case, through_ingest: bool, use_native: bool,
                    seed: int):
     ingest = None
     if through_ingest:
-        ingest = FleetIngest(bypass_bytes=0, warm='block',
-                             placement='host', max_frames=4, min_len=256)
+        # one size class for the case whose callback closes a LATER
+        # stream of the same tick: streams route in slot order within a
+        # class's dispatch, classes narrowest first
+        ingest = FleetIngest(
+            bypass_bytes=0, warm='block', placement='host', max_frames=4,
+            min_len=1024 if case == 'callback_closes_later_connection'
+            else 256)
     lanes: list = []
     if ingest is not None:
         route = ingest._route_batch

@@ -5,10 +5,11 @@ The reference drains every connection with its own scalar loop — bytes
 (lib/zk-streams.js:39-99, lib/connection-fsm.js:213-229).  This module
 replaces that per-socket drain at fleet scale: N live connections
 append their received bytes to per-connection accumulators, and a
-per-event-loop-tick batcher pads them into one [B, L] tensor, runs
+per-event-loop-tick batcher pads those whose first frame is whole into
+[B, L] tensors — one a **size class** present (below) — runs
 :func:`zkstream_tpu.ops.pipeline.wire_pipeline_step` (plus, in
 ``body_mode='device'``, :func:`~zkstream_tpu.ops.replies.parse_reply_bodies`)
-in a single device dispatch, and routes the results back on host —
+in one device dispatch each, and routes the results back on host —
 reply packets to each connection's pending-request futures via the
 normal ``packet``/``process_reply`` path, notifications to the session
 watcher engine.  Observable semantics are identical to the scalar
@@ -40,7 +41,27 @@ exactly.
 
 The tick is synchronous inside the event loop: all ``data_received``
 callbacks of one select cycle run before the ``call_soon``-scheduled
-tick, so one dispatch coalesces everything the loop just read.
+tick, so one tick coalesces everything the loop just read.
+
+**Size classes.**  A tick's rows are dispatched by width: a row's
+class is the power of two that holds the bytes it gives the tick, from
+``min_len`` up to the one that holds a frame at the 16 MiB cap, and
+each class present is a dispatch of its own (``[Bp, L]`` through
+:meth:`FleetIngest._bucket`), so the bytes a tick pads, sends and
+scans follow the bytes it routes — under four times them in every
+class wider than ``min_len`` — and not ``rows x longest row``.  One
+dispatch holds at most :attr:`FleetIngest.DISPATCH_BYTES` padded
+bytes; a class's further rows go to the next dispatch of the same
+tick; a tick's dispatches are in flight together and hold at most
+:attr:`FleetIngest.TICK_BYTES` (what does not fit waits for the
+follow-up tick).  A slot
+whose buffered bytes do not yet hold its first frame whole waits (the
+host reads that frame's 4-byte length prefix and nothing else: the
+frame scan stays on the device), so a large reply that arrives over
+many reads is copied into a batch once.  A stream is in one dispatch a
+tick.  With one class present — every tick of a fleet of small
+replies — the tick is one dispatch, as it was before there were
+classes.
 
 **No tick ever blocks on XLA.**  Compiling the tick program for a new
 (batch, length) bucket costs ~1 s on the host CPU backend — 3 orders
@@ -70,6 +91,7 @@ from __future__ import annotations
 
 import asyncio
 import queue
+import struct
 import threading
 import time
 from typing import TYPE_CHECKING
@@ -100,6 +122,10 @@ def _next_pow2(n: int) -> int:
 #: sentinel distinguishing "never compiled" from "compile failed" in
 #: the executable cache
 _MISSING = object()
+
+#: a frame's 4-byte length prefix, and the most bytes a frame has with it
+_PREFIX = struct.Struct('>I').unpack_from
+_FRAME_TOP = MAX_PACKET + 4
 
 METRIC_INGEST_PHASE = 'zkstream_ingest_phase_ms'
 _PHASE_HELP = ('Device tick time by phase, milliseconds (batch: find the '
@@ -187,6 +213,20 @@ class FleetIngest:
     #: cannot flap on tick-to-tick noise).
     FRAG_ENTER = 0.25
     FRAG_EXIT = 0.40
+
+    #: The padded bytes (``Bp x L``) one dispatch may hold; the rows of
+    #: a size class beyond it go to the next dispatch of the same
+    #: tick.  Only a single row wider than this (a frame near the
+    #: 16 MiB cap) makes a larger dispatch.
+    DISPATCH_BYTES = 16 << 20
+    #: The padded bytes ONE TICK may dispatch: its batches are laid
+    #: side by side in one buffer of this size that every tick uses
+    #: again (no allocation and no zeroing a tick: the scan reads
+    #: nothing beyond a row's length, so what an earlier tick left in
+    #: the padding is never looked at), all of them are in flight
+    #: together, so the device holds this much + the packed results,
+    #: and what does not fit waits in its slots for the follow-up tick.
+    TICK_BYTES = 4 * DISPATCH_BYTES
 
     def __init__(self, max_frames: int = 32, body_mode: str = 'host',
                  max_data: int = 256, max_path: int = 256,
@@ -278,6 +318,19 @@ class FleetIngest:
         #: ticks routed to the scalar drain by the fragmentation guard
         self.ticks_frag = 0
         self.frames_routed = 0
+        #: What the device ticks moved: dispatches made (one a size
+        #: class present a tick, more where a class outgrew
+        #: ``DISPATCH_BYTES``), the stream bytes copied into their
+        #: batches, the padded bytes those batches held (``Bp x L``
+        #: summed), the bytes copied that the tick did not consume (a
+        #: partial frame behind whole ones: they are copied again), and
+        #: the slot-ticks a slot sat out because its first frame was
+        #: not whole yet.
+        self.dispatches = 0
+        self.bytes_batched = 0
+        self.bytes_dispatched = 0
+        self.bytes_recopied = 0
+        self.slots_deferred = 0
         #: Upper dispatch guard: when a large fleet's connections
         #: desynchronize, the tick batches fragment (a small share of
         #: the slots hold a frame) and the per-socket drain is the
@@ -324,6 +377,14 @@ class FleetIngest:
         #: (None when it compiled).
         self.buckets: dict = {}
         self._traced_impl: str | None = None
+        #: the ticks' batch memory (``TICK_BYTES``), made by the first
+        #: device tick
+        self._arena = None
+        #: one compile at a time (``_traced_impl`` is the trace's note
+        #: to the bucket being compiled): a deployment's wide classes
+        #: may be prewarmed from one thread while the fleet's narrow
+        #: buckets are from another
+        self._compile_lock = threading.Lock()
         self._warm_events: dict = {}
         #: background compiles drain FIFO through a one-thread
         #: executor (created lazily): a load pattern hopping several
@@ -590,9 +651,23 @@ class FleetIngest:
     # -- shape-bucket warm-up (AOT compile off the event loop) --
 
     def _bucket(self, n_streams: int, nbytes: int) -> tuple:
-        Bp = _next_pow2(max(n_streams, 8))
-        L = _next_pow2(max(self.min_len, nbytes))
+        """The shape bucket of one dispatch: ``n_streams`` rows of up
+        to ``nbytes`` each.  The width is the size class; the rows pad
+        to a power of two from the count that makes the smallest
+        dispatch ``8 x min_len`` bytes (8 rows in the ``min_len``
+        class, 1 in the classes eight times as wide and wider)."""
+        L = self._width(nbytes)
+        Bp = _next_pow2(max(n_streams, 8 * self.min_len // L, 1))
         return (self.body_mode == 'device', Bp, L)
+
+    def _width(self, nbytes: int) -> int:
+        """The size class of a row of ``nbytes``: its width."""
+        return _next_pow2(max(self.min_len, nbytes))
+
+    def _class_rows(self, nbytes: int) -> int:
+        """How many rows of the size class of ``nbytes`` one dispatch
+        holds."""
+        return max(1, self.DISPATCH_BYTES // self._width(nbytes))
 
     def _compile(self, key: tuple):
         """Lower + AOT-compile the tick program for one shape bucket.
@@ -619,20 +694,21 @@ class FleetIngest:
         (one policy for the inline and background warm paths)."""
         info = self.buckets[key] = {'impl': None, 'platform': None,
                                     'compile_s': None, 'error': None}
-        self._traced_impl = None
-        t0 = time.perf_counter()
-        try:
-            self._resolve_placement()
-            ex = self._compile(key)
-        except Exception as e:
-            info['error'] = '%s: %s' % (type(e).__name__, e)
-            self.log.warning('tick program compile failed for '
-                             'bucket %r: %s', key, e)
-            ex = None
-        else:
-            info['impl'] = self._traced_impl
-            info['platform'] = _executable_platform(ex)
-        info['compile_s'] = time.perf_counter() - t0
+        with self._compile_lock:
+            self._traced_impl = None
+            t0 = time.perf_counter()
+            try:
+                self._resolve_placement()
+                ex = self._compile(key)
+            except Exception as e:
+                info['error'] = '%s: %s' % (type(e).__name__, e)
+                self.log.warning('tick program compile failed for '
+                                 'bucket %r: %s', key, e)
+                ex = None
+            else:
+                info['impl'] = self._traced_impl
+                info['platform'] = _executable_platform(ex)
+            info['compile_s'] = time.perf_counter() - t0
         return ex
 
     def _compile_or_latch(self, key: tuple):
@@ -745,7 +821,20 @@ class FleetIngest:
                 ('zkstream_ingest_frames_routed', 'frames_routed',
                  'frames delivered through the ingest'),
                 ('zkstream_ingest_body_fallbacks', 'body_fallbacks',
-                 'device-body frames that needed the scalar reader')):
+                 'device-body frames that needed the scalar reader'),
+                ('zkstream_ingest_dispatches', 'dispatches',
+                 'device dispatches made (one a size class present a '
+                 'tick)'),
+                ('zkstream_ingest_batched_bytes', 'bytes_batched',
+                 'stream bytes copied into the ticks\' batches'),
+                ('zkstream_ingest_dispatched_bytes', 'bytes_dispatched',
+                 'padded bytes the dispatches held (Bp x L summed)'),
+                ('zkstream_ingest_recopied_bytes', 'bytes_recopied',
+                 'bytes batched that their tick did not consume (a '
+                 'partial frame behind whole ones), so batched again'),
+                ('zkstream_ingest_deferred_slots', 'slots_deferred',
+                 'slot-ticks sat out because the slot\'s first frame '
+                 'was not whole yet')):
             collector.gauge(prefix + name,
                             (lambda a=attr: getattr(self, a)),
                             help_text)
@@ -794,14 +883,18 @@ class FleetIngest:
                       nbytes: int | None = None) -> None:
         """Compile the tick program for an expected fleet shape up
         front (servers at startup, benchmarks before timing): the
-        bucket for ``n_streams`` connections holding up to ``nbytes``
-        buffered bytes each tick (default: ``min_len``).  Concurrent
+        bucket of one dispatch of ``n_streams`` rows (as many of them
+        as one dispatch holds) in the size class of ``nbytes`` (default:
+        ``min_len``, the narrowest).  A deployment whose replies reach
+        wider classes warms those it expects, row count by row count
+        (powers of two).  Concurrent
         prewarms for several buckets drain through the single warm
         worker one at a time (total ~= sum of compiles, not max) — the
         same serialization that keeps background warms from
         oversubscribing a host mid-service.  In force-device mode a
         bucket that fails to compile raises here."""
-        key = self._bucket(n_streams, nbytes or self.min_len)
+        nbytes = nbytes or self.min_len
+        key = self._bucket(min(n_streams, self._class_rows(nbytes)), nbytes)
         if self._exec.get(key, _MISSING) is _MISSING:
             if self.warm == 'block':
                 self._compile_or_latch(key)
@@ -1020,29 +1113,33 @@ class FleetIngest:
         # Phase ``batch`` (host span ``ingest.batch``) opens with the
         # scan for the slots that hold bytes — the first step of
         # building the batch, and at fleet width not a small one —
-        # and closes when ``[Bp, L]`` is built.  A tick that is
-        # drained another way (nothing buffered, pass-through flip,
-        # bucket still compiling) leaves no batch span behind.
+        # and closes when every dispatch's ``[Bp, L]`` is built.  A
+        # tick that is drained another way (nothing buffered,
+        # pass-through flip, bucket still compiling) leaves no batch
+        # span behind.
         active: list = []
+        plans = ()
         before = self.frames_routed
         try:
             t0 = time.perf_counter()
             with host_span('ingest.batch', tick=self.ticks + 1) as bsp:
                 active = [slot for slot in self._slots.values()
                           if slot[1] and slot[0].is_in_state('connected')]
-                plan = self._prepare_batch(active, sp) if active else None
-                if plan is None:
+                plans = self._prepare_batch(active, sp) if active else ()
+                if not plans:
                     bsp.cancel()
-            if plan is not None:
-                self._tick_inner(active, plan, sp, t0)
+            if plans:
+                self._tick_inner(plans, sp, t0)
         finally:
-            if active:
+            # ``()``: no slot held a whole frame — nothing was drained
+            drained = plans != ()
+            if drained:
                 self._note_frames(self.frames_routed - before)
                 self._frames_mark = self.frames_routed
                 sp.set(batch=self.frames_routed - before)
             if self._release_held():
                 self._schedule()     # finish the withheld suffixes
-        return bool(active)
+        return drained
 
     def _inject_tick_faults(self) -> None:
         """Apply the injector's tick-time decisions to the batch-regime
@@ -1085,10 +1182,11 @@ class FleetIngest:
 
     def _prepare_batch(self, active, sp=NO_SPAN):
         """Decide how this tick drains and, for a device tick, build
-        its batch: returns ``(ex, device_bodies, batch, lens)``, or
-        None when the tick was drained here another way (the
-        pass-through flip, a bucket still compiling or one that
-        failed to compile)."""
+        its batches: returns the tick's dispatches, each ``(ex, key,
+        streams, batch, lens, nbytes)``; None when the tick was
+        drained here another way (the pass-through flip, buckets still
+        compiling or that failed to compile); ``()`` when no slot
+        holds a whole frame yet: nothing was drained."""
         if self._want_direct():
             self.ticks_scalar += 1
             if self._frag_scalar:
@@ -1098,74 +1196,164 @@ class FleetIngest:
             self._flip_direct(active)
             return None
 
-        B = len(active)
-        maxlen = max(len(slot[1]) for slot in active)
-        key = self._bucket(B, maxlen)
-        ex = self._exec.get(key, _MISSING)
-        if ex is _MISSING:
-            if self.warm == 'block':
-                ex = self._compile_or_latch(key)
-            else:
-                # never block the loop on a compile: drain this tick
-                # through the scalar codec while the bucket warms
-                self._start_warm(key)
-                self.ticks_warming += 1
-                sp.set(tick=None, detail='warming')
-                for conn, buf, _lane in active:
-                    if id(conn) not in self._slots:
-                        continue
-                    self._deliver_scalar(conn, buf)
-                return None
-        if ex is None:  # compile failed: this bucket stays scalar
-            self._require_compiled(key)
-            self.ticks_scalar += 1
-            sp.set(tick=None, detail='scalar')
-            for conn, buf, _lane in active:
-                if id(conn) not in self._slots:
+        # What each slot gives this tick.  A slot whose first frame is
+        # not whole yet waits — the one thing the host reads of a
+        # stream is that frame's length prefix — so a reply that
+        # arrives over many reads is copied once, when it is whole.
+        # Behind a whole first frame the slot gives what the frame
+        # bound could consume if the frames behind are of its size
+        # (the power of two over ``max_frames`` of it): a partial large
+        # frame behind a small one is not copied along.  A prefix no
+        # frame can have goes to the device, which flags the stream.
+        min_len, frames = self.min_len, self.max_frames
+        streams, sizes = [], []
+        cut = False
+        for slot in active:
+            buf = slot[1]
+            have = len(buf)
+            # the first frame's bytes, prefix included (unsigned: a
+            # negative length reads as one over the cap)
+            n = _PREFIX(buf)[0] + 4 if have >= 4 else _FRAME_TOP
+            if have < n <= _FRAME_TOP:
+                self.slots_deferred += 1
+                continue
+            if have > min_len:
+                have = min(have, min_len if n > _FRAME_TOP
+                           else self._width(n * frames))
+                cut = cut or have < len(buf)
+            streams.append(slot)
+            sizes.append(have)
+        if cut:
+            self._schedule()    # a slot holds more than it gave
+        if not streams:
+            return ()
+
+        # one dispatch a size class present, a class's rows beyond
+        # ``DISPATCH_BYTES`` in further ones; with every row in the
+        # narrowest class (a fleet of small replies) the tick is one
+        # dispatch of everything, and this is all it costs
+        if max(sizes) <= min_len:
+            groups = [(streams, sizes)]
+        else:
+            classes: dict = {}
+            for slot, n in zip(streams, sizes):
+                g = classes.setdefault(
+                    (n - 1).bit_length() if n > min_len else 0, ([], []))
+                g[0].append(slot)
+                g[1].append(n)
+            groups = []
+            for _c, (g_streams, g_sizes) in sorted(classes.items()):
+                rows = self._class_rows(max(g_sizes))
+                for lo in range(0, len(g_streams), rows):
+                    groups.append((g_streams[lo:lo + rows],
+                                   g_sizes[lo:lo + rows]))
+
+        plans, scalar = [], []
+        arena, used = self._arena, 0
+        if arena is None:
+            arena = self._arena = np.empty((self.TICK_BYTES,), np.uint8)
+        for g_streams, g_sizes in groups:
+            key = self._bucket(len(g_streams), max(g_sizes))
+            ex = self._exec.get(key, _MISSING)
+            if ex is _MISSING:
+                if self.warm == 'block':
+                    ex = self._compile_or_latch(key)
+                else:
+                    # never block the loop on a compile: drain these
+                    # streams through the scalar codec while the
+                    # bucket warms
+                    self._start_warm(key)
+                    scalar.append((g_streams, 'warming'))
                     continue
-                self._deliver_scalar(conn, buf)
+            if ex is None:  # compile failed: this bucket stays scalar
+                self._require_compiled(key)
+                scalar.append((g_streams, 'scalar'))
+                continue
+            _device, Bp, L = key
+            if used + Bp * L <= len(arena):
+                batch = arena[used:used + Bp * L].reshape(Bp, L)
+                used += Bp * L
+            elif used:
+                # the tick is full: the rest waits for the next one
+                self._schedule()
+                break
+            else:   # one row wider than a tick: a frame near the cap
+                batch = np.zeros((Bp, L), np.uint8)
+                used = len(arena)
+            # one flat byte view of the batch: a slice assignment
+            # copies a stream into its row before anything can mutate
+            # it, without a numpy call a stream (it was a third of
+            # phase ``batch``)
+            rows = memoryview(batch.reshape(-1))
+            for i, (slot, n) in enumerate(zip(g_streams, g_sizes)):
+                rows[i * L:i * L + n] = (
+                    slot[1] if n == len(slot[1])
+                    else memoryview(slot[1])[:n])
+            lens = np.zeros((Bp,), np.int32)
+            lens[:len(g_sizes)] = g_sizes
+            plans.append((ex, key, g_streams, batch, lens, sum(g_sizes)))
+        hows = {how for _s, how in scalar}
+        self.ticks_warming += 'warming' in hows
+        self.ticks_scalar += 'scalar' in hows
+        for g_streams, _how in scalar:
+            for conn, buf, _lane in g_streams:
+                if id(conn) in self._slots:
+                    self._deliver_scalar(conn, buf)
+        if not plans:
+            sp.set(tick=None, detail=scalar[0][1])
             return None
         self.ticks += 1
-
-        device, Bp, L = key
-        batch = np.zeros((Bp, L), np.uint8)
-        sizes = [len(slot[1]) for slot in active]
-        # one flat byte view of the batch: a slice assignment copies a
-        # stream into its row before anything can mutate it, without a
-        # numpy call a stream (it was a third of phase ``batch``)
-        rows = memoryview(batch.reshape(-1))
-        for i, (slot, n) in enumerate(zip(active, sizes)):
-            rows[i * L:i * L + n] = slot[1]
-        lens = np.zeros((Bp,), np.int32)
-        lens[:B] = sizes
         if sp is not NO_SPAN:
-            sp.set(detail='device %dx%d streams=%d' % (Bp, L, B),
-                   nbytes=int(lens.sum()))
-        return ex, device, batch, lens
+            _device, Bp, L = plans[0][1]
+            rows = sum(len(p[2]) for p in plans)
+            sp.set(detail=('device %dx%d streams=%d' % (Bp, L, rows)
+                           if len(plans) == 1 else
+                           'device %d dispatches streams=%d'
+                           % (len(plans), rows)),
+                   nbytes=sum(p[5] for p in plans))
+        return plans
 
-    def _tick_inner(self, active, plan, sp, t0: float) -> None:
-        """The device tick proper, once its batch stands (``t0``: when
-        phase ``batch`` opened): dispatch, readback, route — each a
+    def _tick_inner(self, plans, sp, t0: float) -> None:
+        """The device tick proper, once its batches stand (``t0``: when
+        phase ``batch`` opened): every dispatch sent, every result read
+        back — all in flight together, in the memory ``TICK_BYTES``
+        bounds — then one route over all of them.  Each phase is a
         host span under ``ingest.tick`` carrying the tick's number
-        (profiler sessions only) and, with ``batch``, one observation
-        of ``zkstream_ingest_phase_ms{phase=}`` (always)."""
-        ex, device, batch, lens = plan
+        (profiler sessions only; ``ingest.dispatch`` and
+        ``ingest.readback`` once a dispatch) and, with ``batch``, one
+        observation of ``zkstream_ingest_phase_ms{phase=}`` a tick
+        (always)."""
         n = self.ticks
+        device = self.body_mode == 'device'
         t1 = time.perf_counter()
-        with host_span('ingest.dispatch', tick=n):
-            out = ex(batch, lens)
+        outs, results = [], []
+        for ex, key, streams, batch, lens, nbytes in plans:
+            with host_span('ingest.dispatch', tick=n, rows=len(streams),
+                           width=key[2], nbytes=nbytes):
+                outs.append(ex(batch, lens))
+            self.dispatches += 1
+            self.bytes_batched += nbytes
+            self.bytes_dispatched += batch.size
         t2 = time.perf_counter()
-        with host_span('ingest.readback', tick=n):
-            if device:
-                ints = np.asarray(out[0])  # the only 2 readbacks per tick
-                byts = np.asarray(out[1])
-            else:
-                ints = np.asarray(out)
-                byts = None
+        for out in outs:
+            with host_span('ingest.readback', tick=n):
+                if device:      # the only 2 readbacks per dispatch
+                    results.append((np.asarray(out[0]),
+                                    np.asarray(out[1])))
+                else:
+                    results.append((np.asarray(out), None))
         t3 = time.perf_counter()
         with host_span('ingest.route', tick=n) as rsp:
-            st, bd = self._unpack(ints, byts)
-            laned, emitted = self._route_batch(active, None, st, bd)
+            laned = emitted = 0
+            for plan, (ints, byts) in zip(plans, results):
+                streams, lens = plan[2], plan[4]
+                st, bd = self._unpack(ints, byts)
+                B = len(streams)
+                self.bytes_recopied += int(
+                    np.where(st.bad[:B], 0, lens[:B] - st.resid[:B]).sum())
+                a, b = self._route_batch(streams, None, st, bd)
+                laned += a
+                emitted += b
             rsp.set(lane=laned, emitted=emitted)
         t4 = time.perf_counter()
         observe = self.phase_hist.observe

@@ -59,6 +59,7 @@ class MeshFleetIngest(FleetIngest):
         #: fleet-global stats of the LAST device tick (None before the
         #: first); scalar/warming ticks do not update it.
         self.global_stats: dict | None = None
+        self._adding = False    # inside a tick of several dispatches
         #: running fleet-wide maximum zxid over all device ticks — the
         #: checkpoint a proxy-level session manager would persist.
         self.fleet_max_zxid = 0
@@ -125,9 +126,19 @@ class MeshFleetIngest(FleetIngest):
         self._fns[device_bodies] = fn
         return fn
 
+    def _tick_inner(self, plans, sp, t0: float) -> None:
+        # a tick of several size classes is several collective
+        # launches: its stats are theirs added up
+        self.global_stats = None
+        self._adding = True
+        try:
+            super()._tick_inner(plans, sp, t0)
+        finally:
+            self._adding = False
+
     def _unpack(self, ints, byts):
         g = ints[0, -_N_GLOBALS:]
-        self.global_stats = {
+        stats = {
             'total_frames': int(g[0]),
             'total_replies': int(g[1]),
             'total_notifications': int(g[2]),
@@ -135,8 +146,12 @@ class MeshFleetIngest(FleetIngest):
             'total_errors': int(g[4]),
             'max_zxid': i64pair_to_int(g[5], g[6]),
         }
-        self.fleet_max_zxid = max(self.fleet_max_zxid,
-                                  self.global_stats['max_zxid'])
+        last = self.global_stats
+        if self._adding and last is not None:
+            stats = {k: (max if k == 'max_zxid' else int.__add__)(v, last[k])
+                     for k, v in stats.items()}
+        self.global_stats = stats
+        self.fleet_max_zxid = max(self.fleet_max_zxid, stats['max_zxid'])
         return super()._unpack(ints[:, :-_N_GLOBALS], byts)
 
 

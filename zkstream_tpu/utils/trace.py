@@ -71,7 +71,8 @@ TRACE_SCHEMA = 3
 #: setattr path populated it.
 _OPTIONAL_FIELDS = ('path', 'xid', 'zxid', 'backend', 'session_id',
                     'member', 'batch', 'nbytes', 'detail', 'error',
-                    'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted')
+                    'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted',
+                    'rows', 'width')
 
 
 class Span:
@@ -82,7 +83,8 @@ class Span:
                  'backend', 'session_id', 'status', 'error',
                  't_wall', '_t0', 'duration_ms',
                  'member', 'batch', 'nbytes', 'detail', '_on_slow',
-                 'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted')
+                 'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted',
+                 'rows', 'width')
 
     def __init__(self, span_id: int, op: str, path: str | None = None,
                  kind: str = 'op'):
@@ -115,6 +117,10 @@ class Span:
         #: handed to the ``'ingestDeliver'`` emitter path.
         self.lane: int | None = None
         self.emitted: int | None = None
+        #: ``ingest.dispatch`` only: the streams in this dispatch and
+        #: the width of its size class (``nbytes``: their payload)
+        self.rows: int | None = None
+        self.width: int | None = None
         self.status: str = 'open'
         self.error: str | None = None
         self.t_wall = time.time()
@@ -243,6 +249,8 @@ class TraceRing:
         span.t1_ns = None
         span.lane = None
         span.emitted = None
+        span.rows = None
+        span.width = None
         span.status = 'ok'
         span.error = None
         span.t_wall = time.time()
@@ -469,7 +477,8 @@ def host_span(name: str, accumulate: bool = False, **ids):
     ``jax.profiler.TraceAnnotation(name, **ids)`` and (2) on exit a
     settled :class:`Span` in :data:`host_ring` — ``kind='host'``,
     ``op`` the name, ``parent`` the enclosing host span's name,
-    ``tick`` from ``ids``, ``t0_ns``/``t1_ns`` from
+    ``ids`` (``tick``; a dispatch's ``rows`` / ``width`` / ``nbytes``)
+    as fields, ``t0_ns``/``t1_ns`` from
     ``time.perf_counter_ns`` — plus whatever :meth:`set` added
     (``batch``, ``nbytes``, ``detail``).  ``accumulate=True`` is for a
     boundary crossed once per op: the annotation is opened, but the
@@ -528,8 +537,10 @@ class _HostSpan:
             tot[0] += 1
             tot[1] += t1 - self._t0
             return False
-        fields = self.fields or {}
-        fields.setdefault('tick', self.ids.get('tick'))
+        # what the caller knew at the start (``ids``: the annotation's
+        # own stats in the trace) and what it set under way
+        fields = dict(self.ids, **(self.fields or {}))
+        fields.setdefault('tick', None)
         host_ring.note(
             self.name, kind='host',
             parent=None if self._parent is None else self._parent.name,
