@@ -13,10 +13,12 @@ import os
 import signal
 import subprocess
 import sys
+import types
 
 import pytest
 
 from helpers import mntr_rows
+from test_server_edges import RawClient
 
 WORKER = os.path.join(os.path.dirname(__file__),
                       'process_member_worker.py')
@@ -388,12 +390,16 @@ async def test_rolling_sigkill_chaos_soak(process_ensemble):
 
 async def test_follower_counts_the_time_parked_in_forwarded_rpcs(
         process_ensemble):
-    """A follower forwards each write through a blocking control-
-    channel RPC on its loop's thread: its tick ledger books that as
-    ``forward_rpc`` (nested under ``decode_apply``, so no longer part
-    of it), and its ``mntr`` exports the phase cumulatively — the
-    window's parked time is after minus before.  The leader, which
-    forwards nothing, has no such series."""
+    """A follower forwards a turn's writes through ONE blocking
+    control-channel RPC on its loop's thread, from the flush its first
+    queued write scheduled (``ZKServer._flush_forwards``): its tick
+    ledger books the round trip as ``forward_rpc`` — subtracted from
+    the flush's own ``decode_apply`` section (the mirror's catch-up
+    and the replies), no longer from the decode that received the
+    write — and its ``mntr`` exports the phase cumulatively: the
+    window's parked time is after minus before.  The RPCs and the
+    writes they carried are counted beside it.  The leader, which
+    forwards nothing, has none of these series."""
     leader, (f1, _f2) = process_ensemble
     c = _client([('127.0.0.1', f1.ports[0])])
     try:
@@ -410,19 +416,168 @@ async def test_follower_counts_the_time_parked_in_forwarded_rpcs(
     total = 'zk_tick_phase_ms_sum{phase="forward_rpc"}'
     inf = 'zk_tick_phase_ms_bucket{phase="forward_rpc",le="+Inf"}'
     # the ledger books per busy tick, and a tick may hold several
-    # writes: at least one tick, at most one per write (plus pings)
+    # flushes: at least one tick, at most one per write (plus pings)
     ticks = int(after[count]) - int(before[count])
     assert 1 <= ticks <= 30
     assert int(after[inf]) - int(before[inf]) == ticks
     parked_ms = float(after[total]) - float(before[total])
     assert parked_ms > 0
-    # the parked time is no longer inside decode_apply: the follower's
-    # own decode + dispatch of 25 small writes is far under the round
-    # trips it waited for
+    # one session with one write outstanding: every batch is a batch
+    # of one, the same code
+    assert int(after['zk_forward_writes']) \
+        - int(before['zk_forward_writes']) == 25
+    assert int(after['zk_forward_rpcs']) \
+        - int(before['zk_forward_rpcs']) == 25
+    # the parked time is not inside decode_apply: the follower's
+    # own decode, catch-up and replies of 25 small writes are far
+    # under the round trips it waited for
     own = 'zk_tick_phase_ms_sum{phase="decode_apply"}'
     assert float(after[own]) - float(before[own]) < parked_ms * 5
     assert float(after[own]) > 0
     assert count not in lead and 'zk_tick_count' in lead
+    assert 'zk_forward_rpcs' not in lead
+    assert 'zk_forward_writes' not in lead
+
+
+async def test_pipelined_connection_through_a_follower_answers_in_order(
+        process_ensemble):
+    """``set, get, set, sync, get, closeSession`` in ONE chunk through
+    a follower: the first set joins the turn's batch and everything
+    behind it waits on the connection, in arrival order, until its
+    reply is written — so the replies come back in xid order, each
+    ``get`` sees the ``set`` before it, and nothing is left
+    outstanding."""
+    _leader, (f1, _f2) = process_ensemble
+    c = _client([('127.0.0.1', f1.ports[0])])
+    try:
+        await c.wait_connected(timeout=10)
+        await c.create('/pl', b'zero')
+    finally:
+        await c.close()
+    raw = RawClient()
+    await raw.connect(types.SimpleNamespace(port=f1.ports[0]),
+                      timeout=12000)
+    reqs = [
+        {'xid': 1, 'opcode': 'SET_DATA', 'path': '/pl', 'data': b'one',
+         'version': -1},
+        {'xid': 2, 'opcode': 'GET_DATA', 'path': '/pl', 'watch': False},
+        {'xid': 3, 'opcode': 'SET_DATA', 'path': '/pl', 'data': b'two',
+         'version': 1},
+        {'xid': 4, 'opcode': 'SYNC', 'path': '/pl'},
+        {'xid': 5, 'opcode': 'GET_DATA', 'path': '/pl', 'watch': False},
+        {'xid': 6, 'opcode': 'SET_DATA', 'path': '/pl', 'data': b'no',
+         'version': 7},
+        {'xid': 7, 'opcode': 'CLOSE_SESSION'},
+    ]
+    try:
+        raw.writer.write(b''.join(raw.codec.encode(r) for r in reqs))
+        got = await raw.recv(len(reqs), timeout=10)
+    finally:
+        raw.close()
+    assert [p['xid'] for p in got] == [1, 2, 3, 4, 5, 6, 7]
+    assert [p['err'] for p in got] == [
+        'OK', 'OK', 'OK', 'OK', 'OK', 'BAD_VERSION', 'OK']
+    assert got[0]['stat'].version == 1 and got[2]['stat'].version == 2
+    assert got[1]['data'] == b'one' and got[1]['stat'].version == 1
+    assert got[4]['data'] == b'two' and got[4]['stat'].version == 2
+    # reply zxids never go back on the connection
+    zxids = [p['zxid'] for p in got]
+    assert zxids == sorted(zxids)
+    rows = await mntr_rows(f1.ports[0])
+    assert int(rows['zk_outstanding_requests']) == 0
+
+
+async def test_concurrent_writers_through_one_follower_share_rpcs(
+        process_ensemble):
+    """16 closed-loop writers on one follower: the writes one turn of
+    its loop receives leave in ONE control-channel RPC, so the
+    follower makes far fewer round trips (and ``forward_rpc`` ticks)
+    than writes, every write is answered with its own result, and the
+    other members hold them all."""
+    leader, (f1, f2) = process_ensemble
+    n, rounds = 16, 20
+    clients = [_client([('127.0.0.1', f1.ports[0])]) for _ in range(n)]
+    try:
+        await asyncio.gather(*(c.wait_connected(timeout=10)
+                               for c in clients))
+        await asyncio.gather(*(c.create('/w%d' % i, b'')
+                               for i, c in enumerate(clients)))
+        before = await mntr_rows(f1.ports[0])
+
+        async def writer(i, c):
+            for r in range(rounds):
+                stat = await c.set('/w%d' % i, b'%d.%d' % (i, r))
+                assert stat.version == r + 1
+
+        await asyncio.gather(*(writer(i, c)
+                               for i, c in enumerate(clients)))
+        after = await mntr_rows(f1.ports[0])
+    finally:
+        await asyncio.gather(*(c.close() for c in clients))
+
+    def delta(key):
+        return float(after[key]) - float(before.get(key, 0))
+
+    writes = delta('zk_forward_writes')
+    rpcs = delta('zk_forward_rpcs')
+    assert writes == n * rounds
+    assert 1 <= rpcs <= writes / 2, (writes, rpcs)
+    assert delta('zk_tick_phase_ms_count{phase="forward_rpc"}') <= rpcs
+    r = _client([('127.0.0.1', f2.ports[0])])
+    try:
+        await r.wait_connected(timeout=10)
+        await r.sync('/')
+        for i in range(n):
+            data, stat = await r.get('/w%d' % i)
+            assert data == b'%d.%d' % (i, rounds - 1)
+            assert stat.version == rounds
+    finally:
+        await r.close()
+
+
+async def test_leader_sigkill_with_batches_in_flight_loses_no_ack(
+        process_ensemble):
+    """SIGKILL the leader under 16 closed-loop writers on one
+    follower: every write in flight settles — acked, or the typed
+    outcome-unknown CONNECTION_LOSS, never a hang and never a torn
+    connection — and no acked write is lost: the follower's mirror
+    held each one before its ack left."""
+    from zkstream_tpu.protocol.errors import ZKError
+
+    leader, (f1, _f2) = process_ensemble
+    n = 16
+    clients = [_client([('127.0.0.1', f1.ports[0])]) for _ in range(n)]
+    acked = [0] * n
+    errors: list = []
+    try:
+        await asyncio.gather(*(c.wait_connected(timeout=10)
+                               for c in clients))
+        await asyncio.gather(*(c.create('/k%d' % i, b'')
+                               for i, c in enumerate(clients)))
+
+        async def writer(i, c):
+            try:
+                while True:
+                    stat = await c.set('/k%d' % i, b'x', deadline=8000)
+                    acked[i] = stat.version
+            except ZKError as e:
+                errors.append(e.code)
+
+        tasks = [asyncio.ensure_future(writer(i, c))
+                 for i, c in enumerate(clients)]
+        await asyncio.sleep(0.3)
+        os.kill(leader.proc.pid, signal.SIGKILL)
+        await asyncio.wait_for(asyncio.gather(*tasks), 15)
+        assert min(acked) > 0
+        assert errors == ['CONNECTION_LOSS'] * n
+        # the follower keeps serving its mirror: every acked version
+        # is there (a later, unacked one may be too)
+        for i, c in enumerate(clients):
+            _data, stat = await c.get('/k%d' % i)
+            assert stat.version >= acked[i]
+    finally:
+        await asyncio.gather(*(c.close() for c in clients),
+                             return_exceptions=True)
 
 
 async def _scrape_trce(port: int) -> dict:

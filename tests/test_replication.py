@@ -299,3 +299,220 @@ async def test_unknown_hello_kind_is_dropped(repl):
     remote = await connect()             # real followers still join
     await _rpc(remote.create, '/ok', b'', OPEN_ACL_UNSAFE,
                CreateFlag(0), None)
+
+
+# -- the batch message: a turn's writes in ONE control-channel RPC -----
+
+def _set(path, data, version=-1):
+    return ('set_data', (path, data, version))
+
+
+async def test_batch_answers_each_write_on_its_own_in_order(repl):
+    """One ``batch`` RPC: the leader applies the elements in list
+    order and answers each with its own result — a BAD_VERSION between
+    two good sets fails alone, a multi stays one all-or-nothing
+    element (its rejection reports per-op errors under 'ok' and
+    applies nothing) — and ONE response piggybacks every entry."""
+    db, svc, connect = repl
+    remote = await connect()
+    store = RemoteReplicaStore(remote, lag=0.0)
+    db.create('/a', b'0', OPEN_ACL_UNSAFE, CreateFlag(0))
+    db.create('/b', b'0', OPEN_ACL_UNSAFE, CreateFlag(0))
+    rpcs = remote.forward_rpcs
+    results = await _rpc(remote.forward, [
+        _set('/a', b'1', 0),
+        _set('/a', b'bad', 7),                    # BAD_VERSION: alone
+        _set('/a', b'2', 1),
+        ('multi', ([{'op': 'create', 'path': '/m', 'data': b'm'},
+                    {'op': 'set_data', 'path': '/b', 'data': b'1'}],
+                   None)),
+        ('multi', ([{'op': 'set_data', 'path': '/b', 'data': b'x'},
+                    {'op': 'delete', 'path': '/nope'}], None)),
+        ('create', ('/c', b'c', OPEN_ACL_UNSAFE, CreateFlag(0), None)),
+        ('delete', ('/c', 0)),
+    ])
+    assert remote.forward_rpcs == rpcs + 1
+    assert remote.forward_writes >= 7
+    statuses = [s for s, _ in results]
+    assert statuses == ['ok', 'err', 'ok', 'ok', 'ok', 'ok', 'ok']
+    assert results[0][1].version == 1
+    assert results[1][1] == 'BAD_VERSION'
+    assert results[2][1].version == 2
+    assert results[3][1][0]['path'] == '/m'
+    # the rejected multi: per-op error results, nothing applied
+    assert any(r.get('err', 'OK') != 'OK' for r in results[4][1])
+    assert results[5][1] == '/c' and results[6][1] is None
+    assert db.nodes['/a'].data == b'2' and db.nodes['/b'].data == b'1'
+    assert '/c' not in db.nodes and '/m' in db.nodes
+    # the one response carried every entry: local catch_up suffices
+    assert remote.log_end() == db.log_end()
+    store.catch_up()
+    assert store.nodes['/a'].data == b'2'
+    assert store.nodes['/b'].data == b'1'
+
+
+async def test_batch_is_made_durable_and_quorum_held_once(
+        event_loop, tmp_path):
+    """The leader answers a batch behind ONE ``sync_for_flush`` that
+    covers every record in it and ONE quorum wait at the batch's LAST
+    zxid — in that order, after every element is applied."""
+    from zkstream_tpu.server.persist import open_wal_database
+
+    db = open_wal_database(str(tmp_path / 'w'), sync='tick')
+    svc = await ReplicationService(db, total=3, quorum=True).start()
+    remote = await RemoteLeader('127.0.0.1', svc.port).connect()
+    calls = []
+    sync_for_flush = db.wal.sync_for_flush
+    wait = svc.quorum.wait
+
+    def spy_sync():
+        calls.append(('sync', db.zxid))
+        sync_for_flush()
+        assert db.wal.durable_zxid == db.zxid
+
+    async def spy_wait(target, timeout_s=None, grant=None):
+        calls.append(('quorum', target, grant))
+        return await wait(target, timeout_s, grant=grant)
+
+    db.wal.sync_for_flush = spy_sync
+    svc.quorum.wait = spy_wait
+    try:
+        db.create('/q', b'0', OPEN_ACL_UNSAFE, CreateFlag(0))
+        calls.clear()
+        results = await _rpc(remote.forward, [
+            _set('/q', b'%d' % i) for i in range(16)])
+        assert [s for s, _ in results] == ['ok'] * 16
+        last = db.zxid
+        # 3 voters: leader + the caller's virtual grant are a majority
+        assert calls == [('sync', last), ('quorum', last, remote.token)]
+        # a batch that commits nothing waits for no quorum
+        calls.clear()
+        results = await _rpc(remote.forward, [_set('/none', b'')])
+        assert results == [('err', 'NO_NODE')]
+        assert calls == [('sync', last)]
+    finally:
+        remote.close()
+        await svc.stop()
+        db.wal.close()
+
+
+async def test_fenced_batch_fails_every_element_and_applies_nothing(
+        repl):
+    db, svc, connect = repl
+    remote = await connect()
+    db.create('/f', b'0', OPEN_ACL_UNSAFE, CreateFlag(0))
+    svc.depose()
+    zxid = db.zxid
+    results = await _rpc(remote.forward, [
+        _set('/f', b'1'), _set('/f', b'2'),
+        ('create', ('/g', b'', OPEN_ACL_UNSAFE, CreateFlag(0), None))])
+    assert results == [('err', 'EPOCH_FENCED')] * 3
+    assert db.zxid == zxid and db.nodes['/f'].data == b'0'
+    assert '/g' not in db.nodes
+    # the db-shaped surface (a batch of one) raises it typed
+    with pytest.raises(ZKOpError) as ei:
+        await _rpc(remote.set_data, '/f', b'3', -1)
+    assert ei.value.code == 'EPOCH_FENCED'
+
+
+async def test_observer_batch_waits_for_real_voter_acks(event_loop):
+    """An observer's mirror is outside the voter set: its batch gets no
+    virtual grant, so the response waits for a REAL voter's ack of the
+    batch's last zxid (here: degrades after the bounded wait, since
+    no voter is attached)."""
+    db = ZKDatabase()
+    svc = await ReplicationService(db, total=3, quorum=True).start()
+    svc.quorum.wait_ms = 60.0
+    obs = await RemoteLeader('127.0.0.1', svc.port,
+                             observer=True).connect()
+    voter = await RemoteLeader('127.0.0.1', svc.port).connect()
+    try:
+        db.create('/o', b'0', OPEN_ACL_UNSAFE, CreateFlag(0))
+        await asyncio.sleep(0.05)         # both mirrors acked the create
+        # the voter's mirror keeps acking pushes: the observer's batch
+        # is released by that REAL ack, well inside the degrade window
+        results = await _rpc(obs.forward,
+                             [_set('/o', b'1'), _set('/o', b'2')])
+        assert [s for s, _ in results] == ['ok', 'ok']
+        assert svc.quorum.degraded_releases == 0
+        assert svc.quorum.quorum_zxid_floor == db.zxid
+        # the voter gone: only the leader holds the next batch, the
+        # observer's own mirror never counts, and the wait degrades
+        voter.close()
+        for _ in range(50):
+            if len(svc._handles) == 1:
+                break
+            await asyncio.sleep(0.02)
+        results = await _rpc(obs.forward, [_set('/o', b'3')])
+        assert [s for s, _ in results] == ['ok']
+        assert svc.quorum.degraded_releases == 1
+    finally:
+        obs.close()
+        voter.close()
+        await svc.stop()
+
+
+async def test_leader_lost_with_a_batch_in_flight_loses_every_element(
+        event_loop):
+    """The control channel dies while the leader holds the batch (here:
+    inside its quorum wait): EVERY element comes back outcome-unknown
+    — typed CONNECTION_LOSS through the db-shaped surface — and the
+    follower retries nothing (the elements may well have applied)."""
+    from zkstream_tpu.server.replication import ZKLeaderLostError
+
+    db = ZKDatabase()
+    svc = await ReplicationService(db, total=3, quorum=True).start()
+    svc.quorum.wait_ms = 5000.0
+    obs = await RemoteLeader('127.0.0.1', svc.port,
+                             observer=True).connect()
+    lost = []
+    obs.on_leader_lost = lambda: lost.append(True)
+    try:
+        db.create('/k', b'0', OPEN_ACL_UNSAFE, CreateFlag(0))
+        fut = event_loop.run_in_executor(
+            None, obs.forward, [_set('/k', b'%d' % i) for i in range(5)])
+        for _ in range(100):
+            if db.nodes['/k'].data == b'4':
+                break
+            await asyncio.sleep(0.01)
+        assert db.nodes['/k'].data == b'4'     # applied, unanswered
+        await svc.stop()                       # the leader "dies"
+        results = await asyncio.wait_for(fut, 5)
+        assert [s for s, _ in results] == ['lost'] * 5
+        assert obs.forward_rpcs == 1 and lost
+        with pytest.raises(ZKLeaderLostError) as ei:
+            await _rpc(obs.set_data, '/k', b'x', -1)
+        assert ei.value.code == 'CONNECTION_LOSS'
+    finally:
+        obs.close()
+        await svc.stop()
+
+
+async def test_batch_is_bounded_in_bytes(repl, monkeypatch):
+    """What one turn collected beyond ``FORWARD_BATCH_BYTES`` goes in
+    the next RPC of the same flush, in order; one oversized element
+    still goes alone."""
+    from zkstream_tpu.server import replication
+
+    db, svc, connect = repl
+    remote = await connect()
+    db.create('/z', b'', OPEN_ACL_UNSAFE, CreateFlag(0))
+    monkeypatch.setattr(replication, 'FORWARD_BATCH_BYTES', 2500)
+    results = await _rpc(remote.forward, [
+        _set('/z', b'a' * 1000), _set('/z', b'b' * 1000),
+        _set('/z', b'c' * 1000), _set('/z', b'd' * 5000),
+        _set('/z', b'e' * 10)])
+    assert [p.version for _, p in results] == [1, 2, 3, 4, 5]
+    # [a b] [c] [d: over the bound, alone] [e]
+    assert remote.forward_rpcs == 4 and remote.forward_writes == 5
+    assert db.nodes['/z'].data == b'e' * 10
+
+
+async def test_a_write_outside_a_batch_is_refused_loudly(repl):
+    db, svc, connect = repl
+    remote = await connect()
+    with pytest.raises(RuntimeError, match='set_data'):
+        await _rpc(remote._rpc, 'set_data', '/x', b'', -1)
+    # ...and a batch carries writes, nothing else
+    results = await _rpc(remote._rpc, 'batch', [('sync_barrier', ())])
+    assert results[0][0] == 'exc'

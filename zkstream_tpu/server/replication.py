@@ -18,11 +18,38 @@ single-leader replication model store.py already implements in-process.
 
 Two channels per follower, paired by a token:
 
-- ``control`` — a *blocking* socket the follower's request handlers
-  call RPCs on (create/delete/set_data, session lifecycle, sync
-  barrier).  Every response piggybacks the commit-log entries the
-  follower has not mirrored yet, so a write-then-read through one
-  member observes its own write without waiting on the async stream.
+- ``control`` — a *blocking* socket the follower calls RPCs on.  Every
+  response piggybacks the commit-log entries the follower has not
+  mirrored yet, so a write-then-read through one member observes its
+  own write without waiting on the async stream.  Its messages:
+
+  - ``('rpc', seq, method, args, have, epoch)`` -> ``('res', seq,
+    status, payload, base, entries, epoch)``, ``method`` one of
+    ``create_session`` / ``resume_session`` / ``close_session`` /
+    ``sync_barrier`` — and ``batch``, the ONLY message that carries
+    writes;
+  - ``batch``: ``args = ([(method, args), ...],)``, each element a
+    ``create`` / ``delete`` / ``set_data`` / ``multi`` — the writes a
+    follower's connections handed it during one turn of its loop
+    (server/server.py ``ZKServer.forward_write``), or one write when a
+    harness calls ``RemoteLeader.create`` and friends directly.
+    *Ordering:* the leader applies the elements in list order, which
+    is arrival order at the follower.  *Results:* the payload is one
+    ``(status, payload)`` per element, in order; an element fails
+    alone (a ``BAD_VERSION`` between two good ``set_data``), and a
+    ``multi`` stays one all-or-nothing element.  *Durability:* the
+    response leaves after ONE ``wal.sync_for_flush()`` that covers
+    every record of the batch and ONE quorum wait at the batch's last
+    zxid — the group commit the leader gives its own tick — so no
+    element is acked before it is fsynced and majority-held.
+    *Fencing:* the epoch fence is checked once; a fenced batch applies
+    nothing and answers ``EPOCH_FENCED`` for every element.
+    *Failure:* the channel dying with a batch in flight leaves EVERY
+    element outcome-unknown (``CONNECTION_LOSS``); the follower
+    retries nothing.  *Size:* a batch carries at most
+    ``FORWARD_BATCH_BYTES`` of payload; a turn with more sends the
+    rest in the next RPC of the same flush;
+  - ``('touch', session_id)``: fire-and-forget, no response.
 - ``events`` — an asyncio stream the leader pushes to: new commit-log
   entries as they land, and session-expiry broadcasts.
 
@@ -69,6 +96,16 @@ from .store import (
 log = logging.getLogger('zkstream_tpu.server.replication')
 
 _LEN = struct.Struct('>I')
+
+#: The control channel's writes: they travel only as elements of a
+#: ``batch`` (module docstring).
+WRITE_METHODS = frozenset(('create', 'delete', 'set_data', 'multi'))
+
+#: Payload bytes one ``batch`` RPC carries at most (znode data, counted
+#: by :meth:`RemoteLeader.forward`): a turn's worth of near-1 MiB
+#: uploads must not become one pickle of hundreds of MiB on both
+#: loops.  One element always goes, whatever its size.
+FORWARD_BATCH_BYTES = 4 << 20
 
 
 class ZKLeaderLostError(ZKOpError):
@@ -119,6 +156,29 @@ def _recv_msg(sock: socket.socket):
             raise ConnectionError('replication control channel closed')
         out += chunk
     return pickle.loads(out)
+
+
+def _wire_args(method: str, args: tuple) -> tuple:
+    """A write's arguments as the control channel carries them: a
+    session travels as its id, a flag set as its integer."""
+    if method == 'create':
+        path, data, acl, flags, session = args
+        return (path, data, acl, int(flags),
+                session.id if session is not None else 0)
+    if method == 'multi':
+        ops, session = args
+        return (list(ops), session.id if session is not None else 0)
+    return args
+
+
+def _payload_bytes(method: str, args: tuple) -> int:
+    """The znode data one forwarded write carries (what bounds a
+    batch; paths, ACLs and framing are noise beside it)."""
+    if method == 'multi':
+        return sum(len(op.get('data') or b'') for op in args[0])
+    if method == 'delete':
+        return 0
+    return len(args[1] or b'')
 
 
 # ---------------------------------------------------------------------
@@ -812,45 +872,20 @@ class ReplicationService:
                     # the caller has seen a newer leader than this
                     # service: it IS deposed, whatever it believed
                     self.depose(rpc_epoch)
-                if (self.deposed or (rpc_epoch is not None
-                                     and rpc_epoch < self.epoch)) \
-                        and method in ('create', 'delete', 'set_data',
-                                       'multi'):
-                    # epoch fence: a deposed leader must not apply —
-                    # or ack — a forwarded write, and a stale-epoch
-                    # follower's write must bounce until it rejoins
-                    # the current epoch.  Typed, never silent.
-                    status, payload = 'err', 'EPOCH_FENCED'
+                if method == 'batch':
+                    status, payload = 'ok', await self._apply_batch(
+                        args[0],
+                        fenced=self.deposed or (
+                            rpc_epoch is not None
+                            and rpc_epoch < self.epoch),
+                        grant=None if is_observer else token)
                 else:
-                    pre_zxid = db.zxid
-                    status, payload = self._dispatch(method, args)
+                    status, payload = self._dispatch(method, args,
+                                                     write=False)
                     if db.wal is not None:
-                        # logged-before-ack across processes too: a
-                        # forwarded write's RPC response is its ack
+                        # logged-before-ack across processes too (a
+                        # session record is a WAL record)
                         db.wal.sync_for_flush()
-                    if status == 'ok' and db.zxid > pre_zxid \
-                            and method in (
-                            'create', 'delete', 'set_data', 'multi'):
-                        # the zxid guard skips writes that committed
-                        # nothing (a rejected multi reports per-op
-                        # errors under status 'ok'; a check-only
-                        # batch consumes no zxid) — they must not
-                        # stall on unrelated in-flight writes' quorum
-                        # quorum-before-ack: the response leaves only
-                        # once a majority holds the txn.  The CALLING
-                        # follower's vote is granted virtually — this
-                        # very response's piggyback delivers the txn
-                        # into its mirror before the client can see
-                        # the ack (its loop is parked in the blocking
-                        # RPC, so awaiting its real ack would
-                        # deadlock).  An OBSERVER caller gets no
-                        # virtual grant: its mirror is outside the
-                        # voter set, so the majority must assemble
-                        # from real voter acks alone.  Bounded:
-                        # degrades like the send-plane gate.
-                        await self.quorum.wait(
-                            db.zxid,
-                            grant=None if is_observer else token)
                 base, entries = self._entries_from(have)
                 writer.write(_dump(
                     ('res', seq, status, payload, base, entries,
@@ -860,8 +895,55 @@ class ReplicationService:
         finally:
             writer.close()
 
-    def _dispatch(self, method: str, args: tuple):
+    async def _apply_batch(self, ops: list, *, fenced: bool,
+                           grant) -> list:
+        """The writes one follower collected in one turn of its loop
+        (module docstring, ``batch``): applied in order, each element
+        with its own ``(status, payload)``; made durable ONCE; the
+        quorum awaited ONCE, at the batch's last zxid.  The response
+        is every element's ack, so it leaves only behind both."""
+        if fenced:
+            # epoch fence: a deposed leader must not apply — or ack —
+            # a forwarded write, and a stale-epoch follower's writes
+            # bounce until it rejoins the current epoch.  Typed, never
+            # silent, and nothing of the batch is applied.
+            return [('err', 'EPOCH_FENCED')] * len(ops)
         db = self.db
+        pre_zxid = db.zxid
+        results = [self._dispatch(method, args, write=True)
+                   for method, args in ops]
+        if db.wal is not None:
+            # logged-before-ack across processes too: the response is
+            # the ack of every record in the batch, and one barrier
+            # covers them all (the leader's own tick does the same)
+            db.wal.sync_for_flush()
+        if db.zxid > pre_zxid:
+            # the zxid guard skips a batch that committed nothing
+            # (every element failed; a rejected multi reports per-op
+            # errors under status 'ok'; a check-only multi consumes no
+            # zxid) — it must not stall on unrelated in-flight writes'
+            # quorum.  Quorum-before-ack: the response leaves only
+            # once a majority holds the batch's LAST txn, hence every
+            # one before it.  The CALLING follower's vote is granted
+            # virtually — this very response's piggyback delivers the
+            # txns into its mirror before a client can see an ack (its
+            # loop is parked in the blocking RPC, so awaiting its real
+            # ack would deadlock).  An OBSERVER caller gets no virtual
+            # grant: its mirror is outside the voter set, so the
+            # majority must assemble from real voter acks alone.
+            # Bounded: degrades like the send-plane gate.
+            await self.quorum.wait(db.zxid, grant=grant)
+        return results
+
+    def _dispatch(self, method: str, args: tuple, *, write: bool):
+        """One RPC against the database.  ``write`` says where it
+        came from: a batch carries writes and nothing else, and a
+        write anywhere else would skip the batch's fence and quorum
+        wait — either mismatch is the loud error an unknown method
+        is."""
+        db = self.db
+        if (method in WRITE_METHODS) != write:
+            return 'exc', 'unknown rpc %r' % (method,)
         try:
             if method == 'create':
                 path, data, acl, flags, sid = args
@@ -974,6 +1056,11 @@ class RemoteLeader(EventEmitter):
         #: wires its own): loop time parked in :meth:`_rpc`
         self.ledger = None
         self._seq = 0
+        #: cumulative: ``batch`` RPCs sent and the writes they carried
+        #: (mntr ``zk_forward_rpcs`` / ``zk_forward_writes``; writes
+        #: over RPCs is what a turn of this member's loop collected)
+        self.forward_rpcs = 0
+        self.forward_writes = 0
         self._events_task: asyncio.Task | None = None
         #: kept referenced: a dropped StreamWriter closes its transport
         #: and the leader would see EOF and detach this follower
@@ -1252,25 +1339,67 @@ class RemoteLeader(EventEmitter):
             raise RuntimeError('leader rpc failed: %s' % (payload,))
         return payload
 
-    # -- the ZKDatabase surface ServerConnection uses --
+    # -- forwarded writes --
+
+    def forward(self, ops: list) -> list:
+        """Forward writes to the leader, in order, as ONE blocking
+        ``batch`` RPC (module docstring) — or one per
+        ``FORWARD_BATCH_BYTES`` of payload.  ``ops`` is ``[(method,
+        args)]`` with ``args`` as the ``ZKDatabase`` method of that
+        name takes them; the answer is one ``(status, payload)`` per
+        op, in order: ``'ok'`` with the method's result, ``'err'``
+        with the leader's error code, ``'exc'`` (a leader-side bug),
+        or ``'lost'``: the channel died with the RPC in flight, the
+        op's outcome is unknown, and nothing is retried."""
+        out: list = []
+        i, n = 0, len(ops)
+        while i < n:
+            chunk, size = [], 0
+            while i < n:
+                method, args = ops[i]
+                size += _payload_bytes(method, args)
+                if chunk and size > FORWARD_BATCH_BYTES:
+                    break
+                chunk.append((method, _wire_args(method, args)))
+                i += 1
+            self.forward_rpcs += 1
+            self.forward_writes += len(chunk)
+            try:
+                out += self._rpc('batch', chunk)
+            except ZKLeaderLostError as e:
+                out += [('lost', e.detail)] * len(chunk)
+        return out
+
+    def _write(self, method: str, *args):
+        """One write, blocking: a batch of one (harnesses and tests
+        call the database surface directly; a serving member's writes
+        come through :meth:`forward` from its server's queue)."""
+        (status, payload), = self.forward([(method, args)])
+        if status == 'ok':
+            return payload
+        if status == 'err':
+            raise ZKOpError(payload)
+        if status == 'lost':
+            raise ZKLeaderLostError(payload)
+        raise RuntimeError('leader rpc failed: %s' % (payload,))
+
+    # -- the ZKDatabase surface --
 
     def create(self, path, data, acl, flags, session=None):
-        sid = session.id if session is not None else 0
-        return self._rpc('create', path, data, acl, int(flags), sid)
+        return self._write('create', path, data, acl, flags, session)
 
     def delete(self, path, version):
-        return self._rpc('delete', path, version)
+        return self._write('delete', path, version)
 
     def set_data(self, path, data, version):
-        return self._rpc('set_data', path, data, version)
+        return self._write('set_data', path, data, version)
 
     def multi(self, ops, session=None):
         """Forward one all-or-nothing MULTI batch; the leader applies
         it as ONE transaction (store.py ``ZKDatabase.multi``) and the
         RPC piggyback delivers the whole ('multi', subs) entry into
         this mirror before the ack, like any forwarded write."""
-        sid = session.id if session is not None else 0
-        return self._rpc('multi', list(ops), sid)
+        return self._write('multi', ops, session)
 
     def sync_barrier(self) -> None:
         """Round-trip to the leader; on return the mirror holds every

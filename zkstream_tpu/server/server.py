@@ -21,6 +21,7 @@ meaning (see store.py).
 from __future__ import annotations
 
 import asyncio
+import collections
 import logging
 import os
 import time
@@ -312,6 +313,13 @@ class ServerConnection:
         self._rx_resume: asyncio.Event | None = None
         self._notif_dropping = False
         self.evicted: str | None = None
+        #: Forwarded-write state (:meth:`ZKServer.forward_write`): a
+        #: write of this connection waits in the member's batch, and
+        #: the packets that arrived behind it, in arrival order — the
+        #: connection answers in request order, so they are dispatched
+        #: only once that write's reply is written (:meth:`_resume`).
+        self._fwd_wait = False
+        self._fwd_behind: collections.deque = collections.deque()
         #: Outbound cork (io/sendplane.py): replies and notifications
         #: of one event-loop tick leave as a single writer.write (a
         #: pipelined request batch is answered with one segment) —
@@ -690,22 +698,25 @@ class ServerConnection:
             # Outstanding accounting is batch-scoped: a
             # pipelined read delivers N requests at once, and
             # every one is outstanding until its handler
-            # replies.  (Handlers are synchronous today, so a
-            # concurrent mntr scrape observes nonzero only
-            # across a handler that awaits — e.g. via an
-            # injected fault gate — but the accounting stays
-            # correct if handlers ever grow await points.)
+            # replies — a forwarded write until its batch is
+            # answered (ZKServer._flush_forwards), and what waits
+            # behind it until :meth:`_resume` has dispatched it.
             self.server.outstanding += len(pkts)
             remaining = len(pkts)
             try:
                 for pkt in pkts:
                     self.server.packets_received += 1
+                    if self._fwd_wait:
+                        self._fwd_behind.append(pkt)
+                        remaining -= 1
+                        continue
                     if self.codec.handshaking:
                         self._handle_connect(pkt)
                     else:
                         self._handle_request(pkt)
-                    self.server.outstanding -= 1
                     remaining -= 1
+                    if not self._fwd_wait:
+                        self.server.outstanding -= 1
                     if self.closed:
                         break
             finally:
@@ -765,6 +776,7 @@ class ServerConnection:
         self._drain_fanout()
         self._tx.flush_hard()
         self.closed = True
+        self._drop_behind()
         self._unsubscribe()
         if self._ingress is not None:
             self._ingress.forget(self)
@@ -786,6 +798,7 @@ class ServerConnection:
         self.closed = True
         self._fanout_buf.clear()
         self._tx.reset()
+        self._drop_behind()
         self._unsubscribe()
         if self._ingress is not None:
             self._ingress.forget(self)
@@ -803,6 +816,47 @@ class ServerConnection:
                 self.writer.close()
         except (ConnectionError, RuntimeError):
             pass
+
+    # -- forwarded writes (ZKServer.forward_write) --
+
+    def _answer_forward(self, pkt: dict, method: str, status: str,
+                        payload) -> None:
+        """This connection's element of an answered batch: the
+        write's reply — or its error code; ``'lost'`` is the outcome-
+        unknown ``CONNECTION_LOSS`` one lost write always got — then
+        whatever waited behind it."""
+        self._fwd_wait = False
+        self.server.outstanding -= 1
+        if self.closed:
+            return
+        xid, op = pkt['xid'], pkt['opcode']
+        if status == 'ok':
+            self._reply(xid, op, **self._write_body(method, payload))
+        elif status == 'err':
+            self._reply(xid, op, err=payload)
+        elif status == 'lost':
+            self._reply(xid, op, err='CONNECTION_LOSS')
+        else:
+            raise RuntimeError('leader rpc failed: %s' % (payload,))
+        self._resume()
+
+    def _resume(self) -> None:
+        """Dispatch what arrived behind an answered forwarded write,
+        in arrival order, up to and including the next write (which
+        joins the member's next batch)."""
+        behind = self._fwd_behind
+        while behind and not self._fwd_wait and not self.closed:
+            try:
+                self._handle_request(behind.popleft())
+            finally:
+                if not self._fwd_wait:
+                    self.server.outstanding -= 1
+
+    def _drop_behind(self) -> None:
+        """The connection is gone: what waited behind its forwarded
+        write is never dispatched, and leaves the gauge."""
+        self.server.outstanding -= len(self._fwd_behind)
+        self._fwd_behind.clear()
 
     # -- handshake (session create / resume / migrate) --
 
@@ -897,23 +951,44 @@ class ServerConnection:
         gate.defer(self, pkt, floor)
         return True
 
-    def _op_create(self, pkt: dict) -> None:
+    def _write(self, pkt: dict, method: str, *args) -> None:
+        """One write op, fenced and throttled like every write, then
+        applied through the leader database ``db`` — or, on a member
+        whose ``db`` FORWARDS (an OS-process follower's
+        ``RemoteLeader``), queued for this turn's one batch RPC
+        (:meth:`ZKServer.forward_write`): the reply follows from the
+        batch's flush, and until it is written this connection
+        handles nothing further (:meth:`_feed`)."""
         self._check_fence()
-        self._check_throttle('CREATE')
-        path = self.db.create(pkt['path'], pkt['data'], pkt['acl'],
-                              CreateFlag(pkt['flags']), self.session)
+        self._check_throttle(pkt['opcode'])
+        if hasattr(self.db, 'forward'):
+            self.server.forward_write(self, pkt, method, args)
+            return
+        result = getattr(self.db, method)(*args)
         # a write through this member catches its store up through the
         # transaction (real ZK: the follower commits before replying),
         # so the author can always read their own write here
         self.store.catch_up()
-        self._reply(pkt['xid'], 'CREATE', path=path)
+        self._reply(pkt['xid'], pkt['opcode'],
+                    **self._write_body(method, result))
+
+    @staticmethod
+    def _write_body(method: str, result) -> dict:
+        """The reply body a write's result travels in."""
+        if method == 'create':
+            return {'path': result}
+        if method == 'set_data':
+            return {'stat': result}
+        if method == 'multi':
+            return {'results': result}
+        return {}
+
+    def _op_create(self, pkt: dict) -> None:
+        self._write(pkt, 'create', pkt['path'], pkt['data'],
+                    pkt['acl'], CreateFlag(pkt['flags']), self.session)
 
     def _op_delete(self, pkt: dict) -> None:
-        self._check_fence()
-        self._check_throttle('DELETE')
-        self.db.delete(pkt['path'], pkt['version'])
-        self.store.catch_up()
-        self._reply(pkt['xid'], 'DELETE')
+        self._write(pkt, 'delete', pkt['path'], pkt['version'])
 
     def _op_get_data(self, pkt: dict) -> None:
         if self._gated(pkt):
@@ -927,11 +1002,8 @@ class ServerConnection:
         self._reply(pkt['xid'], 'GET_DATA', data=data, stat=stat)
 
     def _op_set_data(self, pkt: dict) -> None:
-        self._check_fence()
-        self._check_throttle('SET_DATA')
-        stat = self.db.set_data(pkt['path'], pkt['data'], pkt['version'])
-        self.store.catch_up()
-        self._reply(pkt['xid'], 'SET_DATA', stat=stat)
+        self._write(pkt, 'set_data', pkt['path'], pkt['data'],
+                    pkt['version'])
 
     def _op_exists(self, pkt: dict) -> None:
         if self._gated(pkt):
@@ -979,11 +1051,7 @@ class ServerConnection:
         body: a rejected batch carries per-op error results (the
         failing op's code, RUNTIME_INCONSISTENCY elsewhere) with NO
         sub-op applied."""
-        self._check_fence()
-        self._check_throttle('MULTI')
-        results = self.db.multi(pkt['ops'], self.session)
-        self.store.catch_up()
-        self._reply(pkt['xid'], 'MULTI', results=results)
+        self._write(pkt, 'multi', pkt['ops'], self.session)
 
     def _op_sync(self, pkt: dict) -> None:
         # Flush replication: this member applies everything the leader
@@ -1241,6 +1309,11 @@ class ZKServer:
         self.packets_received = 0
         self.packets_sent = 0
         self.outstanding = 0
+        #: The writes this member's connections handed it during the
+        #: current turn of the loop, as ``(conn, pkt, method, args)``
+        #: in arrival order (:meth:`forward_write`; only a member
+        #: whose ``db`` forwards ever fills it).
+        self._forwards: list = []
         #: Election plane (server/election.py).  ``role`` is this
         #: member's current quorum role (leader | follower |
         #: electing); ``fence`` an optional callable — True while this
@@ -1324,6 +1397,69 @@ class ZKServer:
             # at/over the threshold get their causal chain persisted
             self.trace.slow_ms = slow_op_ms()
             self.trace.on_slow = self.blackbox.slow_span
+
+    def forward_write(self, conn: ServerConnection, pkt: dict,
+                      method: str, args: tuple) -> None:
+        """Queue one write of a member that forwards (its ``db`` is a
+        ``server/replication.py RemoteLeader``).  The queue is
+        flushed ONCE a turn of the loop: the first write schedules
+        the flush with ``call_soon``, so it runs behind every ingress
+        shard's drain of this turn (io/ingress.py ``_drain_shard`` —
+        they were all scheduled before it; a flush per shard would
+        cut the turn's batch into pieces), and whichever path fed the
+        write — a shard's drain, the validator's ``read()``, a fault
+        injector's delayed ``_feed`` — it cannot be stranded.  One
+        path: a turn with one write sends a batch of one."""
+        if not self._forwards:
+            from ..utils.aio import ambient_loop
+            ambient_loop().call_soon(self._flush_forwards)
+        self._forwards.append((conn, pkt, method, args))
+        conn._fwd_wait = True
+
+    def _flush_forwards(self) -> None:
+        """One blocking control-channel round trip (tick phase
+        ``forward_rpc``) for the turn's writes: the leader applies
+        them in order, makes them durable once and waits for the
+        quorum once; then the mirror catches up once and every write
+        is answered in queue order — its reply, or its own error —
+        each connection going on with what it had received behind its
+        write.  The replies leave behind this member's own tick
+        barrier like any reply (the send plane's cork)."""
+        queue, self._forwards = self._forwards, []
+        if not queue:
+            return
+        ledger = self.ledger
+        ledger.enter('decode_apply')
+        try:
+            try:
+                results = self.db.forward(
+                    [(method, args) for _, _, method, args in queue])
+            except Exception as e:
+                # not the leader's death (that is a result, 'lost'):
+                # a protocol fault.  Loud, and nobody stays parked.
+                log.exception('forwarded batch failed')
+                results = [('exc', repr(e))] * len(queue)
+            self.store.catch_up()
+            for (conn, pkt, method, _), (status, payload) in zip(
+                    queue, results):
+                # one connection's failure must not take the rest of
+                # the batch with it (the ingress drain's rule)
+                try:
+                    conn._answer_forward(pkt, method, status, payload)
+                    if self.overload is not None and not conn.closed:
+                        self.overload.check_tx(conn)
+                except Exception:
+                    log.exception('forwarded write: answer failed; '
+                                  'closing connection')
+                    conn.close()
+        finally:
+            ledger.exit()
+
+    def _drop_forwards(self) -> None:
+        """The connections are being severed (stop / repoint): their
+        queued writes were never sent and are never answered."""
+        self.outstanding -= len(self._forwards)
+        self._forwards = []
 
     @property
     def ack_barrier(self):
@@ -1489,6 +1625,7 @@ class ZKServer:
             self.ingress.stop()
         if self.read_gate is not None:
             self.read_gate.reset()   # parked reads die with the conns
+        self._drop_forwards()
         for conn in list(self.conns):
             conn.close()
         self.conns.clear()
@@ -1634,6 +1771,7 @@ class ZKServer:
         resume or re-create sessions, and SET_WATCHES re-arms — and
         the event subscriptions (session expiry, watch-table store
         listeners, trace wiring) move to the new storage."""
+        self._drop_forwards()
         for conn in list(self.conns):
             conn.close()
         self.conns.clear()
@@ -1838,6 +1976,13 @@ class ZKServer:
             [] if self.watch_table is None else
             [('zk_persistent_notifications',
               self.watch_table.persistent_sent)])
+        # a forwarding member's batches (server/replication.py
+        # RemoteLeader.forward), cumulative: writes over RPCs is what
+        # a turn of its loop collected
+        forward_rows: list[tuple[str, object]] = (
+            [] if not hasattr(self.db, 'forward') else
+            [('zk_forward_rpcs', self.db.forward_rpcs),
+             ('zk_forward_writes', self.db.forward_writes)])
         return [
             ('zk_version', 'zkstream_tpu'),
             ('zk_uptime_ms',
@@ -1876,7 +2021,8 @@ class ZKServer:
             + (self.overload.mntr_rows()
                if self.overload is not None else []) \
             + multi_rows + gate_rows \
-            + quorum_rows + config_rows + fanout_rows + tick_rows \
+            + quorum_rows + config_rows + fanout_rows + forward_rows \
+            + tick_rows \
             + blackbox_rows \
             + wal_rows + (self._histogram_rows() if histograms else [])
 

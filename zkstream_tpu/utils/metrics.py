@@ -337,11 +337,14 @@ class TickLedger:
     - ``fanout_flush`` — the watch table's per-shard flush loop
       (minus the nested cork writes it triggers);
     - ``forward_rpc`` — a follower parked in the blocking control-
-      channel RPC that forwards a write (or a session open/close) to
-      the leader (server/replication.py ``RemoteLeader._rpc``): the
-      whole loop stands still for it.  Nested under ``decode_apply``
-      like the rest, so a follower's ``decode_apply`` is its own
-      decode and dispatch, not the leader's round trip.
+      channel RPC (server/replication.py ``RemoteLeader._rpc``): the
+      whole loop stands still for it.  Writes cost one once a turn of
+      the loop — the turn's writes leave as ONE ``batch`` from the
+      flush of the server's forward queue (server/server.py
+      ``ZKServer._flush_forwards``) — a session open/close or a
+      ``sync`` one each.  Nested under ``decode_apply`` like the
+      rest, so a follower's ``decode_apply`` is its own decode,
+      catch-up and replies, not the leader's round trip.
 
     A "tick" here is the whole burst: asyncio runs ``call_soon``
     callbacks scheduled during a callback in the *next* loop
