@@ -1418,13 +1418,26 @@ static PyObject *py_abi_version(PyObject *self, PyObject *noargs) {
  * the ~100-byte record bodies the WAL appends per committed txn. */
 static uint32_t crc32c_table[256];
 
+/* Slicing-by-8: crc32c_tab8[0] is the byte-at-a-time table; table k
+ * advances a byte k positions further, so eight bytes fold in one
+ * step (a WAL record can be ~1 MB: server/persist.py appends it on
+ * the member's loop).  Little-endian hosts; others take the byte
+ * walk. */
+static uint32_t crc32c_tab8[8][256];
+
 static void crc32c_table_init(void) {
   for (uint32_t i = 0; i < 256; i++) {
     uint32_t c = i;
     for (int k = 0; k < 8; k++)
       c = (c & 1) ? (c >> 1) ^ 0x82F63B78u : c >> 1;
     crc32c_table[i] = c;
+    crc32c_tab8[0][i] = c;
   }
+  for (int k = 1; k < 8; k++)
+    for (uint32_t i = 0; i < 256; i++) {
+      uint32_t c = crc32c_tab8[k - 1][i];
+      crc32c_tab8[k][i] = (c >> 8) ^ crc32c_tab8[0][c & 0xFFu];
+    }
 }
 
 static PyObject *py_crc32c(PyObject *self, PyObject *args) {
@@ -1434,6 +1447,20 @@ static PyObject *py_crc32c(PyObject *self, PyObject *args) {
   uint32_t c = seed ^ 0xFFFFFFFFu;
   const unsigned char *p = (const unsigned char *)buf.buf;
   Py_ssize_t n = buf.len;
+#if defined(__BYTE_ORDER__) && __BYTE_ORDER__ == __ORDER_LITTLE_ENDIAN__
+  while (n >= 8) {
+    uint32_t lo, hi;
+    memcpy(&lo, p, 4);
+    memcpy(&hi, p + 4, 4);
+    lo ^= c;
+    c = crc32c_tab8[7][lo & 0xFFu] ^ crc32c_tab8[6][(lo >> 8) & 0xFFu] ^
+        crc32c_tab8[5][(lo >> 16) & 0xFFu] ^ crc32c_tab8[4][lo >> 24] ^
+        crc32c_tab8[3][hi & 0xFFu] ^ crc32c_tab8[2][(hi >> 8) & 0xFFu] ^
+        crc32c_tab8[1][(hi >> 16) & 0xFFu] ^ crc32c_tab8[0][hi >> 24];
+    p += 8;
+    n -= 8;
+  }
+#endif
   for (Py_ssize_t i = 0; i < n; i++)
     c = crc32c_table[(c ^ p[i]) & 0xFFu] ^ (c >> 8);
   PyBuffer_Release(&buf);

@@ -107,6 +107,16 @@ def _codec(use_native: bool, monkeypatch) -> None:
         monkeypatch.setenv('ZKSTREAM_NO_NATIVE', '1')
 
 
+#: the async runner's default budget (tests/conftest.py: 30 s of wall
+#: clock a test) is for tests that wait on sockets; these compile
+#: dozens of tick programs (every class x row count the corpus
+#: reaches), which alone takes ~10-25 s and several times that beside
+#: five other workers: the budget of a test that compiles is set by
+#: what it compiles, not by the machine's load
+COMPILES = pytest.mark.timeout(300)
+
+
+@COMPILES
 @pytest.mark.parametrize('use_native', [True, False],
                          ids=['ext', 'no_native'])
 @pytest.mark.parametrize('seed', [3, 30, 300])
@@ -328,6 +338,7 @@ async def test_prewarm_compiles_the_bucket_asked_for(n, nbytes, bound,
     ingest.close()
 
 
+@COMPILES
 async def test_no_bucket_after_warmup_in_a_run_of_mixed_sizes(
         monkeypatch):
     """Warmed as a deployment warms (every class its replies reach x

@@ -105,6 +105,7 @@ from ..protocol.records import (
     _EMPTY_RESPONSES,
     _RESP_READERS,
 )
+from ..utils import alloc
 from ..utils.logging import Logger
 from ..utils.metrics import TICK_BUCKETS, Histogram
 from ..utils.trace import NO_SPAN, host_span
@@ -293,6 +294,10 @@ class FleetIngest:
         #: connection's direct settle lane (:meth:`register`), None
         #: for one that takes its packets as 'ingestDeliver' events
         self._slots: dict[int, tuple] = {}
+        # the process that holds a fleet's sessions makes and frees a
+        # ``bytes`` a reply body, ~1 MB each in a herd of large
+        # re-reads: keep them for the next tick (utils/alloc.py)
+        alloc.keep_freed_memory()
         self._scheduled = False
         #: diagnostics for tests/benchmarks (``ticks`` counts device
         #: ticks; small ticks under ``bypass_bytes`` and ticks deferred
@@ -331,6 +336,10 @@ class FleetIngest:
         self.bytes_dispatched = 0
         self.bytes_recopied = 0
         self.slots_deferred = 0
+        #: device ticks that left whole frames in their slots for the
+        #: follow-up tick because the tick's batch memory
+        #: (``TICK_BYTES``) was full
+        self.ticks_full = 0
         #: Upper dispatch guard: when a large fleet's connections
         #: desynchronize, the tick batches fragment (a small share of
         #: the slots hold a frame) and the per-socket drain is the
@@ -834,7 +843,10 @@ class FleetIngest:
                  'partial frame behind whole ones), so batched again'),
                 ('zkstream_ingest_deferred_slots', 'slots_deferred',
                  'slot-ticks sat out because the slot\'s first frame '
-                 'was not whole yet')):
+                 'was not whole yet'),
+                ('zkstream_ingest_full_ticks', 'ticks_full',
+                 'device ticks that left whole frames in their slots '
+                 'because the tick\'s batch memory was full')):
             collector.gauge(prefix + name,
                             (lambda a=attr: getattr(self, a)),
                             help_text)
@@ -1275,6 +1287,7 @@ class FleetIngest:
                 used += Bp * L
             elif used:
                 # the tick is full: the rest waits for the next one
+                self.ticks_full += 1
                 self._schedule()
                 break
             else:   # one row wider than a tick: a frame near the cap

@@ -99,6 +99,8 @@ BACKENDS = ('uring', 'mmsg', 'asyncio')
 
 METRIC_FLUSH_SYSCALLS = 'zookeeper_flush_syscalls_total'
 METRIC_SUBMIT_DEPTH = 'zookeeper_submit_depth'
+METRIC_FLUSH_PARTIAL = 'zookeeper_flush_partial_total'
+METRIC_FLUSH_REQUEUED = 'zookeeper_flush_partial_requeued_bytes'
 
 #: Connections per batched submission (the depth distribution: 1 =
 #: batching bought nothing that tick, the interesting mass is 2+).
@@ -302,6 +304,13 @@ class TransportTier:
         self._uring_dead = False
         self.syscalls = 0        # lifetime submissions (tests/mntr)
         self.submissions = 0     # batched submit rounds
+        #: connection flushes submitted raw, those of them the kernel
+        #: took only part of (its socket buffer filled inside a large
+        #: request or reply), and the bytes of their remainders, which
+        #: went through the asyncio transport instead
+        self.flushes = 0
+        self.partial_flushes = 0
+        self.requeued_bytes = 0
         #: Clients holding a :class:`TierLease` on this tier (a
         #: server's tier is its own and stays at 0).
         self.refs = 0
@@ -323,6 +332,14 @@ class TransportTier:
             'Connections covered per batched transport '
             'submission, by plane and backend',
             buckets=DEPTH_BUCKETS)
+        self.partial_ctr = source.counter(
+            METRIC_FLUSH_PARTIAL,
+            'Raw connection flushes the kernel took only part of, '
+            'by plane')
+        self.requeued_ctr = source.counter(
+            METRIC_FLUSH_REQUEUED,
+            'Bytes of partial raw flushes re-queued through the '
+            'asyncio transport, by plane')
 
     # -- SendPlane-facing API --
 
@@ -483,6 +500,7 @@ class TransportTier:
             if led is not None:
                 led.exit()
         self.submissions += 1
+        self.flushes += len(batch_fds)
         self._count(nsys, self.backend)
         self.depth_hist.observe(
             len(batch_fds), {'plane': self.plane,
@@ -510,9 +528,21 @@ class TransportTier:
         # partial write: the kernel buffer filled mid-entry — the
         # remainder must queue in the transport so later ticks (which
         # see a nonzero write buffer) stay behind it
-        rem = memoryview(b''.join(chunks))[res:]
+        self.partial_flushes += 1
+        self.requeued_bytes += nbytes - res
+        labels = {'plane': self.plane}
+        self.partial_ctr.increment(labels)
+        self.requeued_ctr.increment(labels, by=nbytes - res)
         self._count(1, 'asyncio')
-        entry.write(bytes(rem))
+        # the remainder alone, copied once: whole chunks the kernel
+        # took are skipped, not joined and sliced away
+        for i, c in enumerate(chunks):
+            if res < len(c):
+                break
+            res -= len(c)
+        head = memoryview(chunks[i])[res:]
+        entry.write(bytes(head) if i + 1 == len(chunks)
+                    else b''.join([head] + chunks[i + 1:]))
 
     # -- backends --
 
@@ -644,8 +674,9 @@ class TierLease:
                     self.backend, plane='client')
             tier.refs += 1
         self._tier, self._loop = tier, loop
-        self._collector.adopt(tier.syscall_ctr)
-        self._collector.adopt(tier.depth_hist)
+        for series in (tier.syscall_ctr, tier.depth_hist,
+                       tier.partial_ctr, tier.requeued_ctr):
+            self._collector.adopt(series)
         return tier
 
     def release(self) -> None:

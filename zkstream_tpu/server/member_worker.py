@@ -45,6 +45,11 @@ def main() -> int:
     if root not in sys.path:
         sys.path.insert(0, root)
     from zkstream_tpu.server.election import run_member
+    from zkstream_tpu.utils import alloc
+
+    # a member's loop makes and frees ~1 MB blocks for every large
+    # write and reply: keep them for the next one (utils/alloc.py)
+    alloc.keep_freed_memory()
 
     # a read-plane member may serve up to a million sessions: lift
     # the soft fd limit as far as the host allows, and name the

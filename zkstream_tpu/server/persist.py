@@ -106,23 +106,27 @@ _crc_impl = None
 def crc32c(data: bytes, crc: int = 0) -> int:
     """CRC32C over ``data`` (standard reflected form; chainable via
     ``crc``).  Tiered like the wire codec: the C extension's
-    table walk when built (~60x — it checksums every appended record
-    on the commit hot path), the Python spec otherwise; A/B-tested
-    equal in tests/test_wal.py.  The binding resolves once, at first
-    use, through the same already-built-artifact rule the frame
-    scanner uses (utils/native.get_ext — never a blocking build)."""
+    slicing-by-8 walk when built (it checksums every appended record
+    on the commit hot path: ~0.2 ms a 0.5 MB record against ~50 ms
+    for the Python spec), the Python spec otherwise; A/B-tested equal
+    in tests/test_wal.py.  The binding resolves through the same
+    already-built-artifact rule the frame scanner uses
+    (utils/native.get_ext — never a blocking build) and only the
+    extension's is latched: a member whose first append came before
+    the background build landed takes the C walk from the first
+    append after it, not the Python one for the rest of its life."""
     global _crc_impl
-    if _crc_impl is None:
+    impl = _crc_impl
+    if impl is None:
         impl = software_crc32c
         try:
             from ..utils import native
             ext = native.get_ext()
             if ext is not None and hasattr(ext, 'crc32c'):
-                impl = ext.crc32c
+                impl = _crc_impl = ext.crc32c
         except Exception:  # pragma: no cover - packaging-broken ext
             pass
-        _crc_impl = impl
-    return _crc_impl(data, crc)
+    return impl(data, crc)
 
 
 # ---------------------------------------------------------------------
@@ -277,12 +281,17 @@ def _spec_encode_entry(entry: tuple) -> bytes:
     return w.to_bytes()
 
 
+def _buf_len(data: bytes) -> bytes:
+    """A jute buffer's length prefix (-1 for empty — the wire quirk the
+    spec tier inherits from protocol/jute.py).  A znode's data goes
+    into its record's one ``join`` behind this, not through a copy of
+    its own first (a record can be ~1 MB)."""
+    return _I.pack(len(data)) if data else b'\xff\xff\xff\xff'
+
+
 def _buf(data: bytes) -> bytes:
-    """Jute buffer: length prefix (-1 for empty — the wire quirk the
-    spec tier inherits from protocol/jute.py)."""
-    if not data:
-        return b'\xff\xff\xff\xff'
-    return _I.pack(len(data)) + data
+    """Jute buffer: length prefix, then the bytes (small fields)."""
+    return _buf_len(data) + data
 
 
 def encode_entry(entry: tuple) -> bytes:
@@ -292,8 +301,8 @@ def encode_entry(entry: tuple) -> bytes:
     if op == 'set_data':
         _, path, data, zxid, now = entry
         p = path.encode('utf-8')
-        return b''.join((b'\x03', _I.pack(len(p)), p, _buf(data),
-                         _Q2.pack(zxid, now)))
+        return b''.join((b'\x03', _I.pack(len(p)), p, _buf_len(data),
+                         data, _Q2.pack(zxid, now)))
     if op == 'epoch':
         return b'\x04' + _Q2.pack(entry[1], entry[2])
     if op == 'session':
@@ -326,7 +335,7 @@ def encode_entry(entry: tuple) -> bytes:
     if op == 'create':
         _, path, data, acl, eph_owner, zxid, now = entry
         p = path.encode('utf-8')
-        parts = [b'\x01', _I.pack(len(p)), p, _buf(data),
+        parts = [b'\x01', _I.pack(len(p)), p, _buf_len(data), data,
                  _I.pack(len(acl))]
         for a in acl:
             s = a.id.scheme.encode('utf-8')
@@ -828,11 +837,16 @@ class WriteAheadLog:
         self.trace = None
         #: Optional utils/metrics.TickLedger: loop-blocking sync time
         #: (sync='always' appends, the tick-sync fast path) lands in
-        #: the ``fsync_gate`` tick phase.
+        #: the ``fsync_gate`` tick phase, a record's build + CRC +
+        #: write in ``wal_append``, a segment roll's blocking sync and
+        #: snapshot capture in ``wal_roll``.
         self.ledger = None
         self._tree = None
         # counters (gauges read these; cheap ints, no hot-path cost)
         self.appends = 0
+        #: cumulative record bytes appended (header + body): with the
+        #: ``wal_append`` tick phase, what a MiB of log costs the loop
+        self.appended_bytes = 0
         self.fsyncs = 0
         self.sync_errors = 0
         self.snapshots_taken = 0
@@ -1000,19 +1014,33 @@ class WriteAheadLog:
         Runs *before* the txn's ack is corked (store.py `_commit`), so
         the sync policy's barrier covers it."""
         assert not self._closed, 'append to a closed WAL'
-        body = encode_entry(entry)
-        rec = _REC_HDR.pack(len(body), crc32c(body)) + body
-        self._file.write(rec)
-        self._written += len(rec)
+        led = self.ledger
+        if led is not None:
+            led.enter('wal_append')
+        try:
+            body = encode_entry(entry)
+            hdr = _REC_HDR.pack(len(body), crc32c(body))
+            nrec = len(hdr) + len(body)
+            # header, then body: a ~1 MB record is not copied once
+            # more to sit behind its 8 bytes (the file is buffered; a
+            # crash between the two is a torn tail, as inside one
+            # write)
+            self._file.write(hdr)
+            self._file.write(body)
+        finally:
+            if led is not None:
+                led.exit()
+        self._written += nrec
+        self.appended_bytes += nrec
         self.appends += 1
         idx = self.next_index
         self.next_index += 1
         self.last_zxid = entry_zxid(entry)
         if self._append_hist is not None:
-            self._append_hist.observe(len(rec))
+            self._append_hist.observe(nrec)
         if self.trace is not None:
             self.trace.note('WAL_APPEND', zxid=self.last_zxid,
-                            kind='server', nbytes=len(rec))
+                            kind='server', nbytes=nrec)
         if self.sync == 'always':
             if self.ledger is not None:
                 self.ledger.enter('fsync_gate')
@@ -1269,12 +1297,23 @@ class WriteAheadLog:
 
     def roll(self) -> None:
         """Close the open segment (fsynced), open the next, and take
-        the snapshot that anchors truncation of everything before it."""
-        self.sync_now()
-        self._file.close()
-        self._closed_segments.append((self._seg_start, self._seg_path))
-        self._open_segment()
-        self.snapshot_now()
+        the snapshot that anchors truncation of everything before it.
+        What of it holds the loop — the blocking sync and the image's
+        capture (the file's write is on an executor) — is the tick
+        phase ``wal_roll``."""
+        led = self.ledger
+        if led is not None:
+            led.enter('wal_roll')
+        try:
+            self.sync_now()
+            self._file.close()
+            self._closed_segments.append((self._seg_start,
+                                          self._seg_path))
+            self._open_segment()
+            self.snapshot_now()
+        finally:
+            if led is not None:
+                led.exit()
 
     def snapshot_now(self) -> bool:
         """Take one fuzzy snapshot: stamp + image captured atomically

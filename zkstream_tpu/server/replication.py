@@ -107,6 +107,13 @@ WRITE_METHODS = frozenset(('create', 'delete', 'set_data', 'multi'))
 #: loops.  One element always goes, whatever its size.
 FORWARD_BATCH_BYTES = 4 << 20
 
+#: The asyncio streams' buffer limit on the leader's and the mirrors'
+#: channels.  ``readexactly`` of a framed message pauses the transport
+#: whenever twice the limit is buffered and resumes it when the reader
+#: runs: at the default 64 KiB a ~1 MB push or batch crosses its loop
+#: in eight pause / resume turns, at this limit in one.
+STREAM_LIMIT = FORWARD_BATCH_BYTES
+
 
 class ZKLeaderLostError(ZKOpError):
     """The leader process died (or the control channel was severed)
@@ -141,21 +148,24 @@ async def _read_msg(reader: asyncio.StreamReader):
     return pickle.loads(await reader.readexactly(n))
 
 
+def _recv_exact(sock: socket.socket, n: int) -> bytearray:
+    """``n`` bytes off a blocking socket, received into ONE buffer (a
+    response that piggybacks a ~1 MB entry arrives over many reads:
+    appending each to a ``bytes`` copied what had come, again and
+    again)."""
+    out = bytearray(n)
+    view, got = memoryview(out), 0
+    while got < n:
+        k = sock.recv_into(view[got:])
+        if not k:
+            raise ConnectionError('replication control channel closed')
+        got += k
+    return out
+
+
 def _recv_msg(sock: socket.socket):
-    buf = b''
-    while len(buf) < 4:
-        chunk = sock.recv(4 - len(buf))
-        if not chunk:
-            raise ConnectionError('replication control channel closed')
-        buf += chunk
-    (n,) = _LEN.unpack(buf)
-    out = b''
-    while len(out) < n:
-        chunk = sock.recv(n - len(out))
-        if not chunk:
-            raise ConnectionError('replication control channel closed')
-        out += chunk
-    return pickle.loads(out)
+    (n,) = _LEN.unpack(_recv_exact(sock, 4))
+    return pickle.loads(_recv_exact(sock, n))
 
 
 def _wire_args(method: str, args: tuple) -> tuple:
@@ -642,7 +652,7 @@ class ReplicationService:
 
     async def start(self) -> 'ReplicationService':
         self._server = await asyncio.start_server(
-            self._on_follower, self.host, self.port)
+            self._on_follower, self.host, self.port, limit=STREAM_LIMIT)
         self.port = self._server.sockets[0].getsockname()[1]
         if not self._subscribed:
             self.db.on('committed', self._push_commits)
@@ -698,6 +708,20 @@ class ReplicationService:
             pass
 
     def _push_commits(self) -> None:
+        """Ship what each mirror has not been sent.  Tick phase
+        ``repl_push`` on the database's ledger (frame + send), and the
+        bytes handed to the mirrors' transports in the database's
+        cumulative ``repl_pushed_bytes`` (mntr ``zk_repl_pushed_bytes``)."""
+        led = getattr(self.db, 'ledger', None)
+        if led is not None:
+            led.enter('repl_push')
+        try:
+            self._push_commits_inner()
+        finally:
+            if led is not None:
+                led.exit()
+
+    def _push_commits_inner(self) -> None:
         trace = getattr(self.db, 'trace', None)
         self.quorum.note_pushed(self.db.zxid)
         #: per-cursor encode memo: steady-state mirrors share one
@@ -715,6 +739,7 @@ class ReplicationService:
                         ('commit', base, entries, self.epoch))
                 self._push(h, ('commit', base, entries, self.epoch),
                            data=data)
+                self.db.repl_pushed_bytes += len(data)
                 h.shipped = base + len(entries)
                 if trace is not None:
                     # one push span per follower, keyed by the newest
@@ -1104,7 +1129,7 @@ class RemoteLeader(EventEmitter):
         self._sock.sendall(_dump(('control', self._token, None,
                                   role)))
         reader, writer = await asyncio.open_connection(
-            self.host, self.port)
+            self.host, self.port, limit=STREAM_LIMIT)
         writer.write(_dump(('events', self._token, self.have_zxid,
                             role)))
         await writer.drain()

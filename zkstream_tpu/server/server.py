@@ -1747,6 +1747,7 @@ class ZKServer:
             # COMMIT spans, the WAL's append/fsync spans and its
             # loop-blocking sync time all belong to this ring
             self.db.trace = self.trace
+            self.db.ledger = self.ledger
             wal = getattr(self.db, 'wal', None)
             if wal is not None:
                 wal.trace = self.trace
@@ -1760,7 +1761,9 @@ class ZKServer:
             # channel to the leader (server/replication.py
             # RemoteLeader): the loop time it parks in a forwarded
             # RPC is this member's ``forward_rpc`` tick phase
-            if hasattr(self.db, 'ledger'):
+            # (``forward`` marks it: the in-process followers' shared
+            # ZKDatabase has a ledger too — its leader member's)
+            if hasattr(self.db, 'forward'):
                 self.db.ledger = self.ledger
 
     def repoint(self, db, store=None, role: str | None = None) -> None:
@@ -1903,7 +1906,13 @@ class ZKServer:
             ('zk_wal_fsyncs', wal.fsyncs),
             ('zk_wal_sync_errors', wal.sync_errors),
             ('zk_wal_snapshots', wal.snapshots_taken),
+            ('zk_wal_appended_bytes', wal.appended_bytes),
         ]
+        # cumulative bytes of commit pushes to OS-process mirrors
+        # (server/replication.py); a RemoteLeader has no such count
+        pushed = getattr(self.db, 'repl_pushed_bytes', None)
+        if pushed is not None:
+            wal_rows.append(('zk_repl_pushed_bytes', pushed))
         # quorum-commit rows (server/replication.py QuorumGate): the
         # majority floor, degraded (quorum-unconfirmed) releases and
         # epoch-fenced stale acks

@@ -333,9 +333,19 @@ class ZKDatabase(NodeTree):
     ``sessionExpired(session_id)``, ``committed()``.
     """
 
+    #: Optional utils/metrics.TickLedger of the member that serves this
+    #: database (server/server.py wires it beside ``trace``): whoever
+    #: replicates the log books a commit's pushes there (tick phase
+    #: ``repl_push``).
+    ledger = None
+
     def __init__(self) -> None:
         super().__init__()
         self.sessions: dict[int, ZKServerSession] = {}
+        #: cumulative bytes of commit pushes handed to mirror
+        #: transports (server/replication.py ``_push_commits``; mntr
+        #: ``zk_repl_pushed_bytes``): 0 where replicas apply in process
+        self.repl_pushed_bytes = 0
         #: Leadership epoch (server/election.py): a fencing token, not
         #: a zxid component.  0 until the first election; bumped by the
         #: winning member (``bump_epoch``), persisted as a WAL control

@@ -344,7 +344,17 @@ class TickLedger:
       ``ZKServer._flush_forwards``) — a session open/close or a
       ``sync`` one each.  Nested under ``decode_apply`` like the
       rest, so a follower's ``decode_apply`` is its own decode,
-      catch-up and replies, not the leader's round trip.
+      catch-up and replies, not the leader's round trip;
+    - ``wal_append`` — one record's build, CRC32C and write
+      (server/persist.py ``WriteAheadLog.append``, without the fsync
+      gate), ``wal_roll`` — what a segment roll holds the loop for
+      (its blocking sync + the whole-tree snapshot's capture), and
+      ``repl_push`` — a commit's pushes to the mirrors, frame + send
+      (server/replication.py ``_push_commits``).  All three nest
+      under whatever phase holds that time (``decode_apply`` for a
+      client's write; none for a write the control channel applied,
+      whose service has no phase of its own), so the parents keep
+      their subject and the three say what a large record costs.
 
     A "tick" here is the whole burst: asyncio runs ``call_soon``
     callbacks scheduled during a callback in the *next* loop
@@ -360,7 +370,8 @@ class TickLedger:
     """
 
     PHASES = ('rx_drain', 'decode_apply', 'fsync_gate', 'cork_flush',
-              'fanout_flush', 'forward_rpc')
+              'fanout_flush', 'forward_rpc', 'wal_append', 'wal_roll',
+              'repl_push')
 
     #: Close a still-active burst after this many loop iterations
     #: anyway: under saturating back-to-back load every iteration has
@@ -388,7 +399,8 @@ class TickLedger:
         self.phase_hist = source.histogram(
             METRIC_TICK_PHASE,
             'Busy-tick time by phase, ms (rx_drain | decode_apply | '
-            'fsync_gate | cork_flush | fanout_flush | forward_rpc)',
+            'fsync_gate | cork_flush | fanout_flush | forward_rpc | '
+            'wal_append | wal_roll | repl_push)',
             buckets=TICK_BUCKETS)
         self.tick_hist = source.histogram(
             METRIC_TICK, 'Busy-tick wall span, ms',
