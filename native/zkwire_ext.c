@@ -45,13 +45,19 @@
 #include <Python.h>
 
 #include <errno.h>
+#include <fcntl.h>
+#include <pthread.h>
+#include <signal.h>
 #include <stdint.h>
+#include <stdlib.h>
 #include <string.h>
 #include <sys/socket.h>
 #include <sys/uio.h>
+#include <time.h>
 #include <unistd.h>
 
 #ifdef __linux__
+#include <sys/eventfd.h>
 #include <sys/mman.h>
 #include <sys/syscall.h>
 #endif
@@ -1408,7 +1414,7 @@ static PyObject *py_decode_requests(PyObject *self, PyObject *args) {
 }
 
 static PyObject *py_abi_version(PyObject *self, PyObject *noargs) {
-  return PyLong_FromLong(11);
+  return PyLong_FromLong(12);
 }
 
 /* CRC32C (Castagnoli, reflected 0x82F63B78) for the write-ahead-log
@@ -1675,6 +1681,354 @@ fail:
   Py_DECREF(clfast);
   Py_DECREF(results);
   return NULL;
+}
+
+/* ---- native sender thread (io/transport.py, the client plane) -------
+ *
+ * submit_writev holds the GIL, on the event loop's thread, through one
+ * send(2) a connection: at a few hundred connections a tick that is
+ * milliseconds in which nothing else in the process runs.  A sender
+ * is ONE pthread that takes such a batch off the loop: it never
+ * touches a Python object and never takes the GIL.
+ *
+ *   sender_create() -> capsule
+ *   sender_fileno(capsule) -> fd     readable once a batch is done (an
+ *                                    eventfd; a pipe where there is none)
+ *   sender_submit(capsule, fds, chunklists) -> batch_id   (1, 2, ...)
+ *        copies the fds and every chunk's (pointer, length) into a
+ *        malloc'd batch, holds each chunk's buffer until the batch is
+ *        reaped, queues it and returns at once
+ *   sender_reap(capsule) -> [(batch_id, [written|-errno, ...], busy_ns)]
+ *        every finished batch, oldest first; clears the fd
+ *   sender_wait(capsule, batch_id)   blocks, GIL released, until that
+ *                                    batch (and so every earlier one)
+ *                                    is done
+ *   sender_close(capsule)            finishes what is queued, joins
+ *
+ * Batches are sent in submission order by the one thread, an entry by
+ * writev_chunks exactly as submit_writev does it (MSG_NOSIGNAL, the
+ * EINTR retry, the stop at a partial wave); busy_ns is the thread's
+ * own clock around a batch.  What the caller must hold to: an fd is
+ * not closed, and no other write to it is made, while a batch that
+ * names it is in flight (the tier's rules, io/transport.py). */
+
+typedef struct zk_batch {
+  struct zk_batch *next;
+  unsigned long long id;
+  Py_ssize_t n;        /* entries */
+  Py_ssize_t nbufs;    /* chunks over all entries */
+  int *fds;            /* [n] */
+  Py_ssize_t *off;     /* [n + 1]: entry i's chunks are off[i]..off[i+1] */
+  long long *results;  /* [n] */
+  struct iovec *iov;   /* [nbufs] */
+  Py_buffer *bufs;     /* [nbufs], released (GIL held) when reaped */
+  long long busy_ns;
+} zk_batch;
+
+typedef struct {
+  pthread_t thread;
+  pthread_mutex_t mu;
+  pthread_cond_t work; /* the queue grew, or stop */
+  pthread_cond_t done; /* done_id advanced */
+  zk_batch *q_head, *q_tail; /* submitted, not yet sent */
+  zk_batch *d_head, *d_tail; /* sent, not yet reaped */
+  unsigned long long next_id, done_id;
+  int stop;
+  int rfd, wfd; /* the wake-up: one eventfd, or a pipe's two ends */
+} zk_sender;
+
+static zk_sender sender_closed; /* sentinel: explicitly closed */
+
+static long long sender_now_ns(void) {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (long long)ts.tv_sec * 1000000000LL + ts.tv_nsec;
+}
+
+static void *sender_main(void *arg) {
+  zk_sender *s = (zk_sender *)arg;
+  pthread_mutex_lock(&s->mu);
+  for (;;) {
+    while (!s->q_head && !s->stop) pthread_cond_wait(&s->work, &s->mu);
+    zk_batch *b = s->q_head;
+    if (!b) break; /* stop, and nothing left to send */
+    s->q_head = b->next;
+    if (!s->q_head) s->q_tail = NULL;
+    pthread_mutex_unlock(&s->mu);
+    long long t0 = sender_now_ns();
+    for (Py_ssize_t i = 0; i < b->n; i++) {
+      Py_ssize_t nch = b->off[i + 1] - b->off[i];
+      b->results[i] =
+          nch ? writev_chunks(b->fds[i], b->iov + b->off[i], nch) : 0;
+    }
+    b->busy_ns = sender_now_ns() - t0;
+    pthread_mutex_lock(&s->mu);
+    b->next = NULL;
+    if (s->d_tail) s->d_tail->next = b; else s->d_head = b;
+    s->d_tail = b;
+    s->done_id = b->id;
+    pthread_cond_broadcast(&s->done);
+    uint64_t one = 1; /* an eventfd adds it; a pipe takes the 8 bytes */
+    ssize_t r;
+    do {
+      r = write(s->wfd, &one, sizeof(one));
+    } while (r < 0 && errno == EINTR); /* EAGAIN: already readable */
+  }
+  pthread_mutex_unlock(&s->mu);
+  return NULL;
+}
+
+/* GIL held: a reaped (or abandoned) batch lets go of its buffers. */
+static void batch_free(zk_batch *b) {
+  for (Py_ssize_t j = 0; j < b->nbufs; j++) PyBuffer_Release(&b->bufs[j]);
+  free(b);
+}
+
+/* A sender with no thread (never started, or joined): its fds, its
+ * locks, itself. */
+static void sender_release(zk_sender *s) {
+  if (s->wfd != s->rfd) close(s->wfd);
+  close(s->rfd);
+  pthread_cond_destroy(&s->work);
+  pthread_cond_destroy(&s->done);
+  pthread_mutex_destroy(&s->mu);
+  free(s);
+}
+
+/* Stop the thread once its queue is empty, join it, free what was
+ * never reaped.  The GIL stays held (the capsule's destructor may run
+ * while the interpreter finalizes): the thread never wants it, and a
+ * caller that minds the wait has waited for its batches first. */
+static void sender_free(zk_sender *s) {
+  pthread_mutex_lock(&s->mu);
+  s->stop = 1;
+  pthread_cond_signal(&s->work);
+  pthread_mutex_unlock(&s->mu);
+  pthread_join(s->thread, NULL);
+  while (s->d_head) {
+    zk_batch *b = s->d_head;
+    s->d_head = b->next;
+    batch_free(b);
+  }
+  sender_release(s);
+}
+
+static void sender_capsule_destroy(PyObject *cap) {
+  zk_sender *s = PyCapsule_GetPointer(cap, "zkwire.sender");
+  if (s && s != &sender_closed) sender_free(s);
+}
+
+static zk_sender *sender_from_capsule(PyObject *cap) {
+  zk_sender *s = (zk_sender *)PyCapsule_GetPointer(cap, "zkwire.sender");
+  if (s == &sender_closed) {
+    PyErr_SetString(PyExc_ValueError, "sender already closed");
+    return NULL;
+  }
+  return s;
+}
+
+static PyObject *py_sender_create(PyObject *self, PyObject *noargs) {
+  zk_sender *s = calloc(1, sizeof(zk_sender));
+  if (!s) return PyErr_NoMemory();
+  s->next_id = 1;
+#ifdef __linux__
+  s->rfd = s->wfd = eventfd(0, EFD_NONBLOCK | EFD_CLOEXEC);
+  if (s->rfd < 0) {
+#else
+  {
+#endif
+    int p[2];
+    if (pipe(p) < 0) {
+      free(s);
+      return PyErr_SetFromErrno(PyExc_OSError);
+    }
+    for (int k = 0; k < 2; k++) {
+      fcntl(p[k], F_SETFL, fcntl(p[k], F_GETFL) | O_NONBLOCK);
+      fcntl(p[k], F_SETFD, FD_CLOEXEC);
+    }
+    s->rfd = p[0];
+    s->wfd = p[1];
+  }
+  pthread_mutex_init(&s->mu, NULL);
+  pthread_cond_init(&s->work, NULL);
+  pthread_cond_init(&s->done, NULL);
+  /* every signal stays with the interpreter's threads: the sender
+   * inherits a full mask */
+  sigset_t all, old;
+  sigfillset(&all);
+  pthread_sigmask(SIG_SETMASK, &all, &old);
+  int err = pthread_create(&s->thread, NULL, sender_main, s);
+  pthread_sigmask(SIG_SETMASK, &old, NULL);
+  PyObject *cap =
+      err ? NULL
+          : PyCapsule_New(s, "zkwire.sender", sender_capsule_destroy);
+  if (!cap) {
+    if (!err) {
+      sender_free(s);
+      return NULL;
+    }
+    sender_release(s);
+    errno = err;
+    return PyErr_SetFromErrno(PyExc_OSError);
+  }
+  return cap;
+}
+
+static PyObject *py_sender_fileno(PyObject *self, PyObject *args) {
+  PyObject *cap;
+  if (!PyArg_ParseTuple(args, "O", &cap)) return NULL;
+  zk_sender *s = sender_from_capsule(cap);
+  if (!s) return NULL;
+  return PyLong_FromLong(s->rfd);
+}
+
+static PyObject *py_sender_submit(PyObject *self, PyObject *args) {
+  PyObject *cap, *fds_obj, *cl_obj;
+  if (!PyArg_ParseTuple(args, "OOO", &cap, &fds_obj, &cl_obj)) return NULL;
+  zk_sender *s = sender_from_capsule(cap);
+  if (!s) return NULL;
+  PyObject *fast = PySequence_Fast(fds_obj, "fds must be a sequence");
+  if (!fast) return NULL;
+  PyObject *clfast =
+      PySequence_Fast(cl_obj, "chunklists must be a sequence");
+  if (!clfast) {
+    Py_DECREF(fast);
+    return NULL;
+  }
+  zk_batch *b = NULL;
+  Py_ssize_t n = PySequence_Fast_GET_SIZE(fast);
+  if (PySequence_Fast_GET_SIZE(clfast) != n) {
+    PyErr_SetString(PyExc_ValueError, "fds/chunklists length mismatch");
+    goto fail;
+  }
+  Py_ssize_t total = 0;
+  for (Py_ssize_t i = 0; i < n; i++) {
+    PyObject *chunks = PySequence_Fast_GET_ITEM(clfast, i);
+    if (!PyList_Check(chunks) && !PyTuple_Check(chunks)) {
+      PyErr_SetString(PyExc_TypeError,
+                      "a chunk list must be a list or a tuple");
+      goto fail;
+    }
+    total += PySequence_Fast_GET_SIZE(chunks);
+  }
+  /* one block: the batch, then its arrays, widest alignment first */
+  size_t sz = sizeof(zk_batch) + sizeof(Py_buffer) * (size_t)total +
+              sizeof(struct iovec) * (size_t)total +
+              sizeof(long long) * (size_t)n +
+              sizeof(Py_ssize_t) * (size_t)(n + 1) +
+              sizeof(int) * (size_t)n;
+  b = malloc(sz);
+  if (!b) {
+    PyErr_NoMemory();
+    goto fail;
+  }
+  memset(b, 0, sizeof(zk_batch));
+  b->bufs = (Py_buffer *)(b + 1);
+  b->iov = (struct iovec *)(b->bufs + total);
+  b->results = (long long *)(b->iov + total);
+  b->off = (Py_ssize_t *)(b->results + n);
+  b->fds = (int *)(b->off + n + 1);
+  b->n = n;
+  for (Py_ssize_t i = 0; i < n; i++) {
+    PyObject *chunks;
+    if (batch_entry(fast, clfast, i, &b->fds[i], &chunks) < 0) goto fail;
+    b->off[i] = b->nbufs;
+    Py_ssize_t nch = PySequence_Fast_GET_SIZE(chunks);
+    for (Py_ssize_t j = 0; j < nch; j++) {
+      Py_buffer *buf = &b->bufs[b->nbufs];
+      if (PyObject_GetBuffer(PySequence_Fast_GET_ITEM(chunks, j), buf,
+                             PyBUF_SIMPLE) < 0)
+        goto fail;
+      b->iov[b->nbufs].iov_base = buf->buf;
+      b->iov[b->nbufs].iov_len = (size_t)buf->len;
+      b->nbufs++;
+    }
+  }
+  b->off[n] = b->nbufs;
+  Py_DECREF(fast);
+  Py_DECREF(clfast);
+  pthread_mutex_lock(&s->mu);
+  unsigned long long id = b->id = s->next_id++;
+  if (s->q_tail) s->q_tail->next = b; else s->q_head = b;
+  s->q_tail = b;
+  pthread_cond_signal(&s->work);
+  pthread_mutex_unlock(&s->mu);
+  return PyLong_FromUnsignedLongLong(id);
+fail:
+  if (b) batch_free(b);
+  Py_DECREF(fast);
+  Py_DECREF(clfast);
+  return NULL;
+}
+
+static PyObject *py_sender_reap(PyObject *self, PyObject *args) {
+  PyObject *cap;
+  if (!PyArg_ParseTuple(args, "O", &cap)) return NULL;
+  zk_sender *s = sender_from_capsule(cap);
+  if (!s) return NULL;
+  /* the fd first: a batch that finishes after this read makes it
+   * readable again, one that finishes before the pop below is popped
+   * AND leaves one wake-up that finds nothing */
+  uint64_t sink[64];
+  while (read(s->rfd, sink, sizeof(sink)) == (ssize_t)sizeof(sink)) {
+  }
+  pthread_mutex_lock(&s->mu);
+  zk_batch *b = s->d_head;
+  s->d_head = s->d_tail = NULL;
+  pthread_mutex_unlock(&s->mu);
+  PyObject *out = PyList_New(0);
+  while (b) {
+    zk_batch *next = b->next;
+    PyObject *results = out ? PyList_New(b->n) : NULL;
+    for (Py_ssize_t i = 0; results && i < b->n; i++) {
+      PyObject *val = PyLong_FromLongLong(b->results[i]);
+      if (!val) {
+        Py_CLEAR(results);
+        break;
+      }
+      PyList_SET_ITEM(results, i, val);
+    }
+    PyObject *item =
+        results ? Py_BuildValue("(KNL)", b->id, results, b->busy_ns)
+                : NULL;
+    if (!item || PyList_Append(out, item) < 0) Py_CLEAR(out);
+    Py_XDECREF(item);
+    batch_free(b);
+    b = next;
+  }
+  return out; /* NULL with the error set: the batches are gone */
+}
+
+static PyObject *py_sender_wait(PyObject *self, PyObject *args) {
+  PyObject *cap;
+  unsigned long long id;
+  if (!PyArg_ParseTuple(args, "OK", &cap, &id)) return NULL;
+  zk_sender *s = sender_from_capsule(cap);
+  if (!s) return NULL;
+  int known;
+  Py_BEGIN_ALLOW_THREADS
+  pthread_mutex_lock(&s->mu);
+  known = id < s->next_id;
+  while (known && s->done_id < id) pthread_cond_wait(&s->done, &s->mu);
+  pthread_mutex_unlock(&s->mu);
+  Py_END_ALLOW_THREADS
+  if (!known) {
+    PyErr_SetString(PyExc_ValueError, "no such batch");
+    return NULL;
+  }
+  Py_RETURN_NONE;
+}
+
+static PyObject *py_sender_close(PyObject *self, PyObject *args) {
+  PyObject *cap;
+  if (!PyArg_ParseTuple(args, "O", &cap)) return NULL;
+  zk_sender *s = (zk_sender *)PyCapsule_GetPointer(cap, "zkwire.sender");
+  if (!s) return NULL;
+  if (s != &sender_closed) {
+    if (PyCapsule_SetPointer(cap, &sender_closed) < 0) return NULL;
+    sender_free(s);
+  }
+  Py_RETURN_NONE;
 }
 
 /* ---- batched receive drain (io/ingress.py) --------------------------
@@ -2298,6 +2652,22 @@ static PyMethodDef methods[] = {
     {"submit_writev", py_submit_writev, METH_VARARGS,
      "submit_writev(fds, chunklists) -> [written|-errno, ...] — one "
      "vectored write per entry, join-free (parallel arrays)"},
+    {"sender_create", py_sender_create, METH_NOARGS,
+     "sender_create() -> capsule — one native thread that sends "
+     "batches without the GIL"},
+    {"sender_fileno", py_sender_fileno, METH_VARARGS,
+     "sender_fileno(sender) -> fd, readable once a batch is done"},
+    {"sender_submit", py_sender_submit, METH_VARARGS,
+     "sender_submit(sender, fds, chunklists) -> batch_id — queue one "
+     "batch for the thread and return at once"},
+    {"sender_reap", py_sender_reap, METH_VARARGS,
+     "sender_reap(sender) -> [(batch_id, [written|-errno, ...], "
+     "busy_ns), ...] — every finished batch, oldest first"},
+    {"sender_wait", py_sender_wait, METH_VARARGS,
+     "sender_wait(sender, batch_id) — block (GIL released) until that "
+     "batch is done"},
+    {"sender_close", py_sender_close, METH_VARARGS,
+     "sender_close(sender) — send what is queued, join the thread"},
     {"uring_create", py_uring_create, METH_VARARGS,
      "uring_create(depth=256) -> capsule (OSError when io_uring is "
      "unavailable)"},

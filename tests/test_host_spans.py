@@ -215,6 +215,66 @@ async def test_a_fleets_burst_is_one_client_flush(server, armed):
             await c.close()
 
 
+async def test_a_deep_burst_is_one_handoff_one_reap_and_its_sends(
+        server, armed):
+    """The hand-over's books: a burst from N >= OFFLOAD_MIN_SENDS
+    sessions of one loop on ``mmsg`` is ONE ``client.handoff`` and (at
+    least) one ``client.reap``, and ``client.send`` totals N
+    connections with the sender thread's own busy nanoseconds; a
+    shallow burst is sent inline and totals its connections under
+    ``client.send`` on the loop's clock, with no hand-over."""
+    from zkstream_tpu.io.transport import OFFLOAD_MIN_SENDS, probe
+    from zkstream_tpu.utils.native import ensure_ext
+    if not probe().mmsg or not hasattr(ensure_ext(), 'sender_submit'):
+        pytest.skip('no native sender here')
+    n = OFFLOAD_MIN_SENDS + 2
+    clients = []
+    try:
+        for _ in range(n):
+            c = Client(address='127.0.0.1', port=server.port,
+                       transport='mmsg', session_timeout=30000,
+                       max_spares=0)
+            c.start()
+            await c.wait_connected(timeout=5)
+            clients.append(c)
+        await asyncio.sleep(0.05)
+        tier = clients[0].transport_tier
+        trace.host_ring.reset()
+        assert len(await asyncio.gather(
+            *[c.list('/') for c in clients])) == n
+        totals = trace.host_ring.totals
+        assert totals['client.flush'][0] == 1
+        assert totals['client.handoff'][0] == 1 == tier.offloaded_batches
+        assert totals['client.reap'][0] >= 1
+        assert totals['client.send'][0] == n
+        assert 0 < totals['client.send'][1]
+        assert 0 < totals['client.handoff'][1] < totals['client.flush'][1]
+        assert len(trace.host_ring) == 0        # counts and totals only
+        trace.host_ring.reset()
+        few = clients[:3]
+        assert len(await asyncio.gather(*[c.list('/') for c in few])) == 3
+        totals = trace.host_ring.totals
+        assert totals['client.send'][0] == 3 and totals['client.send'][1] > 0
+        assert 'client.handoff' not in totals
+        assert tier.offloaded_batches == 1
+    finally:
+        for c in clients:
+            await c.close()
+
+
+def test_host_add_is_armed_by_the_session_alone(monkeypatch):
+    """``host_add`` books work counted and timed elsewhere (a native
+    thread's batch) under a name's totals inside a profiler session,
+    and nothing outside one."""
+    trace.host_add('client.send', 5, 1000)
+    assert trace.host_ring.totals == {}
+    monkeypatch.setattr(trace, '_is_enabled', lambda: True)
+    trace.host_add('client.send', 5, 1000)
+    trace.host_add('client.send', 2, 500)
+    assert trace.host_ring.totals == {'client.send': [7, 1500]}
+    assert len(trace.host_ring) == 0
+
+
 async def test_a_loops_requests_share_its_deadline_timer(server, armed):
     """The deadline queue's engagement counter: ``client.deadline`` is
     an arming or a firing of the loop's ONE timer, so ``client.submit``'s

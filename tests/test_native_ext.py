@@ -652,3 +652,215 @@ def test_decode_streams_validates_before_decoding():
         ext.decode_streams([bytearray(wire)], [len(wire), 1], [xmap],
                            1 << 24)
     assert xmap == xid_map_for(ALL_REPLIES[:2])
+
+
+# -- the native sender thread (io/transport.py's hand-over) ------------
+
+def _sender_pairs(n):
+    import socket
+    pairs = [socket.socketpair() for _ in range(n)]
+    for _a, b in pairs:
+        b.settimeout(5)
+    return pairs
+
+
+def _recv_exact(sock, n: int) -> bytes:
+    data = b''
+    while len(data) < n:
+        chunk = sock.recv(n - len(data))
+        assert chunk, 'peer closed at %d/%d' % (len(data), n)
+        data += chunk
+    return data
+
+
+def test_sender_sends_batches_in_order_with_results_by_entry():
+    """Batches leave in submission order, an entry's chunks joined on
+    the wire; ``sender_reap`` gives, oldest first, each batch's id, a
+    result an entry (bytes written, or -errno) and the thread's busy
+    time; the wake-up fd is readable exactly while something is done
+    and unreaped."""
+    import errno
+    import select
+    ext = native.ensure_ext()
+    pairs = _sender_pairs(3)
+    sender = ext.sender_create()
+    try:
+        wake = ext.sender_fileno(sender)
+        assert select.select([wake], [], [], 0)[0] == []
+        fds = [a.fileno() for a, _b in pairs]
+        first = ext.sender_submit(
+            sender, fds, [[b'a0-', b'a1'], (b'b0',), []])
+        ext.sender_wait(sender, first)
+        # a dead peer in the middle of the next batch
+        pairs[1][1].close()
+        second = ext.sender_submit(
+            sender, fds, [[b'-a2'], [b'lost'], [memoryview(b'c0')]])
+        assert (first, second) == (1, 2)
+        ext.sender_wait(sender, second)
+        assert select.select([wake], [], [], 5)[0] == [wake]
+        done = ext.sender_reap(sender)
+        assert [(b, r) for b, r, _ns in done] == [
+            (1, [5, 2, 0]), (2, [3, -errno.EPIPE, 2])]
+        assert all(ns > 0 for _b, _r, ns in done)
+        assert select.select([wake], [], [], 0)[0] == []
+        assert ext.sender_reap(sender) == []
+        assert _recv_exact(pairs[0][1], 8) == b'a0-a1-a2'
+        assert _recv_exact(pairs[2][1], 2) == b'c0'
+    finally:
+        ext.sender_close(sender)
+        for a, b in pairs:
+            a.close()
+            b.close()
+
+
+def test_sender_wait_blocks_until_the_batch_is_done():
+    """``sender_wait`` returns only once the batch is sent (here: held
+    inside a blocking send until another thread drains the peer), with
+    the GIL released meanwhile; an id never handed out is refused."""
+    import socket
+    import threading
+    ext = native.ensure_ext()
+    a, b = socket.socketpair()              # blocking
+    a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+    size = 4 << 20
+    sender = ext.sender_create()
+    got = []
+
+    def drain():                            # needs the GIL to run
+        b.settimeout(5)
+        got.append(len(_recv_exact(b, size)))
+    try:
+        batch = ext.sender_submit(sender, [a.fileno()], [[bytes(size)]])
+        tail = ext.sender_submit(sender, [a.fileno()], [[b'tail']])
+        assert ext.sender_reap(sender) == []        # still inside send
+        reader = threading.Thread(target=drain)
+        reader.start()
+        ext.sender_wait(sender, batch)
+        reader.join(10)
+        assert got == [size]
+        ext.sender_wait(sender, tail)
+        assert [(i, r) for i, r, _ns in ext.sender_reap(sender)] == [
+            (batch, [size]), (tail, [4])]
+        assert _recv_exact(b, 4) == b'tail'
+        with pytest.raises(ValueError):
+            ext.sender_wait(sender, tail + 1)
+    finally:
+        ext.sender_close(sender)
+        a.close()
+        b.close()
+
+
+def test_sender_close_sends_what_is_queued_then_refuses():
+    """``sender_close`` joins the thread after it sent every queued
+    batch; the chunks' buffers are released (a bytearray can be
+    resized again); a closed sender refuses every call and a second
+    close is a no-op."""
+    ext = native.ensure_ext()
+    pairs = _sender_pairs(2)
+    sender = ext.sender_create()
+    held = bytearray(b'resizable')
+    try:
+        fds = [a.fileno() for a, _b in pairs]
+        for i in range(50):
+            ext.sender_submit(sender, fds, [[b'%02d' % i], [held]])
+        with pytest.raises(BufferError):
+            held.extend(b'!')           # exported while in flight
+        ext.sender_close(sender)
+        held.extend(b'!')
+        assert _recv_exact(pairs[0][1], 100) == b''.join(
+            b'%02d' % i for i in range(50))
+        assert _recv_exact(pairs[1][1], 450) == b'resizable' * 50
+        for call, args in ((ext.sender_submit, (fds, [[b'x'], [b'y']])),
+                           (ext.sender_reap, ()),
+                           (ext.sender_wait, (1,)),
+                           (ext.sender_fileno, ())):
+            with pytest.raises(ValueError):
+                call(sender, *args)
+        ext.sender_close(sender)
+    finally:
+        for a, b in pairs:
+            a.close()
+            b.close()
+
+
+def test_sender_submit_refuses_a_malformed_batch_whole():
+    """A bad argument fails the call before anything is queued, and
+    holds no buffer."""
+    ext = native.ensure_ext()
+    pairs = _sender_pairs(1)
+    sender = ext.sender_create()
+    held = bytearray(b'abc')
+    try:
+        fd = pairs[0][0].fileno()
+        with pytest.raises(ValueError):
+            ext.sender_submit(sender, [fd, fd], [[b'x']])
+        with pytest.raises(TypeError):
+            ext.sender_submit(sender, [fd], [b'not-a-list'])
+        with pytest.raises(TypeError):
+            ext.sender_submit(sender, [fd, fd], [[held], [held, 7]])
+        held.extend(b'd')               # nothing kept exported
+        assert ext.sender_submit(sender, [fd], [[b'ok']]) == 1
+        ext.sender_wait(sender, 1)
+        assert _recv_exact(pairs[0][1], 2) == b'ok'
+    finally:
+        ext.sender_close(sender)
+        for a, b in pairs:
+            a.close()
+            b.close()
+
+
+def test_sender_stress_submit_reap_and_wait_race():
+    """More threads than the API needs, a shortened switch interval:
+    one thread submits 2,000 batches, one reaps, one drains the peers,
+    and the submitter waits on every 97th — every batch is reaped
+    exactly once, in order, and every connection's bytes arrive in
+    submission order (a lost wake-up would hang the wait, a lost
+    update would drop or repeat a batch)."""
+    import sys
+    import threading
+    ext = native.ensure_ext()
+    pairs = _sender_pairs(4)
+    sender = ext.sender_create()
+    n = 2000
+    reaped, got = [], [bytearray() for _ in pairs]
+    stop = threading.Event()
+
+    def reap():
+        while not stop.is_set() or len(reaped) < n:
+            reaped.extend(ext.sender_reap(sender))
+            if len(reaped) >= n:
+                return
+
+    def drain(i):
+        sock = pairs[i][1]
+        while len(got[i]) < 6 * n:
+            got[i] += sock.recv(1 << 16)
+    threads = [threading.Thread(target=reap)] + [
+        threading.Thread(target=drain, args=(i,)) for i in range(4)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        fds = [a.fileno() for a, _b in pairs]
+        for k in range(n):
+            batch = ext.sender_submit(
+                sender, fds, [[b'%05d' % k, b';']] * 4)
+            assert batch == k + 1
+            if k % 97 == 0:
+                ext.sender_wait(sender, batch)
+        stop.set()
+        for t in threads:
+            t.join(30)
+        assert not any(t.is_alive() for t in threads)
+        assert [b for b, _r, _ns in reaped] == list(range(1, n + 1))
+        assert all(r == [6] * 4 for _b, r, _ns in reaped)
+        want = b''.join(b'%05d;' % k for k in range(n))
+        assert all(bytes(g) == want for g in got)
+    finally:
+        sys.setswitchinterval(old)
+        stop.set()
+        ext.sender_close(sender)
+        for a, b in pairs:
+            a.close()
+            b.close()

@@ -16,15 +16,20 @@ sockets, and the ``zk_transport_backend`` mntr row."""
 from __future__ import annotations
 
 import asyncio
+import errno
+import os
 import socket
+import threading
 
 import pytest
 
 from zkstream_tpu.io.sendplane import SendPlane
 from zkstream_tpu.io.transport import (
     BACKENDS,
+    METRIC_FLUSH_OFFLOADED,
     METRIC_FLUSH_SYSCALLS,
     METRIC_SUBMIT_DEPTH,
+    OFFLOAD_MIN_SENDS,
     TransportTier,
     backend_default,
     make_tier,
@@ -51,6 +56,21 @@ needs_batched = pytest.mark.skipif(
 needs_uring = pytest.mark.skipif(
     not probe().uring,
     reason='io_uring unavailable: %s' % (probe().uring_reason,))
+
+
+def _has_sender() -> bool:
+    from zkstream_tpu.utils.native import ensure_ext
+    return probe().mmsg and hasattr(ensure_ext(), 'sender_submit')
+
+
+needs_sender = pytest.mark.skipif(
+    not _has_sender(), reason='no native sender: the mmsg backend or '
+    'the extension is missing')
+
+#: How a raw batch leaves: sent inline on the loop's thread (any
+#: batched backend), or handed to the tier's native sender thread
+#: (mmsg, the loop's shared client tier, OFFLOAD_MIN_SENDS deep).
+SUBMISSIONS = ['inline'] + (['handed_over'] if _has_sender() else [])
 
 
 # -- a real transport over a socketpair --------------------------------
@@ -276,11 +296,12 @@ async def test_stranded_tick_callback_recovers_on_next_loop():
 
 
 @needs_batched
-async def test_partial_write_falls_back_in_order():
+@pytest.mark.parametrize('submission', SUBMISSIONS)
+async def test_partial_write_falls_back_in_order(submission):
     """A raw write that fills the kernel buffer hands the REMAINDER to
     the asyncio transport, and later ticks queue behind it — the
-    stream survives backpressure byte-identical."""
-    backend = BATCHED[0]
+    stream survives backpressure byte-identical, whether the loop's
+    thread or the sender's made the write."""
     left, right = socket.socketpair()
     left.setblocking(False)
     right.setblocking(False)
@@ -288,23 +309,53 @@ async def test_partial_write_falls_back_in_order():
     loop = asyncio.get_running_loop()
     transport, _ = await loop.create_connection(asyncio.Protocol,
                                                 sock=left)
+    rig = await _Rig(submission).start()
     try:
-        tier = TransportTier(backend)
+        tier = rig.tier
         plane = SendPlane(transport.write, enabled=True, tier=tier,
                           transport_fn=lambda: transport)
-        import os as _os
-        payload = _os.urandom(400000)    # >> SO_SNDBUF and the cap
+        payload = os.urandom(400000)     # >> SO_SNDBUF and the cap
         plane.send(payload)              # cap hit: immediate flush
+        rig.fill()
         await asyncio.sleep(0)
         plane.send(b'TAIL')              # must queue BEHIND the spill
         reader = asyncio.ensure_future(
             _read_exact(right, len(payload) + 4, timeout=10))
         got = await reader
         assert got == payload + b'TAIL'
+        assert tier.partial_flushes == 1
+        assert tier.requeued_bytes > 0
+        rig.check(batches=1)
     finally:
         transport.close()
         right.close()
+        await rig.stop()
 
+
+@needs_batched
+@pytest.mark.parametrize('submission', SUBMISSIONS)
+async def test_dead_socket_inside_a_batch_drops_only_its_bytes(
+        submission):
+    """``EPIPE`` on one connection of a batch (its peer is gone): that
+    entry's bytes are dropped as an aborted transport's would be — no
+    resend through the sink — and every other connection of the batch
+    gets its bytes, whichever thread sent."""
+    rig = await _Rig(submission, width=max(OFFLOAD_MIN_SENDS, 4)).start()
+    try:
+        dead_t, dead_peer = rig.pipes[0]
+        dead_peer.close()
+        sunk = []
+        rig.planes[0]._entry.write = sunk.append
+        for i, plane in enumerate(rig.planes):
+            plane.send(b'frame-%03d' % i)
+        await rig.settle()
+        assert sunk == []
+        assert rig.tier.partial_flushes == 0
+        for i, (_t, peer) in enumerate(rig.pipes[1:], 1):
+            assert await _read_exact(peer, 9) == b'frame-%03d' % i
+        rig.check(batches=1)
+    finally:
+        await rig.stop()
 
 @needs_batched
 async def test_iov_guard_coalesces_pathological_chunk_counts():
@@ -354,6 +405,420 @@ async def test_uring_ring_roundtrip():
         for a, b in pairs:
             a.close()
             b.close()
+
+
+# -- the native sender: hand over, reap, and every rule ----------------
+
+class _Latch:
+    """Holds a tier's sender thread inside one blocking ``send(2)`` (a
+    batch of its own, queued ahead of the tier's) until released:
+    everything the tier hands over meanwhile is in flight, untouched."""
+
+    def __init__(self, tier: TransportTier):
+        from zkstream_tpu.utils.native import ensure_ext
+        self.ext = ensure_ext()
+        if tier._sender is None:
+            tier._sender, tier._ext = self.ext.sender_create(), self.ext
+        self.sender = tier._sender
+        self.a, self.b = socket.socketpair()    # blocking
+        self.a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+        self.batch = self.ext.sender_submit(
+            self.sender, [self.a.fileno()], [[bytes(8 << 20)]])
+        tier._inflight[self.batch] = []     # the tier reaps it: no entry
+
+    def release(self) -> None:
+        self.b.close()          # the blocked send ends in EPIPE
+
+    def release_in(self, seconds: float) -> threading.Timer:
+        """From another thread: for a loop thread that is about to
+        block on the batch behind the latch."""
+        timer = threading.Timer(seconds, self.release)
+        timer.start()
+        return timer
+
+    def close(self) -> None:
+        self.release()
+        self.ext.sender_wait(self.sender, self.batch)
+        self.a.close()
+
+
+class _Rig:
+    """``width`` socketpair connections on one tier that sends as
+    ``submission`` says: ``inline`` is any batched backend's tier as a
+    server builds it; ``handed_over`` is the loop's shared client tier
+    (mmsg, ``attach_sender``), whose batches of OFFLOAD_MIN_SENDS
+    connections go to the sender thread.  ``fill`` dirties the
+    connections that are only there to make the batch deep."""
+
+    def __init__(self, submission: str, width: int = OFFLOAD_MIN_SENDS):
+        self.handed = submission == 'handed_over'
+        self.tier = TransportTier('mmsg' if self.handed else BATCHED[0],
+                                  plane='client')
+        if self.handed:
+            self.tier.attach_sender()
+        self.width = width
+        self.pipes: list = []
+        self.planes: list[SendPlane] = []
+
+    async def start(self) -> '_Rig':
+        self.pipes = [await _pipe() for _ in range(self.width)]
+        self.planes = [SendPlane(t.write, enabled=True, tier=self.tier,
+                                 transport_fn=lambda t=t: t)
+                       for t, _peer in self.pipes]
+        return self
+
+    def fill(self, skip: int = 0) -> None:
+        for plane in self.planes[skip:]:
+            plane.send(b'fill')
+
+    async def settle(self) -> None:
+        """Until nothing is in flight and the tick has run."""
+        for _ in range(500):
+            await asyncio.sleep(0)
+            if not self.tier._inflight and not self.tier._dirty:
+                return
+            await asyncio.sleep(0.002)
+        raise AssertionError('a batch was never reaped')
+
+    def check(self, batches: int) -> None:
+        assert self.tier.offloaded_batches == (batches if self.handed
+                                               else 0)
+
+    async def stop(self) -> None:
+        self.tier.close()
+        for t, peer in self.pipes:
+            t.close()
+            peer.close()
+        await asyncio.sleep(0)      # the transports' own teardown
+
+
+@pytest.mark.parametrize('backend', BATCHED)
+async def test_deep_fleet_burst_is_one_handed_over_batch(backend):
+    """One request from each of N >= OFFLOAD_MIN_SENDS clients in one
+    loop iteration: ONE submission of depth N — on mmsg handed to the
+    sender thread as ONE batch and reaped, on uring one enter as ever —
+    every connection's bytes intact and in order, the depth and the
+    offloaded flushes in every client's collector."""
+    n = OFFLOAD_MIN_SENDS + 3
+    srv = await ZKServer().start()
+    tap = await _Tap(srv.port).start()
+    clients = await _fleet(tap.port, n, backend)
+    try:
+        tier = clients[0].transport_tier
+        handed = backend == 'mmsg' and _has_sender()
+        subs0, sys0 = tier.submissions, tier.syscalls
+        depth0 = _depth(clients[-1], backend)
+        futs = [_send(c, '/a%d' % i) for i, c in enumerate(clients)]
+        futs.append(_send(clients[2], '/second'))   # 2 frames, 1 entry
+        replies = await asyncio.gather(*futs, return_exceptions=True)
+        assert all(getattr(r, 'code', None) == 'NO_NODE'
+                   for r in replies), replies
+        assert tier.submissions == subs0 + 1
+        assert tier.syscalls == sys0 + (1 if backend == 'uring' else n)
+        assert tier.offloaded_batches == (1 if handed else 0)
+        assert tier.offloaded_flushes == (n if handed else 0)
+        assert (tier._sender is not None) == handed
+        assert not tier._inflight
+        for c in clients:
+            count, total = _depth(c, backend)
+            assert (count, total) == (depth0[0] + 1, depth0[1] + n)
+            ctr = c.collector.get_collector(METRIC_FLUSH_OFFLOADED)
+            assert ctr.value({'plane': 'client'}) == tier.offloaded_flushes
+        for i, stream in enumerate(tap.streams):
+            _assert_intact(stream, ['/a%d' % i] + ['/second'] * (i == 2))
+    finally:
+        for c in clients:
+            await c.close()
+        await tap.stop()
+        await srv.stop()
+    assert tier._sender is None and tier.refs == 0
+
+
+@needs_sender
+async def test_shallow_burst_never_touches_the_sender():
+    """Below OFFLOAD_MIN_SENDS the batch is sent inline: no thread is
+    ever started for it."""
+    rig = await _Rig('handed_over', width=OFFLOAD_MIN_SENDS - 1).start()
+    try:
+        for _round in range(3):
+            for i, plane in enumerate(rig.planes):
+                plane.send(b'f%03d' % i)
+            await rig.settle()
+        assert rig.tier.submissions == 3
+        assert rig.tier.offloaded_batches == 0
+        assert rig.tier._sender is None
+        for i, (_t, peer) in enumerate(rig.pipes):
+            assert await _read_exact(peer, 12) == b'f%03d' % i * 3
+    finally:
+        await rig.stop()
+
+
+@needs_sender
+async def test_a_server_tier_never_hands_over():
+    """``make_tier`` (a member's tier) has no sender whatever the
+    depth: its submission stays inside its tick ledger."""
+    tier = make_tier('mmsg', plane='server')
+    pipes = [await _pipe() for _ in range(OFFLOAD_MIN_SENDS + 1)]
+    try:
+        planes = [SendPlane(t.write, enabled=True, tier=tier,
+                            transport_fn=lambda t=t: t)
+                  for t, _ in pipes]
+        for plane in planes:
+            plane.send(b'reply')
+        for _ in range(3):
+            await asyncio.sleep(0)
+        assert tier.submissions == 1 and tier.offloaded_batches == 0
+        assert tier._sender is None
+        for _t, peer in pipes:
+            assert await _read_exact(peer, 5) == b'reply'
+    finally:
+        for t, peer in pipes:
+            t.close()
+            peer.close()
+
+
+@needs_sender
+@pytest.mark.parametrize('second', ['raw', 'sink'])
+async def test_second_flush_waits_for_the_batch_in_flight(second):
+    """Order on a connection: while its batch is in flight (the sender
+    held) a connection's next flush is not submitted — neither raw nor
+    through its asyncio sink, which would write at once and overtake —
+    and leaves, behind the first, once the batch is reaped."""
+    rig = await _Rig('handed_over').start()
+    latch = _Latch(rig.tier)
+    try:
+        plane, (transport, peer) = rig.planes[0], rig.pipes[0]
+        plane.send(b'first-')
+        rig.fill(skip=1)
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+        assert rig.tier.offloaded_batches == 1 and rig.tier._inflight
+        if second == 'sink':
+            # raw-ineligible from now on: the tier must take the sink
+            plane._entry.transport_fn = lambda: None
+        plane.send(b'second')
+        for _ in range(5):
+            await asyncio.sleep(0.002)
+        # held: still with its entry, nothing written anywhere
+        assert plane._entry.nbytes == 6 and plane._entry.batch
+        assert transport.get_write_buffer_size() == 0
+        with pytest.raises(BlockingIOError):
+            peer.recv(64)
+        latch.release()
+        await rig.settle()
+        assert await _read_exact(peer, 12) == b'first-second'
+        assert rig.tier.offloaded_batches == 1
+        assert rig.tier.submissions == (2 if second == 'raw' else 1)
+    finally:
+        latch.close()
+        await rig.stop()
+
+
+@needs_sender
+async def test_flush_hard_with_a_batch_in_flight():
+    """Bytes on the wire before ``flush_hard`` returns: it waits for
+    the connection's batch in flight, then submits what is pending
+    inline — a direct write issued right after cannot overtake."""
+    rig = await _Rig('handed_over').start()
+    latch = _Latch(rig.tier)
+    try:
+        plane, (transport, peer) = rig.planes[0], rig.pipes[0]
+        plane.send(b'first-')
+        rig.fill(skip=1)
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+        assert plane._entry.batch
+        plane.send(b'corked-')
+        timer = latch.release_in(0.05)
+        plane.flush_hard()              # blocks until the batch is out
+        assert not plane._entry.batch and not rig.tier._inflight
+        transport.write(b'injected')
+        assert await _read_exact(peer, 21) == b'first-corked-injected'
+        timer.join()
+        # every other connection of the batch was settled by that reap
+        for _t, other in rig.pipes[1:]:
+            assert await _read_exact(other, 4) == b'fill'
+    finally:
+        latch.close()
+        await rig.stop()
+
+
+@needs_sender
+async def test_buffered_bytes_holds_what_is_in_flight():
+    """The overload plane's tx account: bytes handed to the sender
+    stay in ``buffered_bytes()`` until the batch is reaped."""
+    rig = await _Rig('handed_over').start()
+    latch = _Latch(rig.tier)
+    try:
+        plane = rig.planes[0]
+        plane.send(b'x' * 700)
+        rig.fill(skip=1)
+        assert plane.buffered_bytes() == 700        # corked
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+        assert plane.pending == 0 and plane._entry.nbytes == 0
+        assert plane.buffered_bytes() == 700        # in flight
+        plane.send(b'y' * 50)
+        await asyncio.sleep(0.005)
+        assert plane.buffered_bytes() == 750        # + held behind it
+        latch.release()
+        await rig.settle()
+        assert plane.buffered_bytes() == 0
+        assert len(await _read_exact(rig.pipes[0][1], 750)) == 750
+    finally:
+        latch.close()
+        await rig.stop()
+
+
+@needs_sender
+async def test_reset_in_flight_never_reaches_the_fds_next_owner():
+    """The hazard the hand-over is built around: a connection is reset
+    while its request waits in a batch in flight; asyncio closes the
+    socket right after, and the next connection dialed is given the
+    SAME fd number.  The reset waits for the batch, so the request
+    went out on its own connection: not one stale byte on the new
+    one."""
+    n = OFFLOAD_MIN_SENDS
+    srv = await ZKServer().start()
+    tap = await _Tap(srv.port).start()
+    clients = await _fleet(tap.port, n, 'mmsg')
+    tier = clients[0].transport_tier
+    latch = _Latch(tier)
+    try:
+        futs = [_send(c, '/stale%d' % i) for i, c in enumerate(clients)]
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+        assert tier.offloaded_batches == 1 and tier._inflight
+        conn = clients[0]._conn_or_raise()
+        old_fd = conn.transport.get_extra_info('socket').fileno()
+        timer = latch.release_in(0.1)
+        conn.destroy()                  # blocks until the batch is out
+        assert not tier._inflight
+        timer.join()
+        # the client re-dials on its own; the new socket takes the
+        # lowest free number: the one just closed
+        for _ in range(500):
+            await asyncio.sleep(0.01)
+            if len(tap.streams) > n and clients[0].is_connected():
+                break
+        new = clients[0]._conn_or_raise()
+        assert new is not conn
+        assert new.transport.get_extra_info('socket').fileno() == old_fd
+        await asyncio.gather(*futs, return_exceptions=True)
+        after = await asyncio.gather(_send(clients[0], '/after'),
+                                     return_exceptions=True)
+        assert getattr(after[0], 'code', None) == 'NO_NODE'
+        # the old connection carried its request, whole, exactly once;
+        # the new one starts with its ConnectRequest and holds
+        # nothing of the old session's
+        _assert_intact(tap.streams[0], ['/stale0'])
+        reqs = _assert_intact(tap.streams[n], ['/after'])
+        assert '/stale0' not in [p.get('path') for p in reqs]
+    finally:
+        latch.close()
+        for c in clients:
+            await c.close()
+        await tap.stop()
+        await srv.stop()
+
+
+@needs_sender
+async def test_close_with_a_batch_in_flight_reaps_and_joins():
+    """``tier.close()`` (the last lease released): what is in flight
+    goes out and is settled, the thread is joined, and a later deep
+    batch starts a sender anew."""
+    rig = await _Rig('handed_over').start()
+    latch = _Latch(rig.tier)
+    try:
+        rig.fill()
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+        assert rig.tier._inflight
+        timer = latch.release_in(0.05)
+        first = rig.tier._sender
+        rig.tier.close()
+        timer.join()
+        assert rig.tier._sender is None and not rig.tier._inflight
+        assert all(p._entry.batch == 0 for p in rig.planes)
+        with pytest.raises(ValueError):
+            latch.ext.sender_reap(first)        # closed, not leaked
+        rig.fill()
+        await rig.settle()
+        assert rig.tier.offloaded_batches == 2
+        assert rig.tier._sender is not None
+        for _t, peer in rig.pipes:
+            assert await _read_exact(peer, 8) == b'fillfill'
+    finally:
+        latch.a.close()
+        await rig.stop()
+
+
+@needs_sender
+def test_sender_tier_across_two_runs_moves_its_reader():
+    """One ``asyncio.run`` after another on one tier: the sender's
+    ``eventfd`` is a reader of the loop that hands batches over, and a
+    batch the first loop left unreaped is settled on the second."""
+    tier = TransportTier('mmsg', plane='client')
+    tier.attach_sender()
+    seen = {}
+
+    async def one_run(tag: bytes, reap: bool):
+        pipes = [await _pipe() for _ in range(OFFLOAD_MIN_SENDS)]
+        planes = [SendPlane(t.write, enabled=True, tier=tier,
+                            transport_fn=lambda t=t: t)
+                  for t, _ in pipes]
+        for plane in planes:
+            plane.send(tag)
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+        for _t, peer in pipes:
+            assert await _read_exact(peer, len(tag)) == tag
+        if reap:
+            for _ in range(200):
+                if not tier._inflight:
+                    break
+                await asyncio.sleep(0.005)
+        seen[tag] = (asyncio.get_running_loop(), tier._reader_loop,
+                     dict(tier._inflight))
+        for t, peer in pipes:
+            t.close()
+            peer.close()
+        await asyncio.sleep(0)
+    try:
+        # the reader callback is the only reap here: stop the loop
+        # before it can run, with the batch done but not settled
+        tier._reap, real = (lambda: None), tier._reap
+        asyncio.run(one_run(b'run-1', reap=False))
+        tier._reap = real
+        loop1, reader1, left = seen[b'run-1']
+        assert reader1 is loop1 and len(left) == 1
+        asyncio.run(one_run(b'run-2', reap=True))
+        loop2, reader2, left = seen[b'run-2']
+        assert loop2 is not loop1 and reader2 is loop2 and not left
+        assert tier.offloaded_batches == 2
+    finally:
+        tier.close()
+
+
+@needs_sender
+async def test_without_the_extension_a_deep_batch_goes_inline(
+        monkeypatch):
+    """No extension (or one that predates the sender): the inline
+    submission, as before."""
+    from zkstream_tpu.io import transport as tmod
+    monkeypatch.setattr(tmod, '_sender_ext', lambda: None)
+    rig = await _Rig('handed_over').start()
+    try:
+        for i, plane in enumerate(rig.planes):
+            plane.send(b'f%03d' % i)
+        await rig.settle()
+        assert rig.tier.submissions == 1
+        assert rig.tier.offloaded_batches == 0
+        assert rig.tier._sender is None
+        for i, (_t, peer) in enumerate(rig.pipes):
+            assert await _read_exact(peer, 4) == b'f%03d' % i
+    finally:
+        await rig.stop()
 
 
 # -- e2e over real sockets: parity + accounting + mntr -----------------
@@ -843,6 +1308,29 @@ async def test_chaos_slice_transport_batched(monkeypatch):
     for seed in range(3100, 3106):
         res = await run_schedule(seed)
         assert res.ok, (seed, res.violations)
+
+
+@needs_sender
+async def test_chaos_slice_every_batch_handed_over(monkeypatch):
+    """The same seeds with EVERY raw batch of the clients' tier handed
+    to the sender thread (the hand-over depth patched to 1): byte
+    faults, injected resets and hard flushes meet batches in flight at
+    every step, and the invariants hold."""
+    from zkstream_tpu.io import transport as tmod
+    from zkstream_tpu.io.faults import run_schedule
+    monkeypatch.setenv('ZKSTREAM_TRANSPORT', 'mmsg')
+    monkeypatch.setattr(tmod, 'OFFLOAD_MIN_SENDS', 1)
+    handed = []
+    real = tmod.TransportTier._hand_over
+
+    def counted(self, *args):
+        handed.append(real(self, *args))
+        return handed[-1]
+    monkeypatch.setattr(tmod.TransportTier, '_hand_over', counted)
+    for seed in range(3100, 3106):
+        res = await run_schedule(seed)
+        assert res.ok, (seed, res.violations)
+    assert len(handed) > 30 and all(handed)
 
 
 async def test_chaos_slice_transport_asyncio_validator(monkeypatch):

@@ -145,6 +145,9 @@ class _SocketProtocol(asyncio.Protocol):
         return True  # keep half-open, like the reference's allowHalfOpen
 
     def connection_lost(self, exc) -> None:
+        # asyncio closes the socket as soon as this returns: no send of
+        # it may still be in flight on the tier's sender thread then
+        self._conn._tx.quiesce()
         if exc is not None:
             self._conn.emit('sockError', exc)
         else:

@@ -177,14 +177,15 @@ class SendPlane:
     def buffered_bytes(self) -> int:
         """Everything this connection has accepted for transmission
         but not yet handed to the kernel: the cork's pending bytes,
-        the transport tier entry's deferred chunks, and the asyncio
+        the transport tier entry's deferred chunks and whatever of it
+        the tier's sender thread still has in flight, and the asyncio
         transport's own write buffer — the tx-side account the
         overload plane's watermarks compare against (io/overload.py).
         A stalled reader grows exactly this number."""
         n = self._pending
         e = self._entry
         if e is not None:
-            n += e.nbytes
+            n += e.nbytes + e.flying
         t = (self._transport_fn() if self._transport_fn is not None
              else None)
         if t is not None:
@@ -321,6 +322,13 @@ class SendPlane:
                 led.exit()
         else:
             self._write(data)
+
+    def quiesce(self) -> None:
+        """The connection's socket is about to be closed: return only
+        once nothing of it is in flight on the tier's sender thread
+        (``TransportTier.quiesce``)."""
+        if self._entry is not None:
+            self._tier.quiesce(self._entry)
 
     def reset(self) -> None:
         """Drop corked frames without writing (connection aborted:

@@ -64,6 +64,25 @@ the clients that joined) — a fleet of sessions flushes one loop
 iteration's requests in one ``_tick`` and one submission.  A lone
 client gets the same thing at depth 1.
 
+Who sends.  On ``mmsg`` a submission is one ``send(2)`` a connection
+inside one C call that holds the GIL: at a few hundred connections
+that is milliseconds of the event loop's thread.  The loop's shared
+client tier (and no other: a member's submission stays inside its tick
+ledger, behind the WAL barrier) therefore hands a batch of
+:data:`OFFLOAD_MIN_SENDS` connections or more to ONE native thread
+(``zkwire_ext.sender_*``: no Python object, no GIL), keeps the batch in
+``_inflight`` and applies its results when the thread's ``eventfd``
+wakes the loop (``_reap``) — the same result handling as the inline
+submission, which shallower batches, the ``uring`` backend (already one
+``io_uring_enter`` a batch) and a process without the extension keep.
+What holds while a batch is in flight: a connection in it is not
+submitted again, raw or through its asyncio sink, until the batch is
+reaped (its new chunks wait in its entry: order on a connection);
+``drain`` / ``discard`` / ``quiesce`` first wait for the entry's batch
+(bytes on the wire before a hard flush returns; an fd is never closed,
+and so never reused, under a send in flight); the entry's in-flight
+bytes stay in ``SendPlane.buffered_bytes()``.
+
 Observability: ``zookeeper_flush_syscalls_total{plane,backend}``
 counts actual write submissions (the A/B number: O(dirty conns) per
 tick on mmsg/asyncio, O(1) on uring) and ``zookeeper_submit_depth``
@@ -73,7 +92,12 @@ with; a shared client tier has none and every joined client's
 collector adopts them).  Under a profiler session each tick is a host span
 ``<plane>.flush`` (utils/trace.host_span; count and total only):
 ``client.submit``'s count over ``client.flush``'s is requests per
-flush.
+flush; a hand-over is ``client.handoff``, a reap ``client.reap``, and
+``<plane>.send`` totals the connections every raw batch covered with
+the nanoseconds inside their send loop (the sender's own clock, or the
+loop's around an inline submission).  Always on:
+``zookeeper_flush_offloaded_total{plane}``, ``tier.offloaded_flushes``,
+``tier.offloaded_batches``.
 """
 
 from __future__ import annotations
@@ -85,10 +109,11 @@ import os
 import sys
 import threading
 import weakref
+from time import perf_counter_ns
 
 from ..utils.aio import ambient_loop
 from ..utils.metrics import Collector
-from ..utils.trace import host_span
+from ..utils.trace import host_add, host_span
 
 log = logging.getLogger('zkstream_tpu.transport')
 
@@ -101,6 +126,19 @@ METRIC_FLUSH_SYSCALLS = 'zookeeper_flush_syscalls_total'
 METRIC_SUBMIT_DEPTH = 'zookeeper_submit_depth'
 METRIC_FLUSH_PARTIAL = 'zookeeper_flush_partial_total'
 METRIC_FLUSH_REQUEUED = 'zookeeper_flush_partial_requeued_bytes'
+METRIC_FLUSH_OFFLOADED = 'zookeeper_flush_offloaded_total'
+
+#: Connections in one raw batch from which the loop's shared client
+#: tier hands the batch to its native sender thread instead of sending
+#: inline.  A hand-over costs the loop a condvar signal, the thread's
+#: ``eventfd`` write, a selector wake-up and the reap callback — ~25 us
+#: and 0.9 us a connection where a ``send(2)`` costs 43 (TPU host,
+#: kernel 4.4; PERF.md section 6, PR 33) — so loop time alone would put
+#: this near 2.  It stands where a hand-over was measured to pay end to
+#: end: flushes of 110 connections and more do; a closed loop of 48
+#: writers (9 a flush, at times all 48) read the same either way, and
+#: its sends then also wait for a thread to wake.
+OFFLOAD_MIN_SENDS = 64
 
 #: Connections per batched submission (the depth distribution: 1 =
 #: batching bought nothing that tick, the interesting mass is 2+).
@@ -240,13 +278,18 @@ class _Entry:
     transport object."""
 
     __slots__ = ('transport_fn', 'write', 'chunks', 'nbytes',
-                 '_t', '_fd')
+                 'batch', 'flying', '_t', '_fd')
 
     def __init__(self, write, transport_fn):
         self.write = write              # the plane's asyncio sink
         self.transport_fn = transport_fn
         self.chunks: list[bytes] = []
         self.nbytes = 0
+        #: The sender's batch that holds this connection's last raw
+        #: flush (0 = none in flight) and that flush's bytes: until the
+        #: batch is reaped nothing more of the connection is submitted.
+        self.batch = 0
+        self.flying = 0
         self._t = None
         self._fd = -1
 
@@ -302,6 +345,17 @@ class TransportTier:
         self._scheduled_on = None
         self._uring = None
         self._uring_dead = False
+        #: The native sender (``attach_sender``: the loop's shared
+        #: client tier alone): whether deep batches are handed over,
+        #: the thread's capsule (and the extension that made it) once
+        #: the first one was, the loop its ``eventfd`` is a reader of,
+        #: and batch id -> the batch's ``(entry, chunks, nbytes)`` —
+        #: which is also what keeps the thread's buffers referenced.
+        self._handoff = False
+        self._sender = None
+        self._ext = None
+        self._reader_loop = None
+        self._inflight: dict[int, list] = {}
         self.syscalls = 0        # lifetime submissions (tests/mntr)
         self.submissions = 0     # batched submit rounds
         #: connection flushes submitted raw, those of them the kernel
@@ -311,6 +365,9 @@ class TransportTier:
         self.flushes = 0
         self.partial_flushes = 0
         self.requeued_bytes = 0
+        #: of ``flushes`` / ``submissions``, those the sender took
+        self.offloaded_flushes = 0
+        self.offloaded_batches = 0
         #: Clients holding a :class:`TierLease` on this tier (a
         #: server's tier is its own and stays at 0).
         self.refs = 0
@@ -318,6 +375,9 @@ class TransportTier:
         #: plane's ``client.flush`` is the engagement counter beside
         #: ``client.submit``.
         self._span = plane + '.flush'
+        self._send_span = plane + '.send'
+        self._handoff_span = plane + '.handoff'
+        self._reap_span = plane + '.reap'
         #: The tier's own series: registered with the collector it
         #: was given, standalone without one (a loop's shared client
         #: tier belongs to no client's collector; each joined client
@@ -340,6 +400,24 @@ class TransportTier:
             METRIC_FLUSH_REQUEUED,
             'Bytes of partial raw flushes re-queued through the '
             'asyncio transport, by plane')
+        self.offloaded_ctr = source.counter(
+            METRIC_FLUSH_OFFLOADED,
+            'Raw connection flushes sent by the native sender thread '
+            'instead of the event loop, by plane')
+
+    @property
+    def series(self) -> tuple:
+        """The tier's own series (what a joined client's collector
+        adopts)."""
+        return (self.syscall_ctr, self.depth_hist, self.partial_ctr,
+                self.requeued_ctr, self.offloaded_ctr)
+
+    def attach_sender(self) -> None:
+        """Hand deep ``mmsg`` batches to a native sender thread from
+        now on (made when the first such batch comes, so a lone
+        client's tier never starts one).  ``uring`` has nothing to
+        hand over: its batch is one ``io_uring_enter``."""
+        self._handoff = self.backend == 'mmsg'
 
     # -- SendPlane-facing API --
 
@@ -402,13 +480,26 @@ class TransportTier:
         flush_hard contract — bytes on the wire before return).  The
         entry may stay in the dirty list; the tick submission skips
         entries whose chunks are already gone."""
+        self.quiesce(entry)
         if entry.chunks:
             self._submit([entry])
 
     def discard(self, entry: _Entry) -> None:
         """Connection aborted: its pending bytes have nowhere to go
         (SendPlane.reset)."""
+        self.quiesce(entry)
         entry.take()
+
+    def quiesce(self, entry: _Entry) -> None:
+        """Return only once no send of this connection is in flight on
+        the sender thread, its result applied: before a hard flush
+        writes behind it, and before the connection's socket is closed
+        (asyncio closes it right after ``connection_lost``) — a send
+        into an fd number that another connection has since been given
+        would put one session's request on another's wire."""
+        if entry.batch:
+            self._ext.sender_wait(self._sender, entry.batch)
+            self._reap()
 
     # -- the tick submission --
 
@@ -428,6 +519,10 @@ class TransportTier:
         flush, and the submission + schedule-slot release always
         run."""
         with host_span(self._span, accumulate=True):
+            if self._inflight:
+                # whatever the sender finished meanwhile: its entries
+                # may be in this tick's dirty set
+                self._reap()
             work, self._tick_work = self._tick_work, []
             try:
                 for fn in work:
@@ -458,6 +553,13 @@ class TransportTier:
             chunks = e.chunks
             if not chunks:
                 continue        # drained hard mid-tick, or reset
+            if e.batch:
+                # order on a connection: nothing more of it leaves,
+                # raw or through its sink, before its batch in flight
+                # is reaped; it stays dirty and _reap schedules the
+                # tick that takes these chunks
+                self._dirty.append(e)
+                continue
             # take the chunks NOW: a hard-drained entry re-dirtied in
             # the same tick appears in `entries` twice, and only an
             # emptied entry makes the second visit a no-op
@@ -491,23 +593,99 @@ class TransportTier:
             raw_entries.append((e, chunks, nbytes))
         if not batch_fds:
             return
+        if (self._handoff and len(batch_fds) >= OFFLOAD_MIN_SENDS
+                and self._hand_over(batch_fds, batch_chunks,
+                                    raw_entries)):
+            return
         led = self.ledger
         if led is not None:
             led.enter('cork_flush')
+        t0 = perf_counter_ns()
         try:
             results, nsys = self._submit_raw(batch_fds, batch_chunks)
         finally:
             if led is not None:
                 led.exit()
+        host_add(self._send_span, len(batch_fds), perf_counter_ns() - t0)
+        self._submitted(len(batch_fds), nsys)
+        self._apply(raw_entries, results)
+
+    def _submitted(self, depth: int, nsys: int) -> None:
         self.submissions += 1
-        self.flushes += len(batch_fds)
+        self.flushes += depth
         self._count(nsys, self.backend)
         self.depth_hist.observe(
-            len(batch_fds), {'plane': self.plane,
-                             'backend': self.backend})
+            depth, {'plane': self.plane, 'backend': self.backend})
+
+    def _apply(self, raw_entries, results) -> None:
+        """A raw batch's results, inline or reaped."""
         for (e, chunks, nbytes), res in zip(raw_entries, results):
             if res != nbytes:       # the hot path writes everything
                 self._settle(e, chunks, nbytes, res)
+
+    # -- the native sender (the loop's shared client tier) --
+
+    def _hand_over(self, fds, chunklists, raw_entries) -> bool:
+        """Queue one raw batch for the sender thread; False when there
+        is no sender to be had (the extension is not built, or not yet:
+        the batch then goes inline)."""
+        if self._sender is None:
+            ext = _sender_ext()
+            if ext is None:
+                return False
+            try:
+                self._sender = ext.sender_create()
+            except OSError:
+                self._handoff = False   # no thread to be had here
+                return False
+            self._ext = ext
+        loop = ambient_loop()
+        if loop is not self._reader_loop:
+            self._move_reader(loop)
+        with host_span(self._handoff_span, accumulate=True):
+            batch = self._ext.sender_submit(self._sender, fds,
+                                            chunklists)
+        self._inflight[batch] = raw_entries
+        for e, _chunks, nbytes in raw_entries:
+            e.batch = batch
+            e.flying = nbytes
+        depth = len(fds)
+        self.offloaded_batches += 1
+        self.offloaded_flushes += depth
+        self.offloaded_ctr.increment({'plane': self.plane}, by=depth)
+        self._submitted(depth, depth)
+        return True
+
+    def _move_reader(self, loop) -> None:
+        """The sender's ``eventfd`` is a reader of the loop that hands
+        batches over (a tier reused across ``asyncio.run`` calls moves
+        it; what an ended loop left in flight is reaped on the next)."""
+        fd = self._ext.sender_fileno(self._sender)
+        old, self._reader_loop = self._reader_loop, loop
+        if old is not None and not old.is_closed():
+            old.remove_reader(fd)
+        if loop is not None:
+            loop.add_reader(fd, self._reap)
+
+    def _reap(self) -> None:
+        """Apply what the sender finished, oldest batch first, exactly
+        as the inline submission's tail does, and schedule the tick for
+        entries that were held behind their batch."""
+        if self._sender is None:
+            return      # a wake-up that outlived close()
+        with host_span(self._reap_span, accumulate=True):
+            held = False
+            for batch, results, busy_ns in \
+                    self._ext.sender_reap(self._sender):
+                raw_entries = self._inflight.pop(batch)
+                host_add(self._send_span, len(results), busy_ns)
+                for e, _chunks, _nbytes in raw_entries:
+                    e.batch = e.flying = 0
+                    if e.chunks:
+                        held = True
+                self._apply(raw_entries, results)
+            if held:
+                self._schedule()
 
     def _settle(self, entry: _Entry, chunks: list[bytes],
                 nbytes: int, res: int) -> None:
@@ -611,6 +789,22 @@ class TransportTier:
                 except (OSError, ValueError):
                     pass
             self._uring = None
+        if self._sender is not None:
+            # what is in flight goes out and is settled, then the
+            # thread is joined; the next deep batch starts another
+            if self._inflight:
+                self._ext.sender_wait(self._sender, max(self._inflight))
+            self._reap()
+            self._move_reader(None)
+            self._ext.sender_close(self._sender)
+            self._sender = None
+
+
+def _sender_ext():
+    """The extension, if it is built and has the sender."""
+    from ..utils.native import get_ext
+    ext = get_ext()
+    return ext if hasattr(ext, 'sender_submit') else None
 
 
 def make_tier(arg: str | None, collector=None, plane: str = 'server',
@@ -672,10 +866,10 @@ class TierLease:
             if tier is None:
                 tier = tiers[self.backend] = TransportTier(
                     self.backend, plane='client')
+                tier.attach_sender()
             tier.refs += 1
         self._tier, self._loop = tier, loop
-        for series in (tier.syscall_ctr, tier.depth_hist,
-                       tier.partial_ctr, tier.requeued_ctr):
+        for series in tier.series:
             self._collector.adopt(series)
         return tier
 

@@ -185,7 +185,7 @@ def ensure_lib(timeout: float = 120.0) -> ctypes.CDLL | None:
 # packet dicts (framing + reply bodies in one C pass), and is loaded
 # with the same version-named-artifact / background-build discipline.
 
-_EXT_ABI_VERSION = 11
+_EXT_ABI_VERSION = 12
 
 _ext = None
 _ext_load_failed = False
@@ -198,7 +198,7 @@ def ext_source_path() -> str:
 
 def _ext_cc() -> list[str]:
     import sysconfig
-    return ['gcc', '-O2', '-shared', '-fPIC',
+    return ['gcc', '-O2', '-shared', '-fPIC', '-pthread',
             '-I', sysconfig.get_paths()['include']]
 
 

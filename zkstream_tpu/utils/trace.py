@@ -494,6 +494,27 @@ def host_span(name: str, accumulate: bool = False, **ids):
     return _HostSpan(name, accumulate, ids)
 
 
+def host_add(name: str, count: int, total_ns: int) -> None:
+    """Add to ``host_ring.totals[name]`` work that was counted and
+    timed elsewhere — a batch of ``count`` units that took
+    ``total_ns`` on the caller's clock or on a native thread's own
+    (the send plane's ``client.send``: connections sent to, and the
+    nanoseconds inside their ``send(2)`` loop).  Armed like
+    :func:`host_span`: nothing outside a profiler session."""
+    global _recording
+    if (_annotation is None and not _bind()) or not _is_enabled():
+        _recording = False
+        return
+    if not _recording:
+        host_ring.reset()
+        _recording = True
+    tot = host_ring.totals.get(name)
+    if tot is None:
+        tot = host_ring.totals[name] = [0, 0]
+    tot[0] += count
+    tot[1] += total_ns
+
+
 class _HostSpan:
     __slots__ = ('name', 'ids', 'fields', '_accumulate', '_ann',
                  '_parent', '_t0', '_cancelled')
