@@ -10,8 +10,9 @@ by the name ``BENCHMARK.json`` gives it:
 - ``engines/<engine>.py``        the general generator the mix drives
 - ``end_to_end/<metric>.py``     ``value(run) -> float``
 - ``layer_metrics/<metric>.py``  ``read(run) -> float | None``
-  (a metric split by cell carries the cell's label as its last
-  dotted part: ``x.read`` and ``x.write`` share ``x.py``)
+  (one entry a reader and end-to-end metric it moves, its cells on the
+  entry's ``workloads`` list; the last dotted part says which:
+  ``x.read``, ``x.write`` and ``x.converge`` share ``x.py``)
 - ``controls/<name>.py``         ``wrap_client(client)``: a timed path
                                  broken on purpose (never in a run the
                                  driver makes)
@@ -47,12 +48,25 @@ import members  # noqa: E402
 import reduce_trace  # noqa: E402
 import stats  # noqa: E402
 
-#: host spans the harness writes into the profiler's trace, innermost
-#: first: the ingest's tick (dispatch, readback, routing), the loop
-#: blocked in ``select`` waiting for replies, the post-window checks
-HOST_SPANS = ('ingest_tick', 'await_replies', 'validate')
-#: the loop's thread is in one of those or running the sessions' and
-#: the engine's callbacks: device idle time under no span is that
+#: the host annotations a traced run's idle gaps are attributed to,
+#: innermost first (``reduce_trace.attribute_gaps``: an earlier name
+#: takes what it covers): the program's own spans, written into the
+#: profiler's trace by ``zkstream_tpu.utils.trace.host_span`` on the
+#: device plane's clock — a watch delivery, the ingest tick's four
+#: phases and then what is left of the tick, the send tier's hand-over
+#: and reaping inside its flush, a connection's receive, a request's
+#: submission, the deadline timer — then the harness's two: the loop
+#: blocked in ``select`` (the program has no span around it) and the
+#: post-window checks
+HOST_SPANS = ('client.notify',
+              'ingest.batch', 'ingest.dispatch', 'ingest.readback',
+              'ingest.route', 'ingest.tick',
+              'client.handoff', 'client.reap', 'client.flush',
+              'client.rx', 'client.deadline', 'client.submit',
+              'await_replies', 'validate')
+#: device idle time under none of those: what the loop's thread did
+#: that no span of the program names (the engine's callbacks, asyncio's
+#: own turn, a tick that found nothing to drain)
 LOOP_REST = 'loop_callbacks'
 
 
@@ -76,10 +90,11 @@ def _load_json(path: str) -> dict:
 
 def reader_path(kind: str, name: str) -> str | None:
     """``<kind>/<name>.py``, or — the one other rule — the file of the
-    name less its LAST dotted part: a metric split by cell because its
-    cells report different end-to-end metrics or need bounds of their
-    own carries the cell's label as a suffix (``x.read`` / ``x.write``
-    share ``x.py``) unless it has a file of its own."""
+    name less its LAST dotted part: a metric split because its cells
+    report different end-to-end metrics or need bounds of their own
+    carries the family it moves as a suffix (``x.read`` / ``x.write`` /
+    ``x.converge`` share ``x.py``; a cell's own label where the entry
+    is that cell's alone) unless it has a file of its own."""
     names = [name]
     if '.' in name:
         names.append(name.rsplit('.', 1)[0])
@@ -230,19 +245,6 @@ class Run:
                     - float(self.mntr_before[member][key]))
         except (KeyError, IndexError, ValueError):
             return None
-
-    def mntr_leader(self, key: str) -> float | None:
-        try:
-            return float(self.mntr_after[self.leader][key])
-        except (KeyError, IndexError, ValueError):
-            return None
-
-    def mntr_max(self, key: str) -> float | None:
-        vals = []
-        for rows in self.mntr_after:
-            with contextlib.suppress(KeyError, ValueError):
-                vals.append(float(rows[key]))
-        return max(vals) if vals else None
 
 
 INGEST_COUNTERS = ('ticks', 'ticks_scalar', 'ticks_warming', 'ticks_frag',
@@ -563,19 +565,13 @@ async def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         if trace:
             sink = ingest.tick_hist = _TickSink()
             acc, restore_select = _time_select(loop, fleet.span)
-            tick = ingest._tick
             bucket = ingest._bucket
             ticks_seen: list = []
-
-            def traced_tick():
-                with fleet.span('ingest_tick'):
-                    tick()
 
             def noted_bucket(n, nbytes):
                 key = bucket(n, nbytes)
                 ticks_seen.append((time.perf_counter(), key))
                 return key
-            ingest._tick = traced_tick
             ingest._bucket = noted_bucket
 
         # -- warm-up: the cell's own traffic, unrecorded --------------
@@ -698,20 +694,26 @@ async def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                 os.makedirs(keep_trace, exist_ok=True)
                 shutil.copy(xplane, os.path.join(
                     keep_trace, '%s.%d.xplane.pb' % (workload, seed)))
+            t_load = time.perf_counter()
             tr = reduce_trace.load_xplane(xplane, keep_host=HOST_SPANS)
+            t_load = time.perf_counter() - t_load
             if keep_trace:
                 with open(os.path.join(keep_trace, '%s.%d.txt' % (
                         workload, seed)), 'w') as f:
                     f.write(reduce_trace.summarize(
                         reduce_trace.load_xplane(xplane)))
+            t_reduce = time.perf_counter()
             run.trace = red = reduce_trace.reduce(
                 tr, window_ns=(t_trace[1] - t_trace[0]) * 1e9,
                 host_spans=HOST_SPANS, rest=LOOP_REST)
+            t_reduce = time.perf_counter() - t_reduce
             run.tick_buckets = [k for t, k in ticks_seen
                                 if t_trace[0] <= t <= t_trace[1]]
-            say('# trace %.3fs busy %.6fs programs %s ticks_in_trace=%d'
+            say('# trace %.3fs busy %.6fs programs %s ticks_in_trace=%d '
+                'reduced in %.2fs (load) + %.2fs (attribute)'
                 % (red['window_s'], red['busy_s'],
-                   json.dumps(red['programs']), len(run.tick_buckets)))
+                   json.dumps(red['programs']), len(run.tick_buckets),
+                   t_load, t_reduce))
             if mode == 'chip' and red['busy_s'] <= 0:
                 raise HarnessError('no operation ran on the device in '
                                    'the traced window')

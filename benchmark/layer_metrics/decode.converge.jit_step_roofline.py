@@ -1,7 +1,8 @@
 """``jit_step``'s share of its bytes roofline in the traced window
 (``reduce_trace.tick_roofline_share``), every size class's dispatches
-together; named for the kernel, so the cell's label stands in the
-middle and the reader has this file."""
+together; named for the kernel, so the label — here the end-to-end
+family the entry moves, ``converge_p50_ms`` — stands in the middle and
+the reader has this file."""
 
 import reduce_trace
 

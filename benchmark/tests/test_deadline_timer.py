@@ -30,8 +30,11 @@ def test_entry_and_its_reader():
     (moved,) = [e for e in BENCH['end_to_end'] if e['name'] == m['moves']]
     (like,) = [x for x in BENCH['per_layer']
                if x['name'] == 'client.sends_per_flush.read']
-    assert m == dict(like, name=NAME)
+    # the timer's entry is the read cell's alone; the flush's lists
+    # every cell of its end-to-end family
     assert m['workloads'] == ['hunt3_1k.read']
+    assert set(m['workloads']) <= set(like['workloads'])
+    assert m == dict(like, name=NAME, workloads=m['workloads'])
     assert set(m['workloads']) <= set(moved['workloads'])
     path = harness.reader_path('layer_metrics', NAME)
     assert path and path.endswith('client.ops_per_deadline_timer.py')

@@ -14,7 +14,7 @@ import tempfile
 import harness
 import inside
 import pytest
-from conftest import ROOT
+from conftest import ROOT, entries, entry
 from test_runs import members_alive, rehearse
 
 from zkstream_tpu.utils import trace
@@ -26,6 +26,11 @@ with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
 
 def read(name, run):
     return harness._load_module('layer_metrics', name).read(run)
+
+
+def entries_read_by(*readers) -> list[str]:
+    """The names of every entry one of the reader files reads."""
+    return [m['name'] for r in readers for m in entries(r)]
 
 
 def rows(*hists) -> dict:
@@ -61,28 +66,26 @@ def test_ring_readers_on_a_toy_ring(ring):
     ring.totals['client.rx'] = [1000, 200_000_000]      # 0.2 s
     ring.totals['client.submit'] = [1000, 600_000_000]  # 0.6 s
     run = toy_run()
-    for cell in ('read', 'write'):
-        assert read('ingest.batch_ms_p50.' + cell, run) == 0.6
-        assert read('ingest.dispatch_ms_p50.' + cell, run) == 0.3
-        assert read('ingest.readback_ms_p50.' + cell, run) == 2.0
-        assert read('ingest.route_ms_p50.' + cell, run) == 0.2
-        assert read('client.rx_share.' + cell, run) == pytest.approx(5.0)
-        assert read('client.submit_share.' + cell, run) == pytest.approx(
-            15.0)
-    ring_metrics = [m['name'] for m in BENCH['per_layer']
-                    if m['name'].startswith(('ingest.batch', 'ingest.disp',
-                                             'ingest.readb', 'ingest.route',
-                                             'client.rx', 'client.submit'))]
-    assert len(ring_metrics) == 12
+    want = {'ingest.batch_ms_p50': 0.6, 'ingest.dispatch_ms_p50': 0.3,
+            'ingest.readback_ms_p50': 2.0, 'ingest.route_ms_p50': 0.2,
+            'client.rx_share': 5.0, 'client.submit_share': 15.0}
+    ring_metrics = entries_read_by(*want)
+    # every reader has its entries: the read and the write family each
+    assert {n.rsplit('.', 1)[0] for n in ring_metrics} == set(want)
+    assert {n.rsplit('.', 1)[1] for n in ring_metrics} >= {'read', 'write'}
+    for name in ring_metrics:
+        assert read(name, run) == pytest.approx(
+            want[name.rsplit('.', 1)[0]])
+    nothing = [None] * len(ring_metrics)
     # a ring that wrapped is not the window's: nothing to read
     ring.dropped = 1
-    assert [read(n, run) for n in ring_metrics] == [None] * 12
+    assert [read(n, run) for n in ring_metrics] == nothing
     ring.dropped = 0
     # an untraced run, and an empty ring
     run.trace = None
-    assert [read(n, run) for n in ring_metrics] == [None] * 12
+    assert [read(n, run) for n in ring_metrics] == nothing
     ring.reset()
-    assert [read(n, toy_run()) for n in ring_metrics] == [None] * 12
+    assert [read(n, toy_run()) for n in ring_metrics] == nothing
 
 
 def test_ring_readers_on_a_program_without_a_ring(monkeypatch):
@@ -143,8 +146,11 @@ def test_member_readers_on_toy_mntr_rows():
     run.mntr_before, run.mntr_after = before, after
 
     # busiest member: member 0, (2 + 12) s of 20 s
-    for cell in ('read', 'write', 'relist'):
-        assert read('server.busy_share.' + cell, run) == pytest.approx(70.0)
+    busy = entries_read_by('server.busy_share')
+    assert {n.rsplit('.', 1)[1] for n in busy} == {'read', 'write',
+                                                   'converge'}
+    for name in busy:
+        assert read(name, run) == pytest.approx(70.0)
     # most parked follower: member 0, 12 s of 20 s (member 2: 2 s)
     assert read('forward.rpc_parked_share', run) == pytest.approx(60.0)
     assert inside.phase_share(run, 2, ('forward_rpc',)) == pytest.approx(
@@ -159,11 +165,13 @@ def test_member_readers_on_toy_mntr_rows():
     assert inside.percentile(inside.member_hist(
         run, 1, 'zk_tick_phase_ms', {'phase': 'decode_apply'}),
         99) == pytest.approx(0.5)
-    for name in ('wal.fsync_gate_win_ms_p99',
-                 'wal.fsync_gate_win_ms_p99.relist'):
+    gates = entries_read_by('wal.fsync_gate_win_ms_p99')
+    acks = entries_read_by('quorum.ack_ms_p95')
+    assert len(gates) == len(acks) == 2     # write's, and converge's
+    for name in gates:
         # rank 2,475 of 2,500: the top of (0.25, 0.5]
         assert read(name, run) == pytest.approx(0.5)
-    for name in ('quorum.ack_ms_p95', 'quorum.ack_ms_p95.relist'):
+    for name in acks:
         # rank 950 of 1,000: half way through the 100 in (2.5, 5]
         assert read(name, run) == pytest.approx(3.75)
     # member 1: rank 95 of 100, half way through the 10 in (0.5, 1];
@@ -172,7 +180,7 @@ def test_member_readers_on_toy_mntr_rows():
     # no uptime row: the run's window stands in
     for r in before + after:
         del r['zk_uptime_ms']
-    assert read('server.busy_share.write', run) == pytest.approx(70.0)
+    assert [read(n, run) for n in busy] == [pytest.approx(70.0)] * 3
 
 
 def test_member_readers_find_nothing_on_the_parents_rows():
@@ -180,16 +188,19 @@ def test_member_readers_find_nothing_on_the_parents_rows():
     old = {'zk_tick_phase_ms_p99{phase="decode_apply"}': '13.2',
            'zk_uptime_ms': '5000', 'zk_quorum_degraded': '0'}
     run.mntr_before, run.mntr_after = [dict(old)] * 3, [dict(old)] * 3
-    names = [m['name'] for m in BENCH['per_layer'] if m['name'].startswith(
-        ('server.busy', 'server.decode_apply_win', 'wal.fsync_gate_win',
-         'quorum.ack', 'fanout.tick', 'forward.rpc'))]
-    assert len(names) == 10
-    assert [read(n, run) for n in names] == [None] * 10
+    readers = ('server.busy_share', 'server.decode_apply_win_ms_p99',
+               'wal.fsync_gate_win_ms_p99', 'quorum.ack_ms_p95',
+               'fanout.tick_ms_p95', 'forward.rpc_parked_share')
+    names = entries_read_by(*readers)
+    assert {harness.reader_path('layer_metrics', n) for n in names} \
+        == {harness.reader_path('layer_metrics', r) for r in readers}
+    nothing = [None] * len(names)
+    assert [read(n, run) for n in names] == nothing
     # a member that did not answer gave an empty dict, or none at all
     run.mntr_before, run.mntr_after = [{}, {}, {}], [{}, {}, {}]
-    assert [read(n, run) for n in names] == [None] * 10
+    assert [read(n, run) for n in names] == nothing
     run.mntr_before, run.mntr_after = [], []
-    assert [read(n, run) for n in names] == [None] * 10
+    assert [read(n, run) for n in names] == nothing
 
 
 @pytest.mark.parametrize('q', [50, 90, 95, 99, 100])
@@ -242,24 +253,26 @@ def test_a_rank_past_the_last_edge_reads_the_last_edge():
     assert inside.percentile(hist, 99) == 10.0 == h.percentile(99)
 
 
-CELL_METRICS = {
+#: reader files whose entry each toy cell, traced, must print
+CELL_READERS = {
     'hunt3_1k.read': {'server.decode_apply_win_ms_p99'},
     'hunt3_1k.write': {'wal.fsync_gate_win_ms_p99', 'quorum.ack_ms_p95',
                        'forward.rpc_parked_share'},
-    'discovery3.relist': {'wal.fsync_gate_win_ms_p99.relist',
-                          'quorum.ack_ms_p95.relist',
+    'discovery3.relist': {'wal.fsync_gate_win_ms_p99', 'quorum.ack_ms_p95',
                           'fanout.tick_ms_p95'}}
+RING_READERS = ('ingest.batch_ms_p50', 'ingest.dispatch_ms_p50',
+                'ingest.readback_ms_p50', 'ingest.route_ms_p50',
+                'client.rx_share', 'client.submit_share')
 
 
-@pytest.mark.parametrize('cell', sorted(CELL_METRICS))
+@pytest.mark.parametrize('cell', sorted(CELL_READERS))
 def test_toy_cell_traced_is_correct_and_prints_the_new_metrics(cell):
-    label = cell.rsplit('.', 1)[1]
-    want = set(CELL_METRICS[cell]) | {'server.busy_share.' + label}
-    if label != 'relist':
-        want |= {'%s.%s' % (n, label) for n in (
-            'ingest.batch_ms_p50', 'ingest.dispatch_ms_p50',
-            'ingest.readback_ms_p50', 'ingest.route_ms_p50',
-            'client.rx_share', 'client.submit_share')}
+    ring_cell = not cell.endswith('.relist')
+    readers = CELL_READERS[cell] | {'server.busy_share'}
+    if ring_cell:
+        readers |= set(RING_READERS) | {'ingest.tick_ms_p50'}
+    e = {r: entry(r, cell) for r in readers}
+    want = set(e.values())
     with tempfile.TemporaryDirectory(prefix='benchtest-') as tmp:
         r, out = rehearse(tmp, '--one', cell, '--seed', str(2 ** 31 + 24),
                           '--seconds', '3', '--trace', '1')
@@ -268,15 +281,15 @@ def test_toy_cell_traced_is_correct_and_prints_the_new_metrics(cell):
     got = {k: v['value'] for k, v in out['metrics'].items()}
     assert want <= set(got), sorted(want - set(got))
     assert all(v >= 0 for k, v in got.items() if k in want)
-    assert 0 < got['server.busy_share.' + label] <= 100
-    if label != 'relist':
-        inner = sum(got['ingest.%s_ms_p50.%s' % (p, label)] for p in (
+    assert 0 < got[e['server.busy_share']] <= 100
+    if ring_cell:
+        inner = sum(got[e['ingest.%s_ms_p50' % (p,)]] for p in (
             'batch', 'dispatch', 'readback', 'route'))
         # medians of parts against the median of the whole: close, and
         # the parts cannot make up much more than the whole
-        assert inner <= 1.5 * got['ingest.tick_ms_p50.' + label]
-        assert got['client.rx_share.' + label] \
-            + got['client.submit_share.' + label] <= 100
-    if label == 'write':
-        assert got['forward.rpc_parked_share'] > 0
+        assert inner <= 1.5 * got[e['ingest.tick_ms_p50']]
+        assert got[e['client.rx_share']] \
+            + got[e['client.submit_share']] <= 100
+    if cell.endswith('.write'):
+        assert got[e['forward.rpc_parked_share']] > 0
     assert not members_alive()

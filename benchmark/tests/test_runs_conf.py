@@ -4,6 +4,7 @@ sound; the cache's serve gate and the invalidation stream broken
 underneath are NOT; the traced run reports the cell's counters."""
 
 import pytest
+from conftest import entry
 from test_runs import members_alive, rehearse, run_dirs, tmp  # noqa: F401
 
 def test_sound_run_is_correct_and_leaves_nothing(tmp):  # noqa: F811
@@ -40,15 +41,16 @@ def test_traced_push_reports_its_counters(tmp):  # noqa: F811
     assert r.returncode == 0, r.stderr[-2000:]
     assert out['correct'] is True and out['failed'] == 0
     m = {k: v['value'] for k, v in out['metrics'].items()}
-    assert m['cache.invalidations_per_change.push'] == 24.0
-    assert m['fanout.persistent_per_change.push'] == 24.0
-    assert m['overload.persistent_evictions.push'] == 0.0
-    assert m['ingest.offdevice_share.push'] == 0.0
-    assert 0.0 < m['cache.hit_share.push'] < 100.0
-    assert m['client.notify_share.push'] > 0.0
-    assert {'converge.p95_ms.push', 'gen.late_ms_p95.push',
-            'fanout.tick_ms_p95.push', 'server.busy_share.push',
-            'client.sends_per_flush.push',
-            'ingest.route_ms_p50.push'} <= set(m)
+    e = lambda reader: entry(reader, 'confcache3.push')     # noqa: E731
+    assert m[e('cache.invalidations_per_change')] == 24.0
+    assert m[e('fanout.persistent_per_change')] == 24.0
+    assert m[e('overload.persistent_evictions')] == 0.0
+    assert m[e('ingest.offdevice_share')] == 0.0
+    assert 0.0 < m[e('cache.hit_share')] < 100.0
+    assert m[e('client.notify_share')] > 0.0
+    assert {e(r) for r in (
+        'converge.p95_ms', 'gen.late_ms_p95', 'fanout.tick_ms_p95',
+        'server.busy_share', 'client.sends_per_flush',
+        'ingest.route_ms_p50')} <= set(m)
     # no device, no device metric: the readers found nothing to read
-    assert 'decode.push.jit_step_roofline' not in m
+    assert e('decode.converge.jit_step_roofline') not in m

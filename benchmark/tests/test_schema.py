@@ -6,7 +6,7 @@ import os
 import re
 
 import pytest
-from conftest import BENCH, ROOT
+from conftest import BENCH, ROOT, entries
 
 NAME = re.compile(r'^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$')
 UNIT = re.compile(r'^[A-Za-z0-9_/%.\-]{1,16}$')
@@ -149,6 +149,91 @@ def test_metrics(bench):
     for c in cells:
         assert 'setup_s' in reports[c] and len(reports[c]) >= 2
         assert layer_cells[c] >= 1
+
+
+#: PR 34 retired these: each read a histogram since the member started
+#: (set-up's handshake burst, not the window) beside a windowed twin
+RETIRED = {('server.decode_apply_ms_p99', 'hunt3_1k.read'),
+           ('wal.fsync_gate_ms_p99', 'hunt3_1k.write'),
+           ('wal.fsync_gate_ms_p99.relist', 'discovery3.relist'),
+           ('fanout.flush_ms_p99', 'discovery3.relist')}
+#: ... and merged these three files into the two beside them: all the
+#: same call as ``decode.write.jit_step_roofline.py``, which stands
+MERGED = {'decode.load.jit_step_roofline.py':
+          'decode.read.jit_step_roofline.py',
+          'decode.push.jit_step_roofline.py':
+          'decode.converge.jit_step_roofline.py',
+          'decode.viewchange.jit_step_roofline.py':
+          'decode.converge.jit_step_roofline.py'}
+
+
+def old_pairs():
+    with open(os.path.join(BENCH, 'tests', 'data',
+                           'per_layer_pairs.pr33.txt')) as f:
+        return [tuple(row.split()) for row in f
+                if not row.startswith('#')]
+
+
+def still_read(layers, cell, want):
+    """Some entry lists ``cell`` and resolves to the file ``want``."""
+    return bool(entries(want[:-len('.py')], cell, layers))
+
+
+def test_every_old_pair_is_still_read(bench):
+    """Merging entries lost no reader's coverage of any cell: every
+    (entry, cell) pair of the 128 entries PR 33 left is read by the
+    same file in the same cell under SOME entry of today's list —
+    but the four since-start entries, and nothing else."""
+    import inspect
+
+    import harness
+
+    pairs = old_pairs()
+    assert len(pairs) == 128 and len(set(pairs)) == 128
+    assert RETIRED <= {(n, c) for n, c, _f in pairs}
+    lost = [(name, cell) for name, cell, want in pairs
+            if (name, cell) not in RETIRED
+            and not still_read(bench['per_layer'], cell,
+                               MERGED.get(want, want))]
+    assert not lost, lost
+    for name, cell, want in pairs:
+        if (name, cell) in RETIRED:
+            assert not still_read(bench['per_layer'], cell, want)
+    stands = harness._load_module('layer_metrics',
+                                  'decode.write.jit_step_roofline').read
+    for name in set(MERGED.values()):
+        got = harness._load_module('layer_metrics', name[:-3]).read
+        assert inspect.getsource(got) == inspect.getsource(stands)
+
+
+def test_the_check_sees_a_cell_dropped_from_a_list(bench):
+    """Each of the 124 pairs that stand is read under exactly ONE
+    entry: that entry's ``workloads`` less the pair's cell loses it."""
+    layers = bench['per_layer']
+    for _name, cell, want in old_pairs():
+        if (_name, cell) in RETIRED:
+            continue
+        want = MERGED.get(want, want)
+        (m,) = entries(want[:-len('.py')], cell, layers)
+        cut = [dict(x, workloads=[c for c in x['workloads'] if c != cell])
+               if x is m else x for x in layers]
+        assert not still_read(cut, cell, want), (m['name'], cell)
+
+
+def test_one_entry_a_reader_and_end_to_end_metric(bench):
+    """No two entries share a reader file and ``moves``: such a pair
+    differs in nothing but the suffix and is one entry with both cells
+    on its ``workloads`` list (the roofline files, one to a family
+    because the name must END in ``_roofline``, count by their
+    ``read``)."""
+    import harness
+    seen = {}
+    for m in bench['per_layer']:
+        path = harness.reader_path('layer_metrics', m['name'])
+        key = (MERGED.get(os.path.basename(path), path), m['moves'])
+        assert key not in seen, (m['name'], seen[key])
+        seen[key] = m['name']
+    assert len(bench['per_layer']) == 88
 
 
 def test_every_file_under_paths_has_a_contract_name(bench):

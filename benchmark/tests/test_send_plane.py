@@ -13,7 +13,8 @@ import tempfile
 import harness
 import pytest
 from conftest import ROOT
-from test_inside import read, ring, toy_run  # noqa: F401  (fixture)
+from test_inside import (entries_read_by, read, ring,  # noqa: F401
+                         toy_run)
 from test_runs import members_alive, rehearse
 
 from zkstream_tpu.utils import trace
@@ -21,21 +22,27 @@ from zkstream_tpu.utils import trace
 with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
     BENCH = json.load(f)
 
+#: PR 25's three entries, by name (wherever they stand, whatever cells
+#: later PRs put on their lists)
 NAMES = ['client.flush_share.read', 'client.sends_per_flush.read',
          'client.sends_per_flush.write']
+READERS = ('client.flush_share', 'client.sends_per_flush')
 
 
 def test_entries_and_their_readers():
     by_name = {m['name']: m for m in BENCH['per_layer']}
     cells = {w['name'] for w in BENCH['workloads']}
     e2e = {m['name']: m for m in BENCH['end_to_end']}
-    assert [m['name'] for m in BENCH['per_layer'][-3:]] == NAMES
-    for name in NAMES:
+    assert set(NAMES) <= set(entries_read_by(*READERS))
+    for name in entries_read_by(*READERS):
         m = by_name[name]
         assert m['layer'] == 'client session'
-        assert harness.reader_path('layer_metrics', name)
+        assert m['source'] == 'program_span'
         assert set(m['workloads']) <= cells
         assert set(m['workloads']) <= set(e2e[m['moves']]['workloads'])
+    assert 'hunt3_1k.read' in by_name[NAMES[0]]['workloads']
+    assert 'hunt3_1k.read' in by_name[NAMES[1]]['workloads']
+    assert by_name[NAMES[2]]['workloads'] == ['hunt3_1k.write']
 
 
 def test_flush_readers_on_a_toy_ring(ring):  # noqa: F811
