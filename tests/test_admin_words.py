@@ -141,6 +141,31 @@ async def test_mntr_tick_ledger_and_trace_rows(server):
         await c.close()
 
 
+async def test_mntr_process_cpu_row_never_goes_back(server):
+    """``zk_process_cpu_ms``: the member process's CPU at the scrape,
+    cumulative — what the ledger's phase sums are a share of."""
+    def cpu(text):
+        kv = dict(line.split('\t', 1)
+                  for line in text.decode().strip().splitlines())
+        return float(kv['zk_process_cpu_ms'])
+
+    first = cpu(await _four_letter(server, b'mntr'))
+    assert first > 0
+    c = Client(address='127.0.0.1', port=server.port,
+               session_timeout=5000)
+    c.start()
+    try:
+        await c.wait_connected(timeout=5)
+        await c.create('/cpu', b'x')
+        for i in range(200):
+            await c.set('/cpu', b'v%d' % i)
+        second = cpu(await _four_letter(server, b'mntr'))
+        third = cpu(await _four_letter(server, b'mntr'))
+        assert first < second <= third
+    finally:
+        await c.close()
+
+
 async def test_mntr_uptime_slow_op_and_blackbox_rows(server,
                                                      tmp_path):
     """The black-box plane's mntr rows: zk_uptime_ms and

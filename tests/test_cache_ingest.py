@@ -237,8 +237,8 @@ async def test_the_fill_gate_holds_with_ingest_delivery(fleet):
     primary = c._primary_request
     gate = asyncio.Event()
 
-    async def slow_primary(pkt, opcode, path, deadline):
-        out = await primary(pkt, opcode, path, deadline)
+    async def slow_primary(pkt, opcode, *rest):
+        out = await primary(pkt, opcode, *rest)
         if opcode == 'GET_DATA':
             await gate.wait()
         return out

@@ -23,6 +23,9 @@ PHASES = ['ingest.batch', 'ingest.dispatch', 'ingest.readback',
 
 @pytest.fixture(autouse=True)
 def clean_host_ring():
+    # bound before any test patches the switch: a patch over the
+    # unbound None would be restored to None beside a bound annotation
+    trace._bind()
     trace.host_ring.reset()
     trace._recording = False
     yield
@@ -35,6 +38,13 @@ def armed(monkeypatch):
     """The profiler's switch patched on: spans are recorded as inside
     a session (the annotation itself is then an un-armed no-op)."""
     monkeypatch.setattr(trace, '_is_enabled', lambda: True)
+
+
+def _totals() -> dict:
+    """The ring's totals without the collector's pauses: a collection
+    may fall inside any armed stretch (``gc.pause[@span]``)."""
+    return {k: v for k, v in trace.host_ring.totals.items()
+            if not k.startswith('gc.pause')}
 
 
 def _ingest() -> FleetIngest:
@@ -143,7 +153,7 @@ def test_a_new_session_resets_the_ring(monkeypatch):
     with trace.host_span('a'):
         pass
     assert len(trace.host_ring) == 1
-    assert trace.host_ring.totals == {}
+    assert _totals() == {}
 
 
 def test_host_ring_counts_what_it_drops(armed, monkeypatch):
@@ -271,7 +281,7 @@ def test_host_add_is_armed_by_the_session_alone(monkeypatch):
     monkeypatch.setattr(trace, '_is_enabled', lambda: True)
     trace.host_add('client.send', 5, 1000)
     trace.host_add('client.send', 2, 500)
-    assert trace.host_ring.totals == {'client.send': [7, 1500]}
+    assert _totals() == {'client.send': [7, 1500]}
     assert len(trace.host_ring) == 0
 
 
@@ -415,6 +425,301 @@ async def test_spans_lie_in_the_profilers_trace(server, tmp_path):
         inner = [e for e in events if e[0] in PHASES and e[3] == t.tick]
         assert [e[0] for e in sorted(inner, key=lambda e: e[1])] == PHASES
         assert all(outer[1] <= e[1] <= e[2] <= outer[2] for e in inner)
+
+
+# -- a request's latency by stage, and the collector's pauses ------------
+
+def _stage_totals() -> list:
+    return [trace.host_ring.totals.get(name, [0, 0])
+            for name in trace.STAGE_WAITS]
+
+
+def _check_stages(spans, ops: int) -> None:
+    """``ops`` staged ops were booked, once each, and their four waits
+    sum to what their spans say they took on the host clock."""
+    assert [c for c, _ns in _stage_totals()] == [ops] * 4
+    assert all(s.stages is None for s in spans)     # booked: released
+    staged = [s for s in spans if s.t0_ns is not None]
+    assert len(staged) == ops
+    assert sum(ns for _c, ns in _stage_totals()) == sum(
+        s.t1_ns - s.t0_ns for s in staged)
+
+
+async def test_an_ops_four_waits_sum_to_its_span(server, armed):
+    """Inside a session every op through the ingest is stamped at
+    submit, flush, rx, settle and resume: the four differences are
+    counted once an op under ``client.*_wait`` and sum to the op
+    span's ``t1_ns - t0_ns`` exactly; the span's ``tick`` is the
+    ``ingest.tick`` whose route settled it; the two synchronous
+    stretches beside them are ``client.prepare`` / ``client.resume``."""
+    ingest = _ingest()
+    c = await _fleet_client(server, ingest)
+    try:
+        await c.create('/s', b'v' * 100)
+        trace.host_ring.reset()
+        c.trace.clear()
+        ops = 5
+        for _ in range(ops):
+            await c.get('/s')
+        with pytest.raises(Exception) as ei:         # a failed op too
+            await c.get('/missing')
+        assert getattr(ei.value, 'code', None) == 'NO_NODE'
+        ops += 1
+        spans = c.trace.spans()
+        _check_stages(spans, ops)
+        totals = trace.host_ring.totals
+        assert totals['client.prepare'][0] == ops
+        assert totals['client.resume'][0] == ops
+        assert totals['client.submit'][0] == ops
+        routes = {s.tick: s for s in trace.host_ring.spans()
+                  if s.op == 'ingest.route'}
+        ticks = {s.tick for s in trace.host_ring.spans()
+                 if s.op == 'ingest.tick' and s.tick is not None}
+        for s in spans:
+            # settled inside that tick's route: it began after the
+            # submit and before the awaiter ran again
+            assert s.tick in ticks
+            assert s.t0_ns < routes[s.tick].t0_ns < s.t1_ns
+            # the op's own clock agrees: submit -> settle on it
+            assert s.duration_ms <= (s.t1_ns - s.t0_ns) / 1e6 + 0.05
+        # every wait is a real stretch here: bytes crossed a socket
+        assert all(ns > 0 for _c, ns in _stage_totals())
+        # the schema-3 keys carry it: no new key
+        keys = list(spans[0].to_dict())
+        assert keys[-4:] == ['tick', 't0_ns', 't1_ns', 'duration_ms']
+        assert len(trace.host_ring) == 5 * len(ticks)   # no object an op
+    finally:
+        await c.close()
+
+
+async def test_an_expired_and_a_late_reply_are_booked_once(server, armed):
+    """An op whose deadline fires is booked when its awaiter resumes —
+    its ``wire_wait`` runs to there, the stages it never reached take
+    nothing — and the reply that comes late, dropped, books nothing
+    more.  Off the device an op's ``tick`` stays None."""
+    c = Client(address='127.0.0.1', port=server.port,
+               session_timeout=30000, max_spares=0)
+    c.start()
+    await c.wait_connected(timeout=5)
+    try:
+        await asyncio.sleep(0.05)
+        trace.host_ring.reset()
+        c.trace.clear()
+        await c.list('/')
+        server.drop_replies = True
+        with pytest.raises(Exception) as ei:
+            await c.list('/', deadline=20)
+        assert getattr(ei.value, 'code', None) == 'DEADLINE_EXCEEDED'
+        ok, expired = c.trace.spans()
+        _check_stages([ok, expired], 2)
+        assert ok.tick is None and expired.tick is None
+        assert (expired.t1_ns - expired.t0_ns) / 1e6 >= 20
+        cork, wire, tick, wake = _stage_totals()
+        # nearly all of the 20 ms stood between the flush and a reply
+        assert wire[1] >= 0.9 * (expired.t1_ns - expired.t0_ns)
+        before = [list(t) for t in _stage_totals()]
+        conn = c.current_connection()
+        (xid,) = [x for x in conn.reqs if x > 0]
+        conn._sock_data(b'')                # arms this call's rx mark
+        conn.process_reply({'xid': xid, 'zxid': 1, 'err': 'OK',
+                            'opcode': 'GET_CHILDREN2', 'children': [],
+                            'stat': None})
+        assert xid not in conn.reqs
+        assert _stage_totals() == before
+        assert expired.stages is None and expired.status == 'deadline'
+    finally:
+        server.drop_replies = False
+        await c.close()
+
+
+async def test_two_requests_in_flight_are_each_stamped_by_their_own_flush(
+        armed):
+    """A pipelined connection: the flush that takes a request's bytes
+    stamps THAT request (two corked in one tick share a flush, the one
+    sent a turn later has a later one); both replies in one segment
+    share the ``_sock_data`` call that brought them and the tick that
+    routed them, and settle in order."""
+    import random
+
+    from test_ingest_route import Peer, settle
+
+    ingest = FleetIngest(bypass_bytes=0, warm='block', placement='host',
+                         max_frames=4, min_len=256)
+    p = Peer(0, ingest, True, random.Random(7))
+    ring = trace.TraceRing(8)
+
+    def submit():
+        sub = trace.host_span('client.submit', accumulate=True)
+        with sub:
+            span = ring.start('GET_DATA', '/k')
+            span.stages = [sub.t0_ns, 0, 0, 0]
+            req = p.conn.request({'opcode': 'GET_DATA', 'path': '/k',
+                                  'watch': False}, span)
+        req.as_future()
+        return span, req
+
+    try:
+        (a, ra), (b, rb) = submit(), submit()
+        assert a.stages[trace.T_FLUSH] == 0 == b.stages[trace.T_FLUSH]
+        await settle()                      # the cork's tick flush
+        c_, rc = submit()
+        await settle()
+        fa, fb, fc = (s.stages[trace.T_FLUSH] for s in (a, b, c_))
+        assert a.stages[trace.T_SUBMIT] < b.stages[trace.T_SUBMIT] < fa
+        assert fa == fb < c_.stages[trace.T_SUBMIT] < fc
+        assert len(p.conn.transport.out) >= 2       # two writes went out
+        for req in (ra, rb, rc):
+            p.reply(req.packet['xid'])
+        wire, p.wire = bytes(p.wire), bytearray()
+        t_before = ingest.ticks
+        p.conn._sock_data(wire)             # one segment, three replies
+        await settle()
+        assert ingest.ticks == t_before + 1
+        rx = [s.stages[trace.T_RX] for s in (a, b, c_)]
+        assert rx[0] == rx[1] == rx[2] == p.conn._rx_t0 > fc
+        st = [s.stages[trace.T_SETTLE] for s in (a, b, c_)]
+        assert rx[0] < st[0] < st[1] < st[2]
+        assert {s.tick for s in (a, b, c_)} == {ingest.ticks}
+        assert ingest.routing is None       # only while a route runs
+        for span, req in ((a, ra), (b, rb), (c_, rc)):
+            assert req.fut.done() and span.status == 'ok'
+            trace.op_resumed(span).__exit__(None, None, None)
+        _check_stages([a, b, c_], 3)
+        assert trace.host_ring.totals['client.resume'][0] == 3
+    finally:
+        p.session.close()
+        p.conn.destroy()
+        await settle()
+        ingest.close()
+
+
+async def test_with_no_session_an_op_leaves_nothing(server):
+    """No profiler session: an op carries no stamps, the ring and its
+    totals stay empty, no collector hook records anything."""
+    import gc
+
+    ingest = _ingest()
+    c = await _fleet_client(server, ingest)
+    try:
+        await c.create('/n', b'x')
+        for _ in range(3):
+            await c.get('/n')
+        gc.collect()
+        assert all(s.stages is None and s.tick is None
+                   and s.t0_ns is None for s in c.trace.spans())
+        conn = c.current_connection()
+        assert conn._rx_t0 == 0 and conn._tx.stamps == []
+        assert len(trace.host_ring) == 0 and not trace.host_ring.totals
+        assert trace._gc_open is None
+    finally:
+        await c.close()
+
+
+def test_a_collection_is_booked_under_the_span_that_held_it(armed):
+    """Inside a session the collector's pauses are ``gc.pause`` totals,
+    and ``gc.pause@<name>`` for the innermost host span (accumulated
+    ones count) open on the thread when the collection began; the one
+    hook is the module's, installed once."""
+    import gc
+
+    gc.collect()
+    with trace.host_span('ingest.route', tick=1):
+        gc.collect()
+        with trace.host_span('client.notify', accumulate=True):
+            gc.collect()
+    gc.collect()                            # under no span
+    assert gc.callbacks.count(trace._gc_pause) == 1
+    totals = trace.host_ring.totals
+    n_route, ns_route = totals['gc.pause@ingest.route']
+    n_notify, ns_notify = totals['gc.pause@client.notify']
+    assert n_route >= 1 and n_notify >= 1 and ns_route > 0 < ns_notify
+    assert totals['gc.pause'][0] >= n_route + n_notify + 1
+    assert totals['gc.pause'][1] >= ns_route + ns_notify
+    (route,) = trace.host_ring.spans()
+    # the span held its pauses: subtracting them leaves its own work
+    assert ns_route + ns_notify < route.t1_ns - route.t0_ns
+    assert not [k for k in totals if k.startswith('gc.pause@')
+                and k.split('@')[1] not in ('ingest.route',
+                                            'client.notify')]
+
+
+def test_the_collector_hook_is_inert_outside_a_session(monkeypatch):
+    import gc
+
+    on = [True]
+    monkeypatch.setattr(trace, '_is_enabled', lambda: on[0])
+    with trace.host_span('a'):
+        pass                                # installs the hook
+    assert trace._gc_pause in gc.callbacks
+    # a session's pause total is there before its first collection
+    assert 'gc.pause' in trace.host_ring.totals
+    on[0] = False
+    before = dict(trace.host_ring.totals)
+    gc.collect()
+    assert trace.host_ring.totals == before and trace._gc_open is None
+    # a collection that began inside a session is closed whatever the
+    # session does meanwhile
+    on[0] = True
+    trace._gc_pause('start', {})
+    on[0] = False
+    trace._gc_pause('stop', {})
+    assert trace._gc_open is None
+    assert trace.host_ring.totals['gc.pause'][0] >= 1
+
+
+async def test_stages_and_pauses_under_a_real_session(server, tmp_path):
+    """Under a real profiler session (CPU backend): the stage waits are
+    booked by the session alone, and ``client.prepare``,
+    ``client.resume`` and ``gc.pause`` are annotations on the loop
+    thread's line of ``/host:CPU``, a pause inside the span that held
+    it."""
+    import gc
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    ingest = _ingest()
+    c = await _fleet_client(server, ingest)
+    try:
+        await c.create('/r', b'v' * 64)
+        await c.get('/r')                       # before: nothing
+        assert not trace.host_ring.totals
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            c.trace.clear()
+            for _ in range(3):
+                await c.get('/r')
+            with trace.host_span('client.notify', accumulate=True):
+                gc.collect()
+        finally:
+            jax.profiler.stop_trace()
+        await c.get('/r')                       # after: nothing more
+    finally:
+        await c.close()
+    _check_stages(c.trace.spans()[:3], 3)
+    assert c.trace.spans()[3].t0_ns is None
+    totals = trace.host_ring.totals
+    assert totals['client.prepare'][0] == totals['client.resume'][0] == 3
+    assert totals['gc.pause@client.notify'][0] >= 1
+    (path,) = glob.glob(str(tmp_path / '**' / '*.xplane.pb'),
+                        recursive=True)
+    names = ('client.prepare', 'client.resume', 'gc.pause',
+             'client.notify')
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name == '/host:CPU'
+              for line in plane.lines for e in line.events
+              if e.name in names]
+    assert {n for n, *_ in events} == set(names)
+    assert sum(n == 'client.prepare' for n, *_ in events) == 3
+    assert sum(n == 'client.resume' for n, *_ in events) == 3
+    (held,) = [e for e in events if e[0] == 'client.notify']
+    assert any(held[1] <= a <= b <= held[2]
+               for n, a, b in events if n == 'gc.pause')
 
 
 # -- the members: ledger phases, always-on histograms, mntr rows ---------

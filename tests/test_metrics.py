@@ -328,6 +328,54 @@ def test_tick_ledger_nested_phases_subtract():
     assert tick['total_ms'] - total < 1.0
 
 
+def test_tick_ledger_control_and_repl_ack_close_with_the_tick(
+        monkeypatch):
+    """The leader's two phases (server/replication.py): ``control``
+    around a control-channel message, ``repl_ack`` around a follower's
+    ack.  They close with the tick like every phase, and what nests
+    under them — a forwarded write's ``wal_append`` and ``repl_push``,
+    the ``cork_flush`` an ack's release makes — is subtracted, so each
+    keeps its subject."""
+    import types
+
+    from zkstream_tpu.utils import metrics
+    from zkstream_tpu.utils.metrics import TickLedger
+
+    assert TickLedger.PHASES[-2:] == ('control', 'repl_ack')
+    # the ledger's clock, scripted (seconds): control 0 .. 10 ms with
+    # wal_append 1 .. 3 and repl_push 4 .. 5 inside; then repl_ack
+    # 11 .. 12 with a released flush 11.25 .. 11.75 inside
+    clock = iter([0.0, 0.001, 0.003, 0.004, 0.005, 0.010,
+                  0.011, 0.01125, 0.01175, 0.012])
+    monkeypatch.setattr(metrics, 'time', types.SimpleNamespace(
+        perf_counter=lambda: next(clock)))
+    led = TickLedger()
+    led.enter('control')
+    led.enter('wal_append')
+    led.exit()
+    led.enter('repl_push')
+    led.exit()
+    led.exit()
+    led.close_tick()
+    assert led.ticks == 1                   # closed like any phase
+    led.enter('repl_ack')
+    led.enter('cork_flush')
+    led.exit()
+    led.exit()
+    led.close_tick()
+    assert led.ticks == 2 and not led._stack
+    rows = dict(led.phase_hist.rows())
+
+    def phase_sum(phase):
+        return rows['zk_tick_phase_ms_sum{phase="%s"}' % (phase,)]
+    assert phase_sum('control') == pytest.approx(7.0)
+    assert phase_sum('wal_append') == pytest.approx(2.0)
+    assert phase_sum('repl_push') == pytest.approx(1.0)
+    assert phase_sum('repl_ack') == pytest.approx(0.5)
+    assert phase_sum('cork_flush') == pytest.approx(0.5)
+    assert led.last_tick['total_ms'] == pytest.approx(1.0)
+
+
 def test_tick_ledger_phase_p99_and_scrape():
     from zkstream_tpu.utils.metrics import (
         METRIC_TICK,

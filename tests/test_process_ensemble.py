@@ -535,6 +535,48 @@ async def test_concurrent_writers_through_one_follower_share_rpcs(
         await r.close()
 
 
+async def test_leader_books_forwarded_writes_as_control_and_repl_ack(
+        process_ensemble):
+    """The leader of an OS-process ensemble serves forwarded writes on
+    its control channel and takes its followers' acks on their event
+    streams: both are ledger phases in its ``mntr`` (``control``,
+    ``repl_ack``) with the commits' ``repl_push`` nested — a member
+    that forwards has neither — and every member exports its process's
+    cumulative CPU (``zk_process_cpu_ms``), so phases over CPU can be
+    read from inside."""
+    leader, (f1, _f2) = process_ensemble
+    c = _client([('127.0.0.1', f1.ports[0])])
+    try:
+        await c.wait_connected(timeout=10)
+        await c.create('/cr', b'0')
+        before = await mntr_rows(leader.ports[0])
+        for i in range(40):
+            await c.set('/cr', b'v%d' % i)
+        after = await mntr_rows(leader.ports[0])
+        follower = await mntr_rows(f1.ports[0])
+    finally:
+        await c.close()
+
+    def delta(key):
+        return float(after[key]) - float(before.get(key, 0))
+
+    for phase in ('control', 'repl_ack', 'repl_push'):
+        assert delta('zk_tick_phase_ms_sum{phase="%s"}' % phase) > 0
+        assert delta('zk_tick_phase_ms_count{phase="%s"}' % phase) >= 1
+    # 40 serial round trips, each parked ~a quorum wait: the phase is
+    # the leader's own work, a small part of the elapsed time
+    assert delta('zk_tick_phase_ms_sum{phase="control"}') \
+        < 0.5 * delta('zk_uptime_ms')
+    assert delta('zk_process_cpu_ms') > 0
+    assert float(follower['zk_process_cpu_ms']) > 0
+    assert 'zk_tick_phase_ms_count{phase="control"}' not in follower
+    assert 'zk_tick_phase_ms_count{phase="repl_ack"}' not in follower
+    # the leader's phases over its CPU: what the ledger names
+    phases = sum(delta(k) for k in after
+                 if k.startswith('zk_tick_phase_ms_sum{'))
+    assert 0 < phases <= 1.5 * delta('zk_process_cpu_ms') + 5
+
+
 async def test_leader_sigkill_with_batches_in_flight_loses_no_ack(
         process_ensemble):
     """SIGKILL the leader under 16 closed-loop writers on one

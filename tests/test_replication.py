@@ -452,6 +452,76 @@ async def test_observer_batch_waits_for_real_voter_acks(event_loop):
         await svc.stop()
 
 
+async def test_control_phase_is_not_open_across_the_quorum_wait(
+        event_loop):
+    """The leader's service of a control-channel message is ledger
+    phase ``control`` (server/replication.py ``_serve_control``) — up
+    to the quorum wait and again after it, never across it: a batch
+    parked behind a slow follower books no ``control`` time, and the
+    ledger's stack is empty whenever the loop is given back.  The
+    writes' ``repl_push`` nests under it; a follower's ack is phase
+    ``repl_ack``."""
+    import time
+
+    from zkstream_tpu.utils.metrics import TickLedger
+
+    db = ZKDatabase()
+    led = db.ledger = TickLedger()
+    svc = await ReplicationService(db, total=3, quorum=True).start()
+    svc.quorum.wait_ms = 120.0
+    obs = await RemoteLeader('127.0.0.1', svc.port,
+                             observer=True).connect()
+    voter = await RemoteLeader('127.0.0.1', svc.port).connect()
+    open_at_wait = []
+    wait = svc.quorum.wait
+
+    async def spy_wait(target, timeout_s=None, grant=None):
+        open_at_wait.append(list(led._stack))
+        return await wait(target, timeout_s, grant=grant)
+    svc.quorum.wait = spy_wait
+
+    def phase_ms(phase):
+        return dict(led.phase_hist.rows()).get(
+            'zk_tick_phase_ms_sum{phase="%s"}' % (phase,), 0.0)
+
+    try:
+        db.create('/c', b'0', OPEN_ACL_UNSAFE, CreateFlag(0))
+        await asyncio.sleep(0.05)       # both mirrors acked the create
+        assert phase_ms('repl_ack') > 0         # the voter's ack
+        # no voter left: the observer's batch parks for the whole
+        # bounded wait (its own mirror never counts) and degrades
+        voter.close()
+        for _ in range(50):
+            if len(svc._handles) == 1:
+                break
+            await asyncio.sleep(0.02)
+        before = phase_ms('control')
+        t0 = time.perf_counter()
+        results = await _rpc(obs.forward,
+                             [_set('/c', b'1'), _set('/c', b'2')])
+        parked_ms = (time.perf_counter() - t0) * 1e3
+        await asyncio.sleep(0.02)       # the tick closes
+        assert [s for s, _ in results] == ['ok', 'ok']
+        assert svc.quorum.degraded_releases == 1
+        assert open_at_wait == [[]]     # nothing open when it parked
+        assert parked_ms >= 100
+        booked = phase_ms('control') - before
+        assert 0 < booked < 0.25 * parked_ms
+        assert phase_ms('repl_push') > 0 and not led._stack
+        # a read-side RPC (no wait at all) is control time too
+        before = phase_ms('control')
+        await _rpc(obs.sync_barrier)
+        await asyncio.sleep(0.02)
+        assert phase_ms('control') > before
+        assert set(dict(led.phase_hist.rows())) >= {
+            'zk_tick_phase_ms_count{phase="control"}',
+            'zk_tick_phase_ms_count{phase="repl_ack"}'}
+    finally:
+        obs.close()
+        voter.close()
+        await svc.stop()
+
+
 async def test_leader_lost_with_a_batch_in_flight_loses_every_element(
         event_loop):
     """The control channel dies while the leader holds the batch (here:

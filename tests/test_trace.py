@@ -228,6 +228,34 @@ def test_span_to_dict_is_stable_ordered():
     assert TRACE_SCHEMA == 3
 
 
+def test_one_tuple_of_optional_fields_drives_the_span():
+    """``_OPTIONAL_FIELDS`` is the ONE list: every field in it reads
+    None on a fresh span however it was made (``start``: ``__init__``;
+    ``note``: the hand-built instant span), is emitted by ``to_dict``
+    in the tuple's order once set, and a field added to the tuple
+    needs no other edit."""
+    from zkstream_tpu.utils import trace
+
+    ring = TraceRing(member='3')
+    started, noted = ring.start('GET_DATA'), ring.note('COMMIT')
+    for f in trace._OPTIONAL_FIELDS:
+        if f != 'member':
+            assert getattr(started, f) is None, f
+            assert getattr(noted, f) is None, f
+        assert f in vars(trace.Span) or f in ('path',)
+    assert started.stages is None and noted.stages is None
+    assert started.duration_ms is None and noted.duration_ms == 0.0
+    for i, f in enumerate(trace._OPTIONAL_FIELDS):
+        setattr(started, f, i)
+    d = started.to_dict()
+    assert [k for k in d if k in trace._OPTIONAL_FIELDS] == list(
+        trace._OPTIONAL_FIELDS)
+    # the stage stamps are the op's own business: never serialized
+    started.stages = [1, 2, 3, 4]
+    assert 'stages' not in started.to_dict()
+    assert TRACE_SCHEMA == 3        # no key was added to to_dict
+
+
 def test_ring_counts_dropped_overwrites():
     ring = TraceRing(capacity=4)
     for i in range(4):

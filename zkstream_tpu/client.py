@@ -50,7 +50,7 @@ from .utils.aio import DeadlineExpired, ambient_loop, deadline_queue
 from .utils.fsm import FSM, bind_transition_metrics
 from .utils.logging import Logger
 from .utils.metrics import Collector
-from .utils.trace import TraceRing, host_span
+from .utils.trace import NO_SPAN, TraceRing, host_span, op_resumed
 
 METRIC_ZK_EVENT_COUNTER = 'zookeeper_events'
 METRIC_ZK_DEGRADED_GAUGE = 'zookeeper_degraded'
@@ -556,11 +556,18 @@ class Client(FSM):
 
         Host span ``client.submit`` (profiler sessions only; count
         and total, no object per op): from here until the encoded
-        request is with the connection's send plane."""
-        with host_span('client.submit', accumulate=True):
+        request is with the connection's send plane.  Its one
+        ``is_enabled()`` is the op's answer for everything after: an
+        op submitted inside a session carries ``span.stages``, the
+        stamps of its way out and back (utils/trace.py), and nothing
+        else looks the session up for it until it resumes."""
+        sub = host_span('client.submit', accumulate=True)
+        with sub:
             span = self.trace.start(pkt['opcode'], pkt.get('path'))
+            if sub is not NO_SPAN:
+                span.stages = [sub.t0_ns, 0, 0, 0]
             try:
-                req = conn.request(pkt)
+                req = conn.request(pkt, span)
             except BaseException as e:
                 span.finish(status='abandoned',
                             error=getattr(e, 'code', None)
@@ -569,12 +576,10 @@ class Client(FSM):
         span.xid = pkt['xid']
         span.backend = conn.backend.key
         if conn.session is not None:
-            # the request is already pending here, so the connection
-            # settles this span on every teardown path; the getter
-            # below cannot raise past it
-            # zkanalyze: ignore[span-leak] plain getter; req pending
+            # the request is already pending here — it took the span
+            # with it — so the connection settles this span on every
+            # teardown path
             span.session_id = conn.session.get_session_id()
-        req.span = span
         return req.as_future(), span
 
     async def _await_op(self, fut: asyncio.Future, opcode: str,
@@ -593,7 +598,10 @@ class Client(FSM):
         exactly once internally (a late reply is dropped).
 
         Every completion path (reply, error, deadline) records the
-        elapsed time into the per-op latency histogram."""
+        elapsed time into the per-op latency histogram.  For an op
+        submitted inside a profiler session (``span.stages``) the
+        ``finally`` is where its four stage waits are booked, and it
+        is host span ``client.resume``."""
         ms = self.op_timeout if deadline is _USE_DEFAULT else deadline
         t0 = time.monotonic()
         entry = None
@@ -608,12 +616,18 @@ class Client(FSM):
                             error='DEADLINE_EXCEEDED')
             raise ZKDeadlineError(opcode, path, ms) from None
         finally:
-            if entry is not None:
-                queue.discard(entry)
-            self._op_latency.observe(
-                (time.monotonic() - t0) * 1000.0, {'op': opcode})
-            if self.on_op is not None and span is not None:
-                self.on_op(span)
+            resume = (None if span is None or span.stages is None
+                      else op_resumed(span))
+            try:
+                if entry is not None:
+                    queue.discard(entry)
+                self._op_latency.observe(
+                    (time.monotonic() - t0) * 1000.0, {'op': opcode})
+                if self.on_op is not None and span is not None:
+                    self.on_op(span)
+            finally:
+                if resume is not None:
+                    resume.__exit__(None, None, None)
 
     # -- the read plane (README "Read plane") --
 
@@ -629,10 +643,26 @@ class Client(FSM):
         return max(sess_z, self._read_floor)
 
     async def _primary_request(self, pkt: dict, opcode: str,
-                               path: str | None, deadline) -> dict:
+                               path: str | None, deadline,
+                               prep=None) -> dict:
         """One request on the primary connection (the legacy path):
-        returns the full reply packet."""
-        conn = self._conn_or_raise()
+        returns the full reply packet.
+
+        Host span ``client.prepare`` (profiler sessions only; count
+        and total): an API call's own work before ``_start_op`` —
+        opened here, or by ``_read_request`` in front of its cache and
+        read-plane routing and handed on still open (``prep``:
+        nothing awaits in between), closed once the connection is
+        looked up."""
+        if prep is None:
+            prep = host_span('client.prepare', accumulate=True)
+            if prep is not NO_SPAN:
+                prep.__enter__()
+        try:
+            conn = self._conn_or_raise()
+        finally:
+            if prep is not NO_SPAN:
+                prep.__exit__(None, None, None)
         fut, span = self._start_op(conn, pkt)
         return await self._await_op(fut, opcode, path, deadline, span)
 
@@ -702,6 +732,12 @@ class Client(FSM):
         a read under a subscribed, coherent subtree returns locally —
         no wire round trip at all — and every server reply that does
         go out deposits back in, read-through."""
+        # host span ``client.prepare``: from here to the first await
+        # or return — on the usual way that is ``_primary_request``,
+        # which takes the span over and closes it
+        prep = host_span('client.prepare', accumulate=True)
+        if prep is not NO_SPAN:
+            prep.__enter__()
         cache = self.cache
         if cache is not None and path is not None:
             out = cache.lookup(opcode, path)
@@ -714,6 +750,8 @@ class Client(FSM):
                 span.finish(zxid=out.get('zxid'))
                 if self.on_op is not None:
                     self.on_op(span)
+                if prep is not NO_SPAN:
+                    prep.__exit__(None, None, None)
                 return out
         plane = self._read_plane
         if plane is not None and plane.started:
@@ -721,6 +759,10 @@ class Client(FSM):
             sub = plane.pick(primary.key if primary is not None
                              else None)
             if sub is not None:
+                # about to await: the primary fallback opens its own
+                if prep is not NO_SPAN:
+                    prep.__exit__(None, None, None)
+                prep = None
                 try:
                     out = await sub._primary_request(
                         dict(pkt), opcode, path, deadline)
@@ -745,7 +787,8 @@ class Client(FSM):
                         self._note_read_floor(out['zxid'])
                         return out
                     plane.bounced += 1   # stale member: never surface
-        out = await self._primary_request(pkt, opcode, path, deadline)
+        out = await self._primary_request(pkt, opcode, path, deadline,
+                                          prep)
         if plane is not None \
                 and out.get('zxid', 0) < self._read_floor \
                 and path is not None:

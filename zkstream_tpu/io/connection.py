@@ -29,7 +29,7 @@ from ..utils.aio import set_nodelay
 from ..utils.events import EventEmitter
 from ..utils.fsm import FSM
 from ..utils.logging import Logger
-from ..utils.trace import host_span
+from ..utils.trace import NO_SPAN, host_span, stamp_reply
 from .sendplane import SendPlane
 
 METRIC_ZK_CONNECT_LATENCY = 'zookeeper_connect_latency_ms'
@@ -85,16 +85,21 @@ class ZKRequest(EventEmitter):
             self.fut = asyncio.get_running_loop().create_future()
         return self.fut
 
-    def settle(self, pkt: dict) -> bool:
+    def settle(self, pkt: dict, rx: tuple | None = None) -> bool:
         """The reply has arrived: close the span, stamped with the
         reply zxid, then resolve the request with the packet or fail
         it with the typed error it carries — the one settle of
         :meth:`ZKConnection.process_reply` and of the direct lane
-        (``state_connected``).  True when listeners heard it: their
-        callbacks ran inside this call and may have moved any state
-        machine."""
+        (``state_connected``).  ``rx`` (profiler sessions only, else
+        None): :meth:`ZKConnection.rx_mark`, for the stage stamps of
+        an op that was submitted inside the session.  True when
+        listeners heard it: their callbacks ran inside this call and
+        may have moved any state machine."""
         code = pkt['err']
         span = self.span
+        if (rx is not None and span is not None
+                and span.stages is not None):
+            stamp_reply(span, rx)
         if code != 'OK':
             if span is not None:
                 span.finish(zxid=pkt.get('zxid'), status='error',
@@ -194,6 +199,10 @@ class ZKConnection(FSM):
         #: 'connecting' (or on promote for a parked spare), observed
         #: into the histogram on reaching 'connected'.
         self._connect_t0: float | None = None
+        #: Profiler sessions only, else 0: the start of the newest
+        #: ``_sock_data`` call on ``time.perf_counter_ns``
+        #: (:meth:`rx_mark`).
+        self._rx_t0 = 0
         #: Outbound cork (io/sendplane.py): every encoded frame goes
         #: through it; frames of one event-loop tick leave as a single
         #: transport.write — or, when the client carries a batched
@@ -448,6 +457,7 @@ class ZKConnection(FSM):
                 session.reset_expiry_timer(now)
                 log_trace = (self.log.trace
                              if self.log.enabled_for_trace() else None)
+                rx = self.rx_mark() if self._rx_t0 else None
                 n = 0
                 for pkt in pkts:
                     xid = pkt['xid']
@@ -461,7 +471,7 @@ class ZKConnection(FSM):
                     if log_trace is not None:
                         log_trace('server replied to xid %d err %s',
                                   xid, pkt['err'])
-                    if (req is not None and req.settle(pkt)
+                    if (req is not None and req.settle(pkt, rx)
                             and not lane_open()):
                         break
                 if n < len(pkts) or err is not None:
@@ -649,11 +659,30 @@ class ZKConnection(FSM):
         the ingest's batch regime that ends with them in its slot
         (``FleetIngest.feed``); in the pass-through regime, and with
         no ingest, the decode and delivery are inside it too."""
-        with host_span('client.rx', accumulate=True):
+        sp = host_span('client.rx', accumulate=True)
+        if sp is not NO_SPAN:
+            # a request's stage stamp ``t_rx`` (utils/trace.py): the
+            # start of the call that brought the newest bytes
+            self._rx_t0 = time.perf_counter_ns()
+        elif self._rx_t0:
+            self._rx_t0 = 0
+        with sp:
             if self.faults is None:
                 self.emit('sockData', data)
             else:
                 self.faults.rx(self, data)
+
+    def rx_mark(self) -> tuple:
+        """What a reply settled now knows of its way in (profiler
+        sessions only; ``ZKRequest.settle``'s ``rx``): the start of
+        this connection's newest ``_sock_data`` call — the call that
+        completed the reply of a connection with one request in
+        flight; on a pipelined connection whose replies came in
+        several calls before one tick, an earlier reply reads the
+        later call — and the number of the ``ingest.tick`` whose route
+        is delivering it, None off the device."""
+        ing = self.ingest
+        return self._rx_t0, None if ing is None else ing.routing
 
     def _tx_write(self, data: bytes) -> None:
         """The send plane's sink: one coalesced buffer per flush."""
@@ -697,19 +726,26 @@ class ZKConnection(FSM):
         self.log.trace('server replied to xid %d err %s',
                        xid, pkt['err'])
         if req is not None:
-            req.settle(pkt)
+            req.settle(pkt, self.rx_mark() if self._rx_t0 else None)
 
-    def request(self, pkt: dict) -> ZKRequest:
+    def request(self, pkt: dict, span=None) -> ZKRequest:
         """Send a normal (positive-xid) request
-        (reference: lib/connection-fsm.js:384-408)."""
+        (reference: lib/connection-fsm.js:384-408).  ``span``: the
+        op's trace span (``Client._start_op``), which the reply/error
+        routing closes; one that carries ``stages`` (a profiler
+        session) is stamped by the flush that takes these bytes."""
         if not self.is_in_state('connected'):
             raise ZKProtocolError('CONNECTION_LOSS',
                 'Client must be connected to send requests')
         req = ZKRequest(pkt)
+        req.span = span
         pkt['xid'] = self.next_xid()
         self.reqs[pkt['xid']] = req
         self.log.trace('sent request xid %d opcode %s',
                        pkt['xid'], pkt['opcode'])
+        if span is not None and span.stages is not None:
+            # before the write: a disabled cork flushes inside it
+            self._tx.stamps.append(span.stages)
         self._write(pkt)
         return req
 

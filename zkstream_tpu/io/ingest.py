@@ -306,6 +306,13 @@ class FleetIngest:
         self.ticks = 0
         self.ticks_scalar = 0
         self.ticks_warming = 0
+        #: While a device tick's route runs, its number (the ``tick``
+        #: of its ``ingest.tick`` host span and that span's four
+        #: phases); None otherwise.  A profiler session stamps it on
+        #: the span of every op the route settles
+        #: (``ZKConnection.rx_mark``): what joins an op to the tick
+        #: that delivered it.
+        self.routing: int | None = None
         #: Batched-drain latency distribution: wall time of each tick
         #: that routed work (device dispatch or scalar drain), ms.
         #: Standalone until bind_metrics() swaps in a collector-
@@ -1358,15 +1365,19 @@ class FleetIngest:
         t3 = time.perf_counter()
         with host_span('ingest.route', tick=n) as rsp:
             laned = emitted = 0
-            for plan, (ints, byts) in zip(plans, results):
-                streams, lens = plan[2], plan[4]
-                st, bd = self._unpack(ints, byts)
-                B = len(streams)
-                self.bytes_recopied += int(
-                    np.where(st.bad[:B], 0, lens[:B] - st.resid[:B]).sum())
-                a, b = self._route_batch(streams, None, st, bd)
-                laned += a
-                emitted += b
+            self.routing = n
+            try:
+                for plan, (ints, byts) in zip(plans, results):
+                    streams, lens = plan[2], plan[4]
+                    st, bd = self._unpack(ints, byts)
+                    B = len(streams)
+                    self.bytes_recopied += int(np.where(
+                        st.bad[:B], 0, lens[:B] - st.resid[:B]).sum())
+                    a, b = self._route_batch(streams, None, st, bd)
+                    laned += a
+                    emitted += b
+            finally:
+                self.routing = None
             rsp.set(lane=laned, emitted=emitted)
         t4 = time.perf_counter()
         observe = self.phase_hist.observe

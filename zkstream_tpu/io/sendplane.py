@@ -65,6 +65,7 @@ from __future__ import annotations
 import os
 
 from ..utils.aio import ambient_loop
+from ..utils.trace import stamp_flush
 from .transport import METRIC_FLUSH_SYSCALLS
 
 METRIC_FLUSH_FRAMES = 'zookeeper_flush_batch_frames'
@@ -108,7 +109,7 @@ class SendPlane:
     __slots__ = ('_write', '_chunks', '_pending', '_scheduled',
                  'enabled', 'max_bytes', '_frames_hist', '_bytes_hist',
                  '_labels', '_barrier', '_ledger', '_tier', '_entry',
-                 '_syscall_ctr', '_transport_fn')
+                 '_syscall_ctr', '_transport_fn', 'stamps')
 
     def __init__(self, write, *, enabled: bool | None = None,
                  max_bytes: int | None = None,
@@ -147,6 +148,11 @@ class SendPlane:
         self._barrier = barrier
         self._chunks: list[bytes] = []
         self._pending = 0
+        #: Profiler sessions only (else empty): the stage stamps of
+        #: the requests corked since the last flush
+        #: (utils/trace.py ``Span.stages``) — the flush that takes
+        #: their bytes stamps them: here, or the tier's submission.
+        self.stamps: list = []
         self._scheduled = False
         self.enabled = cork_default() if enabled is None else enabled
         self.max_bytes = (flush_cap_default() if max_bytes is None
@@ -202,6 +208,8 @@ class SendPlane:
             if self._barrier is None:
                 self._observe(1, len(data))
                 self._count_legacy()
+                if self.stamps:
+                    stamp_flush(self.stamps)
                 self._write(data)
                 return
             # write-through still rides the gate: the frame corks for
@@ -307,10 +315,16 @@ class SendPlane:
             # syscall chain covering every dirty connection); the
             # tier accounts the syscalls and the ledger's cork_flush.
             # A hard flush drains this entry synchronously instead.
+            if self.stamps:
+                # the submission that takes the entry's chunks stamps
+                entry.stamps.extend(self.stamps)
+                self.stamps.clear()
             self._tier.enqueue(entry, chunks, size)
             if hard:
                 self._tier.drain(entry)
             return
+        if self.stamps:
+            stamp_flush(self.stamps)
         self._count_legacy()
         led = self._ledger
         data = chunks[0] if n == 1 else b''.join(chunks)
@@ -336,6 +350,7 @@ class SendPlane:
         the transport tier goes with them."""
         self._chunks = []
         self._pending = 0
+        self.stamps = []
         if self._entry is not None:
             self._tier.discard(self._entry)
 

@@ -113,7 +113,7 @@ from time import perf_counter_ns
 
 from ..utils.aio import ambient_loop
 from ..utils.metrics import Collector
-from ..utils.trace import host_add, host_span
+from ..utils.trace import NO_SPAN, host_add, host_span, stamp_flush
 
 log = logging.getLogger('zkstream_tpu.transport')
 
@@ -278,7 +278,7 @@ class _Entry:
     transport object."""
 
     __slots__ = ('transport_fn', 'write', 'chunks', 'nbytes',
-                 'batch', 'flying', '_t', '_fd')
+                 'batch', 'flying', 'stamps', '_t', '_fd')
 
     def __init__(self, write, transport_fn):
         self.write = write              # the plane's asyncio sink
@@ -290,6 +290,9 @@ class _Entry:
         #: batch is reaped nothing more of the connection is submitted.
         self.batch = 0
         self.flying = 0
+        #: Profiler sessions only (else empty): the stage stamps of
+        #: the requests whose bytes are in ``chunks`` (SendPlane.stamps)
+        self.stamps: list = []
         self._t = None
         self._fd = -1
 
@@ -311,6 +314,7 @@ class _Entry:
         chunks = self.chunks
         self.chunks = []
         self.nbytes = 0
+        self.stamps = []
         return chunks
 
 
@@ -356,6 +360,10 @@ class TransportTier:
         self._ext = None
         self._reader_loop = None
         self._inflight: dict[int, list] = {}
+        #: Profiler sessions only, inside :meth:`_tick` (else 0): when
+        #: this tick's flush began — the ``t_flush`` of the requests
+        #: whose bytes it submits or hands over (utils/trace.py).
+        self._flush_t0 = 0
         self.syscalls = 0        # lifetime submissions (tests/mntr)
         self.submissions = 0     # batched submit rounds
         #: connection flushes submitted raw, those of them the kernel
@@ -518,7 +526,10 @@ class TransportTier:
         shared callback must be no weaker — errors are logged per
         flush, and the submission + schedule-slot release always
         run."""
-        with host_span(self._span, accumulate=True):
+        sp = host_span(self._span, accumulate=True)
+        with sp:
+            if sp is not NO_SPAN:
+                self._flush_t0 = sp.t0_ns
             if self._inflight:
                 # whatever the sender finished meanwhile: its entries
                 # may be in this tick's dirty set
@@ -534,6 +545,7 @@ class TransportTier:
                 self._scheduled_on = None
                 dirty, self._dirty = self._dirty, []
                 self._submit(dirty)
+                self._flush_t0 = 0
 
     def _count(self, n: int, backend: str) -> None:
         self.syscalls += n
@@ -566,6 +578,9 @@ class TransportTier:
             nbytes = e.nbytes
             e.chunks = []
             e.nbytes = 0
+            if e.stamps:
+                # a tick's flush stamps its start; a hard drain, now
+                stamp_flush(e.stamps, self._flush_t0)
             fd = -1
             t = e.transport_fn()
             if t is not None:

@@ -142,10 +142,16 @@ def _dump(msg) -> bytes:
     return _LEN.pack(len(payload)) + payload
 
 
-async def _read_msg(reader: asyncio.StreamReader):
+async def _read_frame(reader: asyncio.StreamReader) -> bytes:
+    """One message's pickled bytes: the wait is here, the unpickling
+    is the caller's — inside its ledger phase, where it has one."""
     hdr = await reader.readexactly(4)
     (n,) = _LEN.unpack(hdr)
-    return pickle.loads(await reader.readexactly(n))
+    return await reader.readexactly(n)
+
+
+async def _read_msg(reader: asyncio.StreamReader):
+    return pickle.loads(await _read_frame(reader))
 
 
 def _recv_exact(sock: socket.socket, n: int) -> bytearray:
@@ -839,22 +845,24 @@ class ReplicationService:
                            self.db.config_snapshot()))
             # ship anything committed before this follower connected
             self._push_commits()
+            led = getattr(self.db, 'ledger', None)
             try:
                 # the follower acks mirrored indices on this channel;
                 # acks are what advance the truncation floor, and the
                 # piggybacked (applied_zxid, epoch) pair is what
-                # advances the quorum-commit floor
+                # advances the quorum-commit floor.  Tick phase
+                # ``repl_ack``: an ack from its bytes in hand through
+                # the quorum floor's advance and the releases it makes
+                # (a released send plane's flush nests, as everywhere).
                 while True:
-                    msg = await _read_msg(reader)
-                    if msg[0] == 'ack':
-                        h.applied = max(h.applied, msg[1])
-                        if len(msg) > 2 and not h.observer:
-                            # observer acks advance the truncation
-                            # floor (h.applied above) but never the
-                            # quorum-commit majority
-                            self.quorum.note_ack(
-                                h.token, msg[2],
-                                msg[3] if len(msg) > 3 else None)
+                    raw = await _read_frame(reader)
+                    if led is not None:
+                        led.enter('repl_ack')
+                    try:
+                        self._note_ack(h, pickle.loads(raw))
+                    finally:
+                        if led is not None:
+                            led.exit()
             except (asyncio.IncompleteReadError, ConnectionError):
                 pass                         # EOF = follower died
             finally:
@@ -864,6 +872,16 @@ class ReplicationService:
                                       is_observer=is_observer)
         else:  # pragma: no cover - only this module speaks the protocol
             writer.close()
+
+    def _note_ack(self, h: _FollowerHandle, msg) -> None:
+        if msg[0] == 'ack':
+            h.applied = max(h.applied, msg[1])
+            if len(msg) > 2 and not h.observer:
+                # observer acks advance the truncation floor
+                # (h.applied above) but never the quorum-commit
+                # majority
+                self.quorum.note_ack(
+                    h.token, msg[2], msg[3] if len(msg) > 3 else None)
 
     def _detach(self, h: _FollowerHandle) -> None:
         self._handles.pop(h.token, None)
@@ -879,60 +897,100 @@ class ReplicationService:
                              writer: asyncio.StreamWriter,
                              token: str | None = None,
                              is_observer: bool = False) -> None:
-        db = self.db
+        """One follower's control channel.  Tick phase ``control`` on
+        the database's ledger: a message from its bytes in hand —
+        unpickling, the applies (``wal_append`` / ``repl_push`` /
+        ``fsync_gate`` nest and are subtracted), the barrier — up to
+        the quorum wait, and again from the wait's return through the
+        response's piggyback, pickle and write.  Never across the
+        wait: the ledger is a stack, and a parked batch costs the loop
+        nothing."""
+        led = getattr(self.db, 'ledger', None)
         try:
             while True:
-                msg = await _read_msg(reader)
-                op = msg[0]
-                if op == 'touch':
-                    sess = db.sessions.get(msg[1])
-                    if sess is not None and not sess.expired \
-                            and not sess.closed:
-                        db.touch_session(sess)
+                raw = await _read_frame(reader)
+                if led is not None:
+                    led.enter('control')
+                try:
+                    res = self._control_msg(pickle.loads(raw), token,
+                                            is_observer)
+                finally:
+                    if led is not None:
+                        led.exit()
+                if res is None:
                     continue
-                assert op == 'rpc', op
-                _, seq, method, args, have = msg[:5]
-                rpc_epoch = msg[5] if len(msg) > 5 else None
-                if rpc_epoch is not None and rpc_epoch > self.epoch:
-                    # the caller has seen a newer leader than this
-                    # service: it IS deposed, whatever it believed
-                    self.depose(rpc_epoch)
-                if method == 'batch':
-                    status, payload = 'ok', await self._apply_batch(
-                        args[0],
-                        fenced=self.deposed or (
-                            rpc_epoch is not None
-                            and rpc_epoch < self.epoch),
-                        grant=None if is_observer else token)
-                else:
-                    status, payload = self._dispatch(method, args,
-                                                     write=False)
-                    if db.wal is not None:
-                        # logged-before-ack across processes too (a
-                        # session record is a WAL record)
-                        db.wal.sync_for_flush()
-                base, entries = self._entries_from(have)
-                writer.write(_dump(
-                    ('res', seq, status, payload, base, entries,
-                     self.epoch)))
+                seq, status, payload, have, wait = res
+                if wait is not None:
+                    await self.quorum.wait(wait[0], grant=wait[1])
+                if led is not None:
+                    led.enter('control')
+                try:
+                    base, entries = self._entries_from(have)
+                    writer.write(_dump(
+                        ('res', seq, status, payload, base, entries,
+                         self.epoch)))
+                finally:
+                    if led is not None:
+                        led.exit()
         except (asyncio.IncompleteReadError, ConnectionError):
             pass
         finally:
             writer.close()
 
-    async def _apply_batch(self, ops: list, *, fenced: bool,
-                           grant) -> list:
+    def _control_msg(self, msg, token, is_observer: bool):
+        """Serve one control-channel message up to its quorum wait:
+        None for one that has no response (``touch``), else ``(seq,
+        status, payload, have, wait)`` — ``wait`` the arguments of the
+        ``quorum.wait`` the response must stand behind, or None."""
+        db = self.db
+        op = msg[0]
+        if op == 'touch':
+            sess = db.sessions.get(msg[1])
+            if sess is not None and not sess.expired \
+                    and not sess.closed:
+                db.touch_session(sess)
+            return None
+        assert op == 'rpc', op
+        _, seq, method, args, have = msg[:5]
+        rpc_epoch = msg[5] if len(msg) > 5 else None
+        if rpc_epoch is not None and rpc_epoch > self.epoch:
+            # the caller has seen a newer leader than this
+            # service: it IS deposed, whatever it believed
+            self.depose(rpc_epoch)
+        wait = None
+        if method == 'batch':
+            status = 'ok'
+            payload, wait = self._apply_batch(
+                args[0],
+                fenced=self.deposed or (
+                    rpc_epoch is not None
+                    and rpc_epoch < self.epoch),
+                grant=None if is_observer else token)
+        else:
+            status, payload = self._dispatch(method, args,
+                                             write=False)
+            if db.wal is not None:
+                # logged-before-ack across processes too (a
+                # session record is a WAL record)
+                db.wal.sync_for_flush()
+        return seq, status, payload, have, wait
+
+    def _apply_batch(self, ops: list, *, fenced: bool,
+                     grant) -> tuple[list, tuple | None]:
         """The writes one follower collected in one turn of its loop
         (module docstring, ``batch``): applied in order, each element
         with its own ``(status, payload)``; made durable ONCE; the
-        quorum awaited ONCE, at the batch's last zxid.  The response
-        is every element's ack, so it leaves only behind both."""
+        quorum awaited ONCE, at the batch's last zxid — by the caller,
+        outside its ledger phase: the second element is that wait's
+        ``(zxid, grant)``, None when there is nothing to wait for.  The
+        response is every element's ack, so it leaves only behind
+        both."""
         if fenced:
             # epoch fence: a deposed leader must not apply — or ack —
             # a forwarded write, and a stale-epoch follower's writes
             # bounce until it rejoins the current epoch.  Typed, never
             # silent, and nothing of the batch is applied.
-            return [('err', 'EPOCH_FENCED')] * len(ops)
+            return [('err', 'EPOCH_FENCED')] * len(ops), None
         db = self.db
         pre_zxid = db.zxid
         results = [self._dispatch(method, args, write=True)
@@ -942,23 +1000,22 @@ class ReplicationService:
             # the ack of every record in the batch, and one barrier
             # covers them all (the leader's own tick does the same)
             db.wal.sync_for_flush()
-        if db.zxid > pre_zxid:
-            # the zxid guard skips a batch that committed nothing
-            # (every element failed; a rejected multi reports per-op
-            # errors under status 'ok'; a check-only multi consumes no
-            # zxid) — it must not stall on unrelated in-flight writes'
-            # quorum.  Quorum-before-ack: the response leaves only
-            # once a majority holds the batch's LAST txn, hence every
-            # one before it.  The CALLING follower's vote is granted
-            # virtually — this very response's piggyback delivers the
-            # txns into its mirror before a client can see an ack (its
-            # loop is parked in the blocking RPC, so awaiting its real
-            # ack would deadlock).  An OBSERVER caller gets no virtual
-            # grant: its mirror is outside the voter set, so the
-            # majority must assemble from real voter acks alone.
-            # Bounded: degrades like the send-plane gate.
-            await self.quorum.wait(db.zxid, grant=grant)
-        return results
+        if db.zxid <= pre_zxid:
+            # a batch that committed nothing (every element failed; a
+            # rejected multi reports per-op errors under status 'ok';
+            # a check-only multi consumes no zxid) must not stall on
+            # unrelated in-flight writes' quorum
+            return results, None
+        # Quorum-before-ack: the response leaves only once a majority
+        # holds the batch's LAST txn, hence every one before it.  The
+        # CALLING follower's vote is granted virtually — this very
+        # response's piggyback delivers the txns into its mirror
+        # before a client can see an ack (its loop is parked in the
+        # blocking RPC, so awaiting its real ack would deadlock).  An
+        # OBSERVER caller gets no virtual grant: its mirror is outside
+        # the voter set, so the majority must assemble from real voter
+        # acks alone.  Bounded: degrades like the send-plane gate.
+        return results, (db.zxid, grant)
 
     def _dispatch(self, method: str, args: tuple, *, write: bool):
         """One RPC against the database.  ``write`` says where it

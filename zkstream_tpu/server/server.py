@@ -1996,6 +1996,12 @@ class ZKServer:
             ('zk_version', 'zkstream_tpu'),
             ('zk_uptime_ms',
              int((time.monotonic() - self._started_at) * 1000)),
+            # cumulative CPU of this member's PROCESS, every thread
+            # (an in-process ensemble's members all read the one
+            # process): the ledger's phase sums over its delta is how
+            # much of the CPU the phases name
+            ('zk_process_cpu_ms',
+             round(time.process_time() * 1000.0, 3)),
             ('zk_server_state', self.mode()),
             ('zk_member_role', self.role),
             ('zk_epoch', self.current_epoch()),

@@ -352,9 +352,18 @@ class TickLedger:
       ``repl_push`` — a commit's pushes to the mirrors, frame + send
       (server/replication.py ``_push_commits``).  All three nest
       under whatever phase holds that time (``decode_apply`` for a
-      client's write; none for a write the control channel applied,
-      whose service has no phase of its own), so the parents keep
-      their subject and the three say what a large record costs.
+      client's write, ``control`` for one the control channel
+      applied), so the parents keep their subject and the three say
+      what a large record costs;
+    - ``control`` — the leader's service of a follower's control
+      channel (server/replication.py ``_serve_control``): a message
+      from its bytes in hand — unpickling, a forwarded batch's
+      applies and its one barrier — up to the quorum wait, and from
+      the wait's return through the response's piggybacked entries,
+      pickle and write; never the wait itself;
+    - ``repl_ack`` — a follower-stream ack at the leader, from its
+      bytes in hand through the quorum floor's advance and the
+      releases it makes (the flushes it releases nest under it).
 
     A "tick" here is the whole burst: asyncio runs ``call_soon``
     callbacks scheduled during a callback in the *next* loop
@@ -371,7 +380,7 @@ class TickLedger:
 
     PHASES = ('rx_drain', 'decode_apply', 'fsync_gate', 'cork_flush',
               'fanout_flush', 'forward_rpc', 'wal_append', 'wal_roll',
-              'repl_push')
+              'repl_push', 'control', 'repl_ack')
 
     #: Close a still-active burst after this many loop iterations
     #: anyway: under saturating back-to-back load every iteration has
@@ -400,7 +409,7 @@ class TickLedger:
             METRIC_TICK_PHASE,
             'Busy-tick time by phase, ms (rx_drain | decode_apply | '
             'fsync_gate | cork_flush | fanout_flush | forward_rpc | '
-            'wal_append | wal_roll | repl_push)',
+            'wal_append | wal_roll | repl_push | control | repl_ack)',
             buckets=TICK_BUCKETS)
         self.tick_hist = source.histogram(
             METRIC_TICK, 'Busy-tick wall span, ms',

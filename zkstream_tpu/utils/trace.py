@@ -46,11 +46,42 @@ line of ``/host:CPU`` in the ``.xplane.pb``, on the clock of the
 device's ``XLA Ops`` line) AND a settled :class:`Span` in the
 process-wide :data:`host_ring`; with no session it is one
 ``is_enabled()`` call and a shared no-op.
+
+What a session leaves in ``host_ring.totals`` (``[count, total_ns]``
+a name, no object an op) beside the spans, and what an operator reads
+from each:
+
+- ``client.prepare`` / ``client.submit`` / ``client.resume`` — the
+  loop time of one API call before its request is built (cache and
+  read-plane routing, the connection lookup), while it is encoded
+  and handed to the send plane, and after its reply woke the caller
+  (the deadline entry's discard, the latency observation, ``on_op``);
+  ``client.rx``, ``client.flush``, ``client.handoff``, ``client.reap``,
+  ``client.send``, ``client.notify``, ``client.deadline`` as before.
+- ``client.cork_wait`` / ``client.wire_wait`` / ``client.tick_wait`` /
+  ``client.wake_wait`` — a request's latency by stage, one count an
+  op, stamped on ``time.perf_counter_ns`` (:func:`op_resumed`):
+  submitted -> the tier flush that took its bytes (corked, or held
+  behind the connection's batch in flight); that flush -> the
+  ``_sock_data`` call that brought its reply (the send, the member,
+  and the kernel's socket buffer while the loop was busy); that call
+  -> ``ZKRequest.settle`` (in the ingest's slot, the tick, the route
+  up to this frame); settle -> the awaiting coroutine running again
+  (the rest of the route and the loop's ready queue).  The four sum
+  to the op's ``t1_ns - t0_ns``, which its :class:`Span` carries
+  with the ``tick`` that routed it.
+- ``gc.pause`` and ``gc.pause@<span>`` — the garbage collector's
+  pauses inside the session (one ``gc.callbacks`` hook, installed by a
+  process's first armed span, inert outside a session; each pause is
+  a ``gc.pause`` annotation in the trace too), and the part of them
+  that began while ``<span>`` was the innermost host span open on
+  that thread: subtract it from that span's own total.
 """
 
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import json
 import sys
@@ -66,9 +97,12 @@ import time
 #: them serialize exactly as under schema 2).
 TRACE_SCHEMA = 3
 
-#: ``to_dict`` emission order (after the four always-present keys):
-#: fixed so a span serializes byte-identically regardless of which
-#: setattr path populated it.
+#: A span's optional fields, each None until something stamps it.
+#: The ONE list of them: it makes the class's defaults (below the
+#: class), so neither ``Span.__init__`` nor ``TraceRing.note`` names a
+#: field it does not set, and it is ``to_dict``'s emission order
+#: (after the always-present keys) — fixed, so a span serializes
+#: byte-identically regardless of which setattr path populated it.
 _OPTIONAL_FIELDS = ('path', 'xid', 'zxid', 'backend', 'session_id',
                     'member', 'batch', 'nbytes', 'detail', 'error',
                     'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted',
@@ -77,14 +111,38 @@ _OPTIONAL_FIELDS = ('path', 'xid', 'zxid', 'backend', 'session_id',
 
 class Span:
     """One traced operation: request-side fields stamped at creation,
-    reply-side fields stamped on completion."""
+    reply-side fields stamped on completion.
 
-    __slots__ = ('span_id', 'kind', 'op', 'path', 'xid', 'zxid',
-                 'backend', 'session_id', 'status', 'error',
-                 't_wall', '_t0', 'duration_ms',
-                 'member', 'batch', 'nbytes', 'detail', '_on_slow',
-                 'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted',
-                 'rows', 'width')
+    Optional fields (``_OPTIONAL_FIELDS``) read None until stamped:
+    ``member`` — which ensemble member recorded the span (None =
+    client); ``batch`` — the batch size where the span covers several
+    frames/txns (decode batch, group-fsync barrier, fan-out watch
+    count); ``nbytes`` — bytes the span moved (WAL record, flushed
+    fan-out bytes); ``detail`` — free-form qualifier (log-entry op,
+    follower token); ``parent`` / ``tick`` / ``t0_ns`` / ``t1_ns`` —
+    host spans (:func:`host_span`): the enclosing host span's name,
+    the identifier everything under one ingest tick shares, start/end
+    on ``time.perf_counter_ns`` — and, inside a profiler session, a
+    client op's span too: the ``ingest.tick`` whose route settled it
+    (None: settled off the device) and submit / resume on that clock
+    (:func:`op_resumed`); ``lane`` / ``emitted`` — ``ingest.route``
+    only: the tick's frames settled through the connections' direct
+    lanes, and those handed to the ``'ingestDeliver'`` emitter path;
+    ``rows`` / ``width`` — ``ingest.dispatch`` only: the streams in
+    the dispatch and the width of its size class (``nbytes``: their
+    payload)."""
+
+    duration_ms: float | None = None
+    #: Armed by a ring with a slow-op threshold: called once with the
+    #: span when finish() measures a duration at/over it.
+    _on_slow = None
+    #: A client op inside a profiler session: its stage stamps
+    #: ``[t_submit, t_flush, t_rx, t_settle]`` on
+    #: ``time.perf_counter_ns`` (0 = not reached), from ``_start_op``
+    #: until :func:`op_resumed` books them.  None is the op's answer
+    #: to "was a session active when it was submitted": every later
+    #: stamp is a branch on it.
+    stages: list | None = None
 
     def __init__(self, span_id: int, op: str, path: str | None = None,
                  kind: str = 'op'):
@@ -92,43 +150,9 @@ class Span:
         self.kind = kind  # 'op'|'notification'|'event'|'server'|...
         self.op = op
         self.path = path
-        self.xid: int | None = None
-        self.zxid: int | None = None
-        self.backend: str | None = None
-        self.session_id: str | None = None
-        #: Which ensemble member recorded this span (None = client).
-        self.member: str | None = None
-        #: Batch size, where the span covers several frames/txns
-        #: (decode batch, group-fsync barrier, fan-out watch count).
-        self.batch: int | None = None
-        #: Bytes the span moved (WAL record, flushed fan-out bytes).
-        self.nbytes: int | None = None
-        #: Free-form qualifier (log-entry op, follower token).
-        self.detail: str | None = None
-        #: Host spans only (:func:`host_span`): the enclosing host
-        #: span's name, the identifier everything under one ingest
-        #: tick shares, and start/end on ``time.perf_counter_ns``.
-        self.parent: str | None = None
-        self.tick: int | None = None
-        self.t0_ns: int | None = None
-        self.t1_ns: int | None = None
-        #: ``ingest.route`` only: the frames of the tick that were
-        #: settled through the connections' direct lanes, and those
-        #: handed to the ``'ingestDeliver'`` emitter path.
-        self.lane: int | None = None
-        self.emitted: int | None = None
-        #: ``ingest.dispatch`` only: the streams in this dispatch and
-        #: the width of its size class (``nbytes``: their payload)
-        self.rows: int | None = None
-        self.width: int | None = None
         self.status: str = 'open'
-        self.error: str | None = None
         self.t_wall = time.time()
         self._t0 = time.monotonic()
-        self.duration_ms: float | None = None
-        #: Armed by a ring with a slow-op threshold: called once with
-        #: the span when finish() measures a duration at/over it.
-        self._on_slow = None
 
     def finish(self, zxid: int | None = None, status: str = 'ok',
                error: str | None = None) -> None:
@@ -162,6 +186,11 @@ class Span:
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return '<Span %s>' % (self.to_dict(),)
+
+
+for _field in _OPTIONAL_FIELDS:
+    setattr(Span, _field, None)
+del _field
 
 
 class TraceRing:
@@ -235,28 +264,12 @@ class TraceRing:
         span.kind = kind
         span.op = op
         span.path = path
-        span.xid = None
         span.zxid = zxid
-        span.backend = None
-        span.session_id = None
         span.member = self.member
-        span.batch = None
-        span.nbytes = None
-        span.detail = None
-        span.parent = None
-        span.tick = None
-        span.t0_ns = None
-        span.t1_ns = None
-        span.lane = None
-        span.emitted = None
-        span.rows = None
-        span.width = None
         span.status = 'ok'
-        span.error = None
         span.t_wall = time.time()
         span._t0 = 0.0
-        span.duration_ms = 0.0
-        span._on_slow = None        # already settled; checked below
+        span.duration_ms = 0.0      # already settled; checked below
         for name, val in fields.items():
             setattr(span, name, val)
         if len(self._ring) >= self.capacity:
@@ -468,6 +481,40 @@ def _bind() -> bool:
     return True
 
 
+def _begin_session() -> None:
+    """The first look inside a new profiler session: the ring holds
+    exactly one session, and the collector's pauses are recorded
+    (their total is there from the start: a session without a
+    collection reads zero pauses, not an unrecorded figure)."""
+    global _recording
+    host_ring.reset()
+    host_ring.totals['gc.pause'] = [0, 0]
+    if _gc_pause not in gc.callbacks:
+        gc.callbacks.append(_gc_pause)
+    _recording = True
+
+
+def _armed() -> bool:
+    """Is a profiler session active?  (``host_span`` asks the same
+    question inline: it is the one call an op pays outside a
+    session.)"""
+    global _recording
+    if (_annotation is None and not _bind()) or not _is_enabled():
+        _recording = False
+        return False
+    if not _recording:
+        _begin_session()
+    return True
+
+
+def _add(name: str, count: int, total_ns: int) -> None:
+    tot = host_ring.totals.get(name)
+    if tot is None:
+        tot = host_ring.totals[name] = [0, 0]
+    tot[0] += count
+    tot[1] += total_ns
+
+
 def host_span(name: str, accumulate: bool = False, **ids):
     """Mark a span of this thread's time: ``with host_span('ingest.
     batch', tick=n): ...``.
@@ -489,8 +536,7 @@ def host_span(name: str, accumulate: bool = False, **ids):
         _recording = False
         return NO_SPAN
     if not _recording:
-        host_ring.reset()
-        _recording = True
+        _begin_session()
     return _HostSpan(name, accumulate, ids)
 
 
@@ -501,23 +547,13 @@ def host_add(name: str, count: int, total_ns: int) -> None:
     (the send plane's ``client.send``: connections sent to, and the
     nanoseconds inside their ``send(2)`` loop).  Armed like
     :func:`host_span`: nothing outside a profiler session."""
-    global _recording
-    if (_annotation is None and not _bind()) or not _is_enabled():
-        _recording = False
-        return
-    if not _recording:
-        host_ring.reset()
-        _recording = True
-    tot = host_ring.totals.get(name)
-    if tot is None:
-        tot = host_ring.totals[name] = [0, 0]
-    tot[0] += count
-    tot[1] += total_ns
+    if _armed():
+        _add(name, count, total_ns)
 
 
 class _HostSpan:
     __slots__ = ('name', 'ids', 'fields', '_accumulate', '_ann',
-                 '_parent', '_t0', '_cancelled')
+                 '_parent', 't0_ns', '_cancelled')
 
     def __init__(self, name: str, accumulate: bool, ids: dict):
         self.name = name
@@ -542,7 +578,7 @@ class _HostSpan:
         _open.span = self
         self._ann = _annotation(self.name, **self.ids)
         self._ann.__enter__()
-        self._t0 = time.perf_counter_ns()
+        self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -552,11 +588,7 @@ class _HostSpan:
         if self._cancelled:
             return False
         if self._accumulate:
-            tot = host_ring.totals.get(self.name)
-            if tot is None:
-                tot = host_ring.totals[self.name] = [0, 0]
-            tot[0] += 1
-            tot[1] += t1 - self._t0
+            _add(self.name, 1, t1 - self.t0_ns)
             return False
         # what the caller knew at the start (``ids``: the annotation's
         # own stats in the trace) and what it set under way
@@ -565,6 +597,110 @@ class _HostSpan:
         host_ring.note(
             self.name, kind='host',
             parent=None if self._parent is None else self._parent.name,
-            t0_ns=self._t0, t1_ns=t1,
-            duration_ms=(t1 - self._t0) / 1e6, **fields)
+            t0_ns=self.t0_ns, t1_ns=t1,
+            duration_ms=(t1 - self.t0_ns) / 1e6, **fields)
         return False
+
+
+# ---------------------------------------------------------------------
+# A request's latency by stage: the waits no span of the loop's own
+# time can name, because the loop is doing something else meanwhile.
+# ---------------------------------------------------------------------
+
+#: Indices into ``Span.stages``.
+T_SUBMIT, T_FLUSH, T_RX, T_SETTLE = range(4)
+
+#: The totals the four differences are booked under, in stage order:
+#: submit -> flush -> rx -> settle -> resume.
+STAGE_WAITS = ('client.cork_wait', 'client.wire_wait',
+               'client.tick_wait', 'client.wake_wait')
+
+
+def stamp_flush(stamps: list, t_ns: int = 0) -> None:
+    """The requests corked since the last flush (``stamps``: their
+    ``Span.stages``, emptied here) have their bytes taken by the flush
+    that began at ``t_ns`` (now, by default)."""
+    t_ns = t_ns or time.perf_counter_ns()
+    for st in stamps:
+        st[T_FLUSH] = t_ns
+    stamps.clear()
+
+
+def stamp_reply(span: Span, rx: tuple) -> None:
+    """``ZKRequest.settle`` of a staged op: ``rx`` is what the
+    connection knows of the reply's way in — the start of the
+    ``_sock_data`` call that brought the connection's newest bytes,
+    and the number of the ``ingest.tick`` whose route is delivering it
+    (None off the device)."""
+    st = span.stages
+    st[T_RX], span.tick = rx
+    st[T_SETTLE] = time.perf_counter_ns()
+
+
+def op_resumed(span: Span):
+    """The awaiter of a staged op runs again (``Client._await_op``,
+    whatever the outcome): book the op's four waits — one count each,
+    here and nowhere else, so an op that expired, failed or whose
+    reply came late is counted once too — and open host span
+    ``client.resume``, which the caller closes (``__exit__``).  A
+    stage the op never reached takes no time: its stamp is the next
+    one's (an expired op's ``wire_wait`` runs to its resume).  The
+    span keeps ``t0_ns`` / ``t1_ns`` = submit / resume, so the four
+    sum to ``t1_ns - t0_ns``.  None, and nothing booked, when the
+    session has ended meanwhile."""
+    t_resume = time.perf_counter_ns()
+    (t_submit, t_flush, t_rx, t_settle), span.stages = span.stages, None
+    if not _armed():
+        return None
+    span.t0_ns, span.t1_ns = t_submit, t_resume
+    # backwards from the resume: a stamp that is missing, or (a reply
+    # routed after the awaiter gave up) past its successor, takes the
+    # successor's time
+    if not 0 < t_settle <= t_resume:
+        t_settle = t_resume
+    if not 0 < t_rx <= t_settle:
+        t_rx = t_settle
+    if not t_submit <= t_flush <= t_rx:
+        t_flush = t_rx
+    cork, wire, tick, wake = STAGE_WAITS
+    _add(cork, 1, t_flush - t_submit)
+    _add(wire, 1, t_rx - t_flush)
+    _add(tick, 1, t_settle - t_rx)
+    _add(wake, 1, t_resume - t_settle)
+    return _HostSpan('client.resume', True, {}).__enter__()
+
+
+# ---------------------------------------------------------------------
+# The collector's pauses: they land inside whatever span is open.
+# ---------------------------------------------------------------------
+
+#: the pause under way: (annotation, innermost open host span's name,
+#: start on ``time.perf_counter_ns``)
+_gc_open = None
+
+
+def _gc_pause(phase: str, info: dict) -> None:
+    """The one ``gc.callbacks`` hook (installed by :func:`_armed`):
+    inside a profiler session a collection is a ``gc.pause``
+    annotation from its ``start`` to its ``stop`` and one count in
+    ``host_ring.totals['gc.pause']`` — and in ``['gc.pause@<name>']``
+    for the innermost host span open on this thread when it began, so
+    a reader can take the pauses out of the span that held them.
+    Outside a session it is one ``is_enabled()`` a collection."""
+    global _gc_open
+    if phase == 'start':
+        if not _armed():
+            return
+        ann = _annotation('gc.pause')
+        ann.__enter__()
+        holder = getattr(_open, 'span', None)
+        _gc_open = (ann, None if holder is None else holder.name,
+                    time.perf_counter_ns())
+    elif _gc_open is not None:
+        t1 = time.perf_counter_ns()
+        ann, holder, t0 = _gc_open
+        _gc_open = None
+        ann.__exit__(None, None, None)
+        _add('gc.pause', 1, t1 - t0)
+        if holder is not None:
+            _add('gc.pause@' + holder, 1, t1 - t0)
