@@ -57,6 +57,7 @@
 #include <unistd.h>
 
 #ifdef __linux__
+#include <sys/epoll.h>
 #include <sys/eventfd.h>
 #include <sys/mman.h>
 #include <sys/syscall.h>
@@ -1414,7 +1415,7 @@ static PyObject *py_decode_requests(PyObject *self, PyObject *args) {
 }
 
 static PyObject *py_abi_version(PyObject *self, PyObject *noargs) {
-  return PyLong_FromLong(12);
+  return PyLong_FromLong(13);
 }
 
 /* CRC32C (Castagnoli, reflected 0x82F63B78) for the write-ahead-log
@@ -1745,6 +1746,43 @@ static long long sender_now_ns(void) {
   return (long long)ts.tv_sec * 1000000000LL + ts.tv_nsec;
 }
 
+/* A wake-up another thread can raise and a selector can wait on: one
+ * eventfd, or a pipe's two ends where there is none.  Non-blocking,
+ * close-on-exec.  -1 with errno set. */
+static int wake_pair(int *rfd, int *wfd) {
+#ifdef __linux__
+  *rfd = *wfd = eventfd(0, EFD_NONBLOCK | EFD_CLOEXEC);
+  if (*rfd >= 0) return 0;
+#endif
+  int p[2];
+  if (pipe(p) < 0) return -1;
+  for (int k = 0; k < 2; k++) {
+    fcntl(p[k], F_SETFL, fcntl(p[k], F_GETFL) | O_NONBLOCK);
+    fcntl(p[k], F_SETFD, FD_CLOEXEC);
+  }
+  *rfd = p[0];
+  *wfd = p[1];
+  return 0;
+}
+
+/* Raise it: an eventfd adds the 1; a pipe takes the 8 bytes.  EAGAIN
+ * means it is readable already. */
+static void wake_raise(int wfd) {
+  uint64_t one = 1;
+  ssize_t r;
+  do {
+    r = write(wfd, &one, sizeof(one));
+  } while (r < 0 && errno == EINTR);
+}
+
+/* Clear it, before the state it announces is looked at: what is
+ * published after this read raises it again. */
+static void wake_clear(int rfd) {
+  uint64_t sink[64];
+  while (read(rfd, sink, sizeof(sink)) == (ssize_t)sizeof(sink)) {
+  }
+}
+
 static void *sender_main(void *arg) {
   zk_sender *s = (zk_sender *)arg;
   pthread_mutex_lock(&s->mu);
@@ -1768,11 +1806,7 @@ static void *sender_main(void *arg) {
     s->d_tail = b;
     s->done_id = b->id;
     pthread_cond_broadcast(&s->done);
-    uint64_t one = 1; /* an eventfd adds it; a pipe takes the 8 bytes */
-    ssize_t r;
-    do {
-      r = write(s->wfd, &one, sizeof(one));
-    } while (r < 0 && errno == EINTR); /* EAGAIN: already readable */
+    wake_raise(s->wfd);
   }
   pthread_mutex_unlock(&s->mu);
   return NULL;
@@ -1831,23 +1865,9 @@ static PyObject *py_sender_create(PyObject *self, PyObject *noargs) {
   zk_sender *s = calloc(1, sizeof(zk_sender));
   if (!s) return PyErr_NoMemory();
   s->next_id = 1;
-#ifdef __linux__
-  s->rfd = s->wfd = eventfd(0, EFD_NONBLOCK | EFD_CLOEXEC);
-  if (s->rfd < 0) {
-#else
-  {
-#endif
-    int p[2];
-    if (pipe(p) < 0) {
-      free(s);
-      return PyErr_SetFromErrno(PyExc_OSError);
-    }
-    for (int k = 0; k < 2; k++) {
-      fcntl(p[k], F_SETFL, fcntl(p[k], F_GETFL) | O_NONBLOCK);
-      fcntl(p[k], F_SETFD, FD_CLOEXEC);
-    }
-    s->rfd = p[0];
-    s->wfd = p[1];
+  if (wake_pair(&s->rfd, &s->wfd) < 0) {
+    free(s);
+    return PyErr_SetFromErrno(PyExc_OSError);
   }
   pthread_mutex_init(&s->mu, NULL);
   pthread_cond_init(&s->work, NULL);
@@ -1969,9 +1989,7 @@ static PyObject *py_sender_reap(PyObject *self, PyObject *args) {
   /* the fd first: a batch that finishes after this read makes it
    * readable again, one that finishes before the pop below is popped
    * AND leaves one wake-up that finds nothing */
-  uint64_t sink[64];
-  while (read(s->rfd, sink, sizeof(sink)) == (ssize_t)sizeof(sink)) {
-  }
+  wake_clear(s->rfd);
   pthread_mutex_lock(&s->mu);
   zk_batch *b = s->d_head;
   s->d_head = s->d_tail = NULL;
@@ -2030,6 +2048,534 @@ static PyObject *py_sender_close(PyObject *self, PyObject *args) {
   }
   Py_RETURN_NONE;
 }
+
+/* ---- native receiver thread (io/transport.py, the client plane) -----
+ *
+ * The sender's twin, one direction over: a reply costs the event loop's
+ * thread one recv(2) a connection, behind asyncio's selector transport.
+ * A receiver is ONE pthread with its OWN epoll set that takes those
+ * calls off the loop; like the sender it never touches a Python object
+ * and never takes the GIL.
+ *
+ *   receiver_create() -> capsule       (OSError where there is no epoll)
+ *   receiver_fileno(capsule) -> fd     readable once bytes (or an end)
+ *                                      wait: an eventfd, else a pipe
+ *   receiver_add(capsule, fd) -> token registers fd (level-triggered,
+ *        one epoll_ctl for the connection's life); the token names this
+ *        registration and no other, whatever fd number it drew
+ *   receiver_forget(capsule, token) -> [bytes | -errno, ...]
+ *        takes the connection out and returns only when no recv on its
+ *        fd is in flight (GIL released while it waits): the caller may
+ *        close the fd then.  What was received and not yet reaped comes
+ *        back, in order, as a reap would have given it (without the
+ *        token); an unknown token gives []
+ *   receiver_reap(capsule) -> ([(token, bytes | -errno), ...], recvs, ns)
+ *        every connection with something waiting, oldest first, its
+ *        bytes joined into ONE bytes; b'' is EOF, -errno a hard error,
+ *        each given once and after the connection's last bytes; then
+ *        the thread's recv(2) calls and the nanoseconds inside them
+ *        since the previous reap; clears the fd
+ *   receiver_close(capsule)            joins; what waits is dropped
+ *
+ * The thread: epoll_wait, then ONE recv(fd, 256 KiB, MSG_DONTWAIT) a
+ * ready connection, again only while a call FILLED its buffer (a small
+ * reply never costs a second call to meet EAGAIN; level-triggered
+ * epoll reports what a call left), everything of one wake-up published
+ * together and the fd raised once.  EOF or a hard errno is recorded
+ * and the connection leaves the epoll set at once (a level-triggered
+ * end would spin).  A connection whose unreaped bytes reach
+ * ZK_RX_LIMIT leaves the set too, until a reap takes them: the
+ * kernel's socket buffer then pushes back on the peer.  What the
+ * caller must hold to: an fd is closed only after its forget. */
+
+#ifdef __linux__
+
+#define ZK_RX_BUF (256 * 1024)        /* one recv: asyncio's max_size */
+#define ZK_RX_LIMIT (4 * 1024 * 1024) /* unreaped bytes a connection */
+#define ZK_RX_EVENTS 256              /* ready connections a wake-up */
+#define ZK_RX_SLOT_BITS 24
+#define ZK_RX_CTL (~0ULL)             /* the thread's own wake-up */
+#define ZK_RX_EOF (-1)
+
+typedef struct zk_rxchunk {
+  struct zk_rxchunk *next;
+  size_t len;
+  char data[];
+} zk_rxchunk;
+
+typedef struct zk_rxconn {
+  unsigned long long token; /* generation << SLOT_BITS | slot */
+  int fd;
+  int armed;  /* in the epoll set */
+  int parked; /* out of it at ZK_RX_LIMIT: the next reap re-arms */
+  int fin;    /* 0, ZK_RX_EOF, or the errno that ended it */
+  int fin_given;
+  int queued; /* on the ready list */
+  size_t nbytes; /* received, not yet reaped */
+  zk_rxchunk *head, *tail;
+  struct zk_rxconn *prev, *next; /* the ready list */
+} zk_rxconn;
+
+typedef struct {
+  pthread_t thread;
+  pthread_mutex_t mu;
+  pthread_cond_t idle; /* busy moved on */
+  int ep;
+  int rfd, wfd;   /* wakes the loop: something to reap */
+  int crfd, cwfd; /* wakes the thread: stop */
+  int stop;
+  zk_rxconn **slots;
+  size_t nslots, hint;
+  unsigned long long gen;
+  zk_rxconn *busy; /* the thread is inside recv on it, mu released */
+  int waiters;     /* forgets waiting for busy to move on */
+  zk_rxconn *r_head, *r_tail; /* something to reap, oldest first */
+  size_t nready;
+  long long recv_calls, recv_ns; /* since the last reap */
+} zk_receiver;
+
+static zk_receiver receiver_closed; /* sentinel: explicitly closed */
+
+static zk_rxconn *rx_lookup(zk_receiver *r, unsigned long long token) {
+  size_t slot = (size_t)(token & ((1ULL << ZK_RX_SLOT_BITS) - 1));
+  if (slot >= r->nslots) return NULL;
+  zk_rxconn *c = r->slots[slot];
+  return c && c->token == token ? c : NULL;
+}
+
+static void rx_queue(zk_receiver *r, zk_rxconn *c) {
+  if (c->queued) return;
+  c->queued = 1;
+  c->next = NULL;
+  c->prev = r->r_tail;
+  if (r->r_tail) r->r_tail->next = c; else r->r_head = c;
+  r->r_tail = c;
+  r->nready++;
+}
+
+static void rx_unqueue(zk_receiver *r, zk_rxconn *c) {
+  if (!c->queued) return;
+  c->queued = 0;
+  if (c->prev) c->prev->next = c->next; else r->r_head = c->next;
+  if (c->next) c->next->prev = c->prev; else r->r_tail = c->prev;
+  c->prev = c->next = NULL;
+  r->nready--;
+}
+
+static void rx_disarm(zk_receiver *r, zk_rxconn *c) {
+  if (!c->armed) return;
+  epoll_ctl(r->ep, EPOLL_CTL_DEL, c->fd, NULL);
+  c->armed = 0;
+}
+
+static int rx_arm(zk_receiver *r, zk_rxconn *c) {
+  struct epoll_event ev;
+  memset(&ev, 0, sizeof(ev));
+  ev.events = EPOLLIN;
+  ev.data.u64 = c->token;
+  if (epoll_ctl(r->ep, EPOLL_CTL_ADD, c->fd, &ev) < 0) return -1;
+  c->armed = 1;
+  return 0;
+}
+
+static void rx_free_chunks(zk_rxchunk *ch) {
+  while (ch) {
+    zk_rxchunk *next = ch->next;
+    free(ch);
+    ch = next;
+  }
+}
+
+static void *receiver_main(void *arg) {
+  zk_receiver *r = (zk_receiver *)arg;
+  struct epoll_event evs[ZK_RX_EVENTS];
+  zk_rxchunk *cur = NULL; /* the buffer the next recv fills */
+  for (;;) {
+    int n = epoll_wait(r->ep, evs, ZK_RX_EVENTS, -1);
+    if (n < 0 && errno != EINTR) break; /* the set is gone: never */
+    int published = 0;
+    pthread_mutex_lock(&r->mu);
+    if (r->stop) {
+      pthread_mutex_unlock(&r->mu);
+      break;
+    }
+    for (int k = 0; k < n; k++) {
+      unsigned long long token = evs[k].data.u64;
+      if (token == ZK_RX_CTL) {
+        wake_clear(r->crfd);
+        continue;
+      }
+      /* forgotten, parked or ended since the wait returned? */
+      zk_rxconn *c = rx_lookup(r, token);
+      if (!c || !c->armed) continue;
+      r->busy = c;
+      int again;
+      do {
+        pthread_mutex_unlock(&r->mu);
+        if (!cur) cur = malloc(sizeof(zk_rxchunk) + ZK_RX_BUF);
+        ssize_t got = -1;
+        int err = EAGAIN; /* no buffer: as if nothing were there yet */
+        long long dt = 0;
+        zk_rxchunk *ch = NULL;
+        int called = cur != NULL;
+        if (called) {
+          long long t0 = sender_now_ns();
+          do {
+            got = recv(c->fd, cur->data, ZK_RX_BUF, MSG_DONTWAIT);
+          } while (got < 0 && errno == EINTR);
+          err = errno;
+          dt = sender_now_ns() - t0;
+          if (got > 0) {
+            /* a small read is copied out and the buffer filled again;
+             * a large one (or no memory) keeps its buffer: no copy */
+            if (got < ZK_RX_BUF / 4)
+              ch = malloc(sizeof(zk_rxchunk) + (size_t)got);
+            if (ch) {
+              memcpy(ch->data, cur->data, (size_t)got);
+            } else {
+              ch = cur;
+              cur = NULL;
+            }
+          }
+        } else {
+          usleep(1000);
+        }
+        pthread_mutex_lock(&r->mu);
+        r->recv_calls += called;
+        r->recv_ns += dt;
+        if (got > 0) {
+          ch->len = (size_t)got;
+          ch->next = NULL;
+          if (c->tail) c->tail->next = ch; else c->head = ch;
+          c->tail = ch;
+          c->nbytes += (size_t)got;
+          if (c->nbytes >= ZK_RX_LIMIT && c->armed) {
+            rx_disarm(r, c);
+            c->parked = 1;
+          }
+        } else if (got == 0 || (err != EAGAIN && err != EWOULDBLOCK)) {
+          c->fin = got == 0 ? ZK_RX_EOF : err;
+          rx_disarm(r, c);
+        }
+        if (got >= 0 || c->fin) {
+          rx_queue(r, c);
+          published = 1;
+        }
+        again = got == ZK_RX_BUF && c->armed;
+      } while (again);
+      r->busy = NULL;
+      if (r->waiters) pthread_cond_broadcast(&r->idle);
+    }
+    pthread_mutex_unlock(&r->mu);
+    if (published) wake_raise(r->wfd);
+  }
+  free(cur);
+  return NULL;
+}
+
+/* A receiver with no thread (never started, or joined). */
+static void receiver_release(zk_receiver *r) {
+  for (size_t i = 0; i < r->nslots; i++) {
+    zk_rxconn *c = r->slots[i];
+    if (!c) continue;
+    rx_free_chunks(c->head);
+    free(c);
+  }
+  free(r->slots);
+  if (r->ep >= 0) close(r->ep);
+  if (r->wfd != r->rfd) close(r->wfd);
+  close(r->rfd);
+  if (r->cwfd != r->crfd) close(r->cwfd);
+  close(r->crfd);
+  pthread_cond_destroy(&r->idle);
+  pthread_mutex_destroy(&r->mu);
+  free(r);
+}
+
+/* Stop the thread and join it (at most one recv away; the GIL stays
+ * held: the capsule's destructor may run while the interpreter
+ * finalizes, and the thread never wants it), drop what was never
+ * reaped. */
+static void receiver_free(zk_receiver *r) {
+  pthread_mutex_lock(&r->mu);
+  r->stop = 1;
+  pthread_mutex_unlock(&r->mu);
+  wake_raise(r->cwfd);
+  pthread_join(r->thread, NULL);
+  receiver_release(r);
+}
+
+static void receiver_capsule_destroy(PyObject *cap) {
+  zk_receiver *r = PyCapsule_GetPointer(cap, "zkwire.receiver");
+  if (r && r != &receiver_closed) receiver_free(r);
+}
+
+static zk_receiver *receiver_from_args(PyObject *args, const char *fmt,
+                                       void *extra) {
+  PyObject *cap;
+  if (extra ? !PyArg_ParseTuple(args, fmt, &cap, extra)
+            : !PyArg_ParseTuple(args, fmt, &cap))
+    return NULL;
+  zk_receiver *r =
+      (zk_receiver *)PyCapsule_GetPointer(cap, "zkwire.receiver");
+  if (r == &receiver_closed) {
+    PyErr_SetString(PyExc_ValueError, "receiver already closed");
+    return NULL;
+  }
+  return r;
+}
+
+static PyObject *py_receiver_create(PyObject *self, PyObject *noargs) {
+  zk_receiver *r = calloc(1, sizeof(zk_receiver));
+  if (!r) return PyErr_NoMemory();
+  r->ep = epoll_create1(EPOLL_CLOEXEC);
+  if (r->ep < 0 || wake_pair(&r->rfd, &r->wfd) < 0) {
+    int err = errno;
+    if (r->ep >= 0) close(r->ep);
+    free(r);
+    errno = err;
+    return PyErr_SetFromErrno(PyExc_OSError);
+  }
+  if (wake_pair(&r->crfd, &r->cwfd) < 0) {
+    int err = errno;
+    close(r->ep);
+    if (r->wfd != r->rfd) close(r->wfd);
+    close(r->rfd);
+    free(r);
+    errno = err;
+    return PyErr_SetFromErrno(PyExc_OSError);
+  }
+  pthread_mutex_init(&r->mu, NULL);
+  pthread_cond_init(&r->idle, NULL);
+  struct epoll_event ev;
+  memset(&ev, 0, sizeof(ev));
+  ev.events = EPOLLIN;
+  ev.data.u64 = ZK_RX_CTL;
+  int err = epoll_ctl(r->ep, EPOLL_CTL_ADD, r->crfd, &ev) < 0 ? errno : 0;
+  if (!err) {
+    /* every signal stays with the interpreter's threads */
+    sigset_t all, old;
+    sigfillset(&all);
+    pthread_sigmask(SIG_SETMASK, &all, &old);
+    err = pthread_create(&r->thread, NULL, receiver_main, r);
+    pthread_sigmask(SIG_SETMASK, &old, NULL);
+  }
+  if (err) {
+    receiver_release(r);
+    errno = err;
+    return PyErr_SetFromErrno(PyExc_OSError);
+  }
+  PyObject *cap =
+      PyCapsule_New(r, "zkwire.receiver", receiver_capsule_destroy);
+  if (!cap) receiver_free(r);
+  return cap;
+}
+
+static PyObject *py_receiver_fileno(PyObject *self, PyObject *args) {
+  zk_receiver *r = receiver_from_args(args, "O", NULL);
+  if (!r) return NULL;
+  return PyLong_FromLong(r->rfd);
+}
+
+static PyObject *py_receiver_add(PyObject *self, PyObject *args) {
+  int fd;
+  zk_receiver *r = receiver_from_args(args, "Oi", &fd);
+  if (!r) return NULL;
+  zk_rxconn *c = calloc(1, sizeof(zk_rxconn));
+  if (!c) return PyErr_NoMemory();
+  c->fd = fd;
+  int err = 0;
+  pthread_mutex_lock(&r->mu);
+  size_t slot = r->nslots;
+  for (size_t i = 0; i < r->nslots; i++) {
+    size_t at = (r->hint + i) % r->nslots;
+    if (!r->slots[at]) {
+      slot = at;
+      break;
+    }
+  }
+  if (slot == r->nslots) { /* full: double the table */
+    size_t grown = r->nslots ? r->nslots * 2 : 64;
+    zk_rxconn **slots =
+        grown > (1ULL << ZK_RX_SLOT_BITS)
+            ? NULL
+            : realloc(r->slots, grown * sizeof(zk_rxconn *));
+    if (slots) {
+      memset(slots + r->nslots, 0,
+             (grown - r->nslots) * sizeof(zk_rxconn *));
+      r->slots = slots;
+      r->nslots = grown;
+    } else {
+      err = ENOMEM;
+    }
+  }
+  if (!err) {
+    c->token = (++r->gen << ZK_RX_SLOT_BITS) | slot;
+    if (rx_arm(r, c) < 0) {
+      err = errno;
+    } else {
+      r->slots[slot] = c;
+      r->hint = slot + 1;
+    }
+  }
+  pthread_mutex_unlock(&r->mu);
+  if (err) {
+    free(c);
+    errno = err;
+    return PyErr_SetFromErrno(PyExc_OSError);
+  }
+  return PyLong_FromUnsignedLongLong(c->token);
+}
+
+/* One connection's waiting bytes as ONE bytes object (frees the
+ * chunks, also when it fails). */
+static PyObject *rx_join(zk_rxchunk *head, size_t nbytes) {
+  PyObject *out = PyBytes_FromStringAndSize(NULL, (Py_ssize_t)nbytes);
+  char *at = out ? PyBytes_AS_STRING(out) : NULL;
+  for (zk_rxchunk *ch = head; at && ch; ch = ch->next) {
+    memcpy(at, ch->data, ch->len);
+    at += ch->len;
+  }
+  rx_free_chunks(head);
+  return out;
+}
+
+static PyObject *rx_fin_value(int fin) {
+  return fin == ZK_RX_EOF ? PyBytes_FromStringAndSize(NULL, 0)
+                          : PyLong_FromLong(-(long)fin);
+}
+
+static PyObject *py_receiver_forget(PyObject *self, PyObject *args) {
+  unsigned long long token;
+  zk_receiver *r = receiver_from_args(args, "OK", &token);
+  if (!r) return NULL;
+  zk_rxconn *c;
+  Py_BEGIN_ALLOW_THREADS
+  pthread_mutex_lock(&r->mu);
+  c = rx_lookup(r, token);
+  if (c) {
+    rx_disarm(r, c);
+    c->parked = 0;
+    r->waiters++;
+    while (r->busy == c) pthread_cond_wait(&r->idle, &r->mu);
+    r->waiters--;
+    rx_unqueue(r, c);
+    r->slots[token & ((1ULL << ZK_RX_SLOT_BITS) - 1)] = NULL;
+  }
+  pthread_mutex_unlock(&r->mu);
+  Py_END_ALLOW_THREADS
+  PyObject *out = PyList_New(0);
+  if (!c) return out;
+  PyObject *val = NULL;
+  if (out && c->nbytes) {
+    val = rx_join(c->head, c->nbytes);
+    c->head = NULL;
+    if (!val || PyList_Append(out, val) < 0) Py_CLEAR(out);
+    Py_XDECREF(val);
+  }
+  if (out && c->fin && !c->fin_given) {
+    val = rx_fin_value(c->fin);
+    if (!val || PyList_Append(out, val) < 0) Py_CLEAR(out);
+    Py_XDECREF(val);
+  }
+  rx_free_chunks(c->head);
+  free(c);
+  return out;
+}
+
+typedef struct {
+  unsigned long long token;
+  zk_rxchunk *head;
+  size_t nbytes;
+  int fin;
+} zk_rxtaken;
+
+static PyObject *py_receiver_reap(PyObject *self, PyObject *args) {
+  zk_receiver *r = receiver_from_args(args, "O", NULL);
+  if (!r) return NULL;
+  /* the fd first: what is published after this read raises it again */
+  wake_clear(r->rfd);
+  pthread_mutex_lock(&r->mu);
+  size_t n = r->nready;
+  zk_rxtaken *taken = n ? malloc(n * sizeof(zk_rxtaken)) : NULL;
+  if (n && !taken) {
+    pthread_mutex_unlock(&r->mu);
+    return PyErr_NoMemory();
+  }
+  size_t i;
+  for (i = 0; i < n; i++) {
+    zk_rxconn *c = r->r_head;
+    rx_unqueue(r, c);
+    taken[i].token = c->token;
+    taken[i].head = c->head;
+    taken[i].nbytes = c->nbytes;
+    taken[i].fin = c->fin_given ? 0 : c->fin;
+    c->head = c->tail = NULL;
+    c->nbytes = 0;
+    if (c->fin) c->fin_given = 1;
+    if (c->parked) {
+      c->parked = 0;
+      if (rx_arm(r, c) < 0) { /* cannot be read again: say so */
+        c->fin = errno;
+        rx_queue(r, c);
+      }
+    }
+  }
+  long long calls = r->recv_calls, ns = r->recv_ns;
+  r->recv_calls = r->recv_ns = 0;
+  int again = r->nready != 0;
+  pthread_mutex_unlock(&r->mu);
+  if (again) wake_raise(r->wfd);
+  PyObject *out = PyList_New(0);
+  for (i = 0; i < n; i++) {
+    if (taken[i].nbytes) {
+      PyObject *data = out ? rx_join(taken[i].head, taken[i].nbytes) : NULL;
+      if (!out) rx_free_chunks(taken[i].head);
+      PyObject *item =
+          data ? Py_BuildValue("(KN)", taken[i].token, data) : NULL;
+      if (out && (!item || PyList_Append(out, item) < 0)) Py_CLEAR(out);
+      Py_XDECREF(item);
+    }
+    if (out && taken[i].fin) {
+      PyObject *val = rx_fin_value(taken[i].fin);
+      PyObject *item =
+          val ? Py_BuildValue("(KN)", taken[i].token, val) : NULL;
+      if (!item || PyList_Append(out, item) < 0) Py_CLEAR(out);
+      Py_XDECREF(item);
+    }
+  }
+  free(taken);
+  if (!out) return NULL; /* with the error set: the bytes are gone */
+  return Py_BuildValue("(NLL)", out, calls, ns);
+}
+
+static PyObject *py_receiver_close(PyObject *self, PyObject *args) {
+  PyObject *cap;
+  if (!PyArg_ParseTuple(args, "O", &cap)) return NULL;
+  zk_receiver *r =
+      (zk_receiver *)PyCapsule_GetPointer(cap, "zkwire.receiver");
+  if (!r) return NULL;
+  if (r != &receiver_closed) {
+    if (PyCapsule_SetPointer(cap, &receiver_closed) < 0) return NULL;
+    receiver_free(r);
+  }
+  Py_RETURN_NONE;
+}
+
+#else /* !__linux__: no epoll, no receiver (the tier keeps asyncio's push) */
+
+static PyObject *py_receiver_unsupported(PyObject *self, PyObject *args) {
+  errno = ENOSYS;
+  return PyErr_SetFromErrno(PyExc_OSError);
+}
+#define py_receiver_create py_receiver_unsupported
+#define py_receiver_fileno py_receiver_unsupported
+#define py_receiver_add py_receiver_unsupported
+#define py_receiver_forget py_receiver_unsupported
+#define py_receiver_reap py_receiver_unsupported
+#define py_receiver_close py_receiver_unsupported
+
+#endif /* __linux__ */
 
 /* ---- batched receive drain (io/ingress.py) --------------------------
  *
@@ -2668,6 +3214,23 @@ static PyMethodDef methods[] = {
      "batch is done"},
     {"sender_close", py_sender_close, METH_VARARGS,
      "sender_close(sender) — send what is queued, join the thread"},
+    {"receiver_create", py_receiver_create, METH_NOARGS,
+     "receiver_create() -> capsule — one native thread that polls and "
+     "receives registered connections without the GIL"},
+    {"receiver_fileno", py_receiver_fileno, METH_VARARGS,
+     "receiver_fileno(receiver) -> fd, readable once bytes wait"},
+    {"receiver_add", py_receiver_add, METH_VARARGS,
+     "receiver_add(receiver, fd) -> token — the thread receives fd "
+     "from now on"},
+    {"receiver_forget", py_receiver_forget, METH_VARARGS,
+     "receiver_forget(receiver, token) -> [bytes|-errno, ...] — take "
+     "the connection out (no recv of it in flight on return) and hand "
+     "back what was not reaped"},
+    {"receiver_reap", py_receiver_reap, METH_VARARGS,
+     "receiver_reap(receiver) -> ([(token, bytes|-errno), ...], recvs, "
+     "ns) — what every connection received, oldest first (b'' = EOF)"},
+    {"receiver_close", py_receiver_close, METH_VARARGS,
+     "receiver_close(receiver) — join the thread, drop what waits"},
     {"uring_create", py_uring_create, METH_VARARGS,
      "uring_create(depth=256) -> capsule (OSError when io_uring is "
      "unavailable)"},
@@ -2733,5 +3296,12 @@ PyMODINIT_FUNC PyInit__zkwire_ext(void) {
   s_perms = PyUnicode_InternFromString("perms");
   s_scheme = PyUnicode_InternFromString("scheme");
   s_id_attr = PyUnicode_InternFromString("id");
-  return PyModule_Create(&moduledef);
+  PyObject *mod = PyModule_Create(&moduledef);
+#ifdef __linux__
+  if (mod && (PyModule_AddIntConstant(mod, "RECEIVER_BUF", ZK_RX_BUF) < 0 ||
+              PyModule_AddIntConstant(mod, "RECEIVER_LIMIT",
+                                      ZK_RX_LIMIT) < 0))
+    Py_CLEAR(mod);
+#endif
+  return mod;
 }

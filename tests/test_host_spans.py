@@ -272,6 +272,64 @@ async def test_a_deep_burst_is_one_handoff_one_reap_and_its_sends(
             await c.close()
 
 
+async def test_a_reap_books_its_deliveries_and_the_threads_recvs(
+        server, armed):
+    """The receive half's books: replies to N sessions of one loop on
+    ``mmsg`` come through ``client.rx_reap`` — one span a reap, the
+    connections' ``client.rx`` spans nested inside it —
+    ``client.rx_reaped`` counts the deliveries those reaps made
+    (every ``client.rx`` of the window: the offload share is 100%)
+    and ``client.recv`` totals the receiver thread's ``recv(2)`` calls
+    with the nanoseconds inside them, on its own clock.  On a tier
+    that does not own the receive none of the three exists."""
+    from zkstream_tpu.io.transport import probe
+    from zkstream_tpu.utils.native import ensure_ext
+    if not probe().mmsg or not hasattr(ensure_ext(), 'receiver_reap'):
+        pytest.skip('no native receiver here')
+    n = 12
+    clients = []
+    try:
+        for _ in range(n):
+            c = Client(address='127.0.0.1', port=server.port,
+                       transport='mmsg', session_timeout=30000,
+                       max_spares=0)
+            c.start()
+            await c.wait_connected(timeout=5)
+            clients.append(c)
+        await asyncio.sleep(0.05)
+        tier = clients[0].transport_tier
+        reads = tier.received_reads
+        trace.host_ring.reset()
+        assert len(await asyncio.gather(
+            *[c.list('/') for c in clients])) == n
+        totals = trace.host_ring.totals
+        assert totals['client.rx'][0] == n
+        assert totals['client.rx_reaped'] == [n, 0]
+        assert tier.received_reads - reads == n
+        assert 1 <= totals['client.rx_reap'][0] <= n
+        # the reaps hold the deliveries' own spans, and more
+        assert totals['client.rx_reap'][1] > totals['client.rx'][1] > 0
+        assert totals['client.recv'][0] >= n
+        assert 0 < totals['client.recv'][1]
+        assert len(trace.host_ring) == 0        # counts and totals only
+    finally:
+        for c in clients:
+            await c.close()
+    c = Client(address='127.0.0.1', port=server.port,
+               transport='asyncio', session_timeout=30000, max_spares=0)
+    try:
+        c.start()
+        await c.wait_connected(timeout=5)
+        trace.host_ring.reset()
+        await c.list('/')
+        totals = trace.host_ring.totals
+        assert totals['client.rx'][0] == 1
+        assert not {'client.rx_reap', 'client.rx_reaped',
+                    'client.recv'} & set(totals)
+    finally:
+        await c.close()
+
+
 def test_host_add_is_armed_by_the_session_alone(monkeypatch):
     """``host_add`` books work counted and timed elsewhere (a native
     thread's batch) under a name's totals inside a profiler session,

@@ -864,3 +864,388 @@ def test_sender_stress_submit_reap_and_wait_race():
         for a, b in pairs:
             a.close()
             b.close()
+
+
+# -- the native receiver thread (io/transport.py's receive half) -------
+
+class _Rx:
+    """A receiver and what its reaps brought, by token."""
+
+    def __init__(self):
+        self.ext = native.ensure_ext()
+        self.cap = self.ext.receiver_create()
+        self.wake = self.ext.receiver_fileno(self.cap)
+        self.got: dict[int, list] = {}
+        self.calls = self.ns = 0
+
+    def reap(self) -> list:
+        items, calls, ns = self.ext.receiver_reap(self.cap)
+        self.calls += calls
+        self.ns += ns
+        for token, data in items:
+            self.got.setdefault(token, []).append(data)
+        return items
+
+    def readable(self, timeout: float = 5.0) -> bool:
+        import select
+        return bool(select.select([self.wake], [], [], timeout)[0])
+
+    def until(self, done, timeout: float = 10.0) -> None:
+        """Reap at every wake-up until ``done()``."""
+        import time
+        end = time.monotonic() + timeout
+        while not done():
+            left = end - time.monotonic()
+            assert left > 0, 'never arrived: %r' % (
+                {t: [d if isinstance(d, int) else len(d) for d in v]
+                 for t, v in self.got.items()},)
+            if self.readable(min(left, 0.05)):
+                self.reap()
+
+    def stream(self, token: int) -> bytes:
+        return b''.join(d for d in self.got.get(token, [])
+                        if isinstance(d, bytes))
+
+    def close(self) -> None:
+        self.ext.receiver_close(self.cap)
+
+
+def test_receiver_keeps_each_connections_order_and_its_own_clock():
+    """Bytes come back by token, a connection's in the order its peer
+    wrote them and joined into one ``bytes`` a reap; the wake-up fd is
+    readable exactly while something waits; the thread's own ``recv``
+    count and nanoseconds ride on the reap."""
+    rx = _Rx()
+    pairs = _sender_pairs(3)
+    try:
+        assert not rx.readable(0)
+        tokens = [rx.ext.receiver_add(rx.cap, a.fileno())
+                  for a, _b in pairs]
+        assert len(set(tokens)) == 3
+        want = [b''.join(b'%d:%04d;' % (i, k) for k in range(400))
+                for i in range(3)]
+        for k in range(400):
+            for i, (_a, b) in enumerate(pairs):
+                b.sendall(b'%d:%04d;' % (i, k))
+        rx.until(lambda: all(rx.stream(t) == w
+                             for t, w in zip(tokens, want)))
+        assert rx.calls > 0 and rx.ns > 0
+        # nothing waits: the fd is quiet, a reap is empty and free
+        assert not rx.readable(0.05)
+        assert rx.ext.receiver_reap(rx.cap) == ([], 0, 0)
+        # one reap joins what several recvs brought
+        pairs[0][1].sendall(b'x' * 300000)
+        rx.until(lambda: len(rx.stream(tokens[0])) == len(want[0])
+                 + 300000)
+    finally:
+        rx.close()
+        for a, b in pairs:
+            a.close()
+            b.close()
+
+
+def test_receiver_reports_eof_and_a_reset_once_each_after_the_bytes():
+    """EOF is ``b''`` and a hard errno ``-errno``, each given once,
+    behind the connection's last bytes; the ended connection has left
+    the thread's ``epoll`` set (a level-triggered end does not spin:
+    no further ``recv`` is made)."""
+    import errno
+    import socket
+    import time
+    rx = _Rx()
+    lsock = socket.socket()
+    lsock.bind(('127.0.0.1', 0))
+    lsock.listen(2)
+    conns = []
+    try:
+        for _ in range(2):
+            peer = socket.create_connection(lsock.getsockname())
+            mine, _addr = lsock.accept()
+            conns.append((mine, peer))
+        ended, reset = (rx.ext.receiver_add(rx.cap, mine.fileno())
+                        for mine, _peer in conns)
+        conns[0][1].sendall(b'last words')
+        conns[0][1].close()
+        # RST: the peer closes with our bytes unread and linger 0
+        conns[1][0].sendall(b'never read')
+        conns[1][1].setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                               struct.pack('ii', 1, 0))
+        time.sleep(0.05)
+        conns[1][1].close()
+        rx.until(lambda: rx.got.get(ended, [])[-1:] == [b'']
+                 and len(rx.got.get(reset, [])) >= 1)
+        assert rx.got[ended] == [b'last words', b'']
+        assert rx.got[reset] == [-errno.ECONNRESET]
+        time.sleep(0.1)
+        assert rx.ext.receiver_reap(rx.cap) == ([], 0, 0)
+        # forgetting an ended connection hands nothing back twice
+        assert rx.ext.receiver_forget(rx.cap, ended) == []
+        assert rx.ext.receiver_forget(rx.cap, reset) == []
+    finally:
+        rx.close()
+        lsock.close()
+        for mine, peer in conns:
+            mine.close()
+            peer.close()
+
+
+def test_receiver_forget_hands_back_what_was_not_reaped():
+    """``receiver_forget`` returns the connection's unreaped bytes (and
+    its end, if it had one) in order; later reaps know nothing of the
+    token; forgetting it again, or a token never given, is []."""
+    rx = _Rx()
+    pairs = _sender_pairs(2)
+    try:
+        kept, gone = (rx.ext.receiver_add(rx.cap, a.fileno())
+                      for a, _b in pairs)
+        pairs[1][1].sendall(b'handed ')
+        pairs[1][1].sendall(b'back')
+        pairs[1][1].close()
+        pairs[0][1].sendall(b'reaped')
+        assert rx.readable()
+        import time
+        time.sleep(0.1)         # both arrived, nothing reaped yet
+        assert rx.ext.receiver_forget(rx.cap, gone) == [
+            b'handed back', b'']
+        rx.until(lambda: rx.stream(kept) == b'reaped')
+        assert gone not in rx.got
+        assert rx.ext.receiver_forget(rx.cap, gone) == []
+        assert rx.ext.receiver_forget(rx.cap, 12345) == []
+    finally:
+        rx.close()
+        for a, b in pairs:
+            a.close()
+            b.close()
+
+
+def test_receiver_names_a_registration_by_token_not_by_fd():
+    """Close + reopen on the SAME fd number: the new registration has
+    another token, what the old one received is handed back at its
+    forget and never shows up under the new one."""
+    import socket
+    import time
+    rx = _Rx()
+    try:
+        a, b = socket.socketpair()
+        fd = a.fileno()
+        old = rx.ext.receiver_add(rx.cap, fd)
+        b.sendall(b'for the old connection')
+        assert rx.readable()
+        time.sleep(0.05)
+        left = rx.ext.receiver_forget(rx.cap, old)
+        a.close()
+        b.close()
+        a2, b2 = socket.socketpair()
+        try:
+            # the kernel hands out the lowest free number: a's
+            assert fd in (a2.fileno(), b2.fileno())
+            mine, peer = (a2, b2) if a2.fileno() == fd else (b2, a2)
+            new = rx.ext.receiver_add(rx.cap, mine.fileno())
+            assert new != old
+            peer.sendall(b'for the new one')
+            rx.until(lambda: rx.stream(new) == b'for the new one')
+            assert left == [b'for the old connection']
+            assert old not in rx.got
+        finally:
+            a2.close()
+            b2.close()
+    finally:
+        rx.close()
+
+
+def test_receiver_forget_waits_for_a_recv_in_flight():
+    """The rule that lets the caller close the fd: ``receiver_forget``
+    returns only when no ``recv`` of that fd is in flight, and hands
+    back what that last ``recv`` brought.  A peer keeps the thread
+    inside large receives; the connection is forgotten mid-stream and
+    the test reads the rest of the socket itself: reaps + hand-back +
+    rest is the peer's stream, byte for byte — a ``recv`` that
+    outlived the forget would have taken bytes that nobody got (and a
+    caller that closed the fd then would have let it read the fd's
+    next owner)."""
+    import socket
+    import threading
+    import time
+    rx = _Rx()
+    payload = bytes(bytearray(i * 13 % 253 for i in range(1 << 18))) * 24
+    try:
+        for k in range(40):
+            a, b = socket.socketpair()
+            token = rx.ext.receiver_add(rx.cap, a.fileno())
+
+            def fire(sock=b):
+                sock.sendall(payload)
+                sock.close()
+            t = threading.Thread(target=fire)
+            t.start()
+            assert rx.readable()
+            time.sleep(0.0002 * (k % 7))
+            rx.reap()
+            left = rx.ext.receiver_forget(rx.cap, token)
+            had = rx.stream(token) + b''.join(left)
+            rest = bytearray()
+            while True:
+                chunk = a.recv(1 << 20)
+                if not chunk:
+                    break
+                rest += chunk
+            t.join(10)
+            a.close()
+            assert len(had) + len(rest) == len(payload), k
+            assert had + bytes(rest) == payload, k
+    finally:
+        rx.close()
+
+
+def test_receiver_bound_stops_reading_until_the_reap():
+    """A connection's unreaped bytes are bounded (``RECEIVER_LIMIT``):
+    at the bound the thread stops reading that fd — the peer's
+    ``sendall`` blocks on the kernel's buffers — and the reap that
+    takes the bytes starts it again; nothing is lost or reordered."""
+    import threading
+    import time
+    rx = _Rx()
+    limit, buf = rx.ext.RECEIVER_LIMIT, rx.ext.RECEIVER_BUF
+    pairs = _sender_pairs(1)
+    a, b = pairs[0]
+    b.settimeout(None)
+    total = 3 * limit
+    payload = bytes(bytearray(i * 7 % 251 for i in range(1 << 16)))
+    payload *= total // len(payload)
+    done = threading.Event()
+
+    def write():
+        b.sendall(payload)
+        done.set()
+    t = threading.Thread(target=write)
+    try:
+        token = rx.ext.receiver_add(rx.cap, a.fileno())
+        t.start()
+        assert rx.readable()
+        time.sleep(0.5)             # as far as it will go unreaped
+        assert not done.is_set()
+        items, calls, _ns = rx.ext.receiver_reap(rx.cap)
+        assert [tok for tok, _d in items] == [token]
+        first = items[0][1]
+        assert limit <= len(first) < limit + buf
+        before = calls
+        rx.got[token] = [first]
+        rx.until(lambda: len(rx.stream(token)) == total, timeout=30)
+        assert done.wait(5)
+        assert rx.stream(token) == payload
+        assert rx.calls > 0 and before > 0
+    finally:
+        rx.close()
+        a.close()
+        b.close()
+        t.join(5)
+
+
+def test_receiver_close_with_bytes_waiting_then_refuses():
+    """``receiver_close`` joins the thread whatever waits (unreaped
+    bytes, armed connections) and every later call is refused; a
+    receiver nobody closed goes with its capsule."""
+    import gc
+    rx = _Rx()
+    pairs = _sender_pairs(2)
+    try:
+        for a, _b in pairs:
+            rx.ext.receiver_add(rx.cap, a.fileno())
+        pairs[0][1].sendall(b'never reaped')
+        assert rx.readable()
+        rx.close()
+        rx.close()                  # twice is fine
+        for call in (lambda: rx.ext.receiver_reap(rx.cap),
+                     lambda: rx.ext.receiver_fileno(rx.cap),
+                     lambda: rx.ext.receiver_add(rx.cap, 0),
+                     lambda: rx.ext.receiver_forget(rx.cap, 1)):
+            with pytest.raises(ValueError):
+                call()
+        with pytest.raises(OSError):
+            other = _Rx()
+            try:
+                other.ext.receiver_add(other.cap, 1 << 20)  # EBADF
+            finally:
+                other.close()
+        leaked = _Rx()
+        leaked.ext.receiver_add(leaked.cap, pairs[1][0].fileno())
+        pairs[1][1].sendall(b'dropped with the capsule')
+        assert leaked.readable()
+        del leaked
+        gc.collect()
+    finally:
+        for a, b in pairs:
+            a.close()
+            b.close()
+
+
+def test_receiver_stress_many_connections_three_threads():
+    """64 connections written by one thread, received by the
+    receiver's, reaped by a third with a shortened switch interval,
+    while the main thread adds, forgets and closes 300 more under
+    them: every long-lived connection's stream is whole and in order
+    (a lost wake-up would hang the reaper, a lost update would drop or
+    repeat bytes)."""
+    import socket
+    import sys
+    import threading
+    rx = _Rx()
+    pairs = _sender_pairs(64)
+    rounds = 300
+    want = [b''.join(b'%02d.%04d|' % (i, k) for k in range(rounds))
+            for i in range(64)]
+    stop = threading.Event()
+    failed = []
+
+    def write():
+        try:
+            for k in range(rounds):
+                for i, (_a, b) in enumerate(pairs):
+                    b.sendall(b'%02d.%04d|' % (i, k))
+        except Exception as e:      # pragma: no cover
+            failed.append(e)
+
+    def reap():
+        try:
+            while not stop.is_set():
+                if rx.readable(0.01):
+                    rx.reap()
+        except Exception as e:      # pragma: no cover
+            failed.append(e)
+    threads = [threading.Thread(target=write),
+               threading.Thread(target=reap)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        tokens = [rx.ext.receiver_add(rx.cap, a.fileno())
+                  for a, _b in pairs]
+        for t in threads:
+            t.start()
+        for k in range(300):
+            a, b = socket.socketpair()
+            token = rx.ext.receiver_add(rx.cap, a.fileno())
+            b.sendall(b'churn')
+            left = rx.ext.receiver_forget(rx.cap, token)
+            assert left in ([], [b'churn']), left
+            a.close()
+            b.close()
+        threads[0].join(60)
+        import time
+        end = time.monotonic() + 30
+        while time.monotonic() < end and not failed and not all(
+                len(rx.stream(t)) >= len(w)
+                for t, w in zip(tokens, want)):
+            time.sleep(0.01)
+        stop.set()
+        threads[1].join(10)
+        assert not failed, failed
+        assert not any(t.is_alive() for t in threads)
+        for t, w in zip(tokens, want):
+            assert rx.stream(t) == w
+    finally:
+        sys.setswitchinterval(old)
+        stop.set()
+        rx.close()
+        for a, b in pairs:
+            a.close()
+            b.close()

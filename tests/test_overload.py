@@ -378,12 +378,11 @@ async def test_stalled_persistent_subscriber_evicted_then_resyncs():
         # the backlog, stop reading, then pipeline ~3 MB of fat reads
         # so the tx account crosses the soft watermark.
         dying = cached.current_connection()
-        tr = dying.transport
-        sock = tr.get_extra_info('socket')
+        sock = dying.transport.get_extra_info('socket')
         if sock is not None:
             sock.setsockopt(socketmod.SOL_SOCKET,
                             socketmod.SO_RCVBUF, 4096)
-        tr.pause_reading()
+        dying.pause_reading()
         pending = [asyncio.ensure_future(cached.get('/big'))
                    for _ in range(100)]
         await asyncio.sleep(0)         # let the requests hit the wire
@@ -452,12 +451,12 @@ async def test_stalled_subscriber_tx_bounded_and_evicted():
         # can't mask the backlog, stop reading, then pipeline 100
         # 32 KiB reads — ~3 MB of replies aimed at a socket that
         # will never drain.
-        tr = stalled.current_connection().transport
-        sock = tr.get_extra_info('socket')
+        wedged = stalled.current_connection()
+        sock = wedged.transport.get_extra_info('socket')
         if sock is not None:
             sock.setsockopt(socketmod.SOL_SOCKET,
                             socketmod.SO_RCVBUF, 4096)
-        tr.pause_reading()
+        wedged.pause_reading()
         pending = [asyncio.ensure_future(stalled.get('/big'))
                    for _ in range(100)]
         await asyncio.sleep(0)         # let the requests hit the wire

@@ -821,6 +821,468 @@ async def test_without_the_extension_a_deep_batch_goes_inline(
         await rig.stop()
 
 
+# -- the native receiver: the client tier's receive half ---------------
+
+def _has_receiver() -> bool:
+    from zkstream_tpu.utils.native import ensure_ext
+    return probe().mmsg and hasattr(ensure_ext(), 'receiver_reap')
+
+
+needs_receiver = pytest.mark.skipif(
+    not _has_receiver(), reason='no native receiver: the mmsg backend '
+    'or the extension is missing')
+
+#: How a client connection's bytes come in: asyncio's protocol push
+#: (no tier, or one that does not own the receive), or the loop's
+#: shared client tier's native receiver thread.
+RX_PATHS = ['asyncio_push'] + (['receiver_thread']
+                               if _has_receiver() else [])
+
+
+def _rx_tier(path: str) -> TransportTier | None:
+    if path == 'asyncio_push':
+        return None
+    tier = TransportTier('mmsg', plane='client')
+    tier.attach_sender()
+    return tier
+
+
+class _RxStub:
+    """What a ZKConnection asks of its client."""
+
+    def __init__(self, tier, faults=None):
+        from zkstream_tpu.io.session import ZKSession
+        self.transport_tier = tier
+        self.faults = faults
+        self.use_native_codec = False
+        self.session = ZKSession(30000)
+
+    def get_session(self):
+        return self.session
+
+
+class _RxPeer:
+    """A real ``ZKConnection`` made through the real ``_SocketProtocol``
+    over a socket pair, handshaken by the test, which plays the member
+    on ``self.peer``; everything the connection observes is logged."""
+
+    def __init__(self, idx: int, tier, faults=None, tcp: bool = False):
+        self.idx, self.tier, self.tcp = idx, tier, tcp
+        self.client = _RxStub(tier, faults)
+        self.log: list = []
+        self.chunks: list[int] = []
+
+    async def start(self) -> '_RxPeer':
+        from zkstream_tpu.io.connection import (
+            Backend, ZKConnection, _SocketProtocol)
+        if self.tcp:
+            lsock = socket.socket()
+            lsock.bind(('127.0.0.1', 0))
+            lsock.listen(1)
+            left = socket.create_connection(lsock.getsockname())
+            self.peer, _addr = lsock.accept()
+            lsock.close()
+        else:
+            left, self.peer = socket.socketpair()
+        left.setblocking(False)
+        self.peer.setblocking(False)
+        self.conn = conn = ZKConnection(
+            self.client, Backend('127.0.0.1', 1 + self.idx))
+        conn.codec = PacketCodec(use_native=False)
+        conn.on('sockData', lambda d: self.chunks.append(len(d)))
+        conn.on('packet', lambda p: self.log.append(('packet', p)))
+        for ev in ('sockEnd', 'sockClose'):
+            conn.on(ev, lambda ev=ev: self.log.append((ev,)))
+        conn.on('sockError', lambda e: self.log.append(
+            ('sockError', type(e).__name__, e.errno)))
+        self.client.session.process_notification = lambda pkt: None
+        loop = asyncio.get_running_loop()
+        await loop.create_connection(lambda: _SocketProtocol(conn),
+                                     sock=left)
+        conn._transition('handshaking')
+        srv = PacketCodec(server=True, use_native=False)
+        await _read_exact(self.peer, 44)        # the ConnectRequest
+        self.peer.send(srv.encode({
+            'protocolVersion': 0, 'timeOut': 30000,
+            'sessionId': 0x2000 + self.idx, 'passwd': b'\x01' * 16}))
+        await _until(lambda: conn.is_in_state('connected'))
+        del self.log[:], self.chunks[:]
+        return self
+
+    @property
+    def entry(self):
+        return self.conn._tx._entry
+
+    def expect(self, replies) -> None:
+        """Teach the connection's codec the xids about to be answered
+        (a reply decodes by the opcode its xid was sent with)."""
+        for p in replies:
+            if p['xid'] > 0:
+                self.conn.codec.xid_map[p['xid']] = p['opcode']
+
+    def packets(self) -> list:
+        return [e[1] for e in self.log if e[0] == 'packet']
+
+    async def stop(self) -> None:
+        self.conn.destroy()
+        self.peer.close()
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+
+
+async def _until(cond, timeout: float = 10.0) -> None:
+    loop = asyncio.get_running_loop()
+    end = loop.time() + timeout
+    while not cond():
+        assert loop.time() < end, 'never came true'
+        await asyncio.sleep(0.0005)
+
+
+def _reply_wire() -> tuple[bytes, list]:
+    """The reply corpus as one member would write it, and what a
+    client decodes from it."""
+    enc = PacketCodec(server=True, use_native=False)
+    enc.handshaking = False
+    wire = b''.join(enc.encode(dict(p)) for p in REPLIES)
+    dec = PacketCodec(use_native=False)
+    dec.handshaking = False
+    for p in REPLIES:
+        if p['xid'] > 0:
+            dec.xid_map[p['xid']] = p['opcode']
+    return wire, dec.decode(wire)
+
+
+@pytest.mark.parametrize('path', RX_PATHS)
+async def test_client_receive_parity_at_every_byte_offset(path):
+    """The invariant the receive half hangs on: whichever path brings
+    the bytes, every connection decodes the IDENTICAL frame stream —
+    the whole reply corpus, cut in two at EVERY byte offset, three
+    connections of one tier at once, each cut elsewhere."""
+    tier = _rx_tier(path)
+    wire, want = _reply_wire()
+    peers = [await _RxPeer(i, tier).start() for i in range(3)]
+    try:
+        for p in peers:
+            on_thread = path == 'receiver_thread'
+            assert (p.entry is not None and p.entry.rx_token != 0) \
+                == on_thread
+        for base in range(1, len(wire), 3):
+            cuts = [min(base + i, len(wire) - 1) for i in range(3)]
+            for p, cut in zip(peers, cuts):
+                p.expect(REPLIES)
+                del p.log[:], p.chunks[:]
+                p.peer.send(wire[:cut])
+            for p, cut in zip(peers, cuts):
+                await _until(lambda: sum(p.chunks) == cut)
+                p.peer.send(wire[cut:])
+            for p in peers:
+                await _until(lambda: sum(p.chunks) == len(wire))
+                assert p.packets() == want, (path, p.idx, cuts)
+        if tier is not None:
+            assert tier.received_reads >= 2 * len(peers)
+            assert tier.received_batches > 0
+            assert tier.received_ctr.value({'plane': 'client'}) \
+                == tier.received_reads
+    finally:
+        for p in peers:
+            await p.stop()
+        if tier is not None:
+            assert not tier._rx
+            tier.close()
+
+
+class _RxTap:
+    """Stands where the fault injector stands on the receive side and
+    logs its boundary: one call a ``_sock_data``, one connection's
+    bytes a call."""
+
+    def __init__(self):
+        self.calls: list = []
+
+    def rx(self, conn, data: bytes) -> None:
+        self.calls.append((conn, bytes(data)))
+        conn.emit('sockData', data)
+
+    def tx(self, conn, data: bytes) -> bytes:
+        return data
+
+
+@pytest.mark.parametrize('path', RX_PATHS)
+async def test_fault_injector_rx_boundary_stays_per_connection(path):
+    """An installed injector still sees every received segment, and a
+    segment is one connection's: a reap of many connections' bytes is
+    as many ``faults.rx`` calls, each with the connection whose socket
+    received them — never one joined buffer, never a neighbour's."""
+    tier = _rx_tier(path)
+    tap = _RxTap()
+    wire, want = _reply_wire()
+    peers = [await _RxPeer(i, tier, faults=tap).start()
+             for i in range(8)]
+    try:
+        del tap.calls[:]
+        for p in peers:
+            p.expect(REPLIES)
+            p.peer.send(b'%c' % (65 + p.idx) * 3)   # its own mark...
+        # ...not a frame: never mind, the boundary is what is watched
+        for p in peers:
+            await _until(lambda: sum(
+                len(d) for c, d in tap.calls if c is p.conn) == 3)
+        by_conn = {p.conn: p for p in peers}
+        for conn, data in tap.calls:
+            assert data == b'%c' % (65 + by_conn[conn].idx) * len(data)
+    finally:
+        for p in peers:
+            await p.stop()
+        if tier is not None:
+            tier.close()
+
+
+@pytest.mark.parametrize('path', RX_PATHS)
+async def test_client_receive_end_and_reset_read_the_same(path):
+    """EOF is ``sockEnd`` and a reset is ``sockError`` with the
+    ``OSError`` on both paths, each once and behind the connection's
+    last bytes; the transport is torn down as asyncio tears it down."""
+    tier = _rx_tier(path)
+    wire, want = _reply_wire()
+    ended = await _RxPeer(0, tier, tcp=True).start()
+    reset = await _RxPeer(1, tier, tcp=True).start()
+    try:
+        ended.expect(REPLIES)
+        ended.peer.send(wire)
+        ended.peer.shutdown(socket.SHUT_WR)
+        await _until(lambda: ('sockClose',) in ended.log)
+        assert ended.packets() == want
+        # the end once, behind the bytes; state `error` then aborts
+        assert ended.log[len(want):] == [('sockEnd',), ('sockClose',)]
+        # RST: the member closes with our request unread, linger 0
+        import struct
+        reset.conn._tx_write(b'unread by the member')
+        await asyncio.sleep(0.01)
+        reset.peer.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                              struct.pack('ii', 1, 0))
+        reset.peer.close()
+        await _until(lambda: any(e[0] == 'sockError'
+                                 for e in reset.log))
+        await asyncio.sleep(0.01)
+        assert reset.log == [
+            ('sockError', 'ConnectionResetError', errno.ECONNRESET)]
+        assert reset.conn.transport is None     # error -> closed
+    finally:
+        await ended.stop()
+        await reset.stop()
+        if tier is not None:
+            assert not tier._rx
+            tier.close()
+
+
+@pytest.mark.parametrize('path', RX_PATHS)
+async def test_connection_pause_and_resume_reading(path):
+    """``ZKConnection.pause_reading`` stops whichever path reads the
+    socket (the transport's own pause cannot stop a thread it does not
+    know), what had already arrived is delivered first, and
+    ``resume_reading`` puts the connection back on the path it was
+    made on with nothing lost."""
+    tier = _rx_tier(path)
+    wire, want = _reply_wire()
+    p = await _RxPeer(0, tier).start()
+    try:
+        p.expect(REPLIES)
+        p.peer.send(wire[:100])
+        await _until(lambda: sum(p.chunks) == 100)
+        p.conn.pause_reading()
+        p.peer.send(wire[100:])
+        await asyncio.sleep(0.05)
+        assert sum(p.chunks) == 100
+        if tier is not None:
+            assert p.entry.rx_transport is None and not tier._rx
+        p.conn.resume_reading()
+        await _until(lambda: sum(p.chunks) == len(wire))
+        assert p.packets() == want
+        if tier is not None:
+            assert p.entry.rx_token != 0
+    finally:
+        await p.stop()
+        if tier is not None:
+            tier.close()
+
+
+@needs_receiver
+async def test_connection_lost_with_bytes_the_thread_holds():
+    """``connection_lost`` — behind which asyncio closes the socket —
+    takes the connection from the receiver first: what the thread had
+    received and the loop had not reaped is delivered BEFORE the close
+    is seen, in order; what a reap already holds for a connection that
+    was forgotten since goes to nobody."""
+    import time
+    tier = _rx_tier('receiver_thread')
+    wire, want = _reply_wire()
+    lost = await _RxPeer(0, tier).start()
+    kept = await _RxPeer(1, tier).start()
+    try:
+        lost.expect(REPLIES)
+        kept.expect(REPLIES)
+        lost.peer.send(wire)
+        kept.peer.send(wire)
+        time.sleep(0.05)        # received by the thread; the loop stood
+        assert not lost.chunks
+        token = lost.entry.rx_token
+        lost.conn.transport.abort()     # -> connection_lost, next turn
+        await _until(lambda: ('sockClose',) in lost.log)
+        assert lost.packets() == want
+        assert lost.log[-1] == ('sockClose',)
+        assert token not in tier._rx and lost.entry.rx_token == 0
+        await _until(lambda: sum(kept.chunks) == len(wire))
+        assert kept.packets() == want
+        # a reap that still names the forgotten token reaches nobody
+        items = [(token, b'late'), (kept.entry.rx_token, b'')]
+        real = tier._ext.receiver_reap
+        tier._ext = _ReapOnce(tier._ext, items)
+        del lost.chunks[:], kept.chunks[:]
+        tier._rx_reap()
+        assert not lost.chunks and not kept.chunks
+        assert ('sockEnd',) in kept.log
+        tier._ext = tier._ext.ext
+        assert tier._ext.receiver_reap is real
+    finally:
+        await lost.stop()
+        await kept.stop()
+        tier.close()
+
+
+class _ReapOnce:
+    """The extension with ONE reap's result put in its place."""
+
+    def __init__(self, ext, items):
+        self.ext, self.items = ext, items
+
+    def __getattr__(self, name):
+        return getattr(self.ext, name)
+
+    def receiver_reap(self, cap):
+        return self.items, 0, 0
+
+
+@needs_receiver
+async def test_one_raising_connection_keeps_its_error_to_itself():
+    """A connection whose handler raises inside a reap does not take
+    the batch with it (``_tick``'s rule): its neighbours get their
+    bytes."""
+    import time
+    tier = _rx_tier('receiver_thread')
+    wire, want = _reply_wire()
+    peers = [await _RxPeer(i, tier).start() for i in range(3)]
+    try:
+        def boom(_data):
+            raise RuntimeError('a handler broke')
+        peers[0].conn.on('sockData', boom)
+        for p in peers:
+            p.expect(REPLIES)
+            p.peer.send(wire)
+        time.sleep(0.05)            # one reap carries all three
+        for p in peers[1:]:
+            await _until(lambda: sum(p.chunks) == len(wire))
+            assert p.packets() == want
+    finally:
+        for p in peers:
+            await p.stop()
+        tier.close()
+
+
+@needs_receiver
+def test_receiver_tier_across_two_runs_moves_its_reader():
+    """One ``asyncio.run`` after another on one tier: the receiver's
+    ``eventfd`` is a reader of the loop whose connections it reads, a
+    connection of the second run is read like one of the first, and
+    the thread is the same."""
+    tier = _rx_tier('receiver_thread')
+    wire, want = _reply_wire()
+    seen = {}
+
+    async def one_run(tag: str):
+        p = await _RxPeer(0, tier).start()
+        p.expect(REPLIES)
+        p.peer.send(wire)
+        await _until(lambda: sum(p.chunks) == len(wire))
+        assert p.packets() == want and p.entry.rx_token
+        seen[tag] = (asyncio.get_running_loop(), tier._reader_loop,
+                     tier._receiver)
+        await p.stop()
+    try:
+        asyncio.run(one_run('run-1'))
+        asyncio.run(one_run('run-2'))
+        (loop1, reader1, thread1), (loop2, reader2, thread2) = (
+            seen['run-1'], seen['run-2'])
+        assert loop2 is not loop1
+        assert reader1 is loop1 and reader2 is loop2
+        assert thread1 is thread2
+    finally:
+        tier.close()
+        assert tier._receiver is None
+
+
+@needs_receiver
+async def test_where_no_receiver_is_to_be_had_asyncio_pushes(
+        monkeypatch):
+    """No extension (or one that predates the receiver), a
+    ``receiver_create`` that fails, a tier nobody armed (a member's):
+    the connection is read by its asyncio transport exactly as before,
+    and nothing of the receive half is touched."""
+    from zkstream_tpu.io import transport as tmod
+    wire, want = _reply_wire()
+
+    async def reads_by_push(tier):
+        p = await _RxPeer(0, tier).start()
+        try:
+            p.expect(REPLIES)
+            p.peer.send(wire)
+            await _until(lambda: sum(p.chunks) == len(wire))
+            assert p.packets() == want
+            assert p.entry.rx_transport is None and not p.entry.rx_token
+            assert tier._receiver is None and not tier._rx
+            assert tier.received_reads == 0
+            assert p.conn.transport.is_reading()
+        finally:
+            await p.stop()
+            tier.close()
+    unarmed = TransportTier('mmsg', plane='server')
+    await reads_by_push(unarmed)
+    monkeypatch.setattr(tmod, '_receiver_ext', lambda: None)
+    await reads_by_push(_rx_tier('receiver_thread'))
+
+    class _NoThread:
+        def receiver_create(self):
+            raise OSError(errno.EMFILE, 'no thread, no epoll')
+    monkeypatch.setattr(tmod, '_receiver_ext', _NoThread)
+    tier = _rx_tier('receiver_thread')
+    await reads_by_push(tier)
+    assert tier._rx_on is False         # asked once, not again
+
+
+@needs_receiver
+async def test_closing_the_tier_gives_live_connections_back():
+    """``tier.close()`` with connections alive (the last client of a
+    loop closed while others' sockets still are): what the thread had
+    is delivered, the thread is joined, and the connections read
+    through their own transports from then on."""
+    import time
+    tier = _rx_tier('receiver_thread')
+    wire, want = _reply_wire()
+    p = await _RxPeer(0, tier).start()
+    try:
+        p.expect(REPLIES)
+        p.peer.send(wire[:200])
+        time.sleep(0.05)            # with the thread, unreaped
+        tier.close()
+        assert tier._receiver is None and not tier._rx
+        assert sum(p.chunks) == 200
+        assert p.conn.transport.is_reading()
+        p.peer.send(wire[200:])
+        await _until(lambda: sum(p.chunks) == len(wire))
+        assert p.packets() == want
+    finally:
+        await p.stop()
+
+
 # -- e2e over real sockets: parity + accounting + mntr -----------------
 
 async def _scripted_ops(backend: str) -> list[tuple]:

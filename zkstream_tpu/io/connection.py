@@ -132,15 +132,51 @@ class ZKRequest(EventEmitter):
 
 
 class _SocketProtocol(asyncio.Protocol):
-    """Thin adapter: socket callbacks -> connection events."""
+    """Socket callbacks -> connection events, on one of two receive
+    paths, decided when the connection is made (the tier entry's
+    ``rx_transport`` says which):
+
+    - the loop's shared client tier owns the receive
+      (io/transport.py, "Who receives": ``mmsg``, the extension built):
+      the transport's reading is paused for good, a native receiver
+      thread ``recv``s the socket and the tier's reap calls
+      :meth:`ZKConnection._sock_data` with the bytes, :meth:`_rx_eof`
+      at EOF and :meth:`_rx_error` with a hard errno.
+      ``data_received`` then runs only for bytes that landed in the
+      one-callback window before the tier claimed the fd;
+    - asyncio pushes (every other tier, and none): ``data_received`` /
+      ``eof_received`` as ever.
+
+    Both feed the same ``_sock_data`` -> ``sockData``, so the fault
+    injector's boundary, the ``client.rx`` span and every state's
+    handlers do not know which one brought the bytes."""
 
     def __init__(self, conn: 'ZKConnection'):
         self._conn = conn
+        self._transport = None
+        #: the errno that ended an adopted receive: ``connection_lost``
+        #: reports it, as it reports a failed ``recv`` of asyncio's
+        self._rx_exc: OSError | None = None
 
     def connection_made(self, transport) -> None:
         set_nodelay(transport)
+        self._transport = transport
         self._conn.transport = transport
+        self._conn._proto = self
+        self.adopt()
         self._conn.emit('sockConnect')
+
+    def adopt(self) -> bool:
+        """Hand the socket's receive to the tier, if it takes it."""
+        return self._conn._tx.adopt_rx(
+            self._transport, self._conn._sock_data, self._rx_eof,
+            self._rx_error)
+
+    def release(self) -> None:
+        """Out of the tier's receiver, if it reads this socket (what
+        it had is delivered first); the transport's reading stays
+        paused."""
+        self._conn._tx.forget_rx(self._transport)
 
     def data_received(self, data: bytes) -> None:
         self._conn._sock_data(data)
@@ -149,10 +185,24 @@ class _SocketProtocol(asyncio.Protocol):
         self._conn.emit('sockEnd')
         return True  # keep half-open, like the reference's allowHalfOpen
 
+    _rx_eof = eof_received
+
+    def _rx_error(self, exc: OSError) -> None:
+        """A hard errno on the adopted socket: what asyncio does with
+        a failed ``recv`` — the transport is torn down and
+        ``connection_lost`` carries the error."""
+        self._rx_exc = exc
+        self._transport.abort()
+
     def connection_lost(self, exc) -> None:
-        # asyncio closes the socket as soon as this returns: no send of
-        # it may still be in flight on the tier's sender thread then
+        # asyncio closes the socket as soon as this returns: no send
+        # and no receive of it may still be in flight on the tier's
+        # threads then; what the receiver had is delivered before the
+        # close is seen
         self._conn._tx.quiesce()
+        self.release()
+        if exc is None:
+            exc = self._rx_exc
         if exc is not None:
             self._conn.emit('sockError', exc)
         else:
@@ -180,6 +230,8 @@ class ZKConnection(FSM):
             zkPort=backend.port)
         self.codec: PacketCodec | None = None
         self.transport = None
+        #: ``transport``'s protocol (which knows the receive path)
+        self._proto: _SocketProtocol | None = None
         self.session = None
         #: Optional FleetIngest: when the owning client carries one,
         #: connected-state bytes drain through the batched device
@@ -253,6 +305,22 @@ class ZKConnection(FSM):
         # only — the TCP dial was paid when it parked
         self._connect_t0 = time.monotonic()
         self.emit('promoteAsserted')
+
+    def pause_reading(self) -> None:
+        """Stop reading the socket — a stalled consumer (tests, the
+        chaos schedules' ``stall``).  The transport's own
+        ``pause_reading`` alone would not do where the tier's receiver
+        thread reads the socket: this stops whichever reads it."""
+        t = self.transport
+        if t is not None:
+            # what the thread had is delivered here, and may close us
+            self._proto.release()
+            t.pause_reading()
+
+    def resume_reading(self) -> None:
+        """Read again, on the path the connection was made on."""
+        if self.transport is not None and not self._proto.adopt():
+            self.transport.resume_reading()
 
     def next_xid(self) -> int:
         self._xid += 1

@@ -1556,8 +1556,7 @@ def _make_force_overload(res, ovrng, note_member, live_address,
             c = pick_client()
             conn = (c.current_connection()
                     if c is not None else None)
-            t = getattr(conn, 'transport', None)
-            if t is None:
+            if getattr(conn, 'transport', None) is None:
                 return
             lo, hi = (cfg.stall_window_ms if cfg is not None
                       else (20.0, 120.0))
@@ -1565,12 +1564,12 @@ def _make_force_overload(res, ovrng, note_member, live_address,
             note_member('overload-stall(%.0fms)'
                         % (window * 1e3), '-')
             try:
-                t.pause_reading()
+                conn.pause_reading()
             except (RuntimeError, OSError):
                 return
             await asyncio.sleep(window)
             try:
-                t.resume_reading()
+                conn.resume_reading()
             except (RuntimeError, OSError):
                 pass
         else:

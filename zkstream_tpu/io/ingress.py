@@ -50,6 +50,11 @@ the kernel in one batched call —
   plus the per-connection ``reader.read()`` task, exactly yesterday's
   path (``shards=1`` resolves here too).
 
+This plane is the MEMBERS' receive.  A client fleet's own sits in its
+loop's shared transport tier (io/transport.py, "Who receives": the same
+paused transports, read by one native receiver thread and reaped by
+the loop behind one ``eventfd``); the two never share a connection.
+
 Knobs, capability-probed and env-forced exactly like io/transport.py
 (forcing falls DOWN the order, never up):
 

@@ -344,6 +344,24 @@ class SendPlane:
         if self._entry is not None:
             self._tier.quiesce(self._entry)
 
+    def adopt_rx(self, transport, on_bytes, on_eof, on_error) -> bool:
+        """The connection's other direction, for a tier that owns it
+        (``TransportTier.rx_adopt``: the loop's shared client tier on
+        ``mmsg``): True when the tier's receiver thread reads
+        ``transport``'s socket from now on and hands the connection
+        its bytes, its EOF and its error; False when asyncio's
+        protocol push stays."""
+        return (self._entry is not None
+                and self._tier.rx_adopt(self._entry, transport,
+                                        on_bytes, on_eof, on_error))
+
+    def forget_rx(self, transport) -> None:
+        """Before ``transport``'s socket is closed, or to stop reading
+        it: out of the tier's receiver thread, what it had received
+        delivered first (``TransportTier.rx_forget``)."""
+        if self._entry is not None:
+            self._tier.rx_forget(self._entry, transport)
+
     def reset(self) -> None:
         """Drop corked frames without writing (connection aborted:
         the bytes have nowhere to go) — anything already deferred to

@@ -1,4 +1,6 @@
-"""Batched-syscall transport backends beneath the send plane.
+"""Batched-syscall transport backends beneath the send plane — and,
+for the loop's shared client tier, the receive side of the same
+connections ("Who receives", below).
 
 The tick cork (io/sendplane.py) already joins every frame a connection
 sends within one event-loop iteration into one ``transport.write`` —
@@ -83,6 +85,34 @@ reaped (its new chunks wait in its entry: order on a connection);
 and so never reused, under a send in flight); the entry's in-flight
 bytes stay in ``SendPlane.buffered_bytes()``.
 
+Who receives.  A reply costs the loop one ``recv(2)`` a connection
+behind asyncio's selector transport (``_read_ready`` ->
+``data_received``).  The tier that ``attach_sender`` arms — the loop's
+shared client tier on ``mmsg``, and no other — also owns its
+connections' RECEIVE: ``rx_adopt`` (the connection's protocol, at
+``connection_made``) pauses the asyncio transport's reading and, one
+callback later (``_rx_claim``: a 3.10-3.11 selector transport re-takes
+the fd once behind ``connection_made``), registers the fd with ONE
+native receiver thread (``zkwire_ext.receiver_*``: its own ``epoll``,
+one ``recv`` a ready connection, no Python object, no GIL).  The
+thread's ``eventfd`` wakes the loop and ``_rx_reap`` hands each
+connection its bytes in the order they were received
+(``_Entry.on_bytes``; EOF and a hard errno likewise, once).  So a
+connection is on exactly one of two paths, decided when it is made and
+visible as ``entry.rx_transport``: (a) the receiver thread — client
+tier, ``mmsg``, a selector loop, the extension built and its thread
+started; (b) asyncio's protocol push, exactly as it was — everything
+else (the ``asyncio`` backend has no tier; ``uring``; a member's tier;
+no extension yet; ``receiver_create`` failing; a loop without
+``_remove_reader``).  What holds on (a): bytes are keyed by the
+receiver's token, never by fd number; ``rx_forget``
+(``connection_lost``, before asyncio closes the socket) returns only
+when no ``recv`` of the fd is in flight, and delivers what was received
+and not yet reaped first; a connection's unreaped bytes are bounded in
+the thread (``RECEIVER_LIMIT``), behind which the kernel's socket
+buffer pushes back on the peer; ``close`` gives live connections back
+to their transports.
+
 Observability: ``zookeeper_flush_syscalls_total{plane,backend}``
 counts actual write submissions (the A/B number: O(dirty conns) per
 tick on mmsg/asyncio, O(1) on uring) and ``zookeeper_submit_depth``
@@ -97,7 +127,14 @@ flush; a hand-over is ``client.handoff``, a reap ``client.reap``, and
 the nanoseconds inside their send loop (the sender's own clock, or the
 loop's around an inline submission).  Always on:
 ``zookeeper_flush_offloaded_total{plane}``, ``tier.offloaded_flushes``,
-``tier.offloaded_batches``.
+``tier.offloaded_batches``; for the receive
+``zookeeper_recv_offloaded_total{plane}``, ``tier.received_reads``
+(deliveries through a reap), ``tier.received_batches`` (reaps that
+brought any).  Under a profiler session a reap is ``client.rx_reap``
+(the connections' ``client.rx`` spans nest inside it),
+``client.rx_reaped`` counts the deliveries it made and ``client.recv``
+totals the thread's ``recv(2)`` calls with the nanoseconds inside them,
+on the thread's own clock.
 """
 
 from __future__ import annotations
@@ -127,6 +164,7 @@ METRIC_SUBMIT_DEPTH = 'zookeeper_submit_depth'
 METRIC_FLUSH_PARTIAL = 'zookeeper_flush_partial_total'
 METRIC_FLUSH_REQUEUED = 'zookeeper_flush_partial_requeued_bytes'
 METRIC_FLUSH_OFFLOADED = 'zookeeper_flush_offloaded_total'
+METRIC_RECV_OFFLOADED = 'zookeeper_recv_offloaded_total'
 
 #: Connections in one raw batch from which the loop's shared client
 #: tier hands the batch to its native sender thread instead of sending
@@ -275,10 +313,15 @@ class _Entry:
     next tick submission.  The resolved fd is cached keyed on the
     transport's identity — safe against fd reuse because it is only
     consulted while ``transport_fn()`` returns that same, still-open
-    transport object."""
+    transport object.  Its receive half, on a tier that owns the
+    receive (``TransportTier.rx_adopt``): the transport whose reading
+    the tier took over, the receiver's token for it, and where the
+    connection wants its bytes, its EOF and its error."""
 
     __slots__ = ('transport_fn', 'write', 'chunks', 'nbytes',
-                 'batch', 'flying', 'stamps', '_t', '_fd')
+                 'batch', 'flying', 'stamps', '_t', '_fd',
+                 'rx_transport', 'rx_token', 'on_bytes', 'on_eof',
+                 'on_error')
 
     def __init__(self, write, transport_fn):
         self.write = write              # the plane's asyncio sink
@@ -295,6 +338,11 @@ class _Entry:
         self.stamps: list = []
         self._t = None
         self._fd = -1
+        #: None = asyncio's protocol push; else the adopted transport,
+        #: and its token once ``_rx_claim`` registered the fd (0 until)
+        self.rx_transport = None
+        self.rx_token = 0
+        self.on_bytes = self.on_eof = self.on_error = None
 
     def resolve_fd(self, t) -> int:
         if t is self._t:
@@ -360,6 +408,13 @@ class TransportTier:
         self._ext = None
         self._reader_loop = None
         self._inflight: dict[int, list] = {}
+        #: The native receiver (``attach_sender`` too): whether new
+        #: connections' receive is taken over, the thread's capsule
+        #: once the first one was, and token -> entry for every
+        #: connection it reads.
+        self._rx_on = False
+        self._receiver = None
+        self._rx: dict[int, _Entry] = {}
         #: Profiler sessions only, inside :meth:`_tick` (else 0): when
         #: this tick's flush began — the ``t_flush`` of the requests
         #: whose bytes it submits or hands over (utils/trace.py).
@@ -376,6 +431,10 @@ class TransportTier:
         #: of ``flushes`` / ``submissions``, those the sender took
         self.offloaded_flushes = 0
         self.offloaded_batches = 0
+        #: ``on_bytes`` deliveries the receiver's reaps made, and the
+        #: reaps that brought any
+        self.received_reads = 0
+        self.received_batches = 0
         #: Clients holding a :class:`TierLease` on this tier (a
         #: server's tier is its own and stays at 0).
         self.refs = 0
@@ -386,6 +445,9 @@ class TransportTier:
         self._send_span = plane + '.send'
         self._handoff_span = plane + '.handoff'
         self._reap_span = plane + '.reap'
+        self._rx_reap_span = plane + '.rx_reap'
+        self._rx_reaped_span = plane + '.rx_reaped'
+        self._recv_span = plane + '.recv'
         #: The tier's own series: registered with the collector it
         #: was given, standalone without one (a loop's shared client
         #: tier belongs to no client's collector; each joined client
@@ -412,20 +474,28 @@ class TransportTier:
             METRIC_FLUSH_OFFLOADED,
             'Raw connection flushes sent by the native sender thread '
             'instead of the event loop, by plane')
+        self.received_ctr = source.counter(
+            METRIC_RECV_OFFLOADED,
+            'Connection reads received by the native receiver thread '
+            'instead of the event loop, by plane')
 
     @property
     def series(self) -> tuple:
         """The tier's own series (what a joined client's collector
         adopts)."""
         return (self.syscall_ctr, self.depth_hist, self.partial_ctr,
-                self.requeued_ctr, self.offloaded_ctr)
+                self.requeued_ctr, self.offloaded_ctr,
+                self.received_ctr)
 
     def attach_sender(self) -> None:
         """Hand deep ``mmsg`` batches to a native sender thread from
         now on (made when the first such batch comes, so a lone
-        client's tier never starts one).  ``uring`` has nothing to
-        hand over: its batch is one ``io_uring_enter``."""
-        self._handoff = self.backend == 'mmsg'
+        client's tier never starts one), and the receive of every
+        connection made from now on to a native receiver thread (made
+        when the first one is).  ``uring`` has nothing to hand over:
+        its batch is one ``io_uring_enter``, and its receive stays
+        asyncio's."""
+        self._handoff = self._rx_on = self.backend == 'mmsg'
 
     # -- SendPlane-facing API --
 
@@ -644,6 +714,7 @@ class TransportTier:
         """Queue one raw batch for the sender thread; False when there
         is no sender to be had (the extension is not built, or not yet:
         the batch then goes inline)."""
+        loop = ambient_loop()
         if self._sender is None:
             ext = _sender_ext()
             if ext is None:
@@ -654,8 +725,8 @@ class TransportTier:
                 self._handoff = False   # no thread to be had here
                 return False
             self._ext = ext
-        loop = ambient_loop()
-        if loop is not self._reader_loop:
+            self._move_reader(loop)     # its eventfd is no reader yet
+        elif loop is not self._reader_loop:
             self._move_reader(loop)
         with host_span(self._handoff_span, accumulate=True):
             batch = self._ext.sender_submit(self._sender, fds,
@@ -672,15 +743,24 @@ class TransportTier:
         return True
 
     def _move_reader(self, loop) -> None:
-        """The sender's ``eventfd`` is a reader of the loop that hands
-        batches over (a tier reused across ``asyncio.run`` calls moves
-        it; what an ended loop left in flight is reaped on the next)."""
-        fd = self._ext.sender_fileno(self._sender)
+        """The sender's and the receiver's ``eventfd`` are readers of
+        the loop the tier works on (a tier reused across
+        ``asyncio.run`` calls moves them; what an ended loop left in
+        flight, or unreaped, is reaped on the next)."""
+        ext = self._ext
         old, self._reader_loop = self._reader_loop, loop
-        if old is not None and not old.is_closed():
-            old.remove_reader(fd)
-        if loop is not None:
-            loop.add_reader(fd, self._reap)
+        for fd, reap in (
+                (-1 if self._sender is None
+                 else ext.sender_fileno(self._sender), self._reap),
+                (-1 if self._receiver is None
+                 else ext.receiver_fileno(self._receiver),
+                 self._rx_reap)):
+            if fd < 0:
+                continue
+            if old is not None and not old.is_closed():
+                old.remove_reader(fd)
+            if loop is not None:
+                loop.add_reader(fd, reap)
 
     def _reap(self) -> None:
         """Apply what the sender finished, oldest batch first, exactly
@@ -701,6 +781,146 @@ class TransportTier:
                 self._apply(raw_entries, results)
             if held:
                 self._schedule()
+
+    # -- the native receiver (the loop's shared client tier) --
+
+    def rx_adopt(self, entry: _Entry, transport, on_bytes, on_eof,
+                 on_error) -> bool:
+        """Take over the receive of one connection that was just made
+        (its protocol's ``connection_made``): pause the transport's
+        reading now, register the fd with the receiver thread one
+        callback later (:meth:`_rx_claim`).  False — and nothing
+        touched — where asyncio's protocol push stays: a tier that was
+        not armed, a loop that is no selector loop, no fd, no extension
+        (yet), no thread to be had."""
+        if not self._rx_on:
+            return False
+        loop = ambient_loop()
+        if (not hasattr(loop, '_remove_reader')
+                or entry.resolve_fd(transport) < 0):
+            return False
+        if self._receiver is None:
+            ext = _receiver_ext()
+            if ext is None:
+                return False
+            try:
+                self._receiver = ext.receiver_create()
+            except OSError:
+                self._rx_on = False     # no thread to be had here
+                return False
+            self._ext = ext
+            self._move_reader(loop)     # its eventfd is no reader yet
+        elif loop is not self._reader_loop:
+            self._move_reader(loop)
+        if entry.rx_transport is not None:
+            # the entry's earlier socket never saw connection_lost
+            self.rx_forget(entry, entry.rx_transport, deliver=False)
+        transport.pause_reading()
+        entry.rx_transport = transport
+        entry.on_bytes, entry.on_eof, entry.on_error = (
+            on_bytes, on_eof, on_error)
+        loop.call_soon(self._rx_claim, entry, transport)
+        return True
+
+    def _rx_claim(self, entry: _Entry, transport) -> None:
+        """One callback behind ``connection_made``: a 3.10-3.11
+        selector transport queued its own reader registration there
+        and checks only ``_closing``, not ``_paused``, so it has the fd
+        again by now (io/ingress.py ``_adopted`` has the same window);
+        bytes that landed in it came through ``data_received`` and the
+        same ``on_bytes``.  Take the fd from the loop's selector, then
+        hand it to the thread — never both at once."""
+        if entry.rx_transport is not transport or entry.rx_token:
+            return          # forgotten, or given back, meanwhile
+        fd = entry.resolve_fd(transport)
+        try:
+            if transport.is_closing() or self._receiver is None:
+                raise OSError(errno.EBADF, 'connection is closing')
+            ambient_loop()._remove_reader(fd)
+            token = self._ext.receiver_add(self._receiver, fd)
+        except (OSError, ValueError, RuntimeError):
+            self._rx_give_back(entry)
+            return
+        entry.rx_token = token
+        self._rx[token] = entry
+
+    def _rx_give_back(self, entry: _Entry) -> None:
+        """The connection's receive returns to its asyncio transport:
+        the thread does not read it (or no longer: what it had is
+        delivered first)."""
+        transport = entry.rx_transport
+        self._rx_release(entry)
+        entry.rx_transport = None
+        if transport is not None and not transport.is_closing():
+            try:
+                transport.resume_reading()
+            except (OSError, RuntimeError):
+                pass        # its loop is closed: nobody reads it again
+
+    def _rx_release(self, entry: _Entry, deliver: bool = True) -> None:
+        """Out of the receiver thread: returns only once no ``recv`` of
+        the fd is in flight there, and first delivers, in order, what
+        was received and not yet reaped."""
+        token, entry.rx_token = entry.rx_token, 0
+        if token and self._rx.pop(token, None) is not None:
+            left = self._ext.receiver_forget(self._receiver, token)
+            if deliver:
+                for data in left:
+                    self._rx_deliver(entry, data)
+
+    def rx_forget(self, entry: _Entry, transport,
+                  deliver: bool = True) -> None:
+        """The connection's socket is about to be closed
+        (``connection_lost``: asyncio closes it right after), or its
+        reading is to stop: take it from the receiver thread
+        (:meth:`_rx_release`) — an fd is never closed, and so never
+        reused, under a receive in flight.  Not from inside one of the
+        tier's own deliveries."""
+        if entry.rx_transport is not transport:
+            return
+        self._rx_release(entry, deliver)
+        entry.rx_transport = None
+        entry.on_bytes = entry.on_eof = entry.on_error = None
+
+    def _rx_deliver(self, entry: _Entry, data) -> bool:
+        """One ``bytes | -errno`` of a reap or a hand-back to its
+        connection; True for bytes.  A raising connection keeps its
+        error to itself (``_tick``'s rule)."""
+        try:
+            if data.__class__ is not bytes:
+                entry.on_error(OSError(-data, os.strerror(-data)))
+            elif data:
+                entry.on_bytes(data)
+                return True
+            else:
+                entry.on_eof()
+        except Exception:
+            log.exception('transport receive callback failed')
+            return bool(data)
+        return False
+
+    def _rx_reap(self) -> None:
+        """The receiver's ``eventfd`` is readable: hand every
+        connection what the thread received for it, in the thread's
+        order.  A token no connection holds any more (forgotten since)
+        is dropped: a closed connection's bytes reach nobody else."""
+        if self._receiver is None:
+            return      # a wake-up that outlived close()
+        with host_span(self._rx_reap_span, accumulate=True):
+            items, calls, ns = self._ext.receiver_reap(self._receiver)
+            if calls:
+                host_add(self._recv_span, calls, ns)
+            rx = self._rx
+            n = 0
+            for token, data in items:
+                e = rx.get(token)
+                if e is not None and self._rx_deliver(e, data):
+                    n += 1
+        if n:
+            host_add(self._rx_reaped_span, n, 0)
+            self.received_batches += 1
+            self.received_reads += n
+            self.received_ctr.increment({'plane': self.plane}, by=n)
 
     def _settle(self, entry: _Entry, chunks: list[bytes],
                 nbytes: int, res: int) -> None:
@@ -805,21 +1025,42 @@ class TransportTier:
                     pass
             self._uring = None
         if self._sender is not None:
-            # what is in flight goes out and is settled, then the
-            # thread is joined; the next deep batch starts another
+            # what is in flight goes out and is settled
             if self._inflight:
                 self._ext.sender_wait(self._sender, max(self._inflight))
             self._reap()
+        if self._receiver is not None:
+            # what was received is delivered; connections that live on
+            # read through their own transports again
+            self._rx_reap()
+        if self._reader_loop is not None:
             self._move_reader(None)
+        if self._sender is not None:
+            # the thread is joined; the next deep batch starts another
             self._ext.sender_close(self._sender)
             self._sender = None
+        if self._receiver is not None:
+            # joined likewise (no recv in flight from here on), and
+            # the next connection made starts another
+            for e in list(self._rx.values()):
+                self._rx_give_back(e)
+            self._ext.receiver_close(self._receiver)
+            self._receiver = None
+
+
+def _ext_with(name: str):
+    """The extension, if it is built and has ``name``."""
+    from ..utils.native import get_ext
+    ext = get_ext()
+    return ext if hasattr(ext, name) else None
 
 
 def _sender_ext():
-    """The extension, if it is built and has the sender."""
-    from ..utils.native import get_ext
-    ext = get_ext()
-    return ext if hasattr(ext, 'sender_submit') else None
+    return _ext_with('sender_submit')
+
+
+def _receiver_ext():
+    return _ext_with('receiver_reap')
 
 
 def make_tier(arg: str | None, collector=None, plane: str = 'server',
