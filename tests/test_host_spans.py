@@ -343,6 +343,57 @@ def test_host_add_is_armed_by_the_session_alone(monkeypatch):
     assert len(trace.host_ring) == 0
 
 
+async def test_a_sessions_birth_and_death_are_booked(server, monkeypatch):
+    """``client.connect`` (``start()`` -> the first ``'connect'``) and
+    ``client.close`` (``close()`` from the call to its return) are
+    totals on the wall clock, one count a session, inside a profiler
+    session and not outside one."""
+    quiet = Client(address='127.0.0.1', port=server.port,
+                   session_timeout=5000)
+    quiet.start()
+    await quiet.wait_connected(timeout=5)
+    assert 'client.connect' not in trace.host_ring.totals
+    monkeypatch.setattr(trace, '_is_enabled', lambda: True)
+    c = Client(address='127.0.0.1', port=server.port,
+               session_timeout=5000)
+    c.start()
+    await c.wait_connected(timeout=5)
+    await c.create('/born', b'')
+    born = trace.host_ring.totals['client.connect']
+    assert born[0] == 1 and born[1] > 0
+    assert 'client.close' not in trace.host_ring.totals
+    await c.close()
+    await quiet.close()     # connected before the session: its close counts
+    assert trace.host_ring.totals['client.connect'] == born
+    gone = trace.host_ring.totals['client.close']
+    assert gone[0] == 2 and gone[1] > 0
+
+
+async def test_the_route_counts_the_names_it_delivered(server, armed):
+    """``names_routed`` (always on) and the ``ingest.route`` span's
+    ``names``: the names in the children lists a device tick routed."""
+    ingest = _ingest()
+    c = await _fleet_client(server, ingest)
+    try:
+        await c.create('/dir', b'')
+        for i in range(5):
+            await c.create('/dir/n%d' % (i,), b'')
+        assert ingest.names_routed == 0
+        trace.host_ring.reset()
+        for _ in range(3):
+            names, _stat = await c.list('/dir')
+            assert len(names) == 5
+        await c.get('/dir')
+        assert ingest.names_routed == 15
+        routes = [s for s in trace.host_ring.spans()
+                  if s.op == 'ingest.route']
+        assert sum(s.names for s in routes) == 15
+        assert all(s.to_dict()['names'] == s.names for s in routes
+                   if s.names)
+    finally:
+        await c.close()
+
+
 async def test_a_loops_requests_share_its_deadline_timer(server, armed):
     """The deadline queue's engagement counter: ``client.deadline`` is
     an arming or a firing of the loop's ONE timer, so ``client.submit``'s

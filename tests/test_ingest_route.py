@@ -528,3 +528,51 @@ async def test_lane_is_what_state_connected_registers():
     p.session.close()
     await settle()
     ingest.close()
+
+
+@pytest.mark.parametrize('whole', [True, False], ids=['whole', 'partial'])
+async def test_a_close_drains_the_reply_its_slot_held(whole):
+    """A connection that closes with a request out and that request's
+    reply in its ingest slot — whole and waiting for the next tick, or
+    cut short by the segment — gets the reply: the slot's bytes come
+    back to the codec (``unregister``), the closing state drains what
+    is whole at once and the rest as it arrives, and CLOSE_SESSION
+    follows.  (No later byte completes a WHOLE reply: it waited for
+    the session's timeout.)"""
+    ingest = FleetIngest(bypass_bytes=0, warm='block', placement='host',
+                         max_frames=4, min_len=256)
+    p = Peer(0, ingest, False, random.Random(2))
+    try:
+        xid = p.get()
+        p.reply(xid)
+        wire = bytes(p.wire)
+        p.wire.clear()
+        cut = len(wire) if whole else len(wire) - 7
+        p.conn.emit('sockData', wire[:cut])     # into the slot; no tick yet
+        assert p.pending(ingest) == wire[:cut] and xid in p.conn.reqs
+        def closes():
+            # CLOSE_SESSION frames written so far (a bare header, op -11)
+            return [b for b in p.conn.transport.out if len(b) == 12
+                    and struct.unpack('>i', b[8:12])[0] == -11]
+
+        p.conn.close()
+        assert p.conn.is_in_state('closing')
+        assert id(p.conn) not in ingest._slots
+        await settle()      # the drain runs a turn after the close
+        if not whole:
+            assert xid in p.conn.reqs
+            assert not closes()         # the reply is not whole yet
+            p.conn.emit('sockData', wire[cut:])
+        assert xid not in p.conn.reqs
+        assert ('fut', xid) in [e[:2] for e in p.log]
+        assert len(closes()) == 1               # CLOSE_SESSION, once
+        close_xid = struct.unpack('>i', closes()[0][4:8])[0]
+        p.conn.emit('sockData', p.srv.encode({
+            'xid': close_xid, 'zxid': p.zxid, 'err': 'OK',
+            'opcode': 'CLOSE_SESSION'}))
+        assert p.conn.is_in_state('closed')
+    finally:
+        p.conn.destroy()
+        p.session.close()
+        await settle()
+        ingest.close()

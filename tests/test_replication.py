@@ -396,6 +396,33 @@ async def test_batch_is_made_durable_and_quorum_held_once(
         db.wal.close()
 
 
+async def test_a_write_behind_a_touch_does_not_wait_for_its_ack(repl):
+    """A ``touch`` has no response, so the RPC written behind it (a new
+    session's first write through a follower) used to sit in the
+    kernel until the leader's delayed ACK of the touch, ~40 ms later:
+    the control channel runs without Nagle."""
+    import socket
+    import time
+
+    db, svc, connect = repl
+    remote = await connect()
+    assert remote._sock.getsockopt(socket.IPPROTO_TCP,
+                                   socket.TCP_NODELAY) != 0
+    db.create('/t', b'0', OPEN_ACL_UNSAFE, CreateFlag(0))
+    took = []
+    for i in range(5):
+        sess = db.create_session(30000)
+        sess.last_touch_fwd = 0.0
+
+        def touch_then_write(sess=sess, i=i):
+            t = time.perf_counter()
+            remote.touch_session(sess)
+            remote.forward([_set('/t', b'%d' % i)])
+            return time.perf_counter() - t
+        took.append(await _rpc(touch_then_write))
+    assert min(took) < 0.030, took
+
+
 async def test_fenced_batch_fails_every_element_and_applies_nothing(
         repl):
     db, svc, connect = repl

@@ -50,7 +50,8 @@ from .utils.aio import DeadlineExpired, ambient_loop, deadline_queue
 from .utils.fsm import FSM, bind_transition_metrics
 from .utils.logging import Logger
 from .utils.metrics import Collector
-from .utils.trace import NO_SPAN, TraceRing, host_span, op_resumed
+from .utils.trace import NO_SPAN, TraceRing, host_add, host_span, \
+    op_resumed
 
 METRIC_ZK_EVENT_COUNTER = 'zookeeper_events'
 METRIC_ZK_DEGRADED_GAUGE = 'zookeeper_degraded'
@@ -273,6 +274,9 @@ class Client(FSM):
                                 'ConnectionPool')
 
         self._started = False
+        #: ``start()``'s stamp until the first ``'connect'`` books the
+        #: session's birth (host total ``client.connect``)
+        self._t_start: int | None = None
         super().__init__('normal')
 
     # -- lifecycle (reference: lib/client.js:127-215) --
@@ -322,6 +326,7 @@ class Client(FSM):
         reference starts its resolver in the constructor)."""
         assert not self._started, 'client already started'
         self._started = True
+        self._t_start = time.perf_counter_ns()
         self.pool.start()
         if self._read_plane is not None:
             self._read_plane.start()
@@ -332,6 +337,7 @@ class Client(FSM):
         """Close the session cleanly and stop the pool."""
         if self.is_in_state('closed'):
             return
+        t0 = time.perf_counter_ns()
         loop = ambient_loop()
         fut: asyncio.Future = loop.create_future()
         self.once('close', lambda: fut.done() or fut.set_result(None))
@@ -345,6 +351,7 @@ class Client(FSM):
         # instead of waiting on cyclic GC (the plane/entry closures
         # keep the tier in a cycle); a reused client joins again
         self._tier_lease.release()
+        host_add('client.close', 1, time.perf_counter_ns() - t0)
 
     def update_backends(self, backends) -> bool:
         """Adopt a new live member list (README "Dynamic
@@ -419,6 +426,10 @@ class Client(FSM):
         return self.session
 
     def _event_track(self, evt: str) -> None:
+        if evt == 'connect' and self._t_start is not None:
+            host_add('client.connect', 1,
+                     time.perf_counter_ns() - self._t_start)
+            self._t_start = None
         if evt in ('session', 'connect', 'failed', 'degraded',
                    'recovered'):
             self.collector.get_collector(

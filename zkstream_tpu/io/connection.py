@@ -652,6 +652,16 @@ class ZKConnection(FSM):
 
         if not self.reqs:
             send_close_session()
+        elif self.ingest is not None:
+            # the fleet ingest's slot came back to the codec when
+            # ``connected`` was left (``unregister``).  A reply that
+            # sat there WHOLE, waiting for the next tick, is completed
+            # by no later byte: drain it, or the close waits for the
+            # session to time out.  One turn later: a close asserted
+            # from inside a tick's route (a callback of an earlier
+            # stream) comes before the route has handed back the xids
+            # its batch decode took for these bytes
+            S.immediate(lambda: on_data(b''))
 
     def state_error(self, S) -> None:
         self.log.warning('error communicating with ZK: %s',

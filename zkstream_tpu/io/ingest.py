@@ -347,6 +347,11 @@ class FleetIngest:
         #: follow-up tick because the tick's batch memory
         #: (``TICK_BYTES``) was full
         self.ticks_full = 0
+        #: names the routed children lists held (GET_CHILDREN /
+        #: GET_CHILDREN2 replies of the device ticks): what a fleet
+        #: that watches wide directories pays the list parse and its
+        #: listeners for, a ``str`` each
+        self.names_routed = 0
         #: Upper dispatch guard: when a large fleet's connections
         #: desynchronize, the tick batches fragment (a small share of
         #: the slots hold a frame) and the per-socket drain is the
@@ -853,7 +858,10 @@ class FleetIngest:
                  'was not whole yet'),
                 ('zkstream_ingest_full_ticks', 'ticks_full',
                  'device ticks that left whole frames in their slots '
-                 'because the tick\'s batch memory was full')):
+                 'because the tick\'s batch memory was full'),
+                ('zkstream_ingest_routed_names', 'names_routed',
+                 'names in the children lists the device ticks '
+                 'routed')):
             collector.gauge(prefix + name,
                             (lambda a=attr: getattr(self, a)),
                             help_text)
@@ -1365,6 +1373,7 @@ class FleetIngest:
         t3 = time.perf_counter()
         with host_span('ingest.route', tick=n) as rsp:
             laned = emitted = 0
+            names = self.names_routed
             self.routing = n
             try:
                 for plan, (ints, byts) in zip(plans, results):
@@ -1378,7 +1387,8 @@ class FleetIngest:
                     emitted += b
             finally:
                 self.routing = None
-            rsp.set(lane=laned, emitted=emitted)
+            rsp.set(lane=laned, emitted=emitted,
+                    names=self.names_routed - names)
         t4 = time.perf_counter()
         observe = self.phase_hist.observe
         for labels, a, b in zip(_PHASE_LABELS, (t0, t1, t2, t3),
@@ -1452,6 +1462,10 @@ class FleetIngest:
                 del buf[:resids[i]]
             self.frames_routed += n
             routed += n
+            for pkt in pkts:
+                kids = pkt.get('children')
+                if kids:
+                    self.names_routed += len(kids)
             if pkts or err is not None:
                 if lane is None:
                     conn.emit('ingestDeliver', pkts, err)

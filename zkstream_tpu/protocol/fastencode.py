@@ -90,6 +90,32 @@ def _acl_bytes(acl):
         return None
 
 
+def children_body(kids) -> bytes:
+    """The part of a GET_CHILDREN / GET_CHILDREN2 reply that is the
+    same for every asker: the count and the names.  (The server keeps
+    it a path while the node's Stat stands, server/server.py
+    ``ChildrenReplyCache``; ``GET_CHILDREN2`` puts :func:`stat_bytes`
+    behind it.)"""
+    parts = [_INT.pack(len(kids))]
+    for c in kids:
+        cb = c.encode('utf-8')
+        n = len(cb)
+        parts.append(_INT.pack(n if n else -1))
+        parts.append(cb)
+    return b''.join(parts)
+
+
+def stat_bytes(stat) -> bytes:
+    """The 68-byte Stat record."""
+    return _STAT.pack(*stat)
+
+
+def reply_frame(xid: int, zxid: int, body: bytes) -> bytes:
+    """An OK reply: the framed 16-byte header of its own ``xid`` /
+    ``zxid`` in front of an already encoded body."""
+    return _RESP_HDR.pack(16 + len(body), xid, zxid, 0) + body
+
+
 class FastEncoder:
     """Per-codec single-pass encoder (stateless; the class keeps the
     tier's dispatch tables and the codec-facing API in one place)."""
@@ -312,24 +338,13 @@ class FastEncoder:
         return self._children(pkt, with_stat=True)
 
     def _children(self, pkt, with_stat):
-        kids = pkt['children']
-        parts = [b'', _INT.pack(len(kids))]      # [0] holds the header
-        size = 4
-        for c in kids:
-            cb = c.encode('utf-8')
-            n = len(cb)
-            parts.append(_INT.pack(n if n else -1))
-            parts.append(cb)
-            size += 4 + n
+        body = children_body(pkt['children'])
         if with_stat:
             st = pkt['stat']
             if len(st) != 11:
                 return None
-            parts.append(_STAT.pack(*st))
-            size += 68
-        parts[0] = _RESP_HDR.pack(16 + size, pkt['xid'],
-                                  pkt['zxid'], 0)
-        return b''.join(parts)
+            body += stat_bytes(st)
+        return reply_frame(pkt['xid'], pkt['zxid'], body)
 
     def _rs_multi(self, pkt):
         parts = [b'']                 # [0] holds the reply header

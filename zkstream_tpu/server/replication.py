@@ -1182,6 +1182,10 @@ class RemoteLeader(EventEmitter):
             None, socket.create_connection,
             (self.host, self.port), 10)
         self._sock.settimeout(None)     # RPCs keep blocking semantics
+        # a ``touch`` has no response: with Nagle on, the RPC written
+        # behind one (a new session's first write) waits for the
+        # touch's ACK, which the leader's kernel delays ~40 ms
+        self._sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         role = 'observer' if self.observer else None
         self._sock.sendall(_dump(('control', self._token, None,
                                   role)))

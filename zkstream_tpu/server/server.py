@@ -33,6 +33,7 @@ from ..io.ingress import METRIC_RECV_SYSCALLS, make_plane, \
 from ..io.overload import OverloadConfig, OverloadPlane, \
     overload_enabled
 from ..io.sendplane import SendPlane
+from ..protocol.fastencode import children_body, reply_frame, stat_bytes
 from ..protocol.framing import PacketCodec, resolve_frame_cap
 from ..utils.aio import set_nodelay
 from ..utils.metrics import TickLedger
@@ -252,6 +253,61 @@ class ReadGate:
         self._unsubscribe()
 
 
+class ChildrenReplyCache:
+    """The serialized body of a member's children replies, a path: the
+    count and the names (``names``) and the 68-byte Stat
+    (``GET_CHILDREN2`` puts it behind them), encoded once and handed
+    to every asker while the node's Stat equals the one they were
+    encoded with — ZooKeeper's ``ResponseCache`` (ZOOKEEPER-3180,
+    ``zookeeper.maxGetChildrenResponseCacheSize``, 400, on by
+    default).  Nothing invalidates an entry: whatever changes the
+    list — a create, a delete, a MULTI, a session close, a follower's
+    applied commit — moves the parent's ``cversion`` / ``pzxid``, a
+    ``setData`` on it ``mzxid``, and the entry no longer matches.
+    ``CAPACITY`` paths, least recently used out.  A miss's sort and
+    encode are the tick phase ``list_encode``."""
+
+    CAPACITY = 400
+
+    __slots__ = ('_entries', 'hits', 'misses', 'bytes')
+
+    def __init__(self) -> None:
+        #: path -> (stat, names, stat bytes), least recently used first
+        self._entries: collections.OrderedDict = collections.OrderedDict()
+        self.hits = 0
+        self.misses = 0
+        self.bytes = 0      # held by the entries
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def body(self, path: str, node, ledger) -> tuple[bytes, bytes]:
+        """``(names, stat bytes)`` of ``node``'s children reply."""
+        entries = self._entries
+        stat = node.stat()
+        hit = entries.get(path)
+        if hit is not None:
+            if hit[0] == stat:
+                self.hits += 1
+                entries.move_to_end(path)
+                return hit[1], hit[2]
+            self.bytes -= len(hit[1]) + len(hit[2])
+        self.misses += 1
+        ledger.enter('list_encode')
+        try:
+            names = children_body(sorted(node.children))
+            st = stat_bytes(stat)
+        finally:
+            ledger.exit()
+        entries[path] = (stat, names, st)
+        entries.move_to_end(path)
+        self.bytes += len(names) + len(st)
+        if len(entries) > self.CAPACITY:
+            _path, old = entries.popitem(last=False)
+            self.bytes -= len(old[1]) + len(old[2])
+        return names, st
+
+
 class ServerConnection:
     """One accepted client socket: handshake, request dispatch, and this
     connection's watch tables."""
@@ -404,17 +460,29 @@ class ServerConnection:
             return
         if self.server.drop_pings and opcode == 'PING':
             return
-        # the header zxid is this MEMBER's last applied transaction —
-        # a lagging follower honestly reports its own position
+        pkt = {'xid': xid, 'zxid': self._reply_zxid(), 'err': err,
+               'opcode': opcode}
+        pkt.update(body)
+        self._send(pkt)
+
+    def _reply_zxid(self) -> int:
+        """The zxid a reply's header carries: this MEMBER's last
+        applied transaction — a lagging follower honestly reports its
+        own position."""
         z = self.store.zxid
         sess = self.session
         if sess is not None and z > sess.last_zxid:
             # the session has now SEEN this member state: the zxid
             # read gate's floor (ReadGate) advances with every reply
             sess.last_zxid = z
-        pkt = {'xid': xid, 'zxid': z, 'err': err, 'opcode': opcode}
-        pkt.update(body)
-        self._send(pkt)
+        return z
+
+    def _reply_body(self, xid: int, body: bytes) -> None:
+        """An OK reply whose body is already encoded."""
+        if self.server.drop_replies or self.closed:
+            return
+        self.server.packets_sent += 1
+        self._write_bytes(reply_frame(xid, self._reply_zxid(), body))
 
     def notify(self, ntype: str, path: str, zxid: int,
                persistent: bool = False) -> None:
@@ -1020,22 +1088,27 @@ class ServerConnection:
             self._arm_data(pkt['path'])
         self._reply(pkt['xid'], 'EXISTS', stat=stat)
 
-    def _op_get_children(self, pkt: dict) -> None:
+    def _children(self, pkt: dict, with_stat: bool) -> None:
+        """GET_CHILDREN / GET_CHILDREN2: this reply's own header in
+        front of the body every asker of the path shares
+        (:class:`ChildrenReplyCache`)."""
         if self._gated(pkt):
             return
-        children, stat = self.store.get_children(pkt['path'])
+        path = pkt['path']
+        node = self.store.nodes.get(path)
+        if node is None:
+            raise ZKOpError('NO_NODE')
         if pkt.get('watch'):
-            self._arm_child(pkt['path'])
-        self._reply(pkt['xid'], 'GET_CHILDREN', children=children)
+            self._arm_child(path)
+        names, stat = self.server.children_cache.body(
+            path, node, self.server.ledger)
+        self._reply_body(pkt['xid'], names + stat if with_stat else names)
+
+    def _op_get_children(self, pkt: dict) -> None:
+        self._children(pkt, False)
 
     def _op_get_children2(self, pkt: dict) -> None:
-        if self._gated(pkt):
-            return
-        children, stat = self.store.get_children(pkt['path'])
-        if pkt.get('watch'):
-            self._arm_child(pkt['path'])
-        self._reply(pkt['xid'], 'GET_CHILDREN2', children=children,
-                    stat=stat)
+        self._children(pkt, True)
 
     def _op_get_acl(self, pkt: dict) -> None:
         if self._gated(pkt):
@@ -1278,6 +1351,8 @@ class ZKServer:
         #: encode them); the watch table replaces it with a per-tick
         #: memo (server/watchtable.py)
         self._notif_cache: tuple[tuple, bytes] | None = None
+        #: the serialized children replies of this member's store
+        self.children_cache = ChildrenReplyCache()
         self._notif_codec = PacketCodec(server=True)
         self._notif_codec.handshaking = False
         #: The serving plane's sharded watch fan-out
@@ -1952,6 +2027,14 @@ class ZKServer:
             ('zk_multi_batch_size',
              round(subops / batches, 2) if batches else 0),
         ]
+        # the children-reply cache: replies served from an encoded
+        # body, bodies sorted and encoded, bytes the entries hold
+        cc = self.children_cache
+        cache_rows = [
+            ('zk_children_cache_hits', cc.hits),
+            ('zk_children_cache_misses', cc.misses),
+            ('zk_children_cache_bytes', cc.bytes),
+        ]
         # the tick ledger + trace-ring rows (the per-tick plane
         # decomposition, README "Causal tracing"): tick count, each
         # phase's per-tick p99, and how often the bounded span ring
@@ -2035,7 +2118,7 @@ class ZKServer:
         ] + self._ingress_census_rows() \
             + (self.overload.mntr_rows()
                if self.overload is not None else []) \
-            + multi_rows + gate_rows \
+            + multi_rows + cache_rows + gate_rows \
             + quorum_rows + config_rows + fanout_rows + forward_rows \
             + tick_rows \
             + blackbox_rows \

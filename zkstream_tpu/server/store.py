@@ -311,12 +311,6 @@ class NodeTree(EventEmitter):
             raise ZKOpError('NO_NODE')
         return node.stat()
 
-    def get_children(self, path: str) -> tuple[list[str], Stat]:
-        node = self.nodes.get(path)
-        if node is None:
-            raise ZKOpError('NO_NODE')
-        return sorted(node.children), node.stat()
-
     def get_acl(self, path: str) -> tuple[list[ACL], Stat]:
         node = self.nodes.get(path)
         if node is None:

@@ -58,6 +58,10 @@ from each:
   (the deadline entry's discard, the latency observation, ``on_op``);
   ``client.rx``, ``client.flush``, ``client.handoff``, ``client.reap``,
   ``client.send``, ``client.notify``, ``client.deadline`` as before.
+- ``client.connect`` / ``client.close`` — a session's birth and death
+  on the wall clock (not loop time: the loop serves the rest of the
+  fleet meanwhile): ``Client.start()`` until its first ``'connect'``,
+  and ``Client.close()`` from the call to its return.
 - ``client.cork_wait`` / ``client.wire_wait`` / ``client.tick_wait`` /
   ``client.wake_wait`` — a request's latency by stage, one count an
   op, stamped on ``time.perf_counter_ns`` (:func:`op_resumed`):
@@ -106,7 +110,7 @@ TRACE_SCHEMA = 3
 _OPTIONAL_FIELDS = ('path', 'xid', 'zxid', 'backend', 'session_id',
                     'member', 'batch', 'nbytes', 'detail', 'error',
                     'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted',
-                    'rows', 'width')
+                    'rows', 'width', 'names')
 
 
 class Span:
@@ -127,7 +131,8 @@ class Span:
     (None: settled off the device) and submit / resume on that clock
     (:func:`op_resumed`); ``lane`` / ``emitted`` — ``ingest.route``
     only: the tick's frames settled through the connections' direct
-    lanes, and those handed to the ``'ingestDeliver'`` emitter path;
+    lanes, and those handed to the ``'ingestDeliver'`` emitter path,
+    and ``names``, the names in the children lists it routed;
     ``rows`` / ``width`` — ``ingest.dispatch`` only: the streams in
     the dispatch and the width of its size class (``nbytes``: their
     payload)."""
