@@ -117,6 +117,88 @@ enum {
   RQ_SET_WATCHES2 = 9,
 };
 
+/* ---- one call's children lists, parsed once (decode_streams) ----
+ *
+ * A herd is N sessions asking ONE path in ONE state: the members answer
+ * from their serialized reply cache, so the N bodies of a tick are
+ * byte-equal, and parsing each makes N lists of N x names new `str`,
+ * all but one of each equal to one made microseconds before.  A names
+ * region (`int count` .. the end of the last name; its length follows
+ * from the frame's before a name is read) that is byte-equal to one
+ * already parsed IN THIS CALL gets a new list of the SAME `str`
+ * objects instead.  Every packet still owns its list; only the
+ * immutable names are shared.
+ *
+ * The memo lives on decode_streams' stack and dies with the call:
+ * nothing is kept across ticks, so there is nothing to invalidate.  It
+ * keeps its OWN copy of a remembered region — a stream's buffer export
+ * is released before the next stream is read, after which those bytes
+ * may move — made once a distinct body (a 27 KB herd body: ~1 us).
+ * Only a list that parsed whole, ending exactly where the region ends,
+ * is remembered: for such a region equal bytes mean an equal parse. */
+#define CHILD_MEMO_SLOTS 8 /* distinct bodies remembered; oldest out */
+/* A region shorter than this parses as before.  Fitted on 160 streams
+ * a call, 14-byte names: a miss costs ~0.065 us more than no memo (the
+ * malloc'd copy and a slot; the parse of 2-3 names), which is 6% of a
+ * 292-byte reply's whole decode and 9% of a 76-byte one's, and a hit
+ * under 256 bytes saves under 0.35 us of under 1 us. */
+#define CHILD_MEMO_MIN_BYTES 256
+
+typedef struct {
+  struct {
+    uint8_t *region; /* malloc'd copy, `len` bytes */
+    Py_ssize_t len;
+    PyObject *list; /* owned; the first asker's own list */
+  } slot[CHILD_MEMO_SLOTS];
+  int used, oldest;
+  Py_ssize_t lists;  /* children lists decoded in this call */
+  Py_ssize_t shared; /* of those, served from the memo */
+} ChildMemo;
+
+/* 1 and *out = a NEW list of the remembered names on a hit; 0 on a
+ * miss; -1 with an exception set */
+static int child_memo_get(const ChildMemo *m, const uint8_t *region,
+                          Py_ssize_t len, PyObject **out) {
+  for (int i = 0; i < m->used; ++i) {
+    if (m->slot[i].len != len ||
+        memcmp(m->slot[i].region, region, (size_t)len) != 0)
+      continue;
+    PyObject *lst = m->slot[i].list;
+    *out = PyList_GetSlice(lst, 0, PyList_GET_SIZE(lst));
+    return *out == NULL ? -1 : 1;
+  }
+  return 0;
+}
+
+/* remember (region, list); out of memory only means not remembered */
+static void child_memo_put(ChildMemo *m, const uint8_t *region,
+                           Py_ssize_t len, PyObject *list) {
+  uint8_t *copy = malloc((size_t)len);
+  if (copy == NULL) return;
+  memcpy(copy, region, (size_t)len);
+  int i;
+  if (m->used < CHILD_MEMO_SLOTS) {
+    i = m->used++;
+  } else {
+    i = m->oldest;
+    m->oldest = (m->oldest + 1) % CHILD_MEMO_SLOTS;
+    free(m->slot[i].region);
+    Py_DECREF(m->slot[i].list);
+  }
+  m->slot[i].region = copy;
+  m->slot[i].len = len;
+  Py_INCREF(list);
+  m->slot[i].list = list;
+}
+
+static void child_memo_clear(ChildMemo *m) {
+  for (int i = 0; i < m->used; ++i) {
+    free(m->slot[i].region);
+    Py_DECREF(m->slot[i].list);
+  }
+  m->used = 0;
+}
+
 /* ---- byte readers (big-endian, bounds-checked) ---- */
 
 typedef struct {
@@ -128,6 +210,7 @@ typedef struct {
                     * for (none today — MULTI landed in abi 9): the
                     * frame is left in the buffer and the Python
                     * spec tier decodes it */
+  ChildMemo *memo; /* decode_streams' (NULL: every list is parsed) */
 } Cursor;
 
 static int need(Cursor *c, Py_ssize_t n) {
@@ -322,24 +405,45 @@ static int decode_body(Cursor *c, PyObject *pkt, int layout) {
     }
     case LAYOUT_GET_CHILDREN:
     case LAYOUT_GET_CHILDREN2: {
-      if (!need(c, 4)) return -1;
-      int32_t n = rd_i32(c);
-      if (n < 0) n = 0;
-      /* the count is wire-controlled: every element needs >= 4 bytes
-       * (its length prefix), so bound it by the remaining body before
-       * allocating — a corrupt frame must fail as BAD_DECODE, not as a
-       * multi-GB PyList_New */
-      if (!need(c, 4 * (Py_ssize_t)n)) return -1;
-      PyObject *lst = PyList_New(n);
-      if (lst == NULL) return -1;
-      for (int32_t i = 0; i < n; ++i) {
-        PyObject *s = rd_string(c);
-        if (s == NULL) {
-          Py_DECREF(lst);
-          return -1;
+      /* the names region of a well-formed body: all of it, less the
+       * Stat a GET_CHILDREN2 carries behind */
+      ChildMemo *memo = c->memo;
+      const Py_ssize_t start = c->off;
+      const Py_ssize_t region =
+          c->len - start - (layout == LAYOUT_GET_CHILDREN2 ? 68 : 0);
+      if (memo != NULL && region < CHILD_MEMO_MIN_BYTES) memo = NULL;
+      PyObject *lst = NULL;
+      if (memo != NULL) {
+        int hit = child_memo_get(memo, c->p + start, region, &lst);
+        if (hit < 0) return -1;
+        if (hit) {
+          c->off += region;
+          memo->shared++;
         }
-        PyList_SET_ITEM(lst, i, s);
       }
+      if (lst == NULL) {
+        if (!need(c, 4)) return -1;
+        int32_t n = rd_i32(c);
+        if (n < 0) n = 0;
+        /* the count is wire-controlled: every element needs >= 4
+         * bytes (its length prefix), so bound it by the remaining body
+         * before allocating — a corrupt frame must fail as BAD_DECODE,
+         * not as a multi-GB PyList_New */
+        if (!need(c, 4 * (Py_ssize_t)n)) return -1;
+        lst = PyList_New(n);
+        if (lst == NULL) return -1;
+        for (int32_t i = 0; i < n; ++i) {
+          PyObject *s = rd_string(c);
+          if (s == NULL) {
+            Py_DECREF(lst);
+            return -1;
+          }
+          PyList_SET_ITEM(lst, i, s);
+        }
+        if (memo != NULL && c->off - start == region)
+          child_memo_put(memo, c->p + start, region, lst);
+      }
+      if (c->memo != NULL) c->memo->lists++;
       if (set_steal(pkt, s_children, lst) < 0) return -1;
       if (layout == LAYOUT_GET_CHILDREN2)
         return set_steal(pkt, s_stat, rd_stat(c));
@@ -1166,11 +1270,14 @@ static PyObject *py_setup(PyObject *self, PyObject *args) {
  * *kind stays NULL or names the error (a static string) with its text
  * in msg[256]; *consumed is what the caller drops from its buffer.
  * Holds no buffer export: the caller owns the bytes for the duration
- * of the call.  -1 = a real exception is set (OOM etc.). */
+ * of the call.  `memo` (decode_streams alone; else NULL) shares equal
+ * children lists' names across the frames, and the calls, it is handed
+ * to.  -1 = a real exception is set (OOM etc.). */
 static int decode_span_into(const uint8_t *buf, Py_ssize_t len,
                             PyObject *xid_map, int max_packet,
                             PyObject *pkts, Py_ssize_t *consumed,
-                            const char **kind, char *msg) {
+                            const char **kind, char *msg,
+                            ChildMemo *memo) {
   const char *what = xid_map != NULL ? "Response" : "Request";
   *kind = NULL;
   *consumed = 0;
@@ -1202,7 +1309,7 @@ static int decode_span_into(const uint8_t *buf, Py_ssize_t len,
                            ((uint32_t)buf[off + 1] << 16) |
                            ((uint32_t)buf[off + 2] << 8) |
                            (uint32_t)buf[off + 3]);
-    Cursor c = {buf + off + 4, ln, 0, {0}};
+    Cursor c = {buf + off + 4, ln, 0, {0}, 0, memo};
     PyObject *pkt = xid_map != NULL ? decode_reply(&c, xid_map)
                                     : decode_request(&c);
     if (pkt == NULL) {
@@ -1246,7 +1353,7 @@ static PyObject *decode_stream(Py_buffer view, PyObject *xid_map,
   char msg[256] = {0};
   Py_ssize_t consumed;
   int rc = decode_span_into((const uint8_t *)view.buf, view.len, xid_map,
-                            max_packet, pkts, &consumed, &kind, msg);
+                            max_packet, pkts, &consumed, &kind, msg, NULL);
   PyBuffer_Release(&view);
   PyObject *ret = NULL;
   if (rc == 0)
@@ -1268,7 +1375,7 @@ static PyObject *py_decode_responses(PyObject *self, PyObject *args) {
 }
 
 /* decode_streams(bufs, lens, xid_maps, max_packet)
- *   -> (pkts, counts, consumed, errors)
+ *   -> (pkts, counts, consumed, errors, (lists, shared))
  *
  * The fleet ingest's tick in one call: stream i is bufs[i][0:lens[i]]
  * (the complete-frame prefix the device scan delimited) decoded
@@ -1287,7 +1394,15 @@ static PyObject *py_decode_responses(PyObject *self, PyObject *args) {
  * decoding has begun.  Arguments are validated before the first
  * stream is touched.  A stream of length 0 is not touched at all.
  * Each buffer's export is released before the next stream is read:
- * every buffer is resizable again when the call returns. */
+ * every buffer is resizable again when the call returns.
+ *
+ * The one thing the call does that N calls of decode_responses do not:
+ * a GET_CHILDREN / GET_CHILDREN2 body whose names region is byte-equal
+ * to one already parsed in THIS call (ChildMemo, above) gets its own
+ * new list of the SAME `str` objects, its own Stat.  Equal to the
+ * stream-by-stream parse under `==`; only `is` between two packets'
+ * names can tell.  `lists` counts the children lists the call decoded,
+ * `shared` those served so. */
 static PyObject *py_decode_streams(PyObject *self, PyObject *args) {
   PyObject *bufs, *lens, *maps;
   int max_packet;
@@ -1320,6 +1435,8 @@ static PyObject *py_decode_streams(PyObject *self, PyObject *args) {
   PyObject *counts = PyList_New(n);
   PyObject *consumed = PyList_New(n);
   PyObject *errors = PyDict_New();
+  ChildMemo memo;
+  memset(&memo, 0, sizeof(memo));
   if (!pkts || !counts || !consumed || !errors) goto fail;
   for (Py_ssize_t i = 0; i < n; i++) {
     /* the lists are the caller's and nothing here mutates them, but
@@ -1348,7 +1465,8 @@ static PyObject *py_decode_streams(PyObject *self, PyObject *args) {
           rc = -1;
         } else {
           rc = decode_span_into((const uint8_t *)view.buf, ln, map,
-                                max_packet, pkts, &used, &kind, msg);
+                                max_packet, pkts, &used, &kind, msg,
+                                &memo);
         }
         PyBuffer_Release(&view);
       }
@@ -1396,9 +1514,12 @@ static PyObject *py_decode_streams(PyObject *self, PyObject *args) {
     PyList_SET_ITEM(counts, i, cnt);
     PyList_SET_ITEM(consumed, i, use);
   }
-  return Py_BuildValue("(NNNN)", pkts, counts, consumed, errors);
+  child_memo_clear(&memo);
+  return Py_BuildValue("(NNNN(nn))", pkts, counts, consumed, errors,
+                       memo.lists, memo.shared);
 
 fail:
+  child_memo_clear(&memo);
   /* counts/consumed may hold NULL slots: list dealloc copes */
   Py_XDECREF(pkts);
   Py_XDECREF(counts);
@@ -1415,7 +1536,7 @@ static PyObject *py_decode_requests(PyObject *self, PyObject *args) {
 }
 
 static PyObject *py_abi_version(PyObject *self, PyObject *noargs) {
-  return PyLong_FromLong(13);
+  return PyLong_FromLong(14);
 }
 
 /* CRC32C (Castagnoli, reflected 0x82F63B78) for the write-ahead-log
@@ -3185,7 +3306,8 @@ static PyMethodDef methods[] = {
      "(pkts, consumed, err_kind, err_msg)"},
     {"decode_streams", py_decode_streams, METH_VARARGS,
      "decode_streams(bufs, lens, xid_maps, max_packet) -> "
-     "(pkts, counts, consumed, {i: (err_kind, err_msg) | exception})"},
+     "(pkts, counts, consumed, {i: (err_kind, err_msg) | exception}, "
+     "(lists, shared))"},
     {"decode_requests", py_decode_requests, METH_VARARGS,
      "decode_requests(buf, max_packet) -> "
      "(pkts, consumed, err_kind, err_msg)"},

@@ -394,6 +394,41 @@ async def test_the_route_counts_the_names_it_delivered(server, armed):
         await c.close()
 
 
+async def test_the_route_counts_the_lists_it_shared(server, armed):
+    """``lists_routed`` / ``lists_shared`` (always on) and the
+    ``ingest.route`` span's ``lists`` / ``shared``: the children lists
+    a device tick routed, and those its one C decode served from an
+    equal body it had parsed already.  Every decode parses a body at
+    least once, so a span shares fewer lists than it routed."""
+    ingest = _ingest()
+    c = await _fleet_client(server, ingest)
+    try:
+        await c.create('/herd', b'')
+        wide = sorted('node-%04d:8983_solr' % i for i in range(24))
+        for name in wide:
+            await c.create('/herd/' + name, b'')
+        assert (ingest.lists_routed, ingest.lists_shared) == (0, 0)
+        trace.host_ring.reset()
+        for _ in range(4):
+            views = await asyncio.gather(*[c.list('/herd')
+                                           for _ in range(6)])
+            assert [sorted(v) for v, _stat in views] == [wide] * 6
+            assert len({id(v) for v, _stat in views}) == 6
+        await c.get('/herd')
+        assert ingest.lists_routed == 24
+        routes = [s for s in trace.host_ring.spans()
+                  if s.op == 'ingest.route']
+        assert sum(s.lists for s in routes) == 24
+        assert sum(s.shared for s in routes) == ingest.lists_shared
+        assert all(s.shared < s.lists for s in routes if s.lists)
+        if c.current_connection().codec.ext is None:
+            assert ingest.lists_shared == 0
+        else:   # the replies to a pipelined burst stand in few ticks
+            assert ingest.lists_shared >= 4
+    finally:
+        await c.close()
+
+
 async def test_a_loops_requests_share_its_deadline_timer(server, armed):
     """The deadline queue's engagement counter: ``client.deadline`` is
     an arming or a firing of the loop's ONE timer, so ``client.submit``'s

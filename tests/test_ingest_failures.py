@@ -390,7 +390,8 @@ async def test_ext_slice_failure_wraps_as_bad_decode():
         def decode_streams(self, bufs, lens, xid_maps, max_packet):
             n = len(bufs)
             return ([], [0] * n, [0] * n,
-                    {i: MemoryError('injected') for i in range(n)})
+                    {i: MemoryError('injected') for i in range(n)},
+                    (0, 0))
 
     conn.codec._ext = BrokenExt()
     ing.register(conn)

@@ -135,6 +135,14 @@ class Peer:
         req.fut = LoggedFuture(self.log, xid)
         return xid
 
+    def ls(self, path='/live_nodes', stat=True) -> int:
+        req = self.conn.request({
+            'opcode': 'GET_CHILDREN2' if stat else 'GET_CHILDREN',
+            'path': path, 'watch': True})
+        xid = req.packet['xid']
+        req.fut = LoggedFuture(self.log, xid)
+        return xid
+
     def ping(self) -> None:
         self.conn.ping(lambda err, _lat: self.log.append(('ping', err)))
 
@@ -157,6 +165,15 @@ class Peer:
             pkt['data'] = self.rng.randbytes(self.rng.randrange(0, 200))
             pkt['stat'] = Stat(*(self.rng.randrange(1 << 20)
                                  for _ in range(11)))
+        self.wire += self.srv.encode(pkt)
+
+    def reply_list(self, xid: int, names, stat=None) -> None:
+        """A GET_CHILDREN2 reply, or (no ``stat``) a GET_CHILDREN one."""
+        pkt = {'xid': xid, 'zxid': self._next_zxid(), 'err': 'OK',
+               'opcode': 'GET_CHILDREN' if stat is None
+               else 'GET_CHILDREN2', 'children': names}
+        if stat is not None:
+            pkt['stat'] = stat
         self.wire += self.srv.encode(pkt)
 
     def notification(self, path='/w') -> None:
@@ -412,6 +429,25 @@ async def case_session_moving_away(peers, flush):
     return 0
 
 
+HERD = ['node-%04d:8983_solr' % i for i in range(48)]
+
+
+async def case_equal_relists(peers, flush):
+    """A herd: every session re-lists ONE path in ONE state, so the
+    tick holds equal bodies — both layouts, behind a notification, one
+    session a state ahead, one list too short to be worth sharing.
+    Every asker sees what its own reply carried."""
+    stat = Stat(*range(1, 12))
+    for i, p in enumerate(peers):
+        a, b, c = p.ls(), p.ls(stat=False), p.ls('/few')
+        p.notification('/live_nodes')
+        p.reply_list(a, HERD[:-1] if i == 3 else HERD, stat)
+        p.reply_list(b, HERD)
+        p.reply_list(c, HERD[:2], stat)
+    await flush()
+    return None
+
+
 async def case_seeded_mix(peers, flush):
     """Several ticks of everything that keeps a connection alive, drawn
     from the seed."""
@@ -444,7 +480,8 @@ CASES = {
         case_decode_error_mid_stream, case_reply_after_deadline,
         case_callback_closes_later_connection,
         case_second_packet_listener, case_fault_injector_installed,
-        case_session_moving_away, case_seeded_mix)}
+        case_session_moving_away, case_equal_relists,
+        case_seeded_mix)}
 
 
 async def run_case(case, through_ingest: bool, use_native: bool,
@@ -489,7 +526,8 @@ async def run_case(case, through_ingest: bool, use_native: bool,
         if ingest is not None:
             ingest.close()
     return snaps, expect, sum(a for a, _b in lanes), \
-        sum(b for _a, b in lanes)
+        sum(b for _a, b in lanes), \
+        ingest and (ingest.lists_routed, ingest.lists_shared)
 
 
 @pytest.mark.parametrize('use_native', [True, False],
@@ -504,8 +542,9 @@ async def test_batch_route_equals_per_stream_reference(
         monkeypatch.setenv('ZKSTREAM_NO_NATIVE', '1')
     monkeypatch.setattr(session_mod, 'time', _Time)
     monkeypatch.setattr(ingest_mod, 'time', _Time)
-    want, _n, _l, _e = await run_case(case, False, use_native, seed=29)
-    got, lane_frames, laned, emitted = await run_case(
+    want, _n, _l, _e, _s = await run_case(case, False, use_native,
+                                          seed=29)
+    got, lane_frames, laned, emitted, lists = await run_case(
         case, True, use_native, seed=29)
     for i, (w, g) in enumerate(zip(want, got)):
         assert g == w, 'connection %d differs' % i
@@ -514,6 +553,19 @@ async def test_batch_route_equals_per_stream_reference(
         assert laned == lane_frames
     if case in ('plain', 'two_frames', 'error_reply', 'throttled'):
         assert emitted == 0                  # nothing but the lane
+    if case == 'equal_relists':
+        # 15 lists; all but the first HERD, the one HERD[:-1] and the
+        # five short ones came from the tick's memo — with the C decode
+        assert lists == (15, 8 if use_native else 0)
+        views = [[e[2]['children'] for e in g['log'] if e[0] == 'fut']
+                 for g in got]
+        assert len({id(v) for vs in views for v in vs}) == 15
+        views[0][0].reverse()       # one listener edits its own view
+        assert views[0][1] == views[1][0] == HERD
+    else:
+        assert lists == (sum('children' in e[2] for g in got
+                             for e in g['log'] if e[0] == 'fut'
+                             and isinstance(e[2], dict)), 0)
 
 
 async def test_lane_is_what_state_connected_registers():

@@ -592,7 +592,7 @@ def test_decode_streams_equals_decode_responses(seed):
     want = _per_stream(ext, streams)
     bufs = [bytearray(w) for w, _ln, _xm in streams]
     maps = [dict(xm) for _w, _ln, xm in streams]
-    pkts, counts, consumed, errors = ext.decode_streams(
+    pkts, counts, consumed, errors, _lists = ext.decode_streams(
         bufs, [ln for _w, ln, _xm in streams], maps, 1 << 24)
     pos = 0
     for i, ((w_pkts, w_used, w_kind, w_msg), w_map) in enumerate(want):
@@ -616,9 +616,10 @@ def test_decode_streams_empty_slice_touches_nothing():
     ext = native.ensure_ext()
     xmap = {1: 'GET_DATA'}
     out = ext.decode_streams([bytearray(b'\x00\x00')], [0], [xmap], 1 << 24)
-    assert out == ([], [0], [0], {})
+    assert out == ([], [0], [0], {}, (0, 0))
     assert xmap == {1: 'GET_DATA'}
-    assert ext.decode_streams([], [], [], 1 << 24) == ([], [], [], {})
+    assert ext.decode_streams([], [], [], 1 << 24) == ([], [], [], {},
+                                                       (0, 0))
 
 
 def test_decode_streams_failure_in_one_stream_of_many():
@@ -629,7 +630,7 @@ def test_decode_streams_failure_in_one_stream_of_many():
     wire = encode_replies(ALL_REPLIES[:3])
     bufs = [bytearray(wire) for _ in range(3)]
     maps = [xid_map_for(ALL_REPLIES[:3]) for _ in range(3)]
-    pkts, counts, consumed, errors = ext.decode_streams(
+    pkts, counts, consumed, errors, _lists = ext.decode_streams(
         bufs, [len(wire), len(wire) + 1, len(wire)], maps, 1 << 24)
     assert counts == [3, 0, 3] and consumed == [len(wire), 0, len(wire)]
     assert list(errors) == [1] and isinstance(errors[1], ValueError)
@@ -652,6 +653,230 @@ def test_decode_streams_validates_before_decoding():
         ext.decode_streams([bytearray(wire)], [len(wire), 1], [xmap],
                            1 << 24)
     assert xmap == xid_map_for(ALL_REPLIES[:2])
+
+
+# -- decode_streams: a herd's equal children lists, parsed once --------
+
+#: zkwire_ext.c CHILD_MEMO_MIN_BYTES / CHILD_MEMO_SLOTS
+MEMO_MIN_BYTES = 256
+MEMO_SLOTS = 8
+
+
+def _names(n: int, tag: str = 'node') -> list:
+    return ['%s-%04d:8983_solr' % (tag, i) for i in range(n)]
+
+
+def _region_bytes(names) -> int:
+    """The names region of a children reply: the count and every
+    length-prefixed name."""
+    return 4 + sum(4 + len(nm.encode()) for nm in names)
+
+
+def _list_reply(xid: int, names, stat=STAT, zxid: int = 500) -> dict:
+    """A GET_CHILDREN2 reply, or (``stat=None``) a GET_CHILDREN one."""
+    pkt = {'xid': xid, 'zxid': zxid, 'err': 'OK', 'children': names,
+           'opcode': 'GET_CHILDREN' if stat is None else 'GET_CHILDREN2'}
+    if stat is not None:
+        pkt['stat'] = stat
+    return pkt
+
+
+def _decode_herd(streams):
+    """``streams``: a list of reply lists.  Decodes them in ONE
+    ``decode_streams`` call and each alone through
+    ``decode_responses``; asserts every per-stream observable equal and
+    every buffer resizable afterwards; returns the per-stream packets of
+    the one call and its (lists, shared)."""
+    ext = native.ensure_ext()
+    wires = [encode_replies(replies) for replies in streams]
+    want = [ext.decode_responses(w, xid_map_for(r), 1 << 24)
+            for w, r in zip(wires, streams)]
+    bufs = [bytearray(w) for w in wires]
+    maps = [xid_map_for(r) for r in streams]
+    pkts, counts, consumed, errors, stats = ext.decode_streams(
+        bufs, [len(w) for w in wires], maps, 1 << 24)
+    got, pos = [], 0
+    for i, (w_pkts, w_used, w_kind, w_msg) in enumerate(want):
+        got.append(pkts[pos:pos + counts[i]])
+        pos += counts[i]
+        assert got[i] == w_pkts, i
+        assert consumed[i] == w_used, i
+        assert errors.get(i) == (None if w_kind is None
+                                 else (w_kind, w_msg)), i
+        assert maps[i] == {}, i
+    assert pos == len(pkts)
+    for buf in bufs:
+        buf.extend(b'x')    # no export left held: still resizable
+        buf.clear()
+    return got, stats
+
+
+def _stat(i: int):
+    return records.Stat(*(i * 11 + k for k in range(11)))
+
+
+@pytest.mark.parametrize('n_streams', [4, 7, 160])
+def test_decode_streams_equal_lists_share_names_not_lists(n_streams):
+    """N streams that carry the same GET_CHILDREN2 names: equal to the
+    stream-by-stream parse, each packet its OWN list and its OWN Stat,
+    the names the same objects, and an edit of one list nobody
+    else's."""
+    names = _names(40)
+    got, (lists, shared) = _decode_herd(
+        [[_list_reply(7, names, _stat(i), zxid=500 + i)]
+         for i in range(n_streams)])
+    assert (lists, shared) == (n_streams, n_streams - 1)
+    views = [pkts[0]['children'] for pkts in got]
+    assert [pkts[0]['stat'] for pkts in got] == \
+        [_stat(i) for i in range(n_streams)]
+    assert len({id(v) for v in views}) == n_streams
+    for v in views[1:]:
+        assert all(a is b for a, b in zip(views[0], v))
+    views[0].sort(reverse=True)
+    del views[1][5:]
+    views[-1].append('ghost')
+    for v in views[2:-1]:
+        assert v == names
+    assert views[0] == names[::-1] and views[1] == names[:5]
+
+
+def test_decode_streams_equal_length_lists_that_differ_do_not_share():
+    """Bodies of one length that differ in ONE byte of the LAST name
+    are two bodies."""
+    a = _names(40)
+    b = a[:-1] + [a[-1][:-1] + 'X']
+    got, (lists, shared) = _decode_herd(
+        [[_list_reply(1, a)], [_list_reply(1, b)], [_list_reply(1, a)],
+         [_list_reply(1, b)]])
+    assert (lists, shared) == (4, 2)
+    kids = [pkts[0]['children'] for pkts in got]
+    assert kids == [a, b, a, b]
+    assert kids[0][-1] is kids[2][-1] and kids[1][-1] is kids[3][-1]
+    assert kids[0][0] is not kids[1][0]     # equal names, parsed apart
+
+
+def test_decode_streams_shares_across_both_children_layouts():
+    """GET_CHILDREN (no Stat behind the names) and GET_CHILDREN2 of the
+    same names are the same names region, in one stream or in two."""
+    names = _names(40)
+    got, (lists, shared) = _decode_herd(
+        [[_list_reply(1, names, None), _list_reply(2, names, _stat(3))],
+         [_list_reply(1, names, _stat(4))], [_list_reply(1, names, None)]])
+    assert (lists, shared) == (4, 3)
+    assert 'stat' not in got[0][0] and 'stat' not in got[2][0]
+    assert got[0][1]['stat'] == _stat(3) and got[1][0]['stat'] == _stat(4)
+    first = got[0][0]['children']
+    for pkts in got:
+        for pkt in pkts:
+            assert pkt['children'] == names
+            assert pkt['children'][0] is first[0]
+    assert got[0][1]['children'] is not first
+
+
+def test_decode_streams_more_distinct_lists_than_the_memo_holds():
+    """The memo keeps a handful of bodies, oldest out: a body it let go
+    is parsed again (and remembered again), never served wrong."""
+    bodies = [_names(30, 'rack%02d' % k) for k in range(MEMO_SLOTS + 3)]
+    order = list(range(len(bodies))) + [0, 1] + [len(bodies) - 1] * 2
+    got, (lists, shared) = _decode_herd(
+        [[_list_reply(1, bodies[k])] for k in order])
+    assert [pkts[0]['children'] for pkts in got] == \
+        [bodies[k] for k in order]
+    # bodies 0 and 1 had been pushed out; the newest one had not
+    assert (lists, shared) == (len(order), 2)
+
+
+@pytest.mark.parametrize('names', [[], ['ab'], _names(3), _names(9)],
+                         ids=['empty', 'one', 'three', 'nine'])
+def test_decode_streams_short_lists_parse_as_before(names):
+    """A names region under the size constant is not worth a probe:
+    counted, never shared."""
+    assert _region_bytes(names) < MEMO_MIN_BYTES
+    got, (lists, shared) = _decode_herd(
+        [[_list_reply(1, names, None), _list_reply(2, names)]
+         for _ in range(4)])
+    assert (lists, shared) == (8, 0)
+    if names:
+        assert got[0][0]['children'][0] is not got[1][0]['children'][0]
+
+
+def test_decode_streams_size_constant_is_the_edge():
+    under, at = _names(10), _names(10) + ['x' * 18]
+    assert _region_bytes(under) < MEMO_MIN_BYTES == _region_bytes(at)
+    _got, stats = _decode_herd([[_list_reply(1, under)]] * 3
+                               + [[_list_reply(1, at)]] * 3)
+    assert stats == (6, 2)
+
+
+def test_decode_streams_stream_that_errors_after_a_shared_list():
+    """A stream whose first reply was served from the memo and whose
+    second matches no request: the list is kept, the error is the
+    stream's, its neighbours share on."""
+    ext = native.ensure_ext()
+    names = _names(40)
+    good = encode_replies([_list_reply(1, names)])
+    bad = good + encode_replies([ALL_REPLIES[0] | {'xid': 77}])
+    bufs = [bytearray(good), bytearray(bad), bytearray(good)]
+    maps = [{1: 'GET_CHILDREN2'} for _ in bufs]
+    pkts, counts, consumed, errors, stats = ext.decode_streams(
+        bufs, [len(b) for b in bufs], maps, 1 << 24)
+    assert counts == [1, 1, 1] and stats == (3, 2)
+    assert consumed == [len(good), len(bad), len(good)]
+    assert list(errors) == [1] and errors[1][0] == 'BAD_DECODE'
+    assert [p['children'] for p in pkts] == [names] * 3
+    assert errors[1] == ext.decode_responses(
+        bad, {1: 'GET_CHILDREN2'}, 1 << 24)[2:]
+
+
+def test_decode_streams_truncated_list_is_not_remembered():
+    """A body whose names run past its frame fails as it did, and the
+    same bytes fail again: only a list that parsed whole is kept."""
+    ext = native.ensure_ext()
+    names = _names(40)
+    good = encode_replies([_list_reply(1, names, None)])
+    # the same frame, its count raised by one: the last name is missing
+    n_off = 4 + 16
+    torn = bytearray(good)
+    torn[n_off:n_off + 4] = struct.pack('>i', len(names) + 1)
+    bufs = [bytearray(torn), bytearray(torn), bytearray(good)]
+    maps = [{1: 'GET_CHILDREN'} for _ in bufs]
+    pkts, counts, _consumed, errors, stats = ext.decode_streams(
+        bufs, [len(b) for b in bufs], maps, 1 << 24)
+    assert counts == [0, 0, 1] and sorted(errors) == [0, 1]
+    assert errors[0] == errors[1] == ext.decode_responses(
+        bytes(torn), {1: 'GET_CHILDREN'}, 1 << 24)[2:]
+    assert pkts[0]['children'] == names and stats == (1, 0)
+
+
+def test_decode_streams_trailing_bytes_keep_a_list_out_of_the_memo():
+    """A GET_CHILDREN frame with bytes behind its last name decodes (the
+    reply reader never asked for the frame's end), and is not kept: its
+    region is not where the names end."""
+    ext = native.ensure_ext()
+    names = _names(40)
+    good = encode_replies([_list_reply(1, names, None)])
+    fat = struct.pack('>i', len(good) - 4 + 3) + good[4:] + b'\0\0\0'
+    bufs = [bytearray(fat), bytearray(fat), bytearray(good),
+            bytearray(good)]
+    maps = [{1: 'GET_CHILDREN'} for _ in bufs]
+    pkts, counts, _consumed, errors, stats = ext.decode_streams(
+        bufs, [len(b) for b in bufs], maps, 1 << 24)
+    assert counts == [1] * 4 and not errors and stats == (4, 1)
+    assert [p['children'] for p in pkts] == [names] * 4
+    assert pkts[0] == ext.decode_responses(
+        fat, {1: 'GET_CHILDREN'}, 1 << 24)[0][0]
+
+
+def test_decode_responses_never_shares():
+    """The scalar drain passes no memo: one stream of two equal lists is
+    two parses."""
+    ext = native.ensure_ext()
+    names = _names(40)
+    replies = [_list_reply(1, names), _list_reply(2, names)]
+    pkts, _used, kind, _msg = ext.decode_responses(
+        encode_replies(replies), xid_map_for(replies), 1 << 24)
+    assert kind is None and pkts == replies
+    assert pkts[0]['children'][0] is not pkts[1]['children'][0]
 
 
 # -- the native sender thread (io/transport.py's hand-over) ------------

@@ -147,6 +147,31 @@ def main() -> int:
                 call(bytes(blob))
             except Exception:
                 pass
+    # decode_streams: a herd of one wide children list (over the
+    # memo's size constant) in both layouts, each stream mutated on
+    # its own — equal bodies share, torn ones must not be remembered
+    herd = [enc.encode({'xid': 1, 'zxid': 9, 'opcode': op, 'err': 'OK',
+                        'children': ['node-%04d' % i for i in range(40)],
+                        **({'stat': st} if op == 'GET_CHILDREN2' else {})})
+            for op in ('GET_CHILDREN2', 'GET_CHILDREN')]
+    for _ in range(ROUNDS // 10):
+        bufs = []
+        for _s in range(12):
+            base = rng.choice(herd) * rng.randrange(1, 3)
+            blob = bytearray(base)
+            if rng.random() < 0.5:
+                for _m in range(rng.randrange(1, 4)):
+                    blob[rng.randrange(len(blob))] = rng.randrange(256)
+            if rng.random() < 0.2:
+                del blob[rng.randrange(len(blob)):]
+            bufs.append(blob)
+        maps = [{1: rng.choice(('GET_CHILDREN2', 'GET_CHILDREN'))}
+                for _b in bufs]
+        try:
+            mod.decode_streams(bufs, [len(b) for b in bufs], maps,
+                               16 << 20)
+        except Exception:
+            pass
     # encode paths: well-formed and near-miss dicts
     enc_cases = [
         {'xid': 1, 'opcode': 'GET_DATA', 'path': '/a', 'watch': True},

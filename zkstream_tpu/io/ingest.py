@@ -352,6 +352,14 @@ class FleetIngest:
         #: that watches wide directories pays the list parse and its
         #: listeners for, a ``str`` each
         self.names_routed = 0
+        #: those lists, and how many of them the tick's one C decode
+        #: did not parse again: a herd's re-lists of ONE path in ONE
+        #: state are byte-equal, and ``decode_streams`` hands every
+        #: asker after the first its own list of the SAME ``str``
+        #: objects (0 without the extension, or in ``body_mode=
+        #: 'device'``)
+        self.lists_routed = 0
+        self.lists_shared = 0
         #: Upper dispatch guard: when a large fleet's connections
         #: desynchronize, the tick batches fragment (a small share of
         #: the slots hold a frame) and the per-socket drain is the
@@ -861,7 +869,13 @@ class FleetIngest:
                  'because the tick\'s batch memory was full'),
                 ('zkstream_ingest_routed_names', 'names_routed',
                  'names in the children lists the device ticks '
-                 'routed')):
+                 'routed'),
+                ('zkstream_ingest_routed_lists', 'lists_routed',
+                 'children lists the device ticks routed'),
+                ('zkstream_ingest_shared_lists', 'lists_shared',
+                 'children lists a tick\'s decode served from an '
+                 'equal body it had parsed already (the names are '
+                 'shared, the list is the asker\'s own)')):
             collector.gauge(prefix + name,
                             (lambda a=attr: getattr(self, a)),
                             help_text)
@@ -1374,6 +1388,7 @@ class FleetIngest:
         with host_span('ingest.route', tick=n) as rsp:
             laned = emitted = 0
             names = self.names_routed
+            lists, shared = self.lists_routed, self.lists_shared
             self.routing = n
             try:
                 for plan, (ints, byts) in zip(plans, results):
@@ -1388,7 +1403,9 @@ class FleetIngest:
             finally:
                 self.routing = None
             rsp.set(lane=laned, emitted=emitted,
-                    names=self.names_routed - names)
+                    names=self.names_routed - names,
+                    lists=self.lists_routed - lists,
+                    shared=self.lists_shared - shared)
         t4 = time.perf_counter()
         observe = self.phase_hist.observe
         for labels, a, b in zip(_PHASE_LABELS, (t0, t1, t2, t3),
@@ -1464,7 +1481,8 @@ class FleetIngest:
             routed += n
             for pkt in pkts:
                 kids = pkt.get('children')
-                if kids:
+                if kids is not None:
+                    self.lists_routed += 1
                     self.names_routed += len(kids)
             if pkts or err is not None:
                 if lane is None:
@@ -1492,7 +1510,13 @@ class FleetIngest:
         ``maps[i]``; the packets of all of them in ONE flat list,
         ``counts[i]`` each; ``errors[i]`` where a stream's decode
         failed.  No buffer is consumed here: the route does that,
-        stream by stream."""
+        stream by stream.
+
+        Children lists whose names are byte-equal within the call (a
+        herd's re-lists) share their ``str`` objects: each packet has
+        its own ``list``, so a listener that sorts or edits its view
+        changes nobody else's; only ``is`` between two sessions' names
+        can tell.  ``lists_shared`` counts them."""
         ext = None
         bufs, lens, maps = [], [], []
         for (conn, buf, _lane), n, resid, bad in zip(
@@ -1507,8 +1531,9 @@ class FleetIngest:
             maps.append(codec.xid_map)
         if ext is None:
             return None
-        pkts, counts, _consumed, errors = ext.decode_streams(
-            bufs, lens, maps, MAX_PACKET)
+        pkts, counts, _consumed, errors, (_lists, shared) = \
+            ext.decode_streams(bufs, lens, maps, MAX_PACKET)
+        self.lists_shared += shared
         return lens, maps, pkts, counts, errors
 
     @staticmethod
