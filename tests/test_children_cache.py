@@ -1,5 +1,6 @@
-"""The members' children-reply cache (server/server.py
-``ChildrenReplyCache``): the serialized body of a GET_CHILDREN /
+"""The members' children-reply cache (server/server.py ``ReplyCache``
+with ``children_parts``; its ``getData`` half is
+tests/test_reply_cache.py): the serialized body of a GET_CHILDREN /
 GET_CHILDREN2 reply — the count, the names, and for GET_CHILDREN2 the
 Stat — is encoded once a path and handed to every asker behind the
 16-byte header of its own xid / zxid, while the node's Stat equals the
@@ -22,10 +23,14 @@ from zkstream_tpu import Client, CreateFlag
 from zkstream_tpu.protocol import fastencode
 from zkstream_tpu.protocol.framing import PacketCodec
 from zkstream_tpu.server import ZKEnsemble, ZKServer
-from zkstream_tpu.server.server import ChildrenReplyCache
+from zkstream_tpu.server.server import ReplyCache, children_parts
 from zkstream_tpu.server.store import ZKDatabase
 from zkstream_tpu.protocol.records import OPEN_ACL_UNSAFE
 from zkstream_tpu.utils.metrics import TickLedger
+
+
+def ChildrenReplyCache() -> ReplyCache:
+    return ReplyCache(children_parts, 'list_encode')
 
 
 def _tree(names) -> ZKDatabase:

@@ -106,6 +106,45 @@ async def test_multi_failure_rolls_back_everything():
     assert db.nodes['/new'].data == b'n'
 
 
+def test_multi_rollback_undoes_nested_changes_and_copies_no_parent():
+    """A sub-op's undo is the parent's own change taken back — its
+    counters and the ONE name — never a copy of its children (a batch
+    of creates under one parent of 65,536 was quadratic that way):
+    nested creates and deletes, a node and its child in one batch, and
+    the parents are the objects they were."""
+    db = _db_with('/wide', '/t', '/t/kid', '/t/kid/leaf')
+    for i in range(300):
+        db.create('/wide/c%03d' % (i,), b'', None, CreateFlag(0), None)
+    wide, kids = db.nodes['/wide'], db.nodes['/wide'].children
+    before_nodes = copy.deepcopy(db.nodes)
+    before_zxid = db.zxid
+    res = db.multi([
+        {'op': 'create', 'path': '/wide/new', 'data': b'n'},
+        {'op': 'create', 'path': '/wide/new/deep', 'data': b'd'},
+        {'op': 'delete', 'path': '/wide/c007'},
+        {'op': 'set_data', 'path': '/wide', 'data': b'w'},
+        {'op': 'delete', 'path': '/t/kid/leaf'},
+        {'op': 'delete', 'path': '/t/kid'},
+        {'op': 'create', 'path': '/t/kid', 'data': b'again'},
+        {'op': 'delete', 'path': '/wide/new/deep'},
+        {'op': 'create', 'path': '/wide/c008', 'data': b''},  # exists
+    ])
+    assert res[8]['err'] == 'NODE_EXISTS'
+    assert db.nodes == before_nodes and db.zxid == before_zxid
+    assert db.nodes['/wide'].children is kids and len(kids) == 300
+    assert db.nodes['/t/kid'].children == {'leaf'}
+    # the same batch without the failing op applies as ONE entry
+    res = db.multi([
+        {'op': 'create', 'path': '/wide/new', 'data': b'n'},
+        {'op': 'delete', 'path': '/wide/c007'},
+        {'op': 'delete', 'path': '/t/kid/leaf'},
+    ])
+    assert [r['op'] for r in res] == ['create', 'delete', 'delete']
+    assert db.nodes['/wide'].children is kids and len(kids) == 300
+    assert 'new' in kids and 'c007' not in kids
+    assert wide.cversion == before_nodes['/wide'].cversion + 2
+
+
 def test_multi_interdependent_ops_and_replay():
     """Create-then-delete-in-batch, and the replica replay applies
     the whole entry through the shared apply_entry dispatch."""

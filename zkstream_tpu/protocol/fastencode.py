@@ -94,8 +94,8 @@ def children_body(kids) -> bytes:
     """The part of a GET_CHILDREN / GET_CHILDREN2 reply that is the
     same for every asker: the count and the names.  (The server keeps
     it a path while the node's Stat stands, server/server.py
-    ``ChildrenReplyCache``; ``GET_CHILDREN2`` puts :func:`stat_bytes`
-    behind it.)"""
+    ``ReplyCache``; ``GET_CHILDREN2`` puts :func:`stat_bytes` behind
+    it.)"""
     parts = [_INT.pack(len(kids))]
     for c in kids:
         cb = c.encode('utf-8')
@@ -108,6 +108,13 @@ def children_body(kids) -> bytes:
 def stat_bytes(stat) -> bytes:
     """The 68-byte Stat record."""
     return _STAT.pack(*stat)
+
+
+def data_body(data: bytes, stat) -> bytes:
+    """A GET_DATA reply's whole body — the data behind its length and
+    the 68-byte Stat — which is the same for every asker."""
+    dn = len(data)
+    return b''.join((_INT.pack(dn if dn else -1), data, _STAT.pack(*stat)))
 
 
 def reply_frame(xid: int, zxid: int, body: bytes) -> bytes:

@@ -33,7 +33,8 @@ from ..io.ingress import METRIC_RECV_SYSCALLS, make_plane, \
 from ..io.overload import OverloadConfig, OverloadPlane, \
     overload_enabled
 from ..io.sendplane import SendPlane
-from ..protocol.fastencode import children_body, reply_frame, stat_bytes
+from ..protocol.fastencode import children_body, data_body, \
+    reply_frame, stat_bytes
 from ..protocol.framing import PacketCodec, resolve_frame_cap
 from ..utils.aio import set_nodelay
 from ..utils.metrics import TickLedger
@@ -253,27 +254,54 @@ class ReadGate:
         self._unsubscribe()
 
 
-class ChildrenReplyCache:
-    """The serialized body of a member's children replies, a path: the
-    count and the names (``names``) and the 68-byte Stat
-    (``GET_CHILDREN2`` puts it behind them), encoded once and handed
-    to every asker while the node's Stat equals the one they were
-    encoded with — ZooKeeper's ``ResponseCache`` (ZOOKEEPER-3180,
-    ``zookeeper.maxGetChildrenResponseCacheSize``, 400, on by
-    default).  Nothing invalidates an entry: whatever changes the
-    list — a create, a delete, a MULTI, a session close, a follower's
-    applied commit — moves the parent's ``cversion`` / ``pzxid``, a
-    ``setData`` on it ``mzxid``, and the entry no longer matches.
-    ``CAPACITY`` paths, least recently used out.  A miss's sort and
-    encode are the tick phase ``list_encode``."""
+#: From this size a ``GET_DATA`` body is worth keeping
+#: (``ZKServer.data_cache``).  Fitted on this host (PERF.md section 6,
+#: PR 40): a reply through the encoder costs 2.5 us at 0 B, 3.8 at
+#: 16 KiB, 9.3 at 64 KiB, 138 at 960 KiB; a hit (the lookup, and the
+#: shared body copied once behind this asker's header) 1.9 / 3.0 /
+#: 5.1 / 73; a miss (the lookup, the encode, the entry, the eviction)
+#: 4.2 / 6.4 / 14.0.  Up to 16 KiB a hit saves 0.6-0.8 us and a miss
+#: adds 1.7-2.6: the cache would pay only above 75% hits, and the
+#: cells with small records hit 5-38%.  At 64 KiB a hit saves what a
+#: miss adds (4.3 / 4.6 us: even at 52% hits), and from there up it
+#: halves a reply's cost.
+REPLY_SHARE_BYTES = 65536
+
+
+class ReplyCache:
+    """The serialized body of a member's read replies of ONE family, a
+    path, encoded once and handed to every asker while the node's Stat
+    equals the one it was encoded with — ZooKeeper's ``ResponseCache``
+    (ZOOKEEPER-3180: ``readResponseCache`` for ``getData``,
+    ``getChildrenResponseCache`` for the children replies; 400 each,
+    on by default).  A member holds one a family:
+
+    - ``children`` (:func:`children_parts`): the count and the names,
+      and the 68-byte Stat ``GET_CHILDREN2`` puts behind them; a
+      miss's sort and encode are the tick phase ``list_encode``;
+    - ``data`` (:func:`data_parts`): a ``GET_DATA`` reply's whole
+      body, the data behind its length and the Stat, ONE ``bytes``
+      that every asker's reply shares (phase ``data_encode``) — for
+      data of ``REPLY_SHARE_BYTES`` or more; a smaller record is
+      encoded for its asker, which is cheaper than a miss.
+
+    Nothing invalidates an entry: whatever changes the reply moves the
+    node's Stat — a ``setData`` ``mzxid`` / ``version``, a child's
+    create or delete (a MULTI's, a session close's, a follower's
+    applied commit) ``cversion`` / ``pzxid``, a delete-and-create
+    ``czxid`` — and the entry no longer matches.  ``CAPACITY`` paths,
+    least recently used out."""
 
     CAPACITY = 400
 
-    __slots__ = ('_entries', 'hits', 'misses', 'bytes')
+    __slots__ = ('_entries', '_encode', '_phase', 'hits', 'misses',
+                 'bytes')
 
-    def __init__(self) -> None:
-        #: path -> (stat, names, stat bytes), least recently used first
+    def __init__(self, encode, phase: str) -> None:
+        #: path -> (stat, parts, bytes held), least recently used first
         self._entries: collections.OrderedDict = collections.OrderedDict()
+        self._encode = encode
+        self._phase = phase
         self.hits = 0
         self.misses = 0
         self.bytes = 0      # held by the entries
@@ -281,8 +309,8 @@ class ChildrenReplyCache:
     def __len__(self) -> int:
         return len(self._entries)
 
-    def body(self, path: str, node, ledger) -> tuple[bytes, bytes]:
-        """``(names, stat bytes)`` of ``node``'s children reply."""
+    def body(self, path: str, node, ledger) -> tuple:
+        """The encoded parts of ``node``'s reply."""
         entries = self._entries
         stat = node.stat()
         hit = entries.get(path)
@@ -290,22 +318,29 @@ class ChildrenReplyCache:
             if hit[0] == stat:
                 self.hits += 1
                 entries.move_to_end(path)
-                return hit[1], hit[2]
-            self.bytes -= len(hit[1]) + len(hit[2])
+                return hit[1]
+            self.bytes -= hit[2]
+            del entries[path]       # the new entry goes in at the end
         self.misses += 1
-        ledger.enter('list_encode')
+        ledger.enter(self._phase)
         try:
-            names = children_body(sorted(node.children))
-            st = stat_bytes(stat)
+            parts = self._encode(node, stat)
         finally:
             ledger.exit()
-        entries[path] = (stat, names, st)
-        entries.move_to_end(path)
-        self.bytes += len(names) + len(st)
+        held = sum(map(len, parts))
+        entries[path] = (stat, parts, held)
+        self.bytes += held
         if len(entries) > self.CAPACITY:
-            _path, old = entries.popitem(last=False)
-            self.bytes -= len(old[1]) + len(old[2])
-        return names, st
+            self.bytes -= entries.popitem(last=False)[1][2]
+        return parts
+
+
+def children_parts(node, stat) -> tuple[bytes, bytes]:
+    return children_body(sorted(node.children)), stat_bytes(stat)
+
+
+def data_parts(node, stat) -> tuple[bytes]:
+    return (data_body(node.data, stat),)
 
 
 class ServerConnection:
@@ -1059,15 +1094,23 @@ class ServerConnection:
         self._write(pkt, 'delete', pkt['path'], pkt['version'])
 
     def _op_get_data(self, pkt: dict) -> None:
+        """GET_DATA: a record of ``REPLY_SHARE_BYTES`` or more is this
+        reply's own header in front of the body every asker of the
+        path shares (``ZKServer.data_cache``)."""
         if self._gated(pkt):
             return
-        try:
-            data, stat = self.store.get_data(pkt['path'])
-        except ZKOpError:
-            raise
+        path = pkt['path']
+        node = self.store.nodes.get(path)
+        if node is None:
+            raise ZKOpError('NO_NODE')
         if pkt.get('watch'):
-            self._arm_data(pkt['path'])
-        self._reply(pkt['xid'], 'GET_DATA', data=data, stat=stat)
+            self._arm_data(path)
+        if len(node.data) < REPLY_SHARE_BYTES:
+            self._reply(pkt['xid'], 'GET_DATA', data=node.data,
+                        stat=node.stat())
+            return
+        self._reply_body(pkt['xid'], self.server.data_cache.body(
+            path, node, self.server.ledger)[0])
 
     def _op_set_data(self, pkt: dict) -> None:
         self._write(pkt, 'set_data', pkt['path'], pkt['data'],
@@ -1091,7 +1134,7 @@ class ServerConnection:
     def _children(self, pkt: dict, with_stat: bool) -> None:
         """GET_CHILDREN / GET_CHILDREN2: this reply's own header in
         front of the body every asker of the path shares
-        (:class:`ChildrenReplyCache`)."""
+        (``ZKServer.children_cache``)."""
         if self._gated(pkt):
             return
         path = pkt['path']
@@ -1352,7 +1395,8 @@ class ZKServer:
         #: memo (server/watchtable.py)
         self._notif_cache: tuple[tuple, bytes] | None = None
         #: the serialized children replies of this member's store
-        self.children_cache = ChildrenReplyCache()
+        self.children_cache = ReplyCache(children_parts, 'list_encode')
+        self.data_cache = ReplyCache(data_parts, 'data_encode')
         self._notif_codec = PacketCodec(server=True)
         self._notif_codec.handshaking = False
         #: The serving plane's sharded watch fan-out
@@ -2027,14 +2071,13 @@ class ZKServer:
             ('zk_multi_batch_size',
              round(subops / batches, 2) if batches else 0),
         ]
-        # the children-reply cache: replies served from an encoded
-        # body, bodies sorted and encoded, bytes the entries hold
-        cc = self.children_cache
+        # the reply caches: replies served from an encoded body,
+        # bodies encoded, bytes the entries hold
         cache_rows = [
-            ('zk_children_cache_hits', cc.hits),
-            ('zk_children_cache_misses', cc.misses),
-            ('zk_children_cache_bytes', cc.bytes),
-        ]
+            ('zk_%s_cache_%s' % (family, row), getattr(cache, row))
+            for family, cache in (('children', self.children_cache),
+                                  ('data', self.data_cache))
+            for row in ('hits', 'misses', 'bytes')]
         # the tick ledger + trace-ring rows (the per-tick plane
         # decomposition, README "Causal tracing"): tick count, each
         # phase's per-tick p99, and how often the bounded span ring

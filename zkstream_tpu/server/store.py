@@ -75,12 +75,12 @@ class Znode:
     seq: int = 0
 
     def stat(self) -> Stat:
-        return Stat(czxid=self.czxid, mzxid=self.mzxid, ctime=self.ctime,
-                    mtime=self.mtime, version=self.version,
-                    cversion=self.cversion, aversion=self.aversion,
-                    ephemeralOwner=self.ephemeral_owner,
-                    dataLength=len(self.data),
-                    numChildren=len(self.children), pzxid=self.pzxid)
+        # in the record's field order (built once a read reply: by
+        # position it costs half of what it costs by keyword)
+        return Stat(self.czxid, self.mzxid, self.ctime, self.mtime,
+                    self.version, self.cversion, self.aversion,
+                    self.ephemeral_owner, len(self.data),
+                    len(self.children), self.pzxid)
 
 
 @dataclasses.dataclass
@@ -134,14 +134,6 @@ def durable_sessions(sessions: dict) -> dict:
     ``{sid: (passwd, timeout)}``, live sessions only."""
     return {sid: (s.passwd, s.timeout) for sid, s in sessions.items()
             if not s.expired and not s.closed}
-
-
-def _copy_znode(node: 'Znode | None') -> 'Znode | None':
-    """A rollback-grade copy: every scalar field plus a fresh children
-    set (data bytes and the ACL tuple are immutable and may alias)."""
-    if node is None:
-        return None
-    return dataclasses.replace(node, children=set(node.children))
 
 
 class NodeTree(EventEmitter):
@@ -894,9 +886,10 @@ class ZKDatabase(NodeTree):
         validate-then-apply: each sub-op runs through the exact
         single-op path (so validation can never diverge from it) with
         change events buffered and commits intercepted; the first
-        failure rolls the applied prefix back — pre-copied nodes and
-        parents restored in reverse order, zxid rewound, buffered
-        events dropped — and every position reports an error result
+        failure rolls the applied prefix back — pre-copied nodes put
+        back and each parent's own change undone in reverse order, zxid
+        rewound, buffered events dropped — and every position reports
+        an error result
         (the failing op its real code, the rest
         RUNTIME_INCONSISTENCY, real ZK's multi error shape).  On
         success the buffered events fire in apply order."""
@@ -914,9 +907,17 @@ class ZKDatabase(NodeTree):
             for op in ops:
                 name = op.get('op')
                 path = op.get('path', '')
-                saved = (_copy_znode(self.nodes.get(path)),
-                         _copy_znode(self.nodes.get(
-                             parent_path(path) if path else '/')))
+                # saved: what a sub-op changes OF the node and OF its
+                # parent, never a copy of either (a copy of the
+                # parent's children set a sub-op made a batch of
+                # creates under one wide parent quadratic: 65,536
+                # records under /benchmark loaded in 131 s)
+                node = self.nodes.get(path)
+                parent = self.nodes.get(parent_path(path) if path else '/')
+                saved = (node and (node, node.data, node.version,
+                                   node.mzxid, node.mtime),
+                         parent and (parent.cversion, parent.pzxid,
+                                     parent.seq))
                 n_before = len(buf)
                 try:
                     if name == 'create':
@@ -961,36 +962,43 @@ class ZKDatabase(NodeTree):
         return results
 
     def _rollback_multi(self, undo: list, start_zxid: int) -> None:
-        """Reverse an applied MULTI prefix: each step restores the
-        node/parent copies captured just before its sub-op, newest
-        first, then the zxid rewinds — byte-identical to never having
-        applied (no event fired, nothing logged)."""
-        for entry, (node_copy, parent_copy) in reversed(undo):
+        """Reverse an applied MULTI prefix: each step puts back what
+        its sub-op changed — the node's data, version and times (a
+        deleted node itself: it had no children), the parent's
+        counters, and the child's name out of (or back into) the
+        parent's children — newest first, then the zxid rewinds:
+        byte-identical to never having applied (no event fired,
+        nothing logged)."""
+        for entry, (node_was, parent_was) in reversed(undo):
             op = entry[0]
             path = entry[1]
-            ppath = parent_path(path)
+            parent = self.nodes.get(parent_path(path))
+            if parent is not None and parent_was and op != 'set_data':
+                parent.cversion, parent.pzxid, parent.seq = parent_was
+                name = path.rsplit('/', 1)[1]
+                if op == 'create':
+                    parent.children.discard(name)
+                else:
+                    parent.children.add(name)
             if op == 'create':
                 self.nodes.pop(path, None)
-                if parent_copy is not None:
-                    self.nodes[ppath] = parent_copy
                 if entry[4]:
                     sess = self.sessions.get(entry[4])
                     if sess is not None:
                         sess.ephemerals.discard(path)
             elif op == 'delete':
-                if node_copy is not None:
-                    self.nodes[path] = node_copy
-                    if node_copy.ephemeral_owner:
-                        sess = self.sessions.get(
-                            node_copy.ephemeral_owner)
+                if node_was:
+                    node = self.nodes[path] = node_was[0]
+                    if node.ephemeral_owner:
+                        sess = self.sessions.get(node.ephemeral_owner)
                         if sess is not None:
                             sess.ephemerals.add(path)
-                if parent_copy is not None:
-                    self.nodes[ppath] = parent_copy
             else:
                 assert op == 'set_data', op
-                if node_copy is not None:
-                    self.nodes[path] = node_copy
+                if node_was:
+                    node = node_was[0]
+                    (_n, node.data, node.version, node.mzxid,
+                     node.mtime) = node_was
         self.zxid = start_zxid
 
 

@@ -357,8 +357,11 @@ class TickLedger:
       what a large record costs;
     - ``list_encode`` — the sort and encode of a children list whose
       serialized reply the member did not hold (server/server.py
-      ``ChildrenReplyCache``: once a change of the parent, whoever
-      asks), nested under ``decode_apply`` the same way;
+      ``ReplyCache``: once a change of the parent, whoever asks),
+      and ``data_encode`` — the encode of a ``GET_DATA`` body of
+      ``REPLY_SHARE_BYTES`` or more that it did not hold (once a
+      change of the node, and again when 400 other paths were asked
+      for since), both nested under ``decode_apply`` the same way;
     - ``control`` — the leader's service of a follower's control
       channel (server/replication.py ``_serve_control``): a message
       from its bytes in hand — unpickling, a forwarded batch's
@@ -384,7 +387,8 @@ class TickLedger:
 
     PHASES = ('rx_drain', 'decode_apply', 'fsync_gate', 'cork_flush',
               'fanout_flush', 'forward_rpc', 'wal_append', 'wal_roll',
-              'repl_push', 'list_encode', 'control', 'repl_ack')
+              'repl_push', 'list_encode', 'data_encode', 'control',
+              'repl_ack')
 
     #: Close a still-active burst after this many loop iterations
     #: anyway: under saturating back-to-back load every iteration has
@@ -413,7 +417,8 @@ class TickLedger:
             METRIC_TICK_PHASE,
             'Busy-tick time by phase, ms (rx_drain | decode_apply | '
             'fsync_gate | cork_flush | fanout_flush | forward_rpc | '
-            'wal_append | wal_roll | repl_push | list_encode | control | '
+            'wal_append | wal_roll | repl_push | list_encode | '
+            'data_encode | control | '
             'repl_ack)',
             buckets=TICK_BUCKETS)
         self.tick_hist = source.histogram(
