@@ -577,6 +577,64 @@ async def test_leader_books_forwarded_writes_as_control_and_repl_ack(
     assert 0 < phases <= 1.5 * delta('zk_process_cpu_ms') + 5
 
 
+async def test_leader_ships_a_forwarded_batch_as_one_push_a_mirror(
+        process_ensemble):
+    """16 closed-loop writers on one follower arrive at the leader in
+    batches, and each batch's commits leave for the mirrors as ONE
+    push a mirror (server/replication.py ``_ship``): the leader's
+    ``mntr`` counts the push messages and the entries in them,
+    cumulatively, beside their bytes — entries over messages is the
+    group a push carried, well over one here and exactly what the
+    followers' ``zk_forward_writes`` over ``zk_forward_rpcs`` promise
+    — and a member that forwards has neither row."""
+    leader, (f1, f2) = process_ensemble
+    n, rounds = 16, 20
+    clients = [_client([('127.0.0.1', f1.ports[0])]) for _ in range(n)]
+    try:
+        await asyncio.gather(*(c.wait_connected(timeout=10)
+                               for c in clients))
+        await asyncio.gather(*(c.create('/gp%d' % i, b'')
+                               for i, c in enumerate(clients)))
+        before = await mntr_rows(leader.ports[0])
+        fwd_before = await mntr_rows(f1.ports[0])
+
+        async def writer(i, c):
+            for r in range(rounds):
+                await c.set('/gp%d' % i, b'%d.%d' % (i, r))
+
+        await asyncio.gather(*(writer(i, c)
+                               for i, c in enumerate(clients)))
+        after = await mntr_rows(leader.ports[0])
+        fwd_after = await mntr_rows(f1.ports[0])
+    finally:
+        await asyncio.gather(*(c.close() for c in clients))
+
+    def delta(key, rows=None):
+        a, b = rows or (after, before)
+        return float(a[key]) - float(b[key])
+
+    pushes = delta('zk_repl_pushes')
+    commits = delta('zk_repl_pushed_commits')
+    # every write went to both mirrors (a ping or a scrape's session
+    # commits nothing in between)
+    assert commits == 2 * n * rounds
+    assert delta('zk_repl_pushed_bytes') > commits * 20
+    rpcs = delta('zk_forward_rpcs', (fwd_after, fwd_before))
+    assert 1 <= rpcs <= n * rounds / 2
+    # one push a mirror a batch: no more messages than two an RPC
+    assert 2 <= pushes <= 2 * rpcs, (pushes, rpcs)
+    assert commits / pushes >= 2
+    # ... and the acks fell with them: one a message that grew a mirror
+    assert delta('zk_tick_phase_ms_count{phase="repl_push"}') <= rpcs
+    for key in ('zk_repl_pushes', 'zk_repl_pushed_commits',
+                'zk_repl_pushed_bytes'):
+        assert delta(key) > 0
+        assert key not in fwd_after
+    f2_rows = await mntr_rows(f2.ports[0])
+    assert 'zk_repl_pushes' not in f2_rows
+    assert 'zk_repl_pushed_commits' not in f2_rows
+
+
 async def test_leader_sigkill_with_batches_in_flight_loses_no_ack(
         process_ensemble):
     """SIGKILL the leader under 16 closed-loop writers on one

@@ -613,3 +613,279 @@ async def test_a_write_outside_a_batch_is_refused_loudly(repl):
     # ...and a batch carries writes, nothing else
     results = await _rpc(remote._rpc, 'batch', [('sync_barrier', ())])
     assert results[0][0] == 'exc'
+
+
+# -- the group ship: the commit log leaves once a GROUP of commits -----
+
+class Wire:
+    """Every message read whole off a replication stream, in arrival
+    order; a mirror's events channel is the reader its ``'attached'``
+    came on."""
+
+    def __init__(self):
+        self.seen: list = []        # (reader, msg)
+        self.mirrors: list = []     # events readers, in attach order
+
+    def note(self, reader, msg) -> None:
+        if msg[0] == 'attached':
+            self.mirrors.append(reader)
+        self.seen.append((reader, msg))
+
+    def clear(self) -> None:
+        self.seen.clear()
+
+    def pushed(self, nth: int, kinds=('commit',)) -> list:
+        """What the ``nth`` mirror to attach was pushed, of ``kinds``."""
+        return [m for r, m in self.seen
+                if r is self.mirrors[nth] and m[0] in kinds]
+
+
+@pytest.fixture
+def wire(monkeypatch):
+    from zkstream_tpu.server import replication
+
+    w = Wire()
+    read = replication._read_msg
+
+    async def spy(reader):
+        msg = await read(reader)
+        w.note(reader, msg)
+        return msg
+    monkeypatch.setattr(replication, '_read_msg', spy)
+    return w
+
+
+def spy_acks(svc) -> list:
+    """Every ack the leader takes, as ``(token, msg)``."""
+    acks: list = []
+    note = svc._note_ack
+
+    def spy(h, msg):
+        acks.append((h.token, msg))
+        note(h, msg)
+    svc._note_ack = spy
+    return acks
+
+
+async def settle(cond, what='the mirrors'):
+    for _ in range(250):
+        if cond():
+            return
+        await asyncio.sleep(0.01)
+    raise AssertionError('%s never settled' % (what,))
+
+
+async def test_a_forwarded_batch_is_one_push_and_one_ack_a_mirror(
+        repl, wire):
+    """The n commits of one forwarded ``batch`` reach EVERY mirror —
+    the forwarder's too — as ONE ``'commit'`` message of n entries,
+    shipped from ``_apply_batch``; each mirror acks once.  The ship
+    the batch's first commit scheduled for the turn's end finds
+    nothing left."""
+    db, svc, connect = repl
+    a, b = await connect(), await connect()
+    db.create('/g', b'', OPEN_ACL_UNSAFE, CreateFlag(0))
+    (ha, hb) = (svc._handles[a.token], svc._handles[b.token])
+    await settle(lambda: ha.applied == hb.applied == db.log_end())
+    acks = spy_acks(svc)
+    wire.clear()
+    before = (db.repl_pushes, db.repl_pushed_commits)
+    base = db.log_end()
+
+    results = await _rpc(a.forward,
+                         [_set('/g', b'%d' % i) for i in range(5)])
+    assert [s for s, _ in results] == ['ok'] * 5
+    await settle(lambda: ha.applied == hb.applied == db.log_end())
+    await asyncio.sleep(0.05)           # a second message would land
+    for nth in (0, 1):
+        (msg,) = wire.pushed(nth)
+        assert msg[1] == base and len(msg[2]) == 5
+        assert msg[2] == db.log[base - db.log_base:]
+    assert sorted(t for t, _ in acks) == sorted([a.token, b.token])
+    assert {m[1] for _, m in acks} == {db.log_end()}
+    assert a.log == b.log == db.log
+    # the leader counted two messages of five entries
+    assert (db.repl_pushes - before[0],
+            db.repl_pushed_commits - before[1]) == (2, 10)
+    assert not svc._ship_due
+
+
+async def test_a_turns_own_commits_leave_as_one_push(repl, wire):
+    """Commits made on the leader's loop outside a batch (its own
+    connections' writes): the k of ONE turn leave as one message
+    behind the turn, those of two turns as two."""
+    db, svc, connect = repl
+    remote = await connect()
+    db.create('/t', b'', OPEN_ACL_UNSAFE, CreateFlag(0))
+    await settle(lambda: remote.log_end() == db.log_end())
+    wire.clear()
+
+    for i in range(4):                  # one turn: nothing awaited
+        db.set_data('/t', b'%d' % i, -1)
+    assert svc._ship_due and remote.log_end() == 1
+    await settle(lambda: remote.log_end() == db.log_end())
+    (msg,) = wire.pushed(0)
+    assert msg[1] == 1 and len(msg[2]) == 4
+
+    db.set_data('/t', b'x', -1)
+    await asyncio.sleep(0)              # the turn ends: its ship runs
+    assert not svc._ship_due
+    db.set_data('/t', b'y', -1)
+    await settle(lambda: remote.log_end() == db.log_end())
+    assert [(m[1], len(m[2])) for m in wire.pushed(0)] == [
+        (1, 4), (5, 1), (6, 1)]
+    assert remote.log == db.log
+
+
+async def test_a_commit_with_no_mirror_attached_schedules_nothing(
+        repl):
+    db, svc, connect = repl
+    db.create('/alone', b'', OPEN_ACL_UNSAFE, CreateFlag(0))
+    assert not svc._ship_due and db.repl_pushes == 0
+
+
+async def test_an_expiry_push_does_not_overtake_the_turns_commits(
+        repl, wire):
+    """The events channel keeps its order: a ``session_expired``
+    pushed in the turn that committed a write and the expiry's own
+    records first ships them, so the mirror holds every entry that
+    preceded the broadcast when it arrives."""
+    db, svc, connect = repl
+    remote = await connect()
+    sess = db.create_session(30000)
+    db.create('/e', b'', OPEN_ACL_UNSAFE, CreateFlag.EPHEMERAL, sess)
+    db.create('/x', b'', OPEN_ACL_UNSAFE, CreateFlag(0))
+    await settle(lambda: remote.log_end() == db.log_end())
+    wire.clear()
+    held = []
+    remote.on('sessionExpired', lambda sid: held.append(
+        (sid, remote.log_end())))
+
+    db.set_data('/x', b'1', -1)
+    db.expire_session(sess.id)          # same turn: close + reap
+    end = db.log_end()
+    await settle(lambda: held, 'the expiry broadcast')
+    assert held == [(sess.id, end)]
+    msgs = wire.pushed(0, ('commit', 'session_expired'))
+    assert [m[0] for m in msgs] == ['commit', 'session_expired']
+    # the set, the session's close record, the ephemeral's delete
+    assert len(msgs[0][2]) == 3
+
+
+class DropNext:
+    """The injector's ``drop_push``, deterministic: the next ``n``
+    push messages are lost."""
+
+    def __init__(self, n: int = 1):
+        self.left = n
+
+    def drop_push(self, token: str) -> bool:
+        if self.left:
+            self.left -= 1
+            return True
+        return False
+
+
+async def test_a_dropped_group_push_is_recovered_by_the_piggyback(
+        repl, wire):
+    """``faults.drop_push`` loses a push MESSAGE, now a whole group:
+    the push cursor has moved on, the next push gaps and is refused
+    by the mirror, and the next control-channel response serves from
+    the mirror's end — no entry lost, none doubled."""
+    db, svc, connect = repl
+    remote = await connect()
+    store = RemoteReplicaStore(remote, lag=0.0)
+    db.create('/d', b'', OPEN_ACL_UNSAFE, CreateFlag(0))
+    await settle(lambda: remote.log_end() == db.log_end())
+    (handle,) = svc._handles.values()
+    acks = spy_acks(svc)
+
+    svc.faults = DropNext(1)
+    for i in range(3):                  # one group, lost whole
+        db.set_data('/d', b'%d' % i, -1)
+    await asyncio.sleep(0.05)
+    assert svc.faults.left == 0
+    assert remote.log_end() == 1 and handle.shipped == db.log_end() == 4
+    db.set_data('/d', b'late', -1)      # pushed, but past a gap
+    await asyncio.sleep(0.05)
+    assert remote.log_end() == 1 and not acks
+    assert [(m[1], len(m[2])) for m in wire.pushed(0)][-1] == (4, 1)
+    await _rpc(remote.sync_barrier)
+    assert remote.log == db.log and len(remote.log) == 5
+    store.catch_up()
+    assert store.nodes['/d'].data == b'late'
+    assert store.zxid == db.zxid
+    await settle(lambda: handle.applied == db.log_end(), 'the ack')
+
+
+async def test_quorum_ack_ms_is_stamped_a_commit_at_commit_time(
+        event_loop):
+    """``zk_quorum_ack_ms`` keeps its subject through the group ship:
+    one sample a COMMIT, from the commit's own time — a group of n
+    observes n samples, and none is shorter than the commit's wait
+    for the group's push."""
+    import time
+
+    db = ZKDatabase()
+    svc = await ReplicationService(db, total=2, quorum=True).start()
+    voter = await RemoteLeader('127.0.0.1', svc.port).connect()
+    try:
+        db.create('/q', b'', OPEN_ACL_UNSAFE, CreateFlag(0))
+        await settle(lambda: svc.quorum.quorum_zxid_floor == db.zxid)
+        samples: list = []
+        svc.quorum.ack_hist = type('Hist', (), {
+            'observe': staticmethod(samples.append)})
+        committed: list = []
+        db.on('committed', lambda: committed.append(time.monotonic()))
+        shipped_at: list = []
+        ship = svc._ship
+
+        def spy_ship():
+            shipped_at.append(time.monotonic())
+            ship()
+        svc._ship = spy_ship
+
+        for i in range(3):              # one turn, 10 ms a commit
+            db.set_data('/q', b'%d' % i, -1)
+            time.sleep(0.01)
+        assert len(svc.quorum._commit_t) == 3 and not shipped_at
+        await settle(lambda: svc.quorum.quorum_zxid_floor == db.zxid)
+        assert len(shipped_at) == 1 and len(samples) == 3
+        waits = [(shipped_at[0] - t) * 1e3 for t in committed]
+        assert waits[0] >= 29 and waits[2] >= 9
+        # _advance observes in zxid order: sample i is commit i's
+        assert all(s >= w for s, w in zip(samples, waits)), (
+            samples, waits)
+    finally:
+        voter.close()
+        await svc.stop()
+
+
+async def test_a_batch_whose_every_element_fails_ships_nothing(repl):
+    from zkstream_tpu.utils.metrics import TickLedger
+
+    db, svc, connect = repl
+    led = db.ledger = TickLedger()
+    remote = await connect()
+    db.create('/f', b'', OPEN_ACL_UNSAFE, CreateFlag(0))
+    await settle(lambda: remote.log_end() == db.log_end())
+    await asyncio.sleep(0.02)           # the tick closes
+    sent = []
+    push = svc._push
+    svc._push = lambda h, msg, data=None: (sent.append(msg[0]),
+                                           push(h, msg, data))
+    before = (db.repl_pushes, db.repl_pushed_commits,
+              db.repl_pushed_bytes)
+    count = 'zk_tick_phase_ms_count{phase="repl_push"}'
+    pushes = dict(led.phase_hist.rows()).get(count, 0)
+    results = await _rpc(remote.forward, [
+        _set('/none', b''), _set('/f', b'bad', 7),
+        ('delete', ('/none', -1)),
+        ('create', ('/f', b'', OPEN_ACL_UNSAFE, CreateFlag(0), None))])
+    assert results == [('err', 'NO_NODE'), ('err', 'BAD_VERSION'),
+                       ('err', 'NO_NODE'), ('err', 'NODE_EXISTS')]
+    await asyncio.sleep(0.05)
+    assert not sent and not svc._ship_due
+    assert (db.repl_pushes, db.repl_pushed_commits,
+            db.repl_pushed_bytes) == before
+    assert dict(led.phase_hist.rows()).get(count, 0) == pushes

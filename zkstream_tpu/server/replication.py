@@ -51,7 +51,20 @@ Two channels per follower, paired by a token:
     rest in the next RPC of the same flush;
   - ``('touch', session_id)``: fire-and-forget, no response.
 - ``events`` — an asyncio stream the leader pushes to: new commit-log
-  entries as they land, and session-expiry broadcasts.
+  entries once a GROUP of commits, and session-expiry broadcasts.  A
+  group is what one forwarded ``batch`` committed — shipped as ONE
+  ``('commit', base, entries, epoch)`` message when its last element
+  is applied, before its barrier — or what one turn of the leader's
+  loop committed otherwise (its own connections' writes, session
+  records): the turn's first commit schedules ONE ship with
+  ``call_soon``, behind the turn's ingress drains.  A mirror acks once
+  a message, so the acks fall with the pushes.  *Ordering:* every
+  other message on this channel (``session_expired``, ``attached``,
+  ``snapshot``, ``resync``) first ships what is unshipped, so nothing
+  overtakes a commit that preceded it.  No ack waits for a ship: the
+  quorum floor reads the mirrors' acks, a forwarded batch's response
+  carries its entries itself, and a dropped or never-sent group is
+  served by the next control-channel piggyback.
 
 Wire format: 4-byte big-endian length + pickle.  Pickle is safe here
 for the same reason the reference can shell out to a local JVM: both
@@ -643,6 +656,11 @@ class ReplicationService:
         #: a typed EPOCH_FENCED error instead of being applied to (and
         #: acked from) a history the quorum has moved past.
         self.deposed = False
+        #: the loop :meth:`start` ran on — the mirrors' transports are
+        #: its own — and whether a ship stands in its queue for what
+        #: was committed since the last one (:meth:`_note_commit`)
+        self._loop: asyncio.AbstractEventLoop | None = None
+        self._ship_due = False
 
     @property
     def epoch(self) -> int:
@@ -660,8 +678,9 @@ class ReplicationService:
         self._server = await asyncio.start_server(
             self._on_follower, self.host, self.port, limit=STREAM_LIMIT)
         self.port = self._server.sockets[0].getsockname()[1]
+        self._loop = asyncio.get_running_loop()
         if not self._subscribed:
-            self.db.on('committed', self._push_commits)
+            self.db.on('committed', self._note_commit)
             self.db.on('sessionExpired', self._push_expiry)
             self._subscribed = True
         log.info('replication service on %s:%d', self.host, self.port)
@@ -688,6 +707,10 @@ class ReplicationService:
 
     def _push(self, handle: _FollowerHandle, msg,
               data: bytes | None = None) -> None:
+        if msg[0] != 'commit':
+            # the channel keeps its order: nothing overtakes a commit
+            # that preceded it
+            self._ship()
         if handle.writer is None:
             return
         # Only steady-state pushes partition: the attach/snapshot
@@ -701,11 +724,11 @@ class ReplicationService:
             return                   # scheduled partition window
         if droppable and self.faults is not None and \
                 self.faults.drop_push(handle.token):
-            # Asymmetric partition: this push is lost.  For 'commit'
-            # pushes the shipped cursor still advances in
-            # _push_commits, exactly like bytes lost in the network —
-            # recovery rides the control channel's piggyback (acks
-            # gate the truncation floor, so no entry is lost).
+            # Asymmetric partition: this push is lost — a 'commit'
+            # push with its whole group.  The shipped cursor still
+            # advances in _ship, exactly like bytes lost in the
+            # network — recovery rides the control channel's piggyback
+            # (acks gate the truncation floor, so no entry is lost).
             return
         try:
             handle.writer.write(data if data is not None
@@ -713,39 +736,58 @@ class ReplicationService:
         except (ConnectionError, RuntimeError):
             pass
 
-    def _push_commits(self) -> None:
-        """Ship what each mirror has not been sent.  Tick phase
-        ``repl_push`` on the database's ledger (frame + send), and the
-        bytes handed to the mirrors' transports in the database's
-        cumulative ``repl_pushed_bytes`` (mntr ``zk_repl_pushed_bytes``)."""
-        led = getattr(self.db, 'ledger', None)
+    def _note_commit(self) -> None:
+        """The database's ``'committed'`` edge, once an entry: stamp
+        the commit's time (``zk_quorum_ack_ms`` measures commit ->
+        majority ack, whenever the entry ships) and see that this
+        turn of the loop ends with ONE ship of whatever it committed
+        — the first commit since the last ship schedules it, behind
+        the turn's ingress drains (the pattern of server/server.py
+        ``forward_write``).  A forwarded batch ships its own group
+        before that (:meth:`_apply_batch`); the scheduled ship then
+        finds nothing left and does nothing."""
+        self.quorum.note_pushed(self.db.zxid)
+        if self._handles and not self._ship_due:
+            self._ship_due = True
+            self._loop.call_soon(self._ship)
+
+    def _ship(self) -> None:
+        """Ship what each mirror has not been sent: ONE message a
+        mirror, however many entries the group holds.  Tick phase
+        ``repl_push`` on the database's ledger (frame + send), and on
+        the database the cumulative messages, entries and bytes handed
+        to the mirrors' transports (mntr ``zk_repl_pushes`` /
+        ``zk_repl_pushed_commits`` / ``zk_repl_pushed_bytes``)."""
+        self._ship_due = False
+        db = self.db
+        end = db.log_end()
+        if all(h.shipped >= end for h in self._handles.values()):
+            return
+        trace = getattr(db, 'trace', None)
+        led = getattr(db, 'ledger', None)
         if led is not None:
             led.enter('repl_push')
         try:
-            self._push_commits_inner()
-        finally:
-            if led is not None:
-                led.exit()
-
-    def _push_commits_inner(self) -> None:
-        trace = getattr(self.db, 'trace', None)
-        self.quorum.note_pushed(self.db.zxid)
-        #: per-cursor encode memo: steady-state mirrors share one
-        #: shipped position, so a commit's push bytes are pickled
-        #: ONCE however many followers/observers subscribe — the read
-        #: plane makes wide mirror fleets normal, and a per-handle
-        #: pickle would bill every write O(mirrors) serializations
-        memo: dict[int, bytes] = {}
-        for h in self._handles.values():
-            base, entries = self._entries_from(h.shipped)
-            if entries:
+            #: per-cursor encode memo: steady-state mirrors share one
+            #: shipped position, so a group's push bytes are pickled
+            #: ONCE however many followers/observers subscribe — the
+            #: read plane makes wide mirror fleets normal, and a
+            #: per-handle pickle would bill every write O(mirrors)
+            #: serializations
+            memo: dict[int, bytes] = {}
+            for h in self._handles.values():
+                base, entries = self._entries_from(h.shipped)
+                if not entries:
+                    continue
                 data = memo.get(base)
                 if data is None:
                     data = memo[base] = _dump(
                         ('commit', base, entries, self.epoch))
                 self._push(h, ('commit', base, entries, self.epoch),
                            data=data)
-                self.db.repl_pushed_bytes += len(data)
+                db.repl_pushes += 1
+                db.repl_pushed_commits += len(entries)
+                db.repl_pushed_bytes += len(data)
                 h.shipped = base + len(entries)
                 if trace is not None:
                     # one push span per follower, keyed by the newest
@@ -755,6 +797,9 @@ class ReplicationService:
                                zxid=entry_zxid(entries[-1]),
                                kind='server', batch=len(entries),
                                detail=h.token[:8])
+        finally:
+            if led is not None:
+                led.exit()
 
     def _push_expiry(self, session_id: int) -> None:
         for h in self._handles.values():
@@ -844,7 +889,7 @@ class ReplicationService:
             self._push(h, ('attached', self.epoch,
                            self.db.config_snapshot()))
             # ship anything committed before this follower connected
-            self._push_commits()
+            self._ship()
             led = getattr(self.db, 'ledger', None)
             try:
                 # the follower acks mirrored indices on this channel;
@@ -899,12 +944,12 @@ class ReplicationService:
                              is_observer: bool = False) -> None:
         """One follower's control channel.  Tick phase ``control`` on
         the database's ledger: a message from its bytes in hand —
-        unpickling, the applies (``wal_append`` / ``repl_push`` /
-        ``fsync_gate`` nest and are subtracted), the barrier — up to
-        the quorum wait, and again from the wait's return through the
-        response's piggyback, pickle and write.  Never across the
-        wait: the ledger is a stack, and a parked batch costs the loop
-        nothing."""
+        unpickling, the applies and the batch's one ship (``wal_append``
+        / ``repl_push`` / ``fsync_gate`` nest and are subtracted), the
+        barrier — up to the quorum wait, and again from the wait's
+        return through the response's piggyback, pickle and write.
+        Never across the wait: the ledger is a stack, and a parked
+        batch costs the loop nothing."""
         led = getattr(self.db, 'ledger', None)
         try:
             while True:
@@ -979,12 +1024,13 @@ class ReplicationService:
                      grant) -> tuple[list, tuple | None]:
         """The writes one follower collected in one turn of its loop
         (module docstring, ``batch``): applied in order, each element
-        with its own ``(status, payload)``; made durable ONCE; the
-        quorum awaited ONCE, at the batch's last zxid — by the caller,
-        outside its ledger phase: the second element is that wait's
-        ``(zxid, grant)``, None when there is nothing to wait for.  The
-        response is every element's ack, so it leaves only behind
-        both."""
+        with its own ``(status, payload)``; shipped to the mirrors as
+        ONE group, ahead of the barrier (the mirrors ingest while this
+        loop fsyncs); made durable ONCE; the quorum awaited ONCE, at
+        the batch's last zxid — by the caller, outside its ledger
+        phase: the second element is that wait's ``(zxid, grant)``,
+        None when there is nothing to wait for.  The response is every
+        element's ack, so it leaves only behind both."""
         if fenced:
             # epoch fence: a deposed leader must not apply — or ack —
             # a forwarded write, and a stale-epoch follower's writes
@@ -995,6 +1041,7 @@ class ReplicationService:
         pre_zxid = db.zxid
         results = [self._dispatch(method, args, write=True)
                    for method, args in ops]
+        self._ship()
         if db.wal is not None:
             # logged-before-ack across processes too: the response is
             # the ack of every record in the batch, and one barrier

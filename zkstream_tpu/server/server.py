@@ -2027,11 +2027,15 @@ class ZKServer:
             ('zk_wal_snapshots', wal.snapshots_taken),
             ('zk_wal_appended_bytes', wal.appended_bytes),
         ]
-        # cumulative bytes of commit pushes to OS-process mirrors
-        # (server/replication.py); a RemoteLeader has no such count
-        pushed = getattr(self.db, 'repl_pushed_bytes', None)
-        if pushed is not None:
-            wal_rows.append(('zk_repl_pushed_bytes', pushed))
+        # cumulative commit pushes to OS-process mirrors, the entries
+        # in them and their bytes (server/replication.py ``_ship``;
+        # entries over pushes is the size of a shipped group); a
+        # RemoteLeader has no such counts
+        if getattr(self.db, 'repl_pushed_bytes', None) is not None:
+            wal_rows += [
+                ('zk_repl_pushes', self.db.repl_pushes),
+                ('zk_repl_pushed_commits', self.db.repl_pushed_commits),
+                ('zk_repl_pushed_bytes', self.db.repl_pushed_bytes)]
         # quorum-commit rows (server/replication.py QuorumGate): the
         # majority floor, degraded (quorum-unconfirmed) releases and
         # epoch-fenced stale acks

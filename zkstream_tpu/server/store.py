@@ -328,9 +328,13 @@ class ZKDatabase(NodeTree):
     def __init__(self) -> None:
         super().__init__()
         self.sessions: dict[int, ZKServerSession] = {}
-        #: cumulative bytes of commit pushes handed to mirror
-        #: transports (server/replication.py ``_push_commits``; mntr
+        #: cumulative commit pushes handed to mirror transports
+        #: (server/replication.py ``_ship``: one message a mirror and a
+        #: GROUP of commits), the entries in them and their bytes (mntr
+        #: ``zk_repl_pushes`` / ``zk_repl_pushed_commits`` /
         #: ``zk_repl_pushed_bytes``): 0 where replicas apply in process
+        self.repl_pushes = 0
+        self.repl_pushed_commits = 0
         self.repl_pushed_bytes = 0
         #: Leadership epoch (server/election.py): a fencing token, not
         #: a zxid component.  0 until the first election; bumped by the

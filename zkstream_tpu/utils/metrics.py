@@ -349,12 +349,14 @@ class TickLedger:
       (server/persist.py ``WriteAheadLog.append``, without the fsync
       gate), ``wal_roll`` — what a segment roll holds the loop for
       (its blocking sync + the whole-tree snapshot's capture), and
-      ``repl_push`` — a commit's pushes to the mirrors, frame + send
-      (server/replication.py ``_push_commits``).  All three nest
+      ``repl_push`` — a group of commits' one push a mirror, frame +
+      send (server/replication.py ``_ship``: a forwarded batch's
+      commits, or what a turn of the loop committed).  All three nest
       under whatever phase holds that time (``decode_apply`` for a
-      client's write, ``control`` for one the control channel
-      applied), so the parents keep their subject and the three say
-      what a large record costs;
+      client's write, ``control`` for what the control channel
+      applied; a turn's own ship runs behind the turn, under no
+      other phase), so the parents keep their subject and the three
+      say what a large record costs;
     - ``list_encode`` — the sort and encode of a children list whose
       serialized reply the member did not hold (server/server.py
       ``ReplyCache``: once a change of the parent, whoever asks),
