@@ -135,13 +135,13 @@ async def test_heavy_tailed_streams_equal_the_scalar_drain(
         # the ticks' batch memory is used again and never zeroed: what
         # an earlier tick left in the padding must not matter
         ingest._arena = np.full((ingest.TICK_BYTES,), 0xFF, np.uint8)
-        inner = ingest._tick_inner
+        inner = ingest._dispatch
 
-        def noted(plans, sp, t0):
+        def noted(plans, before, t0):
             seen.extend((key, nbytes, len(streams))
                         for _ex, key, streams, _b, _l, nbytes in plans)
-            return inner(plans, sp, t0)
-        ingest._tick_inner = noted
+            return inner(plans, before, t0)
+        ingest._dispatch = noted
 
     want, _none = await run_corpus(False, use_native, seed)
     got, ingest = await run_corpus(True, use_native, seed, watch)

@@ -14,7 +14,12 @@ identical — the order in which futures settle and with what, the
 notifications, reserved-xid callbacks and state changes between them,
 ``session.last_zxid``, the expiry deadline under one clock, ``reqs``,
 ``xid_map`` and the bytes left over.  Each case runs with the C
-extension and under ``ZKSTREAM_NO_NATIVE``.
+extension and under ``ZKSTREAM_NO_NATIVE``, and with the bytes handed
+over as asyncio's protocol push does (one call a connection: the
+scheduled tick dispatches and waits) and as a receive reap does (the
+tier's ``_rx_reap`` over all of them: the batch is dispatched at the
+reap's end and the scheduled tick finds it — io/ingest.py, "The early
+dispatch").
 """
 
 import asyncio
@@ -29,6 +34,7 @@ from zkstream_tpu.io import session as session_mod
 from zkstream_tpu.io.connection import Backend, ZKConnection
 from zkstream_tpu.io.ingest import FleetIngest
 from zkstream_tpu.io.session import ZKSession
+from zkstream_tpu.io.transport import TransportTier, _Entry
 from zkstream_tpu.protocol.framing import PacketCodec, frame
 from zkstream_tpu.protocol.records import Stat
 from zkstream_tpu.utils import native
@@ -190,9 +196,14 @@ class Peer:
     def raw(self, data: bytes) -> None:
         self.wire += data
 
+    def take(self) -> bytes:
+        """What the server wrote since the last hand-over."""
+        data, self.wire = bytes(self.wire), bytearray()
+        return data
+
     def flush(self) -> None:
         """Hand what the server wrote to the connection."""
-        data, self.wire = bytes(self.wire), bytearray()
+        data = self.take()
         if data:
             self.conn.emit('sockData', data)
 
@@ -224,6 +235,42 @@ class Peer:
         pend = self.conn.codec.take_pending()
         self.conn.codec.restore_pending(pend)
         return bytes(pend)
+
+
+class ReapRig:
+    """A client tier whose native receiver is the test: ``reap`` runs
+    the tier's real ``_rx_reap`` over the bytes given, a delivery a
+    connection, as the receiver thread's wake-up does."""
+
+    def __init__(self):
+        self.tier = TransportTier('mmsg', plane='client')
+        self.tier._receiver, self.tier._ext = object(), self
+        self._items: list = []
+        self._tokens: dict = {}
+
+    def receiver_reap(self, _receiver):
+        items, self._items = self._items, []
+        return items, 0, 0
+
+    def token(self, conn) -> int:
+        token = self._tokens.get(conn)
+        if token is None:
+            e = _Entry(None, None)
+            # as ``Peer.flush`` hands bytes over: below ``_sock_data``,
+            # whose injector gate the cases' stand-in does not have
+            e.on_bytes = lambda data: conn.emit('sockData', data)
+            token = self._tokens[conn] = len(self._tokens) + 1
+            self.tier._rx[token] = e
+        return token
+
+    def reap(self, pairs) -> None:
+        """``pairs``: (connection, bytes | -errno) in arrival order."""
+        self._items = [(self.token(conn), data)
+                       for conn, data in pairs if data]
+        self.tier._rx_reap()
+
+    def flush(self, peers) -> None:
+        self.reap([(p.conn, p.take()) for p in peers])
 
 
 async def settle() -> None:
@@ -485,8 +532,9 @@ CASES = {
 
 
 async def run_case(case, through_ingest: bool, use_native: bool,
-                   seed: int):
+                   seed: int, fed: str = 'push'):
     ingest = None
+    rig = ReapRig() if fed == 'reap' else None
     if through_ingest:
         # one size class for the case whose callback closes a LATER
         # stream of the same tick: streams route in slot order within a
@@ -508,8 +556,11 @@ async def run_case(case, through_ingest: bool, use_native: bool,
              for i in range(5)]
 
     async def flush():
-        for p in peers:
-            p.flush()
+        if rig is not None:
+            rig.flush(peers)
+        else:
+            for p in peers:
+                p.flush()
         await settle()
 
     try:
@@ -527,14 +578,16 @@ async def run_case(case, through_ingest: bool, use_native: bool,
             ingest.close()
     return snaps, expect, sum(a for a, _b in lanes), \
         sum(b for _a, b in lanes), \
-        ingest and (ingest.lists_routed, ingest.lists_shared)
+        ingest and (ingest.lists_routed, ingest.lists_shared,
+                    ingest.ticks, ingest.ticks_early)
 
 
+@pytest.mark.parametrize('fed', ['push', 'reap'])
 @pytest.mark.parametrize('use_native', [True, False],
                          ids=['ext', 'no_native'])
 @pytest.mark.parametrize('case', list(CASES))
 async def test_batch_route_equals_per_stream_reference(
-        case, use_native, monkeypatch):
+        case, use_native, fed, monkeypatch):
     if use_native:
         if native.ensure_ext() is None:
             pytest.skip('no C extension here (no compiler)')
@@ -545,7 +598,14 @@ async def test_batch_route_equals_per_stream_reference(
     want, _n, _l, _e, _s = await run_case(case, False, use_native,
                                           seed=29)
     got, lane_frames, laned, emitted, lists = await run_case(
-        case, True, use_native, seed=29)
+        case, True, use_native, seed=29, fed=fed)
+    *lists, ticks, early = lists
+    lists = tuple(lists)
+    # every device tick a reap fed was dispatched at the reap's end, or
+    # (a follow-up) at the end of the tick before; asyncio's push
+    # leaves only the follow-ups to go ahead of their tick
+    assert ticks > 0
+    assert early == ticks if fed == 'reap' else early < ticks
     for i, (w, g) in enumerate(zip(want, got)):
         assert g == w, 'connection %d differs' % i
     assert any(w['log'] for w in want)       # the case observed something

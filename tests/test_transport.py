@@ -1283,6 +1283,87 @@ async def test_closing_the_tier_gives_live_connections_back():
         await p.stop()
 
 
+@needs_receiver
+async def test_after_reap_runs_what_the_deliveries_left():
+    """``after_reap``: a callee of a reap's delivery leaves a callable;
+    it runs once, inside the reap's callback, when every connection of
+    that reap has its bytes — never between two deliveries; outside a
+    reap the ask is refused."""
+    from zkstream_tpu.io.transport import after_reap
+    tier = _rx_tier('receiver_thread')
+    wire, _want = _reply_wire()
+    peers = [await _RxPeer(i, tier).start() for i in range(3)]
+    order: list = []
+    asked: list = []
+    try:
+        assert after_reap(lambda: order.append('refused')) is False
+        for p in peers:
+            p.expect(REPLIES)
+
+            def on_data(_d, p=p):
+                order.append(('data', p.idx))
+                asked.append(after_reap(
+                    lambda: order.append(('after', p.idx))))
+            p.conn.on('sockData', on_data)
+            p.peer.send(wire)
+        await _until(lambda: all(sum(p.chunks) == len(wire)
+                                 for p in peers))
+        assert asked == [True] * len(asked) and len(asked) >= 3
+        # within a reap: its deliveries, then what they left, in order
+        reaps, cur = [], []
+        for e in order:
+            if e[0] == 'data' and cur and cur[-1][0] == 'after':
+                reaps.append(cur)
+                cur = []
+            cur.append(e)
+        reaps.append(cur)
+        for r in reaps:
+            datas = [e[1] for e in r if e[0] == 'data']
+            assert [e[1] for e in r if e[0] == 'after'] == datas
+            assert r[:len(datas)] == [('data', i) for i in datas]
+        assert 'refused' not in order
+        assert after_reap(lambda: None) is False
+    finally:
+        for p in peers:
+            await p.stop()
+        tier.close()
+
+
+@needs_receiver
+async def test_a_reap_hands_the_fleet_ingest_its_early_dispatch():
+    """Through the real receiver thread: the connections of a fleet
+    ingest get their bytes in a reap, the ingest dispatches its batch
+    at the reap's end (``ticks_early`` = ``ticks``) and the scheduled
+    tick delivers every connection the stream the scalar drain
+    decodes."""
+    from zkstream_tpu.io.ingest import FleetIngest
+    tier = _rx_tier('receiver_thread')
+    ingest = FleetIngest(bypass_bytes=0, warm='block', placement='host',
+                         max_frames=8, min_len=512)
+    wire, want = _reply_wire()
+    peers = []
+    try:
+        for i in range(3):
+            p = _RxPeer(i, tier)
+            p.client.ingest = ingest
+            peers.append(await p.start())
+        for p in peers:
+            assert p.entry.rx_token and id(p.conn) in ingest._slots
+            p.expect(REPLIES)
+            p.peer.send(wire)
+        await _until(lambda: all(len(p.packets()) == len(want)
+                                 for p in peers))
+        assert all(p.packets() == want for p in peers)
+        assert ingest.ticks >= 1 and ingest.ticks_scalar == 0
+        assert ingest.ticks_early == ingest.ticks
+        assert ingest._flight is None
+    finally:
+        for p in peers:
+            await p.stop()
+        tier.close()
+        ingest.close()
+
+
 # -- e2e over real sockets: parity + accounting + mntr -----------------
 
 async def _scripted_ops(backend: str) -> list[tuple]:

@@ -43,6 +43,35 @@ The tick is synchronous inside the event loop: all ``data_received``
 callbacks of one select cycle run before the ``call_soon``-scheduled
 tick, so one tick coalesces everything the loop just read.
 
+**The early dispatch.**  A tick has two halves — build the batches and
+dispatch them (phases ``batch`` and ``dispatch``), then read the
+results back and route them (``readback`` and ``route``) — and between
+them the device computes while the loop has nothing of the tick's to
+do.  So the first half runs as soon as a loop iteration's bytes are
+all in their slots and no batch is in flight: at the end of the
+receive reap that fed them (io/transport.py, ``after_reap``: the
+loop's shared client tier on ``mmsg``), or, for a follow-up tick (a
+slot that held more than it gave, a full tick, the frame bound, a
+withheld suffix released), at the end of the tick before.  The second
+half is the ``call_soon``-scheduled tick, which stands in the loop's
+queue behind what was ready when the bytes came — the tier's flush,
+the awaiters the last route woke and their next submits — and so
+finds the results on the host; where nothing else was ready it runs
+at once and waits, as a tick always did.  Where asyncio's protocol
+push delivers (no reap marks the end of an iteration's bytes) the
+scheduled tick runs both halves together.  Every dispatch asks for
+its results' copy to the host at once (``copy_to_host_async``), so a
+readback that comes later than the device's answer finds them there
+and pays no round trip.  At most one batch is in
+flight: bytes that arrive behind it stay in their slots for the next
+one, and the batch memory is not touched before the route has read
+the results.  Every decision a tick makes (the regime and its flip,
+the size classes, the slot that waits, the injector's faults, a
+bucket still compiling) is made by the same code, at the dispatch;
+what is routed, and in what order, does not depend on which moment
+that was.  ``ticks_early`` counts the device ticks dispatched ahead of
+their tick, beside ``ticks``.
+
 **Size classes.**  A tick's rows are dispatched by width: a row's
 class is the power of two that holds the bytes it gives the tick, from
 ``min_len`` up to the one that holds a frame at the 16 MiB cap, and
@@ -109,6 +138,7 @@ from ..utils import alloc
 from ..utils.logging import Logger
 from ..utils.metrics import TICK_BUCKETS, Histogram
 from ..utils.trace import NO_SPAN, host_span
+from .transport import after_reap
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from .connection import ZKConnection  # noqa: quoted annotations
@@ -136,6 +166,30 @@ _PHASE_HELP = ('Device tick time by phase, milliseconds (batch: find the '
 #: the label sets of ``zkstream_ingest_phase_ms``, built once
 _PHASE_LABELS = tuple({'phase': p} for p in
                       ('batch', 'dispatch', 'readback', 'route'))
+
+
+class _Flight:
+    """A device tick between its two halves: dispatched, not yet read
+    back.  ``tick`` its number, ``plans`` its dispatches
+    (:meth:`FleetIngest._prepare_batch`) and ``outs`` their results on
+    the device, ``before`` the ingest's ``frames_routed`` when the
+    tick began, ``times`` when phases ``batch`` and ``dispatch``
+    opened and when the last dispatch returned; ``early_ms`` the loop
+    time the first half took and ``fields`` what it noted for the
+    ``ingest.tick`` span, where it ran ahead of its tick (else 0.0 and
+    None)."""
+
+    __slots__ = ('tick', 'plans', 'outs', 'before', 'times',
+                 'early_ms', 'fields')
+
+    def __init__(self, tick, plans, outs, before, times):
+        self.tick = tick
+        self.plans = plans
+        self.outs = outs
+        self.before = before
+        self.times = times
+        self.early_ms = 0.0
+        self.fields = None
 
 
 def _executable_platform(ex) -> str | None:
@@ -298,12 +352,30 @@ class FleetIngest:
         # ``bytes`` a reply body, ~1 MB each in a herd of large
         # re-reads: keep them for the next tick (utils/alloc.py)
         alloc.keep_freed_memory()
+        # ...and a device tick allocates a reply dict and a Stat a
+        # frame in one burst: the collector's young generation follows
+        # the registered slots (utils/alloc.py), from here to close()
+        self._gc_slots = 0
+        self._closed = False
+        alloc.fit_collector(self, 0)
+        #: a tick is queued on the loop / something came up that the
+        #: next tick's first half must look at (bytes fed, a follow-up)
         self._scheduled = False
+        self._due = False
+        #: the device tick that is dispatched and not yet routed
+        self._flight: _Flight | None = None
+        #: this ingest's early dispatch stands in the running reap's
+        #: ``after_reap`` list
+        self._asked = False
         #: diagnostics for tests/benchmarks (``ticks`` counts device
         #: ticks; small ticks under ``bypass_bytes`` and ticks deferred
         #: to the scalar drain while a shape bucket compiles count
         #: separately)
         self.ticks = 0
+        #: of ``ticks``, those whose batches were dispatched ahead of
+        #: their tick (at the end of the reap that fed them, or of the
+        #: tick before): the rest dispatched and waited in one call
+        self.ticks_early = 0
         self.ticks_scalar = 0
         self.ticks_warming = 0
         #: While a device tick's route runs, its number (the ``tick``
@@ -467,9 +539,20 @@ class FleetIngest:
             if resid:
                 slot[1].extend(resid)
                 self._schedule()
+        self._fit_collector()
+
+    def _fit_collector(self) -> None:
+        """The registered slots doubled or halved since the collector's
+        young generation was sized to them: size it again."""
+        n, was = len(self._slots), self._gc_slots
+        if (n >= 2 * was or 2 * n <= was) and n != was \
+                and not self._closed:
+            self._gc_slots = n
+            alloc.fit_collector(self, n)
 
     def unregister(self, conn: 'ZKConnection') -> None:
         slot = self._slots.pop(id(conn), None)
+        self._fit_collector()
         self._no_hold.discard(id(conn))
         held = self._held.pop(id(conn), None)
         if held is not None and slot is not None:
@@ -492,8 +575,17 @@ class FleetIngest:
                 slot[1].clear()
             self._deliver_direct(conn, data)
             return
-        slot[1].extend(data)
+        if self._held and id(conn) in self._held:
+            # behind the suffix an early dispatch's injector withheld
+            # (it rejoins the slot when that tick has routed)
+            self._held[id(conn)] += data
+        else:
+            slot[1].extend(data)
         self._schedule()
+        if not self._asked:
+            # fed by a reap: the batch is dispatched when the reap has
+            # fed the last of its connections
+            self._asked = after_reap(self._reaped)
 
     @property
     def direct(self) -> bool:
@@ -528,6 +620,9 @@ class FleetIngest:
             conn.emit('ingestDeliver', pkts, err)
 
     def _schedule(self) -> None:
+        """A tick is due: bytes reached a slot, or a tick left work for
+        a follow-up."""
+        self._due = True
         if not self._scheduled:
             self._scheduled = True
             asyncio.get_running_loop().call_soon(self._tick)
@@ -822,7 +917,12 @@ class FleetIngest:
         exits; without this the parked worker lives until process
         exit — harmless (it holds only the queue, never the ingest)
         but untidy in thread dumps.  The ingest itself needs no other
-        teardown: connections unregister themselves."""
+        teardown: connections unregister themselves.  The collector's
+        young generation stops following this ingest's slots; the
+        process's last ingest to close puts the thresholds back
+        (utils/alloc.py)."""
+        self._closed = True
+        alloc.release_collector(self)
         if self._warm_queue is not None:
             self._warm_queue.put(None)
             self._warm_queue = None
@@ -838,6 +938,11 @@ class FleetIngest:
         for name, attr, help_text in (
                 ('zkstream_ingest_ticks', 'ticks',
                  'device ticks dispatched'),
+                ('zkstream_ingest_early_ticks', 'ticks_early',
+                 'device ticks whose batches were dispatched ahead of '
+                 'their tick, at the end of the receive reap that fed '
+                 'them or of the tick before (the device computes '
+                 'while the loop works)'),
                 ('zkstream_ingest_scalar_ticks', 'ticks_scalar',
                  'ticks drained through the scalar codec (bypass or '
                  'failed bucket)'),
@@ -1109,24 +1214,87 @@ class FleetIngest:
                     buf[:0] = resid
 
     def _tick(self) -> None:
+        """The scheduled tick: the second half of the device tick in
+        flight (its batches went out ahead, "The early dispatch"), or
+        both halves of one that begins here."""
+        self._scheduled = False
+        flight, self._flight = self._flight, None
+        if flight is None and not self._due:
+            return      # an early first half found nothing to dispatch
         t0 = time.perf_counter()
         # host span ``ingest.tick`` (utils/trace.host_span: recorded
-        # only inside a profiler session): the whole tick, as the
-        # duration histogram times it.  ``tick`` is the number this
-        # tick takes if it runs the device program — its phases carry
-        # the same one; a tick that did not says so in ``detail``.
-        with host_span('ingest.tick', tick=self.ticks + 1) as sp:
-            routed = self._tick_impl(sp)
-            if not routed:
+        # only inside a profiler session): the tick as the duration
+        # histogram times it, from here — its phases ``batch`` and
+        # ``dispatch`` stand before it where they ran ahead.  ``tick``
+        # is the number this tick takes if it runs the device program
+        # — its phases carry the same one; a tick that did not says so
+        # in ``detail``.
+        early_ms = 0.0
+        with host_span('ingest.tick',
+                       tick=(self.ticks + 1 if flight is None
+                             else flight.tick)) as sp:
+            if flight is None:
+                routed = flight = self._begin(sp)
+            else:
+                routed = True
+                early_ms = flight.early_ms
+                if flight.fields:
+                    sp.set(**flight.fields)
+            if flight.__class__ is _Flight:
+                self._finish(flight, sp)
+            elif not routed:
                 sp.cancel()
         if routed:
-            self.tick_hist.observe((time.perf_counter() - t0) * 1000.0)
+            self.tick_hist.observe(
+                (time.perf_counter() - t0) * 1000.0 + early_ms)
+        if self._due:
+            # a follow-up (the route hit the frame bound, a slot held
+            # more than it gave, bytes came behind the flight): its
+            # first half now, so the device works while the woken run
+            self._schedule()
+            self._early()
 
-    def _tick_impl(self, sp=NO_SPAN) -> bool:
-        """One drain tick; returns True when it routed work (those
-        ticks feed the duration histogram — empty bookkeeping wakeups
-        would only blur the distribution's low end)."""
-        self._scheduled = False
+    def _reaped(self) -> None:
+        """The reap that fed the slots has fed the last of them
+        (io/transport.py ``after_reap``)."""
+        self._asked = False
+        self._early()
+
+    def _early(self) -> None:
+        """The first half of the tick that is scheduled, ahead of it:
+        what it dispatches is in flight until that tick routes it.
+        Nothing where no tick is scheduled or nothing is due, in the
+        pass-through regime, or behind a batch still in flight (the
+        bytes wait in their slots for the next one)."""
+        if (self._flight is not None or self._direct
+                or not self._scheduled or not self._due):
+            return
+        t0 = time.perf_counter()
+        with host_span('ingest.tick', tick=self.ticks + 1) as sp:
+            flight = self._begin(sp)
+            if flight is not True:
+                # nothing drained, or dispatched: the scheduled tick's
+                # span is the one the ring keeps
+                sp.cancel()
+        took = (time.perf_counter() - t0) * 1000.0
+        if flight is True:      # drained here another way
+            self.tick_hist.observe(took)
+        elif flight:
+            flight.early_ms = took
+            flight.fields = getattr(sp, 'fields', None)
+            self._flight = flight
+            self.ticks_early += 1
+
+    def _begin(self, sp=NO_SPAN):
+        """A tick's first half: the regime's bookkeeping, the
+        injector's tick-time faults, the batches, their dispatch.
+        Returns the :class:`_Flight` to read back and route; True when
+        the tick routed its work here another way (pass-through
+        bookkeeping or flip, buckets still compiling); False when it
+        found nothing to drain.  Ticks that return True or a flight
+        feed the duration histogram — empty bookkeeping wakeups would
+        only blur the distribution's low end."""
+        self._due = False
         win = self._window_bytes
         self._window_bytes = 0
         if win:
@@ -1158,8 +1326,8 @@ class FleetIngest:
         # tick that is drained another way (nothing buffered,
         # pass-through flip, bucket still compiling) leaves no batch
         # span behind.
-        active: list = []
         plans = ()
+        flight = None
         before = self.frames_routed
         try:
             t0 = time.perf_counter()
@@ -1170,17 +1338,24 @@ class FleetIngest:
                 if not plans:
                     bsp.cancel()
             if plans:
-                self._tick_inner(plans, sp, t0)
+                flight = self._dispatch(plans, before, t0)
+                return flight
         finally:
-            # ``()``: no slot held a whole frame — nothing was drained
-            drained = plans != ()
-            if drained:
-                self._note_frames(self.frames_routed - before)
-                self._frames_mark = self.frames_routed
-                sp.set(batch=self.frames_routed - before)
-            if self._release_held():
-                self._schedule()     # finish the withheld suffixes
-        return drained
+            if flight is None:
+                # ``()``: no slot held a whole frame — nothing drained
+                self._end_tick(plans != (), before, sp)
+        return plans != ()
+
+    def _end_tick(self, drained: bool, before: int, sp) -> None:
+        """What every tick of the batch regime ends with, however it
+        drained: the fragmentation EMA fed, the withheld suffixes back
+        in their slots."""
+        if drained:
+            self._note_frames(self.frames_routed - before)
+            self._frames_mark = self.frames_routed
+            sp.set(batch=self.frames_routed - before)
+        if self._release_held():
+            self._schedule()     # finish the withheld suffixes
 
     def _inject_tick_faults(self) -> None:
         """Apply the injector's tick-time decisions to the batch-regime
@@ -1355,61 +1530,84 @@ class FleetIngest:
                    nbytes=sum(p[5] for p in plans))
         return plans
 
-    def _tick_inner(self, plans, sp, t0: float) -> None:
-        """The device tick proper, once its batches stand (``t0``: when
-        phase ``batch`` opened): every dispatch sent, every result read
-        back — all in flight together, in the memory ``TICK_BYTES``
-        bounds — then one route over all of them.  Each phase is a
-        host span under ``ingest.tick`` carrying the tick's number
-        (profiler sessions only; ``ingest.dispatch`` and
-        ``ingest.readback`` once a dispatch) and, with ``batch``, one
-        observation of ``zkstream_ingest_phase_ms{phase=}`` a tick
-        (always)."""
+    def _dispatch(self, plans, before: int, t0: float) -> _Flight:
+        """Phase ``dispatch``, once a tick's batches stand (``t0``:
+        when phase ``batch`` opened): every dispatch sent — all in
+        flight together, in the memory ``TICK_BYTES`` bounds, which
+        nothing writes again until :meth:`_finish` has read the
+        results — and each result's copy to the host asked for at
+        once, so the readback finds it there where the loop had other
+        work meanwhile.  Host span ``ingest.dispatch`` once a
+        dispatch, with the tick's number (profiler sessions only)."""
         n = self.ticks
         device = self.body_mode == 'device'
         t1 = time.perf_counter()
-        outs, results = [], []
+        outs = []
         for ex, key, streams, batch, lens, nbytes in plans:
             with host_span('ingest.dispatch', tick=n, rows=len(streams),
                            width=key[2], nbytes=nbytes):
-                outs.append(ex(batch, lens))
+                out = ex(batch, lens)
+                # the results come to the host as soon as they stand,
+                # not when the readback asks (a D2H round trip is
+                # ~0.6 ms of the loop on the chip, PERF.md, PR 43)
+                for arr in (out if device else (out,)):
+                    arr.copy_to_host_async()
+                outs.append(out)
             self.dispatches += 1
             self.bytes_batched += nbytes
             self.bytes_dispatched += batch.size
-        t2 = time.perf_counter()
-        for out in outs:
-            with host_span('ingest.readback', tick=n):
-                if device:      # the only 2 readbacks per dispatch
-                    results.append((np.asarray(out[0]),
-                                    np.asarray(out[1])))
-                else:
-                    results.append((np.asarray(out), None))
-        t3 = time.perf_counter()
-        with host_span('ingest.route', tick=n) as rsp:
-            laned = emitted = 0
-            names = self.names_routed
-            lists, shared = self.lists_routed, self.lists_shared
-            self.routing = n
-            try:
-                for plan, (ints, byts) in zip(plans, results):
-                    streams, lens = plan[2], plan[4]
-                    st, bd = self._unpack(ints, byts)
-                    B = len(streams)
-                    self.bytes_recopied += int(np.where(
-                        st.bad[:B], 0, lens[:B] - st.resid[:B]).sum())
-                    a, b = self._route_batch(streams, None, st, bd)
-                    laned += a
-                    emitted += b
-            finally:
-                self.routing = None
-            rsp.set(lane=laned, emitted=emitted,
-                    names=self.names_routed - names,
-                    lists=self.lists_routed - lists,
-                    shared=self.lists_shared - shared)
-        t4 = time.perf_counter()
+        return _Flight(n, plans, outs, before,
+                       (t0, t1, time.perf_counter()))
+
+    def _finish(self, flight: _Flight, sp) -> None:
+        """A device tick's second half: every result read back — found
+        on the host where the dispatch ran ahead of the tick, waited
+        for where it did not — then one route over all of them.  Each
+        phase is a host span carrying the tick's number (profiler
+        sessions only; ``ingest.readback`` once a dispatch) and, with
+        the first half's two, one observation of
+        ``zkstream_ingest_phase_ms{phase=}`` a tick (always)."""
+        n, plans = flight.tick, flight.plans
+        device = self.body_mode == 'device'
+        results = []
+        try:
+            t2 = time.perf_counter()
+            for out in flight.outs:
+                with host_span('ingest.readback', tick=n):
+                    if device:      # the only 2 readbacks per dispatch
+                        results.append((np.asarray(out[0]),
+                                        np.asarray(out[1])))
+                    else:
+                        results.append((np.asarray(out), None))
+            t3 = time.perf_counter()
+            with host_span('ingest.route', tick=n) as rsp:
+                laned = emitted = 0
+                names = self.names_routed
+                lists, shared = self.lists_routed, self.lists_shared
+                self.routing = n
+                try:
+                    for plan, (ints, byts) in zip(plans, results):
+                        streams, lens = plan[2], plan[4]
+                        st, bd = self._unpack(ints, byts)
+                        B = len(streams)
+                        self.bytes_recopied += int(np.where(
+                            st.bad[:B], 0, lens[:B] - st.resid[:B]).sum())
+                        a, b = self._route_batch(streams, None, st, bd)
+                        laned += a
+                        emitted += b
+                finally:
+                    self.routing = None
+                rsp.set(lane=laned, emitted=emitted,
+                        names=self.names_routed - names,
+                        lists=self.lists_routed - lists,
+                        shared=self.lists_shared - shared)
+            t4 = time.perf_counter()
+        finally:
+            self._end_tick(True, flight.before, sp)
+        t0, t1, t_sent = flight.times
         observe = self.phase_hist.observe
         for labels, a, b in zip(_PHASE_LABELS, (t0, t1, t2, t3),
-                                (t1, t2, t3, t4)):
+                                (t1, t_sent, t3, t4)):
             observe((b - a) * 1000.0, labels)
 
     def _route_batch(self, streams, rows, st, bd) -> tuple[int, int]:
@@ -1445,18 +1643,21 @@ class FleetIngest:
         max_frames = self.max_frames
         laned = routed = pos = 0
         retick = False
-        for i, (conn, buf, lane) in enumerate(streams):
+        for i, slot in enumerate(streams):
+            conn, buf, lane = slot
             pkts = None
             if lens is not None and lens[i]:
                 # this stream's share of the batch decode
                 pkts = flat[pos:pos + counts[i]]
                 pos += counts[i]
             # A user callback from an earlier stream's delivery may
-            # have torn this connection down mid-tick (unregister
-            # already restored its bytes to the codec): skip it — and
-            # hand back the xids the batch decode consumed for it, for
-            # the codec will decode those bytes again.
-            if id(conn) not in slots:
+            # have torn this connection down mid-tick, or the
+            # connection left ``connected`` between an early dispatch
+            # and this route (unregister already restored its bytes to
+            # the codec): skip it — and hand back the xids the batch
+            # decode consumed for it, for the codec will decode those
+            # bytes again.
+            if slots.get(id(conn)) is not slot:
                 if pkts:
                     maps[i].update((pkt['xid'], pkt['opcode'])
                                    for pkt in pkts

@@ -111,7 +111,12 @@ when no ``recv`` of the fd is in flight, and delivers what was received
 and not yet reaped first; a connection's unreaped bytes are bounded in
 the thread (``RECEIVER_LIMIT``), behind which the kernel's socket
 buffer pushes back on the peer; ``close`` gives live connections back
-to their transports.
+to their transports.  A reap is also the one moment that knows a loop
+iteration's bytes are ALL with their connections: a callee of one of
+its deliveries may leave a callable to be run when the last delivery
+is made (:func:`after_reap` — the fleet ingest does, and builds and
+dispatches that iteration's device batch there instead of a loop
+iteration later: io/ingest.py, "The early dispatch").
 
 Observability: ``zookeeper_flush_syscalls_total{plane,backend}``
 counts actual write submissions (the A/B number: O(dirty conns) per
@@ -153,6 +158,25 @@ from ..utils.metrics import Collector
 from ..utils.trace import NO_SPAN, host_add, host_span, stamp_flush
 
 log = logging.getLogger('zkstream_tpu.transport')
+
+#: ``pending``: while one of this thread's tiers hands a reap's bytes
+#: to their connections, the callables those deliveries left behind
+#: (:func:`after_reap`), in the order left; None otherwise.
+_reaping = threading.local()
+
+
+def after_reap(fn) -> bool:
+    """Called from inside a delivery of :meth:`TransportTier._rx_reap`
+    (a connection's ``on_bytes`` and whatever it calls): run ``fn()``
+    once, when the reap has handed every connection its bytes — still
+    inside the reap's callback, after its own accounting.  False, and
+    ``fn`` is dropped, when no reap is delivering on this thread
+    (asyncio's protocol push, a hand-back outside a reap)."""
+    pending = getattr(_reaping, 'pending', None)
+    if pending is None:
+        return False
+    pending.append(fn)
+    return True
 
 TRANSPORT_ENV = 'ZKSTREAM_TRANSPORT'
 
@@ -906,21 +930,30 @@ class TransportTier:
         is dropped: a closed connection's bytes reach nobody else."""
         if self._receiver is None:
             return      # a wake-up that outlived close()
-        with host_span(self._rx_reap_span, accumulate=True):
-            items, calls, ns = self._ext.receiver_reap(self._receiver)
-            if calls:
-                host_add(self._recv_span, calls, ns)
-            rx = self._rx
-            n = 0
-            for token, data in items:
-                e = rx.get(token)
-                if e is not None and self._rx_deliver(e, data):
-                    n += 1
+        after = _reaping.pending = []
+        try:
+            with host_span(self._rx_reap_span, accumulate=True):
+                items, calls, ns = self._ext.receiver_reap(self._receiver)
+                if calls:
+                    host_add(self._recv_span, calls, ns)
+                rx = self._rx
+                n = 0
+                for token, data in items:
+                    e = rx.get(token)
+                    if e is not None and self._rx_deliver(e, data):
+                        n += 1
+        finally:
+            _reaping.pending = None
         if n:
             host_add(self._rx_reaped_span, n, 0)
             self.received_batches += 1
             self.received_reads += n
             self.received_ctr.increment({'plane': self.plane}, by=n)
+        # every connection has its bytes: what the deliveries left to
+        # be done with all of them (``after_reap``; an error goes to
+        # the loop's exception handler, as one of a tick's does)
+        for fn in after:
+            fn()
 
     def _settle(self, entry: _Entry, chunks: list[bytes],
                 nbytes: int, res: int) -> None:

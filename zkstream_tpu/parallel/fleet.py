@@ -126,13 +126,13 @@ class MeshFleetIngest(FleetIngest):
         self._fns[device_bodies] = fn
         return fn
 
-    def _tick_inner(self, plans, sp, t0: float) -> None:
+    def _finish(self, flight, sp) -> None:
         # a tick of several size classes is several collective
         # launches: its stats are theirs added up
         self.global_stats = None
         self._adding = True
         try:
-            super()._tick_inner(plans, sp, t0)
+            super()._finish(flight, sp)
         finally:
             self._adding = False
 
