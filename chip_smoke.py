@@ -14,8 +14,7 @@ call —
   and writes) under 32 parents of 256 children, from ``--seed``;
 - 1,024 ``Client`` sessions in this process sharing one ``FleetIngest``
   forced onto the device (``bypass_bytes=0``, accelerator placement,
-  no background warm), once with ``body_mode='host'`` and once with
-  ``body_mode='device'``, >= 64 ops per session in lock-step rounds
+  no background warm), >= 64 ops per session in lock-step rounds
   plus one watched ``set`` that must deliver exactly one notification
   per session
 
@@ -25,7 +24,7 @@ on the jute spec tier (no ingest, ``use_native_codec=False``); every
 acknowledged write is read back, after a ``sync``, from a different
 member than took it; every tick ran the device program (no scalar,
 warming or fragmentation-guard tick, no failed bucket, every executable
-on the accelerator); both Pallas kernels, compiled by Mosaic, match the
+on the accelerator); the Pallas kernel, compiled by Mosaic, matches the
 jnp pipeline bit for bit; and the C load generator, built from source,
 runs clean against the same ensemble.
 
@@ -73,8 +72,6 @@ FULL = {
     # (streams, row bytes, frames) per kernel check
     'scan_pocket': (8192, 6144, 64),
     'scan_single': (64, 8192, 64),
-    'full_small': (8192, 2048, 32, 16),     # ..., max_data
-    'full_wide': (1024, 2048, 8, 256),
     'tick_pocket': (4096, 4096, 32),        # Bp, L, max_frames
 }
 
@@ -84,7 +81,7 @@ TOY = {
     'voters': 3,
     'sessions': 12,
     'parents': 2,
-    'children': 20,              # still wider than the 16-slot list plane
+    'children': 20,
     'payload': 64,
     'cycles': 1,
     'max_frames': 4,
@@ -92,15 +89,8 @@ TOY = {
     'loadgen_s': 0.5,
     'scan_pocket': (16, 512, 8),
     'scan_single': (8, 512, 8),
-    'full_small': (16, 512, 8, 16),
-    'full_wide': (8, 1024, 4, 64),
     'tick_pocket': (8, 512, 8),
 }
-
-#: width of the device list plane (io/ingest.py default): a children
-#: list longer than this is the one body the device path hands to the
-#: scalar reader by design
-LIST_PLANE = 16
 
 OP_TIMEOUT_MS = 180_000      # an inline bucket compile blocks the loop
 SESSION_TIMEOUT_MS = 120_000
@@ -233,7 +223,7 @@ async def run_arm(arm: str, addrs, root: str, seed: int, cfg: dict,
     obs: list[list] = [[] for _ in range(n)]
     acked = [{'sets': 0, 'data': None, 'kept': None, 'deleted': []}
              for _ in range(n)]
-    counts = {'ops': 0, 'list_overflow': 0, 'notifications': 0}
+    counts = {'ops': 0, 'notifications': 0}
     loop_errors: list = []
     loop = asyncio.get_running_loop()
     prev_handler = loop.get_exception_handler()
@@ -350,8 +340,6 @@ async def run_arm(arm: str, addrs, root: str, seed: int, cfg: dict,
                     ('set-2', r_set('b')),
                     ('get-after-set-2', r_get_own)):
                 await round_('%d/%s' % (cyc, name), fn)
-            if cfg['children'] > LIST_PLANE:
-                counts['list_overflow'] += n
         # -- the watched set: every session arms a data watch on one
         # znode, one session writes it, each session is notified once
         events: list[list] = [[] for _ in range(n)]
@@ -467,16 +455,16 @@ async def read_back(arm, addrs, root, seed, cfg, own, acked,
 
 
 # ---------------------------------------------------------------------
-# the device arms
+# the device arm
 # ---------------------------------------------------------------------
 
-async def device_arm(mode: str, addrs, root, seed, cfg, on_chip: bool,
+async def device_arm(addrs, root, seed, cfg, on_chip: bool,
                      reference: dict) -> dict:
     from zkstream_tpu.io.ingest import FleetIngest
 
+    arm = 'ingest'      # the name its failures carry
     ingest = FleetIngest(
-        max_frames=cfg['max_frames'], body_mode=mode,
-        max_data=cfg['payload'], min_len=cfg['min_len'],
+        max_frames=cfg['max_frames'], min_len=cfg['min_len'],
         placement='accelerator' if on_chip else 'host',
         bypass_bytes=0, warm='block')
     # every batch bucket a fleet of this size can produce, at the one
@@ -490,38 +478,31 @@ async def device_arm(mode: str, addrs, root, seed, cfg, on_chip: bool,
         bp *= 2
     prewarm_s = time.perf_counter() - t0
     try:
-        got = await run_arm(mode, addrs, root, seed, cfg, ingest=ingest)
+        got = await run_arm(arm, addrs, root, seed, cfg, ingest=ingest)
     finally:
         ingest.close()
 
     check(got['obs'] == reference['obs'],
           '%s: observations differ from the jute-tier reference '
-          '(first differing session: %s)' % (mode, next(
+          '(first differing session: %s)' % (arm, next(
               (s for s, (a, b) in enumerate(zip(
                   got['obs'], reference['obs'])) if a != b), '?')))
-    check(ingest.ticks > 0, '%s: no device tick ran' % (mode,))
+    check(ingest.ticks > 0, '%s: no device tick ran' % (arm,))
     for name in ('ticks_scalar', 'ticks_warming', 'ticks_frag'):
         check(getattr(ingest, name) == 0, '%s: %s = %d, every tick '
               'must run the device program'
-              % (mode, name, getattr(ingest, name)))
+              % (arm, name, getattr(ingest, name)))
     failed = {k: b['error'] for k, b in ingest.buckets.items()
               if b['error']}
     check(not failed, '%s: buckets failed to compile: %r'
-          % (mode, failed))
+          % (arm, failed))
     want = 'tpu' if on_chip else 'cpu'
     wrong = {k: b['platform'] for k, b in ingest.buckets.items()
              if b['platform'] != want}
     check(not wrong, '%s: executables not on %s: %r'
-          % (mode, want, wrong))
+          % (arm, want, wrong))
     check(ingest.placed['platform'] == want,
-          '%s: ticks placed on %r' % (mode, ingest.placed))
-    # device bodies: the only frames the scalar reader may take are the
-    # children lists wider than the list plane, which the script counts
-    expect_fb = got['counts']['list_overflow'] if mode == 'device' else 0
-    check(ingest.body_fallbacks == expect_fb,
-          '%s: body_fallbacks = %d, expected %d (the list-overflow '
-          'frames sent): a GET_DATA / Stat / create / notification '
-          'frame fell back' % (mode, ingest.body_fallbacks, expect_fb))
+          '%s: ticks placed on %r' % (arm, ingest.placed))
     return {
         'sessions': cfg['sessions'],
         'ops': got['counts']['ops'],
@@ -531,8 +512,6 @@ async def device_arm(mode: str, addrs, root, seed, cfg, on_chip: bool,
         'ticks_warming': ingest.ticks_warming,
         'ticks_frag': ingest.ticks_frag,
         'frames': ingest.frames_routed,
-        'body_fallbacks': ingest.body_fallbacks,
-        'list_overflow_frames': got['counts']['list_overflow'],
         'buckets': len(ingest.buckets),
         'failed_buckets': 0,
         'impls': sorted({b['impl'] for b in ingest.buckets.values()}),
@@ -551,14 +530,11 @@ async def device_arm(mode: str, addrs, root, seed, cfg, on_chip: bool,
 # ---------------------------------------------------------------------
 
 def _same(tag: str, want, got) -> None:
-    """Bit-for-bit equality of two (nested) NamedTuples of arrays."""
+    """Bit-for-bit equality of two NamedTuples of arrays."""
     import numpy as np
 
     for f in want._fields:
         a, b = getattr(want, f), getattr(got, f)
-        if hasattr(a, '_fields'):
-            _same('%s.%s' % (tag, f), a, b)
-            continue
         check(np.array_equal(np.asarray(a), np.asarray(b)),
               '%s: field %s differs between the kernel and jnp'
               % (tag, f))
@@ -600,8 +576,7 @@ def kernel_corpus(cfg: dict):
     cuts them to the row length its kernel program fits."""
     import numpy as np
 
-    B = max(cfg['scan_pocket'][0], cfg['full_small'][0],
-            cfg['tick_pocket'][0])
+    B = max(cfg['scan_pocket'][0], cfg['tick_pocket'][0])
     kinds = _SLOT_PATTERN * (_FRAMES // len(_SLOT_PATTERN))
     rng = np.random.RandomState(42)
     v = np.zeros((B, sum(4 + _BODY_LEN[k] for k in kinds)), np.uint8)
@@ -697,8 +672,6 @@ def kernel_checks(cfg: dict, on_chip: bool, corpus) -> dict:
     import numpy as np
 
     from zkstream_tpu.ops.pipeline import (
-        getdata_bodies_jnp,
-        wire_full_decode_pallas,
         wire_pipeline_step,
         wire_pipeline_step_pallas,
     )
@@ -728,32 +701,6 @@ def kernel_checks(cfg: dict, on_chip: bool, corpus) -> dict:
                      'row_bytes': L, 'max_frames': F, 'block_rows': 64,
                      'frames': frames, 'matches_jnp': True,
                      'first_call_s': round(secs, 3)}
-
-    for name in ('full_small', 'full_wide'):
-        B, L, F, MD = cfg[name]
-        buf, lens = cut(B, L)
-        t0 = time.perf_counter()
-        st, bd = jax.block_until_ready(jax.jit(
-            lambda b, l, F=F, MD=MD: wire_full_decode_pallas(
-                b, l, max_frames=F, max_data=MD, block_rows=64,
-                interpret=interpret))(buf, lens))
-        secs = time.perf_counter() - t0
-
-        def ref(b, l, F=F, MD=MD):
-            s = wire_pipeline_step(b, l, max_frames=F)
-            return s, getdata_bodies_jnp(b, s, MD)
-        st_j, bd_j = jax.jit(ref)(buf, lens)
-        _same(name, st_j, st)
-        _same(name, bd_j, bd)
-        frames = int(np.asarray(st.n_frames).sum())
-        check(frames > 0, '%s decoded no frame' % (name,))
-        check(int(np.asarray(bd.data_ok).sum()) > 0,
-              '%s parsed no GET_DATA body' % (name,))
-        out[name] = {'kernel': 'pallas_wire_full_scan', 'streams': B,
-                     'row_bytes': L, 'max_frames': F, 'max_data': MD,
-                     'block_rows': 64, 'frames': frames,
-                     'matches_jnp': True,
-                     'first_call_s': round(secs, 3)}
     return out
 
 
@@ -767,7 +714,7 @@ async def tick_pocket_check(cfg: dict, on_chip: bool, corpus) -> dict:
     from zkstream_tpu.ops.pipeline import wire_pipeline_step
 
     Bp, L, F = cfg['tick_pocket']
-    ingest = FleetIngest(max_frames=F, body_mode='host', min_len=L,
+    ingest = FleetIngest(max_frames=F, min_len=L,
                          placement='accelerator' if on_chip else 'host',
                          bypass_bytes=0, warm='block')
     await ingest.prewarm(Bp, L)
@@ -775,8 +722,7 @@ async def tick_pocket_check(cfg: dict, on_chip: bool, corpus) -> dict:
     check(key == (False, Bp, L), 'tick bucket %r' % (key,))
     batch = np.ascontiguousarray(corpus[:Bp, :L])
     lens = np.full((Bp,), L, np.int32)
-    st, _bd = ingest._unpack(np.asarray(ingest._exec[key](batch, lens)),
-                             None)
+    st = ingest._unpack(np.asarray(ingest._exec[key](batch, lens)))
     with jax.default_device(ingest._device):
         want = jax.jit(lambda b, l: wire_pipeline_step(
             b, l, max_frames=F))(batch, lens)
@@ -974,13 +920,12 @@ async def smoke(args, cfg: dict, reduced: dict) -> dict:
             check(len(sess) == 16 * cfg['cycles'] + 1,
                   'reference script length %d' % (len(sess),))
 
-        for mode, root in (('host', '/h'), ('device', '/d')):
-            await load_tree(addrs[0], root, args.seed, cfg)
-            await wait_tree_everywhere(addrs, root, cfg)
-            arm = report['body_mode_' + mode] = await device_arm(
-                mode, addrs, root, args.seed, cfg, on_chip, reference)
-            arm['cache_entries_after'] = cache_entries()
-            say('# body_mode=%s: %s' % (mode, json.dumps(arm)))
+        await load_tree(addrs[0], '/d', args.seed, cfg)
+        await wait_tree_everywhere(addrs, '/d', cfg)
+        arm = report['ingest'] = await device_arm(
+            addrs, '/d', args.seed, cfg, on_chip, reference)
+        arm['cache_entries_after'] = cache_entries()
+        say('# ingest: %s' % (json.dumps(arm),))
         report['compile_cache']['entries_after_ticks'] = cache_entries()
         report['compile_cache']['new_tick_entries'] = (
             report['compile_cache']['entries_after_ticks']

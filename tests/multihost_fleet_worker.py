@@ -92,7 +92,7 @@ async def run(proc_id: int, scenario: str = 'basic') -> None:
     mesh = make_mesh(dp=8)          # global: 2 hosts x 4 devices
     proxy = MultihostFleetIngest(
         mesh=mesh, local_rows=LOCAL_CLIENTS, stream_len=2048,
-        tick_interval=0.01, body_mode='host', max_frames=4)
+        tick_interval=0.01, max_frames=4)
     srv = await ZKServer().start()
     # one aligned warm-up launch per host compiles the program before
     # any session clock runs

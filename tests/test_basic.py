@@ -153,7 +153,7 @@ async def test_megabyte_payload_all_codec_paths(server):
     configs = [
         dict(use_native_codec=False),
         dict(use_native_codec=None),       # ext when built
-        dict(ingest=FleetIngest(body_mode='host', max_frames=4,
+        dict(ingest=FleetIngest(max_frames=4,
                                 bypass_bytes=0, warm='block')),
     ]
     for i, kw in enumerate(configs):

@@ -46,7 +46,7 @@ def clean_host_ring():
 
 
 def _ingest() -> FleetIngest:
-    return FleetIngest(body_mode='host', placement='host', max_frames=8,
+    return FleetIngest(placement='host', max_frames=8,
                        min_len=256, max_data=256, bypass_bytes=0,
                        warm='block')
 

@@ -583,7 +583,7 @@ async def test_ingest_tick_faults_keep_parity(server):
 
     inj = FaultInjector(7, FaultConfig(p_ingest_hold=1.0,
                                        max_faults=None))
-    ingest = FleetIngest(body_mode='host', max_frames=8,
+    ingest = FleetIngest(max_frames=8,
                          bypass_bytes=0)
     ingest.faults = inj
     c = Client(address='127.0.0.1', port=server.port,
@@ -613,7 +613,7 @@ async def test_ingest_tick_reset_is_survivable(server):
 
     inj = FaultInjector(11, FaultConfig(p_ingest_reset=0.2,
                                         max_faults=4))
-    ingest = FleetIngest(body_mode='host', max_frames=8,
+    ingest = FleetIngest(max_frames=8,
                          bypass_bytes=0)
     ingest.faults = inj
     c = Client(address='127.0.0.1', port=server.port,

@@ -61,21 +61,14 @@ def test_cpu_dry_run_end_to_end():
     assert len(members) == 3 and all(m['jax_free'] for m in members)
     assert [m['role'] for m in members].count('leader') == 1
     n = r['deployment']['sessions']
-    for mode in ('body_mode_host', 'body_mode_device'):
-        arm = r[mode]
-        assert arm['ticks'] > 0 and arm['frames'] >= arm['ops']
-        assert (arm['ticks_scalar'], arm['ticks_warming'],
-                arm['ticks_frag'], arm['failed_buckets']) == (0, 0, 0, 0)
-        assert arm['equal_to_reference'] is True
-        assert arm['notifications'] == n
-        assert arm['placed']['platform'] == 'cpu'
-    # the only frames the device-body path may hand to the scalar
-    # reader are the children lists wider than its list plane
-    assert r['body_mode_host']['body_fallbacks'] == 0
-    dev = r['body_mode_device']
-    assert dev['body_fallbacks'] == dev['list_overflow_frames'] == n
-    for k in ('scan_pocket', 'scan_single', 'full_small', 'full_wide',
-              'tick_pocket'):
+    arm = r['ingest']
+    assert arm['ticks'] > 0 and arm['frames'] >= arm['ops']
+    assert (arm['ticks_scalar'], arm['ticks_warming'],
+            arm['ticks_frag'], arm['failed_buckets']) == (0, 0, 0, 0)
+    assert arm['equal_to_reference'] is True
+    assert arm['notifications'] == n
+    assert arm['placed']['platform'] == 'cpu'
+    for k in ('scan_pocket', 'scan_single', 'tick_pocket'):
         assert r['kernels'][k]['matches_jnp'] is True
     lg = r['loadgen']
     assert lg['errors'] == {'connect': 0, 'io': 0, 'proto': 0}

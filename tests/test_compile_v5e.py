@@ -50,14 +50,14 @@ def _compile(dev, fn, B, L):
             jax.ShapeDtypeStruct((B,), jnp.int32)).compile()
 
 
-def _largest_admitted(fits, B, F, block_rows, **kw) -> int:
+def _largest_admitted(fits, B, F, block_rows) -> int:
     """The longest row (stepping whole 128-byte lane tiles) the guard
     admits for this blocking."""
     lo, hi = 108, 1 << 20
-    assert fits(B, lo, F, block_rows, device_kind=V5E, **kw)
+    assert fits(B, lo, F, block_rows, device_kind=V5E)
     while hi - lo > 128:
         mid = (lo + hi) // 2 // 128 * 128 + 108
-        if fits(B, mid, F, block_rows, device_kind=V5E, **kw):
+        if fits(B, mid, F, block_rows, device_kind=V5E):
             lo = mid
         else:
             hi = mid
@@ -74,18 +74,6 @@ def test_header_kernel_compiles_at_the_guards_edge(v5e, B, F, regime):
     assert (Bp > R) == (regime == 'multi-block')
     _compile(v5e, lambda b, l: ps.pallas_wire_scan(
         b, l, max_frames=F, block_rows=64), B, L)
-
-
-@pytest.mark.parametrize('B,F,MD,regime', [
-    (8192, 32, 16, 'multi-block'),
-    (64, 32, 16, 'single-block'),
-])
-def test_fused_kernel_compiles_at_the_guards_edge(v5e, B, F, MD, regime):
-    L = _largest_admitted(ps.fits_vmem_full, B, F, 64, max_data=MD)
-    R, Bp, _Lp = ps._block_shape(B, L, 64)
-    assert (Bp > R) == (regime == 'multi-block')
-    _compile(v5e, lambda b, l: ps.pallas_wire_full_scan(
-        b, l, max_frames=F, block_rows=64, max_data=MD), B, L)
 
 
 @pytest.mark.parametrize('B,Lp,F', [
@@ -116,35 +104,27 @@ def _ingest_for(dev, **kw):
     return ing
 
 
-@pytest.mark.parametrize('Bp,L,impl', [
+@pytest.mark.parametrize('Bp,L,frames,impl', [
     # inside the auto-dispatch pocket and inside VMEM: the kernel
-    (4096, 4096, 'pallas'),
+    (4096, 4096, 32, 'pallas'),
     # inside the pocket, but one program would need 18.8 MiB: this
     # bucket used to fail to compile and drain scalar for the life of
     # the process; the dispatcher now builds it from jnp, and says so
-    (8192, 8192, 'jnp'),
+    (8192, 8192, 32, 'jnp'),
     # the size classes a tick of large replies dispatches: a full
     # dispatch of the 1 MiB class, and the one row of the widest class
     # (a frame at the 16 MiB cap)
-    (16, 1 << 20, 'jnp'),
-    (1, 1 << 25, 'jnp'),
+    (16, 1 << 20, 32, 'jnp'),
+    (1, 1 << 25, 32, 'jnp'),
+    # the benchmark cells' own ``max_frames``: the read cell's widest
+    # dispatch and a wide class of the load cell
+    (1024, 4096, 8, 'jnp'),
+    (4, 131072, 8, 'jnp'),
 ])
-def test_host_body_tick_bucket_compiles_for_v5e(v5e, Bp, L, impl):
-    ing = _ingest_for(v5e, body_mode='host', max_frames=32)
+def test_host_body_tick_bucket_compiles_for_v5e(v5e, Bp, L, frames, impl):
+    ing = _ingest_for(v5e, max_frames=frames)
     key = (False, Bp, L)
     assert ing._try_compile(key) is not None, ing.buckets[key]
     assert ing.buckets[key]['error'] is None
     assert ing.buckets[key]['impl'] == impl
     assert ing.buckets[key]['platform'] == 'tpu'
-
-
-def test_device_body_tick_bucket_compiles_for_v5e(v5e):
-    """The deployed body planes at chip_smoke.py's widths (1 KiB data
-    plane): one bucket — these take ~15 s each to compile."""
-    ing = _ingest_for(v5e, body_mode='device', max_frames=8,
-                      max_data=1024)
-    key = (True, 1024, 2048)
-    assert ing._try_compile(key) is not None, ing.buckets[key]
-    assert ing.buckets[key] == {
-        'impl': 'jnp', 'platform': 'tpu', 'error': None,
-        'compile_s': ing.buckets[key]['compile_s']}

@@ -178,7 +178,7 @@ async def test_doublecheck_probe_through_ingest(
     the lost-wakeup self-check composes with the TPU data plane."""
     from zkstream_tpu.io.ingest import FleetIngest
 
-    ingest = FleetIngest(body_mode='host', max_frames=8, bypass_bytes=0,
+    ingest = FleetIngest(max_frames=8, bypass_bytes=0,
                          warm='block')
     c = Client(address='127.0.0.1', port=server.port,
                session_timeout=5000, ingest=ingest)

@@ -17,7 +17,6 @@ from zkstream_tpu.protocol.errors import (
     ZKFrameTooLargeError,
     ZKProtocolError,
 )
-from zkstream_tpu.protocol.records import Stat
 from zkstream_tpu.protocol.framing import FrameDecoder, PacketCodec
 from zkstream_tpu.protocol.jute import JuteReader, JuteWriter
 from zkstream_tpu.utils import native
@@ -247,117 +246,3 @@ def test_fuzz_seed_corpus_regression():
     assert d.feed(b'abcde') == [b'abcde']
     assert d.feed(struct.pack('>i', 0) * 3) == [b'', b'', b'']
     assert d.pending() == 0
-
-
-_LIST_FUZZ_STEP = None  # one compile serves every fuzz example
-
-
-@settings(max_examples=40, deadline=None)
-@given(st.data())
-def test_list_bodies_device_matches_scalar(data):
-    """Hypothesis property: parse_list_bodies over a random fleet of
-    children/ACL replies (random counts and element widths, sometimes
-    past the static bounds) agrees
-    with the scalar read_response wherever its ok flag is set, and the
-    ok flag equals the static-bounds predicate.  Empty elements
-    exercise the negative-length wire form (the jute '' -> -1
-    convention, lib/jute-buffer.js:127-130)."""
-    import numpy as np
-
-    from zkstream_tpu.ops.pipeline import wire_pipeline_step
-    from zkstream_tpu.ops.replies import parse_list_bodies
-    from zkstream_tpu.protocol.consts import Perm
-    from zkstream_tpu.protocol.jute import JuteWriter
-    from zkstream_tpu.protocol.records import ACL, Id, write_response
-
-    K, S, A, SS, SI = 4, 12, 2, 8, 10
-    import jax.numpy as jnp
-
-    # fixed shapes: one XLA compile serves every example
-    n_streams, F, L = 3, 3, 1024   # L >= worst-case 3x212B frames
-    pkts, streams = [], []
-    for b in range(n_streams):
-        raw, row = b'', []
-        for f in range(F):
-            kind = data.draw(st.sampled_from(
-                ('GET_CHILDREN', 'GET_CHILDREN2', 'GET_ACL')))
-            pkt = {'xid': f + 1, 'zxid': data.draw(
-                st.integers(0, 2**40)), 'err': 'OK', 'opcode': kind}
-            if kind == 'GET_ACL':
-                pkt['acl'] = [
-                    ACL(Perm(data.draw(st.integers(1, 31))),
-                        Id(data.draw(st.text(
-                            alphabet='ab', max_size=SS + 3)),
-                           data.draw(st.text(
-                               alphabet='cd', max_size=SI + 3))))
-                    for _ in range(data.draw(st.integers(0, A + 1)))]
-                pkt['stat'] = Stat()
-            else:
-                pkt['children'] = [
-                    data.draw(st.text(alphabet='xy', max_size=S + 4))
-                    for _ in range(data.draw(st.integers(0, K + 2)))]
-                if kind == 'GET_CHILDREN2':
-                    pkt['stat'] = Stat()
-            w = JuteWriter()
-            write_response(w, pkt)
-            body = w.to_bytes()
-            raw += struct.pack('>i', len(body)) + body
-            row.append(pkt)
-        streams.append(raw)
-        pkts.append(row)
-    assert max(len(s) for s in streams) <= L
-    buf = np.zeros((n_streams, L), np.uint8)
-    lens = np.zeros((n_streams,), np.int32)
-    for i, s in enumerate(streams):
-        buf[i, :len(s)] = np.frombuffer(s, np.uint8)
-        lens[i] = len(s)
-
-    global _LIST_FUZZ_STEP
-    if _LIST_FUZZ_STEP is None:
-        import jax
-
-        def _step(b, l):
-            stt = wire_pipeline_step(b, l, max_frames=F)
-            return parse_list_bodies(
-                b, stt.starts, stt.sizes, max_children=K, max_name=S,
-                max_acls=A, max_scheme=SS, max_id=SI)
-        _LIST_FUZZ_STEP = jax.jit(_step)
-    lb = _LIST_FUZZ_STEP(jnp.asarray(buf), jnp.asarray(lens))
-    for i in range(n_streams):
-        for f in range(F):
-            pkt = pkts[i][f]
-            if pkt['opcode'] == 'GET_ACL':
-                fits = (len(pkt['acl']) <= A and all(
-                    len(a.id.scheme.encode()) <= SS
-                    and len(a.id.id.encode()) <= SI
-                    for a in pkt['acl']))
-                assert bool(lb.acl_ok[i, f]) == fits, (i, f, pkt)
-                if not fits:
-                    continue
-                cnt = int(lb.acl_count[i, f])
-                assert cnt == len(pkt['acl'])
-                for k in range(cnt):
-                    want = pkt['acl'][k]
-                    assert int(lb.acl_perms[i, f, k]) == int(want.perms)
-                    sl = int(lb.acl_scheme_len[i, f, k])
-                    il = int(lb.acl_id_len[i, f, k])
-                    assert 0 <= sl <= SS and 0 <= il <= SI
-                    assert bytes(np.asarray(
-                        lb.acl_scheme)[i, f, k, :sl]).decode() \
-                        == want.id.scheme
-                    assert bytes(np.asarray(
-                        lb.acl_id)[i, f, k, :il]).decode() == want.id.id
-            else:
-                fits = (len(pkt['children']) <= K and all(
-                    len(c.encode()) <= S for c in pkt['children']))
-                assert bool(lb.ch_ok[i, f]) == fits, (i, f, pkt)
-                if not fits:
-                    continue
-                cnt = int(lb.ch_count[i, f])
-                assert cnt == len(pkt['children'])
-                for k in range(cnt):
-                    n = int(lb.ch_len[i, f, k])
-                    assert 0 <= n <= S
-                    assert bytes(np.asarray(
-                        lb.ch_bytes)[i, f, k, :n]).decode() \
-                        == pkt['children'][k]

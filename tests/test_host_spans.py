@@ -48,7 +48,7 @@ def _totals() -> dict:
 
 
 def _ingest() -> FleetIngest:
-    return FleetIngest(body_mode='host', max_frames=8, min_len=256,
+    return FleetIngest(max_frames=8, min_len=256,
                        bypass_bytes=0, warm='block')
 
 
@@ -499,7 +499,7 @@ async def test_phase_histogram_needs_no_session_and_binds(server):
 async def test_a_tick_off_the_device_says_which(server, armed):
     """A pass-through (direct) tick is one ``ingest.tick`` span with no
     tick number and no phases."""
-    ingest = FleetIngest(body_mode='host', max_frames=8, min_len=256,
+    ingest = FleetIngest(max_frames=8, min_len=256,
                          warm='block')       # bypass_bytes at default
     c = Client(address='127.0.0.1', port=server.port, ingest=ingest,
                session_timeout=5000)

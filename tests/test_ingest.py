@@ -80,9 +80,8 @@ async def _run_mode(ingest: FleetIngest | None) -> list:
     srv = await ZKServer().start()
     if ingest is not None:
         # compile the tick program BEFORE any session exists: an
-        # inline compile (device-bodies takes ~10 s on this host)
-        # inside the first tick would block the loop past the session
-        # timeout and the workload's event waits
+        # inline compile inside the first tick runs on the session's
+        # clock and the workload's event waits
         await ingest.prewarm(1)
     c = make_client(srv.port, ingest=ingest)
     try:
@@ -97,24 +96,15 @@ async def _run_mode(ingest: FleetIngest | None) -> list:
 
 async def test_ingest_semantics_match_scalar_drain():
     """The full op surface + watcher sequence observed through the
-    batched path (both body modes) equals the scalar drain's, and the
-    batched path demonstrably carried the traffic."""
+    batched path equals the scalar drain's, and the batched path
+    demonstrably carried the traffic."""
     scalar = await _run_mode(None)
 
-    host_ing = FleetIngest(body_mode='host', max_frames=8, min_len=256,
+    host_ing = FleetIngest(max_frames=8, min_len=256,
                            bypass_bytes=0, warm='block')
     host = await _run_mode(host_ing)
     assert host == scalar
     assert host_ing.ticks > 0 and host_ing.frames_routed > 0
-
-    # min_len=1024: one (B, L) bucket for every tick this workload
-    # can produce, so the single block-mode compile covers them all
-    dev_ing = FleetIngest(body_mode='device', max_frames=8, min_len=1024,
-                          bypass_bytes=0, max_data=128, max_path=64,
-                          warm='block')
-    dev = await _run_mode(dev_ing)
-    assert dev == scalar
-    assert dev_ing.ticks > 0 and dev_ing.frames_routed > 0
 
 
 async def test_ingest_small_tick_bypass():
@@ -122,7 +112,7 @@ async def test_ingest_small_tick_bypass():
     as a pass-through (no device dispatch, no batching overhead) with
     identical semantics; the device pipeline engages once the observed
     bytes-per-tick cross the threshold."""
-    ingest = FleetIngest(body_mode='host', max_frames=8,
+    ingest = FleetIngest(max_frames=8,
                          warm='block')  # default bypass
     assert ingest.bypass_bytes > 0
     assert ingest._direct              # starts in pass-through
@@ -137,7 +127,7 @@ async def test_ingest_small_tick_bypass():
     # cross the threshold: once the per-tick volume is observed above
     # bypass_bytes (one window of hysteresis), traffic flows through
     # the device path
-    big = FleetIngest(body_mode='host', max_frames=8, bypass_bytes=64,
+    big = FleetIngest(max_frames=8, bypass_bytes=64,
                       warm='block')
     srv = await ZKServer().start()
     c = make_client(srv.port, ingest=big)
@@ -154,37 +144,12 @@ async def test_ingest_small_tick_bypass():
         await srv.stop()
 
 
-async def test_ingest_device_fallbacks():
-    """Oversized data fields and list-shaped bodies take the scalar
-    fallback inside the device body mode, transparently."""
-    ingest = FleetIngest(body_mode='device', max_frames=8, bypass_bytes=0,
-                         max_data=8, max_path=8,  # force fallbacks
-                         min_len=1024, warm='block')
-    srv = await ZKServer().start()
-    await ingest.prewarm(1)   # compile before the session's clock runs
-    c = make_client(srv.port, ingest=ingest)
-    try:
-        await c.wait_connected(timeout=5)
-        await c.create('/big', b'x' * 500)       # data >> max_data
-        data, _stat = await c.get('/big')
-        assert data == b'x' * 500
-        path = await c.create('/deep-name-longer-than-eight', b'')
-        assert path == '/deep-name-longer-than-eight'
-        children, _stat = await c.list('/')
-        assert sorted(children) == ['big', 'deep-name-longer-than-eight']
-        acl = await c.get_acl('/big')
-        assert acl and acl[0].id.scheme == 'world'
-    finally:
-        await c.close()
-        await srv.stop()
-
-
 async def test_ingest_fleet_256_connections(event_loop):
     """~256 live connections served through one shared ingest: every
     op correct, every watcher fires, all frames through the batched
     path."""
     B = 256
-    ingest = FleetIngest(body_mode='host', max_frames=8, min_len=256,
+    ingest = FleetIngest(max_frames=8, min_len=256,
                          bypass_bytes=0, warm='block')
     srv = await ZKServer().start()
     clients = [make_client(srv.port, ingest=ingest) for _ in range(B)]
@@ -286,7 +251,7 @@ async def test_ingest_bad_length_parity(split_writes):
     segment with a good reply."""
     scalar = await _bad_length_scenario(None, split_writes)
     fleet = await _bad_length_scenario(
-        FleetIngest(body_mode='host', max_frames=8, bypass_bytes=0,
+        FleetIngest(max_frames=8, bypass_bytes=0,
                     warm='block'),
         split_writes)
     assert fleet == scalar
@@ -339,18 +304,16 @@ async def _corrupt_create_scenario(ingest: FleetIngest | None):
 async def test_ingest_corrupt_ustring_parity():
     scalar = await _corrupt_create_scenario(None)
     assert scalar == ('raise', 'ZKProtocolError', 'BAD_DECODE')
-    for mode in ('host', 'device'):
-        got = await _corrupt_create_scenario(
-            FleetIngest(body_mode=mode, max_frames=8, bypass_bytes=0,
-                        warm='block'))
-        assert got == scalar, (mode, got)
+    got = await _corrupt_create_scenario(
+        FleetIngest(max_frames=8, bypass_bytes=0, warm='block'))
+    assert got == scalar
 
 
 async def test_ingest_host_placement():
     """Explicit placement='host' pins ticks to the CPU backend and
     serves traffic normally (where 'auto' ends up when the
     accelerator's dispatch RTT exceeds the tick budget)."""
-    ingest = FleetIngest(body_mode='host', max_frames=8, bypass_bytes=0,
+    ingest = FleetIngest(max_frames=8, bypass_bytes=0,
                          placement='host', warm='block')
     srv = await ZKServer().start()
     c = make_client(srv.port, ingest=ingest)
@@ -367,46 +330,13 @@ async def test_ingest_host_placement():
         await srv.stop()
 
 
-async def test_ingest_device_list_bodies():
-    """Within the static bounds, children and ACL list replies assemble
-    from the tensor planes (no scalar fallback), matching the scalar
-    decode exactly; beyond the bounds they fall back per frame."""
-    ingest = FleetIngest(body_mode='device', max_frames=8,
-                         bypass_bytes=0, warm='block', min_len=1024,
-                         max_children=8, max_name=16)
-    srv = await ZKServer().start()
-    await ingest.prewarm(1)   # compile before the session's clock runs
-    c = make_client(srv.port, ingest=ingest)
-    try:
-        await c.wait_connected(timeout=5)
-        for i in range(5):
-            await c.create('/n%d' % i, b'')
-        before = ingest.body_fallbacks
-        children, stat = await c.list('/')
-        assert sorted(children) == ['n%d' % i for i in range(5)]
-        assert stat.numChildren == 5
-        acl = await c.get_acl('/n0')
-        assert acl and acl[0].id.scheme == 'world' \
-            and acl[0].id.id == 'anyone'
-        assert ingest.body_fallbacks == before  # device-served
-        # beyond max_children: falls back, same result
-        for i in range(5, 10):
-            await c.create('/n%d' % i, b'')
-        children, _stat = await c.list('/')
-        assert len(children) == 10
-        assert ingest.body_fallbacks > before
-    finally:
-        await c.close()
-        await srv.stop()
-
-
 async def test_ingest_background_warm():
     """Under the production default warm='background', a tick whose
     shape bucket has no compiled program yet never blocks the loop: it
     drains through the scalar codec (identical semantics, counted as
     ticks_warming) while the AOT compile runs on a daemon thread, and
     once the bucket lands the device path engages."""
-    ingest = FleetIngest(body_mode='host', max_frames=8, bypass_bytes=0)
+    ingest = FleetIngest(max_frames=8, bypass_bytes=0)
     assert ingest.warm == 'background'
     srv = await ZKServer().start()
     c = make_client(srv.port, ingest=ingest)
@@ -434,7 +364,7 @@ async def test_ingest_background_warm():
 async def test_ingest_prewarm_block_mode():
     """prewarm under warm='block' compiles synchronously; the first
     real tick then runs the device path immediately."""
-    ingest = FleetIngest(warm='block', body_mode='host', max_frames=8,
+    ingest = FleetIngest(warm='block', max_frames=8,
                          bypass_bytes=0)
     srv = await ZKServer().start()
     c = make_client(srv.port, ingest=ingest)
@@ -451,7 +381,7 @@ async def test_ingest_prewarm_block_mode():
 async def test_ingest_reticks_past_max_frames():
     """More complete frames buffered than max_frames in one tick are
     finished on follow-up ticks, none lost."""
-    ingest = FleetIngest(body_mode='host', max_frames=2, bypass_bytes=0,
+    ingest = FleetIngest(max_frames=2, bypass_bytes=0,
                          warm='block')
     srv = await ZKServer().start()
     c = make_client(srv.port, ingest=ingest)

@@ -440,12 +440,15 @@ async def test_spans_of_a_tick_of_two_classes(monkeypatch):
     ingest.close()
 
 
-async def test_device_bodies_read_nothing_of_a_stale_batch(monkeypatch):
-    """``body_mode='device'``: the body planes too are cut from the
-    rows' own bytes — replies of two classes over batch memory full of
-    an earlier tick's bytes equal the scalar drain's."""
+@pytest.mark.parametrize('use_native', [True, False],
+                         ids=['ext', 'no_native'])
+async def test_host_decode_reads_nothing_of_a_stale_batch(
+        use_native, monkeypatch):
+    """Replies of two classes over batch memory full of an earlier
+    tick's bytes equal the scalar drain's: the scan and the body
+    readers look at nothing beyond a row's own length."""
+    _codec(use_native, monkeypatch)
     _patch_clock(monkeypatch)
-    use_native = native.ensure_ext() is not None
 
     async def run(ingest):
         if ingest is not None:
@@ -465,14 +468,11 @@ async def test_device_bodies_read_nothing_of_a_stale_batch(monkeypatch):
         return snaps
 
     want = await run(None)
-    ingest = _ingest(body_mode='device', max_data=256)
-    for rows, nbytes in ((1, 256), (2, 512), (1, 2048)):
-        await ingest.prewarm(rows, nbytes)  # seconds each: not in the run
+    ingest = _ingest()
     got = await run(ingest)
     assert len(ingest.buckets) == 3
     for i, (w, g) in enumerate(zip(want, got)):
         assert g == w, 'connection %d differs in %s' % (
             i, [k for k in w if w[k] != g[k]])
     assert ingest.ticks == 1 and ingest.dispatches == 3
-    assert ingest.body_fallbacks == 2     # the 700 B and 350 B bodies
     ingest.close()

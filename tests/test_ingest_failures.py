@@ -21,7 +21,7 @@ from zkstream_tpu.io.ingest import FleetIngest
 from zkstream_tpu.protocol.errors import ZKProtocolError
 from zkstream_tpu.protocol.framing import PacketCodec, frame
 from zkstream_tpu.protocol.jute import JuteWriter
-from zkstream_tpu.protocol.records import Stat, write_response
+from zkstream_tpu.protocol.records import write_response
 
 
 class FakeConn:
@@ -379,7 +379,7 @@ async def test_unsupported_reply_opcode_is_bad_decode():
 
 
 async def test_ext_slice_failure_wraps_as_bad_decode():
-    """body_mode='host' C fast path: a stream whose decode raised
+    """The C fast path: a stream whose decode raised
     inside the extension (``decode_streams`` hands the exception back
     as that stream's error) becomes connection-level BAD_DECODE, not
     a raw crash."""
@@ -455,26 +455,17 @@ async def test_teardown_mid_tick_skips_dead_connection():
         assert id(b) not in ing._slots
 
 
-async def test_oversized_device_body_falls_back_to_scalar_reader():
-    """body_mode='device': a data field wider than the tensor plane
-    must fall back to the scalar reader per frame (counted), with the
-    identical packet delivered."""
-    ing = mk_ingest(body_mode='device', max_data=8, max_path=16,
-                    max_frames=2)
-    conn = FakeConn()
-    conn.codec.xid_map[5] = 'GET_DATA'
-    conn.codec.xid_map[6] = 'GET_DATA'
-    ing.register(conn)
-    st = Stat(czxid=1, mzxid=2, pzxid=3)
-    wire = reply_frame(5, 'GET_DATA', data=b'x' * 32, stat=st)  # > 8
-    wire += reply_frame(6, 'GET_DATA', data=b'ok', stat=st)     # fits
-    ing.feed(conn, wire)
-    await drain()
-    pkts, err = conn.delivered[0]
-    assert err is None
-    assert pkts[0]['data'] == b'x' * 32    # scalar fallback, correct
-    assert pkts[1]['data'] == b'ok'        # device plane
-    assert ing.body_fallbacks == 1
+@pytest.mark.parametrize('mesh', [False, True], ids=['fleet', 'mesh'])
+def test_a_body_mode_other_than_host_is_refused(mesh):
+    """The one mode left: bodies are parsed on the host.  The keyword
+    stays for the benchmark's config files (ROADMAP D19); any other
+    value says so at construction."""
+    if mesh:
+        from zkstream_tpu.parallel import MeshFleetIngest as cls
+    else:
+        cls = FleetIngest
+    with pytest.raises(ValueError, match="body_mode='device'"):
+        cls(body_mode='device')
 
 
 async def test_fragmentation_guard_enters_and_exits():

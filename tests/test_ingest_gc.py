@@ -148,7 +148,7 @@ async def test_reads_through_the_ingest_leave_no_cyclic_garbage():
     count — so a young generation that collects later frees nothing
     later."""
     srv = await ZKServer().start()
-    ingest = FleetIngest(body_mode='host', placement='host', max_frames=8,
+    ingest = FleetIngest(placement='host', max_frames=8,
                          min_len=256, bypass_bytes=0, warm='block')
     for bp in (8, 16):
         await ingest.prewarm(bp)

@@ -48,7 +48,7 @@ class Cluster:
         self.ens = await ZKEnsemble(3).start()
         self.ports = [s.port for s in self.ens.servers]
         self.ingest = FleetIngest(
-            body_mode='host', placement='host', max_frames=8,
+            placement='host', max_frames=8,
             min_len=1024, max_data=256, bypass_bytes=0, warm='block')
         for bp in (8, 16, 32):
             await self.ingest.prewarm(bp)

@@ -31,7 +31,7 @@ def make_client(port, ingest):
 
 async def test_mesh_ingest_serves_live_fleet():
     mesh = make_mesh(dp=8)
-    ingest = MeshFleetIngest(mesh=mesh, body_mode='host', max_frames=4,
+    ingest = MeshFleetIngest(mesh=mesh, max_frames=4,
                              min_len=1024, warm='block')
     assert ingest.bypass_bytes == 0   # the mesh proxy default
     srv = await ZKServer().start()
@@ -76,38 +76,6 @@ async def test_mesh_ingest_serves_live_fleet():
         await srv.stop()
 
 
-@pytest.mark.timeout(75)
-async def test_mesh_ingest_device_bodies():
-    """Device body mode composes with the mesh sharding: Stat/data and
-    children/ACL list bodies assemble from dp-sharded tensor planes."""
-    mesh = make_mesh(dp=8)
-    ingest = MeshFleetIngest(mesh=mesh, body_mode='device',
-                             max_frames=4, min_len=1024, warm='block',
-                             max_data=64, max_path=64,
-                             max_children=8, max_name=16)
-    srv = await ZKServer().start()
-    await ingest.prewarm(8)
-    clients = [make_client(srv.port, ingest) for _ in range(8)]
-    try:
-        await asyncio.gather(*[c.wait_connected(timeout=10)
-                               for c in clients])
-        for i, c in enumerate(clients):
-            await c.create('/b%d' % i, b'w%d' % i)
-        before = ingest.body_fallbacks
-        data, stat = await clients[2].get('/b2')
-        assert data == b'w2' and stat.version == 0
-        children, stat = await clients[0].list('/')
-        assert sorted(children) == ['b%d' % i for i in range(8)]
-        assert stat.numChildren == 8
-        acl = await clients[1].get_acl('/b1')
-        assert acl[0].id.scheme == 'world'
-        assert ingest.body_fallbacks == before  # all device-served
-        assert ingest.ticks > 0
-    finally:
-        await asyncio.gather(*[c.close() for c in clients])
-        await srv.stop()
-
-
 async def test_mesh_ingest_matches_single_device_ingest():
     """The dp-sharded tick and the single-device tick produce
     identical observable results for the same workload (op outcomes
@@ -135,11 +103,11 @@ async def test_mesh_ingest_matches_single_device_ingest():
             await asyncio.gather(*[c.close() for c in cs])
             await srv.stop()
 
-    single = await run(FleetIngest(body_mode='host', max_frames=4,
+    single = await run(FleetIngest(max_frames=4,
                                    min_len=1024, bypass_bytes=0,
                                    warm='block'))
     mesh = await run(MeshFleetIngest(mesh=make_mesh(dp=8),
-                                     body_mode='host', max_frames=4,
+                                     max_frames=4,
                                      min_len=1024, warm='block'))
     assert mesh == single
 
@@ -155,7 +123,7 @@ async def test_multihost_fleet_ingest_single_process():
     mesh = make_mesh(dp=8)
     proxy = MultihostFleetIngest(mesh=mesh, local_rows=8,
                                  stream_len=2048, tick_interval=0.005,
-                                 body_mode='host', max_frames=4)
+                                 max_frames=4)
     srv = await ZKServer().start()
     proxy.warmup_tick()       # compile the global program up front
     clients = [make_client(srv.port, proxy) for _ in range(8)]
@@ -211,7 +179,7 @@ async def test_multihost_assembly_failure_keeps_launches_aligned():
 
     proxy = MultihostFleetIngest(mesh=make_mesh(dp=8), local_rows=8,
                                  stream_len=2048, tick_interval=0.005,
-                                 body_mode='host', max_frames=4)
+                                 max_frames=4)
     srv = await ZKServer().start()
     proxy.warmup_tick()
     clients = [make_client(srv.port, proxy) for _ in range(4)]
@@ -256,7 +224,7 @@ async def test_multihost_dispatch_failure_detected_loudly():
 
     proxy = MultihostFleetIngest(mesh=make_mesh(dp=8), local_rows=8,
                                  stream_len=2048, tick_interval=0.005,
-                                 body_mode='host', max_frames=4)
+                                 max_frames=4)
     srv = await ZKServer().start()
     proxy.warmup_tick()
     clients = [make_client(srv.port, proxy) for _ in range(2)]
@@ -267,7 +235,7 @@ async def test_multihost_dispatch_failure_detected_loudly():
         await clients[0].create('/df', b'v')
 
         # break exactly one dispatch: the compiled fn raises once
-        real_fn = proxy._fns[False]
+        real_fn = proxy._fn
         fail = {'n': 1}
 
         def bad_fn(*a, **k):
@@ -275,7 +243,7 @@ async def test_multihost_dispatch_failure_detected_loudly():
                 fail['n'] -= 1
                 raise RuntimeError('injected dispatch failure')
             return real_fn(*a, **k)
-        proxy._fns[False] = bad_fn
+        proxy._fn = bad_fn
 
         # traffic forces ticks through the broken dispatch
         data, _ = await clients[1].get('/df')

@@ -58,7 +58,7 @@ async def test_ingest_gauges(server):
     from zkstream_tpu.io.ingest import FleetIngest
 
     col = Collector()
-    ingest = FleetIngest(body_mode='host', max_frames=8,
+    ingest = FleetIngest(max_frames=8,
                          bypass_bytes=0, warm='block')
     ingest.bind_metrics(col)
     assert 'zkstream_ingest_ticks 0' in col.expose()

@@ -315,7 +315,7 @@ async def test_sync_through_batched_ingest(ensemble):
     from zkstream_tpu.io.ingest import FleetIngest
 
     ensemble.set_lag(1, None)
-    ing = FleetIngest(body_mode='host', max_frames=8, bypass_bytes=0,
+    ing = FleetIngest(max_frames=8, bypass_bytes=0,
                       warm='block', min_len=1024)
     await ing.prewarm(2)
     c1 = make_client(ensemble, pin=0, ingest=ing)

@@ -242,53 +242,3 @@ def test_wire_pipeline_step_end_to_end_jit():
     # decoding is deterministic
     out2 = step(buf, lens, max_frames=8)
     assert np.array_equal(np.asarray(out.starts), np.asarray(out2.starts))
-
-
-def test_slice_frame_bodies_matches_scalar():
-    from zkstream_tpu.ops.bodies import slice_frame_bodies
-
-    rng = random.Random(21)
-    B, L, F, MB = 12, 300, 6, 48
-    streams = [_random_stream(rng, rng.randrange(0, 5), 40)[0][:L]
-               for _ in range(B)]
-    buf, lens = _pad_batch(streams, L)
-    starts, sizes, counts, bad, resid = frame_cursor_scan(buf, lens, F)
-    bodies, mask = jax.jit(
-        lambda b, s, z: slice_frame_bodies(b, s, z, max_body=MB))(
-            buf, starts, sizes)
-    nb, ns, nz = (np.asarray(buf), np.asarray(starts),
-                  np.asarray(sizes))
-    for i in range(B):
-        for j in range(F):
-            if ns[i, j] < 0:
-                assert not np.asarray(mask)[i, j].any()
-                assert not np.asarray(bodies)[i, j].any()
-                continue
-            want = nb[i, ns[i, j]:ns[i, j] + nz[i, j]][:MB]
-            got = np.asarray(bodies)[i, j][:len(want)]
-            np.testing.assert_array_equal(got, want)
-            assert np.asarray(mask)[i, j].sum() == len(want)
-            # padding stays zeroed
-            assert not np.asarray(bodies)[i, j][len(want):].any()
-
-
-def test_slice_frame_bodies_skip_header():
-    from zkstream_tpu.ops.bodies import slice_frame_bodies
-
-    rng = random.Random(22)
-    streams = [_random_stream(rng, rng.randrange(1, 6), 30)[0]
-               for _ in range(6)]
-    buf, lens = _pad_batch(streams, 256)
-    starts, sizes, *_ = frame_cursor_scan(buf, lens, 6)
-    bodies, mask = slice_frame_bodies(buf, starts, sizes, max_body=32,
-                                      skip_header=True)
-    nb, ns, nz = (np.asarray(buf), np.asarray(starts),
-                  np.asarray(sizes))
-    for i in range(6):
-        for j in range(6):
-            if ns[i, j] < 0 or nz[i, j] <= 16:
-                continue
-            want = nb[i, ns[i, j] + 16:ns[i, j] + nz[i, j]][:32]
-            np.testing.assert_array_equal(
-                np.asarray(bodies)[i, j][:len(want)], want)
-            assert np.asarray(mask)[i, j].sum() == len(want)

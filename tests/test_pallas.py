@@ -133,7 +133,7 @@ def test_vmem_guard_refuses_what_mosaic_refuses():
     refuses (RESOURCE_EXHAUSTED, scoped vmem) are refused by the guard
     now; the pocket shapes that compile are still admitted.
     (tests/test_compile_v5e.py compiles the boundary for real.)"""
-    from zkstream_tpu.ops.pallas_scan import fits_vmem, fits_vmem_full
+    from zkstream_tpu.ops.pallas_scan import fits_vmem
 
     # multi-block R=128: Lp=7296 needs 16.57 MiB, Lp=8320 18.82
     assert not fits_vmem(8192, 7296 - 20, 64, 64, V5E)
@@ -150,19 +150,13 @@ def test_vmem_guard_refuses_what_mosaic_refuses():
     # rows/program double with block_rows 256: half the length fits
     assert fits_vmem(256, 2048, 48, 128, V5E)
     assert not fits_vmem(512, 5000, 48, 256, V5E)
-    # the fused kernel pays for every unrolled data word
-    assert fits_vmem_full(8192, 2048, 32, 64, 16, V5E)
-    assert fits_vmem_full(1024, 512, 8, 64, 256, V5E)
-    assert not fits_vmem_full(8192, 4096, 8, 64, 256, V5E)
 
 
 def test_pallas_names_never_substitute_jnp(monkeypatch):
     """A function named ``*_pallas`` runs the kernel or raises: a
-    shape past the VMEM ceiling is a ValueError from both entry
-    points, never a quiet jnp result (only ``auto_impl`` may choose
-    jnp, and it says so)."""
+    shape past the VMEM ceiling is a ValueError, never a quiet jnp
+    result (only ``auto_impl`` may choose jnp, and it says so)."""
     from zkstream_tpu.ops import pallas_scan, pipeline
-    from zkstream_tpu.ops.pipeline import wire_full_decode_pallas
 
     monkeypatch.setattr(pallas_scan, 'scoped_vmem_limit',
                         lambda device_kind=None: 16 * 1024 * 1024)
@@ -170,7 +164,6 @@ def test_pallas_names_never_substitute_jnp(monkeypatch):
     def no_jnp(*a, **kw):
         raise AssertionError('jnp pipeline substituted for the kernel')
     monkeypatch.setattr(pipeline, 'wire_pipeline_step', no_jnp)
-    monkeypatch.setattr(pipeline, 'getdata_bodies_jnp', no_jnp)
 
     buf = jnp.zeros((1024, 13440), jnp.uint8)
     lens = jnp.zeros((1024,), jnp.int32)
@@ -180,8 +173,7 @@ def test_pallas_names_never_substitute_jnp(monkeypatch):
     buf = jnp.zeros((8, 200_000), jnp.uint8)
     lens = jnp.zeros((8,), jnp.int32)
     with pytest.raises(ValueError, match='scoped VMEM'):
-        wire_full_decode_pallas(buf, lens, max_frames=6, max_data=16,
-                                block_rows=8)
+        wire_pipeline_step_pallas(buf, lens, max_frames=6, block_rows=8)
 
 
 def test_pallas_on_a_device_without_a_ceiling_raises():
@@ -244,64 +236,3 @@ def test_auto_dispatch_honors_default_device_override():
 
     with jax.default_device(jax.devices('cpu')[0]):
         assert auto_impl(8192, 2048, 64) == 'jnp'
-
-
-def _getdata_fleet(rng, B, L, max_data):
-    """Streams of GET_DATA-layout frames: buffer(data) then Stat, with
-    adversarial shapes mixed in (empty data as len -1, truncated Stat,
-    data overrunning the frame, oversized data, non-body frames)."""
-    buf = np.zeros((B, L), np.uint8)
-    lens = np.zeros((B,), np.int32)
-    for i in range(B):
-        s = b''
-        for _ in range(rng.randrange(0, 5)):
-            kind = rng.random()
-            if kind < 0.5:      # well-formed GET_DATA reply
-                dlen = rng.choice([0, 1, 3, max_data - 1, max_data,
-                                   max_data + 5])
-                data = bytes(rng.randrange(256) for _ in range(dlen))
-                body = struct.pack('>i', dlen) + data + bytes(
-                    rng.randrange(256) for _ in range(68))
-            elif kind < 0.6:    # empty buffer as length -1
-                body = struct.pack('>i', -1) + bytes(
-                    rng.randrange(256) for _ in range(68))
-            elif kind < 0.7:    # Stat truncated
-                body = struct.pack('>i', 2) + b'xy' + b'\x01' * 30
-            elif kind < 0.75:   # buffer length overruns the frame
-                body = struct.pack('>i', 4096) + b'zz'
-            elif kind < 0.85:   # wire length near INT32_MAX: the
-                # extent check must clamp, not wrap to "valid"
-                body = struct.pack('>i', 0x7FFFFFF4) + b'zz' + b'\x00' * 70
-            else:               # header-only (PING-like)
-                body = b''
-            s += _reply_frame(rng.randrange(1, 1000),
-                              rng.randrange(1 << 40), 0, body)
-        s = s[:L]
-        buf[i, :len(s)] = np.frombuffer(s, np.uint8)
-        lens[i] = len(s)
-    return jnp.asarray(buf), jnp.asarray(lens)
-
-
-@pytest.mark.parametrize('seed', [0, 7])
-def test_pallas_full_decode_matches_jnp(seed):
-    """The fused full-decode kernel's GET_DATA planes equal
-    parse_reply_bodies' field-for-field, including the adversarial
-    shapes (truncated Stat, overrunning buffer, -1 empty)."""
-    from zkstream_tpu.ops.pipeline import wire_full_decode_pallas
-    from zkstream_tpu.ops.replies import parse_reply_bodies
-
-    rng = random.Random(seed)
-    MD = 16
-    buf, lens = _getdata_fleet(rng, 13, 512, MD)
-    st_p, bd_p = wire_full_decode_pallas(
-        buf, lens, max_frames=6, max_data=MD, block_rows=8,
-        interpret=True)
-    st_j = wire_pipeline_step(buf, lens, max_frames=6)
-    _assert_same(st_p, st_j)
-    bd_j = parse_reply_bodies(buf, st_j.starts, st_j.sizes,
-                              max_data=MD, max_path=8)
-    for f in ('data_len', 'data', 'data_mask', 'data_ok'):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(bd_p, f)), np.asarray(getattr(bd_j, f)),
-            err_msg=f'field {f}')
-    _assert_same(bd_p.stat_after_data, bd_j.stat_after_data)

@@ -44,24 +44,16 @@ EXPECTED = (ZKError, ZKNotConnectedError, ZKProtocolError,
 #: Ingest configurations the soaks run under (VERDICT r2 item 6): the
 #: batched drain has the most novel failure surface (mid-tick
 #: teardown, take/restore_pending hand-off, bad-frame fallback,
-#: background-warm scalar deferral), so it soaks in both body modes
-#: with the bypass both disabled and at its production default.
+#: background-warm scalar deferral), so it soaks with the bypass both
+#: disabled and at its production default.
 def _ingest_variants():
     return {
         'scalar': lambda: None,
         'ingest-host': lambda: FleetIngest(
-            body_mode='host', max_frames=8, bypass_bytes=0,
+            max_frames=8, bypass_bytes=0,
             min_len=1024),
-        # narrow device planes: the soak exercises lifecycle, not
-        # decode width, and the smaller program compiles ~3x faster
-        # (its background compiles would otherwise bleed core time
-        # into the following tests on this single-core host)
-        'ingest-device': lambda: FleetIngest(
-            body_mode='device', max_frames=8, bypass_bytes=0,
-            min_len=1024, max_data=64, max_path=32, max_children=4,
-            max_name=16, max_acls=2, max_scheme=8, max_id=16),
         'ingest-bypass': lambda: FleetIngest(
-            body_mode='host', max_frames=8),  # default bypass
+            max_frames=8),  # default bypass
         'ingest-mesh': _mesh_variant,  # dp-sharded tick under fire
     }
 
@@ -69,7 +61,7 @@ def _ingest_variants():
 def _mesh_variant():
     from zkstream_tpu.parallel import MeshFleetIngest, make_mesh
 
-    return MeshFleetIngest(mesh=make_mesh(dp=8), body_mode='host',
+    return MeshFleetIngest(mesh=make_mesh(dp=8),
                            max_frames=8, min_len=1024)
 
 

@@ -126,7 +126,7 @@ async def test_spare_promotion_with_ingest(ensemble):
     through the batched path (or its scalar bypass) after failover."""
     from zkstream_tpu.io.ingest import FleetIngest
 
-    ingest = FleetIngest(body_mode='host', max_frames=8)
+    ingest = FleetIngest(max_frames=8)
     c = make_client(ensemble, ingest=ingest)
     try:
         await c.wait_connected(timeout=5)

@@ -42,7 +42,7 @@ async def herd(through_ingest: bool, seed: int):
     ports = [s.port for s in ens.servers]
     ingest = None
     if through_ingest:
-        ingest = FleetIngest(body_mode='host', placement='host',
+        ingest = FleetIngest(placement='host',
                              max_frames=4, min_len=1024, max_data=256,
                              bypass_bytes=0, warm='block')
         # three 128 KiB rows a tick; a dispatch holds two

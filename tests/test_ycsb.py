@@ -79,7 +79,7 @@ class Cell:
         gc.collect()    # the cell before this one is not this one's pause
         self.ens = await ZKEnsemble(3).start()
         self.ingest = FleetIngest(
-            body_mode='host', placement='host', max_frames=8,
+            placement='host', max_frames=8,
             min_len=2048, max_data=2048, bypass_bytes=0, warm='block')
         for bp in (8, 16, 32):
             await self.ingest.prewarm(bp)

@@ -90,7 +90,7 @@ async def _run(args) -> int:
         # the batched device plane with its shipped defaults
         # (byte-threshold bypass, background warm)
         from .io.ingest import FleetIngest
-        ingest = FleetIngest(body_mode='host')
+        ingest = FleetIngest()
     client = Client(servers=args.server,
                     session_timeout=args.session_timeout,
                     use_native_codec=use_native, ingest=ingest)

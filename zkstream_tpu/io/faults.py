@@ -1671,7 +1671,7 @@ async def run_ensemble_schedule(seed: int, ops: int = 12,
     if plan.ingest_mode != 'none':
         from .ingest import FleetIngest
         ingest = FleetIngest(
-            body_mode='host', max_frames=8,
+            max_frames=8,
             bypass_bytes=0 if plan.ingest_mode == 'batch' else 16384)
         ingest.faults = inj
 
@@ -2337,7 +2337,7 @@ async def run_concurrent_schedule(seed: int, ops: int = 12,
         # ONE shared ingest across all N clients — shared batched
         # drains are the plane's deployment shape
         ingest = FleetIngest(
-            body_mode='host', max_frames=8,
+            max_frames=8,
             bypass_bytes=0 if plan.ingest_mode == 'batch' else 16384)
         ingest.faults = inj
 

@@ -7,8 +7,7 @@ replaces that per-socket drain at fleet scale: N live connections
 append their received bytes to per-connection accumulators, and a
 per-event-loop-tick batcher pads those whose first frame is whole into
 [B, L] tensors — one a **size class** present (below) — runs
-:func:`zkstream_tpu.ops.pipeline.wire_pipeline_step` (plus, in
-``body_mode='device'``, :func:`~zkstream_tpu.ops.replies.parse_reply_bodies`)
+:func:`zkstream_tpu.ops.pipeline.wire_pipeline_step`
 in one device dispatch each, and routes the results back on host —
 reply packets to each connection's pending-request futures via the
 normal ``packet``/``process_reply`` path, notifications to the session
@@ -22,17 +21,12 @@ Division of labor per tick:
   per-stream routing counts, bad-frame flags — the O(bytes) work;
 - **host**: per-frame packet-dict assembly, and the route: a tick's
   streams are delivered as one batch (:meth:`FleetIngest._route_batch`),
-  each to its connection's direct settle lane.  In ``body_mode='host'``
-  the packets come from the C-extension decoder when it is loaded (ONE
-  call a tick over every stream's device-delimited complete-frame
-  slice — byte-identical to the scalar drain because it *is* the
-  scalar decoder), else from the scalar readers positioned at the
-  device-located body offsets.  In ``body_mode='device'`` fixed-layout
-  bodies (Stat / data / create-path / notification) come from the
-  tensor planes, with the scalar readers as fallback for list-shaped
-  bodies (children / ACL), oversized variable fields, and malformed
-  frames — so a protocol violation raises byte-for-byte the same error
-  the scalar codec would.
+  each to its connection's direct settle lane.  A reply's BODY is
+  parsed here and nowhere else: the packets come from the C-extension
+  decoder when it is loaded (ONE call a tick over every stream's
+  device-delimited complete-frame slice — byte-identical to the scalar
+  drain because it *is* the scalar decoder), else from the scalar
+  readers positioned at the device-located body offsets.
 
 Streams flagged ``bad`` by the device scan re-run through the
 connection's own ``PacketCodec`` so the error surfaced (BAD_LENGTH /
@@ -236,12 +230,9 @@ class FleetIngest:
     Args:
       max_frames: static per-stream frame bound per tick; streams with
         more complete frames buffered are finished on follow-up ticks.
-      body_mode: ``'host'`` (device framing/headers, C/scalar body
-        readers) or ``'device'`` (tensor body parse with scalar
-        fallback).
-      max_data / max_path: static widths for the device body planes
-        (``body_mode='device'`` only); larger fields fall back to the
-        scalar reader.
+      body_mode / max_data: inert; the ``ingest`` blocks of
+        ``benchmark/configs/*.json`` pass them (ROADMAP D19).  Any
+        ``body_mode`` but ``'host'`` raises ``ValueError``.
       min_len: smallest padded stream length, to bound jit cache churn.
       warm: ``'background'`` (default) — a tick whose shape bucket is
         not compiled yet delivers through the scalar codec while the
@@ -283,32 +274,26 @@ class FleetIngest:
     #: and what does not fit waits in its slots for the follow-up tick.
     TICK_BYTES = 4 * DISPATCH_BYTES
 
+    #: always 0: ``benchmark/harness.py`` reads it (``INGEST_COUNTERS``)
+    body_fallbacks = 0
+
     def __init__(self, max_frames: int = 32, body_mode: str = 'host',
-                 max_data: int = 256, max_path: int = 256,
-                 max_children: int = 16, max_name: int = 64,
-                 max_acls: int = 4, max_scheme: int = 16,
-                 max_id: int = 64,
+                 max_data: int = 256,
                  min_len: int = 256, placement: str = 'auto',
                  latency_budget_ms: float = 5.0,
                  bypass_bytes: int = 16384,
                  warm: str = 'background',
                  frag_guard: bool | None = None,
                  log: Logger | None = None):
-        assert body_mode in ('host', 'device'), body_mode
+        # ``body_mode`` and ``max_data``: said by the ``ingest`` blocks of
+        # benchmark/configs/*.json; nothing reads them
+        if body_mode != 'host':
+            raise ValueError(
+                'FleetIngest(body_mode=%r): reply bodies are parsed on '
+                "the host; 'host' is the only mode" % (body_mode,))
         assert placement in ('auto', 'accelerator', 'host'), placement
         assert warm in ('background', 'block'), warm
         self.max_frames = max_frames
-        self.body_mode = body_mode
-        self.max_data = max_data
-        self.max_path = max_path
-        #: bounds for the device list parse (children / ACL replies,
-        #: ops/replies.parse_list_bodies); longer lists fall back to
-        #: the scalar reader per frame
-        self.max_children = max_children
-        self.max_name = max_name
-        self.max_acls = max_acls
-        self.max_scheme = max_scheme
-        self.max_id = max_id
         self.min_len = min_len
         self.warm = warm
         #: Small-tick crossover: while the fleet's bytes-per-tick EMA
@@ -428,8 +413,7 @@ class FleetIngest:
         #: did not parse again: a herd's re-lists of ONE path in ONE
         #: state are byte-equal, and ``decode_streams`` hands every
         #: asker after the first its own list of the SAME ``str``
-        #: objects (0 without the extension, or in ``body_mode=
-        #: 'device'``)
+        #: objects (0 without the extension)
         self.lists_routed = 0
         self.lists_shared = 0
         #: Upper dispatch guard: when a large fleet's connections
@@ -462,15 +446,12 @@ class FleetIngest:
         self._window_bytes = 0
         self._ema_bytes: float | None = None
         self._frames_mark = 0
-        #: device-body mode: frames whose body needed the scalar
-        #: reader (oversized/list-overflow/malformed)
-        self.body_fallbacks = 0
-        self._fns: dict = {}
-        #: (device_bodies, Bp, L) -> AOT executable (None = compile
+        self._fn = None
+        #: (False, Bp, L) -> AOT executable (None = compile
         #: failed; that bucket stays on the scalar drain, or raises in
         #: force-device mode)
         self._exec: dict = {}
-        #: (device_bodies, Bp, L) -> what that bucket compiled to:
+        #: (False, Bp, L) -> what that bucket compiled to:
         #: ``{'impl', 'platform', 'compile_s', 'error'}`` — ``impl`` is
         #: the header-scan implementation the trace chose ('pallas' |
         #: 'jnp'), ``platform`` where the executable lives as the
@@ -634,58 +615,15 @@ class FleetIngest:
     _HDR_PLANES = ('starts', 'sizes', 'xids', 'errs',
                    'zxid_hi', 'zxid_lo')
 
-    def _body_schema(self):
-        """Declarative layout of the device-body planes inside the
-        packed tick output — one source of truth for the device-side
-        pack and the host-side unpack.  Entry kinds:
-
-        - ``('plane', name)``: one int32 [B, F] plane;
-        - ``('multi', name, K)``: an int32 [B, F, K] tensor as K planes;
-        - ``('stat', name)``: a StatPlanes (one plane per field).
-        """
-        K, A = self.max_children, self.max_acls
-        return (
-            ('stat', 'stat0'), ('stat', 'stat_after_data'),
-            ('plane', 'data_len'), ('plane', 'str0_len'),
-            ('plane', 'ntype'), ('plane', 'nstate'),
-            ('plane', 'npath_len'), ('plane', 'data_ok'),
-            ('plane', 'str0_ok'), ('plane', 'npath_ok'),
-            ('plane', 'ch_count'), ('plane', 'ch_ok'),
-            ('multi', 'ch_len', K),
-            ('stat', 'stat_after_children'),
-            ('plane', 'acl_count'), ('plane', 'acl_ok'),
-            ('multi', 'acl_perms', A),
-            ('multi', 'acl_scheme_len', A),
-            ('multi', 'acl_id_len', A),
-            ('stat', 'stat_after_acl'),
-        )
-
-    def _bytes_schema(self):
-        """Widths of the uint8 [B, F, w] segments concatenated into the
-        packed byte plane (4-d sources flatten their trailing axes)."""
-        return (
-            ('data', self.max_data),
-            ('str0', self.max_path),
-            ('npath', self.max_path),
-            ('ch_bytes', self.max_children * self.max_name),
-            ('acl_scheme', self.max_acls * self.max_scheme),
-            ('acl_id', self.max_acls * self.max_id),
-        )
-
-    def _trace_step(self, buf, lens, device_bodies: bool):
+    def _trace_step(self, buf, lens):
         """The traced tick computation: decode ``buf``/``lens`` and
-        pack the results into (ints, byts-or-None).  Pure array code —
+        pack the results into one int32 array.  Pure array code —
         jitted directly here, re-wrapped in ``shard_map`` by the
         mesh-aware subclass (parallel/fleet.py)."""
         import jax
         import jax.numpy as jnp
 
         from ..ops.pipeline import WIRE_STEP_IMPLS, auto_impl
-        from ..ops.replies import (
-            StatPlanes,
-            parse_list_bodies,
-            parse_reply_bodies,
-        )
 
         # auto-dispatch picks the measured winner for this shape and
         # target platform (jnp on the host CPU backend; the Pallas
@@ -698,78 +636,34 @@ class FleetIngest:
                                    max_frames=self.max_frames)
 
         # the stages carry named scopes (frame_scan / header_gather
-        # inside the step, body_parse and pack here): metadata only,
-        # so a kept profiler trace names the program's ops by stage
-        @jax.named_scope('pack')
-        def pack_ints(extra=()):
+        # inside the step, pack here): metadata only, so a kept
+        # profiler trace names the program's ops by stage
+        with jax.named_scope('pack'):
             head = jnp.stack(
                 [st.n_frames, st.resid,
                  st.bad.astype(jnp.int32)], axis=1)     # [B, 3]
-            planes = [getattr(st, f) for f in self._HDR_PLANES]
-            planes += list(extra)
-            flat = jnp.stack(planes, axis=1)            # [B, K, F]
+            flat = jnp.stack([getattr(st, f) for f in self._HDR_PLANES],
+                             axis=1)                    # [B, K, F]
             B = flat.shape[0]
-            return jnp.concatenate([head, flat.reshape(B, -1)], axis=1)
+            return st, jnp.concatenate([head, flat.reshape(B, -1)],
+                                       axis=1)
 
-        if not device_bodies:
-            return st, pack_ints(), None
-        with jax.named_scope('body_parse'):
-            bd = parse_reply_bodies(
-                buf, st.starts, st.sizes,
-                max_data=self.max_data, max_path=self.max_path)
-            lb = parse_list_bodies(
-                buf, st.starts, st.sizes,
-                max_children=self.max_children, max_name=self.max_name,
-                max_acls=self.max_acls, max_scheme=self.max_scheme,
-                max_id=self.max_id)
-
-        def src(name):
-            v = getattr(bd, name, None)
-            return v if v is not None else getattr(lb, name)
-
-        with jax.named_scope('pack'):
-            extra = []
-            for ent in self._body_schema():
-                if ent[0] == 'plane':
-                    extra.append(src(ent[1]).astype(jnp.int32))
-                elif ent[0] == 'multi':
-                    t = src(ent[1]).astype(jnp.int32)
-                    extra += [t[:, :, k] for k in range(ent[2])]
-                else:
-                    sp = src(ent[1])
-                    extra += [getattr(sp, f).astype(jnp.int32)
-                              for f in StatPlanes._fields]
-            B = buf.shape[0]
-            byts = jnp.concatenate(
-                [src(name).reshape(B, self.max_frames, -1)
-                 for name, _w in self._bytes_schema()], axis=2)
-            return st, pack_ints(extra), byts
-
-    def _step_fn(self, device_bodies: bool):
+    def _step_fn(self, _bodies=False):
         """Build (and cache) the jittable one-dispatch decode for this
         configuration — the lowering source for the per-shape AOT
         executables (:meth:`_compile`).
 
-        Everything the host needs comes back as ONE packed int32 array
-        (plus one uint8 array in device-body mode): every readback is
-        a host<->device round trip inside the event loop, so the
-        per-tick readback count is held at one (two with device
-        bodies)."""
-        key = device_bodies
-        fn = self._fns.get(key)
+        Everything the host needs comes back as ONE packed int32
+        array: every readback is a host<->device round trip inside the
+        event loop, so the per-tick readback count is held at one."""
+        # ``_bodies`` is ignored: benchmark/rehearse.py passes False
+        fn = self._fn
         if fn is None:
             import jax
 
-            if device_bodies:
-                def step(buf, lens):
-                    _st, ints, byts = self._trace_step(buf, lens, True)
-                    return ints, byts
-            else:
-                def step(buf, lens):
-                    _st, ints, _n = self._trace_step(buf, lens, False)
-                    return ints
-            fn = jax.jit(step)
-            self._fns[key] = fn
+            def step(buf, lens):
+                return self._trace_step(buf, lens)[1]
+            fn = self._fn = jax.jit(step)
         return fn
 
     # -- shape-bucket warm-up (AOT compile off the event loop) --
@@ -782,7 +676,8 @@ class FleetIngest:
         class, 1 in the classes eight times as wide and wider)."""
         L = self._width(nbytes)
         Bp = _next_pow2(max(n_streams, 8 * self.min_len // L, 1))
-        return (self.body_mode == 'device', Bp, L)
+        # the leading False: benchmark/reduce_trace.py unpacks 3-tuples
+        return (False, Bp, L)
 
     def _width(self, nbytes: int) -> int:
         """The size class of a row of ``nbytes``: its width."""
@@ -802,9 +697,9 @@ class FleetIngest:
 
         from ..utils.platform import enable_compile_cache
 
-        device_bodies, Bp, L = key
+        _bodies, Bp, L = key
         enable_compile_cache()
-        fn = self._step_fn(device_bodies)
+        fn = self._step_fn()
         batch = np.zeros((Bp, L), np.uint8)
         lens = np.zeros((Bp,), np.int32)
         ctx = (jax.default_device(self._device) if self._device is not
@@ -954,8 +849,6 @@ class FleetIngest:
                  'fragmentation guard (fleet large, ticks sparse)'),
                 ('zkstream_ingest_frames_routed', 'frames_routed',
                  'frames delivered through the ingest'),
-                ('zkstream_ingest_body_fallbacks', 'body_fallbacks',
-                 'device-body frames that needed the scalar reader'),
                 ('zkstream_ingest_dispatches', 'dispatches',
                  'device dispatches made (one a size class present a '
                  'tick)'),
@@ -1101,49 +994,19 @@ class FleetIngest:
                            'device_kind': dev.device_kind,
                            'rtt_ms': rtt_ms}
 
-    def _unpack(self, ints, byts):
-        """Rebuild the host-side stat/body views from the packed
-        arrays (numpy views, no copies), walking the same schema the
-        device-side pack wrote."""
+    def _unpack(self, ints):
+        """The host-side views of one dispatch's packed array (numpy
+        views, no copies): the head columns and the ``_HDR_PLANES``."""
         import types
 
-        from ..ops.replies import StatPlanes
-
         B = ints.shape[0]
-        F = self.max_frames
-        head, flat = ints[:, :3], ints[:, 3:].reshape(B, -1, F)
+        head = ints[:, :3]
+        flat = ints[:, 3:].reshape(B, -1, self.max_frames)
         st = types.SimpleNamespace(n_frames=head[:, 0],
                                    resid=head[:, 1], bad=head[:, 2])
-        k = 0
-        for name in self._HDR_PLANES:
+        for k, name in enumerate(self._HDR_PLANES):
             setattr(st, name, flat[:, k])
-            k += 1
-        if byts is None:
-            return st, None
-
-        bd = types.SimpleNamespace()
-        for ent in self._body_schema():
-            if ent[0] == 'plane':
-                setattr(bd, ent[1], flat[:, k])
-                k += 1
-            elif ent[0] == 'multi':
-                K = ent[2]
-                # K consecutive planes -> a [B, F, K] view
-                setattr(bd, ent[1],
-                        np.moveaxis(flat[:, k:k + K], 1, 2))
-                k += K
-            else:
-                vals = {}
-                for f in StatPlanes._fields:
-                    vals[f] = flat[:, k]
-                    k += 1
-                vals['valid'] = vals['valid'].astype(bool)
-                setattr(bd, ent[1], StatPlanes(**vals))
-        off = 0
-        for name, w in self._bytes_schema():
-            setattr(bd, name, byts[:, :, off:off + w])
-            off += w
-        return st, bd
+        return st
 
     def _note_frames(self, n: int) -> None:
         """Feed the fragmentation EMA with one tick's routed frames
@@ -1485,7 +1348,7 @@ class FleetIngest:
                 self._require_compiled(key)
                 scalar.append((g_streams, 'scalar'))
                 continue
-            _device, Bp, L = key
+            _bodies, Bp, L = key
             if used + Bp * L <= len(arena):
                 batch = arena[used:used + Bp * L].reshape(Bp, L)
                 used += Bp * L
@@ -1521,7 +1384,7 @@ class FleetIngest:
             return None
         self.ticks += 1
         if sp is not NO_SPAN:
-            _device, Bp, L = plans[0][1]
+            _bodies, Bp, L = plans[0][1]
             rows = sum(len(p[2]) for p in plans)
             sp.set(detail=('device %dx%d streams=%d' % (Bp, L, rows)
                            if len(plans) == 1 else
@@ -1540,7 +1403,6 @@ class FleetIngest:
         work meanwhile.  Host span ``ingest.dispatch`` once a
         dispatch, with the tick's number (profiler sessions only)."""
         n = self.ticks
-        device = self.body_mode == 'device'
         t1 = time.perf_counter()
         outs = []
         for ex, key, streams, batch, lens, nbytes in plans:
@@ -1550,8 +1412,7 @@ class FleetIngest:
                 # the results come to the host as soon as they stand,
                 # not when the readback asks (a D2H round trip is
                 # ~0.6 ms of the loop on the chip, PERF.md, PR 43)
-                for arr in (out if device else (out,)):
-                    arr.copy_to_host_async()
+                out.copy_to_host_async()
                 outs.append(out)
             self.dispatches += 1
             self.bytes_batched += nbytes
@@ -1568,17 +1429,12 @@ class FleetIngest:
         the first half's two, one observation of
         ``zkstream_ingest_phase_ms{phase=}`` a tick (always)."""
         n, plans = flight.tick, flight.plans
-        device = self.body_mode == 'device'
         results = []
         try:
             t2 = time.perf_counter()
             for out in flight.outs:
                 with host_span('ingest.readback', tick=n):
-                    if device:      # the only 2 readbacks per dispatch
-                        results.append((np.asarray(out[0]),
-                                        np.asarray(out[1])))
-                    else:
-                        results.append((np.asarray(out), None))
+                    results.append(np.asarray(out))
             t3 = time.perf_counter()
             with host_span('ingest.route', tick=n) as rsp:
                 laned = emitted = 0
@@ -1586,13 +1442,13 @@ class FleetIngest:
                 lists, shared = self.lists_routed, self.lists_shared
                 self.routing = n
                 try:
-                    for plan, (ints, byts) in zip(plans, results):
+                    for plan, ints in zip(plans, results):
                         streams, lens = plan[2], plan[4]
-                        st, bd = self._unpack(ints, byts)
+                        st = self._unpack(ints)
                         B = len(streams)
                         self.bytes_recopied += int(np.where(
                             st.bad[:B], 0, lens[:B] - st.resid[:B]).sum())
-                        a, b = self._route_batch(streams, None, st, bd)
+                        a, b = self._route_batch(streams, None, st)
                         laned += a
                         emitted += b
                 finally:
@@ -1610,16 +1466,16 @@ class FleetIngest:
                                 (t1, t_sent, t3, t4)):
             observe((b - a) * 1000.0, labels)
 
-    def _route_batch(self, streams, rows, st, bd) -> tuple[int, int]:
+    def _route_batch(self, streams, rows, st) -> tuple[int, int]:
         """Deliver a tick's decoded results as one batch (shared by
         the event-driven tick and the multihost cadence tick):
         ``streams`` are the slots that were in it, ``rows`` their rows
         in the planes (None: the first ``len(streams)``).  One pass
-        over the head planes as Python lists, one clock read, and in
-        ``body_mode='host'`` with the extension loaded ONE C call that
-        decodes every stream's complete-frame slice
-        (:meth:`_decode_batch`); then each stream in turn goes to its
-        connection — through its direct lane when it brought one.
+        over the head planes as Python lists, one clock read, and with
+        the extension loaded ONE C call that decodes every stream's
+        complete-frame slice (:meth:`_decode_batch`); then each stream
+        in turn goes to its connection — through its direct lane when
+        it brought one.
         Schedules the follow-up tick when a stream hit the per-stream
         frame bound with more buffered.  Returns the frames the lanes
         settled and the frames that went the emitter path."""
@@ -1635,10 +1491,9 @@ class FleetIngest:
             bads = st.bad[rows].tolist()
         now = time.monotonic()
         lens = None      # no stream was decoded in a batch (yet)
-        if bd is None:
-            decoded = self._decode_batch(streams, n_frames, resids, bads)
-            if decoded is not None:
-                lens, maps, flat, counts, errors = decoded
+        decoded = self._decode_batch(streams, n_frames, resids, bads)
+        if decoded is not None:
+            lens, maps, flat, counts, errors = decoded
         slots = self._slots
         max_frames = self.max_frames
         laned = routed = pos = 0
@@ -1671,7 +1526,7 @@ class FleetIngest:
                 continue
             n = n_frames[i]
             if pkts is None:
-                pkts, err = self._assemble_stream(conn, buf, st, bd,
+                pkts, err = self._assemble_stream(conn, buf, st,
                                                   rows[i], n)
             else:
                 err = self._decode_error(errors[i]) if i in errors \
@@ -1697,8 +1552,8 @@ class FleetIngest:
         return laned, routed - laned
 
     def _decode_batch(self, streams, n_frames, resids, bads):
-        """C fast path for ``body_mode='host'``: every stream's
-        device-delimited complete-frame slice decoded in ONE call of
+        """The C fast path: every stream's device-delimited
+        complete-frame slice decoded in ONE call of
         the C-extension decoder — the same code the scalar drain runs,
         so parity is by construction, at C speed.  The device scan
         already proved each slice frame-complete and length-valid
@@ -1781,7 +1636,7 @@ class FleetIngest:
 
     # -- host packet assembly --
 
-    def _assemble_stream(self, conn, buf, st, bd, i: int, n: int):
+    def _assemble_stream(self, conn, buf, st, i: int, n: int):
         """Build the packet dicts for stream ``i``'s ``n`` frames from
         the tick's planes (the streams :meth:`_decode_batch` took never
         come here).  Returns (packets, err); a decode failure
@@ -1818,7 +1673,7 @@ class FleetIngest:
             }
             if pkt['err'] == 'OK' and opcode not in _EMPTY_RESPONSES:
                 try:
-                    self._read_body(pkt, buf, st, bd, i, f)
+                    self._read_body(pkt, buf, st, i, f)
                 except ZKProtocolError as e:
                     return pkts, e
                 except Exception as e:
@@ -1830,14 +1685,10 @@ class FleetIngest:
             pkts.append(pkt)
         return pkts, None
 
-    def _read_body(self, pkt, buf, st, bd, i: int, f: int) -> None:
-        """Fill ``pkt`` with its opcode-specific body."""
+    def _read_body(self, pkt, buf, st, i: int, f: int) -> None:
+        """Fill ``pkt`` with its opcode-specific body: the scalar
+        reader positioned at the device-located body offset."""
         opcode = pkt['opcode']
-        if bd is not None:
-            if self._read_body_device(pkt, bd, i, f):
-                return
-            self.body_fallbacks += 1
-        # Scalar reader positioned at the device-located body offset.
         start = int(st.starts[i, f])
         size = int(st.sizes[i, f])
         r = JuteReader(bytes(buf[start + REPLY_HDR:start + size]))
@@ -1845,78 +1696,3 @@ class FleetIngest:
         if reader is None:
             raise ValueError('unsupported reply opcode %r' % (opcode,))
         reader(r, pkt)
-
-    def _read_body_device(self, pkt, bd, i: int, f: int) -> bool:
-        """Assemble the body from the tensor planes; False = this frame
-        needs the scalar fallback (list-shaped, oversized, malformed)."""
-        from ..ops.replies import stat_from_planes
-        from ..protocol.consts import KeeperState, NotificationType
-
-        opcode = pkt['opcode']
-        if opcode in ('EXISTS', 'SET_DATA'):
-            if not bool(bd.stat0.valid[i, f]):
-                return False  # truncated: scalar reader raises exactly
-            pkt['stat'] = stat_from_planes(bd.stat0, i, f)
-            return True
-        if opcode == 'GET_DATA':
-            dlen = int(bd.data_len[i, f])
-            if dlen > self.max_data or not bool(bd.data_ok[i, f]) or \
-                    not bool(bd.stat_after_data.valid[i, f]):
-                return False
-            pkt['data'] = bytes(bd.data[i, f, :max(dlen, 0)])
-            pkt['stat'] = stat_from_planes(bd.stat_after_data, i, f)
-            return True
-        if opcode == 'CREATE':
-            slen = int(bd.str0_len[i, f])
-            # not-ok = the length field points past the frame: fall
-            # back so the scalar reader raises BAD_DECODE, exactly as
-            # the scalar drain would
-            if slen > self.max_path or not bool(bd.str0_ok[i, f]):
-                return False
-            pkt['path'] = bytes(bd.str0[i, f, :max(slen, 0)]).decode()
-            return True
-        if opcode == 'NOTIFICATION':
-            plen = int(bd.npath_len[i, f])
-            if plen > self.max_path or not bool(bd.npath_ok[i, f]):
-                return False
-            pkt['type'] = NotificationType(int(bd.ntype[i, f])).name
-            pkt['state'] = KeeperState(int(bd.nstate[i, f])).name
-            pkt['path'] = bytes(bd.npath[i, f, :max(plen, 0)]).decode()
-            return True
-        if opcode in ('GET_CHILDREN', 'GET_CHILDREN2'):
-            if not bool(bd.ch_ok[i, f]):
-                return False  # oversized/malformed list: scalar reader
-            if opcode == 'GET_CHILDREN2':
-                if not bool(bd.stat_after_children.valid[i, f]):
-                    return False  # truncated Stat: scalar raises
-                pkt['stat'] = stat_from_planes(
-                    bd.stat_after_children, i, f)
-            cnt = int(bd.ch_count[i, f])
-            # plane contract: ch_ok => lens already clamped to [0, S]
-            lens = bd.ch_len[i, f, :cnt].tolist()
-            row, S = bd.ch_bytes[i, f], self.max_name
-            pkt['children'] = [
-                bytes(row[k * S:k * S + lens[k]]).decode()
-                for k in range(cnt)]
-            return True
-        if opcode == 'GET_ACL':
-            if not bool(bd.acl_ok[i, f]) or \
-                    not bool(bd.stat_after_acl.valid[i, f]):
-                return False
-            from ..protocol.consts import Perm
-            from ..protocol.records import ACL, Id
-
-            cnt = int(bd.acl_count[i, f])
-            perms = bd.acl_perms[i, f, :cnt].tolist()
-            slens = bd.acl_scheme_len[i, f, :cnt].tolist()
-            ilens = bd.acl_id_len[i, f, :cnt].tolist()
-            srow, SS = bd.acl_scheme[i, f], self.max_scheme
-            irow, SI = bd.acl_id[i, f], self.max_id
-            pkt['acl'] = [
-                ACL(Perm(perms[k]), Id(
-                    bytes(srow[k * SS:k * SS + slens[k]]).decode(),
-                    bytes(irow[k * SI:k * SI + ilens[k]]).decode()))
-                for k in range(cnt)]
-            pkt['stat'] = stat_from_planes(bd.stat_after_acl, i, f)
-            return True
-        return False

@@ -25,7 +25,6 @@ connection streams can be decoded in one fused XLA computation:
   codec's isServer encode mode, lib/zk-streams.js:121-148).
 """
 
-from .bodies import slice_frame_bodies
 from .encode import build_reply_streams
 from .bytesops import (
     be_i32_at,
@@ -45,19 +44,10 @@ from .pipeline import (
     wire_pipeline_step,
     wire_pipeline_step_auto,
 )
-from .replies import (
-    ReplyBodies,
-    StatPlanes,
-    parse_reply_bodies,
-    parse_stats,
-    slice_var_bytes,
-    stat_from_planes,
-)
 
 __all__ = [
     'MAX_PACKET',
     'build_reply_streams',
-    'slice_frame_bodies',
     'be_i32_at',
     'be_i64pair_at',
     'u64pair_max',
@@ -70,10 +60,4 @@ __all__ = [
     'WireStats',
     'wire_pipeline_step',
     'wire_pipeline_step_auto',
-    'ReplyBodies',
-    'StatPlanes',
-    'parse_reply_bodies',
-    'parse_stats',
-    'slice_var_bytes',
-    'stat_from_planes',
 ]

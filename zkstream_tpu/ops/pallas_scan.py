@@ -63,15 +63,12 @@ def _word_plane(buf_ref):
 
 
 def _scan_frame(lane, w32, n, cur, bad):
-    """One frame step of the cursor scan, shared by the tick kernel
-    and the fused full-decode kernel so the frame state machine cannot
-    diverge between them.  One subtract per step; each field read is a
-    single-lane equality select + row-sum over the precomputed words —
-    no per-field variable shifts or int multiplies in the loop.
+    """One frame step of the cursor scan.  One subtract per step; each
+    field read is a single-lane equality select + row-sum over the
+    precomputed words — no per-field variable shifts or int multiplies
+    in the loop.
 
-    Returns (start, size, ln, hdr_ok, (xid, zhi, zlo, err), new_cur,
-    new_bad, gather) — ``gather`` reads more 4-byte words at offsets
-    relative to the frame's length prefix."""
+    Returns (start, size, (xid, zhi, zlo, err), new_cur, new_bad)."""
     d = lane - cur
 
     def gather(off):
@@ -91,9 +88,9 @@ def _scan_frame(lane, w32, n, cur, bad):
     hdr_ok = complete & (ln >= 16)
     fields = tuple(jnp.where(hdr_ok, gather(off), 0)
                    for off in (_XID_OFF, _ZHI_OFF, _ZLO_OFF, _ERR_OFF))
-    return (start, size, ln, hdr_ok, fields,
+    return (start, size, fields,
             jnp.where(complete, cur + 4 + ln, cur),
-            bad | is_bad.astype(jnp.int32), gather)
+            bad | is_bad.astype(jnp.int32))
 
 
 def _kernel(buf_ref, len_ref, starts_ref, sizes_ref, xid_ref,
@@ -107,8 +104,8 @@ def _kernel(buf_ref, len_ref, starts_ref, sizes_ref, xid_ref,
 
     def step(j, carry):
         cur, bad = carry  # bad is int32 0/1 (Mosaic-friendly carry)
-        (start, size, _ln, _hdr_ok, (xid, zhi, zlo, err),
-         cur, bad, _gather) = _scan_frame(lane, w32, n, cur, bad)
+        start, size, (xid, zhi, zlo, err), cur, bad = _scan_frame(
+            lane, w32, n, cur, bad)
         row = pl.ds(j, 1)
         starts_ref[row, :] = start.reshape(1, R)
         sizes_ref[row, :] = size.reshape(1, R)
@@ -117,74 +114,6 @@ def _kernel(buf_ref, len_ref, starts_ref, sizes_ref, xid_ref,
         zlo_ref[row, :] = zlo.reshape(1, R)
         err_ref[row, :] = err.reshape(1, R)
         return (cur, bad)
-
-    cur0 = jnp.zeros((R, 1), jnp.int32)
-    bad0 = jnp.zeros((R, 1), jnp.int32)
-    cur, bad = jax.lax.fori_loop(0, max_frames, step, (cur0, bad0))
-    resid_ref[0, :] = cur.reshape(R)
-    bad_ref[0, :] = bad.reshape(R)
-
-
-#: Stat word layout for the fused full-decode kernel: 17 big-endian
-#: int32 words covering the 68-byte Stat (6 longs as hi/lo pairs + 5
-#: ints), wire order (reference: lib/zk-buffer.js:428-442) — index i
-#: reads at byte offset 4*i from the Stat start.
-_STAT_WORDS = 17
-
-
-def _full_kernel(buf_ref, len_ref, starts_ref, sizes_ref, xid_ref,
-                 zhi_ref, zlo_ref, err_ref, dlen_ref, dw_ref, sw_ref,
-                 resid_ref, bad_ref,
-                 *, max_frames: int, max_data: int):
-    """The tick kernel (_kernel) with the GET_DATA body fused in: the
-    jute buffer length at body+4, the data bytes (as BE words), and
-    the Stat record after the data — all gathered in the same VMEM
-    pass, no intermediate HBM round trip (VERDICT r3 next #3's
-    experiment).  Layout: lib/zk-buffer.js:353-357 (buffer then Stat).
-    """
-    R, Lp = buf_ref.shape
-    DW = max_data // 4
-    lane = jax.lax.broadcasted_iota(jnp.int32, (R, Lp), 1)
-    n = len_ref[:]
-    w32 = _word_plane(buf_ref)
-
-    def step(j, carry):
-        cur, bad = carry
-        (start, size, ln, hdr_ok, (xid, zhi, zlo, err),
-         new_cur, new_bad, gather) = _scan_frame(lane, w32, n, cur,
-                                                 bad)
-
-        # -- GET_DATA body: buffer(len, bytes) at body+4, then Stat --
-        # raw jute length field (may be -1 = empty); masked to frames
-        # with a full reply header.  Clamp before extent arithmetic:
-        # a wire length near INT32_MAX must not wrap the checks below
-        # (mirrors replies._ustring_at).
-        draw = jnp.where(hdr_ok, gather(20), 0)
-        nb = jnp.minimum(jnp.maximum(draw, 0), MAX_PACKET + 1)
-        # data words: bytes cur+24 .. cur+24+max_data as BE words;
-        # gather only words the field reaches (byte masking happens in
-        # the XLA unpack, where it is elementwise)
-        row = pl.ds(j, 1)
-        for w in range(DW):
-            need = hdr_ok & (4 * w < nb)
-            dw_ref[pl.ds(j * DW + w, 1), :] = jnp.where(
-                need, gather(24 + 4 * w), 0).reshape(1, R)
-        # Stat after the data: valid only when its 68 bytes fit the
-        # frame (20 + nb + 68 <= ln, the parse_stats extent rule)
-        s_ok = hdr_ok & (20 + nb + 68 <= ln)
-        s_off = 24 + nb
-        for w in range(_STAT_WORDS):
-            sw_ref[pl.ds(j * _STAT_WORDS + w, 1), :] = jnp.where(
-                s_ok, gather(s_off + 4 * w), 0).reshape(1, R)
-
-        starts_ref[row, :] = start.reshape(1, R)
-        sizes_ref[row, :] = size.reshape(1, R)
-        xid_ref[row, :] = xid.reshape(1, R)
-        zhi_ref[row, :] = zhi.reshape(1, R)
-        zlo_ref[row, :] = zlo.reshape(1, R)
-        err_ref[row, :] = err.reshape(1, R)
-        dlen_ref[row, :] = draw.reshape(1, R)
-        return (new_cur, new_bad)
 
     cur0 = jnp.zeros((R, 1), jnp.int32)
     bad0 = jnp.zeros((R, 1), jnp.int32)
@@ -278,15 +207,15 @@ def _block_shape(B: int, L: int, block_rows: int,
 
 def _check_vmem(kernel: str, R: int, Bp: int, Lp: int, max_frames: int,
                 words: int, columns: int) -> None:
-    """The compile guard of both kernels: a readable error where
-    Mosaic would answer RESOURCE_EXHAUSTED."""
+    """The kernel's compile guard: a readable error where Mosaic would
+    answer RESOURCE_EXHAUSTED."""
     need = _vmem_estimate(R, Bp, Lp, max_frames, words, columns)
     limit = scoped_vmem_limit()
     if need > limit:
         raise ValueError(
             '%s: one program of R=%d rows x Lp=%d bytes x %d frames '
             '(%d words/frame) needs ~%.1f MiB of scoped VMEM (> %d MiB '
-            'on this device); shrink block_rows, L or max_data, or use '
+            'on this device); shrink block_rows or L, or use '
             'the jnp pipeline, which has no such bound'
             % (kernel, R, Lp, max_frames, words, need / 2**20,
                limit >> 20))
@@ -369,115 +298,4 @@ def pallas_wire_scan(buf, lens, max_frames: int = 32,
         'counts': jnp.sum((starts >= 0).astype(jnp.int32), axis=1),
         'resid': resid[0, :B],
         'bad': bad[0, :B].astype(jnp.bool_),
-    }
-
-
-def _full_scan_sizes(max_data: int) -> tuple[int, int]:
-    """(output words/frame, live [R, 1] columns) of the fused
-    full-decode kernel, for the VMEM guard: 6 tick planes + dlen +
-    data words + Stat words out; every unrolled data-word gather keeps
-    two columns live on top of a dozen for the scan and the Stat
-    (measured: 18 / 44 / 139 columns at max_data 16 / 64 / 256)."""
-    dw = max_data // 4
-    return 7 + dw + _STAT_WORDS, 12 + 2 * dw
-
-
-def fits_vmem_full(B: int, L: int, max_frames: int = 32,
-                   block_rows: int = 64, max_data: int = 16,
-                   device_kind: str | None = None) -> bool:
-    """VMEM guard for :func:`pallas_wire_full_scan`."""
-    R, Bp, Lp = _block_shape(B, L, block_rows)
-    return (_vmem_estimate(R, Bp, Lp, max_frames,
-                           *_full_scan_sizes(max_data))
-            <= scoped_vmem_limit(device_kind))
-
-
-@functools.partial(
-    jax.jit, static_argnames=('max_frames', 'block_rows', 'max_data',
-                              'interpret'))
-def pallas_wire_full_scan(buf, lens, max_frames: int = 32,
-                          block_rows: int = 64, max_data: int = 16,
-                          interpret: bool = False):
-    """Fused FULL decode on TPU via Pallas: frame scan + reply header
-    + the GET_DATA body (jute buffer length, data bytes, trailing
-    Stat) in one VMEM pass — the experiment that decides whether a
-    custom kernel earns its keep on the body path (VERDICT r3 next
-    #3; the jnp alternative round-trips frame planes through HBM
-    between the scan and each body gather).
-
-    Returns the tick planes of :func:`pallas_wire_scan` plus:
-      ``dlen_raw``  int32 [B, F]  raw jute length field at body+4
-                    (pre-validity; consumers apply the extent rule);
-      ``data_words`` int32 [B, F, max_data//4]  payload bytes as BE
-                    words (unpack + byte-mask on the XLA side);
-      ``stat_words`` int32 [B, F, 17]  the Stat record as BE words,
-                    zeroed where the Stat does not fit the frame.
-    """
-    if max_data % 4:
-        raise ValueError('max_data must be a multiple of 4')
-    B, L = buf.shape
-    R, Bp, Lp = _block_shape(B, L, block_rows, interpret)
-    DW = max_data // 4
-    if not interpret:
-        _check_vmem('pallas_wire_full_scan', R, Bp, Lp, max_frames,
-                    *_full_scan_sizes(max_data))
-
-    buf = jnp.zeros((Bp, Lp), jnp.uint8).at[:B, :L].set(buf)
-    lens = jnp.zeros((Bp, 1), jnp.int32).at[:B, 0].set(
-        lens.astype(jnp.int32))
-
-    kern = functools.partial(_full_kernel, max_frames=max_frames,
-                             max_data=max_data)
-    plane = jax.ShapeDtypeStruct((max_frames, Bp), jnp.int32)
-    dplane = jax.ShapeDtypeStruct((max_frames * DW, Bp), jnp.int32)
-    splane = jax.ShapeDtypeStruct((max_frames * _STAT_WORDS, Bp),
-                                  jnp.int32)
-    rowvec = jax.ShapeDtypeStruct((1, Bp), jnp.int32)
-    grid = (Bp // R,)
-    in_specs = [
-        pl.BlockSpec((R, Lp), lambda i: (i, 0)),
-        pl.BlockSpec((R, 1), lambda i: (i, 0)),
-    ]
-    plane_spec = pl.BlockSpec((max_frames, R), lambda i: (0, i))
-    dw_spec = pl.BlockSpec((max_frames * DW, R), lambda i: (0, i))
-    sw_spec = pl.BlockSpec((max_frames * _STAT_WORDS, R),
-                           lambda i: (0, i))
-    row_spec = pl.BlockSpec((1, R), lambda i: (0, i))
-
-    out = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(plane_spec,) * 7 + (dw_spec, sw_spec)
-        + (row_spec, row_spec),
-        out_shape=(plane,) * 7 + (dplane, splane) + (rowvec, rowvec),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel',)),
-        interpret=interpret,
-    )(buf, lens)
-    (starts, sizes, xid, zhi, zlo, err, dlen, dw, sw,
-     resid, bad) = out
-
-    def unpad(p):
-        return jnp.moveaxis(p, 0, 1)[:B]
-
-    def unpad3(p, k):
-        # [F*k, Bp] -> [B, F, k]
-        return jnp.transpose(
-            p.reshape(max_frames, k, -1), (2, 0, 1))[:B]
-
-    starts = unpad(starts)
-    return {
-        'starts': starts,
-        'sizes': unpad(sizes),
-        'xid': unpad(xid),
-        'zxid_hi': unpad(zhi),
-        'zxid_lo': unpad(zlo),
-        'err': unpad(err),
-        'counts': jnp.sum((starts >= 0).astype(jnp.int32), axis=1),
-        'resid': resid[0, :B],
-        'bad': bad[0, :B].astype(jnp.bool_),
-        'dlen_raw': unpad(dlen),
-        'data_words': unpad3(dw, DW),
-        'stat_words': unpad3(sw, _STAT_WORDS),
     }

@@ -78,9 +78,7 @@ def _assemble(headers, starts, sizes, counts, bad, resid) -> WireStats:
 
 
 def _stats_from_scan(r) -> WireStats:
-    """WireStats from a Pallas scan-result dict — the shared tail of
-    both Pallas entry points, so the short-frame/header routing rules
-    cannot diverge between them."""
+    """WireStats from a Pallas scan-result dict."""
     valid = r['starts'] >= 0
     short = valid & (r['sizes'] < 16)
     headers = {
@@ -110,93 +108,6 @@ def wire_pipeline_step_pallas(buf, lens, max_frames: int = 32,
     r = pallas_wire_scan(buf, lens, max_frames=max_frames,
                          block_rows=block_rows, interpret=interpret)
     return _stats_from_scan(r)
-
-
-class GetDataBodies(NamedTuple):
-    """The GET_DATA slice of :class:`..replies.ReplyBodies`, as
-    produced by the fused Pallas full decode — field-for-field the
-    planes ``parse_reply_bodies`` emits for that layout."""
-
-    data_len: jnp.ndarray      # int32 [B, F] raw jute length (0/-1 ok)
-    data: jnp.ndarray          # uint8 [B, F, max_data] zero-padded
-    data_mask: jnp.ndarray     # bool [B, F, max_data]
-    data_ok: jnp.ndarray       # bool [B, F] field extent fit the frame
-    stat_after_data: 'object'  # replies.StatPlanes
-
-
-def getdata_bodies_jnp(buf, st: WireStats,
-                       max_data: int) -> GetDataBodies:
-    """The GET_DATA planes via the jnp body parser — the reference
-    semantics the fused kernel must match, packaged as GetDataBodies:
-    the reference chip_smoke.py holds the kernel to."""
-    from . import replies as R
-
-    frame_ok = (st.starts >= 0) & (st.sizes >= 16)
-    start = jnp.where(frame_ok, st.starts, 0)
-    end = start + jnp.where(frame_ok, st.sizes, 0)
-    p = start + 16
-    dlen, data, mask, ok = R._ustring_at(buf, p, frame_ok, end,
-                                         max_data)
-    soff = p + 4 + jnp.maximum(dlen, 0)
-    stat = R.parse_stats(buf, soff, ok & (soff + 68 <= end))
-    return GetDataBodies(data_len=dlen, data=data, data_mask=mask,
-                         data_ok=ok, stat_after_data=stat)
-
-
-def wire_full_decode_pallas(buf, lens, max_frames: int = 32,
-                            max_data: int = 16, block_rows: int = 64,
-                            interpret: bool = False):
-    """Fused FULL decode (scan + headers + GET_DATA bodies) in one
-    Mosaic pass (ops/pallas_scan.pallas_wire_full_scan), plus the
-    cheap elementwise unpack XLA fuses for free.  Returns
-    ``(WireStats, GetDataBodies)`` — the Pallas counterpart of
-    ``wire_pipeline_step`` + ``parse_reply_bodies``'s GET_DATA planes
-    (property-tested equivalent in tests/test_pallas.py).  A shape
-    whose kernel would exceed the scoped-VMEM ceiling raises, like
-    :func:`wire_pipeline_step_pallas`."""
-    from ..protocol.consts import MAX_PACKET
-    from .pallas_scan import pallas_wire_full_scan
-    from .replies import _STAT_FIELDS, StatPlanes
-
-    r = pallas_wire_full_scan(buf, lens, max_frames=max_frames,
-                              block_rows=block_rows, max_data=max_data,
-                              interpret=interpret)
-    st = _stats_from_scan(r)
-
-    frame_ok = (r['starts'] >= 0) & ~(r['sizes'] < 16)
-    draw = r['dlen_raw']
-    # same clamp as the kernel and replies._ustring_at: extent math
-    # must not wrap on wire-controlled lengths
-    nb = jnp.minimum(jnp.maximum(draw, 0), MAX_PACKET + 1)
-    # the _ustring_at extent rule: p+4+n <= end, with p = start+16
-    data_ok = frame_ok & (20 + nb <= r['sizes'])
-    data_len = jnp.where(data_ok, draw, 0)
-    n_ok = jnp.where(data_ok, nb, 0)
-    # BE words -> bytes, masked to the field extent
-    shifts = jnp.asarray([24, 16, 8, 0], jnp.int32)
-    byts = ((r['data_words'][..., None] >> shifts) & 0xFF)
-    B, F = draw.shape
-    byts = byts.reshape(B, F, max_data)
-    pos = jnp.arange(max_data, dtype=jnp.int32)
-    data_mask = pos < n_ok[..., None]
-    data = jnp.where(data_mask, byts, 0).astype(jnp.uint8)
-
-    stat_ok = frame_ok & (20 + nb + 68 <= r['sizes'])
-    sw = r['stat_words']
-    # one source of truth for the Stat layout: the kernel writes word
-    # rel//4 (+1 for the low half of 64-bit fields)
-    vals = {}
-    for name, rel, is_long in _STAT_FIELDS:
-        k = rel // 4
-        if is_long:
-            vals[name + '_hi'] = sw[:, :, k]
-            vals[name + '_lo'] = sw[:, :, k + 1]
-        else:
-            vals[name] = sw[:, :, k]
-    stat = StatPlanes(valid=stat_ok, **vals)
-    return st, GetDataBodies(data_len=data_len, data=data,
-                             data_mask=data_mask, data_ok=data_ok,
-                             stat_after_data=stat)
 
 
 def wire_pipeline_step(buf, lens, max_frames: int = 32) -> WireStats:

@@ -79,15 +79,15 @@ class MeshFleetIngest(FleetIngest):
             'proxy-level session resume checkpoint')
 
     def _bucket(self, n_streams: int, nbytes: int) -> tuple:
-        dev, Bp, L = super()._bucket(n_streams, nbytes)
+        bodies, Bp, L = super()._bucket(n_streams, nbytes)
         dp = self.mesh.shape['dp']
         # the batch axis must divide over dp shards
         Bp = max(Bp, dp)
         Bp = ((Bp + dp - 1) // dp) * dp
-        return dev, Bp, L
+        return bodies, Bp, L
 
-    def _step_fn(self, device_bodies: bool):
-        fn = self._fns.get(device_bodies)
+    def _step_fn(self, _bodies=False):
+        fn = self._fn
         if fn is not None:
             return fn
         import jax
@@ -100,7 +100,7 @@ class MeshFleetIngest(FleetIngest):
         from .sharded import _u64_axis_max
 
         def local(buf, lens):
-            st, ints, byts = self._trace_step(buf, lens, device_bodies)
+            st, ints = self._trace_step(buf, lens)
             lh, ll = u64pair_reduce_max(st.max_zxid_hi, st.max_zxid_lo)
             gh, gl = _u64_axis_max(lh, ll, 'dp')
             g = jnp.stack([
@@ -112,18 +112,14 @@ class MeshFleetIngest(FleetIngest):
                 gh, gl])
             # replicated globals ride appended to each local row: the
             # packed readback stays one array, zero extra transfers
-            ints = jnp.concatenate(
+            return jnp.concatenate(
                 [ints, jnp.broadcast_to(g, (ints.shape[0],
                                             _N_GLOBALS))], axis=1)
-            return (ints, byts) if device_bodies else ints
 
-        out_specs = ((P('dp', None), P('dp', None, None))
-                     if device_bodies else P('dp', None))
-        fn = jax.jit(shard_map(
+        fn = self._fn = jax.jit(shard_map(
             local, mesh=self.mesh,
             in_specs=(P('dp', None), P('dp')),
-            out_specs=out_specs))
-        self._fns[device_bodies] = fn
+            out_specs=P('dp', None)))
         return fn
 
     def _finish(self, flight, sp) -> None:
@@ -136,7 +132,7 @@ class MeshFleetIngest(FleetIngest):
         finally:
             self._adding = False
 
-    def _unpack(self, ints, byts):
+    def _unpack(self, ints):
         g = ints[0, -_N_GLOBALS:]
         stats = {
             'total_frames': int(g[0]),
@@ -152,7 +148,7 @@ class MeshFleetIngest(FleetIngest):
                      for k, v in stats.items()}
         self.global_stats = stats
         self.fleet_max_zxid = max(self.fleet_max_zxid, stats['max_zxid'])
-        return super()._unpack(ints[:, :-_N_GLOBALS], byts)
+        return super()._unpack(ints[:, :-_N_GLOBALS])
 
 
 class MultihostFleetIngest(MeshFleetIngest):
@@ -375,10 +371,9 @@ class MultihostFleetIngest(MeshFleetIngest):
         from .multihost import host_local_wire_batch
 
         self.tick_count += 1
-        device = self.body_mode == 'device'
         try:
             batch, lens, active, overflow = self._assemble_tick()
-            fn = self._step_fn(device)
+            fn = self._step_fn()
             gbuf, glens = host_local_wire_batch(self.mesh, batch, lens)
         except Exception:
             # A pre-dispatch host-side failure (assembly, tracing, or
@@ -397,22 +392,14 @@ class MultihostFleetIngest(MeshFleetIngest):
                              np.uint8)
             lens = np.zeros((self.local_rows,), np.int32)
             active, overflow = {}, []
-            fn = self._step_fn(device)
+            fn = self._step_fn()
             gbuf, glens = host_local_wire_batch(self.mesh, batch, lens)
         # the launch itself is unconditional — collective alignment.
         # Global stats read back on every tick (they carry the OTHER
-        # hosts' traffic too); the body planes only when this host has
-        # frames to route.
-        if device:
-            ints, byts = fn(gbuf, glens)
-            self.launch_count += 1
-            byts = self._local_view(byts) if active else None
-        else:
-            ints = fn(gbuf, glens)
-            self.launch_count += 1
-            byts = None
-        ints = self._local_view(ints)
-        st, bd = self._unpack(ints, byts)
+        # hosts' traffic too).
+        ints = fn(gbuf, glens)
+        self.launch_count += 1
+        st = self._unpack(self._local_view(ints))
         for conn, buf in overflow:
             if id(conn) in self._slots:
                 self._deliver_scalar(conn, buf)
@@ -438,4 +425,4 @@ class MultihostFleetIngest(MeshFleetIngest):
                 continue
             streams.append(slot)
             rows.append(row)
-        self._route_batch(streams, rows, st, bd)
+        self._route_batch(streams, rows, st)
