@@ -52,17 +52,21 @@ import stats  # noqa: E402
 #: innermost first (``reduce_trace.attribute_gaps``: an earlier name
 #: takes what it covers): the program's own spans, written into the
 #: profiler's trace by ``zkstream_tpu.utils.trace.host_span`` on the
-#: device plane's clock — a watch delivery, the ingest tick's four
+#: device plane's clock — a collection (it lies inside whatever span
+#: was open when it began), a watch delivery, the ingest tick's four
 #: phases and then what is left of the tick, the send tier's hand-over
-#: and reaping inside its flush, a connection's receive, a request's
-#: submission, the deadline timer — then the harness's two: the loop
-#: blocked in ``select`` (the program has no span around it) and the
-#: post-window checks
-HOST_SPANS = ('client.notify',
+#: and reaping inside its flush, a connection's receive and then what
+#: is left of the receive reap that delivered it (``client.rx`` nests
+#: in ``client.rx_reap``), the deadline timer, an API call's own work
+#: before its submission, the submission, the awaiter's resumption —
+#: then the harness's two: the loop blocked in ``select`` (the program
+#: has no span around it) and the post-window checks
+HOST_SPANS = ('gc.pause', 'client.notify',
               'ingest.batch', 'ingest.dispatch', 'ingest.readback',
               'ingest.route', 'ingest.tick',
               'client.handoff', 'client.reap', 'client.flush',
-              'client.rx', 'client.deadline', 'client.submit',
+              'client.rx', 'client.rx_reap', 'client.deadline',
+              'client.prepare', 'client.submit', 'client.resume',
               'await_replies', 'validate')
 #: device idle time under none of those: what the loop's thread did
 #: that no span of the program names (the engine's callbacks, asyncio's
@@ -247,8 +251,8 @@ class Run:
             return None
 
 
-INGEST_COUNTERS = ('ticks', 'ticks_scalar', 'ticks_warming', 'ticks_frag',
-                   'frames_routed', 'body_fallbacks')
+INGEST_COUNTERS = ('ticks', 'ticks_early', 'ticks_scalar', 'ticks_warming',
+                   'ticks_frag', 'frames_routed')
 
 
 def ingest_counters(ingest) -> dict:
@@ -435,14 +439,33 @@ def buckets(sessions: int):
         bp *= 2
 
 
-async def _prewarm(ingest, sessions: int) -> float:
+def warm_shapes(sessions: int, min_len: int, max_len: int = 0):
+    """The ``(rows, width)`` of every tick program a cell warms: the
+    fleet's batch buckets in the narrowest size class (width None) and
+    — where the mix states ``warm_max_len``, the most bytes one slot
+    hands a tick — every wider class from ``2 * min_len`` up to the one
+    that holds ``max_len``, at every row count a fleet of this size can
+    give a dispatch (``FleetIngest.prewarm`` pads rows and width to the
+    bucket; one asked for twice is compiled once)."""
+    for bp in buckets(sessions):
+        yield bp, None
+    width = 2 * min_len
+    while width < 2 * max_len:
+        rows = 1
+        while rows < 2 * sessions:
+            yield rows, width
+            rows *= 2
+        width *= 2
+
+
+async def _prewarm(ingest, sessions: int, max_len: int) -> float:
     """Compile (or fetch from the cache) the tick program of every
-    batch bucket a fleet of this size can produce, off the loop."""
+    bucket ``warm_shapes`` names, off the loop."""
     t0 = time.perf_counter()
 
     def work():
-        for bp in buckets(sessions):
-            asyncio.run(ingest.prewarm(bp))
+        for rows, width in warm_shapes(sessions, ingest.min_len, max_len):
+            asyncio.run(ingest.prewarm(rows, width))
     await asyncio.get_running_loop().run_in_executor(None, work)
     return time.perf_counter() - t0
 
@@ -538,7 +561,9 @@ async def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         # the data loads through a plain session while the tick
         # programs compile on a thread: neither waits for the other
         prewarm_s, _ = await asyncio.gather(
-            _prewarm(ingest, int(cfg['sessions'])), engine.load())
+            _prewarm(ingest, int(cfg['sessions']),
+                     int(cell.traffic.get('warm_max_len', 0))),
+            engine.load())
         t1 = time.perf_counter()
         warmed = set(ingest.buckets)
         bad = {str(k): b['error'] for k, b in ingest.buckets.items()
@@ -548,6 +573,9 @@ async def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                                % (bad,))
         await engine.connect()
         t2 = time.perf_counter()
+        tier = fleet.clients[0].transport_tier if fleet.clients else None
+        say('# fleet transport=%s (the client tier every session shares)'
+            % (tier.backend if tier is not None else 'asyncio',))
         say('# setup start %.2fs jax+device %.2fs members+leader %.2fs '
             'load+prewarm %.2fs (prewarm %.2fs, compile %.2fs, '
             '%d buckets %s on %s) connect %.2fs'
@@ -714,6 +742,8 @@ async def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                 % (red['window_s'], red['busy_s'],
                    json.dumps(red['programs']), len(run.tick_buckets),
                    t_load, t_reduce))
+            # every name's share (the result line's breakdown holds ten)
+            say('# idle gaps %s' % (json.dumps(red['idle_gaps']),))
             if mode == 'chip' and red['busy_s'] <= 0:
                 raise HarnessError('no operation ran on the device in '
                                    'the traced window')

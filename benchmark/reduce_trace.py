@@ -209,13 +209,13 @@ def attribute_gaps(gaps, spans: dict, order,
 #: int32 planes of [Bp, max_frames] in the packed tick output
 #: (starts, sizes, xids, errs, zxid_hi, zxid_lo) after 3 head columns
 #: (n_frames, resid, bad) — the layout of ``FleetIngest._trace_step``
-#: in ``body_mode='host'``
+#: (headers only: reply bodies are parsed on the host)
 HEADER_PLANES = 6
 HEAD_COLUMNS = 3
 
 
 def tick_bytes(bp: int, length: int, max_frames: int) -> int:
-    """Bytes one host-body tick program must move through HBM for a
+    """Bytes one tick program must move through HBM for a
     ``[bp, length]`` bucket: read the u8 batch and the int32 lengths
     once, write the packed int32 result once.  A lower bound (no
     intermediate is counted), so the share it gives is an upper bound
@@ -237,7 +237,7 @@ def tick_roofline_share(run, program: str) -> float | None:
         return None
     frames = int(run.ingest_params['max_frames'])
     moved = sum(tick_bytes(bp, length, frames)
-                for _bodies, bp, length in run.tick_buckets)
+                for *_, bp, length in run.tick_buckets)
     # the host saw len(tick_buckets) ticks start in the traced window;
     # the trace holds prog['count'] executions: scale to what was timed
     moved *= prog['count'] / len(run.tick_buckets)

@@ -126,7 +126,7 @@ def lower(args) -> int:
             continue
         seen.add(key)
         ingest = FleetIngest(placement='host', **cfg['ingest'])
-        fn = ingest._step_fn(False)
+        fn = ingest._step_fn()
         for bp in harness.buckets(int(cfg['sessions'])):
             t0 = time.time()
             length = int(cfg['ingest']['min_len'])

@@ -5,15 +5,16 @@ the value a hand count gives; a ring that dropped spans, an untraced
 run, a program without the span (the parent of the PR that brought it:
 one ``asyncio.wait_for`` a request) and a program without a ring give
 None; and the toy read cell, traced, prints it with many requests to a
-timer.  Everything here finds the metric by its NAME: entries that
-later PRs append to BENCHMARK.json move nothing."""
+timer.  Everything here finds the metric by the file that reads it and
+the read cell: entries that later PRs append to BENCHMARK.json, and
+cells a merge puts on its list, move nothing."""
 
 import json
 import os
 import tempfile
 
 import harness
-from conftest import ROOT
+from conftest import ROOT, entry
 from test_inside import read, ring, toy_run  # noqa: F401  (fixture)
 from test_runs import members_alive, rehearse
 
@@ -22,17 +23,17 @@ from zkstream_tpu.utils import trace
 with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
     BENCH = json.load(f)
 
-NAME = 'client.ops_per_deadline_timer.read'
+NAME = entry('client.ops_per_deadline_timer', 'hunt3_1k.read')
 
 
 def test_entry_and_its_reader():
     (m,) = [m for m in BENCH['per_layer'] if m['name'] == NAME]
     (moved,) = [e for e in BENCH['end_to_end'] if e['name'] == m['moves']]
-    (like,) = [x for x in BENCH['per_layer']
-               if x['name'] == 'client.sends_per_flush.read']
-    # the timer's entry is the read cell's alone; the flush's lists
-    # every cell of its end-to-end family
-    assert m['workloads'] == ['hunt3_1k.read']
+    (like,) = [x for x in BENCH['per_layer'] if x['name'] == entry(
+        'client.sends_per_flush', 'hunt3_1k.read')]
+    # the timer's entry was the read cell's alone when PR 27 appended
+    # it; the flush's lists every cell of its end-to-end family
+    assert 'hunt3_1k.read' in m['workloads']
     assert set(m['workloads']) <= set(like['workloads'])
     assert m == dict(like, name=NAME, workloads=m['workloads'])
     assert set(m['workloads']) <= set(moved['workloads'])

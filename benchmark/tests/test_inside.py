@@ -69,13 +69,13 @@ def test_ring_readers_on_a_toy_ring(ring):
     want = {'ingest.batch_ms_p50': 0.6, 'ingest.dispatch_ms_p50': 0.3,
             'ingest.readback_ms_p50': 2.0, 'ingest.route_ms_p50': 0.2,
             'client.rx_share': 5.0, 'client.submit_share': 15.0}
-    ring_metrics = entries_read_by(*want)
-    # every reader has its entries: the read and the write family each
-    assert {n.rsplit('.', 1)[0] for n in ring_metrics} == set(want)
-    assert {n.rsplit('.', 1)[1] for n in ring_metrics} >= {'read', 'write'}
-    for name in ring_metrics:
-        assert read(name, run) == pytest.approx(
-            want[name.rsplit('.', 1)[0]])
+    # entry -> its reader: every reader has its entries, one through
+    # which the read cell reads it and another for the write cell
+    ring_metrics = {m['name']: r for r in want for m in entries(r)}
+    for r in want:
+        assert entry(r, 'hunt3_1k.read') != entry(r, 'hunt3_1k.write')
+    for name, r in ring_metrics.items():
+        assert read(name, run) == pytest.approx(want[r])
     nothing = [None] * len(ring_metrics)
     # a ring that wrapped is not the window's: nothing to read
     ring.dropped = 1
@@ -147,8 +147,10 @@ def test_member_readers_on_toy_mntr_rows():
 
     # busiest member: member 0, (2 + 12) s of 20 s
     busy = entries_read_by('server.busy_share')
-    assert {n.rsplit('.', 1)[1] for n in busy} == {'read', 'write',
-                                                   'converge'}
+    # one entry a family: the read, the write and the converge cells
+    assert sorted(busy) == sorted(
+        entry('server.busy_share', c) for c in (
+            'hunt3_1k.read', 'hunt3_1k.write', 'discovery3.relist'))
     for name in busy:
         assert read(name, run) == pytest.approx(70.0)
     # most parked follower: member 0, 12 s of 20 s (member 2: 2 s)
@@ -167,7 +169,11 @@ def test_member_readers_on_toy_mntr_rows():
         99) == pytest.approx(0.5)
     gates = entries_read_by('wal.fsync_gate_win_ms_p99')
     acks = entries_read_by('quorum.ack_ms_p95')
-    assert len(gates) == len(acks) == 2     # write's, and converge's
+    for reader, names in (('wal.fsync_gate_win_ms_p99', gates),
+                          ('quorum.ack_ms_p95', acks)):
+        # write's, and converge's
+        assert sorted(names) == sorted(entry(reader, c) for c in (
+            'hunt3_1k.write', 'discovery3.relist'))
     for name in gates:
         # rank 2,475 of 2,500: the top of (0.25, 0.5]
         assert read(name, run) == pytest.approx(0.5)
@@ -180,7 +186,7 @@ def test_member_readers_on_toy_mntr_rows():
     # no uptime row: the run's window stands in
     for r in before + after:
         del r['zk_uptime_ms']
-    assert [read(n, run) for n in busy] == [pytest.approx(70.0)] * 3
+    assert [read(n, run) for n in busy] == [pytest.approx(70.0)] * len(busy)
 
 
 def test_member_readers_find_nothing_on_the_parents_rows():

@@ -43,6 +43,53 @@ def test_union_and_gap_attribution():
     assert gaps['unattributed'] == pytest.approx((50 + 150) / 1e9)
 
 
+def test_the_harness_lists_an_inner_span_before_the_one_it_lies_in():
+    """``harness.HOST_SPANS`` as the program nests its spans (PRs 35,
+    36): a collection lies inside whatever was open, the tick's phases
+    inside the tick, the send tier's hand-over and reaping inside its
+    flush, a connection's receive inside the receive reap — an inner
+    name stands first, so each keeps its own time and the outer one
+    what is left; a span no name covers is the loop's rest."""
+    import harness
+
+    order = harness.HOST_SPANS
+    at = order.index
+    assert at('gc.pause') == 0
+    for inner, outer in (('ingest.batch', 'ingest.tick'),
+                         ('ingest.dispatch', 'ingest.tick'),
+                         ('ingest.readback', 'ingest.tick'),
+                         ('ingest.route', 'ingest.tick'),
+                         ('client.notify', 'ingest.route'),
+                         ('client.handoff', 'client.flush'),
+                         ('client.reap', 'client.flush'),
+                         ('client.rx', 'client.rx_reap')):
+        assert at(inner) < at(outer), (inner, outer)
+    assert {'client.prepare', 'client.submit', 'client.resume',
+            'client.deadline'} < set(order)
+    assert order[-2:] == ('await_replies', 'validate')
+    # one idle gap, 0..1000: a reap 100..400 that delivers twice
+    # (150..200, 250..300), a tick 500..800 whose route 600..760 holds
+    # a collection 650..700, and an API call's three stretches
+    host = [['client.rx_reap', 100.0, 300.0], ['client.rx', 150.0, 50.0],
+            ['client.rx', 250.0, 50.0], ['ingest.tick', 500.0, 300.0],
+            ['ingest.route', 600.0, 160.0], ['gc.pause', 650.0, 50.0],
+            ['client.prepare', 820.0, 10.0], ['client.submit', 830.0, 30.0],
+            ['client.resume', 900.0, 20.0], ['select', 0.0, 90.0]]
+    trace = {'planes': [
+        {'name': '/device:TPU:0', 'lines': [{'name': 'XLA Ops', 'events': [
+            ['fusion.1', -10.0, 10.0], ['fusion.1', 1000.0, 10.0]]}]},
+        {'name': '/host:CPU', 'lines': [{'name': 'main', 'events': host}]}]}
+    gaps = dict(rt.reduce(trace, window_ns=1020.0, host_spans=order,
+                          rest=harness.LOOP_REST)['idle_gaps'])
+    want = {'gc.pause': 50, 'ingest.route': 110, 'ingest.tick': 140,
+            'client.rx': 100, 'client.rx_reap': 200, 'client.prepare': 10,
+            'client.submit': 30, 'client.resume': 20}
+    for name in order:
+        assert gaps[name] == pytest.approx(want.get(name, 0) / 1e9), name
+    assert gaps[harness.LOOP_REST] == pytest.approx(
+        (1000 - sum(want.values())) / 1e9)
+
+
 def plain_attribution(gaps, spans, order):
     """``attribute_gaps`` as it stood before the sweep: every gap walks
     every name's merged list from its start.  The answers the sweep is

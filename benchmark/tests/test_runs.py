@@ -149,6 +149,68 @@ def test_a_later_pr_adds_a_cell_with_new_files_only(tmp):
     assert before == after
 
 
+def compiled_in_window(stdout: str) -> str:
+    (row,) = [ln for ln in stdout.splitlines()
+              if ln.startswith('# ingest ')]
+    return row.split('compiled_in_window=', 1)[1].split(' loop_errors')[0]
+
+
+def test_warm_shapes_are_the_buckets_and_the_classes_a_mix_states():
+    import harness
+    # no ``warm_max_len``: the fleet's batch buckets, narrowest class
+    assert list(harness.warm_shapes(24, 1024)) == [
+        (8, None), (16, None), (32, None)]
+    got = list(harness.warm_shapes(24, 1024, 4096))
+    assert got[:3] == [(8, None), (16, None), (32, None)]
+    # 2 x min_len up to the class that holds 4,096 B, rows 1 .. 32
+    assert got[3:] == [(r, w) for w in (2048, 4096)
+                       for r in (1, 2, 4, 8, 16, 32)]
+    # a length between two classes is held by the wider one; one that
+    # the narrowest class holds adds nothing
+    assert {w for _r, w in harness.warm_shapes(24, 1024, 4097)} == {
+        None, 2048, 4096, 8192}
+    assert list(harness.warm_shapes(24, 1024, 1024)) == got[:3]
+    assert max(r for r, _w in harness.warm_shapes(1024, 4096, 16384)) \
+        == 1024
+
+
+@pytest.mark.parametrize('stated', [True, False])
+def test_the_widths_a_mix_states_are_warm_before_the_window(tmp, stated):
+    """``hunt3_1k.read`` under a scratch mix that keeps 8 requests a
+    session outstanding (``tests/data/read_deep.json``; as a throw-away
+    file under ``traffic/`` for the run): a slot then hands a tick up to
+    8 replies, which no bucket of the narrowest class holds.  With the
+    mix's ``warm_max_len`` nothing compiles in the window; without it
+    (the control) the wide buckets compile on the loop, inside it."""
+    root = os.path.dirname(BENCH)
+    with open(os.path.join(root, 'BENCHMARK.json')) as f:
+        bench = json.load(f)
+    with open(os.path.join(BENCH, 'tests', 'data', 'read_deep.json')) as f:
+        mix = json.load(f)
+    assert mix['outstanding'] == 8 and mix['warm_max_len'] == 16384
+    if not stated:
+        del mix['warm_max_len'], mix['toy']['warm_max_len']
+    cell = next(w for w in bench['workloads']
+                if w['name'] == 'hunt3_1k.read')
+    cell['traffic'] = 'throwaway_read_deep'
+    path = os.path.join(BENCH, 'traffic', 'throwaway_read_deep.json')
+    alt = os.path.join(tmp, 'BENCHMARK.throwaway.json')
+    assert not os.path.exists(path)
+    try:
+        with open(path, 'w') as f:
+            json.dump(mix, f)
+        with open(alt, 'w') as f:
+            json.dump(bench, f)
+        r, out = rehearse(tmp, '--one', 'hunt3_1k.read', '--seed', '11',
+                          '--seconds', '2', '--bench', alt)
+    finally:
+        os.remove(path)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert out['correct'] is True and out['failed'] == 0
+    assert (compiled_in_window(r.stdout) == '[]') is stated
+    assert not members_alive() and not run_dirs(tmp)
+
+
 def test_traced_run_reports_layer_metrics(tmp):
     r, out = rehearse(tmp, '--one', 'hunt3_1k.read', '--seed', '9',
                       '--seconds', '3', '--trace', '1')

@@ -64,5 +64,5 @@ def test_traced_run_reports_the_herd_and_the_churn(tmp):  # noqa: F811
             e('converge.p95_ms')} <= set(m)
     assert 'compiled_in_window=[]' in r.stdout
     # no device, no device metric: the readers found nothing to read
-    assert e('decode.livenodes.jit_step_roofline') not in m
+    assert e('decode.converge.jit_step_roofline') not in m
     assert e('decode.kernel_ms_per_tick') not in m

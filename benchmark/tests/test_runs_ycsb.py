@@ -60,5 +60,5 @@ def test_traced_run_reports_the_updates_and_the_members(tmp):  # noqa: F811
     assert 'compiled_in_window=[]' in r.stdout
     assert '"changes_acked": ' in r.stdout
     # no device, no device metric: the readers found nothing to read
-    assert e('decode.ycsb.jit_step_roofline') not in m
+    assert e('decode.read.jit_step_roofline') not in m
     assert e('decode.kernel_ms_per_tick') not in m

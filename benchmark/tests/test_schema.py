@@ -157,19 +157,27 @@ RETIRED = {('server.decode_apply_ms_p99', 'hunt3_1k.read'),
            ('wal.fsync_gate_ms_p99', 'hunt3_1k.write'),
            ('wal.fsync_gate_ms_p99.relist', 'discovery3.relist'),
            ('fanout.flush_ms_p99', 'discovery3.relist')}
-#: ... and merged these three files into the two beside them: all the
+#: ... and merged these three files into the two beside them, as PR 46
+#: did the two that PRs 38 and 40 had to add for their cells: all the
 #: same call as ``decode.write.jit_step_roofline.py``, which stands
 MERGED = {'decode.load.jit_step_roofline.py':
           'decode.read.jit_step_roofline.py',
           'decode.push.jit_step_roofline.py':
           'decode.converge.jit_step_roofline.py',
           'decode.viewchange.jit_step_roofline.py':
-          'decode.converge.jit_step_roofline.py'}
+          'decode.converge.jit_step_roofline.py',
+          'decode.livenodes.jit_step_roofline.py':
+          'decode.converge.jit_step_roofline.py',
+          'decode.ycsb.jit_step_roofline.py':
+          'decode.read.jit_step_roofline.py'}
+#: the frozen tables: (entry, cell, reader file) as a merging PR's
+#: parent had them, and how many rows each holds
+TABLES = {'pr33': 128, 'pr45': 185}
 
 
-def old_pairs():
+def old_pairs(table):
     with open(os.path.join(BENCH, 'tests', 'data',
-                           'per_layer_pairs.pr33.txt')) as f:
+                           'per_layer_pairs.%s.txt' % (table,))) as f:
         return [tuple(row.split()) for row in f
                 if not row.startswith('#')]
 
@@ -179,38 +187,45 @@ def still_read(layers, cell, want):
     return bool(entries(want[:-len('.py')], cell, layers))
 
 
-def test_every_old_pair_is_still_read(bench):
+@pytest.mark.parametrize('table', sorted(TABLES))
+def test_every_old_pair_is_still_read(bench, table):
     """Merging entries lost no reader's coverage of any cell: every
-    (entry, cell) pair of the 128 entries PR 33 left is read by the
-    same file in the same cell under SOME entry of today's list —
-    but the four since-start entries, and nothing else."""
+    (entry, cell) pair of the 128 entries PR 33 left, and of the 128
+    PR 45 left, is read by the same file in the same cell under SOME
+    entry of today's list — but the four since-start entries, and
+    nothing else."""
     import inspect
 
     import harness
 
-    pairs = old_pairs()
-    assert len(pairs) == 128 and len(set(pairs)) == 128
-    assert RETIRED <= {(n, c) for n, c, _f in pairs}
+    pairs = old_pairs(table)
+    assert len(pairs) == TABLES[table] == len(set(pairs))
+    retired = RETIRED & {(n, c) for n, c, _f in pairs}
+    assert retired == (RETIRED if table == 'pr33' else set())
     lost = [(name, cell) for name, cell, want in pairs
-            if (name, cell) not in RETIRED
+            if (name, cell) not in retired
             and not still_read(bench['per_layer'], cell,
                                MERGED.get(want, want))]
     assert not lost, lost
     for name, cell, want in pairs:
-        if (name, cell) in RETIRED:
+        if (name, cell) in retired:
             assert not still_read(bench['per_layer'], cell, want)
     stands = harness._load_module('layer_metrics',
                                   'decode.write.jit_step_roofline').read
     for name in set(MERGED.values()):
         got = harness._load_module('layer_metrics', name[:-3]).read
         assert inspect.getsource(got) == inspect.getsource(stands)
+    for name in MERGED:
+        assert not os.path.exists(os.path.join(BENCH, 'layer_metrics',
+                                               name))
 
 
-def test_the_check_sees_a_cell_dropped_from_a_list(bench):
-    """Each of the 124 pairs that stand is read under exactly ONE
-    entry: that entry's ``workloads`` less the pair's cell loses it."""
+@pytest.mark.parametrize('table', sorted(TABLES))
+def test_the_check_sees_a_cell_dropped_from_a_list(bench, table):
+    """Each of the pairs that stand is read under exactly ONE entry:
+    that entry's ``workloads`` less the pair's cell loses it."""
     layers = bench['per_layer']
-    for _name, cell, want in old_pairs():
+    for _name, cell, want in old_pairs(table):
         if (_name, cell) in RETIRED:
             continue
         want = MERGED.get(want, want)
@@ -233,7 +248,8 @@ def test_one_entry_a_reader_and_end_to_end_metric(bench):
         key = (MERGED.get(os.path.basename(path), path), m['moves'])
         assert key not in seen, (m['name'], seen[key])
         seen[key] = m['name']
-    assert len(bench['per_layer']) == 88
+        assert m['workloads'], m['name']
+    assert len(bench['per_layer']) <= 128
 
 
 def test_every_file_under_paths_has_a_contract_name(bench):

@@ -13,7 +13,7 @@ import os
 import tempfile
 
 import pytest
-from conftest import ROOT, entries
+from conftest import ROOT, entries, entry
 from test_inside import (entries_read_by, member_rows, read,  # noqa: F401
                          ring, toy_run)
 from test_runs import members_alive, rehearse
@@ -28,71 +28,66 @@ WRITE = ['hunt3_1k.write']
 CONVERGE = ['discovery3.relist', 'confcache3.push',
             'helixview3.viewchange']
 
-#: entry -> (reader file, layer, source, moves, better, cells)
-ENTRIES = {
-    'client.cork_wait_us.read': (
-        'client.cork_wait_us', 'client session', 'program_span',
-        'read_p95_ms', 'lower', READ),
-    'client.wire_wait_us.read': (
-        'client.wire_wait_us', 'client session', 'program_span',
-        'read_p95_ms', 'lower', READ),
-    'client.tick_wait_us.read': (
-        'client.tick_wait_us', 'client session', 'program_span',
-        'read_p95_ms', 'lower', READ),
-    'client.wake_wait_us.read': (
-        'client.wake_wait_us', 'client session', 'program_span',
-        'read_p95_ms', 'lower', READ),
-    'client.await_share.read': (
-        'client.await_share', 'client session', 'program_span',
-        'ops_per_s.read', 'lower', READ),
-    'gc.pause_share.read': (
-        'gc.pause_share', 'client session', 'program_span',
-        'ops_per_s.read', 'lower', READ),
-    'gc.pause_share.write': (
-        'gc.pause_share', 'client session', 'program_span',
-        'write_p95_ms', 'lower', WRITE),
-    'gc.pause_share.converge': (
-        'gc.pause_share', 'client session', 'program_span',
-        'converge_p50_ms', 'lower', CONVERGE),
-    'ingest.route_gc_us_per_frame.read': (
-        'ingest.route_gc_us_per_frame', 'fleet ingest', 'program_span',
-        'ops_per_s.read', 'lower', READ),
-    'server.control_share.write': (
-        'server.control_share', 'server tick', 'program_counter',
-        'write_p95_ms', 'lower', WRITE),
-    'server.control_share.converge': (
-        'server.control_share', 'server tick', 'program_counter',
-        'converge_p50_ms', 'lower', CONVERGE),
-    'server.repl_ack_share.write': (
-        'server.repl_ack_share', 'replication', 'program_counter',
-        'write_p95_ms', 'lower', WRITE),
-    'server.phase_coverage.write': (
-        'server.phase_coverage', 'server tick', 'program_counter',
-        'write_p95_ms', 'higher', WRITE),
-}
-RING_ENTRIES = [n for n, e in ENTRIES.items() if e[2] == 'program_span']
-MNTR_ENTRIES = [n for n, e in ENTRIES.items() if e[2] == 'program_counter']
+#: (reader file, layer, source, moves, better, the cells the entry
+#: listed when PR 35 appended it: a later merge may add cells, and names
+#: the entry as it likes)
+ENTRIES = [
+    ('client.cork_wait_us', 'client session', 'program_span',
+     'read_p95_ms', 'lower', READ),
+    ('client.wire_wait_us', 'client session', 'program_span',
+     'read_p95_ms', 'lower', READ),
+    ('client.tick_wait_us', 'client session', 'program_span',
+     'read_p95_ms', 'lower', READ),
+    ('client.wake_wait_us', 'client session', 'program_span',
+     'read_p95_ms', 'lower', READ),
+    ('client.await_share', 'client session', 'program_span',
+     'ops_per_s.read', 'lower', READ),
+    ('gc.pause_share', 'client session', 'program_span',
+     'ops_per_s.read', 'lower', READ),
+    ('gc.pause_share', 'client session', 'program_span',
+     'write_p95_ms', 'lower', WRITE),
+    ('gc.pause_share', 'client session', 'program_span',
+     'converge_p50_ms', 'lower', CONVERGE),
+    ('ingest.route_gc_us_per_frame', 'fleet ingest', 'program_span',
+     'ops_per_s.read', 'lower', READ),
+    ('server.control_share', 'server tick', 'program_counter',
+     'write_p95_ms', 'lower', WRITE),
+    ('server.control_share', 'server tick', 'program_counter',
+     'converge_p50_ms', 'lower', CONVERGE),
+    ('server.repl_ack_share', 'replication', 'program_counter',
+     'write_p95_ms', 'lower', WRITE),
+    ('server.phase_coverage', 'server tick', 'program_counter',
+     'write_p95_ms', 'higher', WRITE),
+]
+#: the thirteen by the name they stand under today, found by reader and
+#: cell (never by where they stand in the file, nor by a suffix)
+NAMES = [entry(e[0], e[5][0]) for e in ENTRIES]
+RING_ENTRIES = [n for n, e in zip(NAMES, ENTRIES) if e[2] == 'program_span']
+MNTR_ENTRIES = [n for n, e in zip(NAMES, ENTRIES)
+                if e[2] == 'program_counter']
 
 
 def test_the_thirteen_entries_and_their_readers():
     by_name = {m['name']: m for m in BENCH['per_layer']}
     e2e = {m['name']: m for m in BENCH['end_to_end']}
+    readers = {e[0] for e in ENTRIES}
     layers = {m['layer'] for m in BENCH['per_layer']
-              if m['name'] not in ENTRIES}
+              if m['name'] not in NAMES}
     assert len(BENCH['per_layer']) <= 128
-    assert [m['name'] for m in BENCH['per_layer'][-13:]] == list(ENTRIES)
+    assert len(set(NAMES)) == len(ENTRIES) == 13
     for name, (reader, layer, source, moves, better, cells) in \
-            ENTRIES.items():
+            zip(NAMES, ENTRIES):
         m = by_name[name]
         assert name in entries_read_by(reader)
         assert (m['layer'], m['source'], m['moves'], m['better']) == (
             layer, source, moves, better)
-        assert m['workloads'] == cells
+        # every cell it was given reads it through this entry still
+        assert {entry(reader, c) for c in cells} == {name}
         assert layer in layers          # a name the file had already
-        assert set(cells) <= set(e2e[moves]['workloads'])
+        assert set(m['workloads']) <= set(e2e[moves]['workloads'])
         assert m['unit'] == ('us' if '_us' in name else '%')
     # one entry a reader and family: no suffixed copy a cell
-    for reader in {e[0] for e in ENTRIES.values()}:
+    for reader in readers:
         got = entries(reader)
         assert len({m['moves'] for m in got}) == len(got)
 
@@ -115,15 +110,16 @@ def fill(ring):  # noqa: F811
                   duration_ms=3.0)
 
 
-WANT = {'client.cork_wait_us.read': 2000.0,
-        'client.wire_wait_us.read': 80000.0,
-        'client.tick_wait_us.read': 9000.0,
-        'client.wake_wait_us.read': 1500.0,
-        'client.await_share.read': 5.0,
-        'gc.pause_share.read': 7.5,
-        'gc.pause_share.write': 7.5,
-        'gc.pause_share.converge': 7.5,
-        'ingest.route_gc_us_per_frame.read': 3.0}
+R, W, C = 'hunt3_1k.read', 'hunt3_1k.write', 'discovery3.relist'
+WANT = {entry('client.cork_wait_us', R): 2000.0,
+        entry('client.wire_wait_us', R): 80000.0,
+        entry('client.tick_wait_us', R): 9000.0,
+        entry('client.wake_wait_us', R): 1500.0,
+        entry('client.await_share', R): 5.0,
+        entry('gc.pause_share', R): 7.5,
+        entry('gc.pause_share', W): 7.5,
+        entry('gc.pause_share', C): 7.5,
+        entry('ingest.route_gc_us_per_frame', R): 3.0}
 
 
 def test_ring_readers_on_a_toy_ring(ring):  # noqa: F811
@@ -142,13 +138,13 @@ def test_ring_readers_on_a_toy_ring(ring):  # noqa: F811
     assert [read(n, run) for n in WANT] == nothing
     # no collection fell into a route: 0, not nothing
     del ring.totals['gc.pause@ingest.route']
-    assert read('ingest.route_gc_us_per_frame.read', toy_run()) == 0.0
+    assert read(entry('ingest.route_gc_us_per_frame', R), toy_run()) == 0.0
     # half of a pair is not the pair
     del ring.totals['client.resume']
-    assert read('client.await_share.read', toy_run()) is None
+    assert read(entry('client.await_share', R), toy_run()) is None
     # an op count of zero divides nothing
     ring.totals['client.cork_wait'] = [0, 0]
-    assert read('client.cork_wait_us.read', toy_run()) is None
+    assert read(entry('client.cork_wait_us', R), toy_run()) is None
 
 
 def test_ring_readers_on_the_parents_ring(ring):  # noqa: F811
@@ -188,12 +184,14 @@ def test_leader_readers_on_toy_mntr_rows():
              dict(member_rows(start, uptime_ms=25_000),
                   zk_process_cpu_ms='4000.0')]
     run.mntr_before, run.mntr_after = before, after
-    assert read('server.control_share.write', run) == pytest.approx(40.0)
-    assert read('server.control_share.converge', run) == pytest.approx(
-        40.0)
-    assert read('server.repl_ack_share.write', run) == pytest.approx(5.0)
-    assert read('server.phase_coverage.write', run) == pytest.approx(
-        100.0 * 14_000 / 17_500)
+    assert read(entry('server.control_share', W),
+                run) == pytest.approx(40.0)
+    assert read(entry('server.control_share', C),
+                run) == pytest.approx(40.0)
+    assert read(entry('server.repl_ack_share', W),
+                run) == pytest.approx(5.0)
+    assert read(entry('server.phase_coverage', W),
+                run) == pytest.approx(100.0 * 14_000 / 17_500)
     # the parent's program: the phases and the row are not there
     for rows in before + after:
         del rows['zk_process_cpu_ms']
@@ -210,25 +208,24 @@ def test_leader_readers_on_toy_mntr_rows():
 
 @pytest.mark.parametrize('cell', READ + WRITE + CONVERGE)
 def test_toy_cell_traced_prints_its_entries(cell):
-    want = [n for n, e in ENTRIES.items() if cell in e[5]]
+    e = {r: entry(r, cell) for r, *_rest, cells in ENTRIES if cell in cells}
+    want = set(e.values())
     with tempfile.TemporaryDirectory(prefix='benchtest-') as tmp:
         r, out = rehearse(tmp, '--one', cell, '--seed', str(2 ** 31 + 35),
                           '--seconds', '3', '--trace', '1', timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     assert out['correct'] is True and out['failed'] == 0
     got = {k: v['value'] for k, v in out['metrics'].items()}
-    assert set(want) <= set(got), sorted(set(want) - set(got))
+    assert want <= set(got), sorted(want - set(got))
     assert all(got[n] >= 0 for n in want)
-    assert 0 <= got['gc.pause_share.' + (
-        'read' if cell in READ else 'write' if cell in WRITE
-        else 'converge')] < 50
+    assert 0 <= got[e['gc.pause_share']] < 50
     if cell in READ:
-        waits = [got['client.%s_wait_us.read' % s]
+        waits = [got[e['client.%s_wait_us' % s]]
                  for s in ('cork', 'wire', 'tick', 'wake')]
         assert all(w > 0 for w in waits)
-        assert 0 < got['client.await_share.read'] < 50
+        assert 0 < got[e['client.await_share']] < 50
     if cell in WRITE:
-        assert 0 < got['server.control_share.write'] < 100
-        assert 0 < got['server.repl_ack_share.write'] < 100
-        assert 0 < got['server.phase_coverage.write'] <= 150
+        assert 0 < got[e['server.control_share']] < 100
+        assert 0 < got[e['server.repl_ack_share']] < 100
+        assert 0 < got[e['server.phase_coverage']] <= 150
     assert not members_alive()
