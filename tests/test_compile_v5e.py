@@ -120,6 +120,11 @@ def _ingest_for(dev, **kw):
     # dispatch and a wide class of the load cell
     (1024, 4096, 8, 'jnp'),
     (4, 131072, 8, 'jnp'),
+    # a pipelined fleet's full dispatches (hunt3_1k.read_deep: 1,024
+    # sessions x 8 replies of 1,116 B behind one another in a row): the
+    # 16 KiB class at DISPATCH_BYTES exactly, and the 8 KiB class
+    (1024, 16384, 8, 'jnp'),
+    (1024, 8192, 8, 'jnp'),
 ])
 def test_host_body_tick_bucket_compiles_for_v5e(v5e, Bp, L, frames, impl):
     ing = _ingest_for(v5e, max_frames=frames)

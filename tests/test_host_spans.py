@@ -693,14 +693,7 @@ async def test_two_requests_in_flight_are_each_stamped_by_their_own_flush(
     ring = trace.TraceRing(8)
 
     def submit():
-        sub = trace.host_span('client.submit', accumulate=True)
-        with sub:
-            span = ring.start('GET_DATA', '/k')
-            span.stages = [sub.t0_ns, 0, 0, 0]
-            req = p.conn.request({'opcode': 'GET_DATA', 'path': '/k',
-                                  'watch': False}, span)
-        req.as_future()
-        return span, req
+        return _staged_submit(p, ring)
 
     try:
         (a, ra), (b, rb) = submit(), submit()
@@ -730,6 +723,166 @@ async def test_two_requests_in_flight_are_each_stamped_by_their_own_flush(
             trace.op_resumed(span).__exit__(None, None, None)
         _check_stages([a, b, c_], 3)
         assert trace.host_ring.totals['client.resume'][0] == 3
+    finally:
+        p.session.close()
+        p.conn.destroy()
+        await settle()
+        ingest.close()
+
+
+def _staged_submit(p, ring):
+    sub = trace.host_span('client.submit', accumulate=True)
+    with sub:
+        span = ring.start('GET_DATA', '/k')
+        span.stages = [sub.t0_ns, 0, 0, 0]
+        req = p.conn.request({'opcode': 'GET_DATA', 'path': '/k',
+                              'watch': False}, span)
+    req.as_future()
+    return span, req
+
+
+@pytest.mark.parametrize('lead', ['reply', 'notification'])
+async def test_a_pipelined_reply_is_stamped_by_the_call_that_completed_it(
+        armed, lead):
+    """Three replies of one connection come in two receive calls
+    before one tick — the first whole and half of the second, then the
+    rest: each request's ``t_rx`` is the start of the call that brought
+    ITS reply's last byte, not the connection's newest, through the
+    direct lane and (a notification leads the stream) through
+    ``deliver``; the marks are gone with the bytes."""
+    import random
+
+    from test_ingest_route import Peer, settle
+
+    ingest = FleetIngest(bypass_bytes=0, warm='block', placement='host',
+                         max_frames=8, min_len=256)
+    p = Peer(0, ingest, True, random.Random(11))
+    ring = trace.TraceRing(8)
+    calls: list = []
+    p.conn.on('sockData', lambda _d: calls.append(p.conn._rx_t0))
+    try:
+        (a, ra), (b, rb), (c_, rc) = (_staged_submit(p, ring)
+                                      for _ in range(3))
+        await settle()
+        if lead == 'notification':
+            p.notification()
+        p.reply(ra.packet['xid'])
+        first = len(p.wire)
+        p.reply(rb.packet['xid'])
+        cut = (first + len(p.wire)) // 2        # inside the second reply
+        p.reply(rc.packet['xid'])
+        wire, p.wire = bytes(p.wire), bytearray()
+        ticks = ingest.ticks
+        p.conn._sock_data(wire[:cut])
+        p.conn._sock_data(wire[cut:])
+        assert len(calls) == 2 and 0 < calls[0] < calls[1]
+        assert [m[0] for m in ingest._rx_marks[id(p.conn)]] == [
+            cut, len(wire)]
+        await settle()
+        # one tick routed all three; behind a notification (a first
+        # frame of ~50 B: the slot gives 256) the cut slot finishes on
+        # follow-up ticks, and the marks follow what it consumed
+        assert ingest.ticks - ticks == 1 or lead == 'notification'
+        rx = [s.stages[trace.T_RX] for s in (a, b, c_)]
+        assert rx == [calls[0], calls[1], calls[1]]
+        st = [s.stages[trace.T_SETTLE] for s in (a, b, c_)]
+        assert calls[1] < st[0] < st[1] < st[2]
+        assert {s.tick for s in (a, b, c_)} <= set(
+            range(ticks + 1, ingest.ticks + 1))
+        assert not ingest._rx_marks
+        for span, req in ((a, ra), (b, rb), (c_, rc)):
+            assert req.fut.done() and span.status == 'ok'
+            trace.op_resumed(span).__exit__(None, None, None)
+        _check_stages([a, b, c_], 3)
+        # the first reply waited in its slot while the second came
+        wire_w, tick_w = (trace.host_ring.totals[n][1] for n in (
+            'client.wire_wait', 'client.tick_wait'))
+        assert tick_w >= 3 * (st[0] - calls[1]) + (calls[1] - calls[0])
+    finally:
+        p.session.close()
+        p.conn.destroy()
+        await settle()
+        ingest.close()
+
+
+async def test_the_marks_end_with_the_session(monkeypatch):
+    """Outside a profiler session a receive call leaves no mark, and
+    the first one after a session drops what the session left."""
+    import random
+
+    from test_ingest_route import Peer, settle
+
+    ingest = FleetIngest(bypass_bytes=0, warm='block', placement='host',
+                         max_frames=8, min_len=256)
+    p, q = (Peer(i, ingest, True, random.Random(i)) for i in range(2))
+    try:
+        xid = p.get()
+        p.reply(xid)
+        wire, p.wire = bytes(p.wire), bytearray()
+        p.conn._sock_data(wire[:5])
+        assert not ingest._rx_marks and p.conn._rx_t0 == 0
+        monkeypatch.setattr(trace, '_is_enabled', lambda: True)
+        p.conn._sock_data(wire[5:9])
+        assert [m[0] for m in ingest._rx_marks[id(p.conn)]] == [9]
+        monkeypatch.setattr(trace, '_is_enabled', lambda: False)
+        q.reply(q.get())
+        q.conn._sock_data(q.take())
+        assert not ingest._rx_marks
+        p.conn._sock_data(wire[9:])
+        await settle()
+        assert not p.conn.reqs and not q.conn.reqs
+        assert ingest.frames_routed == 2
+    finally:
+        for x in (p, q):
+            x.session.close()
+            x.conn.destroy()
+        await settle()
+        ingest.close()
+
+
+@pytest.mark.parametrize('k,ticks,bound,reticks', [
+    (3, 1, 0, 0), (8, 1, 1, 0), (9, 2, 1, 1), (20, 3, 2, 2)])
+async def test_a_tick_says_what_it_met_of_the_frame_bound(
+        armed, k, ticks, bound, reticks):
+    """``k`` equal replies behind one another in one slot,
+    ``max_frames`` 8: every device tick's ``ingest.tick`` span carries
+    ``bound`` (rows that gave the whole frame bound), ``cut`` (slots
+    that held more than they gave) and ``retick`` (it left a follow-up
+    for either) — the spans' share of the always-on ``slots_bound`` /
+    ``slots_cut`` / ``reticks``, which a collector exports."""
+    import random
+
+    from test_ingest_classes import reply_sized
+    from test_ingest_route import Peer, settle
+    from zkstream_tpu.utils.metrics import Collector
+
+    ingest = FleetIngest(bypass_bytes=0, warm='block', placement='host',
+                         max_frames=8, min_len=256)
+    col = Collector()
+    ingest.bind_metrics(col)
+    p = Peer(0, ingest, True, random.Random(k))
+    try:
+        for _ in range(k):
+            reply_sized(p, p.get(), 100)
+        p.conn._sock_data(p.take())
+        for _ in range(ticks + 2):
+            await settle()
+        assert not p.conn.reqs
+        spans = [s for s in trace.host_ring.spans()
+                 if s.op == 'ingest.tick' and s.tick is not None]
+        assert len(spans) == ticks == ingest.ticks
+        assert sum(s.batch for s in spans) == k
+        assert [s.retick for s in spans] == [1] * reticks + [0] * (
+            ticks - reticks)
+        assert sum(s.bound for s in spans) == bound == ingest.slots_bound
+        assert sum(s.cut for s in spans) == ingest.slots_cut <= reticks
+        assert ingest.reticks == reticks
+        assert all(s.to_dict()['retick'] == s.retick for s in spans)
+        text = col.expose()
+        for name, val in (('zkstream_ingest_bound_slots', bound),
+                          ('zkstream_ingest_cut_slots', ingest.slots_cut),
+                          ('zkstream_ingest_reticks', reticks)):
+            assert '%s %d' % (name, val) in text
     finally:
         p.session.close()
         p.conn.destroy()

@@ -1,4 +1,5 @@
 """The collector's young generation follows a fleet's registered slots
+— and, where its clients pipeline, the requests it has alive
 (utils/alloc.py ``fit_collector``, called by ``FleetIngest``): the rule,
 who it leaves alone, what the last ``close()`` puts back — and the
 fact it rests on: a read through a fleet ingest leaves no cyclic
@@ -136,6 +137,62 @@ def test_the_last_ingest_to_close_puts_the_thresholds_back():
     gc.collect()
     d = _ingest()                       # c was dropped, never closed
     d.close()
+    assert gc.get_threshold() == DEFAULT
+
+
+@pytest.mark.parametrize('depth,answered,alive,later', [
+    (1, 1, 64, 64),     # one request a session: the slots, as PR 43 set
+    (2, 2, 128, 128),   # 128 frames from 64 slots: 128 alive
+    (8, 8, 512, 512),   # the whole window of 8 in one tick
+    (8, 2, 512, 512),   # 128 routed, 384 still pending: 512 alive
+    (8, 1, 64, 448),    # 64 frames of 64 slots: nobody looks, until
+    (3, 1, 64, 128)])   # the rest comes in one tick
+async def test_the_young_threshold_follows_the_requests_alive(
+        depth, answered, alive, later):
+    """Where the clients pipeline the collector is sized to the
+    requests the fleet has alive — what a tick routed and what is still
+    pending on its slots' connections — found when a tick routes more
+    frames than any look has seen, set once that is twice what stands;
+    at one request outstanding a tick never routes more frames than
+    there are slots, and the threshold is the slots' byte for byte."""
+    import random
+
+    from test_ingest_route import Peer, settle
+
+    ing = FleetIngest(bypass_bytes=0, warm='block', placement='host',
+                      max_frames=8, min_len=256)
+    peers = [Peer(i, ing, True, random.Random(i)) for i in range(64)]
+    try:
+        assert gc.get_threshold() == (K * 64, 10, 10)
+        for p in peers:
+            xids = [p.get() for _ in range(depth)]
+            for xid in xids[:answered]:
+                p.reply(xid)
+            p.flush()
+        await settle()
+        assert ing.frames_routed == 64 * answered
+        assert gc.get_threshold() == (K * alive, 10, 10)
+        # the rest of the window comes: a look only where that tick
+        # routes more frames than the last look found alive
+        for p in peers:
+            for xid in sorted(p.conn.reqs):
+                p.reply(xid)
+            p.flush()
+        await settle()
+        assert ing.frames_routed == 64 * depth
+        assert gc.get_threshold() == (K * later, 10, 10)
+        # the fleet halves: the slots again, whatever was alive before
+        for p in peers[32:]:
+            p.session.close()
+            p.conn.destroy()
+        await settle()
+        assert gc.get_threshold() == (K * 32, 10, 10)
+    finally:
+        for p in peers:
+            p.session.close()
+            p.conn.destroy()
+        await settle()
+        ing.close()
     assert gc.get_threshold() == DEFAULT
 
 

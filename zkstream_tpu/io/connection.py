@@ -474,13 +474,14 @@ class ZKConnection(FSM):
         ping_interval = max(self.session.get_timeout() / 4, 2000)
         S.interval(ping_interval, self.ping)
 
-        def deliver(pkts, err):
-            for pkt in pkts:
+        def deliver(pkts, err, times=None):
+            # ``times``: the lane's, for the packets it hands on
+            for k, pkt in enumerate(pkts):
                 self.emit('packet', pkt)
                 # Notifications are the session's business
                 # (reference: lib/connection-fsm.js:223-224).
                 if pkt['opcode'] != 'NOTIFICATION':
-                    self.process_reply(pkt)
+                    self.process_reply(pkt, times[k] if times else 0)
             if err is not None:
                 self.last_error = err
                 S.goto_state('error')
@@ -505,7 +506,7 @@ class ZKConnection(FSM):
                         and held[0] is session.packet_listener
                         and self.faults is None)
 
-            def lane(pkts, err, now):
+            def lane(pkts, err, now, times=None):
                 """The direct settle lane: what one routed stream of
                 the ingest's tick costs when it is a run of plain
                 replies.  For the leading packets with ``xid > 0`` it
@@ -518,19 +519,28 @@ class ZKConnection(FSM):
                 From the first packet that is anything else — a
                 notification, a reserved xid — and for the stream's
                 decode error, ``deliver`` takes over, in stream order.
+                ``times`` (profiler sessions only, else None): for
+                each packet the start of the receive call that brought
+                its last byte, the ``t_rx`` of its request's stage
+                stamps — a pipelined connection's replies of one tick
+                may have come in several calls.
                 Returns the packets settled here."""
                 if not pkts or pkts[0]['xid'] <= 0 or not lane_open():
-                    deliver(pkts, err)
+                    deliver(pkts, err, times)
                     return 0
                 session.reset_expiry_timer(now)
                 log_trace = (self.log.trace
                              if self.log.enabled_for_trace() else None)
                 rx = self.rx_mark() if self._rx_t0 else None
+                if rx is None:
+                    times = None
                 n = 0
                 for pkt in pkts:
                     xid = pkt['xid']
                     if xid <= 0:
                         break
+                    if times is not None:
+                        rx = (times[n], rx[1])
                     n += 1
                     if pkt['zxid'] > session.last_zxid:
                         session.last_zxid = pkt['zxid']
@@ -543,7 +553,7 @@ class ZKConnection(FSM):
                             and not lane_open()):
                         break
                 if n < len(pkts) or err is not None:
-                    deliver(pkts[n:], err)
+                    deliver(pkts[n:], err, times and times[n:])
                 return n
 
             self.ingest.register(self, lane)
@@ -552,7 +562,7 @@ class ZKConnection(FSM):
             def on_sock(data):
                 ing = self.ingest
                 if not ing.direct:
-                    ing.feed(self, data)
+                    ing.feed(self, data, self._rx_t0)
                     return
                 # Deliberately restates FleetIngest._deliver_direct
                 # minus its emit hop: calling deliver() directly here
@@ -750,17 +760,20 @@ class ZKConnection(FSM):
             else:
                 self.faults.rx(self, data)
 
-    def rx_mark(self) -> tuple:
+    def rx_mark(self, t_rx: int = 0) -> tuple:
         """What a reply settled now knows of its way in (profiler
         sessions only; ``ZKRequest.settle``'s ``rx``): the start of
-        this connection's newest ``_sock_data`` call — the call that
-        completed the reply of a connection with one request in
-        flight; on a pipelined connection whose replies came in
-        several calls before one tick, an earlier reply reads the
-        later call — and the number of the ``ingest.tick`` whose route
-        is delivering it, None off the device."""
+        the ``_sock_data`` call that brought its last byte — ``t_rx``
+        where the ingest's route says which call that was (a
+        pipelined connection's replies of one tick may have come in
+        several), else this connection's newest call, which is that
+        call wherever a reply is decoded inside the call that
+        completed it (the scalar drain, the pass-through regime) —
+        and the number of the ``ingest.tick`` whose route is
+        delivering it, None off the device."""
         ing = self.ingest
-        return self._rx_t0, None if ing is None else ing.routing
+        return (t_rx or self._rx_t0,
+                None if ing is None else ing.routing)
 
     def _tx_write(self, data: bytes) -> None:
         """The send plane's sink: one coalesced buffer per flush."""
@@ -789,9 +802,12 @@ class ZKConnection(FSM):
             return
         self._tx.send(data)
 
-    def process_reply(self, pkt: dict) -> None:
+    def process_reply(self, pkt: dict, t_rx: int = 0) -> None:
         """Route a reply to its pending request
-        (reference: lib/connection-fsm.js:353-376)."""
+        (reference: lib/connection-fsm.js:353-376).  ``t_rx``: where
+        the ingest's route knows which receive call brought this
+        reply's last byte (profiler sessions only), that call's start;
+        else 0 — the connection's newest call (:meth:`rx_mark`)."""
         xid = pkt['xid']
         if xid > 0:
             # One reply settles a normal request; dropping it here
@@ -804,7 +820,7 @@ class ZKConnection(FSM):
         self.log.trace('server replied to xid %d err %s',
                        xid, pkt['err'])
         if req is not None:
-            req.settle(pkt, self.rx_mark() if self._rx_t0 else None)
+            req.settle(pkt, self.rx_mark(t_rx) if self._rx_t0 else None)
 
     def request(self, pkt: dict, span=None) -> ZKRequest:
         """Send a normal (positive-xid) request

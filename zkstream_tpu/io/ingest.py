@@ -339,10 +339,24 @@ class FleetIngest:
         alloc.keep_freed_memory()
         # ...and a device tick allocates a reply dict and a Stat a
         # frame in one burst: the collector's young generation follows
-        # the registered slots (utils/alloc.py), from here to close()
-        self._gc_slots = 0
+        # the requests the fleet has alive (utils/alloc.py) — its
+        # registered slots, and where its clients pipeline what is
+        # pending on them (:meth:`_fit_collector`) — from here to
+        # close().  ``_gc_slots``: the slots when they last doubled or
+        # halved; ``_gc_fit``: what the collector is sized to now;
+        # ``_gc_look``: a tick that routes more frames than this looks
+        # at the requests pending again.
+        self._gc_slots = self._gc_fit = self._gc_look = 0
         self._closed = False
         alloc.fit_collector(self, 0)
+        #: Profiler sessions only (empty outside one): id(conn) ->
+        #: ``[[end, t_ns], ...]``, one mark a receive call that fed the
+        #: slot — the slot's length once the call's bytes were in it,
+        #: and the call's start on ``time.perf_counter_ns`` — so the
+        #: route can hand every reply the call that brought ITS last
+        #: byte (:meth:`_reply_times`), however many replies of one
+        #: connection came in how many calls before one tick.
+        self._rx_marks: dict[int, list] = {}
         #: a tick is queued on the loop / something came up that the
         #: next tick's first half must look at (bytes fed, a follow-up)
         self._scheduled = False
@@ -400,6 +414,21 @@ class FleetIngest:
         self.bytes_dispatched = 0
         self.bytes_recopied = 0
         self.slots_deferred = 0
+        #: What a fleet whose clients pipeline meets, and one request
+        #: a session never does: streams that gave a tick the whole
+        #: frame bound (``max_frames`` frames from one row: the scan's
+        #: every cursor step found one), slots that held more than the
+        #: tick took of them (the power of two over ``max_frames`` x
+        #: their first frame), and the device ticks that scheduled a
+        #: follow-up because of either — a stream at the bound with
+        #: bytes left behind it, or a cut slot.
+        self.slots_bound = 0
+        self.slots_cut = 0
+        self.reticks = 0
+        #: the device tick being built or in flight has a follow-up
+        #: scheduled for a cut or the bound (``reticks`` counts it when
+        #: it has routed)
+        self._retick = False
         #: device ticks that left whole frames in their slots for the
         #: follow-up tick because the tick's batch memory
         #: (``TICK_BYTES``) was full
@@ -498,10 +527,13 @@ class FleetIngest:
     def register(self, conn: 'ZKConnection', lane=None) -> None:
         """Give ``conn`` a slot until :meth:`unregister`.  ``lane`` is
         the one callable its state ``connected`` hands over
-        (io/connection.py): ``lane(pkts, err, now) -> int`` takes a
-        routed stream's packets, its decode error if any and the
-        tick's ``time.monotonic()``, delivers them in stream order and
-        returns how many it settled itself.  The slot holds it and
+        (io/connection.py): ``lane(pkts, err, now, times) -> int``
+        takes a routed stream's packets, its decode error if any, the
+        tick's ``time.monotonic()`` and — inside a profiler session,
+        else None — for each packet the start of the receive call that
+        brought its last byte (:meth:`_reply_times`), delivers them in
+        stream order and returns how many it settled itself.  The slot
+        holds it and
         ``unregister`` (the state's exit) drops it, so it is never
         called outside that state.  Without one the stream goes out
         as the connection's ``'ingestDeliver'`` event, as the scalar,
@@ -522,19 +554,36 @@ class FleetIngest:
                 self._schedule()
         self._fit_collector()
 
-    def _fit_collector(self) -> None:
-        """The registered slots doubled or halved since the collector's
-        young generation was sized to them: size it again."""
+    def _fit_collector(self, routed: int = 0) -> None:
+        """Size the collector's young generation to the requests this
+        fleet has alive, when that may have changed by a factor of
+        two.  The registered slots doubled or halved since it was
+        sized: to the slots — a frame a slot is the most a tick's
+        burst holds while every session keeps one request outstanding.
+        A tick routed ``routed`` frames, more than any look has found
+        alive: the clients pipeline, and the requests alive are what
+        is pending on the slots' connections and what that tick just
+        settled; sized to them once they are twice what stands.  It
+        grows with the traffic and comes down with the slots."""
         n, was = len(self._slots), self._gc_slots
-        if (n >= 2 * was or 2 * n <= was) and n != was \
-                and not self._closed:
-            self._gc_slots = n
+        if self._closed:
+            return
+        if (n >= 2 * was or 2 * n <= was) and n != was:
+            self._gc_slots = self._gc_fit = self._gc_look = n
             alloc.fit_collector(self, n)
+        elif routed > self._gc_look:
+            alive = self._gc_look = routed + sum(
+                len(getattr(slot[0], 'reqs', ()))
+                for slot in self._slots.values())
+            if alive >= 2 * self._gc_fit:
+                self._gc_fit = alive
+                alloc.fit_collector(self, alive)
 
     def unregister(self, conn: 'ZKConnection') -> None:
         slot = self._slots.pop(id(conn), None)
         self._fit_collector()
         self._no_hold.discard(id(conn))
+        self._rx_marks.pop(id(conn), None)
         held = self._held.pop(id(conn), None)
         if held is not None and slot is not None:
             slot[1].extend(held)     # withheld suffix rejoins in order
@@ -543,7 +592,11 @@ class FleetIngest:
         if slot is not None and slot[1] and conn.codec is not None:
             conn.codec.restore_pending(bytes(slot[1]))
 
-    def feed(self, conn: 'ZKConnection', data: bytes) -> None:
+    def feed(self, conn: 'ZKConnection', data: bytes,
+             t_rx: int = 0) -> None:
+        """``conn`` received ``data``.  ``t_rx``: inside a profiler
+        session, the start of the receive call that brought it (the
+        connection's stage stamp, ``time.perf_counter_ns``); else 0."""
         slot = self._slots.get(id(conn))
         if slot is None:  # raced a teardown; the bytes die with the conn
             return
@@ -562,6 +615,11 @@ class FleetIngest:
             self._held[id(conn)] += data
         else:
             slot[1].extend(data)
+            if t_rx:
+                self._rx_marks.setdefault(id(conn), []).append(
+                    [len(slot[1]), t_rx])
+            elif self._rx_marks:
+                self._rx_marks.clear()      # the session is over
         self._schedule()
         if not self._asked:
             # fed by a reap: the batch is dispatched when the reap has
@@ -862,6 +920,16 @@ class FleetIngest:
                 ('zkstream_ingest_deferred_slots', 'slots_deferred',
                  'slot-ticks sat out because the slot\'s first frame '
                  'was not whole yet'),
+                ('zkstream_ingest_bound_slots', 'slots_bound',
+                 'streams that gave a device tick max_frames frames, '
+                 'the per-tick bound of one row'),
+                ('zkstream_ingest_cut_slots', 'slots_cut',
+                 'slots that held more bytes than the tick took of '
+                 'them (they finish on the follow-up tick)'),
+                ('zkstream_ingest_reticks', 'reticks',
+                 'device ticks that scheduled a follow-up tick for a '
+                 'stream at the frame bound with more buffered, or '
+                 'for a cut slot'),
                 ('zkstream_ingest_full_ticks', 'ticks_full',
                  'device ticks that left whole frames in their slots '
                  'because the tick\'s batch memory was full'),
@@ -1056,6 +1124,7 @@ class FleetIngest:
         drains slot buffers, so a tail left in ``_held`` across the
         flip would strand, then reorder behind fresh rx bytes."""
         self._release_held()
+        self._rx_marks.clear()
         for conn, buf, _lane in active:
             if id(conn) not in self._slots:
                 continue
@@ -1214,9 +1283,12 @@ class FleetIngest:
         drained: the fragmentation EMA fed, the withheld suffixes back
         in their slots."""
         if drained:
-            self._note_frames(self.frames_routed - before)
+            frames = self.frames_routed - before
+            self._note_frames(frames)
             self._frames_mark = self.frames_routed
-            sp.set(batch=self.frames_routed - before)
+            sp.set(batch=frames)
+            if frames > self._gc_look:
+                self._fit_collector(frames)
         if self._release_held():
             self._schedule()     # finish the withheld suffixes
 
@@ -1286,7 +1358,7 @@ class FleetIngest:
         # frame can have goes to the device, which flags the stream.
         min_len, frames = self.min_len, self.max_frames
         streams, sizes = [], []
-        cut = False
+        cut = 0
         for slot in active:
             buf = slot[1]
             have = len(buf)
@@ -1299,10 +1371,11 @@ class FleetIngest:
             if have > min_len:
                 have = min(have, min_len if n > _FRAME_TOP
                            else self._width(n * frames))
-                cut = cut or have < len(buf)
+                cut += have < len(buf)
             streams.append(slot)
             sizes.append(have)
         if cut:
+            self.slots_cut += cut
             self._schedule()    # a slot holds more than it gave
         if not streams:
             return ()
@@ -1383,6 +1456,7 @@ class FleetIngest:
             sp.set(tick=None, detail=scalar[0][1])
             return None
         self.ticks += 1
+        self._retick = cut > 0
         if sp is not NO_SPAN:
             _bodies, Bp, L = plans[0][1]
             rows = sum(len(p[2]) for p in plans)
@@ -1390,7 +1464,7 @@ class FleetIngest:
                            if len(plans) == 1 else
                            'device %d dispatches streams=%d'
                            % (len(plans), rows)),
-                   nbytes=sum(p[5] for p in plans))
+                   nbytes=sum(p[5] for p in plans), cut=cut)
         return plans
 
     def _dispatch(self, plans, before: int, t0: float) -> _Flight:
@@ -1440,6 +1514,7 @@ class FleetIngest:
                 laned = emitted = 0
                 names = self.names_routed
                 lists, shared = self.lists_routed, self.lists_shared
+                bound = self.slots_bound
                 self.routing = n
                 try:
                     for plan, ints in zip(plans, results):
@@ -1457,6 +1532,12 @@ class FleetIngest:
                         names=self.names_routed - names,
                         lists=self.lists_routed - lists,
                         shared=self.lists_shared - shared)
+            # what a pipelined tick did (profiler sessions: on the
+            # ``ingest.tick`` span, beside the first half's ``cut``):
+            # rows at the frame bound, a follow-up left for either
+            self.reticks += self._retick
+            sp.set(bound=self.slots_bound - bound,
+                   retick=int(self._retick))
             t4 = time.perf_counter()
         finally:
             self._end_tick(True, flight.before, sp)
@@ -1495,6 +1576,7 @@ class FleetIngest:
         if decoded is not None:
             lens, maps, flat, counts, errors = decoded
         slots = self._slots
+        rx_marks = self._rx_marks
         max_frames = self.max_frames
         laned = routed = pos = 0
         retick = False
@@ -1531,6 +1613,8 @@ class FleetIngest:
             else:
                 err = self._decode_error(errors[i]) if i in errors \
                     else None
+            times = (self._reply_times(id(conn), st, rows[i], n, resids[i])
+                     if rx_marks else None)
             if resids[i]:
                 del buf[:resids[i]]
             self.frames_routed += n
@@ -1544,12 +1628,40 @@ class FleetIngest:
                 if lane is None:
                     conn.emit('ingestDeliver', pkts, err)
                 else:
-                    laned += lane(pkts, err, now)
-            if err is None and n == max_frames and len(buf) >= 4:
-                retick = True   # the frame bound was hit: more may wait
+                    laned += lane(pkts, err, now, times)
+            if n == max_frames:
+                self.slots_bound += 1
+                if err is None and len(buf) >= 4:
+                    retick = True   # at the frame bound: more may wait
         if retick:
+            self._retick = True
             self._schedule()
         return laned, routed - laned
+
+    def _reply_times(self, cid: int, st, row: int, n: int,
+                     resid: int) -> list | None:
+        """Profiler sessions only: for each of the ``n`` frames the
+        tick found in row ``row`` of ``st``, the start of the receive
+        call that brought the frame's LAST byte — the first of the
+        slot's marks (``_rx_marks``) that reaches the frame's end, the
+        newest where bytes came unmarked — in frame order; the marks
+        then follow the slot, which the route cuts by ``resid``.  None
+        for a slot no armed call has fed."""
+        marks = self._rx_marks.get(cid)
+        if not marks:
+            return None
+        times = []
+        j, last = 0, len(marks) - 1
+        for end in (st.starts[row, :n] + st.sizes[row, :n]).tolist():
+            while j < last and marks[j][0] < end:
+                j += 1
+            times.append(marks[j][1])
+        left = [[end - resid, t] for end, t in marks if end > resid]
+        if left:
+            self._rx_marks[cid] = left
+        else:
+            del self._rx_marks[cid]
+        return times
 
     def _decode_batch(self, streams, n_frames, resids, bads):
         """The C fast path: every stream's device-delimited
@@ -1614,6 +1726,7 @@ class FleetIngest:
         the point; the stream is about to die)."""
         data, err, pkts = bytes(buf), None, []
         buf.clear()
+        self._rx_marks.pop(id(conn), None)
         try:
             pkts = conn.codec.decode(data)
         except ZKProtocolError as e:

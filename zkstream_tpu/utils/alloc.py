@@ -20,7 +20,7 @@ process's resident size stays at its peak.
 
 **The collector.**  One device tick of a fleet's ingest allocates its
 replies in a burst — a packet ``dict`` and a ``Stat`` a frame, up to a
-frame a session, nothing freed in between — and 700 net container
+frame a request alive, nothing freed in between — and 700 net container
 allocations (the interpreter's young-generation threshold) start a
 collection: at 1,024 sessions that was ONE collection a tick, ~2.3 ms
 each, 8.6-8.8% of a window (PERF.md, PR 43), and every one of them
@@ -30,8 +30,13 @@ die by reference count when it completes (tests/test_ingest_gc.py
 pins that a read leaves no cyclic garbage) — and promoted them.  A
 process that holds a ``FleetIngest`` therefore sizes the young
 generation to its fleet (:func:`fit_collector`):
-``max(interpreter default, YOUNG_PER_SLOT x registered slots)``,
-derived again when the slot count doubles or halves, the older
+``max(interpreter default, YOUNG_PER_SLOT x requests alive)`` — the
+registered slots while every session keeps one request outstanding,
+derived again when the slot count doubles or halves; where the
+clients pipeline, the requests the ingest finds pending on its slots'
+connections when a tick routes more frames than it was sized to
+(``FleetIngest._fit_collector``: 8,192 at 1,024 sessions x 8
+outstanding; nothing to configure) — the older
 generations' thresholds as they were, put back when the process's
 last ingest closes.  It is applied only while ``gc.get_threshold()``
 reads an interpreter default or what this module set: an owner who
@@ -77,20 +82,23 @@ def keep_freed_memory() -> bool:
     return _done
 
 
-#: Net container allocations the young generation allows a registered
-#: slot before a collection: a tick's burst is about two a frame and
-#: at most a frame a slot at one request outstanding, so the burst and
-#: what the woken operations allocate before the next tick's stay
-#: under it.  Fitted on the chip at 1,024 sessions against its two
-#: neighbours (PERF.md section 5, PR 43).
+#: Net container allocations the young generation allows a request
+#: alive before a collection: a tick's burst is about two a frame and
+#: at most a frame a request alive — a frame a registered slot where
+#: every session keeps one request outstanding, which is what the name
+#: says and what it was fitted at; ``max_frames`` a slot where the
+#: clients pipeline — so the burst and what the woken operations
+#: allocate before the next tick's stay under it.  Fitted on the chip
+#: at 1,024 sessions x 1 against its two neighbours (PERF.md section
+#: 5, PR 43), held at 1,024 x 8 (section 6, PR 47).
 YOUNG_PER_SLOT = 32
 
 #: What CPython starts with (3.8-3.12; 3.13; 3.14): thresholds an
 #: owner has not touched.
 INTERPRETER_DEFAULTS = ((700, 10, 10), (2000, 10, 10), (2000, 10, 0))
 
-#: holder (a ``FleetIngest``) -> the slots it reported last; weak, so
-#: an ingest dropped without ``close()`` leaves the sum
+#: holder (a ``FleetIngest``) -> the requests alive it reported last;
+#: weak, so an ingest dropped without ``close()`` leaves the sum
 _fleets: weakref.WeakKeyDictionary = weakref.WeakKeyDictionary()
 #: the default found before the first raise, and what stands set now
 #: (None: nothing of this module's stands)
@@ -98,11 +106,13 @@ _found: tuple | None = None
 _set: tuple | None = None
 
 
-def fit_collector(holder, slots: int) -> None:
-    """``holder`` (a fleet ingest) has ``slots`` registered slots now —
-    it calls at construction and whenever the count doubled or halved:
-    size the young generation to the process's fleets."""
-    _fleets[holder] = slots
+def fit_collector(holder, alive: int) -> None:
+    """``holder`` (a fleet ingest) can have ``alive`` requests alive
+    now: its registered slots, or what it found pending on them where
+    its clients pipeline — it calls at construction and whenever that
+    doubled or halved: size the young generation to the process's
+    fleets."""
+    _fleets[holder] = alive
     _derive()
 
 

@@ -110,7 +110,7 @@ TRACE_SCHEMA = 3
 _OPTIONAL_FIELDS = ('path', 'xid', 'zxid', 'backend', 'session_id',
                     'member', 'batch', 'nbytes', 'detail', 'error',
                     'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted',
-                    'rows', 'width', 'names')
+                    'rows', 'width', 'names', 'bound', 'cut', 'retick')
 
 
 class Span:
@@ -135,7 +135,10 @@ class Span:
     and ``names``, the names in the children lists it routed;
     ``rows`` / ``width`` — ``ingest.dispatch`` only: the streams in
     the dispatch and the width of its size class (``nbytes``: their
-    payload)."""
+    payload); ``bound`` / ``cut`` / ``retick`` — a device tick's
+    ``ingest.tick`` only: the rows that gave the tick the whole frame
+    bound (``max_frames`` frames), the slots that held more than they
+    gave, and 1 where the tick left a follow-up tick for either."""
 
     duration_ms: float | None = None
     #: Armed by a ring with a slow-op threshold: called once with the
