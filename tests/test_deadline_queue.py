@@ -59,6 +59,11 @@ def last_span(c: Client, op: str) -> dict:
 
 # -- the queue alone ---------------------------------------------------
 
+def _held(queue: DeadlineQueue) -> list:
+    """Every future the queue still refers to."""
+    return [f for lane in queue._lanes.values() for f in lane]
+
+
 async def test_thousand_waits_arm_one_timer_and_hold_nothing():
     loop = CountingLoop(asyncio.get_running_loop())
     queue = DeadlineQueue(loop)
@@ -68,20 +73,20 @@ async def test_thousand_waits_arm_one_timer_and_hold_nothing():
     refs = [weakref.ref(f) for f in futs]
     for f, e in zip(futs, entries):
         f.set_result({'data': b'x' * 1024})
-        queue.discard(e)
-        queue.discard(e)                    # idempotent
+        queue.discard(e, f)
+        queue.discard(e, f)                 # idempotent
     del futs, f
     gc.collect()
     # no future (nor the reply it holds) outlives its wait
     assert [r for r in refs if r() is not None] == []
-    # the heap was compacted on the way (O(1) timers each time), and
-    # the compaction that left nothing live cancelled the timer
-    assert len(queue) == 0 and len(queue._heap) <= queue.COMPACT_MIN
-    assert loop.armed <= 1000 // queue.COMPACT_MIN
+    assert len(queue) == 0 and _held(queue) == []
+    # nothing moved the timer: it stands for a time no later than any
+    # live deadline, fires once for nothing and is not armed again
+    assert loop.armed == 1
     entries.clear()
 
 
-async def test_steady_traffic_keeps_the_heap_near_what_is_live():
+async def test_steady_traffic_keeps_the_queue_at_what_is_live():
     loop = CountingLoop(asyncio.get_running_loop())
     queue = DeadlineQueue(loop)
     live = []
@@ -91,34 +96,44 @@ async def test_steady_traffic_keeps_the_heap_near_what_is_live():
         if len(live) > 64:
             f0, e0 = live.pop(0)
             f0.set_result(None)
-            queue.discard(e0)
-        assert len(queue._heap) <= 2 * len(live) + queue.COMPACT_MIN + 1
-    # a timer per compaction, not per request
-    assert loop.armed < 20_000 // 50
+            queue.discard(e0, f0)
+        assert len(queue) == len(live) == len(_held(queue))
+    # one timer, not one a request nor one a compaction
+    assert loop.armed == 1
     for f, e in live:
         f.cancel()
-        queue.discard(e)
+        queue.discard(e, f)
 
 
-async def test_mixed_deadlines_fire_in_order_and_never_early():
+@pytest.mark.parametrize('order', [(50, 5, 120), (5, 50, 120),
+                                   (120, 50, 5, 50, 5, 120, 80)])
+async def test_mixed_deadlines_fire_in_order_and_never_early(order):
+    """A FIFO a distinct timeout, and the timer at the least of their
+    heads: whatever the order the waits were added in, they come due
+    in deadline order — within one timeout, in the order added."""
     loop = asyncio.get_running_loop()
     queue = DeadlineQueue(loop)
     fired = []
-    t0 = loop.time()
-    futs = {}
-    for ms in (50, 5, 120):
-        f = futs[ms] = loop.create_future()
+    futs = []
+    for n, ms in enumerate(order):
+        f = loop.create_future()
+        t0 = loop.time()
         f.add_done_callback(
-            lambda f, ms=ms: fired.append((ms, loop.time() - t0)))
+            lambda f, n=n, ms=ms, t0=t0: fired.append(
+                (ms, n, loop.time() - t0)))
         queue.add(f, ms / 1000.0)
+        futs.append(f)
     unbounded = loop.create_future()        # deadline=None: never added
     await asyncio.sleep(0.3)
-    assert [ms for ms, _ in fired] == [5, 50, 120]
-    for ms, at in fired:
+    assert [(ms, n) for ms, n, _ in fired] == sorted(
+        (ms, n) for n, ms in enumerate(order))
+    for ms, _n, at in fired:
         assert ms / 1000.0 <= at < ms / 1000.0 + SLACK_S
-        assert isinstance(futs[ms].exception(), DeadlineExpired)
+    assert all(isinstance(f.exception(), DeadlineExpired) for f in futs)
     assert not unbounded.done()
+    # every timeout left the table with its last wait
     assert len(queue) == 0 and queue._timer is None
+    assert queue._lanes == {}
 
 
 async def test_earlier_deadline_moves_the_timer_and_settled_is_skipped():
@@ -127,12 +142,99 @@ async def test_earlier_deadline_moves_the_timer_and_settled_is_skipped():
     slow, fast = loop.create_future(), loop.create_future()
     queue.add(slow, 30.0)
     e_fast = queue.add(fast, 0.02)          # re-arms: now the head
-    assert queue._timer.when() == e_fast[0]
+    assert queue._timer.when() == e_fast[fast] == queue._when
     fast.set_result('in time')              # settled, not yet discarded
     await asyncio.sleep(0.06)
     assert fast.result() == 'in time' and not slow.done()
     assert queue._timer is not None         # moved on to ``slow``
+    assert _held(queue) == [slow]
     slow.cancel()
+
+
+async def test_a_discarded_entry_releases_its_future_at_once():
+    """Discarded from the middle of a FIFO whose other waits go on:
+    the queue's reference is gone with the call, not at some later
+    sweep."""
+    loop = asyncio.get_running_loop()
+    queue = DeadlineQueue(loop)
+    futs = [loop.create_future() for _ in range(5)]
+    entries = [queue.add(f, 30.0) for f in futs]
+    mid = futs[2]
+    ref = weakref.ref(mid)
+    mid.set_result({'data': b'x' * 1024})
+    queue.discard(entries[2], mid)
+    del mid, futs[2]
+    gc.collect()
+    assert ref() is None
+    assert _held(queue) == futs and len(queue) == 4
+    for f, e in zip(futs, [entries[i] for i in (0, 1, 3, 4)]):
+        f.cancel()
+        queue.discard(e, f)
+
+
+async def test_a_discarded_head_costs_one_early_firing_and_no_more():
+    """The wait the timer was armed for is discarded: the timer stays
+    where it is (a time no later than any live deadline), fires once
+    for nothing, moves to the oldest wait still live — which then
+    fires on time — and with nothing live is not armed again."""
+    loop = CountingLoop(asyncio.get_running_loop())
+    queue = DeadlineQueue(loop)
+    head, live = loop.create_future(), loop.create_future()
+    e_head = queue.add(head, 0.03)
+    t0 = loop.time()
+    queue.add(live, 0.09)
+    assert loop.armed == 1
+    head.set_result(None)
+    queue.discard(e_head, head)
+    assert loop.armed == 1 and queue._when == pytest.approx(t0 + 0.03,
+                                                            abs=0.01)
+    await asyncio.sleep(0.05)               # the stale firing
+    assert loop.armed == 2 and not live.done()
+    assert queue._timer.when() == queue._when >= t0 + 0.09
+    with pytest.raises(DeadlineExpired):
+        await live
+    assert 0.09 <= loop.time() - t0 < 0.09 + SLACK_S
+    await asyncio.sleep(0)
+    assert loop.armed == 2 and queue._timer is None
+    assert queue._when == float('inf') and queue._lanes == {}
+
+
+async def test_timeouts_nothing_waits_under_are_swept():
+    """A caller that hands every op the rest of its own budget makes
+    a timeout an op: the table of FIFOs stays near the waits alive."""
+    loop = CountingLoop(asyncio.get_running_loop())
+    queue = DeadlineQueue(loop)
+    for n in range(5000):
+        f = loop.create_future()
+        e = queue.add(f, 30.0 + n / 1000.0)
+        f.set_result(None)
+        queue.discard(e, f)
+        assert len(queue._lanes) <= 2 * queue.SWEEP_MIN
+    assert len(queue) == 0 and loop.armed == 1
+    # an entry handed out before its FIFO was swept still discards
+    f = loop.create_future()
+    e = queue.add(f, 1.0)
+    for n in range(3 * queue.SWEEP_MIN):
+        queue._new_lane(100.0 + n)
+    queue.discard(e, f)
+    assert len(queue) == 0
+
+
+async def test_equal_timeouts_arm_no_more_timers_than_the_heap_did():
+    """1,024 waits under one timeout, added and discarded in rounds as
+    a fleet's closed loop does: the heap this queue had re-armed at
+    every compaction (one a ``COMPACT_MIN`` = 64 discards: 1 + 16
+    armings here); the FIFO arms once."""
+    loop = CountingLoop(asyncio.get_running_loop())
+    queue = DeadlineQueue(loop)
+    for _ in range(4):
+        futs = [loop.create_future() for _ in range(256)]
+        entries = [queue.add(f, 30.0) for f in futs]
+        for f, e in zip(futs, entries):
+            f.set_result(None)
+            queue.discard(e, f)
+    assert loop.armed == 1 <= 1 + 1024 // 64
+    assert len(queue) == 0
 
 
 def test_each_loop_has_its_own_queue():
@@ -187,7 +289,7 @@ async def test_unbounded_op_arms_nothing(server):
         await c.create('/u', b'x')
         assert (await c.get('/u'))[0] == b'x'
         assert (await c.get('/u', deadline=None))[0] == b'x'
-        assert counting.armed == 0 and not queue._heap
+        assert counting.armed == 0 and len(queue) == 0
         assert (await c.get('/u', deadline=1000))[0] == b'x'
         assert counting.armed == 1
     finally:
@@ -207,11 +309,11 @@ async def test_thousand_ops_on_one_loop_share_the_loop_timer(server):
                 c.get('/k', deadline=30000)
                 for c in clients for _ in range(50)])
             assert all(data == b'v' * 1024 for data, _stat in got)
-        # 1,000 requests with deadlines: a handful of timers
-        assert counting.armed <= 1000 // queue.COMPACT_MIN + 1
+        # 1,000 requests with deadlines: one timer
+        assert counting.armed == 1
         assert len(queue) == 0
         gc.collect()
-        assert [e for e in queue._heap if e[2] is not None] == []
+        assert _held(queue) == []
         assert all(not c.current_connection().reqs for c in clients)
     finally:
         queue.loop = loop

@@ -50,8 +50,8 @@ from .utils.aio import DeadlineExpired, ambient_loop, deadline_queue
 from .utils.fsm import FSM, bind_transition_metrics
 from .utils.logging import Logger
 from .utils.metrics import Collector
-from .utils.trace import NO_SPAN, TraceRing, host_add, host_span, \
-    op_resumed
+from .utils.trace import NO_SPAN, TraceRing, armed as session_armed, \
+    host_add, host_span, op_resumed, op_submitted
 
 METRIC_ZK_EVENT_COUNTER = 'zookeeper_events'
 METRIC_ZK_DEGRADED_GAUGE = 'zookeeper_degraded'
@@ -172,6 +172,12 @@ class Client(FSM):
         self._op_latency = self.collector.histogram(
             METRIC_ZK_OP_LATENCY,
             'Client op round-trip latency, milliseconds, by opcode')
+        #: opcode -> that histogram's series, bound on the opcode's
+        #: first op (utils/metrics.BoundSeries)
+        self._op_series: dict = {}
+        #: the running loop's deadline queue, looked up by the first
+        #: bounded op (utils/aio.deadline_queue)
+        self._deadlines = None
         #: Bounded in-memory span ring (utils/trace.py): one span per
         #: op, xid-correlated through the connection and stamped with
         #: the reply zxid.  Injectable so chaos campaigns and tests can
@@ -553,7 +559,8 @@ class Client(FSM):
 
     # -- operations (reference: lib/client.js:318-601) --
 
-    def _start_op(self, conn: ZKConnection, pkt: dict) -> tuple:
+    def _start_op(self, conn: ZKConnection, pkt: dict,
+                  armed: bool | None = None) -> tuple:
         """Send one traced request: the span is created before the
         write, correlated by the xid the connection assigns, and closed
         by the connection's reply/error routing (io/connection.py) with
@@ -567,35 +574,43 @@ class Client(FSM):
 
         Host span ``client.submit`` (profiler sessions only; count
         and total, no object per op): from here until the encoded
-        request is with the connection's send plane.  Its one
-        ``is_enabled()`` is the op's answer for everything after: an
-        op submitted inside a session carries ``span.stages``, the
-        stamps of its way out and back (utils/trace.py), and nothing
-        else looks the session up for it until it resumes."""
-        sub = host_span('client.submit', accumulate=True)
-        with sub:
-            span = self.trace.start(pkt['opcode'], pkt.get('path'))
-            if sub is not NO_SPAN:
+        request is with the connection's send plane.  ``armed`` is
+        the op's ONE look for a session (``_primary_request`` made it
+        for ``client.prepare``; None: made here) and its answer for
+        everything after: an op submitted inside a session carries
+        ``span.stages``, the stamps of its way out and back
+        (utils/trace.py), and nothing else looks the session up for
+        it until it resumes."""
+        if armed is None:
+            armed = session_armed()
+        sub = op_submitted() if armed else None
+        span = self.trace.start(pkt['opcode'], pkt.get('path'))
+        try:
+            if sub is not None:
                 span.stages = [sub.t0_ns, 0, 0, 0]
-            try:
-                req = conn.request(pkt, span)
-            except BaseException as e:
-                span.finish(status='abandoned',
-                            error=getattr(e, 'code', None)
-                            or type(e).__name__)
-                raise
+            req = conn.request(pkt, span)
+        except BaseException as e:
+            span.finish(status='abandoned',
+                        error=getattr(e, 'code', None)
+                        or type(e).__name__)
+            raise
+        finally:
+            if sub is not None:
+                sub.__exit__(None, None, None)
+        # the request is already pending here — it took the span with
+        # it — so the connection settles this span on every teardown
+        # path.  Where it comes from and whose it is change once a
+        # connection, which keeps both as it stamps them
         span.xid = pkt['xid']
-        span.backend = conn.backend.key
-        if conn.session is not None:
-            # the request is already pending here — it took the span
-            # with it — so the connection settles this span on every
-            # teardown path
-            span.session_id = conn.session.get_session_id()
+        span.backend = conn.span_backend
+        span.session_id = conn.span_session_id
         return req.as_future(), span
 
     async def _await_op(self, fut: asyncio.Future, opcode: str,
                         path: str | None, deadline, span=None) -> dict:
-        """Bound one request future by the per-request deadline.
+        """Bound one request future by the per-request deadline: the
+        ONE coroutine frame between an API call and the future it
+        awaits.
 
         ``deadline`` is the per-op override in ms (``_USE_DEFAULT`` =
         the client's ``op_timeout``; ``None`` = unbounded, nothing
@@ -618,7 +633,13 @@ class Client(FSM):
         entry = None
         try:
             if ms is not None:
-                queue = deadline_queue(asyncio.get_running_loop())
+                # the loop's queue, kept: a client that is driven by
+                # a second loop (one ``asyncio.run`` after another)
+                # finds that loop's
+                queue = self._deadlines
+                loop = fut.get_loop()
+                if queue is None or queue.loop is not loop:
+                    queue = self._deadlines = deadline_queue(loop)
                 entry = queue.add(fut, ms / 1000.0)
             return await fut
         except DeadlineExpired:
@@ -631,9 +652,12 @@ class Client(FSM):
                       else op_resumed(span))
             try:
                 if entry is not None:
-                    queue.discard(entry)
-                self._op_latency.observe(
-                    (time.monotonic() - t0) * 1000.0, {'op': opcode})
+                    queue.discard(entry, fut)
+                series = self._op_series.get(opcode)
+                if series is None:
+                    series = self._op_series[opcode] = \
+                        self._op_latency.labels({'op': opcode})
+                series.observe((time.monotonic() - t0) * 1000.0)
                 if self.on_op is not None and span is not None:
                     self.on_op(span)
             finally:
@@ -653,29 +677,42 @@ class Client(FSM):
                                             sess.gate_floor)
         return max(sess_z, self._read_floor)
 
-    async def _primary_request(self, pkt: dict, opcode: str,
-                               path: str | None, deadline,
-                               prep=None) -> dict:
-        """One request on the primary connection (the legacy path):
-        returns the full reply packet.
+    def _primary_request(self, pkt: dict, opcode: str,
+                         path: str | None, deadline, prep=None):
+        """One request on the primary connection: the awaitable that
+        resolves to the full reply packet.
+
+        One pass: the connection is looked up and the request sent
+        HERE, in the caller's own frame (an ``await
+        self._primary_request(...)`` runs this when it evaluates the
+        call, exactly where a coroutine's first step would); what is
+        handed back to await is ``_await_op``'s one coroutine.
 
         Host span ``client.prepare`` (profiler sessions only; count
         and total): an API call's own work before ``_start_op`` —
-        opened here, or by ``_read_request`` in front of its cache and
+        opened here, or by ``_routed_read`` in front of its cache and
         read-plane routing and handed on still open (``prep``:
         nothing awaits in between), closed once the connection is
-        looked up."""
+        looked up.  Asking for it is the op's ONE look for a profiler
+        session."""
         if prep is None:
             prep = host_span('client.prepare', accumulate=True)
             if prep is not NO_SPAN:
                 prep.__enter__()
         try:
-            conn = self._conn_or_raise()
+            # the usual case read straight off the three machines;
+            # anything else (a session to replace, a sub-state, not
+            # connected) is ``_conn_or_raise``'s
+            sess = self.session
+            conn = (sess.conn if self._state == 'normal'
+                    and sess._state == 'attached' else None)
+            if conn is None or conn._state != 'connected':
+                conn = self._conn_or_raise()
         finally:
             if prep is not NO_SPAN:
                 prep.__exit__(None, None, None)
-        fut, span = self._start_op(conn, pkt)
-        return await self._await_op(fut, opcode, path, deadline, span)
+        fut, span = self._start_op(conn, pkt, prep is not NO_SPAN)
+        return self._await_op(fut, opcode, path, deadline, span)
 
     async def _write_op(self, pkt: dict, opcode: str,
                         path: str | None, deadline) -> dict:
@@ -723,9 +760,20 @@ class Client(FSM):
         if sess is not None and zxid > sess.gate_floor:
             sess.gate_floor = zxid
 
-    async def _read_request(self, pkt: dict, opcode: str,
-                            path: str | None, deadline) -> dict:
-        """Route one read: through the read plane when enabled —
+    def _read_request(self, pkt: dict, opcode: str,
+                      path: str | None, deadline):
+        """Route one read: the awaitable that resolves to the reply
+        packet.  With no cache plane and no read plane nothing stands
+        between a read and the primary connection
+        (``_primary_request``'s one pass); either of them has
+        ``_routed_read``."""
+        if self.cache is None and self._read_plane is None:
+            return self._primary_request(pkt, opcode, path, deadline)
+        return self._routed_read(pkt, opcode, path, deadline)
+
+    async def _routed_read(self, pkt: dict, opcode: str,
+                           path: str | None, deadline) -> dict:
+        """One read past the planes: through the read plane when enabled —
         zxid-gated, so a reply from a member behind this client's
         floor (re-checked at REPLY time: a write acked while the
         read was in flight raises it) is DISCARDED and the read
@@ -823,7 +871,7 @@ class Client(FSM):
         loop = ambient_loop()
         fut: asyncio.Future = loop.create_future()
         span = self.trace.start('PING')
-        span.backend = conn.backend.key
+        span.backend = conn.span_backend
 
         def cb(err, latency):
             if fut.done():

@@ -53,9 +53,15 @@ def _finish_span(req, zxid: int | None = None, status: str = 'ok',
     (utils/trace.py — the xid-correlated span is stamped with the
     reply zxid here, where the reply routes back by xid).  Safe on
     every settle path: a span closes once, first outcome wins."""
-    span = getattr(req, 'span', None)
+    span = req.span
     if span is not None:
         span.finish(zxid=zxid, status=status, error=error)
+
+
+#: What a request's ``_listeners`` is until its first ``on`` / ``once``:
+#: ONE shared mapping that stays empty — the emitter's readers find
+#: nobody listening in it, and nothing writes to it.
+_NO_LISTENERS: dict = {}
 
 
 class ZKRequest(EventEmitter):
@@ -68,15 +74,35 @@ class ZKRequest(EventEmitter):
     'error' events serve what must run inside the routing call (the
     reserved xids' piggy-backing, a watcher's arm).  The client
     facade may attach a trace ``span``; the connection's reply/error
-    routing closes it."""
+    routing closes it.
+
+    One is made an op on the fleet's one loop, so it has no
+    ``__dict__`` and makes no listener table: almost no request is
+    listened to (the reserved xids' are), and the table is made by
+    the first ``on`` / ``once``.  ``packet`` stays with the request
+    until it settles: it is the request's xid and opcode for whoever
+    looks at ``conn.reqs`` (the tests do, a debugger would)."""
+
+    __slots__ = ('packet', 'span', 'fut')
 
     def __init__(self, packet: dict):
-        super().__init__()
+        self._listeners = _NO_LISTENERS
+        self._ver = 0
         self.packet = packet
         #: Optional utils/trace.Span, attached by Client._start_op.
         self.span = None
         #: The awaiter's future, once as_future() was asked for it.
         self.fut: asyncio.Future | None = None
+
+    def on(self, event: str, cb: Callable) -> 'ZKRequest':
+        if self._listeners is _NO_LISTENERS:
+            self._listeners = {}
+        return super().on(event, cb)
+
+    def once(self, event: str, cb: Callable) -> 'ZKRequest':
+        if self._listeners is _NO_LISTENERS:
+            self._listeners = {}
+        return super().once(event, cb)
 
     def as_future(self) -> asyncio.Future:
         """The awaitable that resolves to the reply packet (one per
@@ -222,6 +248,12 @@ class ZKConnection(FSM):
         #: (reference: lib/connection-fsm.js:174).
         self.client = client
         self.backend = backend
+        #: What this connection's ops stamp their trace spans with
+        #: (``Client._start_op``): where it leads, and — from
+        #: ``connected`` on, None before — whose session it carries,
+        #: each rendered once a connection and not once an op.
+        self.span_backend = backend.key
+        self.span_session_id: str | None = None
         # Child logger carrying this connection's address context
         # (reference: lib/connection-fsm.js:93-96); sessionId accretes
         # once connected (reference: lib/connection-fsm.js:209-211).
@@ -461,8 +493,8 @@ class ZKConnection(FSM):
         # Handshake is over: steady-state request/reply framing from here
         # (the reference flips this per-frame via isInState checks).
         self.codec.handshaking = False
-        self.log = self.log.child(
-            sessionId=self.session.get_session_id())
+        self.span_session_id = self.session.get_session_id()
+        self.log = self.log.child(sessionId=self.span_session_id)
 
         if self._connect_latency is not None and \
                 self._connect_t0 is not None:
@@ -835,8 +867,9 @@ class ZKConnection(FSM):
         req.span = span
         pkt['xid'] = self.next_xid()
         self.reqs[pkt['xid']] = req
-        self.log.trace('sent request xid %d opcode %s',
-                       pkt['xid'], pkt['opcode'])
+        if self.log.enabled_for_trace():
+            self.log.trace('sent request xid %d opcode %s',
+                           pkt['xid'], pkt['opcode'])
         if span is not None and span.stages is not None:
             # before the write: a disabled cork flushes inside it
             self._tx.stamps.append(span.stages)

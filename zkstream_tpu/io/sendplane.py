@@ -108,7 +108,7 @@ class SendPlane:
 
     __slots__ = ('_write', '_chunks', '_pending', '_scheduled',
                  'enabled', 'max_bytes', '_frames_hist', '_bytes_hist',
-                 '_labels', '_barrier', '_ledger', '_tier', '_entry',
+                 '_plane', '_barrier', '_ledger', '_tier', '_entry',
                  '_syscall_ctr', '_transport_fn', 'stamps')
 
     def __init__(self, write, *, enabled: bool | None = None,
@@ -160,16 +160,19 @@ class SendPlane:
         self._frames_hist = None
         self._bytes_hist = None
         self._syscall_ctr = None
-        self._labels = {'plane': plane}
+        self._plane = plane
         if collector is not None:
+            # this plane's two series, bound once: a flush observes
+            # both (utils/metrics.BoundSeries)
+            labels = {'plane': plane}
             self._frames_hist = collector.histogram(
                 METRIC_FLUSH_FRAMES,
                 'Frames per coalesced transport write, by plane',
-                buckets=FRAME_BUCKETS)
+                buckets=FRAME_BUCKETS).labels(labels)
             self._bytes_hist = collector.histogram(
                 METRIC_FLUSH_BYTES,
                 'Bytes per coalesced transport write, by plane',
-                buckets=BYTE_BUCKETS)
+                buckets=BYTE_BUCKETS).labels(labels)
             self._syscall_ctr = collector.counter(
                 METRIC_FLUSH_SYSCALLS,
                 'Write submissions issued by the outbound plane, by '
@@ -374,10 +377,10 @@ class SendPlane:
 
     def _observe(self, frames: int, nbytes: int) -> None:
         if self._frames_hist is not None:
-            self._frames_hist.observe(frames, self._labels)
-            self._bytes_hist.observe(nbytes, self._labels)
+            self._frames_hist.observe(frames)
+            self._bytes_hist.observe(nbytes)
 
     def _count_legacy(self) -> None:
         if self._syscall_ctr is not None:
             self._syscall_ctr.increment(
-                {'plane': self._labels['plane'], 'backend': 'asyncio'})
+                {'plane': self._plane, 'backend': 'asyncio'})

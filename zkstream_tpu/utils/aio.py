@@ -3,7 +3,7 @@
 from __future__ import annotations
 
 import asyncio
-import heapq
+import math
 import socket
 import threading
 
@@ -47,76 +47,100 @@ class DeadlineQueue:
     that is still pending fails with :class:`DeadlineExpired` — never
     before its deadline by the loop's clock, and late by no more than
     the loop iteration the timer fires in — and the timer moves to the
-    next.  A discarded entry drops its future at once and leaves the
-    heap at the next compaction (more discarded than live), so the
-    queue never holds a settled future or its reply; a compaction
-    that dropped the entry the timer stood for moves the timer too,
-    and one that leaves nothing cancels it.
+    next.  A discarded entry drops its future at once, so the queue
+    never holds a settled future or its reply.
+
+    Nearly every wait of a loop carries one of a few timeouts (a
+    client's ``op_timeout``), and waits of ONE timeout come due in the
+    order they were added.  So the queue keeps a FIFO a distinct
+    timeout — ``{future: when}``, a dict in insertion order — and no
+    heap: an entry is one append (the future itself is its key, no
+    object is made for it), a discard one removal, and the earliest
+    deadline of all is the least of the FIFOs' heads.  The timer
+    stands for a time no later than that: the head it was armed for
+    may have been discarded since, and then it fires early for
+    nothing, once, and moves to the oldest head still live; with
+    nothing live it is not armed again.  A timeout nothing waits under
+    any more leaves the table when that is swept (at a firing, and
+    when a new timeout finds more than :attr:`SWEEP_MIN` others).
 
     Host span ``client.deadline`` (profiler sessions only; count and
     total): each arming and each firing of the timer.  Beside
     ``client.submit``'s count it gives requests per loop timer."""
 
-    __slots__ = ('loop', '_heap', '_seq', '_dead', '_timer')
+    __slots__ = ('loop', '_lanes', '_timer', '_when', '_sweep_at')
 
-    #: Discarded entries tolerated before a compaction is considered.
-    COMPACT_MIN = 64
+    #: Timeouts in the table before a new one looks for empty ones.
+    SWEEP_MIN = 64
 
     def __init__(self, loop: asyncio.AbstractEventLoop):
         self.loop = loop
-        #: ``[when, seq, future]``; the future is None once discarded
-        self._heap: list[list] = []
-        self._seq = 0
-        self._dead = 0
+        #: timeout in seconds -> ``{future: when}``, oldest first
+        self._lanes: dict[float, dict] = {}
         self._timer: asyncio.TimerHandle | None = None
+        #: what the timer is armed for; inf while it is not
+        self._when = math.inf
+        self._sweep_at = self.SWEEP_MIN
 
     def __len__(self) -> int:
         """Entries still waited on."""
-        return len(self._heap) - self._dead
+        return sum(map(len, self._lanes.values()))
 
-    def add(self, fut: asyncio.Future, seconds: float) -> list:
-        self._seq += 1
-        entry = [self.loop.time() + seconds, self._seq, fut]
-        heapq.heappush(self._heap, entry)
-        if self._timer is None or entry[0] < self._timer.when():
+    def add(self, fut: asyncio.Future, seconds: float) -> dict:
+        """Bound ``fut`` (which stands in the queue once) by
+        ``seconds`` from now.  The entry handed back is for
+        :meth:`discard`, with the future."""
+        when = self.loop.time() + seconds
+        lane = self._lanes.get(seconds)
+        if lane is None:
+            lane = self._new_lane(seconds)
+        lane[fut] = when
+        if when < self._when:
             with host_span('client.deadline', accumulate=True):
-                self._set_timer(entry[0])
-        return entry
+                self._set_timer(when)
+        return lane
 
-    def discard(self, entry: list) -> None:
-        if entry[2] is None:
-            return              # fired, or discarded before
-        entry[2] = None
-        self._dead += 1
-        if self._dead > self.COMPACT_MIN and \
-                self._dead * 2 > len(self._heap):
-            self._heap = [e for e in self._heap if e[2] is not None]
-            heapq.heapify(self._heap)
-            self._dead = 0
-            when = self._heap[0][0] if self._heap else None
-            if when != self._timer.when():
-                with host_span('client.deadline', accumulate=True):
-                    self._set_timer(when)
+    def discard(self, entry: dict, fut: asyncio.Future) -> None:
+        """``fut``'s wait has ended: idempotent, and nothing if its
+        deadline fired."""
+        entry.pop(fut, None)
 
-    def _set_timer(self, when: float | None) -> None:
+    def _new_lane(self, seconds: float) -> dict:
+        lanes = self._lanes
+        if len(lanes) >= self._sweep_at:
+            for s in [s for s, lane in lanes.items() if not lane]:
+                del lanes[s]
+            self._sweep_at = max(self.SWEEP_MIN, 2 * len(lanes))
+        lane = lanes[seconds] = {}
+        return lane
+
+    def _set_timer(self, when: float) -> None:
         if self._timer is not None:
             self._timer.cancel()
-        self._timer = None if when is None \
-            else self.loop.call_at(when, self._fire)
+        self._when = when
+        self._timer = self.loop.call_at(when, self._fire)
 
     def _fire(self) -> None:
         with host_span('client.deadline', accumulate=True):
-            self._timer = None
-            heap, now = self._heap, self.loop.time()
-            while heap and (heap[0][2] is None or heap[0][0] <= now):
-                entry = heapq.heappop(heap)
-                fut, entry[2] = entry[2], None
-                if fut is None:
-                    self._dead -= 1
-                elif not fut.done():
-                    fut.set_exception(DeadlineExpired())
-            if heap:
-                self._set_timer(heap[0][0])
+            self._timer, self._when = None, math.inf
+            now, nxt = self.loop.time(), math.inf
+            for seconds, lane in list(self._lanes.items()):
+                overdue = []
+                for fut, when in lane.items():
+                    if when > now:
+                        nxt = min(nxt, when)
+                        break
+                    overdue.append(fut)
+                for fut in overdue:
+                    del lane[fut]
+                    if not fut.done():
+                        fut.set_exception(DeadlineExpired())
+                if not lane:
+                    # nothing in it to orphan (an awaiter that still
+                    # holds it as its entry only discards)
+                    del self._lanes[seconds]
+            if nxt != math.inf:
+                self._set_timer(nxt)
 
 
 #: loop -> its DeadlineQueue.  A closed loop's queue is swept by the

@@ -404,7 +404,7 @@ class BlackBoxRecorder:
         trace = getattr(srv, 'trace', None)
         if trace is not None:
             body['trace_dropped'] = trace.dropped
-            body['trace_tail'] = trace.dump()[-TRACE_TAIL:]
+            body['trace_tail'] = trace.dump(last=TRACE_TAIL)
         return body
 
     # -- public surface -----------------------------------------------

@@ -14,6 +14,11 @@ from typing import Any, Callable
 
 
 class EventEmitter:
+    # its own state in slots; a subclass without ``__slots__`` has its
+    # ``__dict__`` as ever, and one that is made an op at a time
+    # (io/connection.ZKRequest) declares its own and has none
+    __slots__ = ('_listeners', '_ver')
+
     def __init__(self) -> None:
         self._listeners: dict[str, list[Callable]] = {}
         #: bumped on every registry mutation; lets emit() skip the

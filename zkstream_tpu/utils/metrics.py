@@ -15,6 +15,7 @@ ride in a label without producing unparseable scrape output.
 from __future__ import annotations
 
 import time
+from bisect import bisect_left
 
 from .aio import ambient_loop
 
@@ -43,6 +44,8 @@ def _label_key(labels) -> tuple[tuple[str, str], ...]:
     if not labels:
         return ()
     items = labels.items() if isinstance(labels, dict) else labels
+    if len(labels) == 1:
+        return tuple(items)         # one pair is sorted
     return tuple(sorted(items))
 
 
@@ -150,13 +153,34 @@ DEFAULT_BUCKETS = (0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0,
                    250.0, 500.0, 1000.0, 2500.0, 5000.0, 10000.0)
 
 
+class BoundSeries:
+    """One series of a :class:`Histogram`, its label set resolved
+    once (:meth:`Histogram.labels`): ``observe(value)`` touches the
+    series' row directly — a bisect over the bounds and two adds —
+    where ``Histogram.observe(value, labels)`` first normalizes and
+    hashes the label set, every call.  Both land in the same row."""
+
+    __slots__ = ('_row', '_bounds')
+
+    def __init__(self, row: list, bounds: tuple):
+        self._row = row
+        self._bounds = bounds
+
+    def observe(self, value: float) -> None:
+        row = self._row
+        # the first bound with value <= bound; past the last: +Inf
+        row[bisect_left(self._bounds, value)] += 1
+        row[-1] += value
+
+
 class Histogram:
     """A labelled Prometheus histogram: cumulative ``_bucket`` series
     (``le`` upper bounds plus ``+Inf``), ``_sum``, and ``_count``.
 
-    ``observe`` is the hot-path call: one bisect-free linear scan over
-    a small tuple of bounds plus two adds — cheap enough for per-op
-    recording."""
+    ``observe`` is the hot-path call: the label set's key, one bisect
+    over a small tuple of bounds plus two adds — cheap enough for
+    per-op recording; a caller that observes one series over and over
+    binds it once (:meth:`labels`) and skips the key."""
 
     def __init__(self, name: str, help_text: str = '',
                  buckets=DEFAULT_BUCKETS):
@@ -199,15 +223,16 @@ class Histogram:
                 + [0.0]
         return row
 
+    def labels(self, labels: dict[str, str] | None = None) \
+            -> BoundSeries:
+        """The series of one label set as a handle to observe into
+        (made with its row on first ask)."""
+        return BoundSeries(self._row(labels), self.buckets)
+
     def observe(self, value: float,
                 labels: dict[str, str] | None = None) -> None:
         row = self._row(labels)
-        for i, bound in enumerate(self.buckets):
-            if value <= bound:
-                row[i] += 1
-                break
-        else:
-            row[len(self.buckets)] += 1     # +Inf-only
+        row[bisect_left(self.buckets, value)] += 1
         row[-1] += value
 
     def count(self, labels: dict[str, str] | None = None) -> int:

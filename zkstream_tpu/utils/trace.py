@@ -102,22 +102,34 @@ import time
 TRACE_SCHEMA = 3
 
 #: A span's optional fields, each None until something stamps it.
-#: The ONE list of them: it makes the class's defaults (below the
-#: class), so neither ``Span.__init__`` nor ``TraceRing.note`` names a
-#: field it does not set, and it is ``to_dict``'s emission order
-#: (after the always-present keys) — fixed, so a span serializes
-#: byte-identically regardless of which setattr path populated it.
+#: The ONE list of them: they are slots of the class, so neither
+#: ``Span.__init__`` nor ``TraceRing.note`` names a field it does not
+#: set (``Span.__getattr__`` answers None for the rest), and it is
+#: ``to_dict``'s emission order (after the always-present keys) —
+#: fixed, so a span serializes byte-identically regardless of which
+#: setattr path populated it.
 _OPTIONAL_FIELDS = ('path', 'xid', 'zxid', 'backend', 'session_id',
                     'member', 'batch', 'nbytes', 'detail', 'error',
                     'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted',
-                    'rows', 'width', 'names', 'bound', 'cut', 'retick')
+                    'rows', 'width', 'names', 'bound', 'cut', 'retick',
+                    'lists', 'shared')
+
+#: The slots that read None while nothing has stamped them.
+_READS_NONE = frozenset(_OPTIONAL_FIELDS) | {'duration_ms', '_on_slow',
+                                            'stages'}
 
 
 class Span:
     """One traced operation: request-side fields stamped at creation,
     reply-side fields stamped on completion.
 
-    Optional fields (``_OPTIONAL_FIELDS``) read None until stamped:
+    A span has no ``__dict__``: one is made an op on the fleet's one
+    loop, and a name outside its slots is refused (``AttributeError``),
+    not kept.  Optional fields (``_OPTIONAL_FIELDS``) read None until
+    stamped — an unstamped slot costs its reader an exception inside
+    the interpreter, so the fields a client op's path reads
+    (``stages``, ``_on_slow``, ``duration_ms``) are stamped at
+    creation:
     ``member`` — which ensemble member recorded the span (None =
     client); ``batch`` — the batch size where the span covers several
     frames/txns (decode batch, group-fsync barrier, fan-out watch
@@ -132,25 +144,32 @@ class Span:
     (:func:`op_resumed`); ``lane`` / ``emitted`` — ``ingest.route``
     only: the tick's frames settled through the connections' direct
     lanes, and those handed to the ``'ingestDeliver'`` emitter path,
-    and ``names``, the names in the children lists it routed;
+    ``names``, the names in the children lists it routed, and
+    ``lists`` / ``shared``, those lists and the ones among them that
+    the tick's one decode did not parse again;
     ``rows`` / ``width`` — ``ingest.dispatch`` only: the streams in
     the dispatch and the width of its size class (``nbytes``: their
     payload); ``bound`` / ``cut`` / ``retick`` — a device tick's
     ``ingest.tick`` only: the rows that gave the tick the whole frame
     bound (``max_frames`` frames), the slots that held more than they
-    gave, and 1 where the tick left a follow-up tick for either."""
+    gave, and 1 where the tick left a follow-up tick for either.
 
-    duration_ms: float | None = None
-    #: Armed by a ring with a slow-op threshold: called once with the
-    #: span when finish() measures a duration at/over it.
-    _on_slow = None
-    #: A client op inside a profiler session: its stage stamps
-    #: ``[t_submit, t_flush, t_rx, t_settle]`` on
-    #: ``time.perf_counter_ns`` (0 = not reached), from ``_start_op``
-    #: until :func:`op_resumed` books them.  None is the op's answer
-    #: to "was a session active when it was submitted": every later
-    #: stamp is a branch on it.
-    stages: list | None = None
+    Beside them: ``duration_ms``; ``_on_slow`` — armed by a ring with a
+    slow-op threshold: called once with the span when finish()
+    measures a duration at/over it; ``stages`` — a client op inside a
+    profiler session: its stage stamps ``[t_submit, t_flush, t_rx,
+    t_settle]`` on ``time.perf_counter_ns`` (0 = not reached), from
+    ``_start_op`` until :func:`op_resumed` books them.  None is the
+    op's answer to "was a session active when it was submitted": every
+    later stamp is a branch on it.
+
+    The span's start is ONE clock read (``time.monotonic``); its wall
+    time :attr:`t_wall` is that plus ``_anchor``, the wall clock minus
+    the monotonic one as the span's ring read them once when it was
+    made — a step of the system's clock after that moves no span."""
+
+    __slots__ = ('span_id', 'kind', 'op', 'status', '_t0', '_anchor',
+                 'duration_ms', '_on_slow', 'stages') + _OPTIONAL_FIELDS
 
     def __init__(self, span_id: int, op: str, path: str | None = None,
                  kind: str = 'op'):
@@ -159,8 +178,23 @@ class Span:
         self.op = op
         self.path = path
         self.status: str = 'open'
-        self.t_wall = time.time()
-        self._t0 = time.monotonic()
+        self._t0 = t0 = time.monotonic()
+        self._anchor = time.time() - t0     # a ring stamps its own
+        self.duration_ms: float | None = None
+        self._on_slow = None
+        self.stages: list | None = None
+
+    def __getattr__(self, name: str):
+        # reached only for a slot nothing has stamped yet
+        if name in _READS_NONE:
+            return None
+        raise AttributeError('%r object has no attribute %r'
+                             % (type(self).__name__, name))
+
+    @property
+    def t_wall(self) -> float:
+        """The span's start on the wall clock."""
+        return self._anchor + self._t0
 
     def finish(self, zxid: int | None = None, status: str = 'ok',
                error: str | None = None) -> None:
@@ -183,7 +217,8 @@ class Span:
         survives ``json.dumps``), so a span's serialization is stable
         across processes and runs."""
         d = {'span': self.span_id, 'kind': self.kind, 'op': self.op,
-             'status': self.status, 't_wall': round(self.t_wall, 6)}
+             'status': self.status,
+             't_wall': round(self._anchor + self._t0, 6)}
         for field in _OPTIONAL_FIELDS:
             val = getattr(self, field)
             if val is not None:
@@ -194,11 +229,6 @@ class Span:
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return '<Span %s>' % (self.to_dict(),)
-
-
-for _field in _OPTIONAL_FIELDS:
-    setattr(Span, _field, None)
-del _field
 
 
 class TraceRing:
@@ -230,6 +260,10 @@ class TraceRing:
         self._ring: collections.deque[Span] = collections.deque(
             maxlen=capacity)
         self._ids = itertools.count(1)
+        #: wall clock minus monotonic clock, read once: every span of
+        #: this ring starts with ONE clock read and derives its
+        #: ``t_wall`` from this (:class:`Span`)
+        self._anchor = time.time() - time.monotonic()
 
     def __len__(self) -> int:
         return len(self._ring)
@@ -244,14 +278,25 @@ class TraceRing:
 
     def start(self, op: str, path: str | None = None,
               kind: str = 'op') -> Span:
-        span = Span(next(self._ids), op, path, kind=kind)
+        # built inline, as ``note`` is: one is made an op
+        span = Span.__new__(Span)
+        span.span_id = next(self._ids)
+        span.kind = kind
+        span.op = op
+        span.path = path
+        span.status = 'open'
+        span._t0 = time.monotonic()
+        span._anchor = self._anchor
+        span.duration_ms = None
+        span.stages = None
+        span._on_slow = (None if self.slow_ms is None
+                         else self._slow_settled)
         if self.member is not None:
             span.member = self.member
-        if self.slow_ms is not None:
-            span._on_slow = self._slow_settled
-        if len(self._ring) >= self.capacity:
+        ring = self._ring
+        if len(ring) >= self.capacity:
             self.dropped += 1       # the append below evicts one
-        self._ring.append(span)
+        ring.append(span)
         return span
 
     def note(self, op: str, path: str | None = None,
@@ -275,8 +320,8 @@ class TraceRing:
         span.zxid = zxid
         span.member = self.member
         span.status = 'ok'
-        span.t_wall = time.time()
-        span._t0 = 0.0
+        span._t0 = time.monotonic()
+        span._anchor = self._anchor
         span.duration_ms = 0.0      # already settled; checked below
         for name, val in fields.items():
             setattr(span, name, val)
@@ -297,9 +342,13 @@ class TraceRing:
         table without a settle is a span-leak bug)."""
         return [s for s in self._ring if s.status == 'open']
 
-    def dump(self) -> list[dict]:
-        """The ring's contents, oldest first, as JSON-ready dicts."""
-        return [s.to_dict() for s in self._ring]
+    def dump(self, last: int | None = None) -> list[dict]:
+        """The ring's contents, oldest first, as JSON-ready dicts —
+        all of them, or the newest ``last``."""
+        ring = self._ring
+        if last is not None and last < len(ring):
+            ring = itertools.islice(ring, len(ring) - last, None)
+        return [s.to_dict() for s in ring]
 
     def dump_json(self, indent: int | None = None) -> str:
         return json.dumps(self.dump(), indent=indent)
@@ -502,7 +551,7 @@ def _begin_session() -> None:
     _recording = True
 
 
-def _armed() -> bool:
+def armed() -> bool:
     """Is a profiler session active?  (``host_span`` asks the same
     question inline: it is the one call an op pays outside a
     session.)"""
@@ -555,7 +604,7 @@ def host_add(name: str, count: int, total_ns: int) -> None:
     (the send plane's ``client.send``: connections sent to, and the
     nanoseconds inside their ``send(2)`` loop).  Armed like
     :func:`host_span`: nothing outside a profiler session."""
-    if _armed():
+    if armed():
         _add(name, count, total_ns)
 
 
@@ -645,6 +694,15 @@ def stamp_reply(span: Span, rx: tuple) -> None:
     st[T_SETTLE] = time.perf_counter_ns()
 
 
+def op_submitted() -> '_HostSpan':
+    """An op is submitted inside a profiler session (the caller has
+    asked: :func:`armed`, or a ``host_span`` that was not
+    :data:`NO_SPAN`): open host span ``client.submit``, which the
+    caller closes (``__exit__``) once the request is with the send
+    plane.  Its ``t0_ns`` is the op's ``t_submit``."""
+    return _HostSpan('client.submit', True, {}).__enter__()
+
+
 def op_resumed(span: Span):
     """The awaiter of a staged op runs again (``Client._await_op``,
     whatever the outcome): book the op's four waits — one count each,
@@ -658,7 +716,7 @@ def op_resumed(span: Span):
     session has ended meanwhile."""
     t_resume = time.perf_counter_ns()
     (t_submit, t_flush, t_rx, t_settle), span.stages = span.stages, None
-    if not _armed():
+    if not armed():
         return None
     span.t0_ns, span.t1_ns = t_submit, t_resume
     # backwards from the resume: a stamp that is missing, or (a reply
@@ -688,7 +746,7 @@ _gc_open = None
 
 
 def _gc_pause(phase: str, info: dict) -> None:
-    """The one ``gc.callbacks`` hook (installed by :func:`_armed`):
+    """The one ``gc.callbacks`` hook (installed by :func:`armed`):
     inside a profiler session a collection is a ``gc.pause``
     annotation from its ``start`` to its ``stop`` and one count in
     ``host_ring.totals['gc.pause']`` — and in ``['gc.pause@<name>']``
@@ -697,7 +755,7 @@ def _gc_pause(phase: str, info: dict) -> None:
     Outside a session it is one ``is_enabled()`` a collection."""
     global _gc_open
     if phase == 'start':
-        if not _armed():
+        if not armed():
             return
         ann = _annotation('gc.pause')
         ann.__enter__()
