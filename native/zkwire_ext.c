@@ -1536,7 +1536,7 @@ static PyObject *py_decode_requests(PyObject *self, PyObject *args) {
 }
 
 static PyObject *py_abi_version(PyObject *self, PyObject *noargs) {
-  return PyLong_FromLong(14);
+  return PyLong_FromLong(15);
 }
 
 /* CRC32C (Castagnoli, reflected 0x82F63B78) for the write-ahead-log
@@ -2190,12 +2190,21 @@ static PyObject *py_sender_close(PyObject *self, PyObject *args) {
  *        close the fd then.  What was received and not yet reaped comes
  *        back, in order, as a reap would have given it (without the
  *        token); an unknown token gives []
- *   receiver_reap(capsule) -> ([(token, bytes | -errno), ...], recvs, ns)
- *        every connection with something waiting, oldest first, its
- *        bytes joined into ONE bytes; b'' is EOF, -errno a hard error,
- *        each given once and after the connection's last bytes; then
- *        the thread's recv(2) calls and the nanoseconds inside them
- *        since the previous reap; clears the fd
+ *   receiver_reap(capsule[, sinks[, want]])
+ *            -> ([(token, bytes | -errno), ...], recvs, ns, fed)
+ *        every connection with something waiting, oldest first.  One
+ *        whose token is a key of ``sinks`` ({token: bytearray}: where
+ *        the caller would copy those bytes next — the fleet ingest's
+ *        slot, io/ingest.py) has its chunks appended to that bytearray
+ *        here, in place, and makes no item: no bytes object, no tuple.
+ *        Every other one's bytes come joined into ONE bytes (also a
+ *        sunk one's whose bytearray cannot be resized: an export
+ *        alive); b'' is EOF, -errno a hard error, each given once, as
+ *        an item, after the connection's last bytes.  Then the
+ *        thread's recv(2) calls and the nanoseconds inside them since
+ *        the previous reap, and ``fed``: None when no sink was fed,
+ *        else (connections fed, bytes fed, nanoseconds the feeds took,
+ *        [(token, bytes fed), ...] if ``want`` else None); clears the fd
  *   receiver_close(capsule)            joins; what waits is dropped
  *
  * The thread: epoll_wait, then ONE recv(fd, 256 KiB, MSG_DONTWAIT) a
@@ -2611,9 +2620,42 @@ typedef struct {
   int fin;
 } zk_rxtaken;
 
+/* Append one connection's waiting bytes to its sink, in place: one
+ * resize, a memcpy a chunk (frees the chunks).  0 where the bytearray
+ * cannot grow (a buffer export is alive, no memory): nothing is
+ * touched then, and the bytes go back as an item. */
+static int rx_sink(PyObject *sink, zk_rxchunk *head, size_t nbytes) {
+  Py_ssize_t had = PyByteArray_GET_SIZE(sink);
+  if (PyByteArray_Resize(sink, had + (Py_ssize_t)nbytes) < 0) {
+    PyErr_Clear();
+    return 0;
+  }
+  char *at = PyByteArray_AS_STRING(sink) + had;
+  for (zk_rxchunk *ch = head; ch; ch = ch->next) {
+    memcpy(at, ch->data, ch->len);
+    at += ch->len;
+  }
+  rx_free_chunks(head);
+  return 1;
+}
+
 static PyObject *py_receiver_reap(PyObject *self, PyObject *args) {
-  zk_receiver *r = receiver_from_args(args, "O", NULL);
+  PyObject *cap, *sinks = NULL;
+  int want = 0;
+  if (!PyArg_ParseTuple(args, "O|Op", &cap, &sinks, &want)) return NULL;
+  zk_receiver *r =
+      (zk_receiver *)PyCapsule_GetPointer(cap, "zkwire.receiver");
   if (!r) return NULL;
+  if (r == &receiver_closed) {
+    PyErr_SetString(PyExc_ValueError, "receiver already closed");
+    return NULL;
+  }
+  if (sinks == Py_None) sinks = NULL;
+  if (sinks && !PyDict_CheckExact(sinks)) {
+    PyErr_SetString(PyExc_TypeError, "sinks must be a dict or None");
+    return NULL;
+  }
+  if (sinks && !PyDict_GET_SIZE(sinks)) sinks = NULL;
   /* the fd first: what is published after this read raises it again */
   wake_clear(r->rfd);
   pthread_mutex_lock(&r->mu);
@@ -2648,14 +2690,39 @@ static PyObject *py_receiver_reap(PyObject *self, PyObject *args) {
   pthread_mutex_unlock(&r->mu);
   if (again) wake_raise(r->wfd);
   PyObject *out = PyList_New(0);
+  PyObject *fed = sinks && want && out ? PyList_New(0) : NULL;
+  if (sinks && want && !fed) Py_CLEAR(out);
+  long long fed_conns = 0, fed_bytes = 0;
+  long long t0 = sinks ? sender_now_ns() : 0;
   for (i = 0; i < n; i++) {
     if (taken[i].nbytes) {
-      PyObject *data = out ? rx_join(taken[i].head, taken[i].nbytes) : NULL;
-      if (!out) rx_free_chunks(taken[i].head);
-      PyObject *item =
-          data ? Py_BuildValue("(KN)", taken[i].token, data) : NULL;
-      if (out && (!item || PyList_Append(out, item) < 0)) Py_CLEAR(out);
-      Py_XDECREF(item);
+      /* a connection with a sink: its bytes go where the caller's
+       * next step would have copied them, and no object is made */
+      PyObject *key =
+          out && sinks ? PyLong_FromUnsignedLongLong(taken[i].token) : NULL;
+      PyObject *sink = key ? PyDict_GetItemWithError(sinks, key) : NULL;
+      if (sink && PyByteArray_CheckExact(sink) &&
+          rx_sink(sink, taken[i].head, taken[i].nbytes)) {
+        fed_conns++;
+        fed_bytes += (long long)taken[i].nbytes;
+        if (fed) {
+          PyObject *pair = Py_BuildValue("(On)", key,
+                                         (Py_ssize_t)taken[i].nbytes);
+          if (!pair || PyList_Append(fed, pair) < 0) Py_CLEAR(out);
+          Py_XDECREF(pair);
+        }
+        Py_DECREF(key);
+      } else {
+        Py_XDECREF(key);
+        if (PyErr_Occurred()) Py_CLEAR(out); /* the key, or its hash */
+        PyObject *data =
+            out ? rx_join(taken[i].head, taken[i].nbytes) : NULL;
+        if (!out) rx_free_chunks(taken[i].head);
+        PyObject *item =
+            data ? Py_BuildValue("(KN)", taken[i].token, data) : NULL;
+        if (out && (!item || PyList_Append(out, item) < 0)) Py_CLEAR(out);
+        Py_XDECREF(item);
+      }
     }
     if (out && taken[i].fin) {
       PyObject *val = rx_fin_value(taken[i].fin);
@@ -2666,8 +2733,16 @@ static PyObject *py_receiver_reap(PyObject *self, PyObject *args) {
     }
   }
   free(taken);
-  if (!out) return NULL; /* with the error set: the bytes are gone */
-  return Py_BuildValue("(NLL)", out, calls, ns);
+  /* no list: the error is set, and what was not fed is gone */
+  PyObject *ret = NULL;
+  if (out && fed_conns)
+    ret = Py_BuildValue("(OLL(LLLO))", out, calls, ns, fed_conns, fed_bytes,
+                        sender_now_ns() - t0, fed ? fed : Py_None);
+  else if (out)
+    ret = Py_BuildValue("(OLLO)", out, calls, ns, Py_None);
+  Py_XDECREF(out);
+  Py_XDECREF(fed);
+  return ret;
 }
 
 static PyObject *py_receiver_close(PyObject *self, PyObject *args) {
@@ -3349,8 +3424,11 @@ static PyMethodDef methods[] = {
      "the connection out (no recv of it in flight on return) and hand "
      "back what was not reaped"},
     {"receiver_reap", py_receiver_reap, METH_VARARGS,
-     "receiver_reap(receiver) -> ([(token, bytes|-errno), ...], recvs, "
-     "ns) — what every connection received, oldest first (b'' = EOF)"},
+     "receiver_reap(receiver[, sinks[, want]]) -> ([(token, "
+     "bytes|-errno), ...], recvs, ns, fed) — what every connection "
+     "received, oldest first (b'' = EOF); a token in sinks {token: "
+     "bytearray} is appended there instead; fed = None | (conns, bytes, "
+     "ns, [(token, bytes), ...] | None)"},
     {"receiver_close", py_receiver_close, METH_VARARGS,
      "receiver_close(receiver) — join the thread, drop what waits"},
     {"uring_create", py_uring_create, METH_VARARGS,

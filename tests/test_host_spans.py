@@ -330,6 +330,78 @@ async def test_a_reap_books_its_deliveries_and_the_threads_recvs(
         await c.close()
 
 
+async def test_a_reap_that_feeds_books_each_connection_once(
+        server, armed):
+    """The same books with the connections under a fleet ingest and
+    their sinks standing (io/transport.py ``rx_sink``): a connection
+    the reap's C call fed is one ``client.rx`` — its total the
+    nanoseconds inside that call's feeds — one ``client.rx_reaped``
+    and one ``client.rx_fed``; a connection without a sink (a second
+    ``sockData`` listener) is a ``client.rx`` span of its own beside
+    them, so count(``client.rx``) = count(``client.rx_reaped``) = the
+    deliveries, and the fed share is fed / reaped.  Every op's four
+    stage waits still sum to its span to the nanosecond: the fed
+    connections' ``t_rx`` is ONE clock read a reap."""
+    from zkstream_tpu.io.transport import probe
+    from zkstream_tpu.utils.native import ensure_ext
+    if not probe().mmsg or not hasattr(ensure_ext(), 'receiver_reap'):
+        pytest.skip('no native receiver here')
+    n = 12
+    ingest = _ingest()
+    await ingest.prewarm(n)
+    clients = []
+    try:
+        for _ in range(n):
+            c = Client(address='127.0.0.1', port=server.port,
+                       transport='mmsg', session_timeout=30000,
+                       max_spares=0, ingest=ingest)
+            c.start()
+            await c.wait_connected(timeout=5)
+            clients.append(c)
+        await clients[0].create('/fed', b'v' * 100)
+        await asyncio.sleep(0.05)
+        tier = clients[0].transport_tier
+        assert len(tier._sinks) == n and list(tier._sink_owners) == [ingest]
+        # one of them is listened to: it has no sink
+        heard: list = []
+        plain = clients[-1]
+        plain.get_session().conn.on('sockData', heard.append)
+        assert len(tier._sinks) == n - 1
+        reads, fed = tier.received_reads, tier.received_fed
+        trace.host_ring.reset()
+        for c in clients:
+            c.trace.clear()
+        rounds = 3
+        for _ in range(rounds):
+            assert len(await asyncio.gather(
+                *[c.get('/fed') for c in clients])) == n
+        totals = trace.host_ring.totals
+        assert totals['client.rx'][0] == rounds * n
+        assert totals['client.rx_reaped'] == [rounds * n, 0]
+        assert totals['client.rx_fed'][0] == rounds * (n - 1)
+        assert len(heard) == rounds
+        assert tier.received_reads - reads == rounds * n
+        assert tier.received_fed - fed == rounds * (n - 1)
+        assert tier.fed_ctr.value({'plane': 'client'}) \
+            == tier.received_fed
+        # the feeds' nanoseconds are inside ``client.rx``, which is
+        # inside the reaps
+        assert 0 < totals['client.rx_fed'][1] <= totals['client.rx'][1] \
+            < totals['client.rx_reap'][1]
+        spans = [s for c in clients for s in c.trace.spans()]
+        _check_stages(spans, rounds * n)
+        assert all(ns > 0 for _c, ns in _stage_totals())
+        # a reap's fed connections share its one stamp
+        ticks = {s.tick for s in spans}
+        assert ticks <= {s.tick for s in trace.host_ring.spans()
+                         if s.op == 'ingest.tick'}
+        assert not ingest._rx_marks      # consumed with the bytes
+    finally:
+        for c in clients:
+            await c.close()
+        ingest.close()
+
+
 def test_host_add_is_armed_by_the_session_alone(monkeypatch):
     """``host_add`` books work counted and timed elsewhere (a native
     thread's batch) under a name's totals inside a profiler session,
@@ -741,25 +813,37 @@ def _staged_submit(p, ring):
     return span, req
 
 
+@pytest.mark.parametrize('via', ['sock_data', 'sink'])
 @pytest.mark.parametrize('lead', ['reply', 'notification'])
 async def test_a_pipelined_reply_is_stamped_by_the_call_that_completed_it(
-        armed, lead):
+        armed, lead, via):
     """Three replies of one connection come in two receive calls
     before one tick — the first whole and half of the second, then the
     rest: each request's ``t_rx`` is the start of the call that brought
     ITS reply's last byte, not the connection's newest, through the
     direct lane and (a notification leads the stream) through
-    ``deliver``; the marks are gone with the bytes."""
+    ``deliver``; the marks are gone with the bytes.  A call is a
+    ``_sock_data``, or (``sink``) a reap whose one C call fed the
+    slot: its stamp is that reap's one clock read."""
     import random
 
-    from test_ingest_route import Peer, settle
+    from test_ingest_route import Peer, ReapRig, settle
 
     ingest = FleetIngest(bypass_bytes=0, warm='block', placement='host',
                          max_frames=8, min_len=256)
     p = Peer(0, ingest, True, random.Random(11))
     ring = trace.TraceRing(8)
     calls: list = []
-    p.conn.on('sockData', lambda _d: calls.append(p.conn._rx_t0))
+    rig = ReapRig(sink=True)
+    if via == 'sock_data':
+        p.conn.on('sockData', lambda _d: calls.append(p.conn._rx_t0))
+
+        def receive(data):
+            p.conn._sock_data(data)
+    else:
+        def receive(data):
+            rig.reap([(p.conn, data)])
+            calls.append(p.conn._rx_t0)
     try:
         (a, ra), (b, rb), (c_, rc) = (_staged_submit(p, ring)
                                       for _ in range(3))
@@ -773,16 +857,20 @@ async def test_a_pipelined_reply_is_stamped_by_the_call_that_completed_it(
         p.reply(rc.packet['xid'])
         wire, p.wire = bytes(p.wire), bytearray()
         ticks = ingest.ticks
-        p.conn._sock_data(wire[:cut])
-        p.conn._sock_data(wire[cut:])
+        receive(wire[:cut])
+        receive(wire[cut:])
         assert len(calls) == 2 and 0 < calls[0] < calls[1]
+        assert rig.fed == (2 if via == 'sink' else 0)
         assert [m[0] for m in ingest._rx_marks[id(p.conn)]] == [
             cut, len(wire)]
         await settle()
         # one tick routed all three; behind a notification (a first
         # frame of ~50 B: the slot gives 256) the cut slot finishes on
-        # follow-up ticks, and the marks follow what it consumed
-        assert ingest.ticks - ticks == 1 or lead == 'notification'
+        # follow-up ticks, and the marks follow what it consumed; a
+        # reap dispatches at its end, so the first reap's whole reply
+        # is in flight when the second reap comes: two ticks
+        assert ingest.ticks - ticks == (2 if via == 'sink' else 1) \
+            or lead == 'notification'
         rx = [s.stages[trace.T_RX] for s in (a, b, c_)]
         assert rx == [calls[0], calls[1], calls[1]]
         st = [s.stages[trace.T_SETTLE] for s in (a, b, c_)]
@@ -805,30 +893,46 @@ async def test_a_pipelined_reply_is_stamped_by_the_call_that_completed_it(
         ingest.close()
 
 
-async def test_the_marks_end_with_the_session(monkeypatch):
+@pytest.mark.parametrize('via', ['sock_data', 'sink'])
+async def test_the_marks_end_with_the_session(monkeypatch, via):
     """Outside a profiler session a receive call leaves no mark, and
-    the first one after a session drops what the session left."""
+    the first one after a session drops what the session left — the
+    slots' marks, and (``sink``: the call is a reap that fed the
+    slots) the stamp of every connection the session's reaps fed."""
     import random
 
-    from test_ingest_route import Peer, settle
+    from test_ingest_route import Peer, ReapRig, settle
 
     ingest = FleetIngest(bypass_bytes=0, warm='block', placement='host',
                          max_frames=8, min_len=256)
     p, q = (Peer(i, ingest, True, random.Random(i)) for i in range(2))
+    rig = ReapRig(sink=True)
+
+    def receive(x, data):
+        if via == 'sink':
+            rig.reap([(x.conn, data)])
+        else:
+            x.conn._sock_data(data)
     try:
         xid = p.get()
         p.reply(xid)
         wire, p.wire = bytes(p.wire), bytearray()
-        p.conn._sock_data(wire[:5])
+        receive(p, wire[:5])
         assert not ingest._rx_marks and p.conn._rx_t0 == 0
         monkeypatch.setattr(trace, '_is_enabled', lambda: True)
-        p.conn._sock_data(wire[5:9])
+        receive(p, wire[5:9])
         assert [m[0] for m in ingest._rx_marks[id(p.conn)]] == [9]
+        assert p.conn._rx_t0 > 0
         monkeypatch.setattr(trace, '_is_enabled', lambda: False)
         q.reply(q.get())
-        q.conn._sock_data(q.take())
+        receive(q, q.take())
         assert not ingest._rx_marks
-        p.conn._sock_data(wire[9:])
+        # a fed connection makes no call of its own that would drop
+        # its stamp: the first reap outside the session does
+        assert via == 'sock_data' or p.conn._rx_t0 == 0
+        receive(p, wire[9:])
+        assert p.conn._rx_t0 == 0 and rig.fed == (
+            4 if via == 'sink' else 0)
         await settle()
         assert not p.conn.reqs and not q.conn.reqs
         assert ingest.frames_routed == 2

@@ -9,7 +9,12 @@ waits, the injector's tick-time faults at the new moment, a connection
 torn down between the two halves, the regimes that never dispatch
 ahead, and the counter.  The peers are test_ingest_route's (real
 ``ZKConnection`` + ``ZKSession``, no sockets), the reap is the tier's
-real ``_rx_reap`` over a stand-in receiver (``ReapRig``).
+real ``_rx_reap`` over a stand-in receiver (``ReapRig``) — every test
+of a reap once with each delivery handed to the connection
+(``through_sock_data``: the sinks withdrawn) and once with the
+connections' sinks standing (``sunk``: the reap's one call appends to
+the ingest's slots, io/transport.py ``rx_sink``).  Last: everything
+that withdraws a sink, each against the same traffic with no sink.
 """
 
 import asyncio
@@ -57,13 +62,22 @@ async def turn() -> None:
     await asyncio.sleep(0)
 
 
-async def test_a_reap_dispatches_and_the_next_tick_routes():
+@pytest.fixture(params=[False, True], ids=['through_sock_data', 'sunk'])
+def rig(request) -> ReapRig:
+    return ReapRig(sink=request.param)
+
+
+def _fed(rig: ReapRig, n: int) -> bool:
+    """``n`` deliveries so far went into sinks — where they stand."""
+    return rig.fed == (n if rig.sink else 0)
+
+
+async def test_a_reap_dispatches_and_the_next_tick_routes(rig):
     """The reap's end builds and dispatches the batch (``ticks``,
     ``dispatches`` and ``ticks_early`` move, nothing is routed); the
     scheduled tick routes it without dispatching again."""
     ingest = _ingest()
     peers = _peers(ingest, 3)
-    rig = ReapRig()
     try:
         xids = [p.get() for p in peers]
         for p, x in zip(peers, xids):
@@ -72,6 +86,7 @@ async def test_a_reap_dispatches_and_the_next_tick_routes():
         assert (ingest.ticks, ingest.ticks_early, ingest.dispatches) \
             == (1, 1, 1)
         assert ingest._flight is not None and ingest.frames_routed == 0
+        assert _fed(rig, 3) and rig.tier.received_reads == 3
         assert all(not _settled(p) for p in peers)
         await turn()
         assert [_settled(p) for p in peers] == [[x] for x in xids]
@@ -86,13 +101,12 @@ async def test_a_reap_dispatches_and_the_next_tick_routes():
         await _stop(ingest, peers)
 
 
-async def test_a_dispatch_asks_for_its_results_copy_home_at_once():
+async def test_a_dispatch_asks_for_its_results_copy_home_at_once(rig):
     """Every dispatch's result is asked to the host when it is made
     (``copy_to_host_async``), not when the readback wants it: the
     readback of a tick that stood behind other work finds it there."""
     ingest = _ingest()
     peers = _peers(ingest, 2)
-    rig = ReapRig()
     asked: list = []
 
     class Out:
@@ -121,7 +135,7 @@ async def test_a_dispatch_asks_for_its_results_copy_home_at_once():
         await _stop(ingest, peers)
 
 
-async def test_bytes_behind_a_flight_wait_for_the_next_tick():
+async def test_bytes_behind_a_flight_wait_for_the_next_tick(rig):
     """One batch is in flight at most: a second reap before the tick
     feeds the slots and dispatches nothing; the tick routes the first
     batch alone, then dispatches what waited — the batch memory is not
@@ -129,7 +143,6 @@ async def test_bytes_behind_a_flight_wait_for_the_next_tick():
     reply settles once, a connection's in the order sent."""
     ingest = _ingest()
     peers = _peers(ingest, 4)
-    rig = ReapRig()
     try:
         first = [p.get() for p in peers[:3]]
         for p, x in zip(peers, first):
@@ -156,18 +169,18 @@ async def test_bytes_behind_a_flight_wait_for_the_next_tick():
         await settle()
         assert ingest.ticks == 2 and ingest.frames_routed == 5
         assert not any(p.pending(ingest) for p in peers)
+        assert _fed(rig, 5)
         assert not any(p.conn.reqs for p in peers)
     finally:
         await _stop(ingest, peers)
 
 
-async def test_a_partial_first_frame_waits_at_the_reap():
+async def test_a_partial_first_frame_waits_at_the_reap(rig):
     """A slot whose first frame is not whole gives the early dispatch
     nothing: no tick, no flight, the scheduled tick does not scan
     again; the rest of the frame, a reap later, is dispatched."""
     ingest = _ingest()
     peers = _peers(ingest, 2)
-    rig = ReapRig()
     try:
         p = peers[0]
         x = p.get()
@@ -183,6 +196,7 @@ async def test_a_partial_first_frame_waits_at_the_reap():
         assert (ingest.ticks, ingest.ticks_early) == (1, 1)
         await settle()
         assert _settled(p) == [x] and not p.pending(ingest)
+        assert _fed(rig, 2)
     finally:
         await _stop(ingest, peers)
 
@@ -208,14 +222,14 @@ class Injector:
         return min(self.cuts.pop(conn, 0), nbytes - 1)
 
 
-async def _injected(fed: str):
+async def _injected(fed: str, sink: bool = False):
     """Three peers with two replies each; the injector resets peer 0 at
     its tick and withholds 11 bytes of peer 1's suffix; a third reply
     reaches peer 1 while its suffix is withheld (reap mode: while the
     batch is in flight).  Returns every peer's log and state."""
     ingest = _ingest()
     peers = _peers(ingest, 3)
-    rig = ReapRig()
+    rig = ReapRig(sink)
     inj = ingest.faults = Injector(resets=[peers[0].conn],
                                    cuts={peers[1].conn: 11})
 
@@ -252,13 +266,16 @@ async def _injected(fed: str):
         await settle()
         await settle()
         assert not ingest._held and ingest._flight is None
+        assert rig.fed == 0         # an injector's ingest takes no sink
         return [(_settled(p), p.conn.get_state(), sorted(p.conn.reqs),
                  p.pending(ingest)) for p in peers], xids, late
     finally:
         await _stop(ingest, peers)
 
 
-async def test_injector_faults_at_the_early_dispatch(monkeypatch):
+@pytest.mark.parametrize('sink', [False, True],
+                         ids=['through_sock_data', 'sunk'])
+async def test_injector_faults_at_the_early_dispatch(monkeypatch, sink):
     """The injector's tick reset and withheld suffix are applied where
     the batch is built — at the reap's end — and end as they do when
     the scheduled tick applies them: the reset connection's requests
@@ -267,21 +284,20 @@ async def test_injector_faults_at_the_early_dispatch(monkeypatch):
     monkeypatch.setattr(session_mod, 'time', _Time)
     monkeypatch.setattr(ingest_mod, 'time', _Time)
     want, xids, late = await _injected('push')
-    got, _x, _l = await _injected('reap')
+    got, _x, _l = await _injected('reap', sink)
     assert got == want
     assert got[1][0] == xids[1] + [late]      # in order, the late one last
     assert got[2][0] == xids[2]
     assert got[0][2] == []                    # the reset one's: all failed
 
 
-async def test_teardown_between_dispatch_and_route_settles_once():
+async def test_teardown_between_dispatch_and_route_settles_once(rig):
     """A connection that leaves ``connected`` while its rows are in
     flight: its pending requests are failed by the teardown, once; the
     route drops its rows (and hands the xids the decode took back to
     its codec); the others' replies settle."""
     ingest = _ingest()
     peers = _peers(ingest, 3)
-    rig = ReapRig()
     try:
         xids = [[p.get(), p.get()] for p in peers]
         for p, (a, _b) in zip(peers, xids):
@@ -305,12 +321,11 @@ async def test_teardown_between_dispatch_and_route_settles_once():
         await _stop(ingest, peers)
 
 
-async def test_the_direct_regime_never_dispatches_early():
+async def test_the_direct_regime_never_dispatches_early(rig):
     """Pass-through: a reap's bytes are decoded and delivered in the
     delivery itself; nothing is batched, dispatched or in flight."""
     ingest = _ingest(bypass_bytes=16384)
     peers = _peers(ingest, 3)
-    rig = ReapRig()
     try:
         assert ingest.direct
         xids = [p.get() for p in peers]
@@ -319,6 +334,7 @@ async def test_the_direct_regime_never_dispatches_early():
         rig.flush(peers)
         assert [_settled(p) for p in peers] == [[x] for x in xids]
         assert ingest._flight is None and not ingest._asked
+        assert rig.fed == 0 and not rig.tier._sinks     # pass-through
         await settle()
         assert (ingest.ticks, ingest.ticks_early) == (0, 0)
         assert ingest.ticks_scalar == 1
@@ -326,7 +342,7 @@ async def test_the_direct_regime_never_dispatches_early():
         await _stop(ingest, peers)
 
 
-async def test_a_bucket_still_compiling_never_dispatches_early():
+async def test_a_bucket_still_compiling_never_dispatches_early(rig):
     """``warm='background'``: the reap's end finds the bucket cold,
     starts its compile and drains the streams through the scalar codec
     there — no dispatch, no flight; once the bucket is warm the next
@@ -336,7 +352,6 @@ async def test_a_bucket_still_compiling_never_dispatches_early():
     ingest._direct = False          # the batch regime, buckets cold
     ingest.bypass_bytes = 0
     peers = _peers(ingest, 3)
-    rig = ReapRig()
     try:
         xids = [p.get() for p in peers]
         for p, x in zip(peers, xids):
@@ -360,7 +375,7 @@ async def test_a_bucket_still_compiling_never_dispatches_early():
         await _stop(ingest, peers)
 
 
-@pytest.mark.parametrize('fed', ['push', 'reap'])
+@pytest.mark.parametrize('fed', ['push', 'reap', 'sink'])
 async def test_the_early_counter_counts_what_happened(fed):
     """``ticks_early`` beside ``ticks``: every tick a reap fed, and
     under asyncio's push only the follow-up ticks (here: the frame
@@ -370,26 +385,142 @@ async def test_the_early_counter_counts_what_happened(fed):
     col = Collector()
     ingest.bind_metrics(col)
     peers = _peers(ingest, 2)
-    rig = ReapRig()
+    rig = ReapRig(sink=fed == 'sink')
     try:
         for n in (1, 6):
             xids = [[p.get() for _ in range(n)] for p in peers]
             for p, xs in zip(peers, xids):
                 for x in xs:
                     p.reply(x)
-            if fed == 'reap':
-                rig.flush(peers)
-            else:
+            if fed == 'push':
                 for p in peers:
                     p.flush()
+            else:
+                rig.flush(peers)
             await settle()
             assert [_settled(p)[-n:] for p in peers] == xids
         # 1 reply: one tick; 6 replies at 4 frames a tick: two
         assert ingest.ticks == 3
-        assert ingest.ticks_early == (3 if fed == 'reap' else 1)
+        assert ingest.ticks_early == (1 if fed == 'push' else 3)
+        assert rig.fed == (4 if fed == 'sink' else 0)
         text = col.expose()
         assert 'zkstream_ingest_early_ticks %d' % ingest.ticks_early \
             in text
         assert 'zkstream_ingest_ticks 3' in text
     finally:
         await _stop(ingest, peers)
+
+
+# -- what withdraws a sink (io/connection.py ``resink``) ---------------
+
+class _PassInjector:
+    """An injector that changes nothing: its presence is what is asked
+    (on the connection: ``tx`` / ``rx``; on the ingest: the tick-time
+    questions)."""
+
+    def tx(self, conn, data):
+        return data
+
+    def rx(self, conn, data) -> None:
+        conn.emit('sockData', data)
+
+    def ingest_reset(self, conn) -> bool:
+        return False
+
+    def ingest_cut(self, conn, nbytes: int) -> int:
+        return 0
+
+
+def _waiting(p: Peer, ingest) -> bytes:
+    """Bytes received and not yet decoded: the codec's (a flip to the
+    pass-through regime hands them there), then the slot's."""
+    pend = p.conn.codec.take_pending()
+    p.conn.codec.restore_pending(pend)
+    slot = ingest._slots.get(id(p.conn))
+    return bytes(pend) + (b'' if slot is None else bytes(slot[1]))
+
+
+async def _withdrawn(how: str, sink: bool):
+    """Two peers, three replies each.  One reap brings each its first
+    reply and all but 9 bytes of its second, so bytes wait in the
+    slots behind a routed tick; then ``how`` happens (to peer 0, or to
+    the ingest); then the rest of the second reply and the third.
+    Returns what every peer observed, and what the rig fed before and
+    after."""
+    ingest = _ingest()
+    peers = _peers(ingest, 2)
+    rig = ReapRig(sink)
+    victim = peers[0]
+    heard: list = []
+    try:
+        xids = [[p.get() for _ in range(3)] for p in peers]
+        wires = []
+        for p, xs in zip(peers, xids):
+            for x in xs[:2]:
+                p.reply(x)
+            wires.append(p.take())
+            p.reply(xs[2])
+        rig.reap([(p.conn, w[:-9]) for p, w in zip(peers, wires)])
+        await settle()
+        assert [_settled(p) for p in peers] == [xs[:1] for xs in xids]
+        held = [_waiting(p, ingest) for p in peers]
+        assert all(held) and rig.fed == (2 if sink else 0)
+        token = rig.token(victim.conn)
+        assert (token in rig.tier._sinks) == sink
+
+        if how == 'conn_injector':
+            victim.conn.faults = _PassInjector()
+        elif how == 'ingest_injector':
+            ingest.faults = _PassInjector()
+        elif how == 'second_listener':
+            victim.conn.on('sockData', lambda d: heard.append(len(d)))
+        elif how == 'flip_direct':
+            ingest._flip_direct(list(ingest._slots.values()))
+            assert ingest.direct
+        elif how == 'state_exit':
+            victim.conn.close()
+            assert victim.conn.is_in_state('closing')
+        assert token not in rig.tier._sinks
+        if how in ('ingest_injector', 'flip_direct'):
+            assert not rig.tier._sinks
+        elif sink:
+            assert len(rig.tier._sinks) == 1    # the other keeps its own
+        fed = rig.fed
+        # nothing was lost on the way out of the slot
+        assert [_waiting(p, ingest) for p in peers] == held
+
+        rest = [w[-9:] + p.take() for p, w in zip(peers, wires)]
+        rig.reap([(p.conn, r) for p, r in zip(peers, rest)])
+        await settle()
+        await settle()
+        if how == 'second_listener':
+            assert heard == [len(rest[0])]      # a delivery, as ever
+        out = [(_settled(p), sorted(p.conn.reqs), _waiting(p, ingest))
+               for p in peers]
+        return out, xids, fed, rig.fed
+    finally:
+        await _stop(ingest, peers)
+
+
+@pytest.mark.parametrize('how', ['conn_injector', 'ingest_injector',
+                                 'second_listener', 'flip_direct',
+                                 'state_exit'])
+async def test_a_withdrawn_sink_loses_no_byte_and_keeps_the_order(how):
+    """Each thing that withdraws a connection's sink — an injector
+    installed on it or on its ingest mid-stream, a second ``sockData``
+    listener, the flip to the pass-through regime, the state's exit —
+    with half a reply in the slot: no byte is lost, the connection's
+    replies settle in the order sent, and everything observed is what
+    the same traffic gives with no sink at all; from the withdrawal on
+    the connection's bytes come through ``sockData`` (nothing more is
+    fed for it), while its neighbour keeps its own sink."""
+    want, xids, _f, never = await _withdrawn(how, sink=False)
+    got, _x, before, after = await _withdrawn(how, sink=True)
+    assert never == 0 and before == 2
+    assert got == want
+    for (settled, reqs, pending), xs in zip(got, xids):
+        assert settled == xs and not reqs and not pending
+    # the neighbour's second delivery was fed unless the ingest itself
+    # stopped taking sinks; the flip back to batch hands them out again
+    assert after - before == (0 if how == 'ingest_injector' else
+                              0 if how == 'flip_direct' else 1)

@@ -16,10 +16,12 @@ notifications, reserved-xid callbacks and state changes between them,
 ``xid_map`` and the bytes left over.  Each case runs with the C
 extension and under ``ZKSTREAM_NO_NATIVE``, and with the bytes handed
 over as asyncio's protocol push does (one call a connection: the
-scheduled tick dispatches and waits) and as a receive reap does (the
+scheduled tick dispatches and waits), as a receive reap does (the
 tier's ``_rx_reap`` over all of them: the batch is dispatched at the
 reap's end and the scheduled tick finds it — io/ingest.py, "The early
-dispatch").
+dispatch"), and as a reap does while the connections' sinks stand (its
+one call appends the bytes to the ingest's slots and tells the ingest
+once: io/transport.py ``rx_sink``).
 """
 
 import asyncio
@@ -237,20 +239,50 @@ class Peer:
         return bytes(pend)
 
 
+class _RigTx:
+    """A connection's send plane with the rig's tier behind
+    ``sink_rx`` — the one call of it that needs a tier's entry, which
+    these connections (no socket, no tier) do not have."""
+
+    def __init__(self, tx, tier, entry):
+        self._tx, self._tier, self._entry = tx, tier, entry
+
+    def __getattr__(self, name):
+        return getattr(self._tx, name)
+
+    def sink_rx(self, sink) -> None:
+        self._tier.rx_sink(self._entry, sink)
+
+
 class ReapRig:
     """A client tier whose native receiver is the test: ``reap`` runs
     the tier's real ``_rx_reap`` over the bytes given, a delivery a
-    connection, as the receiver thread's wake-up does."""
+    connection, as the receiver thread's wake-up does.  With ``sink``
+    a connection's sink (io/transport.py ``rx_sink``) reaches the
+    rig's tier, and the stand-in reap does with the table what the C
+    call does: a sunk token's bytes are appended to its bytearray and
+    make no item (tests/test_native_ext.py holds the C call to that)."""
 
-    def __init__(self):
+    def __init__(self, sink: bool = False):
+        self.sink = sink
         self.tier = TransportTier('mmsg', plane='client')
         self.tier._receiver, self.tier._ext = object(), self
         self._items: list = []
         self._tokens: dict = {}
 
-    def receiver_reap(self, _receiver):
+    def receiver_reap(self, _receiver, sinks=None, want=False):
         items, self._items = self._items, []
-        return items, 0, 0
+        left, each = [], []
+        for token, data in items:
+            buf = (sinks or {}).get(token)
+            if buf is not None and data.__class__ is bytes and data:
+                buf += data
+                each.append((token, len(data)))
+            else:
+                left.append((token, data))
+        fed = each and (len(each), sum(n for _t, n in each), 0,
+                        each if want else None)
+        return left, 0, 0, fed or None
 
     def token(self, conn) -> int:
         token = self._tokens.get(conn)
@@ -260,8 +292,18 @@ class ReapRig:
             # whose injector gate the cases' stand-in does not have
             e.on_bytes = lambda data: conn.emit('sockData', data)
             token = self._tokens[conn] = len(self._tokens) + 1
+            e.rx_token = token
             self.tier._rx[token] = e
+            if self.sink:
+                conn._tx = _RigTx(conn._tx, self.tier, e)
+                if conn._resink is not None:
+                    conn._resink()      # its state asks again, and is heard
         return token
+
+    @property
+    def fed(self) -> int:
+        """Deliveries the reaps' stand-in C call made into sinks."""
+        return self.tier.received_fed
 
     def reap(self, pairs) -> None:
         """``pairs``: (connection, bytes | -errno) in arrival order."""
@@ -534,7 +576,7 @@ CASES = {
 async def run_case(case, through_ingest: bool, use_native: bool,
                    seed: int, fed: str = 'push'):
     ingest = None
-    rig = ReapRig() if fed == 'reap' else None
+    rig = None if fed == 'push' else ReapRig(sink=fed == 'sink')
     if through_ingest:
         # one size class for the case whose callback closes a LATER
         # stream of the same tick: streams route in slot order within a
@@ -579,10 +621,16 @@ async def run_case(case, through_ingest: bool, use_native: bool,
     return snaps, expect, sum(a for a, _b in lanes), \
         sum(b for _a, b in lanes), \
         ingest and (ingest.lists_routed, ingest.lists_shared,
-                    ingest.ticks, ingest.ticks_early)
+                    ingest.ticks, ingest.ticks_early,
+                    rig and (rig.fed, rig.tier.received_reads))
 
 
-@pytest.mark.parametrize('fed', ['push', 'reap'])
+#: the case whose connection leaves ``connected`` with replies still
+#: to come: those come through ``sockData``, to its closing state
+PARTLY_SUNK = ('callback_closes_later_connection',)
+
+
+@pytest.mark.parametrize('fed', ['push', 'reap', 'sink'])
 @pytest.mark.parametrize('use_native', [True, False],
                          ids=['ext', 'no_native'])
 @pytest.mark.parametrize('case', list(CASES))
@@ -599,13 +647,23 @@ async def test_batch_route_equals_per_stream_reference(
                                           seed=29)
     got, lane_frames, laned, emitted, lists = await run_case(
         case, True, use_native, seed=29, fed=fed)
-    *lists, ticks, early = lists
+    *lists, ticks, early, reaped = lists
     lists = tuple(lists)
     # every device tick a reap fed was dispatched at the reap's end, or
     # (a follow-up) at the end of the tick before; asyncio's push
     # leaves only the follow-ups to go ahead of their tick
     assert ticks > 0
-    assert early == ticks if fed == 'reap' else early < ticks
+    assert early == ticks if fed != 'push' else early < ticks
+    # with the sinks standing the reap's one call put the bytes in the
+    # slots (every delivery, where nothing in the case withdraws one);
+    # without them every delivery came through ``sockData``
+    if fed == 'sink' and case == 'fault_injector_installed':
+        assert reaped[0] == 0 < reaped[1]   # it came before any byte
+    elif fed == 'sink':
+        assert 0 < reaped[0] <= reaped[1]
+        assert (case in PARTLY_SUNK) == (reaped[0] != reaped[1]), reaped
+    elif fed == 'reap':
+        assert reaped[0] == 0 < reaped[1]
     for i, (w, g) in enumerate(zip(want, got)):
         assert g == w, 'connection %d differs' % i
     assert any(w['log'] for w in want)       # the case observed something

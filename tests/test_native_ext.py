@@ -1102,13 +1102,31 @@ class _Rx:
         self.wake = self.ext.receiver_fileno(self.cap)
         self.got: dict[int, list] = {}
         self.calls = self.ns = 0
+        #: the sink table its reaps carry ({token: bytearray}), and
+        #: what they reported fed: connections, bytes, [(token, n)]
+        self.sinks: dict[int, bytearray] = {}
+        self.fed_conns = self.fed_bytes = 0
+        self.fed_each: list = []
+        self.want = False       # ask every reap for the fed tokens
 
     def reap(self) -> list:
-        items, calls, ns = self.ext.receiver_reap(self.cap)
+        want = self.want
+        items, calls, ns, fed = self.ext.receiver_reap(
+            self.cap, self.sinks, want)
         self.calls += calls
         self.ns += ns
         for token, data in items:
             self.got.setdefault(token, []).append(data)
+        if fed is not None:
+            conns, nbytes, fed_ns, each = fed
+            assert conns > 0 and nbytes > 0 and fed_ns >= 0
+            assert (each is not None) == want
+            self.fed_conns += conns
+            self.fed_bytes += nbytes
+            if want:
+                assert len(each) == conns
+                assert sum(n for _t, n in each) == nbytes
+                self.fed_each.extend(each)
         return items
 
     def readable(self, timeout: float = 5.0) -> bool:
@@ -1128,8 +1146,11 @@ class _Rx:
                 self.reap()
 
     def stream(self, token: int) -> bytes:
-        return b''.join(d for d in self.got.get(token, [])
-                        if isinstance(d, bytes))
+        """What the connection received so far: its sink first (a
+        sunk connection's bytes come as items only where the sink
+        could not take them, and these tests let that happen last)."""
+        return bytes(self.sinks.get(token, b'')) + b''.join(
+            d for d in self.got.get(token, []) if isinstance(d, bytes))
 
     def close(self) -> None:
         self.ext.receiver_close(self.cap)
@@ -1157,7 +1178,7 @@ def test_receiver_keeps_each_connections_order_and_its_own_clock():
         assert rx.calls > 0 and rx.ns > 0
         # nothing waits: the fd is quiet, a reap is empty and free
         assert not rx.readable(0.05)
-        assert rx.ext.receiver_reap(rx.cap) == ([], 0, 0)
+        assert rx.ext.receiver_reap(rx.cap) == ([], 0, 0, None)
         # one reap joins what several recvs brought
         pairs[0][1].sendall(b'x' * 300000)
         rx.until(lambda: len(rx.stream(tokens[0])) == len(want[0])
@@ -1202,7 +1223,7 @@ def test_receiver_reports_eof_and_a_reset_once_each_after_the_bytes():
         assert rx.got[ended] == [b'last words', b'']
         assert rx.got[reset] == [-errno.ECONNRESET]
         time.sleep(0.1)
-        assert rx.ext.receiver_reap(rx.cap) == ([], 0, 0)
+        assert rx.ext.receiver_reap(rx.cap) == ([], 0, 0, None)
         # forgetting an ended connection hands nothing back twice
         assert rx.ext.receiver_forget(rx.cap, ended) == []
         assert rx.ext.receiver_forget(rx.cap, reset) == []
@@ -1349,7 +1370,7 @@ def test_receiver_bound_stops_reading_until_the_reap():
         assert rx.readable()
         time.sleep(0.5)             # as far as it will go unreaped
         assert not done.is_set()
-        items, calls, _ns = rx.ext.receiver_reap(rx.cap)
+        items, calls, _ns, _fed = rx.ext.receiver_reap(rx.cap)
         assert [tok for tok, _d in items] == [token]
         first = items[0][1]
         assert limit <= len(first) < limit + buf
@@ -1404,13 +1425,141 @@ def test_receiver_close_with_bytes_waiting_then_refuses():
             b.close()
 
 
-def test_receiver_stress_many_connections_three_threads():
+def test_receiver_reap_feeds_a_sunk_token_and_hands_the_rest_as_items():
+    """A token in the reap's sink table has its chunks appended to its
+    bytearray inside the call — over several chunks and two reaps
+    exactly what the plain reap's joined ``bytes`` hold, behind what
+    the bytearray held — and makes no item; a token outside the table
+    comes back as an item, as without a table; ``fed`` says what was
+    fed, by token when asked."""
+    import time
+    rx = _Rx()
+    pairs = _sender_pairs(3)
+    try:
+        sunk, plain, twin = (rx.ext.receiver_add(rx.cap, a.fileno())
+                             for a, _b in pairs)
+        rx.sinks[sunk] = bytearray(b'held:')
+        rx.want = True
+        # several chunks a connection: a recv fills at most 256 KiB
+        first = bytes(range(256)) * 2400        # 600 KiB
+        for _a, b in pairs:
+            b.sendall(first)
+        rx.until(lambda: all(len(rx.stream(t)) >= len(first)
+                             for t in (plain, twin)))
+        for _a, b in pairs:
+            b.sendall(b'second reap')
+        whole = first + b'second reap'
+        rx.until(lambda: rx.stream(plain) == rx.stream(twin) == whole
+                 and len(rx.sinks[sunk]) == len(b'held:' + whole))
+        # the sunk bytes ARE the plain reap's bytes
+        assert rx.sinks[sunk] == b'held:' + rx.stream(twin)
+        assert sunk not in rx.got
+        assert rx.fed_bytes == len(whole)
+        assert {t for t, _n in rx.fed_each} == {sunk}
+        assert sum(n for _t, n in rx.fed_each) == rx.fed_bytes
+        # an empty table, None and no table read alike
+        for table in ({}, None):
+            assert rx.ext.receiver_reap(rx.cap, table) == ([], 0, 0, None)
+        with pytest.raises(TypeError):
+            rx.ext.receiver_reap(rx.cap, [sunk])
+    finally:
+        rx.close()
+        for a, b in pairs:
+            a.close()
+            b.close()
+
+
+def test_receiver_reap_gives_a_sunk_tokens_end_once_as_an_item():
+    """EOF and a reset of a SUNK connection come once each, as items,
+    after its bytes (which are in the sink by then); nothing is fed
+    for a connection that only ended."""
+    import errno
+    import socket
+    import time
+    rx = _Rx()
+    lsock = socket.socket()
+    lsock.bind(('127.0.0.1', 0))
+    lsock.listen(2)
+    conns = []
+    try:
+        for _ in range(2):
+            peer = socket.create_connection(lsock.getsockname())
+            mine, _addr = lsock.accept()
+            conns.append((mine, peer))
+        ended, reset = (rx.ext.receiver_add(rx.cap, mine.fileno())
+                        for mine, _peer in conns)
+        rx.sinks.update({ended: bytearray(), reset: bytearray()})
+        conns[0][1].sendall(b'last words')
+        conns[0][1].close()
+        conns[1][1].sendall(b'then reset')
+        rx.until(lambda: rx.sinks[reset] == b'then reset')
+        conns[1][0].sendall(b'never read')
+        conns[1][1].setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                               struct.pack('ii', 1, 0))
+        time.sleep(0.05)
+        conns[1][1].close()
+        rx.until(lambda: rx.got.get(ended) == [b'']
+                 and len(rx.got.get(reset, [])) >= 1)
+        assert rx.sinks[ended] == b'last words'
+        assert rx.sinks[reset] == b'then reset'
+        assert rx.got == {ended: [b''], reset: [-errno.ECONNRESET]}
+        assert rx.fed_bytes == len(b'last words') + len(b'then reset')
+        time.sleep(0.1)
+        assert rx.ext.receiver_reap(rx.cap, rx.sinks) == ([], 0, 0, None)
+        assert rx.ext.receiver_forget(rx.cap, ended) == []
+    finally:
+        rx.close()
+        lsock.close()
+        for mine, peer in conns:
+            mine.close()
+            peer.close()
+
+
+def test_receiver_reap_loses_no_byte_of_a_sink_that_cannot_grow():
+    """A bytearray with a live ``memoryview`` cannot be resized: the
+    reap leaves it as it was and hands the connection's bytes back as
+    an item, as for a token outside the table; with the view released
+    the next reap feeds it again.  No byte is lost and none comes
+    twice; a value that is no bytearray is no sink either."""
+    import time
+    rx = _Rx()
+    pairs = _sender_pairs(2)
+    try:
+        pinned, wrong = (rx.ext.receiver_add(rx.cap, a.fileno())
+                         for a, _b in pairs)
+        rx.sinks[pinned] = bytearray(b'before|')
+        rx.sinks[wrong] = b'not a bytearray'
+        view = memoryview(rx.sinks[pinned])
+        for _a, b in pairs:
+            b.sendall(b'while pinned|')
+        rx.until(lambda: all(rx.got.get(t) == [b'while pinned|']
+                             for t in (pinned, wrong)))
+        assert rx.sinks[pinned] == b'before|'
+        assert rx.fed_conns == 0
+        view.release()
+        pairs[0][1].sendall(b'after')
+        rx.until(lambda: rx.sinks[pinned] == b'before|after')
+        assert rx.got[pinned] == [b'while pinned|']
+        assert (rx.fed_conns, rx.fed_bytes) == (1, 5)
+        time.sleep(0.05)
+        assert rx.ext.receiver_reap(rx.cap, rx.sinks) == ([], 0, 0, None)
+    finally:
+        rx.close()
+        for a, b in pairs:
+            a.close()
+            b.close()
+
+
+@pytest.mark.parametrize('sunk', [False, True],
+                         ids=['items', 'half_sunk'])
+def test_receiver_stress_many_connections_three_threads(sunk):
     """64 connections written by one thread, received by the
     receiver's, reaped by a third with a shortened switch interval,
     while the main thread adds, forgets and closes 300 more under
     them: every long-lived connection's stream is whole and in order
     (a lost wake-up would hang the reaper, a lost update would drop or
-    repeat bytes)."""
+    repeat bytes) — with every second connection's bytes appended to
+    its sink by the reap itself (``half_sunk``) as without a table."""
     import socket
     import sys
     import threading
@@ -1444,6 +1593,8 @@ def test_receiver_stress_many_connections_three_threads():
     try:
         tokens = [rx.ext.receiver_add(rx.cap, a.fileno())
                   for a, _b in pairs]
+        if sunk:
+            rx.sinks.update((t, bytearray()) for t in tokens[::2])
         for t in threads:
             t.start()
         for k in range(300):
@@ -1467,6 +1618,9 @@ def test_receiver_stress_many_connections_three_threads():
         assert not any(t.is_alive() for t in threads)
         for t, w in zip(tokens, want):
             assert rx.stream(t) == w
+        # a sunk connection made no item, the others fed nothing
+        assert not any(t in rx.got for t in rx.sinks)
+        assert rx.fed_bytes == sum(map(len, rx.sinks.values()))
     finally:
         sys.setswitchinterval(old)
         stop.set()

@@ -833,9 +833,12 @@ needs_receiver = pytest.mark.skipif(
     'or the extension is missing')
 
 #: How a client connection's bytes come in: asyncio's protocol push
-#: (no tier, or one that does not own the receive), or the loop's
-#: shared client tier's native receiver thread.
-RX_PATHS = ['asyncio_push'] + (['receiver_thread']
+#: (no tier, or one that does not own the receive); the loop's shared
+#: client tier's native receiver thread, a delivery a connection
+#: through ``_sock_data``; or that thread with the connections under a
+#: fleet ingest and their sinks standing — the reap's one C call
+#: appends the bytes to the ingest's slots (``rx_sink``).
+RX_PATHS = ['asyncio_push'] + (['receiver_thread', 'receiver_sink']
                                if _has_receiver() else [])
 
 
@@ -847,13 +850,72 @@ def _rx_tier(path: str) -> TransportTier | None:
     return tier
 
 
+def _counting_ingest():
+    """A force-device fleet ingest that counts the bytes that reach
+    it, whichever way: a ``feed`` a delivery, or a ``fed`` a reap."""
+    from zkstream_tpu.io.ingest import FleetIngest
+
+    class Counting(FleetIngest):
+        arrived = 0
+
+        def feed(self, conn, data, t_rx=0):
+            self.arrived += len(data)
+            super().feed(conn, data, t_rx)
+
+        def fed(self, nbytes, t_rx=0):
+            self.arrived += nbytes
+            super().fed(nbytes, t_rx)
+
+    return Counting(bypass_bytes=0, warm='block', placement='host',
+                    max_frames=8, min_len=512)
+
+
+class _RxGroup:
+    """The peers of one receive path, and how many bytes reached their
+    connections so far (the waits of a test that cuts a stream)."""
+
+    def __init__(self, path: str):
+        self.path = path
+        self.tier = _rx_tier(path)
+        self.ingest = (_counting_ingest() if path == 'receiver_sink'
+                       else None)
+        self.peers: list = []
+
+    async def peer(self, idx: int, **kw) -> '_RxPeer':
+        p = _RxPeer(idx, self.tier, ingest=self.ingest, **kw)
+        self.peers.append(await p.start())
+        return p
+
+    def arrived(self) -> int:
+        if self.ingest is not None:
+            return self.ingest.arrived
+        return sum(sum(p.chunks) for p in self.peers)
+
+    def sunk(self, p: '_RxPeer') -> bool:
+        """Does ``p``'s sink stand?"""
+        return (self.tier is not None
+                and p.entry.rx_token in self.tier._sinks)
+
+    async def close(self) -> None:
+        for p in self.peers:
+            await p.stop()
+        if self.tier is not None:
+            assert not self.tier._rx and not self.tier._sinks
+            assert not self.tier._sink_owners
+            self.tier.close()
+        if self.ingest is not None:
+            self.ingest.close()
+
+
 class _RxStub:
     """What a ZKConnection asks of its client."""
 
-    def __init__(self, tier, faults=None):
+    def __init__(self, tier, faults=None, ingest=None):
         from zkstream_tpu.io.session import ZKSession
         self.transport_tier = tier
         self.faults = faults
+        if ingest is not None:
+            self.ingest = ingest
         self.use_native_codec = False
         self.session = ZKSession(30000)
 
@@ -866,10 +928,13 @@ class _RxPeer:
     over a socket pair, handshaken by the test, which plays the member
     on ``self.peer``; everything the connection observes is logged."""
 
-    def __init__(self, idx: int, tier, faults=None, tcp: bool = False):
+    def __init__(self, idx: int, tier, faults=None, tcp: bool = False,
+                 ingest=None):
         self.idx, self.tier, self.tcp = idx, tier, tcp
-        self.client = _RxStub(tier, faults)
+        self.client = _RxStub(tier, faults, ingest)
         self.log: list = []
+        #: the length of every ``sockData`` segment — listened for only
+        #: WITHOUT an ingest: a second listener withdraws the sink
         self.chunks: list[int] = []
 
     async def start(self) -> '_RxPeer':
@@ -889,7 +954,8 @@ class _RxPeer:
         self.conn = conn = ZKConnection(
             self.client, Backend('127.0.0.1', 1 + self.idx))
         conn.codec = PacketCodec(use_native=False)
-        conn.on('sockData', lambda d: self.chunks.append(len(d)))
+        if getattr(self.client, 'ingest', None) is None:
+            conn.on('sockData', lambda d: self.chunks.append(len(d)))
         conn.on('packet', lambda p: self.log.append(('packet', p)))
         for ev in ('sockEnd', 'sockClose'):
             conn.on(ev, lambda ev=ev: self.log.append((ev,)))
@@ -958,37 +1024,46 @@ async def test_client_receive_parity_at_every_byte_offset(path):
     the bytes, every connection decodes the IDENTICAL frame stream —
     the whole reply corpus, cut in two at EVERY byte offset, three
     connections of one tier at once, each cut elsewhere."""
-    tier = _rx_tier(path)
+    group = _RxGroup(path)
+    tier = group.tier
     wire, want = _reply_wire()
-    peers = [await _RxPeer(i, tier).start() for i in range(3)]
+    peers = [await group.peer(i) for i in range(3)]
     try:
         for p in peers:
-            on_thread = path == 'receiver_thread'
+            on_thread = path != 'asyncio_push'
             assert (p.entry is not None and p.entry.rx_token != 0) \
                 == on_thread
+            assert group.sunk(p) == (path == 'receiver_sink')
+        sent = group.arrived()
         for base in range(1, len(wire), 3):
             cuts = [min(base + i, len(wire) - 1) for i in range(3)]
             for p, cut in zip(peers, cuts):
                 p.expect(REPLIES)
-                del p.log[:], p.chunks[:]
+                del p.log[:]
                 p.peer.send(wire[:cut])
+            sent += sum(cuts)
+            await _until(lambda: group.arrived() == sent)
             for p, cut in zip(peers, cuts):
-                await _until(lambda: sum(p.chunks) == cut)
                 p.peer.send(wire[cut:])
+            sent += 3 * len(wire) - sum(cuts)
+            await _until(lambda: group.arrived() == sent)
             for p in peers:
-                await _until(lambda: sum(p.chunks) == len(wire))
+                await _until(lambda: len(p.packets()) >= len(want))
                 assert p.packets() == want, (path, p.idx, cuts)
         if tier is not None:
             assert tier.received_reads >= 2 * len(peers)
             assert tier.received_batches > 0
             assert tier.received_ctr.value({'plane': 'client'}) \
                 == tier.received_reads
+            # with the sinks standing the reaps' C call made every
+            # delivery but the handshakes' itself; without them none
+            assert tier.received_fed == (
+                tier.received_reads - len(peers)
+                if path == 'receiver_sink' else 0)
+            assert tier.fed_ctr.value({'plane': 'client'}) \
+                == tier.received_fed
     finally:
-        for p in peers:
-            await p.stop()
-        if tier is not None:
-            assert not tier._rx
-            tier.close()
+        await group.close()
 
 
 class _RxTap:
@@ -1013,11 +1088,11 @@ async def test_fault_injector_rx_boundary_stays_per_connection(path):
     segment is one connection's: a reap of many connections' bytes is
     as many ``faults.rx`` calls, each with the connection whose socket
     received them — never one joined buffer, never a neighbour's."""
-    tier = _rx_tier(path)
+    group = _RxGroup(path)
+    tier = group.tier
     tap = _RxTap()
     wire, want = _reply_wire()
-    peers = [await _RxPeer(i, tier, faults=tap).start()
-             for i in range(8)]
+    peers = [await group.peer(i, faults=tap) for i in range(8)]
     try:
         del tap.calls[:]
         for p in peers:
@@ -1030,11 +1105,11 @@ async def test_fault_injector_rx_boundary_stays_per_connection(path):
         by_conn = {p.conn: p for p in peers}
         for conn, data in tap.calls:
             assert data == b'%c' % (65 + by_conn[conn].idx) * len(data)
+        # a connection with an injector has no sink, ingest or none
+        assert not any(group.sunk(p) for p in peers)
+        assert tier is None or tier.received_fed == 0
     finally:
-        for p in peers:
-            await p.stop()
-        if tier is not None:
-            tier.close()
+        await group.close()
 
 
 @pytest.mark.parametrize('path', RX_PATHS)
@@ -1042,13 +1117,19 @@ async def test_client_receive_end_and_reset_read_the_same(path):
     """EOF is ``sockEnd`` and a reset is ``sockError`` with the
     ``OSError`` on both paths, each once and behind the connection's
     last bytes; the transport is torn down as asyncio tears it down."""
-    tier = _rx_tier(path)
+    group = _RxGroup(path)
     wire, want = _reply_wire()
-    ended = await _RxPeer(0, tier, tcp=True).start()
-    reset = await _RxPeer(1, tier, tcp=True).start()
+    ended = await group.peer(0, tcp=True)
+    reset = await group.peer(1, tcp=True)
     try:
         ended.expect(REPLIES)
         ended.peer.send(wire)
+        if group.ingest is not None:
+            # under an ingest replies are routed a tick later, and an
+            # end that overtakes the tick takes the slot's bytes with
+            # the connection: let them settle first
+            await _until(lambda: ended.packets() == want)
+            assert group.sunk(ended) and group.tier.received_fed >= 1
         ended.peer.shutdown(socket.SHUT_WR)
         await _until(lambda: ('sockClose',) in ended.log)
         assert ended.packets() == want
@@ -1068,11 +1149,7 @@ async def test_client_receive_end_and_reset_read_the_same(path):
             ('sockError', 'ConnectionResetError', errno.ECONNRESET)]
         assert reset.conn.transport is None     # error -> closed
     finally:
-        await ended.stop()
-        await reset.stop()
-        if tier is not None:
-            assert not tier._rx
-            tier.close()
+        await group.close()
 
 
 @pytest.mark.parametrize('path', RX_PATHS)
@@ -1082,28 +1159,35 @@ async def test_connection_pause_and_resume_reading(path):
     know), what had already arrived is delivered first, and
     ``resume_reading`` puts the connection back on the path it was
     made on with nothing lost."""
-    tier = _rx_tier(path)
+    group = _RxGroup(path)
+    tier = group.tier
+    sink = path == 'receiver_sink'
     wire, want = _reply_wire()
-    p = await _RxPeer(0, tier).start()
+    p = await group.peer(0)
     try:
         p.expect(REPLIES)
         p.peer.send(wire[:100])
-        await _until(lambda: sum(p.chunks) == 100)
+        await _until(lambda: group.arrived() == 100)
+        assert group.sunk(p) == sink
         p.conn.pause_reading()
         p.peer.send(wire[100:])
         await asyncio.sleep(0.05)
-        assert sum(p.chunks) == 100
+        assert group.arrived() == 100
         if tier is not None:
+            # paused: no thread reads it, and so nothing feeds a sink
             assert p.entry.rx_transport is None and not tier._rx
+            assert not tier._sinks and not tier._sink_owners
         p.conn.resume_reading()
-        await _until(lambda: sum(p.chunks) == len(wire))
+        await _until(lambda: group.arrived() == len(wire))
+        await _until(lambda: len(p.packets()) >= len(want))
         assert p.packets() == want
         if tier is not None:
             assert p.entry.rx_token != 0
+            # read again on the path it was made on: the sink with it
+            assert group.sunk(p) == sink
+            assert (tier.received_fed >= 2) == sink
     finally:
-        await p.stop()
-        if tier is not None:
-            tier.close()
+        await group.close()
 
 
 @needs_receiver
@@ -1158,8 +1242,8 @@ class _ReapOnce:
     def __getattr__(self, name):
         return getattr(self.ext, name)
 
-    def receiver_reap(self, cap):
-        return self.items, 0, 0
+    def receiver_reap(self, cap, _sinks=None, _want=False):
+        return self.items, 0, 0, None
 
 
 @needs_receiver
@@ -1330,12 +1414,16 @@ async def test_after_reap_runs_what_the_deliveries_left():
 
 
 @needs_receiver
-async def test_a_reap_hands_the_fleet_ingest_its_early_dispatch():
+@pytest.mark.parametrize('sink', [False, True],
+                         ids=['through_sock_data', 'sunk'])
+async def test_a_reap_hands_the_fleet_ingest_its_early_dispatch(sink):
     """Through the real receiver thread: the connections of a fleet
-    ingest get their bytes in a reap, the ingest dispatches its batch
-    at the reap's end (``ticks_early`` = ``ticks``) and the scheduled
-    tick delivers every connection the stream the scalar drain
-    decodes."""
+    ingest get their bytes in a reap — a delivery a connection through
+    ``_sock_data`` (a second ``sockData`` listener stands: no sink), or
+    appended to their slots by the reap's one C call (``sunk``) — the
+    ingest dispatches its batch at the reap's end (``ticks_early`` =
+    ``ticks``) and the scheduled tick delivers every connection the
+    stream the scalar drain decodes."""
     from zkstream_tpu.io.ingest import FleetIngest
     tier = _rx_tier('receiver_thread')
     ingest = FleetIngest(bypass_bytes=0, warm='block', placement='host',
@@ -1344,11 +1432,14 @@ async def test_a_reap_hands_the_fleet_ingest_its_early_dispatch():
     peers = []
     try:
         for i in range(3):
-            p = _RxPeer(i, tier)
-            p.client.ingest = ingest
+            p = _RxPeer(i, tier, ingest=ingest)
             peers.append(await p.start())
+            if not sink:
+                p.conn.on('sockData', lambda d, p=p: p.chunks.append(
+                    len(d)))
         for p in peers:
             assert p.entry.rx_token and id(p.conn) in ingest._slots
+            assert (p.entry.rx_token in tier._sinks) == sink
             p.expect(REPLIES)
             p.peer.send(wire)
         await _until(lambda: all(len(p.packets()) == len(want)
@@ -1357,11 +1448,78 @@ async def test_a_reap_hands_the_fleet_ingest_its_early_dispatch():
         assert ingest.ticks >= 1 and ingest.ticks_scalar == 0
         assert ingest.ticks_early == ingest.ticks
         assert ingest._flight is None
+        if sink:
+            assert tier.received_fed == tier.received_reads - 3 > 0
+            assert not any(p.chunks for p in peers)
+        else:
+            assert tier.received_fed == 0
+            assert all(sum(p.chunks) == len(wire) for p in peers)
+        assert tier.fed_ctr.value({'plane': 'client'}) \
+            == tier.received_fed
     finally:
         for p in peers:
             await p.stop()
+        assert not tier._sinks and not tier._sink_owners
         tier.close()
         ingest.close()
+
+
+def _cuts(rng, n: int, pieces: int) -> list[int]:
+    """``pieces - 1`` distinct cut points inside ``n`` bytes, sorted,
+    with both ends."""
+    return [0] + sorted(rng.sample(range(1, n), pieces - 1)) + [n]
+
+
+@needs_receiver
+@pytest.mark.parametrize('seed', [3, 11, 29, 47])
+async def test_a_stream_cut_anywhere_settles_the_same_through_the_sink(
+        seed):
+    """The property the sink hangs on: one reply stream a connection,
+    cut at random points (four connections, two to nine pieces each,
+    a piece sent when the one before has arrived — every cut is a
+    delivery of its own), gives every connection the packets the
+    scalar decoder reads from the whole stream, in order — fed into
+    the ingest's slots by the reaps' C call, and handed over a
+    delivery a connection through ``_sock_data`` (the same fleet with
+    a second ``sockData`` listener, which withdraws every sink)."""
+    import random
+    wire, want = _reply_wire()
+    seen = {}
+    for path in ('receiver_sink', 'withdrawn'):
+        rng = random.Random(seed)
+        group = _RxGroup('receiver_sink')
+        peers = [await group.peer(i) for i in range(4)]
+        try:
+            if path == 'withdrawn':
+                for p in peers:
+                    p.conn.on('sockData', lambda d, p=p:
+                              p.chunks.append(len(d)))
+            assert [group.sunk(p) for p in peers] \
+                == [path == 'receiver_sink'] * 4
+            plans = [_cuts(rng, len(wire), rng.randrange(2, 10))
+                     for _ in peers]
+            for p in peers:
+                p.expect(REPLIES)
+            sent = group.arrived()
+            for step in range(max(map(len, plans)) - 1):
+                for p, cuts in zip(peers, plans):
+                    if step + 1 < len(cuts):
+                        p.peer.send(wire[cuts[step]:cuts[step + 1]])
+                        sent += cuts[step + 1] - cuts[step]
+                await _until(lambda: group.arrived() == sent)
+            for p in peers:
+                await _until(lambda: len(p.packets()) >= len(want))
+            seen[path] = [p.packets() for p in peers]
+            fed = group.tier.received_fed
+            if path == 'withdrawn':
+                assert fed == 0
+                assert [len(p.chunks) for p in peers] == [
+                    len(cuts) - 1 for cuts in plans]
+            else:
+                assert fed == sum(len(cuts) - 1 for cuts in plans)
+        finally:
+            await group.close()
+    assert seen['receiver_sink'] == seen['withdrawn'] == [want] * 4
 
 
 # -- e2e over real sockets: parity + accounting + mntr -----------------

@@ -175,7 +175,11 @@ class _SocketProtocol(asyncio.Protocol):
 
     Both feed the same ``_sock_data`` -> ``sockData``, so the fault
     injector's boundary, the ``client.rx`` span and every state's
-    handlers do not know which one brought the bytes."""
+    handlers do not know which one brought the bytes.  One thing the
+    tier's reap does without ``_sock_data``: while state ``connected``
+    has a sink standing (``resink`` there: all that ``sockData`` would
+    do is append the bytes to the fleet ingest's slot), the reap's one
+    C call makes that append itself."""
 
     def __init__(self, conn: 'ZKConnection'):
         self._conn = conn
@@ -272,7 +276,13 @@ class ZKConnection(FSM):
         #: Optional FaultInjector (io/faults.py): when the owning
         #: client carries one, dials, received bytes and outbound
         #: frames route through its seeded fault schedule.
-        self.faults = getattr(client, 'faults', None)
+        self._faults = getattr(client, 'faults', None)
+        #: State ``connected`` under a fleet ingest, else None: looks
+        #: again at where the receive may put this connection's bytes
+        #: (``resink`` there) — called when something it depends on
+        #: changes: the ingest's word, the ``sockData`` listeners, the
+        #: injector.
+        self._resink = None
         self.last_error: Exception | None = None
         self._xid = 0
         #: xid -> ZKRequest for everything awaiting a reply
@@ -311,6 +321,27 @@ class ZKConnection(FSM):
                 'by backend')
             self.bind_fsm_metrics(collector, 'ZKConnection')
         super().__init__('init')
+
+    @property
+    def faults(self):
+        return self._faults
+
+    @faults.setter
+    def faults(self, injector) -> None:
+        self._faults = injector
+        if self._resink is not None:
+            self._resink()
+
+    def on(self, event: str, cb) -> 'ZKConnection':
+        super().on(event, cb)
+        if event == 'sockData' and self._resink is not None:
+            self._resink()
+        return self
+
+    def remove_listener(self, event: str, cb) -> None:
+        super().remove_listener(event, cb)
+        if event == 'sockData' and self._resink is not None:
+            self._resink()
 
     # -- public controls (reference: lib/connection-fsm.js:51-76) --
 
@@ -536,7 +567,7 @@ class ZKConnection(FSM):
                 held = self._listeners.get('packet')
                 return (held is not None and len(held) == 1
                         and held[0] is session.packet_listener
-                        and self.faults is None)
+                        and self._faults is None)
 
             def lane(pkts, err, now, times=None):
                 """The direct settle lane: what one routed stream of
@@ -588,9 +619,6 @@ class ZKConnection(FSM):
                     deliver(pkts[n:], err, times and times[n:])
                 return n
 
-            self.ingest.register(self, lane)
-            S.defer(lambda: self.ingest.unregister(self))
-
             def on_sock(data):
                 ing = self.ingest
                 if not ing.direct:
@@ -610,8 +638,48 @@ class ZKConnection(FSM):
                     err = e
                 ing.note_direct(len(data), len(pkts))
                 deliver(pkts, err)
-            S.on(self, 'sockData', on_sock)
+            sock_listener = S.on(self, 'sockData', on_sock)
             S.on(self, 'ingestDeliver', deliver)
+
+            #: the ingest's word (``sink``): its slot's accumulator
+            #: while bytes may be appended to it from outside, else None
+            opened = None
+
+            def resink():
+                """Where the receive puts this connection's bytes.
+                Straight into the ingest's slot (the tier's reap
+                appends them in its one C call and tells the ingest
+                once a reap: io/transport.py ``rx_sink``) while that
+                is ALL a delivery would do: the ingest takes them
+                (batch regime, no injector: ``opened``), ``on_sock``
+                is the one ``sockData`` listener, and no injector
+                stands in this stream — the lane's rule, for bytes.
+                Else through ``_sock_data``, as ever.  Nothing here
+                sets it: it follows what is so."""
+                buf = opened
+                if buf is not None:
+                    held = self._listeners.get('sockData')
+                    if (self._faults is not None or held is None
+                            or len(held) != 1
+                            or held[0] is not sock_listener):
+                        buf = None
+                self._tx.sink_rx(None if buf is None
+                                 else (buf, self.ingest, self))
+
+            def sink(buf):
+                nonlocal opened
+                opened = buf
+                resink()
+
+            def leave():
+                # the slot's bytes go back to the codec with no sink
+                # left to put more behind them
+                self.ingest.unregister(self)
+                self._resink = None
+
+            self._resink = resink
+            self.ingest.register(self, lane, sink)
+            S.defer(leave)
         else:
             def on_data(data):
                 err = None
@@ -787,10 +855,10 @@ class ZKConnection(FSM):
         elif self._rx_t0:
             self._rx_t0 = 0
         with sp:
-            if self.faults is None:
+            if self._faults is None:
                 self.emit('sockData', data)
             else:
-                self.faults.rx(self, data)
+                self._faults.rx(self, data)
 
     def rx_mark(self, t_rx: int = 0) -> tuple:
         """What a reply settled now knows of its way in (profiler
@@ -814,10 +882,10 @@ class ZKConnection(FSM):
 
     def _write(self, pkt: dict) -> None:
         data = self.codec.encode(pkt)
-        if self.faults is not None:
+        if self._faults is not None:
             # Per-frame fault boundary, BEFORE the cork: may truncate
             # the frame and schedule an injected reset.
-            out = self.faults.tx(self, data)
+            out = self._faults.tx(self, data)
             if out is None:
                 return
             if out is not data:

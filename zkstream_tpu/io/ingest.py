@@ -37,6 +37,15 @@ The tick is synchronous inside the event loop: all ``data_received``
 callbacks of one select cycle run before the ``call_soon``-scheduled
 tick, so one tick coalesces everything the loop just read.
 
+How bytes reach a slot: :meth:`FleetIngest.feed`, a call a delivery —
+or, where the loop's shared client tier's receiver thread reads the
+connection and a delivery would do nothing but that append
+(:attr:`FleetIngest.sinkable`, and the connection's own conditions:
+io/connection.py ``resink``), the tier's reap appends to the slot's
+bytearray itself, in its one C call, and tells the ingest once a reap
+(:meth:`FleetIngest.fed`): the window's bytes, the tick, the early
+dispatch.  The slots do not know which it was.
+
 **The early dispatch.**  A tick has two halves — build the batches and
 dispatch them (phases ``batch`` and ``dispatch``), then read the
 results back and route them (``readback`` and ``route``) — and between
@@ -333,6 +342,11 @@ class FleetIngest:
         #: connection's direct settle lane (:meth:`register`), None
         #: for one that takes its packets as 'ingestDeliver' events
         self._slots: dict[int, tuple] = {}
+        #: id(conn) -> the ``sink`` its connection registered with
+        #: (:meth:`register`): called with the slot's accumulator while
+        #: received bytes may be appended to it from outside
+        #: (:attr:`sinkable`), with None when that ends
+        self._sinks: dict[int, object] = {}
         # the process that holds a fleet's sessions makes and frees a
         # ``bytes`` a reply body, ~1 MB each in a herd of large
         # re-reads: keep them for the next tick (utils/alloc.py)
@@ -511,7 +525,7 @@ class FleetIngest:
         #: the pass-through regime the per-connection rx gate already
         #: owns byte-level faults — the drain there IS the scalar
         #: codec — so these hooks fire only on the batched tick.
-        self.faults = None
+        self._faults = None
         #: id(conn) -> bytes withheld from the current tick by the
         #: injector; re-appended after the tick routes (FIFO: the
         #: suffix of a slot goes back to the same position).
@@ -522,9 +536,37 @@ class FleetIngest:
         #: a busy loop until new data arrives
         self._no_hold: set[int] = set()
 
+    @property
+    def faults(self):
+        return self._faults
+
+    @faults.setter
+    def faults(self, injector) -> None:
+        # an injector cuts and resets at tick time what ``feed`` put
+        # in the slots and what it holds back (``_held``): every byte
+        # comes through ``feed`` while one stands
+        self._faults = injector
+        self._sink_all(self.sinkable)
+
+    @property
+    def sinkable(self) -> bool:
+        """May a connection's received bytes be appended to its slot
+        from outside (:meth:`fed`)?  Where :meth:`feed` itself would do
+        that append and nothing else: the batch regime, no injector."""
+        return not self._direct and self._faults is None
+
+    def _sink_all(self, on: bool) -> None:
+        """Tell every connection that brought a ``sink`` whether its
+        slot takes bytes from outside from now on."""
+        for cid, sink in list(self._sinks.items()):
+            slot = self._slots.get(cid)
+            if slot is not None:
+                sink(slot[1] if on else None)
+
     # -- connection registry --
 
-    def register(self, conn: 'ZKConnection', lane=None) -> None:
+    def register(self, conn: 'ZKConnection', lane=None,
+                 sink=None) -> None:
         """Give ``conn`` a slot until :meth:`unregister`.  ``lane`` is
         the one callable its state ``connected`` hands over
         (io/connection.py): ``lane(pkts, err, now, times) -> int``
@@ -537,9 +579,23 @@ class FleetIngest:
         ``unregister`` (the state's exit) drops it, so it is never
         called outside that state.  Without one the stream goes out
         as the connection's ``'ingestDeliver'`` event, as the scalar,
-        fallback and pass-through deliveries always do."""
+        fallback and pass-through deliveries always do.
+
+        ``sink`` is the connection's other callable, ``sink(buf)``:
+        called with the slot's accumulator (whose identity never
+        changes: it is extended, cleared and cut in place) whenever
+        what receives for the connection may append to it directly and
+        tell the ingest once a batch (:meth:`fed`) instead of calling
+        :meth:`feed` a delivery — :attr:`sinkable`: here, and when the
+        regime returns to batch or an injector leaves — and with None
+        when it may not: the pass-through flip, an injector, and
+        :meth:`unregister`."""
         slot = self._slots.setdefault(id(conn),
                                       (conn, bytearray(), lane))
+        if sink is not None:
+            self._sinks[id(conn)] = sink
+            if self.sinkable:
+                sink(slot[1])
         # A partial steady-state frame may have ridden the same TCP
         # segment as the ConnectResponse.  In the BATCH regime it must
         # migrate out of the scalar decoder into the slot (the tick
@@ -580,6 +636,9 @@ class FleetIngest:
                 alloc.fit_collector(self, alive)
 
     def unregister(self, conn: 'ZKConnection') -> None:
+        sink = self._sinks.pop(id(conn), None)
+        if sink is not None:
+            sink(None)      # nothing lands in the slot from here on
         slot = self._slots.pop(id(conn), None)
         self._fit_collector()
         self._no_hold.discard(id(conn))
@@ -625,6 +684,34 @@ class FleetIngest:
             # fed by a reap: the batch is dispatched when the reap has
             # fed the last of its connections
             self._asked = after_reap(self._reaped)
+
+    def fed(self, nbytes: int, t_rx: int = 0) -> None:
+        """A reap of the transport tier appended ``nbytes`` to the
+        slots of connections whose sink stands (:meth:`register`;
+        io/transport.py ``rx_sink``): :meth:`feed`'s plain branch for
+        all of them, once a reap and not once a connection.  ``t_rx``:
+        inside a profiler session the start of that reap's receive
+        call, after :meth:`fed_mark` stamped each of them; else 0."""
+        self._window_bytes += nbytes
+        if not t_rx and self._rx_marks:
+            self._rx_marks.clear()          # the session is over
+        self._schedule()
+        if not self._asked:
+            self._asked = after_reap(self._reaped)
+
+    def fed_mark(self, conn: 'ZKConnection', end: int,
+                 t_rx: int) -> None:
+        """Profiler sessions only: the receive call that began at
+        ``t_rx`` fed ``conn``'s slot through its sink, which holds
+        ``end`` bytes with them — what ``ZKConnection._sock_data`` and
+        :meth:`feed` stamp a delivery: the connection's ``_rx_t0``,
+        the slot's mark."""
+        conn._rx_t0 = t_rx
+        marks = self._rx_marks.get(id(conn))
+        if marks is None:
+            self._rx_marks[id(conn)] = [[end, t_rx]]
+        else:
+            marks.append([end, t_rx])
 
     @property
     def direct(self) -> bool:
@@ -1134,6 +1221,7 @@ class FleetIngest:
                 conn.codec.restore_pending(bytes(buf))
                 buf.clear()
         self._direct = True
+        self._sink_all(False)   # from here every byte through ``feed``
 
     def _flip_batch(self) -> None:
         """Pass-through -> batch: reclaim each codec's partial-frame
@@ -1144,6 +1232,7 @@ class FleetIngest:
                 resid = conn.codec.take_pending()
                 if resid:
                     buf[:0] = resid
+        self._sink_all(self.sinkable)
 
     def _tick(self) -> None:
         """The scheduled tick: the second half of the device tick in
@@ -1249,7 +1338,7 @@ class FleetIngest:
                 self._flip_batch()
             sp.set(tick=None, detail='direct', nbytes=win)
             return True
-        if self.faults is not None:
+        if self._faults is not None:
             self._inject_tick_faults()
         # Phase ``batch`` (host span ``ingest.batch``) opens with the
         # scan for the slots that hold bytes — the first step of

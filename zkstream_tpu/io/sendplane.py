@@ -365,6 +365,14 @@ class SendPlane:
         if self._entry is not None:
             self._tier.rx_forget(self._entry, transport)
 
+    def sink_rx(self, sink) -> None:
+        """Where the tier's receiver may put this connection's bytes
+        itself (``TransportTier.rx_sink``: ``(accumulator, owner,
+        conn)``), or None: through ``on_bytes`` again.  Nothing without
+        a tier: asyncio's protocol push has no sink."""
+        if self._entry is not None:
+            self._tier.rx_sink(self._entry, sink)
+
     def reset(self) -> None:
         """Drop corked frames without writing (connection aborted:
         the bytes have nowhere to go) — anything already deferred to

@@ -111,12 +111,38 @@ when no ``recv`` of the fd is in flight, and delivers what was received
 and not yet reaped first; a connection's unreaped bytes are bounded in
 the thread (``RECEIVER_LIMIT``), behind which the kernel's socket
 buffer pushes back on the peer; ``close`` gives live connections back
-to their transports.  A reap is also the one moment that knows a loop
+to their transports.
+
+Where a reap's bytes go.  A fleet connection's ``on_bytes`` is a chain
+of Python calls (``_sock_data`` -> ``sockData`` -> the state's handler
+-> ``FleetIngest.feed``) that ends by appending the bytes to the
+ingest's slot for the connection, a bytearray.  While that append is
+ALL the chain would do, the connection says so (:meth:`TransportTier.
+rx_sink`: state ``connected`` under an ingest in its batch regime, no
+injector on either, no second ``sockData`` listener —
+io/connection.py ``resink``; it follows what is so, nothing sets it)
+and the tier keeps ``{token: bytearray}`` (``_sinks``) for the
+connections its thread reads.  A reap's ONE C call
+(``receiver_reap(receiver, sinks, want)``) then appends such a
+connection's chunks straight to its bytearray — no ``bytes``, no
+tuple, no Python call a connection — and returns every other
+connection's bytes, and every EOF and errno (a sunk connection's
+too), as items, which take ``on_bytes`` / ``on_eof`` / ``on_error`` as
+ever, after the feeds.  The ingest hears of a reap's feeds once
+(``owner.fed``: its byte window, its tick, its early dispatch).  A
+connection's bytes travel one way at a time — what the thread held
+when a sink came or went takes the way that stands at the next reap —
+so a connection's order holds by construction; ``_rx_release``
+(pause, close, give-back) takes the table's entry with the token, and
+``_rx_claim`` puts it back when the connection is read here again.
+
+A reap is also the one moment that knows a loop
 iteration's bytes are ALL with their connections: a callee of one of
-its deliveries may leave a callable to be run when the last delivery
-is made (:func:`after_reap` — the fleet ingest does, and builds and
-dispatches that iteration's device batch there instead of a loop
-iteration later: io/ingest.py, "The early dispatch").
+its deliveries, or the owner of a sink it fed, may leave a callable to
+be run when the last delivery is made (:func:`after_reap` — the fleet
+ingest does, and builds and dispatches that iteration's device batch
+there instead of a loop iteration later: io/ingest.py, "The early
+dispatch").
 
 Observability: ``zookeeper_flush_syscalls_total{plane,backend}``
 counts actual write submissions (the A/B number: O(dirty conns) per
@@ -135,11 +161,19 @@ loop's around an inline submission).  Always on:
 ``tier.offloaded_batches``; for the receive
 ``zookeeper_recv_offloaded_total{plane}``, ``tier.received_reads``
 (deliveries through a reap), ``tier.received_batches`` (reaps that
-brought any).  Under a profiler session a reap is ``client.rx_reap``
+brought any), and of the deliveries those a reap's C call made into a
+sink: ``zookeeper_recv_fed_total{plane}``, ``tier.received_fed``.
+Under a profiler session a reap is ``client.rx_reap``
 (the connections' ``client.rx`` spans nest inside it),
 ``client.rx_reaped`` counts the deliveries it made and ``client.recv``
 totals the thread's ``recv(2)`` calls with the nanoseconds inside them,
-on the thread's own clock.
+on the thread's own clock.  A fed connection has no ``_sock_data``
+call to be a span: it counts once under ``client.rx``, with the
+nanoseconds the C call's pass over its connections took (booked like
+``client.recv``), once under ``client.rx_reaped`` and once under
+``client.rx_fed`` — so ``client.rx`` still counts every delivery and
+still nests in ``client.rx_reap`` — and its stage stamp ``t_rx`` is
+the one clock read the reap made before the call (``owner.fed_mark``).
 """
 
 from __future__ import annotations
@@ -189,6 +223,7 @@ METRIC_FLUSH_PARTIAL = 'zookeeper_flush_partial_total'
 METRIC_FLUSH_REQUEUED = 'zookeeper_flush_partial_requeued_bytes'
 METRIC_FLUSH_OFFLOADED = 'zookeeper_flush_offloaded_total'
 METRIC_RECV_OFFLOADED = 'zookeeper_recv_offloaded_total'
+METRIC_RECV_FED = 'zookeeper_recv_fed_total'
 
 #: Connections in one raw batch from which the loop's shared client
 #: tier hands the batch to its native sender thread instead of sending
@@ -345,7 +380,7 @@ class _Entry:
     __slots__ = ('transport_fn', 'write', 'chunks', 'nbytes',
                  'batch', 'flying', 'stamps', '_t', '_fd',
                  'rx_transport', 'rx_token', 'on_bytes', 'on_eof',
-                 'on_error')
+                 'on_error', 'sink')
 
     def __init__(self, write, transport_fn):
         self.write = write              # the plane's asyncio sink
@@ -367,6 +402,10 @@ class _Entry:
         self.rx_transport = None
         self.rx_token = 0
         self.on_bytes = self.on_eof = self.on_error = None
+        #: ``(accumulator, owner, conn)`` while the connection's bytes
+        #: may go straight where ``on_bytes`` would end by putting them
+        #: (``TransportTier.rx_sink``), else None
+        self.sink = None
 
     def resolve_fd(self, t) -> int:
         if t is self._t:
@@ -439,6 +478,15 @@ class TransportTier:
         self._rx_on = False
         self._receiver = None
         self._rx: dict[int, _Entry] = {}
+        #: token -> the bytearray a reap's C call appends that
+        #: connection's bytes to (:meth:`rx_sink`: the entries of
+        #: ``_rx`` that have a sink), and owner -> how many of them are
+        #: its (one owner, and a reap tells it once what it fed)
+        self._sinks: dict[int, bytearray] = {}
+        self._sink_owners: dict = {}
+        #: the last reap was inside a profiler session: it stamped the
+        #: connections it fed, and the first reap outside one unstamps
+        self._rx_stamped = False
         #: Profiler sessions only, inside :meth:`_tick` (else 0): when
         #: this tick's flush began — the ``t_flush`` of the requests
         #: whose bytes it submits or hands over (utils/trace.py).
@@ -459,6 +507,9 @@ class TransportTier:
         #: reaps that brought any
         self.received_reads = 0
         self.received_batches = 0
+        #: of ``received_reads``, the deliveries a reap's C call made
+        #: itself, into the connection's sink (:meth:`rx_sink`)
+        self.received_fed = 0
         #: Clients holding a :class:`TierLease` on this tier (a
         #: server's tier is its own and stays at 0).
         self.refs = 0
@@ -471,6 +522,8 @@ class TransportTier:
         self._reap_span = plane + '.reap'
         self._rx_reap_span = plane + '.rx_reap'
         self._rx_reaped_span = plane + '.rx_reaped'
+        self._rx_span = plane + '.rx'
+        self._rx_fed_span = plane + '.rx_fed'
         self._recv_span = plane + '.recv'
         #: The tier's own series: registered with the collector it
         #: was given, standalone without one (a loop's shared client
@@ -502,6 +555,10 @@ class TransportTier:
             METRIC_RECV_OFFLOADED,
             'Connection reads received by the native receiver thread '
             'instead of the event loop, by plane')
+        self.fed_ctr = source.counter(
+            METRIC_RECV_FED,
+            'Of those, the reads a reap appended to the fleet '
+            "ingest's slot in its one native call, by plane")
 
     @property
     def series(self) -> tuple:
@@ -509,7 +566,7 @@ class TransportTier:
         adopts)."""
         return (self.syscall_ctr, self.depth_hist, self.partial_ctr,
                 self.requeued_ctr, self.offloaded_ctr,
-                self.received_ctr)
+                self.received_ctr, self.fed_ctr)
 
     def attach_sender(self) -> None:
         """Hand deep ``mmsg`` batches to a native sender thread from
@@ -867,6 +924,8 @@ class TransportTier:
             return
         entry.rx_token = token
         self._rx[token] = entry
+        if entry.sink is not None:
+            self._sink_on(token, entry.sink)
 
     def _rx_give_back(self, entry: _Entry) -> None:
         """The connection's receive returns to its asyncio transport:
@@ -887,6 +946,8 @@ class TransportTier:
         was received and not yet reaped."""
         token, entry.rx_token = entry.rx_token, 0
         if token and self._rx.pop(token, None) is not None:
+            if entry.sink is not None:
+                self._sink_off(token, entry.sink)
             left = self._ext.receiver_forget(self._receiver, token)
             if deliver:
                 for data in left:
@@ -905,6 +966,80 @@ class TransportTier:
         self._rx_release(entry, deliver)
         entry.rx_transport = None
         entry.on_bytes = entry.on_eof = entry.on_error = None
+
+    def rx_sink(self, entry: _Entry, sink) -> None:
+        """``sink`` = ``(accumulator, owner, conn)``: from now on a
+        reap appends what the receiver thread has for this connection
+        straight to ``accumulator`` (a bytearray) inside its one C
+        call, instead of handing ``on_bytes`` a ``bytes`` — the caller
+        saying that ``on_bytes`` would do exactly that append and
+        nothing else.  ``owner`` (the fleet ingest) is then told once a
+        reap, not once a connection: ``owner.fed(nbytes, t_rx)``, and
+        inside a profiler session first ``owner.fed_mark(conn, end,
+        t_rx)`` a connection fed (``end``: the accumulator's length
+        with its bytes in it; ``t_rx``: the start of the reap's C
+        call, 0 outside a session).  None withdraws it: the
+        connection's bytes come through ``on_bytes`` again, behind
+        what was appended.  The sink follows the entry, not the
+        socket: it stands only while the receiver thread reads the
+        connection (``rx_token``), so a connection that is not
+        adopted, paused (``rx_forget``) or given back has none until
+        it is read here again."""
+        was, entry.sink = entry.sink, sink
+        token = entry.rx_token
+        if token:
+            if was is not None:
+                self._sink_off(token, was)
+            if sink is not None:
+                self._sink_on(token, sink)
+
+    def _sink_on(self, token: int, sink: tuple) -> None:
+        self._sinks[token] = sink[0]
+        owners = self._sink_owners
+        owners[sink[1]] = owners.get(sink[1], 0) + 1
+
+    def _sink_off(self, token: int, sink: tuple) -> None:
+        del self._sinks[token]
+        owners = self._sink_owners
+        left = owners[sink[1]] - 1
+        if left:
+            owners[sink[1]] = left
+        else:
+            del owners[sink[1]]
+
+    def _rx_fed(self, fed: tuple, t_rx: int) -> int:
+        """What a reap's C call appended to sinks: the owners told,
+        the counters fed.  Returns the connections fed."""
+        n, nbytes, ns, each = fed
+        owners = self._sink_owners
+        if each is not None and len(owners) > 1:
+            # whose bytes: every owner hears of its own
+            rx = self._rx
+            owed: dict = {}
+            for token, took in each:
+                owner = rx[token].sink[1]
+                owed[owner] = owed.get(owner, 0) + took
+        else:
+            owed = dict.fromkeys(owners, nbytes)
+        try:
+            if t_rx:
+                rx = self._rx
+                for token, _took in each:
+                    buf, owner, conn = rx[token].sink
+                    owner.fed_mark(conn, len(buf), t_rx)
+            for owner, took in owed.items():
+                owner.fed(took, t_rx)
+        except Exception:
+            # the bytes are in their slots; the deliveries go on
+            log.exception('transport receive sink owner failed')
+        if t_rx:
+            # a fed connection is one ``client.rx`` — its way into the
+            # slot, here inside the C call — and one of them fed
+            host_add(self._rx_span, n, ns)
+            host_add(self._rx_fed_span, n, ns)
+        self.received_fed += n
+        self.fed_ctr.increment({'plane': self.plane}, by=n)
+        return n
 
     def _rx_deliver(self, entry: _Entry, data) -> bool:
         """One ``bytes | -errno`` of a reap or a hand-back to its
@@ -932,12 +1067,24 @@ class TransportTier:
             return      # a wake-up that outlived close()
         after = _reaping.pending = []
         try:
-            with host_span(self._rx_reap_span, accumulate=True):
-                items, calls, ns = self._ext.receiver_reap(self._receiver)
+            with host_span(self._rx_reap_span, accumulate=True) as sp:
+                # a profiler session stamps every fed connection with
+                # ONE clock read: the receive call that brought its
+                # bytes to the loop is this one
+                t_rx = 0 if sp is NO_SPAN else perf_counter_ns()
+                if self._rx_stamped and not t_rx:
+                    self._rx_unstamp()
+                items, calls, ns, fed = self._ext.receiver_reap(
+                    self._receiver, self._sinks,
+                    t_rx != 0 or len(self._sink_owners) > 1)
                 if calls:
                     host_add(self._recv_span, calls, ns)
-                rx = self._rx
                 n = 0
+                if fed is not None:
+                    self._rx_stamped = t_rx != 0
+                    n = self._rx_fed(fed, t_rx)
+                # what has no sink, and every end, after the feeds
+                rx = self._rx
                 for token, data in items:
                     e = rx.get(token)
                     if e is not None and self._rx_deliver(e, data):
@@ -954,6 +1101,15 @@ class TransportTier:
         # the loop's exception handler, as one of a tick's does)
         for fn in after:
             fn()
+
+    def _rx_unstamp(self) -> None:
+        """The profiler session is over: the connections its reaps
+        stamped (``fed_mark``) carry no receive time into a later one
+        (``ZKConnection._sock_data`` does the same for its own)."""
+        self._rx_stamped = False
+        for e in self._rx.values():
+            if e.sink is not None:
+                e.sink[2]._rx_t0 = 0
 
     def _settle(self, entry: _Entry, chunks: list[bytes],
                 nbytes: int, res: int) -> None:

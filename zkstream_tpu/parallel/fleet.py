@@ -225,7 +225,7 @@ class MultihostFleetIngest(MeshFleetIngest):
     def _schedule(self) -> None:
         pass
 
-    def register(self, conn, lane=None) -> None:
+    def register(self, conn, lane=None, sink=None) -> None:
         # Never raise here: register runs inside the connection FSM's
         # state-entry handler, and an exception there would strand a
         # half-wired connection.  Overflow connections get no row —
@@ -242,7 +242,7 @@ class MultihostFleetIngest(MeshFleetIngest):
                     '(local_rows=%d); overflow connections are served '
                     'by the scalar drain — size the proxy for the '
                     'host\'s connection budget', self.local_rows)
-        super().register(conn, lane)
+        super().register(conn, lane, sink)
 
     def unregister(self, conn) -> None:
         row = self._rows.pop(id(conn), None)

@@ -35,9 +35,7 @@ class EventEmitter:
             self.remove_listener(event, wrapper)
             cb(*args)
         wrapper.__wrapped__ = cb  # type: ignore[attr-defined]
-        self._listeners.setdefault(event, []).append(wrapper)
-        self._ver += 1
-        return self
+        return self.on(event, wrapper)
 
     def remove_listener(self, event: str, cb: Callable) -> None:
         lst = self._listeners.get(event)

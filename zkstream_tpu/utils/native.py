@@ -185,7 +185,7 @@ def ensure_lib(timeout: float = 120.0) -> ctypes.CDLL | None:
 # packet dicts (framing + reply bodies in one C pass), and is loaded
 # with the same version-named-artifact / background-build discipline.
 
-_EXT_ABI_VERSION = 14
+_EXT_ABI_VERSION = 15
 
 _ext = None
 _ext_load_failed = False
