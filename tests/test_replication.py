@@ -889,3 +889,54 @@ async def test_a_batch_whose_every_element_fails_ships_nothing(repl):
     assert (db.repl_pushes, db.repl_pushed_commits,
             db.repl_pushed_bytes) == before
     assert dict(led.phase_hist.rows()).get(count, 0) == pushes
+
+
+async def test_a_push_group_carries_one_stamp_and_the_mirror_trails_by_it(
+        repl, wire):
+    """``zk_apply_lag_ms``'s source: a ``'commit'`` push carries ONE
+    stamp, the leader's ``time.monotonic()`` when the group's FIRST
+    entry was committed (a float a group, not a commit), and so does a
+    control-channel response for the entries it piggybacks; the
+    mirror's replica observes, for each entry it applies, the time
+    since that stamp — here the 30 ms the first commit of the turn
+    waited for the turn to end, at least."""
+    import time
+
+    db, svc, connect = repl
+    remote = await connect()
+    store = RemoteReplicaStore(remote)
+    db.create('/s', b'', OPEN_ACL_UNSAFE, CreateFlag(0))
+    await settle(lambda: store.applied == db.log_end())
+    n0 = store.apply_lag.count()
+    wire.clear()
+
+    t0 = time.monotonic()
+    for i in range(3):                  # one turn, 10 ms a commit
+        db.set_data('/s', b'%d' % i, -1)
+        time.sleep(0.01)
+    t1 = time.monotonic()
+    await settle(lambda: store.applied == db.log_end())
+    (msg,) = wire.pushed(0)
+    assert len(msg) == 5 and len(msg[2]) == 3
+    assert t0 <= msg[4] <= t0 + 0.005 < t1      # the FIRST commit's
+    assert msg[4] == db.stamps.at(msg[1]) == remote.stamps.at(msg[1])
+    # the group's later entries read the group's stamp on the mirror
+    assert remote.stamps.at(msg[1] + 2) == msg[4]
+    assert db.stamps.at(msg[1] + 2) >= msg[4] + 0.019
+    assert store.apply_lag.count() == n0 + 3
+    assert store.apply_lag.sum() >= 3 * 29.0
+    # a response's piggyback: entries the mirror got no push of
+    svc.partitioned.add(remote.token)
+    db.set_data('/s', b'late', -1)
+    t2 = time.monotonic()
+    await asyncio.sleep(0.03)
+    assert store.applied == db.log_end() - 1
+    svc.partitioned.discard(remote.token)
+    await _rpc(remote.sync_barrier)
+    store.catch_up()
+    assert store.applied == db.log_end()
+    assert t2 - 0.005 <= remote.stamps.at(db.log_end() - 1) <= t2
+    assert store.apply_lag.count() == n0 + 4
+    assert store.apply_lag.sum() >= 3 * 29.0 + 29.0
+    # history from before the marks has no stamp, and costs nothing
+    assert remote.stamps.at(-1) is None

@@ -57,8 +57,8 @@ MIX = {'readproportion': 0.95, 'updateproportion': 0.05,
 class Fleet:
     """What ``benchmark/harness.Fleet`` gives an engine."""
 
-    def __init__(self, seed: int, ports, ingest, mix=MIX):
-        self.config, self.params, self.seed = CONFIG, mix, seed
+    def __init__(self, seed: int, ports, ingest, mix=MIX, config=CONFIG):
+        self.config, self.params, self.seed = config, mix, seed
         self.addrs = [('127.0.0.1', p) for p in ports]
         self.ingest = ingest
         self.deadline_ms = 15000
@@ -75,7 +75,11 @@ class Fleet:
 
 
 class Cell:
-    async def start(self, seed: int, mix=MIX):
+    #: the engine under test and the deployment and mix it is given
+    #: (tests/test_ycsb_latest.py runs workload D's through this class)
+    Engine, config, mix = ycsb_core.Engine, CONFIG, MIX
+
+    async def start(self, seed: int, mix=None):
         gc.collect()    # the cell before this one is not this one's pause
         self.ens = await ZKEnsemble(3).start()
         self.ingest = FleetIngest(
@@ -84,8 +88,8 @@ class Cell:
         for bp in (8, 16, 32):
             await self.ingest.prewarm(bp)
         self.fleet = Fleet(seed, [s.port for s in self.ens.servers],
-                           self.ingest, mix)
-        self.engine = ycsb_core.Engine(self.fleet)
+                           self.ingest, mix or self.mix, self.config)
+        self.engine = self.Engine(self.fleet)
         await self.engine.load()
         await self.engine.connect()
         return self
@@ -162,23 +166,92 @@ async def test_workload_b_against_the_plain_reference(event_loop, seed):
 async def test_a_target_paces_every_session_from_its_own_schedule(
         event_loop):
     """YCSB's ``-target``: 24 sessions at 960 operations a second are
-    one operation every 25 ms each, timed from when it was due."""
+    one operation every 25 ms each.  What the engine promises, held on
+    its own record (``late_ms``: due -> sent) and on when each
+    session's requests were sent and answered — not on how many this
+    machine completed: a session's k-th operation is due at its first
+    due time + k intervals and never sent before; one that is late is
+    late by what held it — the operation before it still out, or this
+    process's loop waking late, which the test measures itself — and
+    sent at once, so the schedule never drifts; every read is timed
+    from when it was due."""
     cell = await Cell().start(13, dict(MIX, target_ops_per_s=960))
     try:
-        assert cell.engine.interval == pytest.approx(0.025)
+        eng = cell.engine
+        interval = eng.interval
+        assert interval == pytest.approx(0.025)
+        clock = time.perf_counter
+        sent = [[] for _ in range(N)]   # a session's operations: sent
+        done = [[] for _ in range(N)]   # ... and answered (its setData)
+
+        def watched(s, c):
+            get, put = c.get, c.set
+
+            async def get_(path, **kw):
+                sent[s].append(clock())
+                done[s].append(0.0)
+                try:
+                    return await get(path, **kw)
+                finally:
+                    done[s][-1] = clock()
+
+            async def set_(path, data, **kw):
+                try:
+                    return await put(path, data, **kw)
+                finally:
+                    done[s][-1] = clock()
+            c.get, c.set = get_, set_
+        for s, c in enumerate(eng.clients):
+            watched(s, c)
+        # how late THIS loop wakes a sleeper, all through the run: a
+        # stall of the process or a long callback delays this probe as
+        # it delays a session
+        lag = [0.0]
+
+        async def probe():
+            while True:
+                t = clock()
+                await asyncio.sleep(0.002)
+                lag.append(clock() - t - 0.002)
+        prober = asyncio.ensure_future(probe())
+        t_start = clock()
         res = await cell.run(1.5)
+        prober.cancel()
         assert not res['violations'], res['violations']
         assert res['failed'] == 0
-        # the fleet completes what the schedule asks, no more
-        assert 0.93 * 1440 < res['acked'] < 1.04 * 1440
         assert abs(res['acked'] - res['attempted']) <= N
-        # sent when due (a loop this idle wakes within a few ms), and
-        # every read timed from its due time: never under its lateness
-        late = cell.engine.late_ms
-        assert len(late) == res['attempted']
-        assert 0.0 <= min(late) and res['counters']['gen_late_ms_p95'] < 20
-        assert min(res['samples']['read']) > 0.0
-        assert res['counters']['changes_acked'] > 20
+        # the engine's record: never sent before it was due, and every
+        # read timed from its due time — its lateness is IN its latency
+        late = eng.late_ms
+        assert len(late) == res['attempted'] > 10 * N
+        assert min(late) >= 0.0
+        reads = sorted(res['samples']['read'])
+        assert len(reads) == len(late) and reads[0] > 0.0
+        assert all(r > l for r, l in zip(reads, sorted(late)))
+        assert res['counters']['gen_late_ms_p95'] == pytest.approx(
+            sorted(late)[int(0.95 * (len(late) - 1))], abs=1.0)
+        assert res['counters']['changes_acked'] > 0
+        # each session against its own schedule.  Its first due time is
+        # not recorded; the most punctual of its operations bounds it
+        # from above (d = sent - k * interval >= first due, with
+        # equality for an operation sent when due), which only makes
+        # an operation look LESS late than it was
+        slack = max(lag) + 0.003
+        for s in range(N):
+            t, end = sent[s], done[s]
+            assert len(t) > 10
+            d = [t[k] - k * interval for k in range(len(t))]
+            first_due = min(d)
+            # no more than the schedule asks: k intervals lie between
+            # the first due time (after the start) and the k-th send
+            assert len(t) - 1 <= (t[-1] - t_start) / interval
+            for k in range(1, len(t)):
+                due = first_due + k * interval
+                # held by the operation before it, or by the loop; sent
+                # at once then, so the lateness never adds up
+                held = max(0.0, end[k - 1] - due)
+                assert t[k] - due <= held + slack, (
+                    s, k, (t[k] - due) * 1e3, held * 1e3, slack * 1e3)
     finally:
         await cell.stop()
 

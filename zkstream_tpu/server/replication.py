@@ -99,6 +99,7 @@ from ..utils.events import EventEmitter
 from ..utils.metrics import Collector
 from .persist import entry_zxid
 from .store import (
+    CommitStamps,
     ReplicaStore,
     ZKDatabase,
     ZKOpError,
@@ -781,8 +782,12 @@ class ReplicationService:
                     continue
                 data = memo.get(base)
                 if data is None:
+                    # the group's ONE stamp: when its first entry was
+                    # committed, on this host's monotonic clock (the
+                    # mirror's ``zk_apply_lag_ms`` subtracts it)
                     data = memo[base] = _dump(
-                        ('commit', base, entries, self.epoch))
+                        ('commit', base, entries, self.epoch,
+                         db.stamps.at(base)))
                 self._push(h, ('commit', base, entries, self.epoch),
                            data=data)
                 db.repl_pushes += 1
@@ -973,7 +978,7 @@ class ReplicationService:
                     base, entries = self._entries_from(have)
                     writer.write(_dump(
                         ('res', seq, status, payload, base, entries,
-                         self.epoch)))
+                         self.epoch, self.db.stamps.at(base))))
                 finally:
                     if led is not None:
                         led.exit()
@@ -1152,6 +1157,10 @@ class RemoteLeader(EventEmitter):
         #: the commit-log mirror (never truncated: one local replica)
         self.log: list = []
         self.log_base = 0
+        #: the leader's commit stamps of what this mirror ingested,
+        #: one a message (``_ingest``): what the replica's
+        #: ``zk_apply_lag_ms`` subtracts
+        self.stamps = CommitStamps()
         self.sessions: dict[int, ZKServerSession] = {}
         #: replicated membership config (store.py config_snapshot
         #: form): seeded by the bootstrap image, then maintained by
@@ -1314,7 +1323,8 @@ class RemoteLeader(EventEmitter):
                     if not self._adopt_epoch(
                             msg[3] if len(msg) > 3 else None):
                         continue       # fenced: a stale leader's push
-                    self._ingest(msg[1], msg[2])
+                    self._ingest(msg[1], msg[2],
+                                 msg[4] if len(msg) > 4 else None)
                     self.emit('committed')
                 elif msg[0] == 'session_expired':
                     self._adopt_epoch(msg[2] if len(msg) > 2 else None)
@@ -1359,11 +1369,14 @@ class RemoteLeader(EventEmitter):
             # follower's election trigger (server/election.py)
             self._note_leader_lost()
 
-    def _ingest(self, base: int, entries: list) -> None:
+    def _ingest(self, base: int, entries: list,
+                stamp: float | None = None) -> None:
         """Merge a batch of log entries starting at absolute index
         ``base`` into the mirror (entries can arrive on both channels;
         overlap is dropped under the mirror lock, gaps are impossible
-        on ordered sockets from one leader loop).  Growth is acked to
+        on ordered sockets from one leader loop).  ``stamp`` is the
+        leader's commit time of the batch's first entry, kept for the
+        first entry that is new here (``stamps``).  Growth is acked to
         the leader — acks, not shipments, advance its truncation
         floor, so the control channel's piggyback can always serve
         from this mirror's end."""
@@ -1378,6 +1391,8 @@ class RemoteLeader(EventEmitter):
                 return
             tail = entries[end - base:]
             if tail:
+                if stamp is not None:
+                    self.stamps.note(end, stamp)
                 self.log.extend(tail)
                 if self.wal is not None:
                     # mirror durability: the follower's own WAL logs
@@ -1463,7 +1478,7 @@ class RemoteLeader(EventEmitter):
         tag, rseq, status, payload, base, entries = res[:6]
         assert tag == 'res' and rseq == seq, res
         self._adopt_epoch(res[6] if len(res) > 6 else None)
-        self._ingest(base, entries)
+        self._ingest(base, entries, res[7] if len(res) > 7 else None)
         if entries:
             self.emit('committed')
         if status == 'err':

@@ -267,6 +267,11 @@ class ReadGate:
 #: halves a reply's cost.
 REPLY_SHARE_BYTES = 65536
 
+#: The reads: an answer of ``NO_NODE`` to one of these is counted
+#: (``ZKServer.read_no_node``, mntr ``zk_read_no_node``).
+READ_OPS = frozenset(('GET_DATA', 'EXISTS', 'GET_CHILDREN',
+                      'GET_CHILDREN2', 'GET_ACL'))
+
 
 class ReplyCache:
     """The serialized body of a member's read replies of ONE family, a
@@ -1013,6 +1018,8 @@ class ServerConnection:
             # Failed reads with a watch flag still arm existence watches
             # where the protocol says so (handled inside the op); other
             # failures just carry the code.
+            if e.code == 'NO_NODE' and op in READ_OPS:
+                self.server.read_no_node += 1
             self._reply(xid, op, err=e.code)
 
     def _op_ping(self, pkt: dict) -> None:
@@ -1428,6 +1435,11 @@ class ZKServer:
         self.packets_received = 0
         self.packets_sent = 0
         self.outstanding = 0
+        #: reads this member answered ``NO_NODE`` (mntr
+        #: ``zk_read_no_node``): on a follower, beside
+        #: ``zk_apply_lag_ms``, how often a reader asked for what its
+        #: member had not applied yet — or for what nobody made
+        self.read_no_node = 0
         #: The writes this member's connections handed it during the
         #: current turn of the loop, as ``(conn, pkt, method, args)``
         #: in arrival order (:meth:`forward_write`; only a member
@@ -2144,6 +2156,7 @@ class ZKServer:
             ('zk_num_alive_connections', len(self.conns)),
             ('zk_packets_received', self.packets_received),
             ('zk_packets_sent', self.packets_sent),
+            ('zk_read_no_node', self.read_no_node),
             ('zk_ephemerals_count', ephemerals),
             ('zk_approximate_data_size', data_size),
             ('zk_sessions', len(self.db.sessions)),
@@ -2177,7 +2190,9 @@ class ZKServer:
         ``_count``, utils/metrics ``Histogram.rows``): each tick
         phase (``zk_tick_phase_ms{phase=}``), the busy tick
         (``zk_tick_ms``), commit -> majority ack on a leader
-        (``zk_quorum_ack_ms``) and the fan-out shard flush
+        (``zk_quorum_ack_ms``), leader commit -> this member's apply
+        on a follower (``zk_apply_lag_ms``, server/store.py
+        ``ReplicaStore.apply_lag``) and the fan-out shard flush
         (``zk_fanout_tick_ms{plane="fanout"}``).  A scraper that keeps
         the rows before and after a window has that window's exact
         bucket counts, busy time and count by subtraction — what the
@@ -2186,6 +2201,9 @@ class ZKServer:
         q = self.quorum
         if q is not None and q.enabled:
             hists.append(q.ack_hist)
+        lag = getattr(self.store, 'apply_lag', None)
+        if lag is not None:
+            hists.append(lag)
         if self.watch_table is not None:
             hists.append(self.watch_table.tick_hist)
         return [row for h in hists for row in h.rows()]

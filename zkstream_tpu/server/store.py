@@ -29,6 +29,7 @@ byte-identical Stat on every member.
 from __future__ import annotations
 
 import asyncio
+import bisect
 import dataclasses
 import logging
 import secrets
@@ -42,6 +43,7 @@ from ..protocol.records import ACL, OPEN_ACL_UNSAFE, Stat
 from .persist import entry_zxid
 from ..utils.events import EventEmitter
 from ..utils.aio import ambient_loop
+from ..utils.metrics import Collector
 
 log = logging.getLogger('zkstream_tpu.server.store')
 
@@ -310,6 +312,47 @@ class NodeTree(EventEmitter):
         return list(node.acl), node.stat()
 
 
+METRIC_APPLY_LAG = 'zk_apply_lag_ms'
+APPLY_LAG_BUCKETS = (0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0,
+                     100.0, 250.0, 500.0, 1000.0, 2500.0)
+
+
+class CommitStamps:
+    """When the leader committed what: ``(absolute log index,
+    time.monotonic())`` marks, ascending.  The leader marks every
+    commit (:meth:`ZKDatabase._commit`); a mirror in another process
+    marks each message's first new entry with the ONE stamp the
+    message carries (server/replication.py: the commit time of its
+    first entry — 8 bytes a push group, not a commit), so an entry
+    deeper in a group reads a stamp at most the group's span too old.
+    Members of one host share ``CLOCK_MONOTONIC``, so a follower's
+    ``time.monotonic()`` less :meth:`at` is how long it trails
+    (``zk_apply_lag_ms``).  Bounded: the oldest marks go."""
+
+    KEEP = 4096
+
+    def __init__(self) -> None:
+        self._index: list[int] = []
+        self._t: list[float] = []
+
+    def note(self, index: int, t: float) -> None:
+        idx = self._index
+        if idx and index <= idx[-1]:
+            return
+        idx.append(index)
+        self._t.append(t)
+        if len(idx) > 2 * self.KEEP:
+            del idx[:self.KEEP]
+            del self._t[:self.KEEP]
+
+    def at(self, index: int) -> float | None:
+        """The stamp of the newest mark at or below ``index``; None
+        for an entry older than every mark (history from before the
+        marks: a snapshot, a recovered WAL)."""
+        i = bisect.bisect_right(self._index, index) - 1
+        return self._t[i] if i >= 0 else None
+
+
 class ZKDatabase(NodeTree):
     """The leader: validates and sequences writes, allocates zxids,
     owns the session table, and appends every committed transaction to
@@ -352,6 +395,9 @@ class ZKDatabase(NodeTree):
         #: grow memory without bound either.
         self.log: list[tuple] = []
         self.log_base = 0
+        #: when each retained entry was committed (replicas read how
+        #: far they trail from it: ``zk_apply_lag_ms``)
+        self.stamps = CommitStamps()
         #: The zxid the retained log is contiguous *after*: every txn
         #: with zxid > log_start_zxid is in ``log``.  Maintained so a
         #: follower recovering from its own WAL (server/persist.py)
@@ -661,6 +707,7 @@ class ZKDatabase(NodeTree):
             self.install_config(rec.config)
         self.log.clear()
         self.log_base = 0
+        self.stamps = CommitStamps()    # the indexes start over
         self.log_start_zxid = rec.zxid
         # the SAME WriteAheadLog object reopens: collector-bound
         # gauges/histograms and the fault injector stay live on it
@@ -697,6 +744,8 @@ class ZKDatabase(NodeTree):
         if self.wal is not None:
             self.wal.append(entry)
         if self._replicas:
+            self.stamps.note(self.log_base + len(self.log),
+                             time.monotonic())
             self.log.append(entry)
             self.emit('committed')
             self._truncate_applied()
@@ -1043,6 +1092,14 @@ class ReplicaStore(NodeTree):
         #: trigger it on the loop; an unguarded read-modify-write of
         #: ``applied`` would skip or double-apply an entry.
         self._apply_lock = threading.Lock()
+        #: for each entry applied, how long since the leader committed
+        #: it (its group, across processes: :class:`CommitStamps`), ms.
+        #: Standalone, as the quorum gate's histogram: a member has no
+        #: collector and exports it through ``mntr`` (server/server.py)
+        self.apply_lag = Collector().histogram(
+            METRIC_APPLY_LAG,
+            'Leader commit to this replica applying it, ms',
+            buckets=APPLY_LAG_BUCKETS)
         try:
             leader.attach_replica(self)
         except ValueError:
@@ -1092,9 +1149,23 @@ class ReplicaStore(NodeTree):
         races an on-loop events push — see ``_apply_lock``)."""
         ldr = self.leader
         with self._apply_lock:
+            first = self.applied
             while self.applied < min(target, ldr.log_end()):
                 self._apply_one(ldr.log[self.applied - ldr.log_base])
                 self.applied += 1
+            if self.applied > first:
+                self._note_lag(first, self.applied)
+
+    def _note_lag(self, first: int, end: int) -> None:
+        """Entries ``first .. end`` were applied just now: each one's
+        time since the leader's stamp."""
+        stamps = self.leader.stamps
+        now = time.monotonic()
+        observe = self.apply_lag.observe
+        for index in range(first, end):
+            t = stamps.at(index)
+            if t is not None:
+                observe((now - t) * 1000.0)
 
     #: Optional quorum-commit ack hook (server/replication.py
     #: QuorumGate): called with this replica's zxid after every
