@@ -272,3 +272,84 @@ def test_fsm_unknown_state_raises():
     b = Bad('ok')
     with pytest.raises(AttributeError):
         b.emit('go')
+
+
+# -- the state table (utils/fsm.py): every machine of the package --
+
+def _package_fsms():
+    import zkstream_tpu.client  # noqa: F401  (pulls in every machine)
+    found, todo = [], [FSM]
+    while todo:
+        for sub in todo.pop().__subclasses__():
+            todo.append(sub)
+            if sub.__module__.startswith('zkstream_tpu.'):
+                found.append(sub)
+    return sorted(found, key=lambda c: c.__qualname__)
+
+
+def _state_names(cls) -> dict:
+    """{state name: handler attribute}: ``state_a_b`` is the substate
+    ``a.b`` where the class also has ``state_a``, else the state
+    ``a_b``."""
+    handlers = {a[len('state_'):] for a in dir(cls)
+                if a.startswith('state_')}
+
+    def name(h):
+        cut = [i for i, ch in enumerate(h)
+               if ch == '_' and h[:i] in handlers]
+        return (name(h[:cut[-1]]) + '.' + h[cut[-1] + 1:]) if cut else h
+    return {name(h): 'state_' + h for h in sorted(handlers)}
+
+
+def test_the_package_has_its_machines():
+    assert {c.__name__ for c in _package_fsms()} >= {
+        'Client', 'ZKConnection', 'ZKSession', 'ZKWatchEvent'}
+    assert 'armed.doublecheck' in _state_names(_package_fsms()[-1])
+
+
+@pytest.mark.parametrize('cls', _package_fsms(),
+                         ids=lambda c: c.__name__)
+def test_fsm_states_resolve_through_the_class_table(cls):
+    """Every ``state_*`` of every machine resolves through its class's
+    table (parent prefixes + handler attribute, made once), and a
+    dotted substate keeps its parent's scope while leaving disposes
+    both — on a bare instance whose handlers only mark their scope."""
+    names = _state_names(cls)
+    assert names
+    m = cls.__new__(cls)
+    EventEmitter.__init__(m)
+    m._state, m._scopes = None, []
+    m._in_transition, m._queued = False, None
+    log = []
+    for state, handler in names.items():
+        def enter(S, state=state):
+            log.append('+' + state)
+            S.defer(lambda: log.append('-' + state))
+        setattr(m, handler, enter)
+    tops = [n for n in names if '.' not in n]
+    for state, handler in names.items():
+        parents = tuple(state.rsplit('.', i)[0] for i in
+                        range(state.count('.'), 0, -1))
+        assert cls._fsm_state(state) == (parents, handler)
+        assert cls._fsm_states[state] is cls._fsm_state(state)
+        assert callable(getattr(cls, handler))
+        # enter it, parents first, then leave for a top-level state
+        chain = list(parents) + [state]
+        away = next(t for t in tops if t != chain[0])
+        m._transition(away)
+        del log[:]
+        for step in chain:
+            m._transition(step)
+        assert m.get_state() == state
+        assert [st for st, _scope in m._scopes] == chain
+        assert all(m.is_in_state(step) for step in chain)
+        # of the chain nothing is disposed yet
+        assert log == ['-' + away] + ['+' + step for step in chain]
+        m._transition(away)
+        assert log[1 + len(chain):] == \
+            ['-' + step for step in reversed(chain)] + ['+' + away]
+        assert [st for st, _scope in m._scopes] == [away]
+    # a table a class: nobody else's names
+    assert set(cls._fsm_states) == set(names)
+    with pytest.raises(AttributeError):
+        cls._fsm_state('no_such_state')

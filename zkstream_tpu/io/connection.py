@@ -72,7 +72,11 @@ class ZKRequest(EventEmitter):
     awaits it takes :meth:`as_future` — the connection settles that
     future itself, with no listener in between; the 'reply' /
     'error' events serve what must run inside the routing call (the
-    reserved xids' piggy-backing, a watcher's arm).  The client
+    reserved xids' piggy-backing), and so does ``on_settled``, the
+    ONE callback a request may carry instead: ``on_settled(req, None,
+    pkt)`` on its reply, ``on_settled(req, err, None)`` on a failure
+    (a watcher's arm, a thousand a change: no table, no disposers).
+    The client
     facade may attach a trace ``span``; the connection's reply/error
     routing closes it.
 
@@ -83,12 +87,14 @@ class ZKRequest(EventEmitter):
     until it settles: it is the request's xid and opcode for whoever
     looks at ``conn.reqs`` (the tests do, a debugger would)."""
 
-    __slots__ = ('packet', 'span', 'fut')
+    __slots__ = ('packet', 'span', 'fut', 'on_settled')
 
     def __init__(self, packet: dict):
         self._listeners = _NO_LISTENERS
         self._ver = 0
         self.packet = packet
+        #: Optional ``cb(req, err, pkt)``, run inside the routing call.
+        self.on_settled: Callable | None = None
         #: Optional utils/trace.Span, attached by Client._start_op.
         self.span = None
         #: The awaiter's future, once as_future() was asked for it.
@@ -130,7 +136,7 @@ class ZKRequest(EventEmitter):
             if span is not None:
                 span.finish(zxid=pkt.get('zxid'), status='error',
                             error=code)
-            heard = bool(self._listeners)
+            heard = bool(self._listeners) or self.on_settled is not None
             # the overloaded-member bounce gets its typed class so
             # the client's write path can key its backoff+retry on
             # isinstance instead of string-matching the code
@@ -144,15 +150,23 @@ class ZKRequest(EventEmitter):
         # and the late reply is dropped
         if fut is not None and not fut.done():
             fut.set_result(pkt)
+        heard = False
+        cb = self.on_settled
+        if cb is not None:
+            cb(self, None, pkt)
+            heard = True
         if self._listeners:
             self.emit('reply', pkt)
             return True
-        return False
+        return heard
 
     def fail(self, err: Exception, *args) -> None:
         fut = self.fut
         if fut is not None and not fut.done():
             fut.set_exception(err)
+        cb = self.on_settled
+        if cb is not None:
+            cb(self, err, None)
         if self._listeners:
             self.emit('error', err, *args)
 

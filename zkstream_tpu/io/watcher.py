@@ -29,7 +29,9 @@ from __future__ import annotations
 
 import random
 import time
+import weakref
 
+from ..utils.aio import ambient_loop
 from ..utils.events import EventEmitter
 from ..utils.fsm import FSM
 from ..utils.logging import Logger
@@ -46,6 +48,21 @@ ARM_RETRY_POLICY = BackoffPolicy(delay=5, cap=500, factor=2.0)
 #: has not missed a wakeup (reference: lib/zk-session.js:27-36).
 DOUBLECHECK_TIMEOUT = 4 * 3600 * 1000
 DOUBLECHECK_RAND = 8 * 3600 * 1000
+
+
+#: Which event FSMs a server notification reaches (the module
+#: docstring's matrix, read conservatively), in the order they are told.
+_NOTIFIES = {
+    'created': ('createdOrDeleted', 'dataChanged'),
+    'deleted': ('createdOrDeleted', 'dataChanged', 'childrenChanged'),
+    'dataChanged': ('dataChanged', 'createdOrDeleted'),
+    'childrenChanged': ('childrenChanged',),
+}
+
+#: The read that arms each kind of watch.
+_ARM_OPCODES = {'createdOrDeleted': 'EXISTS',
+                'dataChanged': 'GET_DATA',
+                'childrenChanged': 'GET_CHILDREN2'}
 
 
 class LostWakeupError(RuntimeError):
@@ -83,16 +100,8 @@ class ZKWatcher(EventEmitter):
         means our model of ZK watch semantics is wrong and we cannot
         guarantee a working watcher (reference: lib/zk-session.js:556-593).
         """
-        if evt == 'created':
-            to_notify = ['createdOrDeleted', 'dataChanged']
-        elif evt == 'deleted':
-            to_notify = ['createdOrDeleted', 'dataChanged',
-                         'childrenChanged']
-        elif evt == 'dataChanged':
-            to_notify = ['dataChanged', 'createdOrDeleted']
-        elif evt == 'childrenChanged':
-            to_notify = ['childrenChanged']
-        else:
+        to_notify = _NOTIFIES.get(evt)
+        if to_notify is None:
             raise ValueError('Unknown notification type: %s' % (evt,))
         notified = False
         for kind in to_notify:
@@ -175,9 +184,28 @@ class ZKPersistentWatcher(EventEmitter):
         self.emit('lost')
 
 
+def _probe_due(ref) -> None:
+    """The double-check timer of one watch event (held weakly: a timer
+    that stands for hours keeps no closed client alive)."""
+    event = ref()
+    if event is not None:
+        event._probe_due()
+
+
 class ZKWatchEvent(FSM):
     """One watch's arm / re-arm loop (state diagram: reference
-    lib/zk-session.js:616-674).  Lives as long as the session."""
+    lib/zk-session.js:616-674).  Lives as long as the session.
+
+    A herd pays a re-arm once a watcher a change, so it is one pass
+    each way: :meth:`notify` takes ``armed`` to ``arming`` in ONE
+    transition where it can see ``wait_session`` and
+    ``wait_connected`` would pass straight through, the arming
+    request calls :meth:`_arm_settled` back without a listener table
+    (``ZKRequest.on_settled``), and ``armed`` arms no timer: ONE lazy
+    timer an event chases the double-check's deadline.  What the
+    session and the emitter assert (:meth:`arm`, :meth:`notify`,
+    :meth:`disconnected`, :meth:`resume`) is a transition where the
+    state takes it and nothing elsewhere."""
 
     def __init__(self, session, path: str, emitter: ZKWatcher, evt: str):
         self.path = path
@@ -196,11 +224,17 @@ class ZKWatchEvent(FSM):
         #: ``_arm_retry`` is the "last attempt failed" latch.
         self._arm_backoff = ARM_RETRY_POLICY.backoff()
         self._arm_retry = False
+        #: The request state ``arming`` waits on and when it was sent;
+        #: a request that settles and is not this one (the machine has
+        #: left that ``arming`` since) is heard by nobody.
+        self._arm_req = None
+        self._arm_t0 = 0.0
         #: (Re-)arm latency instrumentation: the arming read's
         #: round-trip, labelled by watch kind — the window a watch is
         #: dark after a notification consumed it server-side.
         collector = getattr(session, 'collector', None)
         self._rearm_latency = None
+        self._rearm_series = None
         if collector is not None:
             self._rearm_latency = collector.histogram(
                 METRIC_ZK_WATCH_REARM_LATENCY,
@@ -212,22 +246,35 @@ class ZKWatchEvent(FSM):
         #: node (connection churn forces re-arms) must not re-emit
         #: 'deleted' for the same deletion.
         self._deleted_seen = False
+        #: The double-check: the probe is due ``_probe_at``
+        #: (``time.monotonic()``) if the event is still ``armed``
+        #: then, and ONE lazy timer chases that deadline
+        #: (``ZKSession.reset_expiry_timer``'s pattern) — an arm moves
+        #: the number, and no timer is made and cancelled a
+        #: notification.  The deadline only moves later: the window's
+        #: random part is drawn once an event (it is there to spread
+        #: a fleet's probes, which one draw an event does).
+        self._probe_jitter = random.random()
+        self._probe_at = 0.0
+        self._probe_handle = None
         super().__init__('disarmed')
 
     def _arm_ok(self) -> None:
         self._arm_retry = False
         self._arm_backoff.reset()
-
-    def _observe_rearm(self, t0: float) -> None:
         if self._rearm_latency is not None:
-            self._rearm_latency.observe(
-                (time.monotonic() - t0) * 1000.0, {'event': self.evt})
+            series = self._rearm_series
+            if series is None:
+                series = self._rearm_series = \
+                    self._rearm_latency.labels({'event': self.evt})
+            series.observe((time.monotonic() - self._arm_t0) * 1000.0)
 
     def get_event(self) -> str:
         return self.evt
 
     def arm(self) -> None:
-        self.emit('armAsserted')
+        if self._state == 'disarmed':
+            self._transition('wait_session')
 
     def notify(self) -> None:
         """A matching notification arrived.  Only meaningful when armed
@@ -239,26 +286,36 @@ class ZKWatchEvent(FSM):
         # from the re-arm read (only *churn-forced* re-arms — which
         # never come through here — stay suppressed).
         self._deleted_seen = False
-        if self.is_in_state('armed') or self.is_in_state('resuming'):
-            self.emit('notifyAsserted')
+        if self.is_in_state('armed') or self._state == 'resuming':
+            # wait_session and wait_connected only hold a re-arm back
+            # while the session is detached, its connection is not
+            # connected or a failed attempt owes its backoff: with
+            # none of that in sight they would pass straight through
+            # inside this call, so the read leaves from here, the same
+            # bytes in the same turn.
+            conn = (None if self._arm_retry
+                    else self.session.get_connection())
+            self._transition(
+                'arming' if conn is not None
+                and conn.is_in_state('connected') else 'wait_session')
 
     def disconnected(self) -> None:
         """The session detached; if armed, we are on its auto-resume
         list (reference: lib/zk-session.js:722-730)."""
         if self.is_in_state('armed'):
-            self.emit('disconnectAsserted')
+            self._transition('resuming')
 
     def resume(self) -> None:
         """Auto-resume (server-side SET_WATCHES re-arm) completed.  If a
         catch-up notification already moved us along, ignore it
         (reference: lib/zk-session.js:732-740)."""
-        if self.is_in_state('resuming'):
-            self.emit('resumeAsserted')
+        if self._state == 'resuming':
+            self._transition('armed')
 
     # -- states --
 
     def state_disarmed(self, S) -> None:
-        S.on(self, 'armAsserted', lambda: S.goto_state('wait_session'))
+        pass
 
     def state_wait_session(self, S) -> None:
         if self.session.is_in_state('attached'):
@@ -298,85 +355,107 @@ class ZKWatchEvent(FSM):
             # pending (state_wait_connected's check is stale by the
             # time the timer fires): back to waiting, don't throw.
             self._arm_retry = True
+            self._arm_req = None
             S.immediate(lambda: S.goto_state('wait_session'))
             return
-        arm_t0 = time.monotonic()
-        req = conn.request(self.to_packet())
+        self._arm_t0 = time.monotonic()
+        req = self._arm_req = conn.request(self.to_packet())
+        req.on_settled = self._arm_settled
 
-        def on_reply(pkt):
-            if self.evt == 'createdOrDeleted':
-                # EXISTS returned OK: the node exists.
-                args = ('created', pkt['stat'])
-                zxid = pkt['stat'].czxid
-            elif self.evt == 'dataChanged':
-                args = ('dataChanged', pkt['data'], pkt['stat'])
-                zxid = pkt['stat'].mzxid
-            elif self.evt == 'childrenChanged':
-                args = ('childrenChanged', pkt['children'], pkt['stat'])
-                zxid = pkt['stat'].pzxid
-            else:
-                raise ValueError('Unknown watcher event %s' % (self.evt,))
-            # Emit only if the relevant zxid moved FORWARD since the
-            # last emit: equality suppresses duplicate notifications
-            # from the server watch-kind overlap (reference:
-            # lib/zk-session.js:849-856), and an OLDER zxid is a
-            # stale read — a churn-forced re-arm can land on a
-            # lagging follower that has not applied a change this
-            # watcher already delivered, and re-emitting the old
-            # state would be a duplicate fire for a change the
-            # watcher saw (the at-most-once invariant,
-            # io/invariants.py check_watch_once).
-            self._arm_ok()
-            self._observe_rearm(arm_t0)
-            self._deleted_seen = False
-            if self.prev_zxid is not None and zxid <= self.prev_zxid:
-                S.goto_state('armed')
-                return
+    def _arm_settled(self, req, err, pkt) -> None:
+        """The arming request settled (``ZKRequest.on_settled``: inside
+        the routing call).  The state's guard: only the request the
+        machine waits on NOW in ``arming`` is heard — a reply that
+        lands after the machine left that state changes nothing."""
+        if req is not self._arm_req or self._state != 'arming':
+            return
+        self._arm_req = None
+        if err is not None:
+            self._arm_failed(err)
+            return
+        evt = self.evt
+        stat = pkt['stat']
+        if evt == 'childrenChanged':
+            args = ('childrenChanged', pkt['children'], stat)
+            zxid = stat.pzxid
+        elif evt == 'dataChanged':
+            args = ('dataChanged', pkt['data'], stat)
+            zxid = stat.mzxid
+        elif evt == 'createdOrDeleted':
+            # EXISTS returned OK: the node exists.
+            args = ('created', stat)
+            zxid = stat.czxid
+        else:
+            raise ValueError('Unknown watcher event %s' % (evt,))
+        # Emit only if the relevant zxid moved FORWARD since the
+        # last emit: equality suppresses duplicate notifications
+        # from the server watch-kind overlap (reference:
+        # lib/zk-session.js:849-856), and an OLDER zxid is a
+        # stale read — a churn-forced re-arm can land on a
+        # lagging follower that has not applied a change this
+        # watcher already delivered, and re-emitting the old
+        # state would be a duplicate fire for a change the
+        # watcher saw (the at-most-once invariant,
+        # io/invariants.py check_watch_once).
+        self._arm_ok()
+        self._deleted_seen = False
+        prev = self.prev_zxid
+        if prev is None or zxid > prev:
             EventEmitter.emit(self.emitter, *args)
             self.prev_zxid = zxid
-            S.goto_state('armed')
-        S.on(req, 'reply', on_reply)
+        self._transition('armed')
 
-        def on_error(err, *a):
-            code = getattr(err, 'code', None)
-            if code == 'PING_TIMEOUT':
-                self._arm_retry = True
-                S.goto_state('wait_session')
-                return
-            if self.evt == 'createdOrDeleted' and code == 'NO_NODE':
-                # Existence watches arm fine on a missing node
-                # (reference: lib/zk-session.js:865-874).  Emit
-                # 'deleted' once per disappearance: churn-forced
-                # re-arms over the same absence stay silent.
-                self._arm_ok()
-                self._observe_rearm(arm_t0)
-                if not self._deleted_seen:
-                    self._deleted_seen = True
-                    EventEmitter.emit(self.emitter, 'deleted')
-                S.goto_state('armed')
-                return
-            if code == 'NO_NODE':
-                # Other watch kinds cannot attach to a missing node;
+    def _arm_failed(self, err) -> None:
+        code = getattr(err, 'code', None)
+        if code == 'NO_NODE':
+            self._arm_ok()
+            if self.evt != 'createdOrDeleted':
+                # Only an existence watch attaches to a missing node;
                 # park until it is created.
-                self._arm_ok()
-                self._observe_rearm(arm_t0)
-                S.goto_state('wait_node')
+                self._transition('wait_node')
                 return
-            self._arm_retry = True
+            # Existence watches arm fine on a missing node
+            # (reference: lib/zk-session.js:865-874).  Emit
+            # 'deleted' once per disappearance: churn-forced
+            # re-arms over the same absence stay silent.
+            if not self._deleted_seen:
+                self._deleted_seen = True
+                EventEmitter.emit(self.emitter, 'deleted')
+            self._transition('armed')
+            return
+        self._arm_retry = True
+        if code != 'PING_TIMEOUT':
             self.log.debug('watcher attach failure (%s); will retry',
                            err)
-            S.goto_state('wait_session')
-        S.on(req, 'error', on_error)
+        self._transition('wait_session')
 
     def state_wait_node(self, S) -> None:
         S.on(self.emitter, 'created',
              lambda *a: S.goto_state('wait_session'))
 
     def state_armed(self, S) -> None:
-        S.on(self, 'notifyAsserted', lambda: S.goto_state('wait_session'))
-        S.on(self, 'disconnectAsserted', lambda: S.goto_state('resuming'))
-        dbl = round(DOUBLECHECK_TIMEOUT + random.random() * DOUBLECHECK_RAND)
-        S.timeout(dbl, lambda: S.goto_state('armed.doublecheck'))
+        delay = (DOUBLECHECK_TIMEOUT
+                 + self._probe_jitter * DOUBLECHECK_RAND) / 1000.0
+        self._probe_at = time.monotonic() + delay
+        if self._probe_handle is None:
+            self._probe_handle = ambient_loop().call_later(
+                delay, _probe_due, weakref.ref(self))
+
+    def _probe_due(self) -> None:
+        """The lazy timer fired.  Due and still ``armed``: probe.
+        ``armed`` with the deadline moved on while it slept: sleep the
+        rest.  Anywhere else (mid re-arm, resuming, probing) no probe
+        is owed, and the next entry to ``armed`` starts the chase
+        again."""
+        self._probe_handle = None
+        if self._state != 'armed':
+            return
+        remaining = self._probe_at - time.monotonic()
+        if remaining > 0:
+            self._probe_handle = ambient_loop().call_later(
+                remaining, _probe_due, weakref.ref(self))
+        else:
+            self._transition('armed.doublecheck')
 
     def state_armed_doublecheck(self, S) -> None:
         """Probe EXISTS (no watch) and compare zxids; a moved zxid with
@@ -418,13 +497,10 @@ class ZKWatchEvent(FSM):
         S.on(req, 'error', lambda err, *a: S.goto_state('armed'))
 
     def state_resuming(self, S) -> None:
-        S.on(self, 'resumeAsserted', lambda: S.goto_state('armed'))
-        S.on(self, 'notifyAsserted', lambda: S.goto_state('wait_session'))
+        pass
 
     def to_packet(self) -> dict:
-        opcode = {'createdOrDeleted': 'EXISTS',
-                  'dataChanged': 'GET_DATA',
-                  'childrenChanged': 'GET_CHILDREN2'}.get(self.evt)
+        opcode = _ARM_OPCODES.get(self.evt)
         if opcode is None:
             raise ValueError('Unknown watcher event %s' % (self.evt,))
         return {'path': self.path, 'opcode': opcode, 'watch': True}

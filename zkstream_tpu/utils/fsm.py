@@ -16,6 +16,14 @@ races tractable, so this module provides the same contract for asyncio:
   (reference: lib/zk-session.js:671-673);
 - ``is_in_state('armed')`` is true while in ``armed.doublecheck``;
 - every transition emits ``stateChanged`` with the new state name.
+
+A transition is what a herd's re-arm pays a thousand times a change, so
+what does not vary is looked up, not rebuilt: a state name's parent
+prefixes and its handler's attribute (``FSM._fsm_states``, a table a
+class, an entry made the first time the class enters the name), the
+transition counter's label key (``_transition_key``, one a
+``(label, from, to)``), and ``stateChanged`` is emitted only to a
+listener.
 """
 
 from __future__ import annotations
@@ -26,6 +34,7 @@ from typing import Callable
 
 from .events import EventEmitter
 from .aio import ambient_loop
+from .metrics import label_key
 
 METRIC_FSM_TRANSITIONS = 'zkstream_fsm_transitions'
 METRIC_FSM_STATE = 'zkstream_fsm_state'
@@ -75,18 +84,32 @@ def bind_transition_metrics(machine, collector,
     registry.add(machine)
 
 
+#: (label, from, to) -> the counter's key for that series: a machine's
+#: transitions are few and every one is taken over and over.
+_transition_keys: dict[tuple, tuple] = {}
+
+
+def _transition_key(label: str, old: str | None, new: str) -> tuple:
+    key = _transition_keys.get((label, old, new))
+    if key is None:
+        key = _transition_keys[(label, old, new)] = label_key(
+            {'fsm': label, 'from': old or '', 'to': new})
+    return key
+
+
 def note_transition(machine, old: str | None, new: str) -> None:
     """Count one state transition on the machine's bound collector
     (no-op until :func:`bind_transition_metrics` ran)."""
     ctr = getattr(machine, '_fsm_metrics_ctr', None)
     if ctr is not None:
-        ctr.increment({'fsm': machine._fsm_metrics_label,
-                       'from': old or '', 'to': new})
+        ctr.add(_transition_key(machine._fsm_metrics_label, old, new))
 
 
 class StateScope:
     """Handle passed to ``state_*`` methods; everything registered through
     it is disposed when the machine leaves the state."""
+
+    __slots__ = ('_fsm', '_state', '_disposers', '_valid')
 
     def __init__(self, fsm: 'FSM', state: str):
         self._fsm = fsm
@@ -154,6 +177,14 @@ class FSM(EventEmitter):
     """Base class: subclasses define ``state_<name>(self, S)`` methods and
     call ``super().__init__(initial_state)``."""
 
+    #: state name -> (its parent prefixes, its handler's attribute), a
+    #: table a class (``__init_subclass__``), filled as names are entered
+    _fsm_states: dict[str, tuple[tuple[str, ...], str]] = {}
+
+    def __init_subclass__(cls, **kw) -> None:
+        super().__init_subclass__(**kw)
+        cls._fsm_states = {}
+
     def __init__(self, initial: str):
         super().__init__()
         self._state: str | None = None
@@ -180,6 +211,23 @@ class FSM(EventEmitter):
         too; after, counting starts from the next transition."""
         bind_transition_metrics(self, collector, label)
 
+    @classmethod
+    def _fsm_state(cls, name: str) -> tuple[tuple[str, ...], str]:
+        """The table's entry for state ``name``, made on first ask:
+        ``('armed',), 'state_armed_doublecheck'`` for
+        ``'armed.doublecheck'``."""
+        entry = cls._fsm_states.get(name)
+        if entry is None:
+            parts = name.split('.')
+            handler = 'state_' + '_'.join(parts)
+            if not callable(getattr(cls, handler, None)):
+                raise AttributeError('%s has no state %r' %
+                                     (cls.__name__, name))
+            entry = cls._fsm_states[name] = (
+                tuple('.'.join(parts[:i]) for i in range(1, len(parts))),
+                handler)
+        return entry
+
     def _transition(self, name: str) -> None:
         # A transition triggered from inside a state_* entry function is
         # deferred until the entry function returns (mooremachine allows
@@ -187,36 +235,34 @@ class FSM(EventEmitter):
         if self._in_transition:
             self._queued = name
             return
+        prefixes, handler = (self._fsm_states.get(name)
+                             or self._fsm_state(name))
 
         # Dispose scopes that are not parents of the new state.  Entering
         # 'armed.doublecheck' from 'armed' keeps the 'armed' scope alive;
         # entering 'wait_session' from 'armed.doublecheck' disposes both.
+        scopes = self._scopes
         keep = 0
-        parts = name.split('.')
-        prefixes = ['.'.join(parts[:i + 1]) for i in range(len(parts) - 1)]
-        for st, _scope in self._scopes:
-            if keep < len(prefixes) and st == prefixes[keep]:
-                keep += 1
-            else:
-                break
-        for _st, scope in reversed(self._scopes[keep:]):
-            scope._dispose()
-        del self._scopes[keep:]
+        if prefixes:
+            for st, _scope in scopes:
+                if keep < len(prefixes) and st == prefixes[keep]:
+                    keep += 1
+                else:
+                    break
+        while len(scopes) > keep:
+            scopes.pop()[1]._dispose()
 
-        handler = getattr(self, 'state_' + name.replace('.', '_'), None)
-        if handler is None:
-            raise AttributeError('%s has no state %r' %
-                                 (type(self).__name__, name))
         scope = StateScope(self, name)
-        self._scopes.append((name, scope))
+        scopes.append((name, scope))
         note_transition(self, self._state, name)
         self._state = name
         self._in_transition = True
         try:
-            handler(scope)
+            getattr(self, handler)(scope)
         finally:
             self._in_transition = False
-        self.emit('stateChanged', name)
+        if 'stateChanged' in self._listeners:
+            self.emit('stateChanged', name)
         if self._queued is not None:
             nxt, self._queued = self._queued, None
             self._transition(nxt)

@@ -49,6 +49,11 @@ def _label_key(labels) -> tuple[tuple[str, str], ...]:
     return tuple(sorted(items))
 
 
+#: what a series is keyed by, for a caller that resolves its label set
+#: once (``Counter.add``)
+label_key = _label_key
+
+
 class Counter:
     def __init__(self, name: str, help_text: str = ''):
         self.name = name
@@ -61,6 +66,13 @@ class Counter:
     def increment(self, labels: dict[str, str] | None = None,
                   by: float = 1.0) -> None:
         key = _label_key(labels)
+        self._values[key] = self._values.get(key, 0.0) + by
+
+    def add(self, key: tuple[tuple[str, str], ...],
+            by: float = 1.0) -> None:
+        """:meth:`increment` for a caller that kept its label set's
+        :func:`label_key` (utils/fsm.py: a transition counts into one
+        of a few series, over and over)."""
         self._values[key] = self._values.get(key, 0.0) + by
 
     def link(self, other: Counter) -> None:
