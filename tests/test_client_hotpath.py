@@ -318,9 +318,11 @@ async def test_a_client_keeps_its_loops_deadline_queue(server):
     c = await connected(server, op_timeout=None)
     try:
         await c.create('/k', b'v')
-        assert c._deadlines is None         # unbounded: never asked
-        await c.get('/k', deadline=5000)
+        # the loop's, from ``start()`` on (it carries the idle clock);
+        # an unbounded op stands in it never
         queue = deadline_queue(asyncio.get_running_loop())
+        assert c._deadlines is queue and len(queue) == 0
+        await c.get('/k', deadline=5000)
         assert c._deadlines is queue and len(queue) == 0
         await c.get('/k', deadline=5000)
         assert c._deadlines is queue

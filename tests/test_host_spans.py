@@ -28,6 +28,7 @@ def clean_host_ring():
     trace._bind()
     trace.host_ring.reset()
     trace._recording = False
+    _no_loops_thread()
     yield
     trace.host_ring.reset()
     trace._recording = False
@@ -38,6 +39,12 @@ def armed(monkeypatch):
     """The profiler's switch patched on: spans are recorded as inside
     a session (the annotation itself is then an un-armed no-op)."""
     monkeypatch.setattr(trace, '_is_enabled', lambda: True)
+
+
+def _no_loops_thread() -> None:
+    """This thread is no loop's until a test's loop turns under a
+    session (an earlier test's did)."""
+    trace._turn()[:] = [None, None, 0, None, None, None]
 
 
 def _totals() -> dict:
@@ -1121,6 +1128,326 @@ async def test_stages_and_pauses_under_a_real_session(server, tmp_path):
     (held,) = [e for e in events if e[0] == 'client.notify']
     assert any(held[1] <= a <= b <= held[2]
                for n, a, b in events if n == 'gc.pause')
+
+
+# -- the loop's whole turn: named spans, named gaps, idle ----------------
+
+APP_GAP = 'loop.gap@client.resume>client.prepare'
+
+
+def _a_loops_thread() -> None:
+    """What the select hook does in a session: this thread is a
+    loop's from its first ``loop.idle`` on."""
+    with trace.loop_idle():
+        pass
+
+
+def _gaps() -> dict:
+    return {k: v for k, v in trace.host_ring.totals.items()
+            if k.startswith('loop.gap@')}
+
+
+def test_a_gap_is_booked_under_the_pair_that_bounds_it(armed):
+    """Top-level span to top-level span on a loop's thread: one count
+    under ``loop.gap@<previous>><next>``, from the two spans' own
+    stamps; a nested span opens and closes none."""
+    _a_loops_thread()
+    with trace.host_span('ingest.tick', tick=1):
+        with trace.host_span('ingest.batch', tick=1):
+            pass
+        with trace.host_span('client.rx', accumulate=True):
+            pass
+    with trace.host_span('client.flush', accumulate=True):
+        pass
+    with trace.host_span('ingest.tick', tick=2) as sp:
+        sp.cancel()             # no span in the ring, a boundary still
+    with trace.host_span('client.flush', accumulate=True):
+        pass
+    gaps = _gaps()
+    assert {k: v[0] for k, v in gaps.items()} == {
+        'loop.gap@loop.idle>ingest.tick': 1,
+        'loop.gap@ingest.tick>client.flush': 2,
+        'loop.gap@client.flush>ingest.tick': 1}
+    assert all(ns > 0 for _n, ns in gaps.values())
+    (batch, tick) = trace.host_ring.spans()
+    assert trace.host_ring.totals['loop.named'][0] == 5
+    assert trace.host_ring.totals['loop.named'][1] >= tick.t1_ns - tick.t0_ns
+
+
+def test_named_gaps_and_pauses_sum_to_the_threads_time(armed):
+    """``loop.named`` + every gap + ``gc.pause@loop.gap`` is the time
+    from the first top-level span's start to the last one's end, to
+    the nanosecond."""
+    import gc
+
+    with trace.host_span('first'):
+        pass                    # not a loop's thread yet: not booked
+    _a_loops_thread()
+    with trace.host_span('a'):
+        with trace.host_span('client.rx', accumulate=True):
+            gc.collect()        # inside a span: that span's
+    gc.collect()                # in a gap
+    for tick in range(3):
+        with trace.host_span('ingest.tick', tick=tick) as sp:
+            if tick == 1:
+                sp.cancel()
+        with trace.host_span('client.submit', accumulate=True):
+            pass
+    with trace.host_span('z'):
+        pass
+    totals = trace.host_ring.totals
+    idle_ns = totals['loop.idle'][1]
+    a, *_rest, z = [s for s in trace.host_ring.spans() if s.parent is None
+                    and s.op != 'first']
+    assert (a.op, z.op) == ('a', 'z')
+    booked = (totals['loop.named'][1] + totals['gc.pause@loop.gap'][1]
+              + sum(ns for _n, ns in _gaps().values()))
+    # less what lies before ``a``: the idle span and the gap after it
+    assert booked - idle_ns - totals['loop.gap@loop.idle>a'][1] == (
+        z.t1_ns - a.t0_ns)
+    assert totals['loop.named'][0] == 9
+
+
+def test_a_thread_without_the_hook_books_no_gap(armed):
+    """A span opened on a thread whose loop has no idle hook (an
+    executor's ``prewarm``) books neither a gap nor ``loop.named`` —
+    here, or on a loop's thread meanwhile."""
+    import threading
+
+    def work():
+        for _ in range(3):
+            with trace.host_span('ingest.tick', tick=0):
+                pass
+    work()
+    assert not _gaps() and 'loop.named' not in trace.host_ring.totals
+    _a_loops_thread()
+    with trace.host_span('a', accumulate=True):
+        pass
+    t = threading.Thread(target=work)
+    t.start()
+    t.join()
+    with trace.host_span('b', accumulate=True):
+        pass
+    assert {k: v[0] for k, v in _gaps().items()} == {
+        'loop.gap@loop.idle>a': 1, 'loop.gap@a>b': 1}
+    assert trace.host_ring.totals['loop.named'][0] == 3
+
+
+def test_two_empty_spans_back_to_back_book_the_instruments_floor(armed):
+    """The calibration the chip run repeats: the gap between two empty
+    top-level spans opened back to back holds nothing but the closing
+    annotation's exit, the next ``host_span()`` call and the opening
+    annotation's enter."""
+    _a_loops_thread()
+    n = 2000
+    for _ in range(n):
+        with trace.host_span('floor.a', accumulate=True):
+            pass
+        with trace.host_span('floor.b', accumulate=True):
+            pass
+    count, total_ns = trace.host_ring.totals['loop.gap@floor.a>floor.b']
+    assert count == n
+    assert trace.host_ring.totals['loop.gap@floor.b>floor.a'][0] == n - 1
+    # a floor: positive, and far under what a gap with work in it
+    # holds (un-armed annotations here: a fraction of the chip's
+    # figure, PERF.md section 5)
+    assert 0 < total_ns / count < 20_000
+
+
+def test_a_collection_in_a_gap_is_taken_out_of_it(armed):
+    """A collection that begins under no span on a loop's thread is
+    ``gc.pause@loop.gap``, and the gap it fell in is booked without
+    it."""
+    import gc
+    import time
+
+    junk = [[i] for i in range(200_000)]        # a pause worth timing
+    gc.collect()
+    gc.disable()                # none but the one asked for
+    try:
+        _a_loops_thread()
+        with trace.host_span('a'):
+            pass
+        t0 = time.perf_counter_ns()
+        gc.collect()
+        t1 = time.perf_counter_ns()
+        with trace.host_span('b'):
+            pass
+    finally:
+        gc.enable()
+    del junk
+    a, b = trace.host_ring.spans()
+    n, pause_ns = trace.host_ring.totals['gc.pause@loop.gap']
+    gap = trace.host_ring.totals['loop.gap@a>b']
+    assert n == 1 and gap[0] == 1
+    assert 0 < pause_ns <= t1 - t0
+    assert gap[1] + pause_ns == b.t0_ns - a.t1_ns
+    assert trace.host_ring.totals['gc.pause'][1] >= pause_ns
+    # off a loop's thread a pause under no span is ``gc.pause`` alone
+    _no_loops_thread()
+    gc.collect()
+    assert trace.host_ring.totals['gc.pause@loop.gap'][0] == 1
+
+
+async def test_outside_a_session_the_loop_keeps_two_integers(server):
+    """No profiler session: the hook counts the loop's turns and its
+    time in ``select`` (the client's ``zkstream_loop_*`` series) and
+    books nothing — the ring's totals stay empty."""
+    from zkstream_tpu.utils.aio import deadline_queue
+
+    c = Client(address='127.0.0.1', port=server.port,
+               session_timeout=5000)
+    c.start()
+    queue = deadline_queue(asyncio.get_running_loop())
+    assert c._deadlines is queue
+    turns, idle_ns = queue.turns, queue.idle_ns
+    try:
+        await c.wait_connected(timeout=5)
+        await c.create('/n', b'x')
+        await asyncio.sleep(0.05)
+        for _ in range(3):
+            await c.get('/n')
+        assert queue.turns >= turns + 4
+        assert queue.idle_ns >= idle_ns + 40_000_000    # the sleep
+        assert not trace.host_ring.totals and len(trace.host_ring) == 0
+        assert trace._turn()[trace.L_SESSION] is None
+        rows = dict(line.rsplit(' ', 1) for line in
+                    c.collector.expose().splitlines()
+                    if line.startswith('zkstream_loop_'))
+        assert float(rows['zkstream_loop_idle_ms_total']) >= 40.0
+        assert int(rows['zkstream_loop_turns_total']) >= turns + 4
+    finally:
+        await c.close()
+
+
+def test_a_new_session_forgets_the_last_ones_mark(monkeypatch):
+    """``_begin_session()``: the first top-level span of a session has
+    no predecessor, whatever the thread closed in the session
+    before."""
+    on = [True]
+    monkeypatch.setattr(trace, '_is_enabled', lambda: on[0])
+    _a_loops_thread()
+    with trace.host_span('a', accumulate=True):
+        pass
+    assert 'loop.gap@loop.idle>a' in trace.host_ring.totals
+    on[0] = False
+    assert trace.host_span('a') is trace.NO_SPAN
+    on[0] = True
+    with trace.host_span('b', accumulate=True):
+        pass                                    # the new session's first
+    assert not _gaps()
+    assert trace.host_ring.totals['loop.named'][0] == 1
+    with trace.host_span('c', accumulate=True):
+        pass
+    assert {k: v[0] for k, v in _gaps().items()} == {'loop.gap@b>c': 1}
+
+
+async def test_the_idle_hook_nests_under_a_wrapper_put_on_later(armed):
+    """The harness's pattern (``_time_select``): a second wrapper put
+    around ``loop._selector.select`` after the program's and taken off
+    first finds the program's underneath, and leaves it in place;
+    inside a session each turn is one ``loop.idle``, a top-level span
+    like any other."""
+    from zkstream_tpu.utils.aio import deadline_queue
+
+    loop = asyncio.get_running_loop()
+    queue = deadline_queue(loop)
+    selector = loop._selector
+    hooked = selector.select
+    assert deadline_queue(loop) is queue and selector.select is hooked
+    outer = []
+
+    def timed(timeout=None):
+        outer.append(timeout)
+        return hooked(timeout)
+    selector.select = timed
+    try:
+        turns = queue.turns
+        with trace.host_span('client.flush', accumulate=True):
+            pass
+        await asyncio.sleep(0.02)
+        assert len(outer) >= 1 and queue.turns == turns + len(outer)
+    finally:
+        selector.select = hooked
+    await asyncio.sleep(0.01)
+    assert queue.turns > turns + len(outer)     # still on
+    totals = trace.host_ring.totals
+    assert totals['loop.idle'][0] >= 2
+    assert totals['loop.idle'][1] >= 25_000_000
+    assert any(k.startswith('loop.gap@loop.idle>') for k in totals)
+    assert any(k.endswith('>loop.idle') for k in _gaps())
+
+
+def test_a_loop_without_a_selector_gets_no_hook(armed):
+    """No ``_selector`` (a proactor loop, uvloop): no wrapper, both
+    integers stay 0, and the loop's thread books no gap."""
+    from zkstream_tpu.utils.aio import DeadlineQueue
+
+    class Loop:
+        pass
+
+    loop = Loop()
+    queue = DeadlineQueue(loop)
+    assert (queue.turns, queue.idle_ns) == (0, 0)
+    assert not hasattr(loop, '_selector')
+    for _ in range(2):
+        with trace.host_span('a', accumulate=True):
+            pass
+    assert not _gaps() and 'loop.named' not in trace.host_ring.totals
+
+
+async def test_a_closed_loop_caller_books_one_app_gap_an_op(server, armed):
+    """``client.resume`` -> ``client.prepare``: a caller that sends
+    its next request when the last one's reply wakes it leaves exactly
+    one ``loop.gap@client.resume>client.prepare`` an op after the
+    first — its own code between the two."""
+    c = Client(address='127.0.0.1', port=server.port,
+               session_timeout=5000)
+    c.start()
+    try:
+        await c.wait_connected(timeout=5)
+        await c.create('/r', b'v' * 64)
+        await asyncio.sleep(0.01)
+        trace.host_ring.reset()
+        trace._recording = False                # a session of its own
+        for _ in range(6):
+            await c.get('/r')
+        totals = dict(trace.host_ring.totals)
+    finally:
+        await c.close()
+    assert totals['client.prepare'][0] == totals['client.resume'][0] == 6
+    count, total_ns = totals[APP_GAP]
+    assert count == 5 and total_ns > 0
+    # every other gap an op opens is the loop's own: its request left
+    # (``client.submit``) and nothing of the caller's ran until the
+    # reply woke it
+    assert totals['loop.gap@client.prepare>client.submit'][0] >= 5
+    assert not [k for k in totals
+                if k.startswith('loop.gap@client.submit>client.prepare')]
+
+
+async def test_mntr_has_the_loop_threads_cpu(server):
+    """``zk_loop_cpu_ms``: cumulative, the scraping thread's, so no
+    more than the process's (``zk_process_cpu_ms``) beside it."""
+    before = await mntr_rows(server.port)
+    c = Client(address='127.0.0.1', port=server.port,
+               session_timeout=5000)
+    c.start()
+    try:
+        await c.wait_connected(timeout=5)
+        for i in range(200):
+            await c.create('/n%d' % i, b'x' * 128)
+    finally:
+        await c.close()
+    after = await mntr_rows(server.port)
+    keys = list(after)
+    assert keys.index('zk_loop_cpu_ms') == keys.index(
+        'zk_process_cpu_ms') + 1
+    loop_ms = [float(r['zk_loop_cpu_ms']) for r in (before, after)]
+    cpu_ms = [float(r['zk_process_cpu_ms']) for r in (before, after)]
+    assert 0 < loop_ms[0] < loop_ms[1]
+    assert all(lp <= cpu for lp, cpu in zip(loop_ms, cpu_ms))
+    assert loop_ms[1] - loop_ms[0] <= cpu_ms[1] - cpu_ms[0] + 1.0
 
 
 # -- the members: ledger phases, always-on histograms, mntr rows ---------

@@ -56,6 +56,8 @@ from .utils.trace import NO_SPAN, TraceRing, armed as session_armed, \
 METRIC_ZK_EVENT_COUNTER = 'zookeeper_events'
 METRIC_ZK_DEGRADED_GAUGE = 'zookeeper_degraded'
 METRIC_ZK_OP_LATENCY = 'zookeeper_op_latency_ms'
+METRIC_LOOP_IDLE = 'zkstream_loop_idle_ms_total'
+METRIC_LOOP_TURNS = 'zkstream_loop_turns_total'
 
 #: Default session timeout, ms (reference: lib/client.js:80-83).
 DEFAULT_SESSION_TIMEOUT = 30000
@@ -175,8 +177,10 @@ class Client(FSM):
         #: opcode -> that histogram's series, bound on the opcode's
         #: first op (utils/metrics.BoundSeries)
         self._op_series: dict = {}
-        #: the running loop's deadline queue, looked up by the first
-        #: bounded op (utils/aio.deadline_queue)
+        #: the running loop's deadline queue (utils/aio.deadline_queue),
+        #: looked up by ``start()`` — which is what puts the loop's
+        #: idle clock on — and again by a bounded op that finds itself
+        #: on another loop
         self._deadlines = None
         #: Bounded in-memory span ring (utils/trace.py): one span per
         #: op, xid-correlated through the connection and stamped with
@@ -267,9 +271,24 @@ class Client(FSM):
                 METRIC_ZK_DEGRADED_GAUGE,
                 lambda: 1.0 if self.pool.degraded else 0.0,
                 'Client degraded mode (1 = all backends failing)')
+            # The loop this client runs on, as its own clock has it
+            # (utils/aio.DeadlineQueue): 1 - idle / elapsed is the
+            # loop's utilisation.  0 before ``start()``.
+            self.collector.gauge(
+                METRIC_LOOP_IDLE,
+                lambda: 0.0 if self._deadlines is None
+                else self._deadlines.idle_ns / 1e6,
+                "Cumulative time this client's event loop stood in "
+                'select, milliseconds')
+            self.collector.gauge(
+                METRIC_LOOP_TURNS,
+                lambda: 0 if self._deadlines is None
+                else self._deadlines.turns,
+                "Cumulative turns (select calls) of this client's "
+                'event loop')
         except ValueError:
             # Shared collector across clients: the first registrant's
-            # pool owns the series.
+            # pool (and loop) owns the series.
             pass
 
         # FSM observability (utils/fsm.py): transition counters + a
@@ -333,6 +352,7 @@ class Client(FSM):
         assert not self._started, 'client already started'
         self._started = True
         self._t_start = time.perf_counter_ns()
+        self._deadlines = deadline_queue(ambient_loop())
         self.pool.start()
         if self._read_plane is not None:
             self._read_plane.start()

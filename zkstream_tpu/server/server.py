@@ -2144,6 +2144,13 @@ class ZKServer:
             # much of the CPU the phases name
             ('zk_process_cpu_ms',
              round(time.process_time() * 1000.0, 3)),
+            # ... and of the THREAD that answers the scrape, which is
+            # this member's event loop's (an in-process ensemble's
+            # members all read the one thread): its delta over the
+            # process's is the share of the CPU that is the loop's —
+            # the rest is other threads' (fsync, black box, collector)
+            ('zk_loop_cpu_ms',
+             round(time.thread_time() * 1000.0, 3)),
             ('zk_server_state', self.mode()),
             ('zk_member_role', self.role),
             ('zk_epoch', self.current_epoch()),

@@ -6,8 +6,9 @@ import asyncio
 import math
 import socket
 import threading
+import time
 
-from .trace import host_span
+from .trace import host_span, loop_idle
 
 
 def ambient_loop() -> asyncio.AbstractEventLoop:
@@ -66,9 +67,19 @@ class DeadlineQueue:
 
     Host span ``client.deadline`` (profiler sessions only; count and
     total): each arming and each firing of the timer.  Beside
-    ``client.submit``'s count it gives requests per loop timer."""
+    ``client.submit``'s count it gives requests per loop timer.
 
-    __slots__ = ('loop', '_lanes', '_timer', '_when', '_sweep_at')
+    Being the one object a loop's clients share from their start, it
+    also carries the loop's idle clock: :attr:`idle_ns` and
+    :attr:`turns`, fed by one wrapper around the loop's
+    ``_selector.select`` that is put on here and left in place
+    (a loop without that attribute gets none, and both stay 0).
+    1 - idle / elapsed is the loop's utilisation; inside a profiler
+    session the same call is host span ``loop.idle``
+    (utils/trace.loop_idle)."""
+
+    __slots__ = ('loop', '_lanes', '_timer', '_when', '_sweep_at',
+                 'idle_ns', 'turns')
 
     #: Timeouts in the table before a new one looks for empty ones.
     SWEEP_MIN = 64
@@ -81,6 +92,26 @@ class DeadlineQueue:
         #: what the timer is armed for; inf while it is not
         self._when = math.inf
         self._sweep_at = self.SWEEP_MIN
+        #: nanoseconds the loop stood in ``select``, and how often
+        self.idle_ns = 0
+        self.turns = 0
+        self._time_select()
+
+    def _time_select(self) -> None:
+        selector = getattr(self.loop, '_selector', None)
+        if selector is None:
+            return
+        select = selector.select
+        clock = time.perf_counter_ns
+
+        def timed(timeout=None):
+            t0 = clock()
+            with loop_idle():
+                events = select(timeout)
+            self.idle_ns += clock() - t0
+            self.turns += 1
+            return events
+        selector.select = timed
 
     def __len__(self) -> int:
         """Entries still waited on."""

@@ -80,6 +80,14 @@ from each:
   a ``gc.pause`` annotation in the trace too), and the part of them
   that began while ``<span>`` was the innermost host span open on
   that thread: subtract it from that span's own total.
+- ``loop.idle``, ``loop.named``, ``loop.gap@<previous>><next>`` and
+  ``gc.pause@loop.gap`` — a client loop's whole turn (see "The loop's
+  whole turn", below): blocked in ``select``; under any top-level
+  span (``loop.idle`` among them); between two of them, named by
+  both; and the collections that began there.  How much of my loop is
+  the library (the named spans less ``loop.idle``), how much my own
+  code (``client.resume>client.prepare``), how much the loop itself
+  (every other gap).
 """
 
 from __future__ import annotations
@@ -521,7 +529,33 @@ _annotation = None
 _is_enabled = None
 #: a session was active at the last look (its first span reset the ring)
 _recording = False
+
+
+#: ``turn``, a thread's own list (``L_*``; made by :func:`_turn`): the
+#: innermost host span open on the thread and — on a thread whose
+#: event loop carries the idle hook (:func:`loop_idle`), else None —
+#: the profiler session its mark belongs to (a mark of an earlier one
+#: opens no gap), the last top-level span's end and name, that name's
+#: row of ``_gaps``, and the session's ``loop.named`` total: what the
+#: last top-level span that closed there left for the next, the gap's
+#: start.
 _open = threading.local()
+L_SPAN, L_SESSION, L_END, L_NAME, L_GAPS, L_NAMED = range(6)
+#: the number of the profiler session the ring holds
+_session = 0
+#: previous span's name -> {next span's name -> the ``[count,
+#: total_ns]`` under ``loop.gap@<previous>><next>`` in ``host_ring.
+#: totals``}: a pair's key is built once a session (some forty occur)
+_gaps: dict = {}
+
+
+def _turn() -> list:
+    """This thread's ``turn``, made at its first host span."""
+    try:
+        return _open.turn
+    except AttributeError:
+        turn = _open.turn = [None, None, 0, None, None, None]
+        return turn
 
 
 def _bind() -> bool:
@@ -543,9 +577,11 @@ def _begin_session() -> None:
     exactly one session, and the collector's pauses are recorded
     (their total is there from the start: a session without a
     collection reads zero pauses, not an unrecorded figure)."""
-    global _recording
+    global _recording, _session
     host_ring.reset()
     host_ring.totals['gc.pause'] = [0, 0]
+    _gaps.clear()
+    _session += 1
     if _gc_pause not in gc.callbacks:
         gc.callbacks.append(_gc_pause)
     _recording = True
@@ -610,7 +646,7 @@ def host_add(name: str, count: int, total_ns: int) -> None:
 
 class _HostSpan:
     __slots__ = ('name', 'ids', 'fields', '_accumulate', '_ann',
-                 '_parent', 't0_ns', '_cancelled')
+                 '_parent', '_turn', 't0_ns', '_cancelled')
 
     def __init__(self, name: str, accumulate: bool, ids: dict):
         self.name = name
@@ -631,17 +667,48 @@ class _HostSpan:
         self._cancelled = True
 
     def __enter__(self):
-        self._parent = getattr(_open, 'span', None)
-        _open.span = self
+        try:
+            turn = self._turn = _open.turn
+        except AttributeError:
+            turn = self._turn = _turn()
+        parent = self._parent = turn[L_SPAN]
+        turn[L_SPAN] = self
         self._ann = _annotation(self.name, **self.ids)
         self._ann.__enter__()
-        self.t0_ns = time.perf_counter_ns()
+        t0 = self.t0_ns = time.perf_counter_ns()
+        if parent is None and turn[L_SESSION] == _session:
+            # a top-level span closes the gap its predecessor on this
+            # loop's thread opened ("The loop's whole turn", below)
+            tot = turn[L_GAPS].get(self.name)
+            if tot is None:
+                tot = turn[L_GAPS][self.name] = host_ring.totals[
+                    'loop.gap@%s>%s' % (turn[L_NAME], self.name)
+                ] = [0, 0]
+            tot[0] += 1
+            tot[1] += t0 - turn[L_END]
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter_ns()
         self._ann.__exit__(*exc)
-        _open.span = self._parent
+        turn = self._turn
+        parent = turn[L_SPAN] = self._parent
+        if parent is None and turn[L_SESSION] is not None:
+            # ... and opens the next (a cancelled span too: the
+            # thread's time was under it all the same)
+            if turn[L_SESSION] != _session:
+                turn[L_SESSION] = _session
+                turn[L_NAMED] = host_ring.totals.setdefault(
+                    LOOP_NAMED, [0, 0])
+            name = turn[L_NAME] = self.name
+            turn[L_END] = t1
+            gaps = _gaps.get(name)
+            if gaps is None:
+                gaps = _gaps[name] = {}
+            turn[L_GAPS] = gaps
+            named = turn[L_NAMED]
+            named[0] += 1
+            named[1] += t1 - self.t0_ns
         if self._cancelled:
             return False
         if self._accumulate:
@@ -653,10 +720,53 @@ class _HostSpan:
         fields.setdefault('tick', None)
         host_ring.note(
             self.name, kind='host',
-            parent=None if self._parent is None else self._parent.name,
+            parent=None if parent is None else parent.name,
             t0_ns=self.t0_ns, t1_ns=t1,
             duration_ms=(t1 - self.t0_ns) / 1e6, **fields)
         return False
+
+
+# ---------------------------------------------------------------------
+# The loop's whole turn: inside a profiler session every nanosecond of
+# a client loop's thread is under a top-level host span, in a named gap
+# between two of them, or idle (which is a top-level span too).
+# ---------------------------------------------------------------------
+
+#: ``host_ring.totals`` names.  ``loop.gap@<previous>><next>``: the
+#: time between the end of one top-level span and the start of the
+#: next on a loop's thread, one count a gap — WHAT it was follows from
+#: its neighbours (``client.resume>client.prepare`` is the caller's own
+#: code between one reply and its next request; ``client.submit>
+#: client.resume``, ``ingest.tick>client.resume``, ``X>loop.idle`` are
+#: asyncio's switch and nothing else).  Both stamps are the spans' own
+#: (``t0_ns`` is read after the annotation's ``__enter__``, ``t1``
+#: before its ``__exit__``), so a gap holds the closing annotation's
+#: exit, the next ``host_span()`` call and the opening annotation's
+#: enter: the instrument's floor a gap, which two empty top-level spans
+#: opened back to back book alone.  ``loop.named``: every top-level
+#: span's own ``t1 - t0_ns`` (``loop.idle`` among them).
+#: ``gc.pause@loop.gap``: the collections that began under no span,
+#: taken out of the gap they fell in — so ``loop.named`` + every gap +
+#: ``gc.pause@loop.gap`` is the thread's time from its first top-level
+#: span's start to its last one's end.
+LOOP_NAMED = 'loop.named'
+LOOP_GAP = 'loop.gap'
+
+
+def loop_idle():
+    """Host span ``loop.idle`` (count and total), for the one hook an
+    event loop's ``select`` carries (utils/aio.DeadlineQueue): the loop
+    blocked waiting for sockets and timers.  Asking for it inside a
+    session is what makes the calling thread a loop's thread: from its
+    first ``loop.idle`` on, the thread's top-level spans book their
+    gaps and ``loop.named``.  A span on any other thread (a
+    ``prewarm`` on an executor) books neither."""
+    sp = host_span('loop.idle', accumulate=True)
+    if sp is not NO_SPAN:
+        turn = _turn()
+        if turn[L_SESSION] is None:
+            turn[L_SESSION] = 0     # no session's: no predecessor
+    return sp
 
 
 # ---------------------------------------------------------------------
@@ -751,7 +861,9 @@ def _gc_pause(phase: str, info: dict) -> None:
     annotation from its ``start`` to its ``stop`` and one count in
     ``host_ring.totals['gc.pause']`` — and in ``['gc.pause@<name>']``
     for the innermost host span open on this thread when it began, so
-    a reader can take the pauses out of the span that held them.
+    a reader can take the pauses out of the span that held them; one
+    that began under no span on a loop's thread is booked under
+    ``gc.pause@loop.gap`` and taken out of that gap here.
     Outside a session it is one ``is_enabled()`` a collection."""
     global _gc_open
     if phase == 'start':
@@ -759,9 +871,13 @@ def _gc_pause(phase: str, info: dict) -> None:
             return
         ann = _annotation('gc.pause')
         ann.__enter__()
-        holder = getattr(_open, 'span', None)
-        _gc_open = (ann, None if holder is None else holder.name,
-                    time.perf_counter_ns())
+        turn = _turn()
+        holder = turn[L_SPAN]
+        if holder is not None:
+            holder = holder.name
+        elif turn[L_SESSION] == _session:
+            holder = LOOP_GAP
+        _gc_open = (ann, holder, time.perf_counter_ns())
     elif _gc_open is not None:
         t1 = time.perf_counter_ns()
         ann, holder, t0 = _gc_open
@@ -770,3 +886,6 @@ def _gc_pause(phase: str, info: dict) -> None:
         _add('gc.pause', 1, t1 - t0)
         if holder is not None:
             _add('gc.pause@' + holder, 1, t1 - t0)
+            if holder == LOOP_GAP:
+                # the gap the pause fell in starts that much later
+                _open.turn[L_END] += t1 - t0
