@@ -110,6 +110,79 @@ def test_pallas_empty_and_full_rows():
     assert int(got.n_frames[1]) == 1 and bool(got.bad[2])
 
 
+def _step(impl, buf, lens, max_frames):
+    if impl == 'jnp':
+        return wire_pipeline_step(buf, lens, max_frames=max_frames)
+    return wire_pipeline_step_pallas(buf, lens, max_frames=max_frames,
+                                     block_rows=8, interpret=True)
+
+
+def _one_frame_rows(rng, B, L, widest):
+    """``B`` rows of width ``widest``: the even ones hold exactly ONE
+    frame wider than ``L`` (a reply of random header and body), the odd
+    ones an ordinary run of frames that fits ``L``.  Returns the
+    full-width batch, its ``lens``, and the rows' first ``L`` bytes."""
+    small, small_lens = _fleet(rng, B, L, partial_tail=True)
+    buf = np.zeros((B, widest), np.uint8)
+    buf[:, :L] = np.asarray(small)
+    lens = np.array(small_lens)
+    for i in range(0, B, 2):
+        n = rng.choice([L + 1, L + 17, rng.randrange(L + 1, widest),
+                        widest])
+        f = _reply_frame(rng.randrange(1, 1 << 30),
+                         rng.randrange(0, 1 << 62),
+                         rng.choice([0, 0, -101]),
+                         rng.randbytes(n - 20))
+        buf[i, :n] = np.frombuffer(f, np.uint8)
+        buf[i, n:] = 0xEE
+        lens[i] = n
+    return jnp.asarray(buf), jnp.asarray(lens), jnp.asarray(buf[:, :L])
+
+
+@pytest.mark.parametrize('impl', ['jnp', 'pallas'])
+@pytest.mark.parametrize('seed', [0, 1, 2])
+def test_header_rows_scan_as_their_full_width_rows(seed, impl):
+    """What the fleet ingest gives a tick for a slot that holds exactly
+    one whole frame (io/ingest.py, "Size classes"): the frame's first
+    ``L`` bytes under its TRUE length.  Both implementations — the jnp
+    pipeline and the Pallas kernel (interpret mode) — give every plane
+    of ``WireStats`` as they give it for the full-width row: one frame
+    at 4, its size the prefix, ``resid == lens``, the header parsed,
+    nothing flagged; the ordinary rows beside them are not disturbed.
+    So ``auto_impl`` needs no exception for a bucket that may hold
+    header rows: the kernel agrees."""
+    rng = random.Random(seed)
+    B, L, widest = 16, 128, 4096
+    full, lens, heads = _one_frame_rows(rng, B, L, widest)
+    want = wire_pipeline_step(full, lens, max_frames=8)
+    got = _step(impl, heads, lens, 8)
+    _assert_same(want, got)
+    lens = np.asarray(lens)
+    for i in range(0, B, 2):
+        assert int(got.n_frames[i]) == 1 and not bool(got.bad[i])
+        assert int(got.resid[i]) == lens[i] > L
+        assert int(got.starts[i, 0]) == 4
+        assert int(got.sizes[i, 0]) == lens[i] - 4
+
+
+@pytest.mark.parametrize('impl', ['jnp', 'pallas'])
+def test_a_header_row_of_a_frame_at_the_cap(impl):
+    """``lens`` far beyond the row: a frame of the 16 MiB cap from its
+    first 64 bytes (nothing wider is built to compare it with)."""
+    f = _reply_frame(77, (5 << 32) | 9, -101, b'\x05' * 44)
+    row = bytearray(f)
+    row[:4] = struct.pack('>i', MAX_PACKET)
+    buf = jnp.asarray(np.frombuffer(bytes(row), np.uint8)[None, :])
+    lens = jnp.asarray(np.array([MAX_PACKET + 4], np.int32))
+    st = _step(impl, buf, lens, 4)
+    assert int(st.n_frames[0]) == 1 and not bool(st.bad[0])
+    assert int(st.resid[0]) == MAX_PACKET + 4
+    assert (int(st.starts[0, 0]), int(st.sizes[0, 0])) == (4, MAX_PACKET)
+    assert (int(st.xids[0, 0]), int(st.errs[0, 0])) == (77, -101)
+    assert (int(st.zxid_hi[0, 0]), int(st.zxid_lo[0, 0])) == (5, 9)
+    assert np.asarray(st.starts[0, 1:]).tolist() == [-1, -1, -1]
+
+
 V5E = 'TPU v5 lite'
 
 

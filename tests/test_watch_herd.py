@@ -3,10 +3,12 @@ ingest whose tick memory is smaller than the burst.
 
 Every change of the znode notifies N ``ZKWatcher``s at once; each
 re-arms with a ``getData`` that returns the whole document, so the N
-replies of one size class land in the ingest together.  With
-``TICK_BYTES`` below N rows of that class a tick dispatches what fits
-and leaves the rest — whole frames — in their slots for the follow-up
-tick (``ticks_full`` counts those ticks).  Held against a plain
+replies land in the ingest together — each all its slot holds, so each
+a header row of ``min_len`` bytes (io/ingest.py, "Size classes"): the
+90 KiB behind it stay in the slot.  With ``TICK_BYTES`` below N such
+rows a tick dispatches what fits and leaves the rest — whole frames —
+in their slots for the follow-up tick (``ticks_full`` counts those
+ticks).  Held against a plain
 dictionary model (version -> bytes) and against the same run on the
 per-socket scalar drain: every watcher emits every version once, in
 order, with the model's bytes over their whole length.
@@ -27,7 +29,7 @@ from zkstream_tpu.server import ZKEnsemble
 PATH = '/view'
 N = 12              # watchers, four a member
 VERSIONS = 5
-BASE = 90 * 1024    # version v holds BASE + 160 v bytes: one 128 KiB row
+BASE = 90 * 1024    # version v holds BASE + 160 v bytes
 
 
 def payload(seed: int, version: int) -> bytes:
@@ -45,9 +47,10 @@ async def herd(through_ingest: bool, seed: int):
         ingest = FleetIngest(placement='host',
                              max_frames=4, min_len=1024, max_data=256,
                              bypass_bytes=0, warm='block')
-        # three 128 KiB rows a tick; a dispatch holds two
-        ingest.DISPATCH_BYTES = 1 << 18
-        ingest.TICK_BYTES = 3 << 17
+        # four rows a dispatch (its bucket is the [8, min_len]
+        # floor), one dispatch a tick
+        ingest.DISPATCH_BYTES = 4 << 10
+        ingest.TICK_BYTES = 8 << 10
     writer = Client(address='127.0.0.1', port=ports[0],
                     session_timeout=30000)
     clients = [Client(servers=[('127.0.0.1', ports[i % 3])],
@@ -103,7 +106,12 @@ async def test_every_watcher_emits_every_version_once_in_order(seed):
                                  or ingest.ticks_warming
                                  or ingest.ticks_frag)
     assert ingest.bytes_recopied == 0
-    assert len(ingest._arena) == 3 << 17
+    assert len(ingest._arena) == 8 << 10
+    # the re-reads were header rows (all of them, but for one that a
+    # slow machine lets a ping's reply share a slot with): a document's
+    # bytes stayed home
+    assert ingest.rows_headed >= N * VERSIONS
+    assert ingest.bytes_kept_home >= ingest.rows_headed * (BASE - 1024)
 
 
 async def test_full_ticks_are_exported(monkeypatch):

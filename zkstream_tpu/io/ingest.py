@@ -90,10 +90,42 @@ follow-up tick).  A slot
 whose buffered bytes do not yet hold its first frame whole waits (the
 host reads that frame's 4-byte length prefix and nothing else: the
 frame scan stays on the device), so a large reply that arrives over
-many reads is copied into a batch once.  A stream is in one dispatch a
+many reads is given to a tick once.  A stream is in one dispatch a
 tick.  With one class present — every tick of a fleet of small
 replies — the tick is one dispatch, as it was before there were
 classes.
+
+**What a slot gives a tick.**  The tick program reads, of every frame,
+its length prefix and the 16 header bytes behind it (ops/frame_scan.py,
+ops/headers.py), and compares cursors with the row's ``lens``; the
+bodies are decoded on the host, from the slot.  So a slot whose bytes
+are EXACTLY one whole frame wider than ``min_len`` — what the prefix
+the host reads anyway says, and what every slot of a fleet with one
+request outstanding holds — gives a **header row**: the frame's first
+``min_len`` bytes, under a ``lens`` entry that is the frame's TRUE
+length.  The scan's first step finds the frame whole (``4 + ln <=
+lens``), emits its start and size and moves the cursor to its end,
+where no further step finds a prefix; the header parse gathers bytes
+4..19; ``resid`` is the frame's length: plane for plane what the
+frame's full-width row gives (tests/test_pallas.py holds both
+implementations to it), and the row stands in the ``min_len`` class
+whatever the frame's size — a tick of large replies is ONE narrow
+dispatch.  What stops is copying and sending bytes that no device op
+reads; the device still delimits every frame and parses every header of
+every tick.  Any other slot gives its bytes to their class as above: a
+slot that holds MORE than its first frame (a pipelined session's run of
+replies, a notification in front of a reply) — what lies behind the
+first frame is unknown until the device has scanned it — a frame of at
+most ``min_len``, a prefix no frame can have.  The code decides by what
+it finds in the slot; nothing is set.  A tick's header rows join its
+``min_len`` dispatch; a tick whose other rows all stand in wider classes
+lets them ride the narrowest dispatch it has (a header row fits any
+width) unless that would pad it by more than
+:attr:`FleetIngest.RIDE_BYTES`, where a ``min_len`` dispatch of their
+own is cheaper.  ``rows_headed`` counts the header rows,
+``bytes_kept_home`` the bytes of their frames that stayed in the slots;
+``bytes_batched`` is, as before, what was copied into rows, and
+``bytes_dispatched`` the ``Bp x L`` that crossed the link.
 
 **No tick ever blocks on XLA.**  Compiling the tick program for a new
 (batch, length) bucket costs ~1 s on the host CPU backend — 3 orders
@@ -160,6 +192,9 @@ _MISSING = object()
 #: a frame's 4-byte length prefix, and the most bytes a frame has with it
 _PREFIX = struct.Struct('>I').unpack_from
 _FRAME_TOP = MAX_PACKET + 4
+#: the bytes of a frame the tick program reads: its length prefix and
+#: the reply header behind it
+_HEAD = 4 + REPLY_HDR
 
 METRIC_INGEST_PHASE = 'zkstream_ingest_phase_ms'
 _PHASE_HELP = ('Device tick time by phase, milliseconds (batch: find the '
@@ -276,12 +311,18 @@ class FleetIngest:
     DISPATCH_BYTES = 16 << 20
     #: The padded bytes ONE TICK may dispatch: its batches are laid
     #: side by side in one buffer of this size that every tick uses
-    #: again (no allocation and no zeroing a tick: the scan reads
-    #: nothing beyond a row's length, so what an earlier tick left in
-    #: the padding is never looked at), all of them are in flight
+    #: again (no allocation and no zeroing a tick: the scan uses
+    #: nothing beyond the bytes a row was given, so what an earlier
+    #: tick left in the padding is never looked at), all of them are in flight
     #: together, so the device holds this much + the packed results,
     #: and what does not fit waits in its slots for the follow-up tick.
     TICK_BYTES = 4 * DISPATCH_BYTES
+    #: The padded bytes a tick's header rows may ADD to a wider
+    #: dispatch they ride (a tick with no ``min_len`` dispatch of its
+    #: own): a dispatch costs the loop ~0.7 ms whatever it carries and
+    #: the link moves ~2.5 GB/s (PERF.md), so beyond ~1 MiB of padding
+    #: a ``min_len`` dispatch of their own is the cheaper one.
+    RIDE_BYTES = 1 << 20
 
     #: always 0: ``benchmark/harness.py`` reads it (``INGEST_COUNTERS``)
     body_fallbacks = 0
@@ -428,6 +469,13 @@ class FleetIngest:
         self.bytes_dispatched = 0
         self.bytes_recopied = 0
         self.slots_deferred = 0
+        #: The header rows the device ticks were given (a slot that
+        #: held exactly one whole frame wider than ``min_len``: the
+        #: row is the frame's first ``min_len`` bytes under its true
+        #: length), and the bytes of those frames that were not copied
+        #: and did not cross the link: no device op reads them.
+        self.rows_headed = 0
+        self.bytes_kept_home = 0
         #: What a fleet whose clients pipeline meets, and one request
         #: a session never does: streams that gave a tick the whole
         #: frame bound (``max_frames`` frames from one row: the scan's
@@ -820,9 +868,12 @@ class FleetIngest:
         dispatch ``8 x min_len`` bytes (8 rows in the ``min_len``
         class, 1 in the classes eight times as wide and wider)."""
         L = self._width(nbytes)
-        Bp = _next_pow2(max(n_streams, 8 * self.min_len // L, 1))
         # the leading False: benchmark/reduce_trace.py unpacks 3-tuples
-        return (False, Bp, L)
+        return (False, self._padded_rows(n_streams, L), L)
+
+    def _padded_rows(self, n_streams: int, width: int) -> int:
+        """The rows of a dispatch of ``n_streams`` rows of ``width``."""
+        return _next_pow2(max(n_streams, 8 * self.min_len // width, 1))
 
     def _width(self, nbytes: int) -> int:
         """The size class of a row of ``nbytes``: its width."""
@@ -832,6 +883,25 @@ class FleetIngest:
         """How many rows of the size class of ``nbytes`` one dispatch
         holds."""
         return max(1, self.DISPATCH_BYTES // self._width(nbytes))
+
+    def _head_class(self, classes: dict, heads: int) -> int:
+        """The size class (its width's bit length; 0: ``min_len``)
+        whose dispatch a tick's ``heads`` header rows stand in, given
+        the tick's other rows by class: the narrowest — ``min_len``'s
+        where the tick has such rows or no others; else they ride the
+        narrowest class present, so that they add no dispatch, where
+        its one dispatch has the rows and grows by no more than
+        ``RIDE_BYTES`` for them."""
+        if not classes or 0 in classes:
+            return 0
+        c = min(classes)
+        width, rows = 1 << c, len(classes[c][0])
+        grown = (self._padded_rows(rows + heads, width)
+                 - self._padded_rows(rows, width)) * width
+        if (rows + heads <= self._class_rows(width)
+                and grown <= self.RIDE_BYTES):
+            return c
+        return 0
 
     def _compile(self, key: tuple):
         """Lower + AOT-compile the tick program for one shape bucket.
@@ -1001,6 +1071,15 @@ class FleetIngest:
                  'stream bytes copied into the ticks\' batches'),
                 ('zkstream_ingest_dispatched_bytes', 'bytes_dispatched',
                  'padded bytes the dispatches held (Bp x L summed)'),
+                ('zkstream_ingest_headed_rows', 'rows_headed',
+                 'header rows given to the device ticks: a slot that '
+                 'held exactly one whole frame wider than min_len gave '
+                 'the frame\'s first min_len bytes under its true '
+                 'length'),
+                ('zkstream_ingest_kept_home_bytes', 'bytes_kept_home',
+                 'bytes of the header rows\' frames that stayed in '
+                 'their slots: not copied into a batch, not sent over '
+                 'the link (no device op reads them)'),
                 ('zkstream_ingest_recopied_bytes', 'bytes_recopied',
                  'bytes batched that their tick did not consume (a '
                  'partial frame behind whole ones), so batched again'),
@@ -1423,7 +1502,10 @@ class FleetIngest:
     def _prepare_batch(self, active, sp=NO_SPAN):
         """Decide how this tick drains and, for a device tick, build
         its batches: returns the tick's dispatches, each ``(ex, key,
-        streams, batch, lens, nbytes)``; None when the tick was
+        streams, batch, lens, nbytes, headed, kept)`` — ``lens`` what
+        the device gets, ``nbytes`` the bytes copied into the rows,
+        ``headed`` / ``kept`` its header rows and the bytes of their
+        frames that were not copied; None when the tick was
         drained here another way (the pass-through flip, buckets still
         compiling or that failed to compile); ``()`` when no slot
         holds a whole frame yet: nothing was drained."""
@@ -1440,13 +1522,20 @@ class FleetIngest:
         # not whole yet waits — the one thing the host reads of a
         # stream is that frame's length prefix — so a reply that
         # arrives over many reads is copied once, when it is whole.
-        # Behind a whole first frame the slot gives what the frame
+        # A slot that holds exactly one whole frame wider than
+        # ``min_len`` gives a header row ("What a slot gives a tick"):
+        # its first ``min_len`` bytes under the frame's true length (a
+        # ``min_len`` under what the program reads of a frame has
+        # none).  Behind a
+        # whole first frame any other slot gives what the frame
         # bound could consume if the frames behind are of its size
         # (the power of two over ``max_frames`` of it): a partial large
         # frame behind a small one is not copied along.  A prefix no
         # frame can have goes to the device, which flags the stream.
         min_len, frames = self.min_len, self.max_frames
         streams, sizes = [], []
+        heads, trues = [], []
+        head_top = _FRAME_TOP if min_len >= _HEAD else 0
         cut = 0
         for slot in active:
             buf = slot[1]
@@ -1458,6 +1547,10 @@ class FleetIngest:
                 self.slots_deferred += 1
                 continue
             if have > min_len:
+                if have == n <= head_top:
+                    heads.append(slot)
+                    trues.append(n)
+                    continue
                 have = min(have, min_len if n > _FRAME_TOP
                            else self._width(n * frames))
                 cut += have < len(buf)
@@ -1466,15 +1559,18 @@ class FleetIngest:
         if cut:
             self.slots_cut += cut
             self._schedule()    # a slot holds more than it gave
-        if not streams:
+        if not streams and not heads:
             return ()
 
         # one dispatch a size class present, a class's rows beyond
         # ``DISPATCH_BYTES`` in further ones; with every row in the
         # narrowest class (a fleet of small replies) the tick is one
-        # dispatch of everything, and this is all it costs
-        if max(sizes) <= min_len:
-            groups = [(streams, sizes)]
+        # dispatch of everything, and this is all it costs.  A group
+        # is its slots, the bytes each gives, and — where header rows
+        # stand in it — the lengths the device gets (else None: the
+        # bytes given).
+        if not heads and max(sizes) <= min_len:
+            groups = [(streams, sizes, None)]
         else:
             classes: dict = {}
             for slot, n in zip(streams, sizes):
@@ -1482,18 +1578,28 @@ class FleetIngest:
                     (n - 1).bit_length() if n > min_len else 0, ([], []))
                 g[0].append(slot)
                 g[1].append(n)
+            host = None
+            if heads:
+                host = self._head_class(classes, len(heads))
+                classes.setdefault(host, ([], []))
             groups = []
-            for _c, (g_streams, g_sizes) in sorted(classes.items()):
+            for c, (g_streams, g_sizes) in sorted(classes.items()):
+                g_lens = None
+                if c == host:
+                    g_lens = g_sizes + trues
+                    g_streams = g_streams + heads
+                    g_sizes = g_sizes + [min_len] * len(heads)
                 rows = self._class_rows(max(g_sizes))
                 for lo in range(0, len(g_streams), rows):
                     groups.append((g_streams[lo:lo + rows],
-                                   g_sizes[lo:lo + rows]))
+                                   g_sizes[lo:lo + rows],
+                                   g_lens and g_lens[lo:lo + rows]))
 
         plans, scalar = [], []
         arena, used = self._arena, 0
         if arena is None:
             arena = self._arena = np.empty((self.TICK_BYTES,), np.uint8)
-        for g_streams, g_sizes in groups:
+        for g_streams, g_sizes, g_lens in groups:
             key = self._bucket(len(g_streams), max(g_sizes))
             ex = self._exec.get(key, _MISSING)
             if ex is _MISSING:
@@ -1532,8 +1638,14 @@ class FleetIngest:
                     slot[1] if n == len(slot[1])
                     else memoryview(slot[1])[:n])
             lens = np.zeros((Bp,), np.int32)
-            lens[:len(g_sizes)] = g_sizes
-            plans.append((ex, key, g_streams, batch, lens, sum(g_sizes)))
+            lens[:len(g_sizes)] = g_lens or g_sizes
+            nbytes = sum(g_sizes)
+            # of a group with header rows: how many, and the bytes of
+            # their frames that stayed in the slots
+            headed = sum(map(int.__ne__, g_lens, g_sizes)) if g_lens else 0
+            kept = sum(g_lens) - nbytes if g_lens else 0
+            plans.append((ex, key, g_streams, batch, lens, nbytes,
+                          headed, kept))
         hows = {how for _s, how in scalar}
         self.ticks_warming += 'warming' in hows
         self.ticks_scalar += 'scalar' in hows
@@ -1553,7 +1665,9 @@ class FleetIngest:
                            if len(plans) == 1 else
                            'device %d dispatches streams=%d'
                            % (len(plans), rows)),
-                   nbytes=sum(p[5] for p in plans), cut=cut)
+                   nbytes=sum(p[5] for p in plans), cut=cut,
+                   headed=sum(p[6] for p in plans),
+                   kept=sum(p[7] for p in plans))
         return plans
 
     def _dispatch(self, plans, before: int, t0: float) -> _Flight:
@@ -1568,7 +1682,7 @@ class FleetIngest:
         n = self.ticks
         t1 = time.perf_counter()
         outs = []
-        for ex, key, streams, batch, lens, nbytes in plans:
+        for ex, key, streams, batch, lens, nbytes, headed, kept in plans:
             with host_span('ingest.dispatch', tick=n, rows=len(streams),
                            width=key[2], nbytes=nbytes):
                 out = ex(batch, lens)
@@ -1580,6 +1694,8 @@ class FleetIngest:
             self.dispatches += 1
             self.bytes_batched += nbytes
             self.bytes_dispatched += batch.size
+            self.rows_headed += headed
+            self.bytes_kept_home += kept
         return _Flight(n, plans, outs, before,
                        (t0, t1, time.perf_counter()))
 

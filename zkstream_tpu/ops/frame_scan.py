@@ -43,7 +43,11 @@ def frame_cursor_scan(buf, lens, max_frames: int):
 
     Args:
       buf: uint8 [B, L] — each row is one connection's accumulated bytes.
-      lens: int32 [B] — valid byte count per row.
+      lens: int32 [B] — valid byte count per row.  It may exceed L
+        for a row that holds the first bytes of exactly ONE frame (the
+        fleet ingest's header row, io/ingest.py): the first step finds
+        the frame whole from its prefix and moves the cursor to its
+        end, where no step finds another; the gathers clip to the row.
       max_frames: static bound on frames per stream (scan length).
 
     Returns:

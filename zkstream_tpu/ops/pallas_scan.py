@@ -240,7 +240,8 @@ def pallas_wire_scan(buf, lens, max_frames: int = 32,
 
     Args:
       buf: uint8 [B, L] accumulated bytes per connection.
-      lens: int32 [B] valid byte counts.
+      lens: int32 [B] valid byte counts (beyond L for a header row, as
+        in ``frame_cursor_scan``: no lane matches a cursor past the row).
       max_frames: static per-stream frame bound.
       block_rows: streams per kernel program (grid = B / block_rows).
       interpret: run in the Pallas interpreter (for CPU-based tests).

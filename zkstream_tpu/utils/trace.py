@@ -120,7 +120,7 @@ _OPTIONAL_FIELDS = ('path', 'xid', 'zxid', 'backend', 'session_id',
                     'member', 'batch', 'nbytes', 'detail', 'error',
                     'parent', 'tick', 't0_ns', 't1_ns', 'lane', 'emitted',
                     'rows', 'width', 'names', 'bound', 'cut', 'retick',
-                    'lists', 'shared')
+                    'lists', 'shared', 'headed', 'kept')
 
 #: The slots that read None while nothing has stamped them.
 _READS_NONE = frozenset(_OPTIONAL_FIELDS) | {'duration_ms', '_on_slow',
@@ -160,7 +160,10 @@ class Span:
     payload); ``bound`` / ``cut`` / ``retick`` — a device tick's
     ``ingest.tick`` only: the rows that gave the tick the whole frame
     bound (``max_frames`` frames), the slots that held more than they
-    gave, and 1 where the tick left a follow-up tick for either.
+    gave, and 1 where the tick left a follow-up tick for either;
+    ``headed`` / ``kept`` — the header rows it was given (a slot that
+    held exactly one whole frame wider than ``min_len``) and the bytes
+    of their frames that stayed in the slots.
 
     Beside them: ``duration_ms``; ``_on_slow`` — armed by a ring with a
     slow-op threshold: called once with the span when finish()
